@@ -29,9 +29,12 @@ def setup_logging():
 
 
 def get_device():
-    """The training device: the TPU when one is visible, else whatever
-    JAX exposes (mx.tpu() already falls back to the default backend)."""
-    return mx.tpu()
+    """The training device: the TPU when this process has one, else the
+    host CPU — chosen here, in the open, and logged by the examples;
+    ``mx.tpu()`` itself never falls back (it raises without a chip).
+    These are tutorials, not measurements: a script that reports device
+    numbers asks for ``mx.tpu()`` outright."""
+    return mx.tpu() if mx.context.num_devices("tpu") else mx.cpu()
 
 
 def add_fit_args(parser: argparse.ArgumentParser):

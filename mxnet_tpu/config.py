@@ -17,7 +17,8 @@ from typing import Any, Dict, List
 from .base import get_env
 
 __all__ = ["EnvVar", "register_env", "list_env", "describe", "current",
-           "env_bool", "ensure_overlap_flags"]
+           "env_bool", "ensure_overlap_flags", "place_compile_cache",
+           "refuse_shared_chip"]
 
 EnvVar = namedtuple("EnvVar", ["name", "default", "dtype", "doc"])
 
@@ -133,18 +134,18 @@ register_env(
     "raise when the fused step is built.")
 register_env(
     "MXNET_ASYNC_COLLECTIVES", 1, int,
-    "1 (default): on TPU/GPU backends, append the async-collective + "
-    "latency-hiding-scheduler XLA flags to XLA_FLAGS at import (TPU: "
-    "xla_enable_async_all_gather / xla_enable_async_collective_"
-    "permute / xla_tpu_enable_async_collective_fusion*; GPU: "
-    "xla_gpu_enable_latency_hiding_scheduler) so the per-bucket "
+    "1 (default): when the process targets the TPU, append libtpu's "
+    "async-collective flags to LIBTPU_INIT_ARGS at import "
+    "(xla_enable_async_all_gather / xla_enable_async_collective_"
+    "permute / xla_tpu_enable_async_collective_fusion* / "
+    "xla_tpu_overlap_compute_collective_tc) so the per-bucket "
     "gradient collectives emitted by the ZeRO update segment overlap "
     "backward/update compute — the in-program analogue of the PR-3 "
-    "CommScheduler.  Flags the user already set in XLA_FLAGS are "
-    "never overridden.  On CPU builds nothing is appended (the TPU "
-    "flag names are unknown there and XLA aborts on unknown flags).  "
-    "0: leave XLA_FLAGS untouched.  Values other than 0/1 raise at "
-    "import.")
+    "CommScheduler.  Flags the user already set there are never "
+    "overridden; XLA_FLAGS is never touched (jaxlib aborts on flags "
+    "it does not know, and these are libtpu's).  On CPU nothing is "
+    "appended.  0: leave LIBTPU_INIT_ARGS untouched.  Values other "
+    "than 0/1 raise at import.")
 register_env(
     "MXNET_PP_CONSTRAIN", 0, int,
     "1: pin the pipeline's (stage, microbatch, ...) activation stash "
@@ -709,10 +710,11 @@ register_env(
 register_env(
     "MXNET_PEAK_TFLOPS", None, float,
     "Per-chip peak dense-matmul TFLOP/s for the training.mfu gauge "
-    "denominator.  Unset: a built-in table keyed on the jax device "
-    "kind (TPU v4/v5e/v5p/v6); REQUIRED for MFU on CPU meshes and "
-    "unlisted hardware (the gauge is withheld rather than guessed).  "
-    "Non-positive or garbage values raise at first use.")
+    "denominator.  Unset: profiler.PEAK_BY_DEVICE_KIND, keyed on "
+    "jax's device_kind (it holds the TPU v5e); on a CPU backend the "
+    "gauge is withheld rather than guessed, and an accelerator the "
+    "table does not know raises.  Non-positive or garbage values "
+    "raise at first use.")
 register_env(
     "MXNET_SLO_TTFT_MS", "interactive=250,batch=5000", str,
     "Per-class time-to-first-token SLO targets, as 'class=ms,...' "
@@ -775,23 +777,20 @@ register_env(
     "Device the test utilities bind to (test_utils.default_context; "
     "the reference's MXNET_TEST_DEVICE).  Unset: the ambient current "
     "context.")
-register_env(
-    "MXNET_TEST_TPU", 0, int,
-    "1: run the pytest suite against the real TPU instead of the "
-    "virtual CPU mesh (tests/conftest.py).")
 
 
 # ---------------------------------------------------------------------------
-# Async-collective XLA flag wiring (MXNET_ASYNC_COLLECTIVES)
+# Async-collective compiler flag wiring (MXNET_ASYNC_COLLECTIVES)
 # ---------------------------------------------------------------------------
 
-# The flag sets the overlap path needs, per accelerator backend.  They
-# split each collective into <op>-start / <op>-done pairs and let the
-# latency-hiding scheduler move real compute between them — the
-# structural property tests/test_overlap.py inspects in the compiled
-# HLO.  GPU flag names are registered in every XLA build; the TPU ones
-# live in libtpu and are fatal-unknown elsewhere, hence the platform
-# gate in ensure_overlap_flags.
+# The flags the overlap path needs.  They split each collective into
+# <op>-start / <op>-done pairs and let the latency-hiding scheduler
+# move real compute between them.  They are libtpu's, so they go where
+# libtpu reads them: LIBTPU_INIT_ARGS.  jaxlib's own XLA_FLAGS parser
+# does not know them and kills the process at the first backend
+# ("Unknown flags in XLA_FLAGS") — on the chip machine too.  Each one
+# below was accepted through LIBTPU_INIT_ARGS by libtpu 0.0.34 on a
+# v5e (chip run, PR 21).
 TPU_OVERLAP_FLAGS = (
     "--xla_enable_async_all_gather=true",
     "--xla_enable_async_collective_permute=true",
@@ -799,9 +798,6 @@ TPU_OVERLAP_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
     "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
     "--xla_tpu_overlap_compute_collective_tc=true",
-)
-GPU_OVERLAP_FLAGS = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
 )
 
 
@@ -820,12 +816,11 @@ def env_bool(name: str) -> bool:
     raise MXNetError(f"{name}={raw!r} must be 0 or 1")
 
 
-def _wants_tpu() -> bool:
-    """True when this process will initialize a TPU backend — decided
-    WITHOUT importing jax (XLA_FLAGS must be final before the first
-    backend query, and an unknown --xla_tpu_* flag aborts non-TPU
-    builds)."""
-    plats = os.environ.get("JAX_PLATFORMS", "")
+def _wants_tpu(env=os.environ) -> bool:
+    """True when a process with environment ``env`` may initialize a
+    TPU backend — decided WITHOUT importing jax (libtpu reads
+    LIBTPU_INIT_ARGS once, when the backend is created)."""
+    plats = env.get("JAX_PLATFORMS", "")
     if plats:
         return "tpu" in plats.lower()
     import importlib.util
@@ -836,47 +831,81 @@ def _wants_tpu() -> bool:
         return False
 
 
-def _wants_gpu() -> bool:
-    plats = os.environ.get("JAX_PLATFORMS", "")
-    if plats:
-        return any(p in plats.lower() for p in ("gpu", "cuda", "rocm"))
-    # JAX_PLATFORMS unset is the COMMON GPU configuration (jax[cuda]
-    # autodetects): look for the PJRT plugin packages instead
-    import importlib.util
-
-    for name in ("jax_cuda12_plugin", "jax_cuda11_plugin",
-                 "jax_rocm60_plugin", "jax_rocm7_plugin"):
-        try:
-            if importlib.util.find_spec(name) is not None:
-                return True
-        except (ImportError, ValueError):
-            continue
-    return False
-
-
 def ensure_overlap_flags() -> bool:
-    """Append the async-collective / latency-hiding XLA flags to
-    ``XLA_FLAGS`` when MXNET_ASYNC_COLLECTIVES=1 and the process
-    targets an accelerator backend.  Called at package import (before
-    any jax backend exists); idempotent; never overrides a flag the
-    user already set (first occurrence wins in XLA's parser is NOT
-    guaranteed, so ours are simply skipped).  Returns True when flags
-    were appended."""
-    if not env_bool("MXNET_ASYNC_COLLECTIVES"):
+    """Append the async-collective flags to ``LIBTPU_INIT_ARGS`` when
+    MXNET_ASYNC_COLLECTIVES=1 and the process targets the TPU.  Called
+    at package import (before any jax backend exists); idempotent;
+    never overrides a flag the user already set there.  Returns True
+    when flags were appended."""
+    if not env_bool("MXNET_ASYNC_COLLECTIVES") or not _wants_tpu():
         return False
-    flags = ()
-    if _wants_tpu():
-        flags = TPU_OVERLAP_FLAGS + GPU_OVERLAP_FLAGS
-    elif _wants_gpu():
-        flags = GPU_OVERLAP_FLAGS
-    if not flags:
-        return False
-    current = os.environ.get("XLA_FLAGS", "")
+    current = os.environ.get("LIBTPU_INIT_ARGS", "")
     have = {f.split("=")[0] for f in current.split() if f.startswith("--")}
-    add = [f for f in flags if f.split("=")[0] not in have]
+    add = [f for f in TPU_OVERLAP_FLAGS if f.split("=")[0] not in have]
     if add:
-        os.environ["XLA_FLAGS"] = (current + " " + " ".join(add)).strip()
+        os.environ["LIBTPU_INIT_ARGS"] = (
+            current + " " + " ".join(add)).strip()
     return bool(add)
+
+
+def refuse_shared_chip(child_env, what: str) -> None:
+    """One process for each chip.  Called by the launchers before they
+    start a JAX child process: a child that would open the TPU while
+    this process holds it, or that is not told which chip is its own
+    (``TPU_VISIBLE_CHIPS``) and so would open every chip of the host
+    like each of its siblings, fails or hangs at its first backend —
+    refuse here, with a message, instead.  Children held to the CPU
+    (``JAX_PLATFORMS=cpu``, what the tests and ``launch.py --cpu``
+    start) pass."""
+    if not _wants_tpu(child_env):
+        return
+    import sys
+
+    from .base import MXNetError
+
+    bridge = sys.modules.get("jax._src.xla_bridge")
+    if bridge is not None and "tpu" in getattr(bridge, "_backends", {}):
+        raise MXNetError(
+            f"{what}: this process has already opened the TPU, and a "
+            f"chip belongs to one process at a time — the child would "
+            f"fail or hang.  Start children from a parent that never "
+            f"creates a jax backend, or hold them to JAX_PLATFORMS=cpu")
+    if not child_env.get("TPU_VISIBLE_CHIPS"):
+        raise MXNetError(
+            f"{what}: the child targets the TPU (JAX_PLATFORMS="
+            f"{child_env.get('JAX_PLATFORMS', '')!r}) but its environment "
+            f"sets no TPU_VISIBLE_CHIPS, so it would open every chip of "
+            f"this host, as would each sibling — one process for each "
+            f"chip.  Give each child its own chip(s) through its env "
+            f"(TPU_VISIBLE_CHIPS plus the process-bounds variables "
+            f"libtpu documents), drive all chips from ONE process "
+            f"(MeshPlan / DecodeEngine(tp=..., devices=...)), or hold "
+            f"the children to JAX_PLATFORMS=cpu")
+
+
+def place_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and say where it
+    lives — the ONE place this repo's scripts decide that.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set the cache lives there and no
+    directory is set in code (jax reads the variable itself); where it
+    is not, ``<checkout>/.jax_cache`` — a fixed path, never one made
+    from tempfile, a pid or the time: a directory that moves never
+    hits.  Returns the directory in use.
+
+    Every compile is kept, however short: ``init_params`` alone runs a
+    couple of hundred 0.2-0.4 s compiles on the chip (one per
+    parameter shape), a minute of a cold run that jax's default
+    threshold of 1 s would never cache."""
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
 
 
 ensure_overlap_flags()

@@ -75,8 +75,12 @@ class Context:
         """Resolve to a concrete jax.Device.
 
         gpu/tpu both resolve to the accelerator backend (alias so
-        reference scripts with ``mx.gpu()`` work); falls back to CPU
-        when no accelerator is present.
+        reference scripts with ``mx.gpu()`` work).  An accelerator
+        ordinal this process does not have is an error — never a CPU
+        device, never another chip: a run that asked for the chip and
+        got something else would report its numbers under the chip's
+        name.  cpu ids are logical, as in the reference: they wrap
+        over the host devices.
         """
         if self._jax_device is not None:
             return self._jax_device
@@ -87,7 +91,15 @@ class Context:
             self._jax_device = devs[self.device_id % len(devs)]
         else:
             devs = _accelerator_devices()
-            self._jax_device = devs[self.device_id % len(devs)]
+            if not 0 <= self.device_id < len(devs):
+                from .base import MXNetError
+
+                raise MXNetError(
+                    f"{self}: this process has {len(devs)} accelerator "
+                    f"device(s) (jax default backend "
+                    f"{jax.default_backend()!r}); ask for mx.cpu() to "
+                    f"run on the host")
+            self._jax_device = devs[self.device_id]
         return self._jax_device
 
 
@@ -101,8 +113,7 @@ def _local_cpu_devices():
 
 def _accelerator_devices(local_only: bool = True):
     devs = jax.local_devices() if local_only else jax.devices()
-    accel = [d for d in devs if d.platform != "cpu"]
-    return accel if accel else devs
+    return [d for d in devs if d.platform != "cpu"]
 
 
 def cpu(device_id: int = 0) -> Context:
@@ -119,7 +130,8 @@ def tpu(device_id: int = 0) -> Context:
 
 
 def num_devices(device_type: str = "tpu") -> int:
-    """Per-process (addressable) device count."""
+    """Per-process (addressable) device count; 0 accelerators when the
+    process has none."""
     if device_type in ("cpu", "cpu_pinned"):
         return len(_local_cpu_devices())
     return len(_accelerator_devices())

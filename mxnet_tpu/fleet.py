@@ -832,7 +832,15 @@ def spawn_replica(rid: int, fleet_dir: str, builder: str,
     ``devices``: device ordinals this replica's engine meshes over —
     exported as ``MXNET_SERVING_DEVICES`` so a model-parallel replica
     (MXNET_SERVING_TP / MXNET_SERVING_PP > 1) binds its tp x pp slice
-    of the host's chips while its siblings bind theirs."""
+    of the host's chips while its siblings bind theirs.
+
+    Each replica is a JAX process of its own, and a TPU chip belongs
+    to one process at a time: on a TPU host ``env`` must give the
+    replica its chip(s) (``TPU_VISIBLE_CHIPS``; the ordinals in
+    ``devices`` then count within what it sees), and the parent — the
+    router — must never have created a jax backend.  Otherwise this
+    refuses (``config.refuse_shared_chip``) instead of starting a
+    child that hangs."""
     spec = {"rid": int(rid), "fleet_dir": fleet_dir, "builder": builder,
             "kwargs": builder_kwargs or {}, "parent": os.getpid()}
     child_env = dict(os.environ)
@@ -840,6 +848,9 @@ def spawn_replica(rid: int, fleet_dir: str, builder: str,
     if devices is not None:
         child_env["MXNET_SERVING_DEVICES"] = \
             ",".join(str(int(d)) for d in devices)
+    from .config import refuse_shared_chip
+
+    refuse_shared_chip(child_env, f"fleet.spawn_replica(rid={rid})")
     return subprocess.Popen(
         [sys.executable, "-m", "mxnet_tpu.fleet", json.dumps(spec)],
         env=child_env)

@@ -98,7 +98,9 @@ def collective_summary(hlo_text: str) -> List[Tuple[str, str, int]]:
     overlap question."""
     rows: List[Tuple[str, str, int]] = []
     for i, line in enumerate(_entry_lines(hlo_text)):
-        m = _INSTR.match(line)
+        # long tuple shapes carry /*index=5*/ markers — the combined
+        # gradient all-reduce is such a tuple
+        m = _INSTR.match(re.sub(r"/\*.*?\*/", "", line))
         if not m:
             continue
         shape, op = m.group(1), m.group(2)

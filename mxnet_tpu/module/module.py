@@ -521,6 +521,16 @@ class Module(BaseModule):
                     arr._data = plan.place(np.asarray(bcast[name]), sh)
             else:
                 arr._set_data(arr._data)  # re-place via the sharding pin
+                if not arr._data.sharding.is_equivalent_to(sh, arr.ndim):
+                    # _set_data leaves a value it cannot place where it
+                    # was; one parameter left on one device surfaces
+                    # later as an "incompatible devices" error that
+                    # names neither the parameter nor the cause
+                    raise MXNetError(
+                        f"parameter {name!r} {tuple(arr.shape)} could "
+                        f"not be placed as {sh.spec} on the "
+                        f"{dict(plan.mesh.shape)} mesh — a sharded "
+                        f"dimension must divide by its mesh axis")
             g = self._exec.grad_dict.get(name)
             if g is not None:
                 g._sharding = sh
@@ -929,6 +939,8 @@ class Module(BaseModule):
         import jax
         import jax.numpy as jnp
 
+        from ..parallel import tracing_for
+
         plan = self._mesh_plan
         if plan is not None and (plan.pp > 1 or plan.microbatches > 1):
             return self._build_pipelined_step()
@@ -957,7 +969,10 @@ class Module(BaseModule):
                 full = dict(inputs)
                 full.update(fixed)
                 full.update(p)
-                outs, new_aux = graph_fn(full, aux, rng, True)
+                # kernels that the compiler cannot partition shard_map
+                # themselves over the plan they are traced for
+                with tracing_for(plan):
+                    outs, new_aux = graph_fn(full, aux, rng, True)
                 return tuple(outs), new_aux
 
             if do_mirror:
@@ -1505,8 +1520,7 @@ class Module(BaseModule):
             self._fused_t = plan.place(np.int32(self._step_count), rep)
             self._fused_key = plan.place(np.asarray(key), rep)
         else:
-            with jax.default_device(dev):
-                self._fused_t = jnp.int32(self._step_count)
+            self._fused_t = jax.device_put(np.int32(self._step_count), dev)
             self._fused_key = jax.device_put(
                 np.asarray(restored_key) if restored_key is not None
                 else _random.next_key(), dev)
@@ -1777,8 +1791,7 @@ class Module(BaseModule):
                 lr_dev = self._mesh_plan.place(
                     np.float32(lr), self._mesh_plan.replicated())
             else:
-                with jax.default_device(dev):
-                    lr_dev = jnp.float32(lr)
+                lr_dev = jax.device_put(np.float32(lr), dev)  # committed
             self._lr_cache[lr] = lr_dev
         return lr_dev
 
@@ -1896,6 +1909,20 @@ class Module(BaseModule):
         params = _copy_donated_aliases(
             params, _buffer_ids(fixed, aux, inputs, self._fused_state,
                                 self._fused_key, self._fused_t))
+        if not self._fused_warm and self._mesh_plan is None:
+            # First run (after the alias scan, which goes by object
+            # identity): COMMIT every argument to the device.  An
+            # uncommitted array (fresh from init_params) lowers without
+            # a sharding annotation and a committed one (any output of
+            # the step) with it, so the first call, the second call and
+            # fused_hlo_text's lowering were three different programs:
+            # three full XLA compiles of one step — minutes of a cold
+            # run on the chip.  Committed, they are one program (and one
+            # persistent-cache entry).  No copy: same device.
+            params, fixed, aux, self._fused_state = jax.device_put(
+                (params, fixed, aux, self._fused_state), dev)
+            for n, v in fixed.items():  # re-read from here every step
+                self._exec.arg_dict[n]._data = v
         compiled = not self._fused_warm
         self._fused_warm = True
         if compiled:

@@ -267,6 +267,57 @@ def _check_qkv_packing(last_dim, num_heads, shape):
             f"heads' d_head lanes (got shape {tuple(shape)})")
 
 
+def _flash_mha_packed_on_plan(qkv, H, causal, block):
+    """The packed-heads kernel, on one device or across the mesh the
+    graph is traced for.
+
+    A Mosaic kernel cannot be partitioned by the compiler (on the chip
+    a mesh program holding a bare one fails to lower), so under a
+    MeshPlan the call is a shard_map: the batch splits over the mesh
+    axis the rules give 'batch', the heads over the one they give
+    'heads' (when it divides them), every other axis replicates.
+    qkv arrives whole along its packed dim — [q | k | v], each H·D
+    wide, so a contiguous split of that dim is NOT a split by heads —
+    and each device cuts its own heads' q, k and v spans out of it.
+    The output leaves sharded by heads on its last dim: contiguous
+    H·D/tp spans, the layout the row-parallel output projection
+    takes."""
+    from jax.sharding import PartitionSpec as P
+
+    from . import pallas_kernels as pk
+    from ..parallel import traced_plan
+
+    plan = traced_plan()
+    if plan is None or plan.num_devices == 1:
+        return pk.flash_mha_packed(qkv, H, causal=causal, block_size=block)
+    sizes = dict(plan.mesh.shape)
+    B, _T, HD3 = qkv.shape
+    HD = HD3 // 3
+    b_ax = plan.rules.axis_or_none("batch")
+    if b_ax is not None and B % sizes[b_ax]:
+        b_ax = None
+    h_ax = plan.rules.axis_or_none("heads")
+    if h_ax is not None and (h_ax == b_ax or H % sizes[h_ax]):
+        h_ax = None
+    n_h = sizes[h_ax] if h_ax is not None else 1
+    span = HD // n_h  # this device's heads, as lanes of q (or k, or v)
+
+    def local(x):
+        if n_h > 1:
+            lo = jax.lax.axis_index(h_ax) * span
+            x = jnp.concatenate(
+                [jax.lax.dynamic_slice_in_dim(x, part * HD + lo, span,
+                                              axis=2)
+                 for part in range(3)], axis=2)
+        return pk.flash_mha_packed(x, H // n_h, causal=causal,
+                                   block_size=block)
+
+    return jax.shard_map(local, mesh=plan.mesh,
+                         in_specs=P(b_ax, None, None),
+                         out_specs=P(b_ax, None, h_ax),
+                         check_vma=False)(qkv)
+
+
 def _qkv_infer(attrs, in_shapes):
     (s,) = in_shapes
     if s is None:
@@ -299,8 +350,7 @@ def _qkv_attention(op_ctx, attrs, inputs, aux):
     _check_qkv_packing(HD3, H, qkv.shape)
     D = HD3 // (3 * H)
     if pk.enabled():
-        return [pk.flash_mha_packed(qkv, H, causal=causal,
-                                    block_size=block)]
+        return [_flash_mha_packed_on_plan(qkv, H, causal, block)]
     # lax fallback: unpack → blockwise attention → repack
     q, k, v = (jnp.reshape(x, (B, T, H, D))
                for x in jnp.split(qkv, 3, axis=-1))

@@ -29,16 +29,11 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu import works on non-TPU hosts; kernels then use interpret
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
 def enabled() -> bool:
     """Use the Pallas kernels?  Default: only on a real TPU backend."""
-    if pltpu is None:
-        return False  # kernels need the TPU pallas module (scratch/VMEM)
     flag = os.environ.get("MXNET_PALLAS")
     if flag is not None:
         return flag != "0"
@@ -49,13 +44,24 @@ def _interpret() -> bool:
     return jax.default_backend() != "tpu"
 
 
-def _vmem_spec(block=None, index_map=None):
-    kwargs = {}
-    if pltpu is not None:
-        kwargs["memory_space"] = pltpu.VMEM
-    if block is None:
-        return pl.BlockSpec(**kwargs)
-    return pl.BlockSpec(block, index_map, **kwargs)
+def _vmem_spec(block, index_map):
+    return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
+
+
+# What a kernel may ask of the chip's VMEM.  Mosaic's default scoped
+# limit is 16 MiB; the flash families' (block, H*D) tiles, double
+# buffered, need more at block 1024.  A v5e core has 128 MiB and its
+# compiler accepts this request (tests/test_tpu_compile.py).
+_VMEM_LIMIT = 100 * 1024 * 1024
+
+
+def _compiler_params(*dimension_semantics, vmem_limit_bytes=None):
+    """Mosaic parameters for a compiled kernel; None in interpret mode
+    (the interpreter takes none)."""
+    if _interpret():
+        return None
+    return pltpu.CompilerParams(dimension_semantics=dimension_semantics,
+                                vmem_limit_bytes=vmem_limit_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -93,11 +99,6 @@ def _lstm_kernel(xw_ref, h0_ref, c0_ref, ut_ref, y_ref, ht_ref, ct_ref,
 
 def _lstm_pallas_fwd(xw, h0, c0, ut):
     """xw: (T, B, 4H) input projection (+biases); ut: (H, 4H)."""
-    if pltpu is None:
-        raise RuntimeError(
-            "Pallas TPU module unavailable (jax.experimental.pallas.tpu "
-            "failed to import) — the lstm_scan kernel needs its VMEM "
-            "scratch allocators; use the lax.scan path instead")
     T, B, G = xw.shape
     H = G // 4
     dt = xw.dtype
@@ -168,51 +169,68 @@ lstm_scan.defvjp(_lstm_fwd_rule, _lstm_bwd_rule)
 # Greedy NMS
 # ---------------------------------------------------------------------------
 
-def _nms_kernel(rows_ref, out_ref, *, nms_threshold, force_suppress):
-    """rows (1, A, 6) score-sorted [cls, score, l, t, r, b]; suppressed
-    rows get cls = -1.  The i-loop is sequential (each round depends on
-    previous suppressions); each round's IoU test is one VPU vector op
-    over all rows."""
-    out_ref[:] = rows_ref[:]
-    A = out_ref.shape[1]
+def _nms_kernel(rows_ref, cls_ref, *, nms_threshold, force_suppress,
+                n_rows):
+    """rows (1, 8, Ap): FIELD-major [cls, score, l, t, r, b, pad, pad]
+    with the score-sorted anchors along the lanes — an (A, 6) block
+    would pad 6 fields to 128 lanes and overflow VMEM at SSD's 8,732
+    anchors; this layout holds them in ~280 KB.  Writes the (1, 1, Ap)
+    class row with suppressed anchors set to -1.  The i-loop is
+    sequential (each round depends on previous suppressions); each
+    round's IoU test is one VPU vector op over all anchors."""
+    cls_ref[0] = rows_ref[0, 0:1, :]
+    Ap = rows_ref.shape[2]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, Ap), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1)
 
     def round_i(i, _):
-        cls_i = out_ref[0, i, 0]
-        box_i = out_ref[0, i, 2:6]
-        cls = out_ref[0, :, 0]
-        l = jnp.maximum(out_ref[0, :, 2], box_i[0])
-        t = jnp.maximum(out_ref[0, :, 3], box_i[1])
-        r = jnp.minimum(out_ref[0, :, 4], box_i[2])
-        b = jnp.minimum(out_ref[0, :, 5], box_i[3])
-        inter = jnp.maximum(r - l, 0.0) * jnp.maximum(b - t, 0.0)
-        area = (out_ref[0, :, 4] - out_ref[0, :, 2]) * \
-               (out_ref[0, :, 5] - out_ref[0, :, 3])
-        area_i = (box_i[2] - box_i[0]) * (box_i[3] - box_i[1])
+        # anchor i's fields as an (8, 1) column: load its aligned
+        # 128-lane tile and mask-reduce (no dynamic lane indexing)
+        base = pl.multiple_of((i // 128) * 128, 128)
+        pick = sub == i - base
+        col = jnp.sum(jnp.where(pick, rows_ref[0, :, pl.ds(base, 128)], 0.0),
+                      axis=1, keepdims=True)
+        cls_i = jnp.sum(jnp.where(pick[0:1], cls_ref[0, :, pl.ds(base, 128)],
+                                  0.0), axis=1, keepdims=True)
+        l_i, t_i, r_i, b_i = col[2:3], col[3:4], col[4:5], col[5:6]
+        cls = cls_ref[0]
+        l_a, t_a = rows_ref[0, 2:3, :], rows_ref[0, 3:4, :]
+        r_a, b_a = rows_ref[0, 4:5, :], rows_ref[0, 5:6, :]
+        inter = jnp.maximum(jnp.minimum(r_a, r_i) - jnp.maximum(l_a, l_i),
+                            0.0) \
+            * jnp.maximum(jnp.minimum(b_a, b_i) - jnp.maximum(t_a, t_i), 0.0)
+        area = (r_a - l_a) * (b_a - t_a)
+        area_i = (r_i - l_i) * (b_i - t_i)
         union = area + area_i - inter
         iou = jnp.where(union > 0, inter / jnp.maximum(union, 1e-12), 0.0)
-        later = jax.lax.broadcasted_iota(jnp.int32, (A,), 0) > i
         same = jnp.logical_or(bool(force_suppress), cls == cls_i)
-        suppress = (cls_i >= 0) & later & same & (cls >= 0) \
+        suppress = (cls_i >= 0) & (lane > i) & same & (cls >= 0) \
             & (iou >= nms_threshold)
-        out_ref[0, :, 0] = jnp.where(suppress, -1.0, cls)
+        cls_ref[0] = jnp.where(suppress, -1.0, cls)
         return 0
 
-    jax.lax.fori_loop(0, A, round_i, 0)
+    jax.lax.fori_loop(0, n_rows, round_i, 0)
 
 
 def nms(rows, nms_threshold, force_suppress):
     """rows (B, A, 6) sorted by score desc → suppressed rows cls=-1."""
     B, A, _ = rows.shape
+    # field-major, anchors in lanes; padding is cls = -1 (never alive)
+    fields = jnp.pad(jnp.swapaxes(rows, 1, 2),
+                     ((0, 0), (0, 2), (0, (-A) % 128)),
+                     constant_values=-1.0)
+    Ap = fields.shape[2]
     kern = functools.partial(_nms_kernel, nms_threshold=float(nms_threshold),
-                             force_suppress=bool(force_suppress))
-    return pl.pallas_call(
+                             force_suppress=bool(force_suppress), n_rows=A)
+    cls = pl.pallas_call(
         kern,
         grid=(B,),
-        in_specs=[_vmem_spec((1, A, 6), lambda b: (b, 0, 0))],
-        out_specs=_vmem_spec((1, A, 6), lambda b: (b, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, A, 6), rows.dtype),
+        in_specs=[_vmem_spec((1, 8, Ap), lambda b: (b, 0, 0))],
+        out_specs=_vmem_spec((1, 1, Ap), lambda b: (b, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, 1, Ap), rows.dtype),
         interpret=_interpret(),
-    )(rows)
+    )(fields)
+    return rows.at[:, :, 0].set(cls[:, 0, :A])
 
 
 # ---------------------------------------------------------------------------
@@ -347,16 +365,15 @@ def flash_attention_partial(q, k, v, causal, block_size, kv_offset):
             _vmem_spec((1, bq, 128), lambda bh, qi, kj, koff: (bh, qi, 0)),
             _vmem_spec((1, bq, 128), lambda bh, qi, kj, koff: (bh, qi, 0)),
         ],
-    ) if pltpu is not None else None
+    )
     o, m, l = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
         out_shape=[_sds((B * H, Tqp, Dp), vma),
                    _sds((B * H, Tqp, 128), vma),
                    _sds((B * H, Tqp, 128), vma)],
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"))
-            if pltpu is not None and not _interpret() else None),
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
         interpret=_interpret(),
     )(koff, qf, kf, vf)
     o = jnp.reshape(o[:, :Tq, :D], (B, H, Tq, D))
@@ -506,9 +523,7 @@ def flash_attention_bwd(q, k, v, m, o_bar, l_bar, causal, block_size,
     koff = jnp.asarray(kv_offset, jnp.int32).reshape(1)
     kern_kwargs = dict(causal=causal, block_q=bq, block_k=bk,
                        tk_valid=Tk, scale=scale)
-    cparams = (pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-        if pltpu is not None and not _interpret() else None)
+    cparams = _compiler_params("parallel", "parallel", "arbitrary")
 
     dq = pl.pallas_call(
         functools.partial(_flash_bwd_dq_kernel, **kern_kwargs),
@@ -526,7 +541,7 @@ def flash_attention_bwd(q, k, v, m, o_bar, l_bar, causal, block_size,
             out_specs=[
                 _vmem_spec((1, bq, Dp), lambda bh, qi, kj, koff: (bh, qi, 0)),
             ],
-        ) if pltpu is not None else None,
+        ),
         out_shape=[_sds((B * H, Tqp, Dp), vma)],
         compiler_params=cparams,
         interpret=_interpret(),
@@ -549,7 +564,7 @@ def flash_attention_bwd(q, k, v, m, o_bar, l_bar, causal, block_size,
                 _vmem_spec((1, bk, Dp), lambda bh, kj, qi, koff: (bh, kj, 0)),
                 _vmem_spec((1, bk, Dp), lambda bh, kj, qi, koff: (bh, kj, 0)),
             ],
-        ) if pltpu is not None else None,
+        ),
         out_shape=[_sds((B * H, Tkp, Dp), vma),
                    _sds((B * H, Tkp, Dp), vma)],
         compiler_params=cparams,
@@ -826,10 +841,9 @@ def _mha_fwd(q, k, v, causal, block_size):
         out_shape=[_sds_t((BH, Tqp, D), q.dtype, vma),
                    _sds_t((BH, Tqp, 128), jnp.float32, vma)],
         scratch_shapes=scratch,
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024)
-            if pltpu is not None and not _interpret() else None),
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "arbitrary",
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
     )(qf, kf, vf)
     return o[:, :Tq], lse[:, :Tq]
@@ -858,10 +872,8 @@ def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
     nq, nk = Tqp // bq, Tkp // bk
     kw = dict(causal=causal, block_q=bq, block_k=bk, tq_valid=Tq,
               tk_valid=Tk, scale=scale)
-    cparams = (pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=100 * 1024 * 1024)
-        if pltpu is not None and not _interpret() else None)
+    cparams = _compiler_params("parallel", "parallel", "arbitrary",
+                               vmem_limit_bytes=_VMEM_LIMIT)
 
     dq = pl.pallas_call(
         functools.partial(_mha_bwd_dq_kernel, nk=nk, **kw),
@@ -1218,10 +1230,9 @@ def _mhap_fwd(qkv, H, D, causal, block_size):
         scratch_shapes=[pltpu.VMEM((bq, HD), jnp.float32),
                         pltpu.VMEM((bq, HD), jnp.float32),
                         pltpu.VMEM((bq, HD), jnp.float32)],
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024)
-            if pltpu is not None and not _interpret() else None),
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "arbitrary",
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
     )(qkvf, qkvf, qkvf)
     return o[:, :T], lse[:, :T]
@@ -1240,10 +1251,8 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
     nq = nk = Tp // bq
     kw = dict(H=H, D=D, causal=causal, block_q=bq, block_k=bk,
               tq_valid=T, tk_valid=T, scale=scale)
-    cparams = (pltpu.CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
-        vmem_limit_bytes=100 * 1024 * 1024)
-        if pltpu is not None and not _interpret() else None)
+    cparams = _compiler_params("parallel", "parallel", "arbitrary",
+                               vmem_limit_bytes=_VMEM_LIMIT)
 
     dq = pl.pallas_call(
         functools.partial(_mhap_bwd_dq_kernel, nk=nk, **kw),
@@ -1291,210 +1300,36 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
 
 
 # ---------------------------------------------------------------------------
-# Paged decode attention: one query position per stream attending a KV
-# cache scattered over fixed-size pages, gathered page-by-page INTO
-# VMEM through a scalar-prefetched block table (the PagedAttention
-# pattern, Kwon et al. SOSP '23).  The gathered cache never
-# materializes in HBM — HBM traffic per step is exactly the pages a
-# stream actually holds.
+# Paged attention: W query positions per stream attending a KV cache
+# scattered over fixed-size pages, gathered page-by-page INTO VMEM
+# through a scalar-prefetched block table (the PagedAttention pattern,
+# Kwon et al. SOSP '23).  The gathered cache never materializes in
+# HBM — HBM traffic per step is exactly the pages a stream actually
+# holds.
+#
+# ONE kernel family serves the decode step (W = 1), the quantized-
+# cache decode step (W = 1 plus per-slot scales dequantized in VMEM)
+# and the speculative-verify window (W = 1 + k).  Grid (B, MB): each
+# step DMAs ONE page of K and V and folds it into a (H, W, ...)
+# online-softmax state under the DIAGONAL mask
+# k_pos < start[b] + 1 + w — row w reproduces exactly the mask (and
+# block chain) of a single-query decode at length start[b] + 1 + w.
+# A page fully masked for a row is an exact no-op of that row's state
+# merge (alpha == 1, p == 0).
+#
+# Every contraction keeps the query in (W, H, D) form: heads are the
+# batch dimension and W the left operand's free dimension, which is
+# what Mosaic's dot needs (a 2-D (H, D) query batched over H leaves
+# the left operand no free dimension and is refused on the chip).
 # ---------------------------------------------------------------------------
 
 
-def _paged_fold_page(q, k, v, b, j, len_ref, acc_scr, m_scr, l_scr, *,
-                     scale, kvb):
-    """Fold one (KVB, H, D) page into the per-head online-softmax
-    state held in VMEM scratch — shared by the raw and the dequantized
-    kernels (softmax statistics accumulate in fp32 either way)."""
-    # s[h, t] = q[h, :] . k[t, h, :]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32) * scale
-    k_pos = j * kvb + jax.lax.broadcasted_iota(
-        jnp.int32, s.shape, 1)
-    valid = k_pos < len_ref[b]
-    s = jnp.where(valid, s, -jnp.inf)
-    m_prev = m_scr[:, 0]                      # (H,)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-    p = jnp.where(valid, jnp.exp(s - m_safe[:, None]), 0.0)
-    alpha = jnp.where(m_prev == -jnp.inf, 0.0,
-                      jnp.exp(m_prev - m_safe))
-    l_scr[...] = jnp.broadcast_to(
-        (l_scr[:, 0] * alpha + jnp.sum(p, axis=1))[:, None],
-        l_scr.shape)
-    # pv[h, d] = sum_t p[h, t] * v[t, h, d]
-    pv = jax.lax.dot_general(
-        p.astype(v.dtype), v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)
-    acc_scr[...] = acc_scr[...] * alpha[:, None] + pv
-    m_scr[...] = jnp.broadcast_to(m_new[:, None], m_scr.shape)
-
-
-def _paged_decode_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         acc_scr, m_scr, l_scr, *, scale, kvb, nb):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    # pages past the stream's last block hold nothing visible — skip
-    # their matmuls entirely (the block table pads them to the scratch
-    # page, so the prefetch itself is always a valid page id)
-    @pl.when(j * kvb < len_ref[b])
-    def _compute():
-        _paged_fold_page(q_ref[0], k_ref[0], v_ref[0], b, j, len_ref,
-                         acc_scr, m_scr, l_scr, scale=scale, kvb=kvb)
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_scr[:, 0]
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-def _paged_decode_quant_kernel(table_ref, len_ref, q_ref, k_ref, v_ref,
-                               ks_ref, vs_ref, o_ref, acc_scr, m_scr,
-                               l_scr, *, scale, kvb, nb):
-    """The quantized-cache variant: pages arrive in VMEM as int8/fp8
-    plus their (KVB, H) per-slot-per-head float32 scales and are
-    dequantized IN KERNEL, right after the DMA — the narrow dtype is
-    what crosses HBM, the fp32 values never materialize off-chip."""
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
-
-    @pl.when(j * kvb < len_ref[b])
-    def _compute():
-        k = k_ref[0].astype(jnp.float32) * ks_ref[0][:, :, None]
-        v = v_ref[0].astype(jnp.float32) * vs_ref[0][:, :, None]
-        _paged_fold_page(q_ref[0], k, v, b, j, len_ref,
-                         acc_scr, m_scr, l_scr, scale=scale, kvb=kvb)
-
-    @pl.when(j == nb - 1)
-    def _finish():
-        l = l_scr[:, 0]
-        o_ref[0] = (acc_scr[...]
-                    / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-
-
-def paged_attention_decode(q, k_pool, v_pool, block_table, lengths):
-    """q (B, H, D) at position lengths-1; k_pool/v_pool (P, KVB, H, D);
-    block_table (B, MB) int32 page ids (page 0 = scratch); lengths (B,)
-    int32 counting the current token -> (B, H, D) in q.dtype.
-
-    Grid (B, MB): each step DMAs ONE page of K and V into VMEM via the
-    scalar-prefetched block table and folds it into the per-head
-    online-softmax state held in VMEM scratch.
-
-    H here is whatever the caller holds — under the serving mesh's
-    shard_map it is the LOCAL head count H/tp with pools sliced on
-    their head dim, and the kernel is head-wise independent, so the
-    grid/DMA structure (and per-step VMEM footprint) just shrinks
-    with the shard."""
-    B, H, D = q.shape
-    P, KVB = k_pool.shape[0], k_pool.shape[1]
-    MB = block_table.shape[1]
-    scale = 1.0 / float(D) ** 0.5
-    kern = functools.partial(_paged_decode_kernel, scale=scale, kvb=KVB,
-                             nb=MB)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, MB),
-        in_specs=[
-            _vmem_spec((1, H, D), lambda b, j, tr, lr: (b, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
-        ],
-        out_specs=_vmem_spec((1, H, D), lambda b, j, tr, lr: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((H, D), jnp.float32),
-                        pltpu.VMEM((H, 128), jnp.float32),
-                        pltpu.VMEM((H, 128), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024)
-            if pltpu is not None and not _interpret() else None),
-        interpret=_interpret(),
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool)
-
-
-def paged_attention_decode_quant(q, k_pool, v_pool, k_scale, v_scale,
-                                 block_table, lengths):
-    """Quantized-cache paged decode: like :func:`paged_attention_decode`
-    but k_pool/v_pool hold int8 (or fp8) values and
-    k_scale/v_scale (P, KVB, H) float32 hold the per-slot-per-head
-    dequantization scales, applied in kernel after each page's DMA.
-    Softmax statistics and the P·V accumulation stay float32."""
-    B, H, D = q.shape
-    P, KVB = k_pool.shape[0], k_pool.shape[1]
-    MB = block_table.shape[1]
-    scale = 1.0 / float(D) ** 0.5
-    kern = functools.partial(_paged_decode_quant_kernel, scale=scale,
-                             kvb=KVB, nb=MB)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, MB),
-        in_specs=[
-            _vmem_spec((1, H, D), lambda b, j, tr, lr: (b, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0, 0)),
-            _vmem_spec((1, KVB, H),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0)),
-            _vmem_spec((1, KVB, H),
-                       lambda b, j, tr, lr: (tr[b, j], 0, 0)),
-        ],
-        out_specs=_vmem_spec((1, H, D), lambda b, j, tr, lr: (b, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((H, D), jnp.float32),
-                        pltpu.VMEM((H, 128), jnp.float32),
-                        pltpu.VMEM((H, 128), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024)
-            if pltpu is not None and not _interpret() else None),
-        interpret=_interpret(),
-    )(block_table.astype(jnp.int32), lengths.astype(jnp.int32),
-      q, k_pool, v_pool, k_scale, v_scale)
-
-
-# ---------------------------------------------------------------------------
-# Speculative-verify paged attention: the k-query variant of the paged
-# decode kernel.  Same grid (B, MB), same one-page-per-step DMA through
-# the scalar-prefetched block table, but W = 1 + k query rows per
-# stream fold into a (H, W, ...) online-softmax state under the
-# DIAGONAL mask k_pos < start[b] + 1 + w — row w reproduces exactly
-# the mask (and block chain) of the single-query decode at length
-# start[b] + 1 + w.  A page fully masked for a row is an exact no-op
-# of that row's state merge (alpha == 1, p == 0), so per-row results
-# match the decode kernel's bit for bit over the same pool bytes.
-# ---------------------------------------------------------------------------
-
-
-def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref,
-                         o_ref, acc_scr, m_scr, l_scr, *, scale, kvb,
-                         nb, w):
+def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
+                  scale, kvb, nb, w, quant):
+    if quant:
+        ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr = rest
+    else:
+        o_ref, acc_scr, m_scr, l_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
 
@@ -1505,12 +1340,20 @@ def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
 
     # pages past the window's last visible position hold nothing any
-    # row can see — skip their matmuls entirely
+    # row can see — skip their matmuls entirely (the block table pads
+    # them to the scratch page, so the prefetch itself is always a
+    # valid page id)
     @pl.when(j * kvb < start_ref[b] + w)
     def _compute():
         q = q_ref[0]                      # (W, H, D)
         k = k_ref[0]                      # (KVB, H, D)
         v = v_ref[0]
+        if quant:
+            # pages arrive as int8/fp8 plus their (KVB, H) float32
+            # scales and are dequantized right after the DMA — the
+            # narrow dtype is what crosses HBM
+            k = k.astype(jnp.float32) * ks_ref[0][:, :, None]
+            v = v.astype(jnp.float32) * vs_ref[0][:, :, None]
         # s[h, w, t] = q[w, h, :] . k[t, h, :]
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((1,), (1,))),
@@ -1542,31 +1385,28 @@ def _paged_verify_kernel(table_ref, start_ref, q_ref, k_ref, v_ref,
         o_ref[0] = out.swapaxes(0, 1).astype(o_ref.dtype)   # (W, H, D)
 
 
-def paged_attention_verify(q, k_pool, v_pool, block_table, start):
-    """q (B, W, H, D): the verify window's queries at absolute
-    positions ``start[b] + i`` (window K/V already in the pools);
-    k_pool/v_pool (P, KVB, H, D); block_table (B, MB) int32 page ids
-    (page 0 = scratch); start (B,) int32 tokens cached BEFORE the
-    window -> (B, W, H, D) in q.dtype, row i bit-identical to the
-    single-query decode kernel at length ``start[b] + i + 1``."""
+def _paged_attention(q, k_pool, v_pool, scales, block_table, start):
+    """q (B, W, H, D) at absolute positions ``start[b] + i``; scales is
+    () or (k_scale, v_scale), each (P, KVB, H) float32."""
     B, W, H, D = q.shape
-    P, KVB = k_pool.shape[0], k_pool.shape[1]
+    KVB = k_pool.shape[1]
     MB = block_table.shape[1]
-    scale = 1.0 / float(D) ** 0.5
-    kern = functools.partial(_paged_verify_kernel, scale=scale,
-                             kvb=KVB, nb=MB, w=W)
+    kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
+                             kvb=KVB, nb=MB, w=W, quant=bool(scales))
+
+    def page(*tail):
+        zeros = (0,) * (2 + len(tail))
+        return _vmem_spec((1, KVB, H) + tail,
+                          lambda b, j, tr, sr: (tr[b, j],) + zeros)
+
+    def rows():
+        return _vmem_spec((1, W, H, D), lambda b, j, tr, sr: (b, 0, 0, 0))
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, MB),
-        in_specs=[
-            _vmem_spec((1, W, H, D), lambda b, j, tr, sr: (b, 0, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, sr: (tr[b, j], 0, 0, 0)),
-            _vmem_spec((1, KVB, H, D),
-                       lambda b, j, tr, sr: (tr[b, j], 0, 0, 0)),
-        ],
-        out_specs=_vmem_spec((1, W, H, D),
-                             lambda b, j, tr, sr: (b, 0, 0, 0)),
+        in_specs=[rows(), page(D), page(D)] + [page() for _ in scales],
+        out_specs=rows(),
         scratch_shapes=[pltpu.VMEM((H, W, D), jnp.float32),
                         pltpu.VMEM((H, W, 128), jnp.float32),
                         pltpu.VMEM((H, W, 128), jnp.float32)],
@@ -1575,10 +1415,42 @@ def paged_attention_verify(q, k_pool, v_pool, block_table, start):
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
-        compiler_params=(pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
-            vmem_limit_bytes=100 * 1024 * 1024)
-            if pltpu is not None and not _interpret() else None),
+        compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret(),
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
-      q, k_pool, v_pool)
+      q, k_pool, v_pool, *scales)
+
+
+def paged_attention_decode(q, k_pool, v_pool, block_table, lengths):
+    """q (B, H, D) at position lengths-1; k_pool/v_pool (P, KVB, H, D);
+    block_table (B, MB) int32 page ids (page 0 = scratch); lengths (B,)
+    int32 counting the current token -> (B, H, D) in q.dtype.
+
+    The W = 1 case of the paged kernel.  H here is whatever the caller
+    holds — under the serving mesh's shard_map it is the LOCAL head
+    count H/tp with pools sliced on their head dim, and the kernel is
+    head-wise independent, so the grid/DMA structure (and per-step
+    VMEM footprint) just shrinks with the shard."""
+    return _paged_attention(q[:, None], k_pool, v_pool, (), block_table,
+                            lengths - 1)[:, 0]
+
+
+def paged_attention_decode_quant(q, k_pool, v_pool, k_scale, v_scale,
+                                 block_table, lengths):
+    """Quantized-cache paged decode: like :func:`paged_attention_decode`
+    but k_pool/v_pool hold int8 (or fp8) values and
+    k_scale/v_scale (P, KVB, H) float32 hold the per-slot-per-head
+    dequantization scales, applied in kernel after each page's DMA.
+    Softmax statistics and the P·V accumulation stay float32."""
+    return _paged_attention(q[:, None], k_pool, v_pool, (k_scale, v_scale),
+                            block_table, lengths - 1)[:, 0]
+
+
+def paged_attention_verify(q, k_pool, v_pool, block_table, start):
+    """q (B, W, H, D): the verify window's queries at absolute
+    positions ``start[b] + i`` (window K/V already in the pools);
+    k_pool/v_pool (P, KVB, H, D); block_table (B, MB) int32 page ids
+    (page 0 = scratch); start (B,) int32 tokens cached BEFORE the
+    window -> (B, W, H, D) in q.dtype, row i the single-query decode
+    at length ``start[b] + i + 1``."""
+    return _paged_attention(q, k_pool, v_pool, (), block_table, start)

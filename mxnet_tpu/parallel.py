@@ -38,7 +38,9 @@ logical axis over 'dp'.
 
 from __future__ import annotations
 
+import contextlib
 import re
+import threading
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -51,6 +53,30 @@ __all__ = ["MeshPlan", "make_plan", "shard_attr", "annotate_shard",
            "PartitionRules", "DEFAULT_RULES"]
 
 MESH_AXES = ("dp", "pp", "tp")
+
+# The plan a graph is being TRACED for.  Module sets it around the
+# graph function inside its fused step; an op whose TPU lowering is a
+# Mosaic kernel reads it, because such a kernel cannot be partitioned
+# by the compiler ("Mosaic kernels cannot be automatically
+# partitioned") and has to say, with a shard_map over this plan's
+# mesh, which slice each device runs.
+_TRACED = threading.local()
+
+
+@contextlib.contextmanager
+def tracing_for(plan):
+    prev = getattr(_TRACED, "plan", None)
+    _TRACED.plan = plan
+    try:
+        yield
+    finally:
+        _TRACED.plan = prev
+
+
+def traced_plan():
+    """The MeshPlan the current trace is for, or None (one device, or
+    not inside a Module step)."""
+    return getattr(_TRACED, "plan", None)
 
 
 def shard_attr(axis: str, dim: int = 0) -> Dict[str, str]:
@@ -198,15 +224,27 @@ class PartitionRules:
         out._entries = out._entries + self._entries
         return out
 
-    def axis_for(self, logical: str, param: str = "<array>") -> Optional[str]:
-        """First-match-wins lookup of one logical axis name."""
+    def _match(self, logical: str, default):
         for _p, compiled, axis in self._entries:
             if compiled.fullmatch(logical):
                 return axis
+        return default
+
+    def axis_for(self, logical: str, param: str = "<array>") -> Optional[str]:
+        """First-match-wins lookup of one logical axis name."""
+        axis = self._match(logical, self)  # None is a real answer
+        if axis is not self:
+            return axis
         raise MXNetError(
             f"no partition rule matches logical axis {logical!r} of "
             f"{param!r}; add a rule (use axis '-'/None to replicate "
             f"explicitly).  Table: {self!r}")
+
+    def axis_or_none(self, logical: str) -> Optional[str]:
+        """:meth:`axis_for`, with a name no rule matches replicated —
+        for activations an op shards on its own initiative, where a
+        table that never mentions the name is no error."""
+        return self._match(logical, None)
 
     def spec(self, axes: Sequence[Optional[str]],
              shape: Optional[Sequence[int]] = None,

@@ -884,13 +884,8 @@ def _manual_pp(mesh, in_specs, out_specs):
     import jax
 
     def wrap(f):
-        if hasattr(jax, "shard_map"):  # jax >= 0.6 spelling
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        from jax.experimental.shard_map import shard_map
-
-        return shard_map(f, mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)
 
     return wrap
 

@@ -90,7 +90,8 @@ __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "trace_point", "make_trace", "clock_anchor", "FlightRecorder",
            "flight_recorder", "init_flight_recorder", "flight_snapshot",
            "dump_flight_record", "read_flight_file", "GoodputTracker",
-           "goodput_tracker", "device_peak_flops", "MetricsServer",
+           "goodput_tracker", "device_peak_flops", "peak_flops",
+           "PEAK_BY_DEVICE_KIND", "MetricsServer",
            "start_metrics_server", "maybe_start_metrics_server",
            "metrics_server_running",
            "register_statusz", "unregister_statusz", "statusz"]
@@ -1001,24 +1002,38 @@ def start_reporter(path, interval=10.0, registry=None) -> Reporter:
 
 
 # -- live goodput / MFU accounting ---------------------------------------
-# Known per-chip peak dense-matmul rates (bf16 FLOP/s) keyed by a
-# substring of the jax device description — the same numbers the
-# offline bench (tools/bench_secondary.py) divides by, promoted into
-# the library so a real `fit` exports the SAME MFU definition live.
-_PEAK_FLOPS_TABLE = (
-    ("v5 lite", 197e12),
-    ("v5e", 197e12),
-    ("v5p", 459e12),
-    ("v4", 275e12),
-    ("v6", 918e12),
-)
+# THE peak table: per-chip peak rates keyed by jax's ``device_kind``,
+# each row with its source.  bench.py, tools/bench_secondary.py and the
+# live MFU gauge all divide by these.  It holds the one chip this repo
+# runs on; a device that is not here is an error, not a default — add
+# its row, with the source, when it is first measured.
+PEAK_BY_DEVICE_KIND = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
+    # 819 GB/s HBM.  jax reports the chip as "TPU v5 lite".
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def peak_flops(device_kind: str) -> float:
+    """Peak dense bf16 FLOP/s of one chip of ``device_kind`` (as
+    ``jax.devices()[0].device_kind`` reports it).  Unknown kinds
+    raise."""
+    try:
+        return PEAK_BY_DEVICE_KIND[device_kind]["bf16_flops"]
+    except KeyError:
+        raise _mx_error(
+            f"no peak rate for device_kind {device_kind!r} in "
+            f"profiler.PEAK_BY_DEVICE_KIND (knows "
+            f"{sorted(PEAK_BY_DEVICE_KIND)}): add its row with the "
+            f"source, or set MXNET_PEAK_TFLOPS") from None
 
 
 def device_peak_flops():
-    """Per-chip peak FLOP/s for the MFU denominator:
-    ``MXNET_PEAK_TFLOPS`` (authoritative — required on CPU meshes and
-    unlisted hardware) or the built-in device table.  None = unknown →
-    the mfu gauge is simply not exported (goodput still is)."""
+    """Per-chip peak FLOP/s for the live MFU denominator:
+    ``MXNET_PEAK_TFLOPS`` (authoritative) or :func:`peak_flops` of the
+    first device.  None on a CPU backend — a CPU has no such peak, so
+    the mfu gauge is withheld there (goodput still is exported); an
+    accelerator the table does not know raises."""
     raw = os.environ.get("MXNET_PEAK_TFLOPS")
     if raw is not None:
         try:
@@ -1030,16 +1045,12 @@ def device_peak_flops():
         if v <= 0:
             raise _mx_error(f"MXNET_PEAK_TFLOPS={v} must be > 0")
         return v * 1e12
-    try:
-        import jax
+    import jax
 
-        desc = str(jax.devices()[0]).lower()
-    except Exception:  # noqa: BLE001 — no backend yet
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return None
-    for token, flops in _PEAK_FLOPS_TABLE:
-        if token in desc:
-            return flops
-    return None
+    return peak_flops(dev.device_kind)
 
 
 class GoodputTracker:

@@ -42,20 +42,8 @@ __all__ = ["sequence_mesh", "ring_attention", "ulysses_attention"]
 
 
 def _shard_map(f, mesh, in_specs, out_specs, check: bool):
-    """Version shim: ``jax.shard_map(..., check_vma=)`` (jax >= 0.6)
-    vs ``jax.experimental.shard_map.shard_map(..., check_rep=)``
-    (0.4.x/0.5.x) — same semantics, renamed flag."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map as _sm
-
-    # 0.4.x's replication checker miscounts cond-over-ppermute bodies
-    # (the ring's remat backward); its own error message prescribes
-    # check_rep=False — scoped to the legacy API, new-jax runs keep
-    # full vma checking
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def sequence_mesh(sp: Optional[int] = None, devices=None,

@@ -1896,6 +1896,14 @@ class DecodeEngine:
         against the engine counters."""
         return list(self._cost_agg.records)
 
+    def executable_text(self, key) -> str:
+        """Compiled HLO text of one cached executable — ``key`` as in
+        :attr:`compiles`, e.g. ``("decode", 8, 32)``.  What
+        chip_smoke.py reads to prove the paged kernel (a
+        ``tpu_custom_call``) is in the decode step on the chip — the
+        engine's twin of ``Module.fused_hlo_text``."""
+        return self._exe_cache[key].as_text()
+
     # ------------------------------------------------------------------
     # lifecycle
     # ------------------------------------------------------------------

@@ -78,7 +78,10 @@ def test_custom_in_module_training():
     net = mx.sym.SoftmaxOutput(net, name="softmax")
     it = mx.io.NDArrayIter(X, yv, batch_size=20)
     mod = mx.mod.Module(net, context=mx.cpu())
-    mod.fit(it, num_epoch=10, optimizer="sgd",
+    # 40 epochs, not 10: at 10 the accuracy over five seeds spans
+    # 0.75-0.90 (this seed: exactly 0.85, a coin toss against the
+    # threshold); at 40 every seed reaches 0.988
+    mod.fit(it, num_epoch=40, optimizer="sgd",
             optimizer_params={"learning_rate": 0.5})
     acc = mod.score(mx.io.NDArrayIter(X, yv, batch_size=20), "acc")[0][1]
     assert acc > 0.85, acc

@@ -81,15 +81,36 @@ def _engine(params, **kw):
 
 
 # ---------------------------------------------------------------------------
-# bit-identity of prefill + incremental decode vs the full forward
+# prefill + incremental decode vs the full forward
 # ---------------------------------------------------------------------------
+#
+# What is bitwise and what is not: a PREFILL row (M = prompt length)
+# is bit-identical to the full forward's row (M = T) — same dot
+# kernel, same accumulation order.  A DECODE step feeds every
+# FullyConnected a ONE-row left operand, and the installed XLA:CPU
+# picks a different dot kernel for M = 1 than for M > 1 (gemv vs
+# gemm: `dot_general(x[5:6], W)` != `dot_general(x, W)[5:6]` in the
+# last bit, measured 2026-09 on jaxlib 0.9.0), so a decode step's
+# logits differ from the full forward's row by accumulation order,
+# ~1e-6 at these sizes.  That is XLA:CPU's kernel choice, not the
+# cache's: the contract (ROADMAP D3) is the greedy token equal and the
+# logits within LOGIT_TOL.
+
+LOGIT_TOL = dict(rtol=1e-5, atol=1e-5)
+
+
+def _assert_decode_row(got, want, msg):
+    np.testing.assert_allclose(got, want, err_msg=msg, **LOGIT_TOL)
+    assert int(np.argmax(got)) == int(np.argmax(want)), msg
+
 
 
 def test_prefill_decode_logits_bitwise_contiguous(lm):
-    """Op-level contract: prefill + N contiguous decode steps produce
-    logits BIT-IDENTICAL to the full-sequence causal forward row, at
-    every step, across a cache-length bucket boundary (the cache here
-    is padded to C > T like a bucketed executable would)."""
+    """Op-level contract: prefill rows are BIT-IDENTICAL to the
+    full-sequence causal forward, and N contiguous decode steps give
+    its greedy token with logits within LOGIT_TOL at every step,
+    across a cache-length bucket boundary (the cache here is padded
+    to C > T like a bucketed executable would)."""
     import jax
     import jax.numpy as jnp
 
@@ -135,16 +156,16 @@ def test_prefill_decode_logits_bitwise_contiguous(lm):
             a[f"layer{i}_kcache"] = caches[2 * i]
             a[f"layer{i}_vcache"] = caches[2 * i + 1]
         outs, _ = gfn(a, {}, key, False)
-        np.testing.assert_array_equal(
-            np.asarray(outs[0][0, 0]), full[t],
-            err_msg=f"decode step t={t} not bit-identical")
+        _assert_decode_row(np.asarray(outs[0][0, 0]), full[t],
+                           f"decode step t={t} off the full forward")
         caches = [jnp.asarray(x) for x in outs[1:]]
 
 
 def test_paged_decode_bitwise_under_fragmentation(lm):
     """The paged path with a DELIBERATELY fragmented block table
     (pages interleaved/allocated out of order, stale data in freed
-    pages) is bit-identical to the full forward."""
+    pages): prefill rows bit-identical to the full forward, decode
+    steps its greedy token with logits within LOGIT_TOL."""
     import jax
     import jax.numpy as jnp
 
@@ -195,9 +216,8 @@ def test_paged_decode_bitwise_under_fragmentation(lm):
             a[f"layer{i}_kpool"] = pools[2 * i]
             a[f"layer{i}_vpool"] = pools[2 * i + 1]
         outs, _ = dfn(a, {}, key, False)
-        np.testing.assert_array_equal(
-            np.asarray(outs[0][0, 0]), full[t],
-            err_msg=f"paged decode t={t} not bit-identical")
+        _assert_decode_row(np.asarray(outs[0][0, 0]), full[t],
+                           f"paged decode t={t} off the full forward")
         pools = [jnp.asarray(x) for x in outs[1:]]
 
 

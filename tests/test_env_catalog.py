@@ -61,7 +61,7 @@ def test_catalog_has_no_dead_entries():
     """The inverse direction: every registered var is actually
     mentioned somewhere OUTSIDE config.py (a stale catalog entry
     documents configuration that nothing reads).  tests/ and tools/
-    count — some vars (MXNET_TEST_TPU) are consumed by the harness."""
+    count — some vars are consumed by the harness."""
     repo = os.path.dirname(_PKG)
     mentioned = set()
     for sub in ("mxnet_tpu", "tests", "tools"):

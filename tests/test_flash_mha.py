@@ -129,3 +129,43 @@ def test_qkv_packing_validation():
         sym.infer_shape(qkv=(2, 8, 0))  # d_head = 0
     _, out, _ = sym.infer_shape(qkv=(2, 8, 24))
     assert tuple(out[0]) == (2, 8, 8)
+
+
+@pytest.mark.parametrize("mesh", ["dp2_tp2", "dp4", "tp_indivisible"])
+def test_packed_qkv_on_a_mesh_plan_matches_one_device(monkeypatch, mesh):
+    """Under a MeshPlan the packed kernel shard_maps itself (a Mosaic
+    kernel cannot be partitioned by the compiler): batch over 'dp',
+    heads over 'tp' when tp divides them — each device cutting ITS
+    heads' q/k/v spans out of the packed dim — and the result and the
+    qkv gradient equal the one-device kernel's."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu import parallel
+    from mxnet_tpu.models import transformer
+
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    B, T, H, D = 4, 256, 4, 64
+    dp, tp = {"dp2_tp2": (2, 2), "dp4": (4, 1), "tp_indivisible": (1, 3)}[mesh]
+    plan = parallel.MeshPlan(jax.devices()[:dp * tp], dp=dp, tp=tp,
+                             rules=transformer.lm_partition_rules())
+    rng = np.random.RandomState(2)
+    qkv = jnp.asarray(rng.randn(B, T, 3 * H * D).astype(np.float32))
+
+    def one(x):
+        return pk.flash_mha_packed(x, H, causal=True, block_size=128)
+
+    def meshed(x):
+        with parallel.tracing_for(plan):
+            return att._flash_mha_packed_on_plan(x, H, True, 128)
+
+    x_mesh = jax.device_put(qkv, NamedSharding(plan.mesh, P("dp", None, None)))
+    got = jax.jit(meshed)(x_mesh)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(one(qkv)),
+                               rtol=1e-6, atol=1e-6)
+    if mesh == "dp2_tp2":  # heads really are split over tp
+        assert got.sharding.spec == P("dp", None, "tp")
+    g_one = jax.grad(lambda x: jnp.sum(jnp.sin(one(x))))(qkv)
+    g_mesh = jax.jit(jax.grad(lambda x: jnp.sum(jnp.sin(meshed(x)))))(x_mesh)
+    np.testing.assert_allclose(np.asarray(g_mesh), np.asarray(g_one),
+                               rtol=1e-5, atol=1e-5)
+    # outside a traced plan: the plain kernel
+    assert parallel.traced_plan() is None

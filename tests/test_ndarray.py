@@ -156,3 +156,23 @@ def test_gather_global_local_fast_paths():
     np.testing.assert_array_equal(mx.nd.gather_global(a), a.asnumpy())
     np.testing.assert_array_equal(mx.nd.gather_global(np.ones(3)),
                                   np.ones(3))
+
+
+def test_accelerator_context_never_falls_back():
+    """mx.tpu(i) / mx.gpu(i) with no such accelerator is an error —
+    never a CPU device, never another chip (ordinals do not wrap) —
+    and num_devices() counts 0 accelerators where there are none, so
+    `mx.tpu() if mx.context.num_devices() else mx.cpu()` means what it
+    says.  cpu ids stay logical and wrap over the host devices."""
+    import jax
+
+    assert jax.default_backend() == "cpu"  # the suite's platform
+    assert mx.context.num_devices("tpu") == 0
+    for ctx in (mx.tpu(), mx.tpu(5), mx.gpu(0)):
+        with pytest.raises(mx.MXNetError, match="accelerator"):
+            ctx.jax_device()
+    with pytest.raises(mx.MXNetError, match="accelerator"):
+        mx.nd.zeros((2,), ctx=mx.tpu())
+    n = mx.context.num_devices("cpu")
+    assert n >= 1
+    assert mx.cpu(n + 1).jax_device() == mx.cpu(1 % n).jax_device()

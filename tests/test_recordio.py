@@ -64,6 +64,38 @@ def test_recordio_native_python_identical_bytes(tmp_path):
         assert a.read() == b.read()
 
 
+def test_native_lib_from_a_tree_without_it(tmp_path, monkeypatch):
+    """A checkout holds no ``mxnet_tpu/lib/`` (.gitignore hides it):
+    the library is built there from ``native/recordio.cc``, and where
+    it cannot be built ``lib()`` answers None and every consumer takes
+    the Python path — nothing depends on a .so that happens to lie on
+    the builder's disk."""
+    from mxnet_tpu import _native
+
+    so = tmp_path / "lib" / "libmxtpu_io.so"
+    monkeypatch.setattr(_native, "_SO", str(so))
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_tried", False)
+    built = _native.lib()
+    if built is not None:          # a compiler is here: built from source
+        assert so.exists()
+        assert built.MXTPURecordIOScan is not None
+    # no source, no library: None, and a writer/reader pair still works
+    monkeypatch.setattr(_native, "_SO", str(tmp_path / "none" / "x.so"))
+    monkeypatch.setattr(_native, "_SRC", str(tmp_path / "missing.cc"))
+    monkeypatch.setattr(_native, "_lib", None)
+    monkeypatch.setattr(_native, "_tried", False)
+    assert _native.lib() is None
+    path = str(tmp_path / "p.rec")
+    w = rio.MXRecordIO(path, "w")
+    assert w._native is None
+    w.write(b"abc")
+    w.close()
+    r = rio.MXRecordIO(path, "r")
+    assert r.read() == b"abc"
+    r.close()
+
+
 def test_indexed_recordio(tmp_path):
     prefix = str(tmp_path / "i")
     w = rio.MXIndexedRecordIO(prefix + ".idx", prefix + ".rec", "w")

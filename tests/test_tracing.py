@@ -248,6 +248,20 @@ def test_peak_flops_env_override(monkeypatch):
         profiler.device_peak_flops()
 
 
+def test_one_peak_table_unknown_kind_is_an_error(monkeypatch):
+    """profiler.PEAK_BY_DEVICE_KIND is THE peak table (bench.py,
+    bench_secondary and the live gauge divide by it): it holds the one
+    chip there is, and a device_kind without a row raises instead of
+    answering None and letting MFU drop out in silence."""
+    assert profiler.peak_flops("TPU v5 lite") == pytest.approx(197e12)
+    with pytest.raises(mx.MXNetError, match="TPU v9000"):
+        profiler.peak_flops("TPU v9000")
+    # the live gauge: withheld on a CPU backend (no such peak), never
+    # guessed
+    monkeypatch.delenv("MXNET_PEAK_TFLOPS", raising=False)
+    assert profiler.device_peak_flops() is None
+
+
 def test_fit_exports_live_goodput(monkeypatch):
     """A real (tiny) fit exports training.goodput/mfu gauges whose
     decomposition covers ~100% of wall, with flops from the fused

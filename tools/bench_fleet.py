@@ -770,9 +770,9 @@ def main():
     args = ap.parse_args()
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(_REPO, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from mxnet_tpu.config import place_compile_cache
+
+    place_compile_cache()
     if args.disagg_drill:
         if args.replicas == 2:
             args.replicas = 3  # a drill needs a survivor of each role

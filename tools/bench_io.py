@@ -256,9 +256,9 @@ def train_real(n_images=1024, batch=128, epochs=3):
 
     import jax
 
-    jax.config.update("jax_compilation_cache_dir",
-                      os.path.join(_REPO, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    from mxnet_tpu.config import place_compile_cache
+
+    place_compile_cache()
     import jax.numpy as jnp
 
     import mxnet_tpu as mx
@@ -267,7 +267,7 @@ def train_real(n_images=1024, batch=128, epochs=3):
     sys.path.insert(0, os.path.join(_REPO, "tools"))
     from xplane_parse import dominant_module_ms
 
-    ctx = mx.tpu() if mx.context.num_devices() else mx.cpu()
+    ctx = mx.tpu()  # no chip: an error, never CPU numbers
     with tempfile.TemporaryDirectory() as td:
         path = os.path.join(td, "train")
         make_dataset(path, n=n_images)

@@ -37,9 +37,10 @@ Model-parallel mode (--tp N [--pp M]): TP_CLIENTS, TP_REQUESTS,
 TP_PROMPT, TP_NEW, TP_DEVICE_POOL_BYTES (per-device pool budget the
 tp=1 pool must exceed; see the "Model-parallel serving" PERF.md
 appendix).
-CPU fallback shrinks the models (ResNet-50 CIFAR-style at 32x32, a
-2-layer transformer) so the sweep finishes in minutes; on TPU the
-full-size models run.
+On the TPU the full-size models run; with no TPU the script fails.
+``--cpu`` asks for the CPU rehearsal instead: shrunken models (ResNet-50
+CIFAR-style at 32x32, a 2-layer transformer) on mx.cpu(), minutes long,
+its numbers no device metric.
 """
 
 import json
@@ -53,15 +54,38 @@ sys.path.insert(0, _REPO)
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mxnet_tpu.config import place_compile_cache
+
+place_compile_cache()
 
 import numpy as np
 
 
 def log(msg):
     print(f"[bench_serving] {msg}", file=sys.stderr, flush=True)
+
+
+def run_mode():
+    """(backend, cpu).  This is a measurement script: it needs the chip
+    and fails without one.  The shrunken CPU sweep runs only when asked
+    for with ``--cpu`` — a rehearsal of the control flow whose numbers
+    say nothing about the device — never because no chip was found."""
+    backend = jax.default_backend()
+    cpu = "--cpu" in sys.argv
+    if not cpu and backend != "tpu":
+        raise SystemExit(
+            f"bench_serving: jax's default backend is {backend!r}, not "
+            f"'tpu' — a serving measurement needs the chip (pass --cpu "
+            f"for the shrunken CPU rehearsal)")
+    return backend, cpu
+
+
+def bench_ctx():
+    """Where every module and engine of this script lives: the chip,
+    or the host under ``--cpu`` — said, not detected."""
+    import mxnet_tpu as mx
+
+    return mx.cpu() if "--cpu" in sys.argv else mx.tpu()
 
 
 def _csv_ints(s):
@@ -101,7 +125,7 @@ def build_predictor(model_name, cpu):
         raise SystemExit(f"unknown model {model_name!r} "
                          "(SERVE_MODELS wants resnet50|transformer)")
 
-    ctx = mx.tpu() if not cpu and mx.context.num_devices() else mx.cpu()
+    ctx = bench_ctx()
     mod = mx.mod.Module(sym, context=ctx)
     mod.bind(data_shapes=[("data", (2,) + data_shape)],
              label_shapes=[("softmax_label", (2,) + label_shape)],
@@ -228,8 +252,7 @@ def build_lm_params(cfg):
         cfg["vocab_size"], cfg["max_len"],
         num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
         d_model=cfg["d_model"], block_size=cfg["kv_block"])
-    mod = mx.mod.Module(sym, context=mx.cpu()
-                        if jax.default_backend() == "cpu" else mx.tpu())
+    mod = mx.mod.Module(sym, context=bench_ctx())
     T = cfg["max_len"]
     mod.bind(data_shapes=[("data", (2, T))],
              label_shapes=[("softmax_label", (2, T))],
@@ -389,8 +412,7 @@ def bench_decode_point(eng, mk_request, clients, per_client):
 def main_decode():
     import mxnet_tpu as mx
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_decode_config(cpu)
     clients_sweep = _csv_ints(os.environ.get(
         "DECODE_CLIENTS", "1,4,8" if cpu else "1,8,32,64"))
@@ -424,7 +446,7 @@ def main_decode():
 
     max_streams = max(clients_sweep)
     eng = mx.DecodeEngine(
-        params, vocab_size=cfg["vocab_size"],
+        params, ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
         num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
         d_model=cfg["d_model"], max_len=cfg["max_len"],
         kv_block=cfg["kv_block"], max_streams=max_streams,
@@ -575,8 +597,7 @@ def main_decode_lora():
     import mxnet_tpu as mx
     from mxnet_tpu.adapters import AdapterPool, TenantQuota
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_decode_config(cpu)
     adapters_sweep = _csv_ints(os.environ.get("LORA_ADAPTERS", "1,4,8"))
     clients = int(os.environ.get("LORA_CLIENTS", "4" if cpu else "16"))
@@ -594,7 +615,8 @@ def main_decode_lora():
     t0 = time.perf_counter()
     params = build_lm_params(cfg)
     log(f"model built in {time.perf_counter() - t0:.1f}s")
-    kw = dict(vocab_size=cfg["vocab_size"], num_layers=cfg["num_layers"],
+    kw = dict(ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
+              num_layers=cfg["num_layers"],
               num_heads=cfg["num_heads"], d_model=cfg["d_model"],
               max_len=cfg["max_len"], kv_block=cfg["kv_block"],
               max_streams=clients, temperature=0.0, prewarm=True)
@@ -744,8 +766,7 @@ def main_decode_shared():
     import mxnet_tpu as mx
     from mxnet_tpu.kv_cache import blocks_for_tokens
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_decode_config(cpu)
     kvb = cfg["kv_block"]
     clients = int(os.environ.get("DECODE_CLIENTS",
@@ -808,7 +829,7 @@ def main_decode_shared():
 
     def run(prefix_on):
         eng = mx.DecodeEngine(
-            params, vocab_size=cfg["vocab_size"],
+            params, ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
             num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
             d_model=cfg["d_model"], max_len=cfg["max_len"],
             kv_block=kvb, max_streams=clients,
@@ -929,8 +950,7 @@ def train_copy_lm(cfg, epochs, seqs=1024, batch=16, lr=2e-3):
         V, T, num_layers=cfg["num_layers"],
         num_heads=cfg["num_heads"], d_model=cfg["d_model"],
         block_size=cfg["kv_block"])
-    mod = mx.mod.Module(sym, context=mx.cpu()
-                        if jax.default_backend() == "cpu" else mx.tpu())
+    mod = mx.mod.Module(sym, context=bench_ctx())
     mod.fit(it, num_epoch=epochs, optimizer="adam",
             optimizer_params={"learning_rate": lr},
             initializer=mx.initializer.Xavier(factor_type="in",
@@ -943,8 +963,7 @@ def train_copy_lm(cfg, epochs, seqs=1024, batch=16, lr=2e-3):
 def main_decode_spec():
     import mxnet_tpu as mx
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_decode_config(cpu)
     clients = int(os.environ.get("DECODE_CLIENTS", "4" if cpu else "16"))
     per_client = int(os.environ.get("DECODE_REQUESTS",
@@ -997,7 +1016,7 @@ def main_decode_spec():
 
     def run(k):
         eng = mx.DecodeEngine(
-            params, vocab_size=cfg["vocab_size"],
+            params, ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
             num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
             d_model=cfg["d_model"], max_len=cfg["max_len"],
             kv_block=cfg["kv_block"], max_streams=clients,
@@ -1076,8 +1095,7 @@ def main_decode_spec():
 def main_decode_mixed():
     import mxnet_tpu as mx
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_decode_config(cpu)
     chat_clients = int(os.environ.get("DECODE_CLIENTS",
                                       "4" if cpu else "16"))
@@ -1102,7 +1120,7 @@ def main_decode_mixed():
 
     def run(chunk_tokens):
         eng = mx.DecodeEngine(
-            params, vocab_size=cfg["vocab_size"],
+            params, ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
             num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
             d_model=cfg["d_model"], max_len=cfg["max_len"],
             kv_block=cfg["kv_block"], max_streams=chat_clients + 1,
@@ -1202,8 +1220,7 @@ def main_decode_tp():
     tp = int(sys.argv[sys.argv.index("--tp") + 1])
     pp = int(sys.argv[sys.argv.index("--pp") + 1]) \
         if "--pp" in sys.argv else 1
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     cfg = build_tp_config(cpu)
     clients = int(os.environ.get("TP_CLIENTS", "4"))
     per_client = int(os.environ.get("TP_REQUESTS", "3" if cpu else "8"))
@@ -1241,7 +1258,7 @@ def main_decode_tp():
 
     def run(tp_, pp_):
         eng = mx.DecodeEngine(
-            params, vocab_size=cfg["vocab_size"],
+            params, ctx=bench_ctx(), vocab_size=cfg["vocab_size"],
             num_layers=cfg["num_layers"], num_heads=cfg["num_heads"],
             d_model=cfg["d_model"], max_len=cfg["max_len"],
             kv_block=cfg["kv_block"], max_streams=max_streams,
@@ -1294,8 +1311,7 @@ def main_decode_tp():
 def main():
     import mxnet_tpu as mx
 
-    backend = jax.default_backend()
-    cpu = backend == "cpu"
+    backend, cpu = run_mode()
     models_arg = os.environ.get("SERVE_MODELS", "resnet50,transformer")
     clients_sweep = _csv_ints(os.environ.get(
         "SERVE_CLIENTS", "1,4,8,16" if cpu else "1,2,4,8,16,32,64"))

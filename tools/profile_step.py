@@ -26,8 +26,9 @@ sys.path.insert(0, _REPO)
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mxnet_tpu.config import place_compile_cache
+
+place_compile_cache()
 
 import numpy as np
 
@@ -40,7 +41,7 @@ def build_module(batch, precision="bf16"):
     sym = models.resnet(num_classes=1000, num_layers=50,
                         image_shape=(3, 224, 224),
                         stem=os.environ.get("BENCH_STEM", "s2d"))
-    ctx = mx.tpu() if mx.context.num_devices() else mx.cpu()
+    ctx = mx.tpu()  # no chip: an error, never CPU numbers
     data_dtype = jnp.bfloat16 if precision == "bf16" else np.float32
     rng = np.random.RandomState(0)
     X = mx.nd.array(rng.rand(batch, 3, 224, 224).astype(np.float32)

@@ -15,8 +15,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mxnet_tpu.config import place_compile_cache
+
+place_compile_cache()
 
 import numpy as np
 
@@ -32,7 +33,7 @@ def build(layers=12, d_model=768, heads=12, T=1024, batch=8, vocab=32768,
                                 num_layers=layers, num_heads=heads,
                                 d_model=d_model, dtype="bfloat16",
                                 head=head)
-    ctx = mx.tpu() if mx.context.num_devices() else mx.cpu()
+    ctx = mx.tpu()  # no chip: an error, never CPU numbers
     mod = mx.mod.Module(sym, context=ctx)
     mod.bind(data_shapes=[mx.io.DataDesc("data", (batch, T))],
              label_shapes=[mx.io.DataDesc("softmax_label", (batch, T))],

@@ -23,8 +23,9 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", os.path.join(_REPO, ".jax_cache"))
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+from mxnet_tpu.config import place_compile_cache
+
+place_compile_cache()
 
 import numpy as np
 

@@ -196,3 +196,27 @@ def dominant_module_ms(trace_dir):
         return None, 0
     _, (tot, cnt) = max(mods.items(), key=lambda kv: kv[1][0])
     return tot / max(cnt, 1), cnt
+
+
+def traced_module_ms(run, prefix="trace_"):
+    """Run ``run()`` (which must block until its device work is done)
+    under ``jax.profiler.trace`` in a throw-away directory and return
+    the dominant XLA executable's on-device ms per execution.  A trace
+    that cannot be taken or read fails the run — a null under the name
+    of a device metric would read as measured."""
+    import shutil
+    import tempfile
+
+    import jax
+
+    tdir = tempfile.mkdtemp(prefix=prefix)
+    try:
+        with jax.profiler.trace(tdir):
+            run()
+        ms, _ = dominant_module_ms(tdir)
+    finally:
+        shutil.rmtree(tdir, ignore_errors=True)
+    if ms is None:
+        raise SystemExit("no XLA module on a device plane of the profiler "
+                         "trace — device time cannot be reported")
+    return ms
