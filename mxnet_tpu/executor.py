@@ -26,7 +26,6 @@ OpReqType (include/mxnet/op_attr_types.h).
 from __future__ import annotations
 
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -69,12 +68,16 @@ def build_graph_fn(symbol):
             if op.needs_rng:
                 key = jax.random.fold_in(rng, node_index[id(n)])
             op_ctx = OpContext(is_train=is_train, rng=key)
-            if aux_names:
-                outs, aux_out = op.compute(op_ctx, n.attrs, inputs, aux_in)
-                for a, v in zip(aux_names, aux_out):
-                    new_aux[a] = v
-            else:
-                outs = op.compute(op_ctx, n.attrs, inputs, [])
+            # every HLO op carries the symbol's own node name (a
+            # name-stack push while tracing; nothing at run time)
+            with jax.named_scope(n.name):
+                if aux_names:
+                    outs, aux_out = op.compute(op_ctx, n.attrs, inputs,
+                                               aux_in)
+                    for a, v in zip(aux_names, aux_out):
+                        new_aux[a] = v
+                else:
+                    outs = op.compute(op_ctx, n.attrs, inputs, [])
             if not isinstance(outs, (list, tuple)):
                 outs = [outs]
             for i, o in enumerate(outs):
@@ -174,8 +177,8 @@ class Executor:
                          "executor.live_buffer_bytes", -self._buffer_bytes,
                          gen=gen)
 
-    def _record_program(self, tag, start_s, dur_s, args=None):
-        """Telemeter one program dispatch: first run per tag counts as
+    def _record_program(self, tag, args=None):
+        """Span round one program dispatch: first run per tag counts as
         the compile (trace+compile+run — XLA caches afterwards)."""
         compiled = tag not in self._warm_programs
         if compiled:
@@ -183,9 +186,9 @@ class Executor:
         ev_args = {"program": tag}
         if args:
             ev_args.update(args)
-        _prof.record_program(
+        return _prof.record_program(
             f"Executor.compile+{tag}" if compiled else f"Executor.{tag}",
-            start_s, dur_s, compiled, args=ev_args)
+            compiled, args=ev_args)
 
     # ------------------------------------------------------------------
     def _to_dict(self, values, names, what, allow_missing=False) -> Dict[str, NDArray]:
@@ -282,27 +285,30 @@ class Executor:
         if self._monitor_callback is not None:
             self._run_monitor(arg_vals, aux_vals, rng, is_train)
 
-        t_start = time.perf_counter()
-        if is_train and self._grad_names and self._outputs_all_loss_heads():
-            # training step on a loss-head graph: run the single fused
-            # fwd+bwd program now and cache the grads — backward() then
-            # just writes them out, so fwd+bwd costs ONE program run
-            tag = "fused_fwd_bwd"
-            outs, new_aux, grads = self._jit_fused_ones(arg_vals, aux_vals, rng)
-            self._cached_grads = grads
-            self._train_snapshot = (arg_vals, aux_vals, rng)
-        else:
-            tag = "forward/train" if is_train else "forward"
-            fn = self._jit_fwd_train if is_train else self._jit_fwd
-            outs, new_aux = fn(arg_vals, aux_vals, rng)
-            if is_train and self._grad_names:
-                # stash the *pristine* inputs + rng so a later
-                # backward(out_grads) reproduces this forward exactly
-                # (same dropout masks, same pre-update aux)
+        fused = bool(is_train and self._grad_names
+                     and self._outputs_all_loss_heads())
+        tag = "fused_fwd_bwd" if fused else \
+            "forward/train" if is_train else "forward"
+        with self._record_program(tag):
+            if fused:
+                # training step on a loss-head graph: run the single
+                # fused fwd+bwd program now and cache the grads —
+                # backward() then just writes them out, so fwd+bwd
+                # costs ONE program run
+                outs, new_aux, grads = self._jit_fused_ones(
+                    arg_vals, aux_vals, rng)
+                self._cached_grads = grads
                 self._train_snapshot = (arg_vals, aux_vals, rng)
-        if _prof._profiler.running:
-            jax.block_until_ready(outs)  # real span, not dispatch time
-        self._record_program(tag, t_start, time.perf_counter() - t_start)
+            else:
+                fn = self._jit_fwd_train if is_train else self._jit_fwd
+                outs, new_aux = fn(arg_vals, aux_vals, rng)
+                if is_train and self._grad_names:
+                    # stash the *pristine* inputs + rng so a later
+                    # backward(out_grads) reproduces this forward
+                    # exactly (same dropout masks, same pre-update aux)
+                    self._train_snapshot = (arg_vals, aux_vals, rng)
+            if _prof._profiler.running:
+                jax.block_until_ready(outs)  # real span, not dispatch time
         for name, val in new_aux.items():
             self.aux_dict[name]._set_data(val)
         self.outputs_cache = [NDArray(o, self._ctx) for o in outs]
@@ -341,12 +347,11 @@ class Executor:
                 raise MXNetError(
                     f"out_grads has {len(heads)} entries for "
                     f"{len(self.output_names)} outputs")
-            t_start = time.perf_counter()
-            _, _, grads = self._jit_fused(arg_vals, aux_vals, rng, heads)
-            if _prof._profiler.running:
-                jax.block_until_ready(grads)
-            self._record_program("backward", t_start,
-                                 time.perf_counter() - t_start)
+            with self._record_program("backward"):
+                _, _, grads = self._jit_fused(arg_vals, aux_vals, rng,
+                                              heads)
+                if _prof._profiler.running:
+                    jax.block_until_ready(grads)
         for name in self._grad_names:
             g = grads[name]
             dst = self.grad_dict[name]

@@ -286,8 +286,8 @@ class BaseModule:
                     # once per BUILT program, attribute the fused
                     # program's OWN collectives to the comm fraction
                     # (in-program reduce-scatter/all-gather otherwise
-                    # books as compute).  Costs one extra cached XLA
-                    # compile per program, so it waits for step 8 —
+                    # books as compute).  Walks the compiled program's
+                    # HLO text once per program, so it waits for step 8 —
                     # short smoke fits never pay — unless the ops
                     # endpoint is live (an operator is watching; pay at
                     # step 1).  Called every step past the threshold:

@@ -16,7 +16,6 @@ from __future__ import annotations
 
 import logging
 import pickle
-import time
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
@@ -950,7 +949,8 @@ class Module(BaseModule):
         update = self._make_param_update()
         prologue = self._input_prologue
 
-        def step(params, fixed, aux, states, inputs, key, lr, t):
+        def step_train(params, fixed, aux, states, inputs, key, lr, t):
+            # (the program's name in a device trace: jit_step_train)
             # per-step PRNG derived on device from the base key + int32
             # step counter — no per-step host→device key transfer
             rng = jax.random.fold_in(key, t)
@@ -987,7 +987,7 @@ class Module(BaseModule):
             new_params, new_states = update(params, grads, states, lr, t_f)
             return list(outs), new_params, new_aux, new_states, t + 1
 
-        return jax.jit(step, donate_argnums=(0, 3, 7))
+        return jax.jit(step_train, donate_argnums=(0, 3, 7))
 
     def _split_pp_graph(self):
         """Validate + split the symbol for the pipeline executor
@@ -1262,7 +1262,8 @@ class Module(BaseModule):
             nonslab = list(self._pp_nonslab_grad_names)
             slab_keys = list(self._pp_slab_keys)
 
-            def step(params, fixed, aux, states, inputs, key, lr, t):
+            def step_train(params, fixed, aux, states, inputs, key, lr,
+                           t):
                 rng = jax.random.fold_in(key, t)
                 if prologue is not None:
                     inputs = prologue(inputs,
@@ -1282,14 +1283,14 @@ class Module(BaseModule):
                 return (list(outs), new_params, dict(aux), new_states,
                         t + 1)
 
-            return jax.jit(step, donate_argnums=(0, 3, 7))
+            return jax.jit(step_train, donate_argnums=(0, 3, 7))
 
         pipe = _pp.build_pipeline_fn(pg, plan, self._grad_param_names,
                                      param_specs, schedule_kind=kind)
         self._pp_schedule = pipe.schedule
         pnames = list(self._grad_param_names)
 
-        def step(params, fixed, aux, states, inputs, key, lr, t):
+        def step_train(params, fixed, aux, states, inputs, key, lr, t):
             rng = jax.random.fold_in(key, t)
             if prologue is not None:
                 inputs = prologue(inputs, jax.random.fold_in(key, -1 - t),
@@ -1306,7 +1307,7 @@ class Module(BaseModule):
                                             t_f)
             return list(outs), new_params, dict(aux), new_states, t + 1
 
-        return jax.jit(step, donate_argnums=(0, 3, 7))
+        return jax.jit(step_train, donate_argnums=(0, 3, 7))
 
     def _make_param_update(self):
         """The optimizer segment of the fused program, shared by
@@ -1360,16 +1361,21 @@ class Module(BaseModule):
         if not self._zero:
             wsc0 = jax.lax.with_sharding_constraint
 
+            # a scope of its own in the device trace, each parameter's
+            # update under its name (trace-time only)
+            @jax.named_scope("optimizer_update")
             def update(params, grads, states, lr, t_f):
                 new_params = {}
                 new_states = {}
                 for n in pnames:
-                    w, s = optimizer.apply(params[n], grads[n], states[n],
-                                           lr * lr_mult[n],
-                                           optimizer.wd * wd_mult[n], t_f)
-                    # the f32 lr scalar must not promote low-precision
-                    # params
-                    w = w.astype(params[n].dtype)
+                    with jax.named_scope(n):
+                        w, s = optimizer.apply(
+                            params[n], grads[n], states[n],
+                            lr * lr_mult[n], optimizer.wd * wd_mult[n],
+                            t_f)
+                        # the f32 lr scalar must not promote
+                        # low-precision params
+                        w = w.astype(params[n].dtype)
                     if n in slab_keys:
                         # elementwise update of a stage-resident slab:
                         # keep it pinned where it lives
@@ -1418,6 +1424,7 @@ class Module(BaseModule):
             wn = jnp.reshape(wn[:, :size], shape).astype(w.dtype)
             return wsc(wn, slab_sh[key]), new_state
 
+        @jax.named_scope("optimizer_update")
         def update(params, grads, states, lr, t_f):
             new_params = {}
             new_states = {}
@@ -1925,23 +1932,20 @@ class Module(BaseModule):
                 self._exec.arg_dict[n]._data = v
         compiled = not self._fused_warm
         self._fused_warm = True
+        step_args = (params, fixed, aux, self._fused_state, inputs,
+                     self._fused_key, lr_dev, self._fused_t)
         if compiled:
             # first run of this build: feed the live-MFU tracker the
-            # program's FLOPs (specs captured BEFORE the call — the
-            # donated buffers are gone after it)
-            self._account_step_flops(
-                (params, fixed, aux, self._fused_state, inputs,
-                 self._fused_key, lr_dev, self._fused_t))
-        t_start = time.perf_counter()
-        outs, new_params, new_aux, new_states, self._fused_t = \
-            self._fused_step(params, fixed, aux, self._fused_state,
-                             inputs, self._fused_key, lr_dev,
-                             self._fused_t)
-        if _prof._profiler.running:
-            jax.block_until_ready(outs)
-        _prof.record_program("Module.fused_step", t_start,
-                             time.perf_counter() - t_start, compiled,
-                             args={"step": self._step_count})
+            # program's FLOPs from the ONE lowering the call below
+            # reuses (before the call — the donated buffers are gone
+            # after it)
+            self._account_step_flops(step_args)
+        with _prof.record_program("Module.fused_step", compiled,
+                                  args={"step": self._step_count}):
+            outs, new_params, new_aux, new_states, self._fused_t = \
+                self._fused_step(*step_args)
+            if _prof._profiler.running:
+                jax.block_until_ready(outs)
         self._store_fused_params(new_params)
         for n, v in new_aux.items():
             self._exec.aux_dict[n]._set_data(v)
@@ -1956,13 +1960,18 @@ class Module(BaseModule):
 
     def _account_step_flops(self, step_args):
         """Promote the offline bench's FLOPs/MFU math into the live
-        fit path: XLA's own HLO cost analysis of the SAME jitted fused
-        step (one extra trace on the first run — never executed)
-        yields the per-step FLOPs, divided across the mesh so
-        ``training.mfu`` is per-chip like the bench's number.  Also
-        declares the pipeline's static bubble fraction.  Best-effort:
-        a toolchain without a cost model simply leaves the mfu gauge
-        unexported (goodput and the decomposition still work)."""
+        fit path: XLA's own HLO cost analysis of the jitted fused
+        step's lowering yields the per-step FLOPs, divided across the
+        mesh so ``training.mfu`` is per-chip like the bench's number.
+        The step is lowered HERE, from the very arrays the first call
+        is about to pass: jax keeps that lowering, so the call traces
+        and lowers nothing again and ``_fused_compiled`` hands out the
+        executable the call compiled (from shape specs instead, the
+        call can lower the whole step a second time: on XLA:CPU it
+        does, on the TPU it did not — PERF.md, PR 25).  Also declares the
+        pipeline's static bubble fraction.  Best-effort: a toolchain
+        without a cost model simply leaves the mfu gauge unexported
+        (goodput and the decomposition still work)."""
         import jax
         import jax.numpy as jnp
 
@@ -1974,16 +1983,16 @@ class Module(BaseModule):
             tracker.set_pp_bubble(
                 (plan.pp - 1) / (plan.microbatches + plan.pp - 1))
         try:
-            # specs carry shardings so the SAME trees can later lower
-            # the SPMD program for fused_hlo_text() — the lowered
-            # (pre-partitioning) cost analysis below is unaffected
-            specs = jax.tree_util.tree_map(
+            # the shapes outlive the donated buffers: a step rebuilt
+            # later (a new input prologue) lowers from them
+            self._fused_arg_specs = jax.tree_util.tree_map(
                 lambda a: jax.ShapeDtypeStruct(
                     jnp.shape(a), jnp.result_type(a),
                     sharding=getattr(a, "sharding", None)),
                 step_args)
-            self._fused_arg_specs = specs
-            cost = self._fused_step.lower(*specs).cost_analysis()
+            lowered = self._fused_step.lower(*step_args)
+            self._fused_lowered = (self._fused_step, lowered)
+            cost = lowered.cost_analysis()  # pre-partitioning: global
             if isinstance(cost, (list, tuple)):
                 cost = cost[0] if cost else None
             flops = float((cost or {}).get("flops", 0.0))
@@ -1994,22 +2003,22 @@ class Module(BaseModule):
             pass  # break the training step
 
     def _fused_compiled(self):
-        """The compiled (SPMD-partitioned) fused step, re-lowered from
-        the arg specs captured at the first run.  Costs ONE extra XLA
-        compile per built step — cached on the module so the HLO text,
-        the memory analysis and the comm-fraction cost read all share
-        it."""
+        """The compiled (SPMD-partitioned) fused step: the executable
+        of the lowering kept at the first run — the one the step itself
+        runs, so the HLO text, the memory analysis and the
+        comm-fraction cost read cost no compile of their own.  Only a
+        step rebuilt since (a new input prologue) is lowered again,
+        from the arg specs captured then."""
         specs = getattr(self, "_fused_arg_specs", None)
         if specs is None or self._fused_step is None:
             raise MXNetError(
                 "needs a built fused step: run one training step "
                 "first (forward_backward + update)")
-        cache = getattr(self, "_fused_hlo_cache", None)
-        if cache is not None and cache[0] is self._fused_step:
-            return cache[1]
-        compiled = self._fused_step.lower(*specs).compile()
-        self._fused_hlo_cache = (self._fused_step, compiled)
-        return compiled
+        kept = getattr(self, "_fused_lowered", None)
+        if kept is None or kept[0] is not self._fused_step:
+            kept = (self._fused_step, self._fused_step.lower(*specs))
+            self._fused_lowered = kept
+        return kept[1].compile()  # cached on the lowering
 
     def fused_hlo_text(self):
         """Compiled (scheduled, SPMD-partitioned) HLO text of the
@@ -2017,9 +2026,8 @@ class Module(BaseModule):
         inspection reads (``mxnet_tpu.hlo.overlap_report``;
         tests/test_overlap.py, tools/bench_pp.py, PERF.md evidence).
 
-        Costs one extra XLA compile of the program the first time
-        (cached per built step afterwards); call after at least one
-        fused step has run."""
+        Reads the executable the step runs (no compile of its own);
+        call after at least one fused step has run."""
         return self._fused_compiled().as_text()
 
     def fused_memory_analysis(self):
@@ -2038,8 +2046,8 @@ class Module(BaseModule):
         counted.  Returns the fraction, or None when it cannot be
         computed (no mesh, program not built, toolchain without a
         cost model).  fit() calls this once per built program (step 8,
-        or step 1 when the ops endpoint is live); the one extra
-        compile it costs is cached by fused_hlo_text."""
+        or step 1 when the ops endpoint is live); it reads the
+        executable the step runs (its HLO text and cost analysis)."""
         plan = self._mesh_plan
         if plan is None or plan.num_devices <= 1 \
                 or self._fused_step is None:
@@ -2053,7 +2061,7 @@ class Module(BaseModule):
         from .. import hlo as _hlo
 
         try:
-            compiled = self._fused_compiled()  # ONE compile, cached
+            compiled = self._fused_compiled()
             cbytes = _hlo.collective_bytes(compiled.as_text())
             cost = compiled.cost_analysis()
             if isinstance(cost, (list, tuple)):

@@ -18,6 +18,17 @@ reference's runtime CUDA compilation (``src/common/mxrtc.cc:13-76``,
 Kernels run natively on TPU; everywhere else they run in interpreter
 mode, which keeps CPU tests meaningful (same kernel code path).
 Opt-out / force: ``MXNET_PALLAS=0|1`` (default: on for TPU backends).
+
+Every ``pallas_call`` carries a ``name=``: one word per kernel family
+plus the variant (``flash_fwd_packed``, ``paged_attention``,
+``lstm_scan``, ``nms``), which is what the TPU compiler names the
+kernel's HLO instruction by and so what a device trace shows.  The two
+backward kernels of flash attention are ``flash_transpose_dq_*`` /
+``flash_transpose_dkv_*`` and not ``flash_bwd_*``: the accepted
+benchmark's ``flash_roofline`` reader tells backward from forward by
+``transpose`` in the instruction's name (which, unnamed, came from
+jax's ``transpose(jvp())`` name stack), and only a ``benchmark`` issue
+may change that reader.
 """
 
 from __future__ import annotations
@@ -124,6 +135,7 @@ def _lstm_pallas_fwd(xw, h0, c0, ut):
         scratch_shapes=[pltpu.VMEM((B, H), jnp.float32),
                         pltpu.VMEM((B, H), jnp.float32)],
         interpret=_interpret(),
+        name="lstm_scan",
     )(xw, h0, c0, ut)
     return y, hT, cT
 
@@ -229,6 +241,7 @@ def nms(rows, nms_threshold, force_suppress):
         out_specs=_vmem_spec((1, 1, Ap), lambda b: (b, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, 1, Ap), rows.dtype),
         interpret=_interpret(),
+        name="nms",
     )(fields)
     return rows.at[:, :, 0].set(cls[:, 0, :A])
 
@@ -375,6 +388,7 @@ def flash_attention_partial(q, k, v, causal, block_size, kv_offset):
         compiler_params=_compiler_params("parallel", "parallel",
                                          "arbitrary"),
         interpret=_interpret(),
+        name="flash_fwd_partial",
     )(koff, qf, kf, vf)
     o = jnp.reshape(o[:, :Tq, :D], (B, H, Tq, D))
     m = jnp.reshape(m[:, :Tq, 0], (B, H, Tq))
@@ -545,6 +559,7 @@ def flash_attention_bwd(q, k, v, m, o_bar, l_bar, causal, block_size,
         out_shape=[_sds((B * H, Tqp, Dp), vma)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dq_partial",
     )(koff, qf, kf, vf, mf, obf, lbf)[0]
 
     dk, dv = pl.pallas_call(
@@ -569,6 +584,7 @@ def flash_attention_bwd(q, k, v, m, o_bar, l_bar, causal, block_size,
                    _sds((B * H, Tkp, Dp), vma)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dkv_partial",
     )(koff, qf, kf, vf, mf, obf, lbf)
 
     def _unflat(x, t):
@@ -845,6 +861,7 @@ def _mha_fwd(q, k, v, causal, block_size):
             "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
+        name="flash_fwd_mha",
     )(qf, kf, vf)
     return o[:, :Tq], lse[:, :Tq]
 
@@ -891,6 +908,7 @@ def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dq_mha",
     )(qf, kf, vf, dof, lsef, deltaf)[0]
 
     dk, dv = pl.pallas_call(
@@ -914,6 +932,7 @@ def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
                         pltpu.VMEM((bk, D), jnp.float32)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dkv_mha",
     )(qf, kf, vf, dof, lsef, deltaf)
 
     return dq[:, :Tq], dk[:, :Tk], dv[:, :Tk]
@@ -1234,6 +1253,7 @@ def _mhap_fwd(qkv, H, D, causal, block_size):
             "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
+        name="flash_fwd_packed",
     )(qkvf, qkvf, qkvf)
     return o[:, :T], lse[:, :T]
 
@@ -1271,6 +1291,7 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
                         pltpu.VMEM((bq, HD), jnp.float32)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dq_packed",
     )(qkvf, qkvf, qkvf, dof, lsef, of)[0]
 
     dk, dv = pl.pallas_call(
@@ -1294,6 +1315,7 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
                         pltpu.VMEM((bk, HD), jnp.float32)],
         compiler_params=cparams,
         interpret=_interpret(),
+        name="flash_transpose_dkv_packed",
     )(qkvf, qkvf, qkvf, dof, lsef, of)
 
     return jnp.concatenate([dq[:, :T], dk[:, :T], dv[:, :T]], axis=-1)
@@ -1417,6 +1439,7 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start):
         out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret(),
+        name="paged_attention_q" if scales else "paged_attention",
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       q, k_pool, v_pool, *scales)
 

@@ -13,8 +13,11 @@ this module's own events time the *host-visible program units* the
 framework actually dispatches (forward / backward / fused step /
 update / io / push / pull), which is the granularity a single-XLA-
 program design has.  Framework internals mark spans with
-``profiler.scope(name, cat, args=...)`` — a no-op when profiling is
-off; ``args`` (step number, bytes moved, bucket key) render in the
+``profiler.scope(name, cat, args=...)``: timed into the flight
+recorder (and the chrome trace when it runs), and ALSO a
+``jax.profiler.TraceAnnotation``, so any running jax trace shows the
+span on its thread's line beside the device's ``XLA Ops``, on one
+clock; ``args`` (step number, bytes moved, bucket key) render in the
 trace viewer's detail pane.
 
 The observability layer on top (the Dapper-style "where did this STEP
@@ -72,7 +75,6 @@ question):
 from __future__ import annotations
 
 import collections
-import contextlib
 import json
 import os
 import struct
@@ -80,8 +82,14 @@ import threading
 import time
 from contextlib import contextmanager
 
+# host-side only: neither import opens a backend (the package has
+# imported jax before this module either way — context.py does)
+import jax.monitoring as _monitoring
+from jax.profiler import TraceAnnotation as _TraceAnnotation
+
 __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
-           "scope", "add_event", "record_program", "start_xla_trace",
+           "scope", "name_thread", "add_event", "record_program",
+           "compile_events", "COMPILE_EVENT_KINDS", "start_xla_trace",
            "stop_xla_trace", "Profiler", "MetricsRegistry", "inc_counter",
            "observe", "metrics_summary", "reset_metrics", "set_gauge",
            "inc_gauge", "gauge_generation", "process_rank",
@@ -161,19 +169,24 @@ class Profiler:
             rec.record(ev)
 
     def scope(self, name, cat="op", args=None):
-        # shared null context when BOTH the trace profiler and the
-        # flight recorder are off: zero allocation on the hot path.
-        # With the (always-on-by-default) flight recorder enabled the
-        # span is still timed and lands in the bounded ring only.
+        # Every span is ALSO a jax TraceAnnotation: whenever anyone has
+        # a jax trace running (start_xla_trace, a benchmark's
+        # start_trace, a TensorBoard capture) it lands on its thread's
+        # line of /host:CPU in the same .xplane.pb, on the clock of
+        # the device's XLA Ops.  With no trace running the annotation
+        # is a sub-microsecond no-op.  With BOTH the chrome profiler
+        # and the (always-on-by-default) flight recorder off the span
+        # is not timed at all: the annotation alone is returned.
         if not self._running and _flight_if_enabled() is None:
-            return _NULL_CTX
+            return _annotation(name, args)
         return self._span(name, cat, args)
 
     @contextmanager
     def _span(self, name, cat, args=None):
         start = time.perf_counter()
         try:
-            yield
+            with _annotation(name, args):
+                yield
         finally:
             self.add_event(name, start, time.perf_counter() - start, cat,
                            args=args)
@@ -210,7 +223,35 @@ class Profiler:
         return filename
 
 
-_NULL_CTX = contextlib.nullcontext()
+def name_thread(name):
+    """Give the calling thread its OS-level name: what a jax trace
+    titles the thread's line of ``/host:CPU`` with (Python before 3.14
+    names a thread for itself only, so every Python thread's line reads
+    ``python``).  Linux keeps 15 characters.  Best effort: a platform
+    without ``pthread_setname_np`` keeps the old title."""
+    import ctypes
+
+    try:
+        libc = ctypes.CDLL(None)
+        libc.pthread_self.restype = ctypes.c_ulong
+        libc.pthread_self.argtypes = []
+        libc.pthread_setname_np.restype = ctypes.c_int
+        libc.pthread_setname_np.argtypes = [ctypes.c_ulong,
+                                            ctypes.c_char_p]
+        libc.pthread_setname_np(libc.pthread_self(),
+                                name.encode()[:15])
+    except (OSError, AttributeError):
+        pass
+
+
+def _annotation(name, args=None):
+    """The jax-trace form of one span.  ``args`` become the event's
+    stats in the trace viewer; they are read when the span is ENTERED
+    (the flight recorder reads them when it ends)."""
+    if args:
+        return _TraceAnnotation(name, **args)
+    return _TraceAnnotation(name)
+
 
 _profiler = Profiler()
 
@@ -682,20 +723,26 @@ def add_event(name, start_s, dur_s, cat="op", args=None):
     _profiler.add_event(name, start_s, dur_s, cat, args=args)
 
 
-def record_program(name, start_s, dur_s, compiled, cat="exec", args=None):
-    """Telemeter one jitted-program dispatch — the ONE compile-
+@contextmanager
+def record_program(name, compiled, cat="exec", args=None):
+    """Span round one jitted-program dispatch — the ONE compile-
     accounting contract shared by Executor and the Module fused step:
     a first run (``compiled``) bumps the ``executor.compiles`` counter,
     samples ``executor.compile_ms``, and tags the span cat='compile';
     warm runs emit a plain exec span.  Every span carries the
-    ``compile`` flag in its args."""
-    if compiled:
-        inc_counter("executor.compiles")
-        observe("executor.compile_ms", dur_s * 1e3)
+    ``compile`` flag in its args.  Like :func:`scope` the span is a jax
+    ``TraceAnnotation`` too; a dispatch that raises books nothing."""
     ev_args = {"compile": compiled}
     if args:
         ev_args.update(args)
-    _profiler.add_event(name, start_s, dur_s,
+    start = time.perf_counter()
+    with _annotation(name, ev_args):
+        yield
+    dur_s = time.perf_counter() - start
+    if compiled:
+        inc_counter("executor.compiles")
+        observe("executor.compile_ms", dur_s * 1e3)
+    _profiler.add_event(name, start, dur_s,
                         "compile" if compiled else cat, args=ev_args)
 
 
@@ -723,6 +770,10 @@ class MetricsRegistry:
         self._reservoir = reservoir
         self._t_reset = time.monotonic()
         self._gen = 0
+        # counters of ONE hot writer that may not take the lock (the
+        # jax compile listener below): plain dict adds, merged into
+        # summary() under their names
+        self._unlocked = {}
 
     def inc(self, name, value=1.0):
         with self._lock:
@@ -801,6 +852,8 @@ class MetricsRegistry:
 
         with self._lock:
             counters = dict(self._counters)
+            counters.update((k, v) for k, v in self._unlocked.items()
+                            if v)
             gauges = dict(self._gauges)
             hists = {k: (_np.asarray(h[0], dtype=_np.float64), h[1],
                          h[2], list(h[3]))
@@ -831,6 +884,8 @@ class MetricsRegistry:
     def reset(self):
         with self._lock:
             self._counters.clear()
+            for k in self._unlocked:
+                self._unlocked[k] = 0.0
             self._gauges.clear()
             self._hists.clear()
             self._t_reset = time.monotonic()
@@ -892,6 +947,83 @@ def metrics_summary():
 
 def reset_metrics():
     _metrics.reset()
+
+
+# -- set-up by phase: what jax spent building programs -------------------
+#: jax.monitoring duration events -> the counter each feeds
+COMPILE_EVENT_KINDS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "backend",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "cache_fetch",
+}
+_COMPILE_COUNTER = {k: f"compile.{k}_s"
+                    for k in COMPILE_EVENT_KINDS.values()}
+_COMPILE_LOG_MIN_S = 1e-3
+_compile_counters = _metrics._unlocked
+_compile_counters.update({c: 0.0 for c in _COMPILE_COUNTER.values()},
+                         **{"compile.programs": 0.0})
+_compile_log = collections.deque(maxlen=8192)
+# (start, seconds) of the trace events no later one has contained yet
+_trace_open: list = []
+
+
+def _on_compile_duration(event, duration, **_kw):
+    """The ONE ``jax.monitoring`` duration listener of the package:
+    why a process took two minutes to come up, as counters on
+    ``/metrics``.  ``compile.trace_s`` — Python tracing to jaxprs;
+    ``compile.lower_s`` — jaxpr to MLIR; ``compile.backend_s`` — XLA
+    compiling a program OR fetching it from the persistent cache;
+    ``compile.cache_fetch_s`` — the part of backend_s that was such a
+    fetch; ``compile.programs`` — programs built either way.
+
+    jax fires the trace event for every ``jnp`` function called inside
+    a trace (thousands per program), so this stays a few dict and list
+    operations: no lock (two threads building programs at the same
+    instant can lose one add), no logging.  NESTED trace events do NOT
+    double count: an event arrives when its trace ENDS, after the
+    traces it contains, so it is booked with what those have not
+    booked already (``_trace_open`` holds the intervals no later event
+    has contained yet) and ``compile.trace_s`` is the wall time in
+    which something was being traced.  Booked whole, the events of the
+    first fused step of gpt2-medium summed to 198.6 s inside a set-up
+    of 136.6 s (my chip run, PR 25); of a 4-layer LM on XLA:CPU to
+    0.708 s over a union of 0.657 s.  Lower and backend events do not
+    nest.  Checked against the benchmark's ``mark`` lines on the chip:
+    per phase, trace + lower + backend stay below the phase's wall
+    seconds (PERF.md section 5)."""
+    kind = COMPILE_EVENT_KINDS.get(event)
+    if kind is None:
+        return
+    now = time.perf_counter()
+    if kind == "trace":
+        start, whole = now - duration, duration
+        while _trace_open and _trace_open[-1][0] >= start:
+            duration -= _trace_open.pop()[1]
+        _trace_open.append((start, whole))
+        if len(_trace_open) > 65536:  # finished programs' intervals
+            del _trace_open[:32768]
+        duration = max(duration, 0.0)
+    elif kind == "backend":
+        _compile_counters["compile.programs"] += 1.0
+    _compile_counters[_COMPILE_COUNTER[kind]] += duration
+    if duration >= _COMPILE_LOG_MIN_S:
+        _compile_log.append((now, kind, duration))
+
+
+def compile_events():
+    """The compile events of a millisecond or more, oldest first:
+    ``(time.perf_counter() when it ended, kind, seconds)`` with kind
+    one of ``trace``/``lower``/``backend``/``cache_fetch`` (a trace
+    event's seconds are its own: what the traces inside it have not
+    booked) — bounded (the newest 8192), never reset: a reader sums what fell before or
+    after an instant of its own (the benchmark: before its window)."""
+    return list(_compile_log)
+
+
+# registered once, with the package's import: it sees set-up from the
+# start.  (jax.monitoring keeps listeners for the life of the process.)
+_monitoring.register_event_duration_secs_listener(_on_compile_duration)
 
 
 # -- exporters -----------------------------------------------------------
@@ -1386,8 +1518,8 @@ def start_metrics_server(port: int | None = None,
 def metrics_server_running() -> bool:
     """True when THE process metrics server is up (an operator is
     watching /statusz — the fit loop uses this to decide whether the
-    in-program comm attribution is worth its one extra compile at
-    step 1 instead of step 8)."""
+    in-program comm attribution is worth walking the program's HLO
+    at step 1 instead of step 8)."""
     return _metrics_server is not None
 
 

@@ -1796,7 +1796,7 @@ class DecodeEngine:
                 "preempted", "prefills", "steps", "stream_steps",
                 "prefill_chunks", "spec_steps", "spec_proposed",
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
-                "d2h_syncs_saved")}
+                "d2h_syncs_saved", "context_tokens")}
         # speculative-decoding headline ratios: how much of what the
         # proposer offered the target model verified, and how many
         # tokens ONE target-model evaluation of one stream commits
@@ -2007,6 +2007,13 @@ class DecodeEngine:
                                         sharding=self._device)
         return jax.ShapeDtypeStruct(shape, dtype)
 
+    @staticmethod
+    def _program(fn, name):
+        """``fn`` under the name its program carries in a device trace
+        (``jit_<name>``: ``jit_step_decode_b48x64``, ``jit_prefill_t1024``)."""
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+
     def _decode_exe(self, bb: int, mb: int):
         key = ("decode", bb, mb)
         exe = self._exe_cache.get(key)
@@ -2051,7 +2058,7 @@ class DecodeEngine:
                                 "serving", args={"batch": bb,
                                                  "blocks": mb}):
                 jitted = jax.jit(
-                    step,
+                    self._program(step, f"step_decode_b{bb}x{mb}"),
                     donate_argnums=(8,) if self._donate else ())
                 exe = jitted.lower(*specs).compile()
             self._exe_cache[key] = exe
@@ -2116,7 +2123,8 @@ class DecodeEngine:
                     "serving", args={"batch": bb, "blocks": mb,
                                      "window": W}):
                 jitted = jax.jit(
-                    step,
+                    self._program(step,
+                                  f"step_verify_b{bb}x{mb}w{W}"),
                     donate_argnums=(9,) if self._donate else ())
                 exe = jitted.lower(*specs).compile()
             self._exe_cache[key] = exe
@@ -2171,7 +2179,7 @@ class DecodeEngine:
             with profiler.scope(f"serving.compile.prefill.t{tp}",
                                 "serving", args={"tokens": tp}):
                 jitted = jax.jit(
-                    prefill,
+                    self._program(prefill, f"prefill_t{tp}"),
                     donate_argnums=(8,) if self._donate else ())
                 exe = jitted.lower(*specs).compile()
             self._exe_cache[key] = exe
@@ -2292,7 +2300,8 @@ class DecodeEngine:
                     f"serving.compile.prefix_prefill.t{tp}x{mb}",
                     "serving", args={"tokens": tp, "blocks": mb}):
                 jitted = jax.jit(
-                    prefill,
+                    self._program(prefill,
+                                  f"prefill_suffix_t{tp}x{mb}"),
                     donate_argnums=(9,) if self._donate else ())
                 exe = jitted.lower(*specs).compile()
             self._exe_cache[key] = exe
@@ -2344,6 +2353,7 @@ class DecodeEngine:
     # scheduler
     # ------------------------------------------------------------------
     def _loop(self):
+        profiler.name_thread("mx-decode-loop")  # its line in a trace
         try:
             while True:
                 with self._cond:
@@ -2351,7 +2361,8 @@ class DecodeEngine:
                             and not self._active \
                             and not self._imports \
                             and self._prefilling is None:
-                        self._cond.wait(timeout=0.5)
+                        with profiler.scope("serving.idle", "serving"):
+                            self._cond.wait(timeout=0.5)
                     if not self._alive:
                         return
                 if self._imports:
@@ -2359,7 +2370,14 @@ class DecodeEngine:
                     # stream is past its prefill, so it joins the very
                     # next decode batch (migration adds no queue wait)
                     self._absorb_imports()
-                self._admit()
+                if self._pending:
+                    with profiler.scope(
+                            "serving.admit", "serving",
+                            args={"pending": len(self._pending),
+                                  "active": len(self._active)}):
+                        self._admit()
+                else:
+                    self._admit()  # (queued prefix invalidations)
                 if self._prefilling is not None:
                     # ONE chunk per iteration: the decode step below
                     # runs between chunks, so a long admission can no
@@ -2371,7 +2389,8 @@ class DecodeEngine:
                     # head-of-line request can't be admitted and no
                     # stream is decoding (transient: submit racing the
                     # loop) — don't busy-spin on the allocator
-                    with self._cond:
+                    with self._cond, \
+                            profiler.scope("serving.idle", "serving"):
                         self._cond.wait(timeout=0.05)
                 profiler.set_gauge("serving.active_streams",
                                    len(self._active))
@@ -2603,7 +2622,9 @@ class DecodeEngine:
             toks, tp = self._suffix_prefill_call(
                 s, seq, c, n, "suffix length", "suffix",
                 {"cached": c, "resume": s.resume})
-            first = int(np.asarray(toks)[0])
+            with profiler.scope("serving.d2h_sync", "serving",
+                                args={"sids": s.sid}):
+                first = int(np.asarray(toks)[0])
         else:
             ns = n
             tp = self._bucket(self._prefill_buckets, n, "prompt length")
@@ -2616,7 +2637,8 @@ class DecodeEngine:
             table = np.zeros((1, mb), np.int32)
             table[0, :len(pages)] = pages
             with profiler.scope(f"serving.prefill.t{tp}", "serving",
-                                args={"tokens": n, "bucket": tp,
+                                args={"sids": s.sid, "tokens": n,
+                                      "bucket": tp,
                                       "resume": s.resume}):
                 toks, self._pools = exe(
                     self._params, stage_array(tokens, dev),
@@ -2625,7 +2647,9 @@ class DecodeEngine:
                     stage_array(table, dev), stage_array(temps, dev),
                     stage_array(seeds, dev), stage_array(steps, dev),
                     self._pools, *self._adapter_args([s], 1))
-                first = int(np.asarray(toks)[0])
+                with profiler.scope("serving.d2h_sync", "serving",
+                                    args={"sids": s.sid}):
+                    first = int(np.asarray(toks)[0])
             s.cost.flops_est += self._exe_flops.get(("prefill", tp),
                                                     0.0)
         # both branches just fetched the sampled first token
@@ -2734,7 +2758,9 @@ class DecodeEngine:
         # interleaved decode step queues behind them on the device (so
         # chunk_ms here times the launch, not the compute, for those).
         if end >= n:
-            first = int(np.asarray(toks)[0])
+            with profiler.scope("serving.d2h_sync", "serving",
+                                args={"sids": s.sid}):
+                first = int(np.asarray(toks)[0])
             self._count("d2h_syncs")
             s.cost.d2h_syncs += 1  # the final chunk's token fetch
         t_done = time.perf_counter()
@@ -3196,11 +3222,24 @@ class DecodeEngine:
                 streams = list(self._active)
             drafts = {s.sid: self._propose(s) for s in streams}
             if any(d.size for d in drafts.values()):
-                return self._verify_step(drafts)
+                with profiler.scope("serving.step", "serving",
+                                    args={"active": len(streams),
+                                          "verify": True}):
+                    return self._verify_step(drafts)
             # nothing proposed anywhere: the plain one-token step IS
             # the zero-draft verify step (bit-identically, greedy and
             # temperature alike) at a fraction of the compute
-        self._plain_step()
+        with profiler.scope("serving.step", "serving",
+                            args={"active": len(self._active)}):
+            self._plain_step()
+
+    @staticmethod
+    def _sids(streams):
+        """Span arg: the batch's stream ids, or their count where the
+        batch is wide."""
+        if len(streams) > 8:
+            return len(streams)
+        return " ".join(str(s.sid) for s in streams)
 
     def _verify_step(self, drafts: Dict[int, np.ndarray]):
         """One speculative scheduling step: feed every active stream
@@ -3231,122 +3270,138 @@ class DecodeEngine:
                           max(len(s.blocks) for s in streams),
                           "cache blocks")
         exe = self._verify_exe(bb, mb)
-        tokens = np.zeros((bb, W), np.int32)
-        positions = np.zeros((bb, W), np.int32)
-        start = np.zeros((bb,), np.int32)
-        lengths = np.zeros((bb,), np.int32)
-        table = np.zeros((bb, mb), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        seeds = np.zeros((bb,), np.int32)
-        steps0 = np.zeros((bb,), np.int32)
-        fed: List[np.ndarray] = []
-        proposed = 0
-        for i, s in enumerate(streams):
-            d = drafts.get(s.sid)
-            if d is None:  # admitted after the propose pass
-                d = np.empty(0, np.int32)
-            w = 1 + len(d)
-            row = np.concatenate(
-                [np.asarray([s.next_token], np.int32), d])
-            fed.append(row)
-            proposed += len(d)
-            tokens[i, :w] = row
-            # pad rows keep in-range positions (their pos-embed rows
-            # are garbage anyway); their K/V writes route to the
-            # scratch page because lengths[i] stops at the live window
-            positions[i] = np.minimum(s.length + np.arange(W),
-                                      self._max_len - 1)
-            start[i] = s.length
-            lengths[i] = s.length + w
-            table[i, :len(s.blocks)] = s.blocks
-            temps[i] = s.temp
-            seeds[i] = s.seed
-            steps0[i] = s.length  # row j keys position length + j
-        dev = self._device
+        with profiler.scope("serving.stage", "serving",
+                            args={"sids": self._sids(streams),
+                                  "active": n}):
+            tokens = np.zeros((bb, W), np.int32)
+            positions = np.zeros((bb, W), np.int32)
+            start = np.zeros((bb,), np.int32)
+            lengths = np.zeros((bb,), np.int32)
+            table = np.zeros((bb, mb), np.int32)
+            temps = np.zeros((bb,), np.float32)
+            seeds = np.zeros((bb,), np.int32)
+            steps0 = np.zeros((bb,), np.int32)
+            fed: List[np.ndarray] = []
+            proposed = 0
+            for i, s in enumerate(streams):
+                d = drafts.get(s.sid)
+                if d is None:  # admitted after the propose pass
+                    d = np.empty(0, np.int32)
+                w = 1 + len(d)
+                row = np.concatenate(
+                    [np.asarray([s.next_token], np.int32), d])
+                fed.append(row)
+                proposed += len(d)
+                tokens[i, :w] = row
+                # pad rows keep in-range positions (their pos-embed
+                # rows are garbage anyway); their K/V writes route to
+                # the scratch page because lengths[i] stops at the
+                # live window
+                positions[i] = np.minimum(s.length + np.arange(W),
+                                          self._max_len - 1)
+                start[i] = s.length
+                lengths[i] = s.length + w
+                table[i, :len(s.blocks)] = s.blocks
+                temps[i] = s.temp
+                seeds[i] = s.seed
+                steps0[i] = s.length  # row j keys position length + j
+            dev = self._device
+            feeds = (stage_array(tokens, dev),
+                     stage_array(positions, dev),
+                     stage_array(start, dev), stage_array(lengths, dev),
+                     stage_array(table, dev), stage_array(temps, dev),
+                     stage_array(seeds, dev), stage_array(steps0, dev))
+            adapter = self._adapter_args(streams, bb)
+        self._count("context_tokens", int(lengths.sum()))
         with profiler.scope(f"serving.verify_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
                                   "blocks": mb, "window": W}):
-            emit, self._pools = exe(
-                self._params, stage_array(tokens, dev),
-                stage_array(positions, dev), stage_array(start, dev),
-                stage_array(lengths, dev), stage_array(table, dev),
-                stage_array(temps, dev), stage_array(seeds, dev),
-                stage_array(steps0, dev), self._pools,
-                *self._adapter_args(streams, bb))
+            emit, self._pools = exe(self._params, *feeds, self._pools,
+                                    *adapter)
+        # the staged inputs die here, as call temporaries would: freeing
+        # device arrays lets other threads run, and WHERE that happens
+        # decides whether a caller's next request makes the next
+        # admission (kept to the function's end, ttft halved)
+        del feeds, adapter
+        with profiler.scope("serving.d2h_sync", "serving",
+                            args={"active": n}):
             emit = np.asarray(emit)  # ONE (B, W) D2H for k+1 tokens
         self._count("d2h_syncs")
         t_done = time.perf_counter()
-        step_ms = (t_done - t0) * 1e3
-        self._count("steps")
-        self._count("stream_steps", n)
-        self._count("spec_steps")
-        self._count("spec_proposed", proposed)
-        self._metrics.observe("step_ms", step_ms)
-        profiler.observe("serving.decode_step_ms", step_ms)
-        # the batch program's FLOPs, split evenly across the riders
-        fl = self._exe_flops.get(("verify", bb, mb, W), 0.0) / n
-        retired = []
-        for i, s in enumerate(streams):
-            d = fed[i][1:]
-            t = 0
-            for j in range(len(fed[i])):
-                tok = int(emit[i, j])
-                # every emission up to and including the first
-                # mismatch is an exact sample for its own slot
-                s.generated.append(tok)
-                t += 1
-                if len(s.generated) >= s.max_new or \
-                        (s.eos is not None and tok == s.eos):
-                    break
-                if j < len(d) and tok != int(d[j]):
-                    break
-            s.length += t
-            s.next_token = s.generated[-1]
-            self._count("tokens", t)
-            self._count("spec_accepted", t - 1)
-            s.cost.tokens += t  # same sites as the engine counters
-            s.cost.spec_accepted += t - 1
-            s.cost.decode_steps += 1
-            s.cost.d2h_syncs += 1
-            s.cost.flops_est += fl
-            if s.await_first:
-                s.await_first = False
-                ttft = (t_done - s.t_submit) * 1e3
-                self._metrics.observe("ttft_ms", ttft)
-                profiler.observe("serving.ttft_ms", ttft)
-                self._metrics.observe("ttft_hit_ms", ttft)
-                profiler.observe("serving.ttft_hit_ms", ttft)
-                self._slo.observe_ttft(s.slo_class, ttft)
-            per_tok = step_ms / t
-            for _ in range(t):
-                self._metrics.observe("time_per_token_ms", per_tok)
-                profiler.observe("serving.time_per_token_ms", per_tok)
-                self._slo.observe_tpt(s.slo_class, per_tok)
-            # rejected-token rollback: pages past the committed tail
-            # (+ the pending token's slot) held only rejected writes
-            keep, surplus = trim_blocks(s.blocks, s.length + 1,
-                                        self._kv_block)
-            if surplus:
-                s.cost.book_pages(len(s.blocks))
-                s.blocks = keep
-                self._release_pages(surplus)
-                self._count("spec_pages_rolled_back", len(surplus))
-            if s.trace is not None:
-                profiler.add_trace_event(
-                    "serving.verify_step", t0, t_done - t0,
-                    s.trace.child(), cat="serving",
-                    args={"sid": s.sid, "position": s.length,
-                          "batch": bb, "active": n,
-                          "drafts": int(len(d)), "accepted": t - 1})
-            if s.done():
-                retired.append(s)
-        if retired:
-            with self._lock:
+        span_args = {"active": n, "retired": 0}
+        with profiler.scope("serving.absorb", "serving", args=span_args):
+            step_ms = (t_done - t0) * 1e3
+            self._count("steps")
+            self._count("stream_steps", n)
+            self._count("spec_steps")
+            self._count("spec_proposed", proposed)
+            self._metrics.observe("step_ms", step_ms)
+            profiler.observe("serving.decode_step_ms", step_ms)
+            # the batch program's FLOPs, split evenly across the riders
+            fl = self._exe_flops.get(("verify", bb, mb, W), 0.0) / n
+            retired = []
+            for i, s in enumerate(streams):
+                d = fed[i][1:]
+                t = 0
+                for j in range(len(fed[i])):
+                    tok = int(emit[i, j])
+                    # every emission up to and including the first
+                    # mismatch is an exact sample for its own slot
+                    s.generated.append(tok)
+                    t += 1
+                    if len(s.generated) >= s.max_new or \
+                            (s.eos is not None and tok == s.eos):
+                        break
+                    if j < len(d) and tok != int(d[j]):
+                        break
+                s.length += t
+                s.next_token = s.generated[-1]
+                self._count("tokens", t)
+                self._count("spec_accepted", t - 1)
+                s.cost.tokens += t  # same sites as the engine counters
+                s.cost.spec_accepted += t - 1
+                s.cost.decode_steps += 1
+                s.cost.d2h_syncs += 1
+                s.cost.flops_est += fl
+                if s.await_first:
+                    s.await_first = False
+                    ttft = (t_done - s.t_submit) * 1e3
+                    self._metrics.observe("ttft_ms", ttft)
+                    profiler.observe("serving.ttft_ms", ttft)
+                    self._metrics.observe("ttft_hit_ms", ttft)
+                    profiler.observe("serving.ttft_hit_ms", ttft)
+                    self._slo.observe_ttft(s.slo_class, ttft)
+                per_tok = step_ms / t
+                for _ in range(t):
+                    self._metrics.observe("time_per_token_ms", per_tok)
+                    profiler.observe("serving.time_per_token_ms", per_tok)
+                    self._slo.observe_tpt(s.slo_class, per_tok)
+                # rejected-token rollback: pages past the committed tail
+                # (+ the pending token's slot) held only rejected writes
+                keep, surplus = trim_blocks(s.blocks, s.length + 1,
+                                            self._kv_block)
+                if surplus:
+                    s.cost.book_pages(len(s.blocks))
+                    s.blocks = keep
+                    self._release_pages(surplus)
+                    self._count("spec_pages_rolled_back", len(surplus))
+                if s.trace is not None:
+                    profiler.add_trace_event(
+                        "serving.verify_step", t0, t_done - t0,
+                        s.trace.child(), cat="serving",
+                        args={"sid": s.sid, "position": s.length,
+                              "batch": bb, "active": n,
+                              "drafts": int(len(d)), "accepted": t - 1})
+                if s.done():
+                    retired.append(s)
+            if retired:
+                with self._lock:
+                    for s in retired:
+                        self._active.remove(s)
                 for s in retired:
-                    self._active.remove(s)
-            for s in retired:
-                self._retire(s)
+                    self._retire(s)
+            span_args["retired"] = len(retired)
 
     def _plain_step(self):
         from .io import stage_array
@@ -3403,35 +3458,48 @@ class DecodeEngine:
         # (the batch composition is pinned, so the slot vectors are
         # identical; a concurrent publish lands at the next pair)
         adapter = self._adapter_args(streams, bb)
-        tokens = np.zeros((bb, 1), np.int32)
-        positions = np.zeros((bb, 1), np.int32)
-        lengths = np.zeros((bb,), np.int32)
-        table = np.zeros((bb, mb), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        seeds = np.zeros((bb,), np.int32)
-        steps = np.zeros((bb,), np.int32)
-        for i, s in enumerate(streams):
-            tokens[i, 0] = s.next_token
-            positions[i, 0] = s.length
-            lengths[i] = s.length + 1
-            table[i, :len(s.blocks)] = s.blocks
-            temps[i] = s.temp
-            seeds[i] = s.seed
-            steps[i] = s.length  # the position being sampled FROM
+        span_args = {"sids": self._sids(streams), "active": n,
+                     "pipelined": pipeline}
         dev = self._device
+        with profiler.scope("serving.stage", "serving", args=span_args):
+            tokens = np.zeros((bb, 1), np.int32)
+            positions = np.zeros((bb, 1), np.int32)
+            lengths = np.zeros((bb,), np.int32)
+            table = np.zeros((bb, mb), np.int32)
+            temps = np.zeros((bb,), np.float32)
+            seeds = np.zeros((bb,), np.int32)
+            steps = np.zeros((bb,), np.int32)
+            for i, s in enumerate(streams):
+                tokens[i, 0] = s.next_token
+                positions[i, 0] = s.length
+                lengths[i] = s.length + 1
+                table[i, :len(s.blocks)] = s.blocks
+                temps[i] = s.temp
+                seeds[i] = s.seed
+                steps[i] = s.length  # the position being sampled FROM
+            feeds = (stage_array(tokens, dev),
+                     stage_array(positions, dev),
+                     stage_array(lengths, dev), stage_array(table, dev),
+                     stage_array(temps, dev), stage_array(seeds, dev),
+                     stage_array(steps, dev))
+        # the paged kernel's need: the live context this step attends
+        self._count("context_tokens", int(lengths.sum()))
         with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
                                   "blocks": mb,
                                   "pipelined": pipeline}):
-            toks_dev, self._pools = exe(
-                self._params, stage_array(tokens, dev),
-                stage_array(positions, dev), stage_array(lengths, dev),
-                stage_array(table, dev), stage_array(temps, dev),
-                stage_array(seeds, dev), stage_array(steps, dev),
-                self._pools, *adapter)
+            toks_dev, self._pools = exe(self._params, *feeds,
+                                        self._pools, *adapter)
+        # the staged inputs die here, as call temporaries would: freeing
+        # device arrays lets other threads run, and WHERE that happens
+        # decides whether a caller's next request makes the next
+        # admission (kept to the function's end, ttft halved)
+        del feeds
         if not pipeline:
-            toks = np.asarray(toks_dev)
+            with profiler.scope("serving.d2h_sync", "serving",
+                                args=span_args):
+                toks = np.asarray(toks_dev)
             self._count("d2h_syncs")
             t_done = time.perf_counter()
             self._absorb_step(streams, toks, t0, t_done, bb, n, fl)
@@ -3439,37 +3507,54 @@ class DecodeEngine:
         # step t+1, fed from the device: live rows advance one
         # position; pad rows stay dead (lengths 0 keeps their write on
         # the scratch page and their mask empty)
-        live = lengths > 0
-        positions2 = positions + live[:, None].astype(np.int32)
-        lengths2 = np.where(live, lengths + 1, 0).astype(np.int32)
-        steps2 = steps + 1
+        with profiler.scope("serving.stage", "serving", args=span_args):
+            live = lengths > 0
+            positions2 = positions + live[:, None].astype(np.int32)
+            lengths2 = np.where(live, lengths + 1, 0).astype(np.int32)
+            steps2 = steps + 1
+            feeds2 = (toks_dev.reshape(bb, 1),
+                      stage_array(positions2, dev),
+                      stage_array(lengths2, dev),
+                      stage_array(table, dev), stage_array(temps, dev),
+                      stage_array(seeds, dev),
+                      stage_array(steps2, dev))
+        self._count("context_tokens", int(lengths2.sum()))
         with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
                                   "blocks": mb, "pipelined": True}):
-            toks2_dev, self._pools = exe(
-                self._params, toks_dev.reshape(bb, 1),
-                stage_array(positions2, dev),
-                stage_array(lengths2, dev), stage_array(table, dev),
-                stage_array(temps, dev), stage_array(seeds, dev),
-                stage_array(steps2, dev), self._pools, *adapter)
-        toks = np.asarray(toks_dev)  # overlaps step t+1's compute
+            toks2_dev, self._pools = exe(self._params, *feeds2,
+                                         self._pools, *adapter)
+        del feeds2
+        with profiler.scope("serving.d2h_sync", "serving",
+                            args=span_args):
+            toks = np.asarray(toks_dev)  # overlaps step t+1's compute
         self._count("d2h_syncs")
         self._count("d2h_syncs_saved")
         t_mid = time.perf_counter()
         # no retires possible (predicate): t+1's assumed composition
         # held, so its results are the real step t+1
         self._absorb_step(streams, toks, t0, t_mid, bb, n, fl)
-        toks2 = np.asarray(toks2_dev)
+        with profiler.scope("serving.d2h_sync", "serving",
+                            args=span_args):
+            toks2 = np.asarray(toks2_dev)
         self._count("d2h_syncs")
         t_done = time.perf_counter()
         self._absorb_step(streams, toks2, t_mid, t_done, bb, n, fl)
 
     def _absorb_step(self, streams, toks, t0, t_done, bb, n,
                      fl: float = 0.0):
-        """Book one plain decode step's results into the scheduler:
-        counters, per-stream token append, full-hit TTFT, trace spans,
-        retirement."""
+        """Book one plain decode step's results into the scheduler
+        (under the ``serving.absorb`` span: counters, per-stream token
+        append, full-hit TTFT, trace spans, retirement with the
+        futures' callbacks)."""
+        span_args = {"active": n, "retired": 0}
+        with profiler.scope("serving.absorb", "serving", args=span_args):
+            span_args["retired"] = self._book_step(
+                streams, toks, t0, t_done, bb, n, fl)
+
+    def _book_step(self, streams, toks, t0, t_done, bb, n, fl):
+        """:meth:`_absorb_step`'s work; returns how many retired."""
         step_ms = (t_done - t0) * 1e3
         self._count("steps")
         self._count("stream_steps", n)
@@ -3516,6 +3601,7 @@ class DecodeEngine:
                     self._active.remove(s)
             for s in retired:
                 self._retire(s)
+        return len(retired)
 
 
 # ---------------------------------------------------------------------------
