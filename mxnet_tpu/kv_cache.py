@@ -1,10 +1,12 @@
 """Paged KV-cache bookkeeping for autoregressive serving.
 
 The device side of the paged cache is two pool arrays per layer —
-``k_pool``/``v_pool`` of shape ``(num_blocks, block_tokens, H, D)`` —
-updated functionally inside the decode program (``ops/attention.py``
-``QKVPagedAttentionDecode`` / ``PagedCacheWrite``, donated under jit).
-This module is the HOST side: which pages belong to which stream.
+``k_pool``/``v_pool`` of shape ``(num_blocks, block_tokens, H·D)``
+(:func:`value_pool_shape`: lane-dense, a head is a D-lane span of a
+row) — updated functionally inside the decode program
+(``ops/attention.py`` ``QKVPagedAttentionDecode`` /
+``PagedCacheWrite``, donated under jit).  This module is the HOST
+side: which pages belong to which stream.
 
 Design (PagedAttention, Kwon et al. SOSP '23):
 
@@ -50,7 +52,8 @@ from .base import MXNetError
 
 __all__ = ["BlockAllocator", "blocks_for_tokens", "bucket_ladder",
            "trim_blocks", "kv_storage_dtype", "kv_quantized",
-           "pool_device_bytes", "KV_DTYPES", "KV_QMAX"]
+           "pool_device_bytes", "value_pool_shape", "KV_DTYPES",
+           "KV_QMAX"]
 
 SCRATCH_PAGE = 0
 
@@ -92,6 +95,31 @@ def kv_storage_dtype(name: str) -> np.dtype:
         f"wants one of {KV_DTYPES})")
 
 
+def value_pool_shape(pages: int, kv_block: int, num_heads: int,
+                     d_head: int) -> tuple:
+    """THE shape of a K or V value pool: ``(pages, kv_block, H·D)``.
+
+    Lane-dense — a token's row holds its heads side by side, head h on
+    lanes ``[h·D, (h+1)·D)``, exactly as the fused QKV projection the
+    row was cut from.  Not ``(…, H, D)``: D = 64 is half a 128-lane
+    tile, so the TPU backend has no tiled row-major layout for such a
+    pool without padding (20 x 64 pads to 32 x 128, 3.2 x the bytes);
+    it holds it pages-minor instead, and every program that scatters
+    into it or hands it to the paged kernel re-lays-out the WHOLE pool
+    on the way in and again on the way out (4 pool-sized copies a
+    layer a program, PERF.md §6 PR 26).  At H·D a multiple of 128 a
+    page is whole tiles; elsewhere the code is the same and the
+    backend picks the layout.  The shape fits any head count; the
+    paged KERNEL over it does not — its all-heads form holds
+    W·H²·D-sized state in VMEM and refuses, by name, what exceeds it
+    (``ops/pallas_kernels._paged_attention``: every GPT-2 size fits,
+    64 heads x 128 with a 5-row window does not).  Everything that
+    builds, checks or ships a pool takes its shape from here; the
+    quantized engines' float32 scale pools stay
+    ``(pages, kv_block, H)``."""
+    return (int(pages), int(kv_block), int(num_heads) * int(d_head))
+
+
 def pool_device_bytes(cache_blocks: int, kv_block: int,
                       num_layers: int, num_heads: int, d_model: int,
                       kv_dtype: str = "fp32", tp: int = 1,
@@ -103,13 +131,13 @@ def pool_device_bytes(cache_blocks: int, kv_block: int,
     single-device total — capacity planners (and bench_serving's
     --tp sizing) compare the two to prove a model's pool doesn't fit
     one chip."""
-    d_head = int(d_model) // int(num_heads)
-    slots = int(num_layers) * int(cache_blocks) * int(kv_block) \
-        * int(num_heads)
-    total = 2 * slots * d_head * kv_storage_dtype(kv_dtype).itemsize
+    pool = value_pool_shape(cache_blocks, kv_block, num_heads,
+                            int(d_model) // int(num_heads))
+    per_layer = int(np.prod(pool)) * kv_storage_dtype(kv_dtype).itemsize
     if kv_quantized(kv_dtype):
-        total += 2 * slots * 4  # per-slot-per-head float32 scales
-    return total // (int(tp) * int(pp))
+        # per-slot-per-head float32 scales, (pages, kv_block, H)
+        per_layer += int(np.prod(pool[:2])) * int(num_heads) * 4
+    return 2 * int(num_layers) * per_layer // (int(tp) * int(pp))
 
 
 def blocks_for_tokens(tokens: int, block_tokens: int) -> int:

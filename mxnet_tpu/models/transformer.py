@@ -217,11 +217,14 @@ def _decode_block(x, d_model, num_heads, d_ff, name, kv_block, attend,
 
 
 def kv_pool_var(name: str):
-    """A KV value-pool Variable (P, KVB, H, D): the 'heads' dim is the
-    pool's tensor-parallel shard axis (the rules table maps it to
-    'tp', splitting pages head-wise exactly like the attention)."""
-    return sym.Variable(name, attr=logical_axes(None, None, "heads",
-                                                None))
+    """A KV value-pool Variable (P, KVB, H·D) —
+    ``kv_cache.value_pool_shape``: lane-dense, because D = 64 is half
+    a lane tile and a (…, H, D) pool is re-laid-out whole by every
+    program that touches it.  The last dim is 'heads', the pool's
+    tensor-parallel shard axis: heads are contiguous D-lane spans, so
+    the rules table's 'tp' split still hands each device H/tp whole
+    heads, exactly like the attention."""
+    return sym.Variable(name, attr=logical_axes(None, None, "heads"))
 
 
 def kv_scale_var(name: str):

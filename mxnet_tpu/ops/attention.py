@@ -393,6 +393,25 @@ def _unpack_qkv(qkv, H):
     return q, k, v, D
 
 
+def _split_qkv(qkv, H):
+    """The q, k and v lane spans of a fused projection, each
+    (B, S, H·D) — the rows the lane-dense K/V pools hold as they are
+    (``kv_cache.value_pool_shape``), never reshaped to (H, D)."""
+    _check_qkv_packing(qkv.shape[2], H, qkv.shape)
+    return jnp.split(qkv, 3, axis=-1)
+
+
+def _heads(x, H):
+    """(..., H·D) rows -> (..., H, D): for GATHERED rows and queries
+    on the lax fallbacks, never for a pool."""
+    return jnp.reshape(x, x.shape[:-1] + (H, x.shape[-1] // H))
+
+
+def _rows(x):
+    """(..., H, D) -> (..., H·D) rows."""
+    return jnp.reshape(x, x.shape[:-2] + (x.shape[-2] * x.shape[-1],))
+
+
 def quantize_kv(x, qdtype):
     """Quantize K or V state (..., H, D) to ``qdtype`` (int8 or an fp8
     type) with one float32 scale per (..., H) — per token slot, per
@@ -433,12 +452,15 @@ def cache_update(cache_k, cache_v, k_t, v_t, lengths):
 
 
 def paged_cache_update(k_pool, v_pool, k_t, v_t, block_table, lengths):
-    """Scatter the current token's K/V into the paged pools.
+    """Scatter the current token's K/V rows into the paged pools.
 
-    k_pool/v_pool: (P, KVB, H, D); block_table: (B, MB) int32 page ids;
-    lengths: (B,) including the current token.  Page 0 is the reserved
-    scratch page: inactive streams (lengths == 0) land there, so the
-    scatter needs no masking and never corrupts a live page."""
+    k_pool/v_pool: (P, KVB, H·D) — lane-dense, see
+    ``kv_cache.value_pool_shape``; k_t/v_t: (B, 1, H·D), the k and v
+    lane spans of the fused projection; block_table: (B, MB) int32
+    page ids; lengths: (B,) including the current token.  Page 0 is
+    the reserved scratch page: inactive streams (lengths == 0) land
+    there, so the scatter needs no masking and never corrupts a live
+    page."""
     KVB = k_pool.shape[1]
     pos = jnp.maximum(lengths - 1, 0)
     B = block_table.shape[0]
@@ -470,9 +492,9 @@ def _paged_write_coords(block_table, lengths, T, KVB, start=None):
 def paged_prefill_write(k, v, k_pool, v_pool, block_table, lengths,
                         start=None):
     """Scatter a prompt's (or — with ``start`` — a prompt suffix's)
-    K/V (B, T, H, D) into the paged pools.  Positions >= lengths[b]
-    (padding) are routed to the scratch page 0 instead of being masked
-    out of the scatter."""
+    K/V rows (B, T, H·D) into the (P, KVB, H·D) paged pools.
+    Positions >= lengths[b] (padding) are routed to the scratch page 0
+    instead of being masked out of the scatter."""
     KVB = k_pool.shape[1]
     T = k.shape[1]
     page, slot, _ = _paged_write_coords(block_table, lengths, T, KVB,
@@ -481,17 +503,25 @@ def paged_prefill_write(k, v, k_pool, v_pool, block_table, lengths,
             v_pool.at[page, slot].set(v.astype(v_pool.dtype)))
 
 
+def _quantize_rows(x, H, qdtype):
+    """:func:`quantize_kv` of (..., H·D) rows, per head: the quantized
+    rows (..., H·D) and their (..., H) float32 scales."""
+    q, scale = quantize_kv(_heads(x, H), qdtype)
+    return _rows(q), scale
+
+
 def paged_prefill_write_q(k, v, k_pool, v_pool, k_scale, v_scale,
                           block_table, lengths, start=None):
-    """Quantize-on-write prefill scatter: values land in the int8/fp8
-    pools, their per-slot-per-head float32 scales in the
-    (P, KVB, H) scale pools."""
+    """Quantize-on-write prefill scatter: K/V rows (B, T, H·D) land in
+    the int8/fp8 (P, KVB, H·D) pools, their per-slot-per-head float32
+    scales in the (P, KVB, H) scale pools."""
     KVB = k_pool.shape[1]
     T = k.shape[1]
+    H = k_scale.shape[2]
     page, slot, _ = _paged_write_coords(block_table, lengths, T, KVB,
                                         start)
-    kq, ks = quantize_kv(k, k_pool.dtype)
-    vq, vs = quantize_kv(v, v_pool.dtype)
+    kq, ks = _quantize_rows(k, H, k_pool.dtype)
+    vq, vs = _quantize_rows(v, H, v_pool.dtype)
     return (k_pool.at[page, slot].set(kq),
             v_pool.at[page, slot].set(vq),
             k_scale.at[page, slot].set(ks),
@@ -501,30 +531,34 @@ def paged_prefill_write_q(k, v, k_pool, v_pool, k_scale, v_scale,
 def paged_cache_update_q(k_pool, v_pool, k_scale, v_scale, k_t, v_t,
                          block_table, lengths):
     """Quantize-on-write single-token scatter (the decode step): the
-    new token's K/V quantizes against its own per-head scale and lands
-    in the narrow pools; the scales land in the (P, KVB, H) scale
-    pools.  Previously-written slots are untouched — no page-wide
-    re-scaling, so shared full pages keep their bytes."""
+    new token's K/V rows (B, 1, H·D) quantize against their own
+    per-head scales and land in the narrow pools; the scales land in
+    the (P, KVB, H) scale pools.  Previously-written slots are
+    untouched — no page-wide re-scaling, so shared full pages keep
+    their bytes."""
     KVB = k_pool.shape[1]
+    H = k_scale.shape[2]
     pos = jnp.maximum(lengths - 1, 0)
     B = block_table.shape[0]
     rows = jnp.arange(B)
     page = jnp.where(lengths > 0,
                      block_table[rows, pos // KVB], 0)
     slot = jnp.where(lengths > 0, pos % KVB, 0)
-    kq, ks = quantize_kv(k_t[:, 0], k_pool.dtype)   # (B, H, D), (B, H)
-    vq, vs = quantize_kv(v_t[:, 0], v_pool.dtype)
+    kq, ks = _quantize_rows(k_t[:, 0], H, k_pool.dtype)  # (B, H·D), (B, H)
+    vq, vs = _quantize_rows(v_t[:, 0], H, v_pool.dtype)
     return (k_pool.at[page, slot].set(kq),
             v_pool.at[page, slot].set(vq),
             k_scale.at[page, slot].set(ks),
             v_scale.at[page, slot].set(vs))
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_table, lengths):
-    """Gather-by-block-table decode attention (lax fallback).
+def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, H):
+    """Gather-by-block-table decode attention: q (B, 1, H·D) ->
+    (B, 1, H·D).
 
-    Materializes the gathered cache (B, MB*KVB, H, D) and runs the
-    same blockwise body with block == KVB, so the result is
+    The lax fallback materializes the gathered cache and reshapes IT —
+    the gathered rows, never the pool — to (B, MB*KVB, H, D), then
+    runs the same blockwise body with block == KVB, so the result is
     bit-identical to the contiguous-cache decode (pages hold the same
     values; page boundaries ARE block boundaries).  The Pallas kernel
     (pallas_kernels.paged_attention_decode) gathers page-by-page in
@@ -535,13 +569,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths):
     KVB = k_pool.shape[1]
     if pk.enabled():
         out = pk.paged_attention_decode(q[:, 0], k_pool, v_pool,
-                                        block_table, lengths)
+                                        block_table, lengths, H)
         return out[:, None]
     B, MB = block_table.shape
-    H, D = k_pool.shape[2], k_pool.shape[3]
+    D = k_pool.shape[2] // H
     kg = k_pool[block_table].reshape(B, MB * KVB, H, D)
     vg = v_pool[block_table].reshape(B, MB * KVB, H, D)
-    return decode_attention(q, kg, vg, lengths, KVB)
+    return _rows(decode_attention(_heads(q, H), kg, vg, lengths, KVB))
 
 
 def paged_decode_attention_q(q, k_pool, v_pool, k_scale, v_scale,
@@ -553,18 +587,19 @@ def paged_decode_attention_q(q, k_pool, v_pool, k_scale, v_scale,
     from . import pallas_kernels as pk
 
     KVB = k_pool.shape[1]
+    H = k_scale.shape[2]
     if pk.enabled():
         out = pk.paged_attention_decode_quant(
             q[:, 0], k_pool, v_pool, k_scale, v_scale, block_table,
-            lengths)
+            lengths, H)
         return out[:, None]
     B, MB = block_table.shape
-    H, D = k_pool.shape[2], k_pool.shape[3]
+    D = k_pool.shape[2] // H
     kg = dequantize_kv(k_pool[block_table].reshape(B, MB * KVB, H, D),
                        k_scale[block_table].reshape(B, MB * KVB, H))
     vg = dequantize_kv(v_pool[block_table].reshape(B, MB * KVB, H, D),
                        v_scale[block_table].reshape(B, MB * KVB, H))
-    return decode_attention(q, kg, vg, lengths, KVB)
+    return _rows(decode_attention(_heads(q, H), kg, vg, lengths, KVB))
 
 
 def prefix_suffix_attention(q, k_suf, v_suf, kg, vg, start, block):
@@ -674,24 +709,64 @@ def _check_decode_step_shape(op_name, qkv_shape):
             f"the first would be silently dropped, not attended")
 
 
-def _qkv_paged_infer(attrs, in_shapes):
-    qkv, kp, vp, bt, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    _check_decode_step_shape("QKVPagedAttentionDecode", qkv)
-    return in_shapes, [(qkv[0], 1, qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp)], []
+def _pools_out(op_name, pools, H, D):
+    """Output shapes of the pools a paged op hands back — its inputs'
+    (a missing v pool/scale takes its k twin's) — with every value pool
+    held to ``kv_cache.value_pool_shape``: a (P, KVB, H, D) pool from
+    before the pools went lane-dense is refused here, by name."""
+    from ..kv_cache import value_pool_shape
+
+    out = []
+    for i, p in enumerate(pools):
+        p = p if p is not None else pools[i - i % 2]
+        if p is not None and i < 2 \
+                and tuple(p) != value_pool_shape(p[0], p[1], H, D):
+            raise MXNetError(
+                f"{op_name}: value pool {tuple(p)} is not (pages, "
+                f"kv_block, num_heads*d_head = {H}*{D}) — "
+                f"kv_cache.value_pool_shape")
+        out.append(tuple(p) if p is not None else None)
+    return out
+
+
+def _paged_qkv_infer(op_name, n_pools, one_position=False):
+    """infer_shape of a paged op fed the fused qkv: inputs (qkv,
+    *pools, ...), outputs (attention, *pools)."""
+    def infer(attrs, in_shapes):
+        qkv, pools = in_shapes[0], in_shapes[1:1 + n_pools]
+        if qkv is None or pools[0] is None:
+            return in_shapes, None, None
+        H = attr_int(attrs.get("num_heads", 1), 1)
+        _check_qkv_packing(qkv[2], H, qkv)
+        if one_position:
+            _check_decode_step_shape(op_name, qkv)
+        return in_shapes, [(qkv[0], qkv[1], qkv[2] // 3)] + _pools_out(
+            op_name, pools, H, qkv[2] // (3 * H)), []
+    return infer
+
+
+def _paged_write_infer(op_name, n_pools):
+    """infer_shape of PagedCacheWrite[Q]: inputs (key, value, *pools,
+    ...) with key/value (B, T, H, D), outputs the pools."""
+    def infer(attrs, in_shapes):
+        k, pools = in_shapes[0], in_shapes[2:2 + n_pools]
+        if k is None or pools[0] is None:
+            return in_shapes, None, None
+        return in_shapes, _pools_out(op_name, pools, k[2], k[3]), []
+    return infer
 
 
 @register("QKVPagedAttentionDecode",
           arg_names=("qkv", "k_pool", "v_pool", "block_table", "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool"),
-          infer_shape=_qkv_paged_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedAttentionDecode", 2, True),
           doc="One incremental-decode step over the PAGED KV cache: "
-              "qkv (B, 1, 3*H*D), k_pool/v_pool (P, KVB, H, D) shared "
-              "page pools, block_table (B, MB) int32 page ids (page 0 "
+              "qkv (B, 1, 3*H*D), k_pool/v_pool (P, KVB, H*D) shared "
+              "page pools — lane-dense, a head is a D-lane span of a "
+              "row as in qkv (D = 64 is half a lane tile: a "
+              "(..., H, D) pool has no unpadded tiled layout and is "
+              "re-laid-out whole by every program that touches it) — "
+              "block_table (B, MB) int32 page ids (page 0 "
               "reserved scratch), lengths (B,) int32 -> output "
               "(B, 1, H*D) + updated pools (donate under jit).  The "
               "page size KVB is the attention block size; memory "
@@ -702,43 +777,38 @@ def _qkv_paged_attention_decode(op_ctx, attrs, inputs, aux):
     qkv, k_pool, v_pool, block_table, lengths = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
     _check_decode_step_shape("QKVPagedAttentionDecode", qkv.shape)
-    q, k_t, v_t, D = _unpack_qkv(qkv, H)
+    q, k_t, v_t = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
     new_kp, new_vp = paged_cache_update(k_pool, v_pool, k_t, v_t,
                                         block_table, lengths)
-    out = paged_decode_attention(q, new_kp, new_vp, block_table, lengths)
-    B = qkv.shape[0]
-    return [jnp.reshape(out, (B, 1, H * D)), new_kp, new_vp]
-
-
-def _paged_write_infer(attrs, in_shapes):
-    k, v, kp, vp, bt, ln = in_shapes
-    if kp is None:
-        return in_shapes, None, None
-    return in_shapes, [tuple(kp), tuple(vp if vp is not None else kp)], []
+    out = paged_decode_attention(q, new_kp, new_vp, block_table, lengths,
+                                 H)
+    return [out, new_kp, new_vp]
 
 
 @register("PagedCacheWrite",
           arg_names=("key", "value", "k_pool", "v_pool", "block_table",
                      "lengths"),
           out_names=("new_k_pool", "new_v_pool"),
-          infer_shape=_paged_write_infer,
+          infer_shape=_paged_write_infer("PagedCacheWrite", 2),
           doc="Scatter a prefilled prompt's (B, T, H, D) key/value "
-              "state into the paged pools through each stream's block "
+              "state, as (B, T, H*D) rows, into the (P, KVB, H*D) "
+              "paged pools through each stream's block "
               "table; positions >= lengths[b] land on the scratch page "
               "0.  The prefill half of paged incremental decode.")
 def _paged_cache_write(op_ctx, attrs, inputs, aux):
     k, v, k_pool, v_pool, block_table, lengths = inputs
     new_kp, new_vp = paged_prefill_write(
-        k, v, k_pool, v_pool, block_table.astype(jnp.int32),
-        lengths.astype(jnp.int32))
+        _rows(k), _rows(v), k_pool, v_pool,
+        block_table.astype(jnp.int32), lengths.astype(jnp.int32))
     return [new_kp, new_vp]
 
 
 # ---------------------------------------------------------------------------
 # Prefix-shared + quantized cache ops.  The *Q variants carry the
-# (P, KVB, H) float32 scale pools alongside the int8/fp8 value pools
+# (P, KVB, H) float32 scale pools alongside the int8/fp8 (P, KVB, H·D)
+# value pools
 # (quantize-on-write, dequantize-on-read, fp32 softmax accumulation);
 # the PrefillAttend pair is the suffix-only prefill of a prefix-cache
 # hit: the uncached suffix's K/V is written at offset ``start`` and
@@ -746,45 +816,22 @@ def _paged_cache_write(op_ctx, attrs, inputs, aux):
 # ---------------------------------------------------------------------------
 
 
-def _paged_write_q_infer(attrs, in_shapes):
-    k, v, kp, vp, ks, vs, bt, ln = in_shapes
-    if kp is None:
-        return in_shapes, None, None
-    return in_shapes, [tuple(kp), tuple(vp if vp is not None else kp),
-                       tuple(ks) if ks is not None else None,
-                       tuple(vs if vs is not None else ks)
-                       if (vs is not None or ks is not None) else None], []
-
-
 @register("PagedCacheWriteQ",
           arg_names=("key", "value", "k_pool", "v_pool", "k_scale",
                      "v_scale", "block_table", "lengths"),
           out_names=("new_k_pool", "new_v_pool", "new_k_scale",
                      "new_v_scale"),
-          infer_shape=_paged_write_q_infer,
+          infer_shape=_paged_write_infer("PagedCacheWriteQ", 4),
           doc="PagedCacheWrite for QUANTIZED pools: the (B, T, H, D) "
-              "key/value state quantizes on write into int8/fp8 pools "
-              "with per-slot-per-head float32 scales in the "
-              "(P, KVB, H) scale pools.  Positions >= lengths[b] land "
-              "on the scratch page 0.")
+              "key/value state quantizes on write into int8/fp8 "
+              "(P, KVB, H*D) pools with per-slot-per-head float32 "
+              "scales in the (P, KVB, H) scale pools.  Positions >= "
+              "lengths[b] land on the scratch page 0.")
 def _paged_cache_write_q(op_ctx, attrs, inputs, aux):
     k, v, k_pool, v_pool, k_scale, v_scale, block_table, lengths = inputs
     return list(paged_prefill_write_q(
-        k, v, k_pool, v_pool, k_scale, v_scale,
+        _rows(k), _rows(v), k_pool, v_pool, k_scale, v_scale,
         block_table.astype(jnp.int32), lengths.astype(jnp.int32)))
-
-
-def _qkv_paged_q_infer(attrs, in_shapes):
-    qkv, kp, vp, ks, vs, bt, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    _check_decode_step_shape("QKVPagedAttentionDecodeQ", qkv)
-    return in_shapes, [(qkv[0], 1, qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp),
-                       tuple(ks) if ks is not None else None,
-                       tuple(vs) if vs is not None else None], []
 
 
 @register("QKVPagedAttentionDecodeQ",
@@ -792,7 +839,7 @@ def _qkv_paged_q_infer(attrs, in_shapes):
                      "block_table", "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool",
                      "new_k_scale", "new_v_scale"),
-          infer_shape=_qkv_paged_q_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedAttentionDecodeQ", 4, True),
           doc="QKVPagedAttentionDecode over QUANTIZED pools: the "
               "current token's K/V quantizes on write (per-slot-per-"
               "head scales); attention dequantizes inside the Pallas "
@@ -803,7 +850,7 @@ def _qkv_paged_attention_decode_q(op_ctx, attrs, inputs, aux):
     qkv, k_pool, v_pool, k_scale, v_scale, block_table, lengths = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
     _check_decode_step_shape("QKVPagedAttentionDecodeQ", qkv.shape)
-    q, k_t, v_t, D = _unpack_qkv(qkv, H)
+    q, k_t, v_t = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
     new_kp, new_vp, new_ks, new_vs = paged_cache_update_q(
@@ -811,26 +858,14 @@ def _qkv_paged_attention_decode_q(op_ctx, attrs, inputs, aux):
         lengths)
     out = paged_decode_attention_q(q, new_kp, new_vp, new_ks, new_vs,
                                    block_table, lengths)
-    B = qkv.shape[0]
-    return [jnp.reshape(out, (B, 1, H * D)), new_kp, new_vp, new_ks,
-            new_vs]
-
-
-def _qkv_prefix_infer(attrs, in_shapes):
-    qkv, kp, vp, bt, st, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    return in_shapes, [(qkv[0], qkv[1], qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp)], []
+    return [out, new_kp, new_vp, new_ks, new_vs]
 
 
 @register("QKVPagedPrefillAttend",
           arg_names=("qkv", "k_pool", "v_pool", "block_table", "start",
                      "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool"),
-          infer_shape=_qkv_prefix_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedPrefillAttend", 2),
           doc="Suffix prefill over a prefix-shared paged cache: qkv "
               "(B, Ts, 3*H*D) holds the UNCACHED suffix (absolute "
               "positions start[b]+i, start block-aligned); its K/V is "
@@ -843,7 +878,7 @@ def _qkv_prefix_infer(attrs, in_shapes):
 def _qkv_paged_prefill_attend(op_ctx, attrs, inputs, aux):
     qkv, k_pool, v_pool, block_table, start, lengths = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
-    q, k, v, D = _unpack_qkv(qkv, H)
+    q, k, v = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     start = start.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
@@ -851,22 +886,12 @@ def _qkv_paged_prefill_attend(op_ctx, attrs, inputs, aux):
         k, v, k_pool, v_pool, block_table, lengths, start=start)
     KVB = k_pool.shape[1]
     B, MB = block_table.shape
+    D = k_pool.shape[2] // H
     kg = new_kp[block_table].reshape(B, MB * KVB, H, D)
     vg = new_vp[block_table].reshape(B, MB * KVB, H, D)
-    out = prefix_suffix_attention(q, k, v, kg, vg, start, KVB)
-    return [jnp.reshape(out, (B, qkv.shape[1], H * D)), new_kp, new_vp]
-
-
-def _qkv_prefix_q_infer(attrs, in_shapes):
-    qkv, kp, vp, ks, vs, bt, st, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    return in_shapes, [(qkv[0], qkv[1], qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp),
-                       tuple(ks) if ks is not None else None,
-                       tuple(vs) if vs is not None else None], []
+    out = prefix_suffix_attention(_heads(q, H), _heads(k, H),
+                                  _heads(v, H), kg, vg, start, KVB)
+    return [_rows(out), new_kp, new_vp]
 
 
 @register("QKVPagedPrefillAttendQ",
@@ -874,7 +899,7 @@ def _qkv_prefix_q_infer(attrs, in_shapes):
                      "block_table", "start", "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool",
                      "new_k_scale", "new_v_scale"),
-          infer_shape=_qkv_prefix_q_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedPrefillAttendQ", 4),
           doc="QKVPagedPrefillAttend over QUANTIZED pools: the suffix "
               "quantizes on write; the cached prefix dequantizes on "
               "gather; the suffix attends its own K/V raw (pre-"
@@ -884,7 +909,7 @@ def _qkv_paged_prefill_attend_q(op_ctx, attrs, inputs, aux):
     (qkv, k_pool, v_pool, k_scale, v_scale, block_table, start,
      lengths) = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
-    q, k, v, D = _unpack_qkv(qkv, H)
+    q, k, v = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     start = start.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
@@ -893,13 +918,14 @@ def _qkv_paged_prefill_attend_q(op_ctx, attrs, inputs, aux):
         start=start)
     KVB = k_pool.shape[1]
     B, MB = block_table.shape
+    D = k_pool.shape[2] // H
     kg = dequantize_kv(new_kp[block_table].reshape(B, MB * KVB, H, D),
                        new_ks[block_table].reshape(B, MB * KVB, H))
     vg = dequantize_kv(new_vp[block_table].reshape(B, MB * KVB, H, D),
                        new_vs[block_table].reshape(B, MB * KVB, H))
-    out = prefix_suffix_attention(q, k, v, kg, vg, start, KVB)
-    return [jnp.reshape(out, (B, qkv.shape[1], H * D)), new_kp, new_vp,
-            new_ks, new_vs]
+    out = prefix_suffix_attention(_heads(q, H), _heads(k, H),
+                                  _heads(v, H), kg, vg, start, KVB)
+    return [_rows(out), new_kp, new_vp, new_ks, new_vs]
 
 
 # ---------------------------------------------------------------------------
@@ -919,11 +945,11 @@ def _qkv_paged_prefill_attend_q(op_ctx, attrs, inputs, aux):
 # ---------------------------------------------------------------------------
 
 
-def paged_verify_attention(q, k_pool, v_pool, block_table, start):
+def paged_verify_attention(q, k_pool, v_pool, block_table, start, H):
     """Multi-query decode attention for a verify window.
 
-    q (B, W, H, D) at absolute positions ``start[b] + i`` (window K/V
-    already written); returns (B, W, H, D), each row bit-identical
+    q (B, W, H·D) at absolute positions ``start[b] + i`` (window K/V
+    already written); returns (B, W, H·D), each row bit-identical
     (lax path) to the single-query paged decode at length
     ``start[b] + i + 1`` over the same pool bytes."""
     from . import pallas_kernels as pk
@@ -931,49 +957,42 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, start):
     KVB = k_pool.shape[1]
     if pk.enabled():
         return pk.paged_attention_verify(q, k_pool, v_pool, block_table,
-                                         start)
+                                         start, H)
     B, MB = block_table.shape
-    H, D = k_pool.shape[2], k_pool.shape[3]
+    D = k_pool.shape[2] // H
     kg = k_pool[block_table].reshape(B, MB * KVB, H, D)
     vg = v_pool[block_table].reshape(B, MB * KVB, H, D)
     o, m, l = _blockwise_attention_partial_lax(
-        q, kg, vg, False, KVB, 0, lengths=start + 1, diagonal=True)
-    return normalize_attention_state(o, m, l, q.dtype)
+        _heads(q, H), kg, vg, False, KVB, 0, lengths=start + 1,
+        diagonal=True)
+    return _rows(normalize_attention_state(o, m, l, q.dtype))
 
 
 def paged_verify_attention_q(q, k_pool, v_pool, k_scale, v_scale,
                              block_table, start):
-    """Quantized-pool verify attention: dequantize the gathered cache
+    """Quantized verify window: dequantize the gathered cache to fp32
     (window keys included — matching the quantized decode step, which
     also reads its own token back through the pools), then run the
     diagonal-masked blockwise body with fp32 softmax accumulation."""
     KVB = k_pool.shape[1]
     B, MB = block_table.shape
-    H, D = k_pool.shape[2], k_pool.shape[3]
+    H = k_scale.shape[2]
+    D = k_pool.shape[2] // H
     kg = dequantize_kv(k_pool[block_table].reshape(B, MB * KVB, H, D),
                        k_scale[block_table].reshape(B, MB * KVB, H))
     vg = dequantize_kv(v_pool[block_table].reshape(B, MB * KVB, H, D),
                        v_scale[block_table].reshape(B, MB * KVB, H))
     o, m, l = _blockwise_attention_partial_lax(
-        q, kg, vg, False, KVB, 0, lengths=start + 1, diagonal=True)
-    return normalize_attention_state(o, m, l, q.dtype)
-
-
-def _qkv_verify_infer(attrs, in_shapes):
-    qkv, kp, vp, bt, st, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    return in_shapes, [(qkv[0], qkv[1], qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp)], []
+        _heads(q, H), kg, vg, False, KVB, 0, lengths=start + 1,
+        diagonal=True)
+    return _rows(normalize_attention_state(o, m, l, q.dtype))
 
 
 @register("QKVPagedVerifyAttend",
           arg_names=("qkv", "k_pool", "v_pool", "block_table", "start",
                      "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool"),
-          infer_shape=_qkv_verify_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedVerifyAttend", 2),
           doc="Speculative-verify decode step over the paged cache: "
               "qkv (B, W, 3*H*D) holds the pending token plus k draft "
               "tokens at absolute positions start[b]+i; their K/V is "
@@ -987,27 +1006,15 @@ def _qkv_verify_infer(attrs, in_shapes):
 def _qkv_paged_verify_attend(op_ctx, attrs, inputs, aux):
     qkv, k_pool, v_pool, block_table, start, lengths = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
-    q, k, v, D = _unpack_qkv(qkv, H)
+    q, k, v = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     start = start.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
     new_kp, new_vp = paged_prefill_write(
         k, v, k_pool, v_pool, block_table, lengths, start=start)
-    out = paged_verify_attention(q, new_kp, new_vp, block_table, start)
-    B = qkv.shape[0]
-    return [jnp.reshape(out, (B, qkv.shape[1], H * D)), new_kp, new_vp]
-
-
-def _qkv_verify_q_infer(attrs, in_shapes):
-    qkv, kp, vp, ks, vs, bt, st, ln = in_shapes
-    if qkv is None or kp is None:
-        return in_shapes, None, None
-    H = attr_int(attrs.get("num_heads", 1), 1)
-    _check_qkv_packing(qkv[2], H, qkv)
-    return in_shapes, [(qkv[0], qkv[1], qkv[2] // 3), tuple(kp),
-                       tuple(vp if vp is not None else kp),
-                       tuple(ks) if ks is not None else None,
-                       tuple(vs) if vs is not None else None], []
+    out = paged_verify_attention(q, new_kp, new_vp, block_table, start,
+                                 H)
+    return [out, new_kp, new_vp]
 
 
 @register("QKVPagedVerifyAttendQ",
@@ -1015,7 +1022,7 @@ def _qkv_verify_q_infer(attrs, in_shapes):
                      "block_table", "start", "lengths"),
           out_names=("output", "new_k_pool", "new_v_pool",
                      "new_k_scale", "new_v_scale"),
-          infer_shape=_qkv_verify_q_infer,
+          infer_shape=_paged_qkv_infer("QKVPagedVerifyAttendQ", 4),
           doc="QKVPagedVerifyAttend over QUANTIZED pools: the window "
               "quantizes on write and every query reads the gathered, "
               "dequantized cache (its own window keys included — the "
@@ -1025,18 +1032,15 @@ def _qkv_paged_verify_attend_q(op_ctx, attrs, inputs, aux):
     (qkv, k_pool, v_pool, k_scale, v_scale, block_table, start,
      lengths) = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)
-    q, k, v, D = _unpack_qkv(qkv, H)
+    q, k, v = _split_qkv(qkv, H)
     lengths = lengths.astype(jnp.int32)
     start = start.astype(jnp.int32)
     block_table = block_table.astype(jnp.int32)
-    new_kp, new_vp, new_ks, new_vs = paged_prefill_write_q(
+    new_pools = paged_prefill_write_q(
         k, v, k_pool, v_pool, k_scale, v_scale, block_table, lengths,
         start=start)
-    out = paged_verify_attention_q(q, new_kp, new_vp, new_ks, new_vs,
-                                   block_table, start)
-    B = qkv.shape[0]
-    return [jnp.reshape(out, (B, qkv.shape[1], H * D)), new_kp, new_vp,
-            new_ks, new_vs]
+    out = paged_verify_attention_q(q, *new_pools, block_table, start)
+    return [out, *new_pools]
 
 
 @register("DotProductAttention", arg_names=("query", "key", "value"),

@@ -42,6 +42,8 @@ from jax.experimental import pallas as pl
 
 from jax.experimental.pallas import tpu as pltpu
 
+from ..base import MXNetError
+
 
 def enabled() -> bool:
     """Use the Pallas kernels?  Default: only on a real TPU backend."""
@@ -64,6 +66,9 @@ def _vmem_spec(block, index_map):
 # buffered, need more at block 1024.  A v5e core has 128 MiB and its
 # compiler accepts this request (tests/test_tpu_compile.py).
 _VMEM_LIMIT = 100 * 1024 * 1024
+# what a kernel that sets no limit of its own is given (v5e's default
+# scoped VMEM), less room for the compiler's own temporaries
+_PAGED_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _compiler_params(*dimension_semantics, vmem_limit_bytes=None):
@@ -1329,34 +1334,64 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
 # HBM — HBM traffic per step is exactly the pages a stream actually
 # holds.
 #
-# ONE kernel family serves the decode step (W = 1), the quantized-
-# cache decode step (W = 1 plus per-slot scales dequantized in VMEM)
-# and the speculative-verify window (W = 1 + k).  Grid (B, MB): each
-# step DMAs ONE page of K and V and folds it into a (H, W, ...)
-# online-softmax state under the DIAGONAL mask
-# k_pos < start[b] + 1 + w — row w reproduces exactly the mask (and
-# block chain) of a single-query decode at length start[b] + 1 + w.
-# A page fully masked for a row is an exact no-op of that row's state
-# merge (alpha == 1, p == 0).
+# The pools are LANE-DENSE, (P, KVB, H·D): a head is a D-lane span of
+# a page's rows, as in the fused QKV projection the rows were cut
+# from.  D = 64 is half a lane tile, so a (…, H, D) pool has no tiled
+# layout without padding (20 heads x 64 pad to 32 x 128, 3.2 x the
+# bytes); the backend then holds it pages-minor and every program that
+# scatters into it or hands it to this kernel re-lays-out the whole
+# pool on the way in and again on the way out.  At (P, KVB, H·D) a
+# bf16 page of 16 rows x 1280 lanes is ten whole tiles, the scatter
+# runs in place and the kernel takes the pool as it is.
 #
-# Every contraction keeps the query in (W, H, D) form: heads are the
-# batch dimension and W the left operand's free dimension, which is
-# what Mosaic's dot needs (a 2-D (H, D) query batched over H leaves
-# the left operand no free dimension and is refused on the chip).
+# ONE kernel family serves the decode step (W = 1), the quantized-
+# cache decode step (W = 1 plus per-slot scales dequantized in VMEM) and
+# the speculative-verify window (W = 1 + k).  Grid (B, MB): each step
+# DMAs ONE page of K and V and folds it into an online-softmax state
+# under the DIAGONAL mask k_pos < start[b] + 1 + w — row w reproduces
+# exactly the mask (and block chain) of a single-query decode at
+# length start[b] + 1 + w.  A page fully masked for a row is an exact
+# no-op of that row's state merge (alpha == 1, p == 0).
+#
+# Heads are contracted WITHOUT reshaping the page: at the first page
+# of a stream the W query rows are spread over HP = H rounded up to a
+# sublane tile rows each, row (w, h) holding q[w] on head h's lane
+# span and exact zeros elsewhere.  One (W·HP, H·D) x (KVB, H·D)^T
+# matmul then gives every head's scores (the zeros add nothing to the
+# float32 accumulation), and one (W·HP, KVB) x (KVB, H·D) matmul every
+# head's P·V on its own span of the row; the finish keeps row (w, h)'s
+# span h and sums the rows of a w.  The MXU does H times the needed
+# multiplies, which it has to spare at one page a step; what the step
+# is short of is instructions, and this form has two matmuls and a
+# dozen whole-tile vector ops where a loop over heads has 2·H matmuls
+# on half-tile lane slices.
 # ---------------------------------------------------------------------------
 
 
 def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, kvb, nb, w, quant):
+                  scale, kvb, nb, w, h, d, hp, quant):
     if quant:
-        ks_ref, vs_ref, o_ref, acc_scr, m_scr, l_scr = rest
+        ks_ref, vs_ref, o_ref, qx_scr, acc_scr, m_scr, l_scr = rest
     else:
-        o_ref, acc_scr, m_scr, l_scr = rest
+        o_ref, qx_scr, acc_scr, m_scr, l_scr = rest
     b = pl.program_id(0)
     j = pl.program_id(1)
+    hd = h * d
+
+    def head_span(rows):
+        # (rows, H·D) bool: lane belongs to the row's head (rows >= H,
+        # HP's padding up to a sublane tile, select nothing)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+        return (lane >= row * d) & (lane < (row + 1) * d)
 
     @pl.when(j == 0)
     def _init():
+        span = head_span(hp)
+        q = q_ref[0].astype(jnp.float32)              # (W, H·D)
+        for i in range(w):
+            qx_scr[i * hp:(i + 1) * hp, :] = jnp.where(
+                span, q[i:i + 1, :], 0.0).astype(qx_scr.dtype)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -1367,76 +1402,119 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
     # valid page id)
     @pl.when(j * kvb < start_ref[b] + w)
     def _compute():
-        q = q_ref[0]                      # (W, H, D)
-        k = k_ref[0]                      # (KVB, H, D)
+        qx = qx_scr[...]                              # (W·HP, H·D)
+        k = k_ref[0]                                  # (KVB, H·D)
         v = v_ref[0]
         if quant:
             # pages arrive as int8/fp8 plus their (KVB, H) float32
-            # scales and are dequantized right after the DMA — the
-            # narrow dtype is what crosses HBM
-            k = k.astype(jnp.float32) * ks_ref[0][:, :, None]
-            v = v.astype(jnp.float32) * vs_ref[0][:, :, None]
-        # s[h, w, t] = q[w, h, :] . k[t, h, :]
+            # scales and are dequantized to float32 right after the
+            # DMA — the narrow dtype is what crosses HBM.  A scale
+            # reaches its head's D lanes through an exact 0/1 matmul
+            # (one non-zero term a lane), and from here on every
+            # operand is float32: q, the values, the probabilities
+            lanes = head_span(h).astype(jnp.float32)      # (H, H·D)
+
+            def dequant(x, scale_ref):
+                return x.astype(jnp.float32) * jax.lax.dot_general(
+                    scale_ref[0], lanes, (((1,), (0,)), ((), ())),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+
+            k = dequant(k, ks_ref)
+            v = dequant(v, vs_ref)
+            qx = qx.astype(jnp.float32)
+        # s[(w, h), t] = q[w, span h] . k[t, span h]
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((1,), (1,))),
+            qx, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        k_pos = j * kvb + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        valid = k_pos < start_ref[b] + 1 + row
+        k_pos = j * kvb + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        win = sum((row >= i * hp).astype(jnp.int32) for i in range(1, w))
+        valid = k_pos < start_ref[b] + 1 + win
         s = jnp.where(valid, s, -jnp.inf)
-        m_prev = m_scr[:, :, 0]                       # (H, W)
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2))
+        m_prev = m_scr[:, :1]                         # (W·HP, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.where(valid, jnp.exp(s - m_safe[:, :, None]), 0.0)
+        p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
         alpha = jnp.where(m_prev == -jnp.inf, 0.0,
                           jnp.exp(m_prev - m_safe))
         l_scr[...] = jnp.broadcast_to(
-            (l_scr[:, :, 0] * alpha + jnp.sum(p, axis=2))[:, :, None],
+            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
             l_scr.shape)
-        # pv[h, w, d] = sum_t p[h, w, t] * v[t, h, d]
+        # pv[(w, h), :] = sum_t p[(w, h), t] * v[t, :] — right on span h
         pv = jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (0,)), ((0,), (1,))),
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        acc_scr[...] = acc_scr[...] * alpha[:, :, None] + pv
-        m_scr[...] = jnp.broadcast_to(m_new[:, :, None], m_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
     @pl.when(j == nb - 1)
     def _finish():
-        l = l_scr[:, :, 0]                            # (H, W)
-        out = acc_scr[...] / jnp.maximum(l, 1e-30)[:, :, None]
-        o_ref[0] = out.swapaxes(0, 1).astype(o_ref.dtype)   # (W, H, D)
+        span = head_span(hp)
+        out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+        rows = [jnp.sum(jnp.where(span, out[i * hp:(i + 1) * hp, :], 0.0),
+                        axis=0, keepdims=True) for i in range(w)]
+        out = jnp.concatenate(rows, axis=0) if w > 1 else rows[0]
+        o_ref[0] = out.astype(o_ref.dtype)            # (W, H·D)
 
 
-def _paged_attention(q, k_pool, v_pool, scales, block_table, start):
-    """q (B, W, H, D) at absolute positions ``start[b] + i``; scales is
-    () or (k_scale, v_scale), each (P, KVB, H) float32."""
-    B, W, H, D = q.shape
+def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
+                     num_heads):
+    """q (B, W, H·D) at absolute positions ``start[b] + i``; pools
+    (P, KVB, H·D); scales is () or (k_scale, v_scale), each
+    (P, KVB, H) float32."""
+    B, W, HD = q.shape
+    H = int(num_heads)
+    D = HD // H
     KVB = k_pool.shape[1]
     MB = block_table.shape[1]
+    HP = -(-H // 16) * 16  # whole sublane tiles of a 16-bit q
+    # The all-heads form holds W·HP rows of H·D lanes three times over
+    # (the spread q, the float32 accumulator, the P·V product; a
+    # fourth, q in float32, over quantized pools), so its
+    # VMEM grows as W·H²·D: 0.4 MB at the benchmark's 20 x 64, W = 1;
+    # 2 MB at W = 5; every GPT-2 size at W <= 8 stays under 5 MB.  It
+    # does NOT fit every head count — 64 heads x 128 at W = 5 would
+    # want 26 MB of the 16 MB a kernel is given — and is refused here
+    # by name rather than by a Mosaic allocation error.  A model that
+    # wide needs the loop-over-heads form (H-fold less MXU work and
+    # VMEM, more instructions a step: 2.97 ms against 1.55 ms a layer
+    # at 20 x 64, PERF.md §6 PR 26).
+    quant = bool(scales)
+    vmem = (W * HP * HD * (q.dtype.itemsize + 4 + 4 + 4 * quant)
+            + 2 * KVB * HD * (2 * k_pool.dtype.itemsize + 4 * quant))
+    if vmem > _PAGED_VMEM_BUDGET:
+        raise MXNetError(
+            f"paged_attention: {H} heads x {D} with a {W}-row window "
+            f"needs about {vmem >> 20} MB of VMEM in the all-heads "
+            f"form ({W * HP} rows of {HD} lanes, three times over), "
+            f"more than the {_PAGED_VMEM_BUDGET >> 20} MB it may count "
+            f"on; fewer window rows fit, or heads sharded over tp")
     kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
-                             kvb=KVB, nb=MB, w=W, quant=bool(scales))
+                             kvb=KVB, nb=MB, w=W, h=H, d=D, hp=HP,
+                             quant=quant)
 
-    def page(*tail):
-        zeros = (0,) * (2 + len(tail))
-        return _vmem_spec((1, KVB, H) + tail,
-                          lambda b, j, tr, sr: (tr[b, j],) + zeros)
+    def page(width):
+        return _vmem_spec((1, KVB, width),
+                          lambda b, j, tr, sr: (tr[b, j], 0, 0))
 
     def rows():
-        return _vmem_spec((1, W, H, D), lambda b, j, tr, sr: (b, 0, 0, 0))
+        return _vmem_spec((1, W, HD), lambda b, j, tr, sr: (b, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(B, MB),
-        in_specs=[rows(), page(D), page(D)] + [page() for _ in scales],
+        in_specs=[rows(), page(HD), page(HD)] + [page(H) for _ in scales],
         out_specs=rows(),
-        scratch_shapes=[pltpu.VMEM((H, W, D), jnp.float32),
-                        pltpu.VMEM((H, W, 128), jnp.float32),
-                        pltpu.VMEM((H, W, 128), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((W * HP, HD), q.dtype),
+                        pltpu.VMEM((W * HP, HD), jnp.float32),
+                        pltpu.VMEM((W * HP, 128), jnp.float32),
+                        pltpu.VMEM((W * HP, 128), jnp.float32)],
     )
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, W, HD), q.dtype),
         compiler_params=_compiler_params("parallel", "arbitrary"),
         interpret=_interpret(),
         name="paged_attention_q" if scales else "paged_attention",
@@ -1444,36 +1522,39 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start):
       q, k_pool, v_pool, *scales)
 
 
-def paged_attention_decode(q, k_pool, v_pool, block_table, lengths):
-    """q (B, H, D) at position lengths-1; k_pool/v_pool (P, KVB, H, D);
+def paged_attention_decode(q, k_pool, v_pool, block_table, lengths,
+                           num_heads):
+    """q (B, H·D) at position lengths-1; k_pool/v_pool (P, KVB, H·D);
     block_table (B, MB) int32 page ids (page 0 = scratch); lengths (B,)
-    int32 counting the current token -> (B, H, D) in q.dtype.
+    int32 counting the current token -> (B, H·D) in q.dtype.
 
     The W = 1 case of the paged kernel.  H here is whatever the caller
     holds — under the serving mesh's shard_map it is the LOCAL head
-    count H/tp with pools sliced on their head dim, and the kernel is
-    head-wise independent, so the grid/DMA structure (and per-step
-    VMEM footprint) just shrinks with the shard."""
+    count H/tp with pools sliced on their lane dim (whole heads), and
+    the kernel is head-wise independent, so the grid/DMA structure (and
+    per-step VMEM footprint) just shrinks with the shard."""
     return _paged_attention(q[:, None], k_pool, v_pool, (), block_table,
-                            lengths - 1)[:, 0]
+                            lengths - 1, num_heads)[:, 0]
 
 
 def paged_attention_decode_quant(q, k_pool, v_pool, k_scale, v_scale,
-                                 block_table, lengths):
+                                 block_table, lengths, num_heads):
     """Quantized-cache paged decode: like :func:`paged_attention_decode`
     but k_pool/v_pool hold int8 (or fp8) values and
     k_scale/v_scale (P, KVB, H) float32 hold the per-slot-per-head
     dequantization scales, applied in kernel after each page's DMA.
     Softmax statistics and the P·V accumulation stay float32."""
     return _paged_attention(q[:, None], k_pool, v_pool, (k_scale, v_scale),
-                            block_table, lengths - 1)[:, 0]
+                            block_table, lengths - 1, num_heads)[:, 0]
 
 
-def paged_attention_verify(q, k_pool, v_pool, block_table, start):
-    """q (B, W, H, D): the verify window's queries at absolute
+def paged_attention_verify(q, k_pool, v_pool, block_table, start,
+                           num_heads):
+    """q (B, W, H·D): the verify window's queries at absolute
     positions ``start[b] + i`` (window K/V already in the pools);
-    k_pool/v_pool (P, KVB, H, D); block_table (B, MB) int32 page ids
+    k_pool/v_pool (P, KVB, H·D); block_table (B, MB) int32 page ids
     (page 0 = scratch); start (B,) int32 tokens cached BEFORE the
-    window -> (B, W, H, D) in q.dtype, row i the single-query decode
+    window -> (B, W, H·D) in q.dtype, row i the single-query decode
     at length ``start[b] + i + 1``."""
-    return _paged_attention(q, k_pool, v_pool, (), block_table, start)
+    return _paged_attention(q, k_pool, v_pool, (), block_table, start,
+                            num_heads)

@@ -1045,7 +1045,7 @@ class DecodeEngine:
 
         from .kv_cache import (BlockAllocator, blocks_for_tokens,
                                bucket_ladder, kv_quantized,
-                               kv_storage_dtype)
+                               kv_storage_dtype, value_pool_shape)
         from .executor import build_graph_fn
         from .models.transformer import (transformer_lm_decode,
                                          transformer_lm_prefill,
@@ -1397,10 +1397,10 @@ class DecodeEngine:
         if self._mesh is not None:
             self._pools = self._mesh.init_pools(int(cache_blocks))
         else:
-            pool_shape = (int(cache_blocks), self._kv_block, self._H,
-                          self._D)
+            pool_shape = value_pool_shape(cache_blocks, self._kv_block,
+                                          self._H, self._D)
             pool_zero = np.zeros(pool_shape, self._np_dtype)
-            scale_one = np.ones(pool_shape[:3], np.float32)
+            scale_one = np.ones(pool_shape[:2] + (self._H,), np.float32)
             pools = []
             for _ in range(self._L):
                 pools.append(jax.device_put(pool_zero, dev))
@@ -2943,6 +2943,16 @@ class DecodeEngine:
     # ------------------------------------------------------------------
     # live KV page migration (disaggregated prefill/decode roles)
     # ------------------------------------------------------------------
+    def _frame_shape(self, i: int, n: int) -> tuple:
+        """What a migration frame declares for ``n`` pages of pool
+        ``i``.  Value pages travel as (n, KVB, H, D) — the same bytes
+        as the pool's (n, KVB, H·D) rows, so frames read as they did
+        when the pools were 4-D; a quantized pool's scales travel as
+        they lie, (n, KVB, H)."""
+        if i % self._pool_stride < 2:
+            return (n, self._kv_block, self._H, self._D)
+        return (n, self._kv_block, self._H)
+
     def _export_stream(self, s: _Stream):
         """Gather a prefill-only stream's KV pages off the pool and
         resolve its Future with a migration payload: ``meta`` (stream
@@ -2958,11 +2968,12 @@ class DecodeEngine:
         done = s.done()  # max_new == 1 or instant eos: state-only frame
         if s.blocks and not done:
             idx = np.asarray(s.blocks, np.int32)
-            slabs = [np.asarray(p[idx]) for p in self._pools]
             self._count("d2h_syncs")
             s.cost.d2h_syncs += 1
         else:
-            slabs = [np.asarray(p[0:0]) for p in self._pools]
+            idx = np.zeros((0,), np.int32)
+        slabs = [np.asarray(p[idx]).reshape(self._frame_shape(i, len(idx)))
+                 for i, p in enumerate(self._pools)]
         nbytes = sum(a.nbytes for a in slabs)
         meta = {
             "fmt": 1,
@@ -3068,8 +3079,8 @@ class DecodeEngine:
                 f"expected prompt + generated + {len(self._pools)} "
                 f"page slabs")
         n_pages = int(meta["n_pages"])
-        for p, slab in zip(self._pools, arrays[2:]):
-            want = (n_pages,) + tuple(np.shape(p))[1:]
+        for i, (p, slab) in enumerate(zip(self._pools, arrays[2:])):
+            want = self._frame_shape(i, n_pages)
             if tuple(np.shape(slab)) != want \
                     or np.dtype(slab.dtype) != np.dtype(p.dtype):
                 raise MXNetError(
@@ -3139,7 +3150,8 @@ class DecodeEngine:
                 idx = np.asarray(pages, np.int32)
                 pools = list(self._pools)
                 for i, slab in enumerate(arrays[2:]):
-                    pools[i] = pools[i].at[idx].set(slab)
+                    pools[i] = pools[i].at[idx].set(np.reshape(
+                        slab, (n_pages,) + pools[i].shape[1:]))
                 self._pools = tuple(pools)
             prompt = np.asarray(arrays[0], np.int32)
             tenant = meta.get("tenant")

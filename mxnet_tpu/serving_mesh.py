@@ -25,7 +25,10 @@ only OUTPUT dims:
   sharded embedding lookup is a clip + masked local gather + ``psum``
   — exact, one shard contributes the row, the rest contribute zeros);
 * the KV pools' and scale pools' head dim (``'heads'`` in the rules
-  table), so per-device pool bytes drop by ~1/tp.
+  table; the value pools' is their lane dim H·D, heads being
+  contiguous D-lane spans — ``kv_cache.value_pool_shape`` — so a tp
+  shard holds H/tp whole heads), so per-device pool bytes drop by
+  ~1/tp.
 
 ``proj_weight``/``ff2_weight`` — whose rules spec shards the
 CONTRACTION dim ('heads'/'ffn' on dim 1) — stay REPLICATED on purpose:
@@ -193,8 +196,7 @@ class MeshPrograms:
         # KV/scale pool specs through the rules table: the pools'
         # 'heads' dim resolves to 'tp'; the stacked layer dim rides
         # 'pp' (stage-resident slabs)
-        kv_axes = self._axes.get("layer0_kpool", (None, None, "heads",
-                                                  None))
+        kv_axes = self._axes.get("layer0_kpool", (None, None, "heads"))
         sc_axes = self._axes.get("layer0_kscale", (None, None, "heads"))
         self._kv_spec = ("pp",) + tuple(
             plan.rules.spec(kv_axes, None, param="layer0_kpool"))
@@ -286,15 +288,18 @@ class MeshPrograms:
         return self._host_shapes.get(name)
 
     def init_pools(self, cache_blocks: int) -> tuple:
-        """Zeroed stacked pools: k/v (L, P, KVB, H, D) sharded
-        ('pp', -, -, 'tp', -) + quantized f32 scale pools
+        """Zeroed stacked pools: k/v (L, P, KVB, H·D) sharded
+        ('pp', -, -, 'tp') + quantized f32 scale pools
         (L, P, KVB, H) sharded ('pp', -, -, 'tp')."""
-        shape = (self.L, int(cache_blocks), self.kvb, self.H, self.D)
+        from .kv_cache import value_pool_shape
+
+        shape = (self.L,) + value_pool_shape(cache_blocks, self.kvb,
+                                             self.H, self.D)
         zero = np.zeros(shape, self._pool_dtype)
         pools = [self._put(zero, self._kv_spec),
                  self._put(zero, self._kv_spec)]
         if self._quant:
-            one = np.ones(shape[:4], np.float32)
+            one = np.ones(shape[:3] + (self.H,), np.float32)
             pools.append(self._put(one, self._sc_spec))
             pools.append(self._put(one, self._sc_spec))
         return tuple(pools)

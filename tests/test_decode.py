@@ -17,7 +17,7 @@ import mxnet_tpu as mx
 from mxnet_tpu import models, profiler
 from mxnet_tpu.executor import build_graph_fn
 from mxnet_tpu.kv_cache import (BlockAllocator, blocks_for_tokens,
-                                bucket_ladder)
+                                bucket_ladder, value_pool_shape)
 from mxnet_tpu.models.transformer import (transformer_lm_decode,
                                           transformer_lm_prefill)
 
@@ -187,7 +187,7 @@ def test_paged_decode_bitwise_under_fragmentation(lm):
     P = 12
     # stale garbage in the pool: a previous tenant's values must not
     # leak through the masks (finite garbage — K/V are activations)
-    pools = [jnp.asarray(rng.randn(P, KVB, H, DM // H)
+    pools = [jnp.asarray(rng.randn(*value_pool_shape(P, KVB, H, DM // H))
                          .astype(np.float32)) for _ in range(2 * L)]
     # fragmented page order from interleaved alloc/free
     table = np.zeros((1, 4), np.int32)
@@ -221,37 +221,123 @@ def test_paged_decode_bitwise_under_fragmentation(lm):
         pools = [jnp.asarray(x) for x in outs[1:]]
 
 
-def test_paged_pallas_kernel_matches_lax(monkeypatch):
+@pytest.mark.parametrize("kind", ["w1", "w5", "int8"])
+@pytest.mark.parametrize("nH,D", [(2, 8), (4, 32), (20, 64)])
+def test_paged_pallas_kernel_matches_lax(monkeypatch, nH, D, kind):
     """The gather-by-block-table Pallas kernel (interpret mode on CPU
-    — the same kernel code path as TPU) matches the lax gather
-    fallback at dtype tolerance."""
+    — the same kernel code path as TPU) over lane-dense (P, KVB, H·D)
+    pools matches the blockwise lax body over a cache gathered (and,
+    for int8 pools, dequantized) HERE, in numpy, from the pool viewed
+    as (P, KVB, H, D) — at dtype tolerance: the decode step (W = 1), a
+    verify window (W = 5) and int8 pools, at the benchmark's 20 heads
+    x 64, at a width where a head is a quarter of a lane tile, and at
+    a toy one."""
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops import pallas_kernels as pk
-    from mxnet_tpu.ops.attention import decode_attention
+    from mxnet_tpu.ops import attention as att, pallas_kernels as pk
 
     rng = np.random.RandomState(3)
-    B, nH, D, P, MB = 3, 2, 8, 10, 3
-    q = rng.randn(B, 1, nH, D).astype(np.float32)
-    kp = rng.randn(P, KVB, nH, D).astype(np.float32)
-    vp = rng.randn(P, KVB, nH, D).astype(np.float32)
+    B, P, MB = 3, 10, 3
+    W = 5 if kind == "w5" else 1
+    q = rng.randn(B, W, nH * D).astype(np.float32)
+    shape = value_pool_shape(P, KVB, nH, D)
+    kp = rng.randn(*shape).astype(np.float32)
+    vp = rng.randn(*shape).astype(np.float32)
     table = np.array([[5, 2, 9], [1, 7, 3], [0, 0, 0]], np.int32)
-    lengths = np.array([9, 5, 0], np.int32)
+    # tokens cached before the window; row 2 is an inactive slot
+    start = np.array([8, 4, -1], np.int32)
+    scales = ()
+    if kind == "int8":
+        (kp, ks), (vp, vs) = (
+            [np.asarray(a) for a in
+             att._quantize_rows(jnp.asarray(x), nH, jnp.int8)]
+            for x in (kp, vp))
+        scales = (ks, vs)
 
     monkeypatch.setenv("MXNET_PALLAS", "1")
     assert pk.enabled()
-    out = np.asarray(pk.paged_attention_decode(
-        jnp.asarray(q[:, 0]), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lengths)))
-    kg = kp[table].reshape(B, MB * KVB, nH, D)
-    vg = vp[table].reshape(B, MB * KVB, nH, D)
-    ref = np.asarray(decode_attention(
-        jnp.asarray(q), jnp.asarray(kg), jnp.asarray(vg),
-        jnp.asarray(lengths), KVB))[:, 0]
-    np.testing.assert_allclose(out, ref, rtol=1e-6, atol=1e-6)
+    args = [jnp.asarray(x) for x in (kp, vp, *scales, table)]
+    if kind == "int8":
+        out = pk.paged_attention_decode_quant(
+            jnp.asarray(q[:, 0]), *args, jnp.asarray(start + 1),
+            nH)[:, None]
+    elif kind == "w5":
+        out = pk.paged_attention_verify(jnp.asarray(q), *args,
+                                        jnp.asarray(start), nH)
+    else:
+        out = pk.paged_attention_decode(
+            jnp.asarray(q[:, 0]), *args, jnp.asarray(start + 1),
+            nH)[:, None]
+    out = np.asarray(out)
+    assert out.shape == (B, W, nH * D)
+
+    def gathered(pool, scale=None):
+        g = pool[table].reshape(B, MB * KVB, nH, D).astype(np.float32)
+        if scale is not None:
+            g = g * scale[table].reshape(B, MB * KVB, nH)[..., None]
+        return jnp.asarray(g)
+
+    o, m, l = att._blockwise_attention_partial_lax(
+        jnp.asarray(q.reshape(B, W, nH, D)), gathered(kp, *scales[:1]),
+        gathered(vp, *scales[1:]), False, KVB, 0,
+        lengths=jnp.asarray(start + 1), diagonal=True)
+    ref = np.asarray(att.normalize_attention_state(
+        o, m, l, jnp.float32)).reshape(B, W, nH * D)
+    live = slice(0, 2)
+    np.testing.assert_allclose(out[live], ref[live], rtol=1e-6,
+                               atol=1e-6)
     # a fully-masked (inactive) stream produces zeros, not NaN
     assert np.all(np.isfinite(out))
-    np.testing.assert_array_equal(out[2], np.zeros_like(out[2]))
+    if W == 1:
+        np.testing.assert_array_equal(out[2], np.zeros_like(out[2]))
+
+
+@pytest.mark.parametrize("nH,D", [(4, 32), (20, 64)])
+def test_paged_quant_kernel_float32_operands_under_bf16_query(
+        monkeypatch, nH, D):
+    """A bf16 engine over int8 pools: the kernel dequantizes a page to
+    float32 and keeps q, the values AND the probabilities float32
+    through both matmuls, as the (P, KVB, H, D) kernel did.  Its bf16
+    output then equals, element for element, the float32 body over the
+    numpy-dequantized cache rounded to bf16 (but for a rare tie at a
+    rounding boundary).  Probabilities cast to the query's bf16 before
+    P·V — 8 mantissa bits for 24 — move a third of the elements by a
+    bf16 ulp, and fail here."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att, pallas_kernels as pk
+
+    rng = np.random.RandomState(3)
+    B, P, MB = 2, 10, 3
+    q = jnp.asarray(rng.randn(B, 1, nH * D).astype(np.float32)
+                    ).astype(jnp.bfloat16)
+    shape = value_pool_shape(P, KVB, nH, D)
+    table = np.array([[5, 2, 9], [1, 7, 3]], np.int32)
+    lengths = np.array([9, 5], np.int32)
+    (kq, ks), (vq, vs) = (
+        att._quantize_rows(jnp.asarray(rng.randn(*shape)
+                                       .astype(np.float32)), nH, jnp.int8)
+        for _ in range(2))
+
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    out = pk.paged_attention_decode_quant(
+        q[:, 0], kq, vq, ks, vs, jnp.asarray(table), jnp.asarray(lengths),
+        nH)
+    assert out.dtype == jnp.bfloat16
+
+    def gathered(pool, scale):
+        g = np.asarray(pool)[table].reshape(B, MB * KVB, nH, D)
+        return jnp.asarray(
+            g.astype(np.float32)
+            * np.asarray(scale)[table].reshape(B, MB * KVB, nH)[..., None])
+
+    ref = att.decode_attention(
+        q.astype(jnp.float32).reshape(B, 1, nH, D), gathered(kq, ks),
+        gathered(vq, vs), jnp.asarray(lengths), KVB)
+    ref = ref.astype(jnp.bfloat16).reshape(B, nH * D)
+    differ = np.asarray(out.astype(jnp.float32)) \
+        != np.asarray(ref.astype(jnp.float32))
+    assert differ.mean() < 0.02, differ.mean()
 
 
 def test_paged_op_pallas_vs_lax_path(monkeypatch, lm):
@@ -264,8 +350,8 @@ def test_paged_op_pallas_vs_lax_path(monkeypatch, lm):
     rng = np.random.RandomState(4)
     B, nH, D, P = 2, 2, 8, 8
     qkv = rng.randn(B, 1, 3 * nH * D).astype(np.float32)
-    kp = rng.randn(P, KVB, nH, D).astype(np.float32)
-    vp = rng.randn(P, KVB, nH, D).astype(np.float32)
+    kp = rng.randn(*value_pool_shape(P, KVB, nH, D)).astype(np.float32)
+    vp = rng.randn(*value_pool_shape(P, KVB, nH, D)).astype(np.float32)
     table = np.array([[3, 6], [1, 4]], np.int32)
     lengths = np.array([6, 3], np.int32)
     ins = [jnp.asarray(x) for x in (qkv, kp, vp, table, lengths)]
@@ -613,7 +699,7 @@ def test_multi_token_decode_qkv_rejected():
     rng = np.random.RandomState(9)
     nH, D = 2, 8
     qkv2 = jnp.asarray(rng.randn(1, 2, 3 * nH * D).astype(np.float32))
-    kp = jnp.zeros((4, KVB, nH, D))
+    kp = jnp.zeros(value_pool_shape(4, KVB, nH, D))
     table = jnp.zeros((1, 2), jnp.int32)
     lengths = jnp.asarray([3], jnp.int32)
     with pytest.raises(mx.MXNetError, match="ONE query position"):

@@ -105,6 +105,52 @@ def test_migration_splice_bit_identity(lm_params, kv_dtype):
         dec.close()
 
 
+def test_golden_page_frame_imports_bit_identically(lm_params):
+    """The pools are (P, KVB, H·D) but a migration frame declares its
+    value pages (n, KVB, H, D), as every frame exported before the
+    pools went lane-dense did: the same bytes.  A golden frame built
+    from row-major (n, KVB, H, D) arrays must pack to the bytes the
+    exporter's own frame packs to, and its pages must land in the
+    importer's pools bit for bit."""
+    D = DM // H
+    prompt = np.arange(1, 2 * KVB + 1, dtype=np.int32)  # 2 full pages
+    pre = _engine(lm_params)
+    dec = _engine(lm_params)
+    try:
+        pay = pre.submit(prompt, 6, temperature=0.0, seed=1,
+                         prefill_only=True).result(120)
+        meta, arrays = pay["meta"], pay["kv_arrays"]
+        n = meta["n_pages"]
+        assert n == 2 and len(arrays) == 2 + 2 * L
+        assert all(a.shape == (n, KVB, H, D) for a in arrays[2:])
+        # a frame from before: the same meta, slabs made as (KVB, H, D)
+        # pages — here holding the exporter's own values ...
+        old = [np.ascontiguousarray(
+            np.asarray(a).reshape(n * KVB, H, D).reshape(n, KVB, H, D))
+            for a in arrays[2:]]
+        secret = b"k" * 32
+        assert wire.pack_page_frame(secret, meta, arrays[:2] + old) \
+            == wire.pack_page_frame(secret, meta, arrays)
+        # ... and here values no pool has ever held
+        rng = np.random.RandomState(7)
+        golden = [rng.randn(n, KVB, H, D).astype(np.float32)
+                  for _ in range(2 * L)]
+        m2, a2 = wire.unpack_page_frame(
+            secret, memoryview(wire.pack_page_frame(
+                secret, meta, arrays[:2] + golden)))
+        assert np.asarray(dec.import_stream(m2, a2).result(120)).size
+        for pool, want in zip(dec._pools, golden):
+            pool = np.asarray(pool)
+            assert pool.shape[1:] == (KVB, H * D)
+            for page in want.reshape(n, KVB, H * D):
+                # the stream decoded on from position 2*KVB: it wrote
+                # a THIRD page and left these two as they came
+                assert (pool == page).all(axis=(1, 2)).sum() == 1
+    finally:
+        pre.close()
+        dec.close()
+
+
 def test_migration_cost_conservation(lm_params):
     """sum(per-stream CostRecords) == stats() for the new
     migration_bytes/migration_ms fields — the PR-13 conservation

@@ -40,6 +40,10 @@ def test_module_states_and_shapes():
 
 def test_module_fit_convergence():
     np.random.seed(42)  # NDArrayIter shuffle draws from the global RNG
+    # ... and the initializer from the process-global mx.random key,
+    # which is wherever the tests this xdist worker ran before left it
+    # (1 start in 12 ends at 0.88)
+    mx.random.seed(0)
     X, y = _make_data()
     train = mx.io.NDArrayIter(X, y, batch_size=20, shuffle=True)
     mod = mx.mod.Module(_mlp_sym(), context=mx.cpu())

@@ -29,7 +29,7 @@ import pytest
 
 import mxnet_tpu as mx
 from mxnet_tpu import models
-from mxnet_tpu.kv_cache import trim_blocks
+from mxnet_tpu.kv_cache import trim_blocks, value_pool_shape
 from mxnet_tpu.speculative import NgramProposer, make_proposer
 
 V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 48
@@ -115,13 +115,14 @@ def test_verify_op_bitwise_vs_sequential_decode():
 
     rng = np.random.RandomState(3)
     P, B, W, start0 = 9, 2, 3, np.array([6, 3], np.int32)
-    kp = jnp.asarray(rng.randn(P, KVB, H, 8).astype(np.float32))
-    vp = jnp.asarray(rng.randn(P, KVB, H, 8).astype(np.float32))
+    pool = value_pool_shape(P, KVB, H, 8)
+    kp = jnp.asarray(rng.randn(*pool).astype(np.float32))
+    vp = jnp.asarray(rng.randn(*pool).astype(np.float32))
     table = jnp.asarray(
         np.array([[3, 1, 7, 0], [5, 2, 0, 0]], np.int32))
-    q = jnp.asarray(rng.randn(B, W, H, 8).astype(np.float32))
-    kw_ = jnp.asarray(rng.randn(B, W, H, 8).astype(np.float32))
-    vw = jnp.asarray(rng.randn(B, W, H, 8).astype(np.float32))
+    q = jnp.asarray(rng.randn(B, W, H * 8).astype(np.float32))
+    kw_ = jnp.asarray(rng.randn(B, W, H * 8).astype(np.float32))
+    vw = jnp.asarray(rng.randn(B, W, H * 8).astype(np.float32))
     start = jnp.asarray(start0)
     lengths = start + W
 
@@ -129,7 +130,7 @@ def test_verify_op_bitwise_vs_sequential_decode():
     kp1, vp1 = paged_prefill_write(kw_, vw, kp, vp, table, lengths,
                                    start=start)
     out_v = np.asarray(paged_verify_attention(q, kp1, vp1, table,
-                                              start))
+                                              start, H))
 
     # sequential path: W single-token decode steps
     kp2, vp2 = kp, vp
@@ -138,7 +139,7 @@ def test_verify_op_bitwise_vs_sequential_decode():
         kp2, vp2 = paged_cache_update(
             kp2, vp2, kw_[:, i:i + 1], vw[:, i:i + 1], table, li)
         out_i = np.asarray(paged_decode_attention(
-            q[:, i:i + 1], kp2, vp2, table, li))
+            q[:, i:i + 1], kp2, vp2, table, li, H))
         # same mask, same block chain; the W-row and the one-row score
         # contractions are different XLA:CPU dot kernels (M = W vs
         # M = 1), so the last bit may differ — see tests/test_decode.py
@@ -157,14 +158,16 @@ def test_pallas_verify_kernel_interpret_matches_lax():
 
     rng = np.random.RandomState(5)
     P, B, W, D = 7, 2, 4, 8
-    kp = jnp.asarray(rng.randn(P, KVB, H, D).astype(np.float32))
-    vp = jnp.asarray(rng.randn(P, KVB, H, D).astype(np.float32))
+    pool = value_pool_shape(P, KVB, H, D)
+    kp = jnp.asarray(rng.randn(*pool).astype(np.float32))
+    vp = jnp.asarray(rng.randn(*pool).astype(np.float32))
     table = jnp.asarray(
         np.array([[2, 5, 1, 0], [4, 3, 0, 0]], np.int32))
-    q = jnp.asarray(rng.randn(B, W, H, D).astype(np.float32))
+    q = jnp.asarray(rng.randn(B, W, H * D).astype(np.float32))
     start = jnp.asarray(np.array([5, 2], np.int32))
-    want = np.asarray(paged_verify_attention(q, kp, vp, table, start))
-    got = np.asarray(pk.paged_attention_verify(q, kp, vp, table, start))
+    want = np.asarray(paged_verify_attention(q, kp, vp, table, start, H))
+    got = np.asarray(pk.paged_attention_verify(q, kp, vp, table, start,
+                                               H))
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
 
 

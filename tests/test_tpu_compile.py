@@ -13,6 +13,7 @@ xdist worker that is handed this file loads libtpu.  Nothing here runs
 at import, in a ``skipif`` or in a ``parametrize`` argument.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -20,6 +21,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
 from mxnet_tpu import hlo, parallel
+from mxnet_tpu.kv_cache import value_pool_shape
 from mxnet_tpu.models import transformer
 from mxnet_tpu.ops import attention, pallas_kernels as pk
 
@@ -99,31 +101,114 @@ def test_flash_attention_partial(on_chip, one_chip):
 
 
 # the engine's decode step: 8 streams, 12 heads x 64, page 16, a
-# 1024-token table (64 pages a stream) over a 640-page pool
+# 1024-token table (64 pages a stream) over a 640-page pool of
+# lane-dense (P, KVB, H*D) pages
 _B, _H, _D, _KVB, _MB, _P = 8, 12, 64, 16, 64, 640
+
+
+def _pool(dt, pages=_P, heads=_H):
+    return (value_pool_shape(pages, _KVB, heads, _D), dt)
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 def test_paged_attention_decode(on_chip, one_chip, dtype):
     dt = jnp.dtype(dtype)
-    pool = ((_P, _KVB, _H, _D), dt)
-    _compile(pk.paged_attention_decode, one_chip,
-             ((_B, _H, _D), dt), pool, pool, ((_B, _MB), i32), ((_B,), i32))
-
-
-def test_paged_attention_decode_quant_int8(on_chip, one_chip):
-    pool = ((_P, _KVB, _H, _D), i8)
-    scale = ((_P, _KVB, _H), f32)
-    _compile(pk.paged_attention_decode_quant, one_chip,
-             ((_B, _H, _D), bf16), pool, pool, scale, scale,
+    _compile(functools.partial(pk.paged_attention_decode, num_heads=_H),
+             one_chip, ((_B, _H * _D), dt), _pool(dt), _pool(dt),
              ((_B, _MB), i32), ((_B,), i32))
 
 
+def test_paged_attention_decode_quant_int8(on_chip, one_chip):
+    scale = ((_P, _KVB, _H), f32)
+    _compile(functools.partial(pk.paged_attention_decode_quant,
+                               num_heads=_H),
+             one_chip, ((_B, _H * _D), bf16), _pool(i8), _pool(i8), scale,
+             scale, ((_B, _MB), i32), ((_B,), i32))
+
+
 def test_paged_attention_verify_w5(on_chip, one_chip):
-    pool = ((_P, _KVB, _H, _D), bf16)
-    _compile(pk.paged_attention_verify, one_chip,
-             ((_B, 5, _H, _D), bf16), pool, pool, ((_B, _MB), i32),
-             ((_B,), i32))
+    _compile(functools.partial(pk.paged_attention_verify, num_heads=_H),
+             one_chip, ((_B, 5, _H * _D), bf16), _pool(bf16), _pool(bf16),
+             ((_B, _MB), i32), ((_B,), i32))
+
+
+def _verify_shapes(heads, d_head, w):
+    pool = (value_pool_shape(_P, _KVB, heads, d_head), bf16)
+    return [((_B, w, heads * d_head), bf16), pool, pool,
+            ((_B, _MB), i32), ((_B,), i32)]
+
+
+def test_paged_attention_widest_admitted(on_chip, one_chip):
+    """The all-heads kernel's VMEM grows as W*H^2*D.  The widest shape
+    its guard admits (32 heads x 128, an 8-row window: 11 MB by the
+    guard's count) really compiles ..."""
+    _compile(functools.partial(pk.paged_attention_verify, num_heads=32),
+             one_chip, *_verify_shapes(32, 128, 8))
+
+
+def test_paged_attention_refuses_what_vmem_cannot_hold(on_chip, one_chip):
+    """... and one Mosaic has no VMEM for (64 heads x 128, W = 5: 26 MB
+    of the 16 MB a kernel is given; unguarded, "Ran out of memory in
+    memory space vmem") is refused by name, with its sizes."""
+    from mxnet_tpu.base import MXNetError
+
+    with pytest.raises(MXNetError, match="64 heads x 128.*VMEM"):
+        _compile(functools.partial(pk.paged_attention_verify,
+                                   num_heads=64),
+                 one_chip, *_verify_shapes(64, 128, 5))
+
+
+# The serving cells' programs touch the pools in two places: the decode
+# step (one token's K/V scattered in, then the paged kernel) and the
+# prefill's write of a whole prompt.  At the cells' sizes — 48 streams,
+# 3073 pages, gpt2-large's 20 heads and gpt2-medium's 16 — neither may
+# re-lay-out a pool: a (P, KVB, H, D) pool cost every program four
+# pool-sized copies a layer and 3.5 pools of temporaries (PERF.md §6,
+# PR 26).  The pools are donated, as the engine donates them.
+_CB, _CP = 48, 3073
+
+
+def _decode_pool_ops(heads, pool):
+    def step(qkv, k_pool, v_pool, table, lengths):
+        q, k, v = attention._split_qkv(qkv, heads)
+        k_pool, v_pool = attention.paged_cache_update(
+            k_pool, v_pool, k, v, table, lengths)
+        out = attention.paged_decode_attention(q, k_pool, v_pool, table,
+                                               lengths, heads)
+        return out, k_pool, v_pool
+
+    return step, (1, 2), [((_CB, 1, 3 * heads * _D), bf16), pool, pool,
+                          ((_CB, _MB), i32), ((_CB,), i32)]
+
+
+def _prefill_pool_ops(heads, pool):
+    rows = ((1, 1024, heads * _D), bf16)
+    return attention.paged_prefill_write, (2, 3), [
+        rows, rows, pool, pool, ((1, _MB), i32), ((1,), i32)]
+
+
+@pytest.mark.parametrize("heads", [20, 16])
+@pytest.mark.parametrize("ops", [_decode_pool_ops, _prefill_pool_ops])
+def test_pool_ops_update_the_pools_in_place(on_chip, one_chip, ops, heads):
+    pool = _pool(bf16, _CP, heads)
+    fn, donated, shapes = ops(heads, pool)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    text = compiled.as_text()
+    if ops is _decode_pool_ops:
+        assert "tpu_custom_call" in text, "no paged kernel in the step"
+    dims = ",".join(str(n) for n in pool[0])
+    # no instruction COPIES something pool-shaped ...
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(.*", text)
+    assert not copies, copies
+    # ... the pools come in row-major (pages major, lanes minor) ...
+    entry = next(ln for ln in text.splitlines()
+                 if "entry_computation_layout" in ln)
+    assert entry.count(f"bf16[{dims}]{{2,1,0") == 2 * len(donated), entry
+    # ... and what the program needs beside them is less than one pool
+    pool_bytes = 2 * _CP * _KVB * heads * _D
+    assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
 def test_lstm_scan_ptb(on_chip, one_chip):

@@ -7,10 +7,13 @@ probe, interpret-mode parity, AND the benchmark shape, yet returned
 Mosaic-level races, so this tool exists: it sweeps the packed and
 per-head flash kernels across a (T, block, causal, H) matrix ON THE
 CHIP and compares forward + all input gradients against the lax
-formulation.  Run it after ANY kernel change:
+formulation, and the paged kernel — decode, verify window, int8 pools
+— over lane-dense (P, KVB, H·D) pools against the lax gather.  Run it
+after ANY kernel change:
 
     python tools/verify_kernels.py          # full matrix (~5 min)
     python tools/verify_kernels.py --quick  # smoke subset
+    python tools/verify_kernels.py --paged  # the paged kernel alone
 """
 
 import os
@@ -106,9 +109,77 @@ def check_mha(T, block, causal, B=2, H=8, D=128):
     return ok
 
 
+def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16):
+    """The paged kernel over (P, KVB, H·D) pools (``kv_cache.
+    value_pool_shape``) against a lax gather of the pages and the
+    fallbacks' blockwise body: W = 1 is the decode step, W > 1 a
+    verify window, ``int8`` the quantized pools.  Streams of every
+    length from one token to a full table, pages handed out in a
+    shuffled order, one idle row."""
+    from mxnet_tpu.kv_cache import value_pool_shape
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(H * 1000 + W)
+    P = B * MB + 1
+    shape = value_pool_shape(P, KVB, H, D)
+    pools = tuple(
+        jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.5)
+        .astype(jnp.bfloat16) for _ in range(2))
+    if kv_dtype == "int8":
+        kq, ks = att._quantize_rows(pools[0], H, jnp.int8)
+        vq, vs = att._quantize_rows(pools[1], H, jnp.int8)
+        pools = (kq, vq, ks, vs)
+    table = jnp.asarray((1 + rng.permutation(P - 1))
+                        .reshape(B, MB).astype(np.int32))
+    # tokens cached before the window: row 0 none, the last row all
+    # but the window, row 1 idle (a decode step's lengths == 0)
+    start = np.linspace(0, MB * KVB - W, B).astype(np.int32)
+    if W == 1:
+        start[1] = -1
+    start = jnp.asarray(start)
+    q = jnp.asarray(rng.randn(B, W, H * D).astype(np.float32)
+                    * 0.5).astype(jnp.bfloat16)
+    scales = pools[2:]
+    got = jax.jit(lambda q, t, s, *p: pk._paged_attention(
+        q, p[0], p[1], p[2:], t, s, H))(q, table, start, *pools)
+
+    def gather_and_attend(q, t, s, *p):
+        # the gathered ROWS take the head dim, never a pool
+        kg, vg = (x[t].reshape(B, MB * KVB, H, D) for x in p[:2])
+        if scales:
+            kg = att.dequantize_kv(kg, p[2][t].reshape(B, MB * KVB, H))
+            vg = att.dequantize_kv(vg, p[3][t].reshape(B, MB * KVB, H))
+        o, m, l = att._blockwise_attention_partial_lax(
+            q.reshape(B, W, H, D), kg, vg, False, KVB, 0,
+            lengths=s + 1, diagonal=True)
+        return att.normalize_attention_state(o, m, l, q.dtype).reshape(
+            B, W, H * D)
+
+    want = jax.jit(gather_and_attend)(q, table, start, *pools)
+    got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
+    live = np.asarray(start) >= 0
+    err = float(np.abs(got[live] - want[live]).max()
+                / max(np.abs(want[live]).max(), 1e-9))
+    ok = err < TOL and bool(np.isfinite(got).all()) \
+        and not np.abs(got[~live]).any()
+    print(f"{'OK ' if ok else 'FAIL'} paged  H={H} D={D} W={W} "
+          f"pools={kv_dtype}{' +scales' if scales else ''}: "
+          f"fwd={err:.4f}", flush=True)
+    return ok
+
+
 def main():
     quick = "--quick" in sys.argv
     results = []
+    # the cells' widths (20 and 16 heads of 64), a head a quarter of a
+    # lane tile, and H·D not a multiple of 128
+    for H, D in ([(20, 64)] if quick else
+                 [(20, 64), (16, 64), (12, 64), (4, 32), (3, 48)]):
+        for W, kv in ((1, "bf16"), (5, "bf16"), (1, "int8")):
+            results.append(check_paged(H, D, W, kv))
+    if "--paged" in sys.argv:
+        return _report(results)
     # packed: sweep revisit counts, block sizes, head counts, causality
     matrix = [(1024, 0, True, 12), (4096, 0, True, 12)] if quick else [
         (1024, 0, True, 12), (1024, 0, False, 12),
@@ -123,6 +194,10 @@ def main():
                              [(1024, 0, True), (4096, 0, True),
                               (4096, 1024, False), (2048, 512, True)]):
         results.append(check_mha(T, block, causal))
+    _report(results)
+
+
+def _report(results):
     n_fail = results.count(False)
     print(f"\n{len(results) - n_fail}/{len(results)} kernel parity checks "
           f"passed")
