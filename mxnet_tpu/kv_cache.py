@@ -50,10 +50,10 @@ import numpy as np
 from . import profiler
 from .base import MXNetError
 
-__all__ = ["BlockAllocator", "blocks_for_tokens", "bucket_ladder",
-           "trim_blocks", "kv_storage_dtype", "kv_quantized",
-           "pool_device_bytes", "value_pool_shape", "KV_DTYPES",
-           "KV_QMAX"]
+__all__ = ["BlockAllocator", "SlotAllocator", "blocks_for_tokens",
+           "bucket_ladder", "trim_blocks", "kv_storage_dtype",
+           "kv_quantized", "pool_device_bytes", "value_pool_shape",
+           "state_pool_shape", "conv_tail_shape", "KV_DTYPES", "KV_QMAX"]
 
 SCRATCH_PAGE = 0
 
@@ -118,6 +118,30 @@ def value_pool_shape(pages: int, kv_block: int, num_heads: int,
     quantized engines' float32 scale pools stay
     ``(pages, kv_block, H)``."""
     return (int(pages), int(kv_block), int(num_heads) * int(d_head))
+
+
+def state_pool_shape(slots: int, num_heads: int, d_head: int) -> tuple:
+    """Shape of a recurrent layer's STATE pool: one (heads, d_head,
+    d_head) float32 matrix stack per slot — the second kind of
+    per-stream state, beside the K/V pages.  A stream holds ONE slot
+    from admission to retirement, whatever its length; slot 0 is
+    scratch (padded batch rows land there), so ``slots`` = live streams
+    + 1.  A head's matrix is held transposed, (d_v, d_k)
+    (``ops/pallas_hybrid.py``)."""
+    return (int(slots), int(num_heads), int(d_head), int(d_head))
+
+
+def conv_tail_shape(slots: int, kernel: int, channels: int) -> tuple:
+    """Shape of a short convolution's carried inputs: per slot (slot 0
+    scratch) the last ``kernel - 1`` rows of ``channels``, back to back
+    in ONE run of (kernel - 1) * channels numbers held as whole
+    (8, 128)-tiles: (slots, 8, W), W the run's eighth rounded up to
+    whole lanes.  As (slots, kernel - 1, channels) the 3-row middle
+    axis has no unpadded tiled layout and every decode step copied the
+    pool twice round its scatter; a slot of whole tiles is written in
+    place, one DMA (the value pools' lesson, :func:`value_pool_shape`)."""
+    run = (int(kernel) - 1) * int(channels)
+    return (int(slots), 8, -(-run // 1024) * 128)
 
 
 def pool_device_bytes(cache_blocks: int, kv_block: int,
@@ -192,6 +216,39 @@ def bucket_ladder(max_value: int, base: int = 1) -> List[int]:
         v *= 2
     out.append(int(max_value))
     return out
+
+
+class SlotAllocator:
+    """Which state slot belongs to which stream: slots 1..n handed out
+    from a LIFO free list, slot 0 reserved scratch.  A slot has one
+    owner at a time; freeing one that is not held is an error."""
+
+    def __init__(self, num_slots: int):
+        if num_slots < 1:
+            raise MXNetError(f"state slots {num_slots} must be >= 1")
+        self.num_slots = int(num_slots)
+        self._free = list(range(self.num_slots, 0, -1))
+        self._owner: Dict[int, object] = {}
+
+    @property
+    def live(self) -> int:
+        return len(self._owner)
+
+    def owner(self, slot: int):
+        return self._owner.get(slot)
+
+    def alloc(self, owner=None) -> Optional[int]:
+        if not self._free:
+            return None
+        slot = self._free.pop()
+        self._owner[slot] = owner
+        return slot
+
+    def free(self, slot: int) -> None:
+        if slot not in self._owner:
+            raise MXNetError(f"state slot {slot} is not held")
+        del self._owner[slot]
+        self._free.append(slot)
 
 
 class BlockAllocator:

@@ -1,0 +1,249 @@
+"""Hybrid decoder-only language models, built from a LAYER LIST.
+
+A model of this family is a stack of pre-norm residual blocks (RMSNorm,
+no biases, no positions) in which every layer names its own MIXER and
+its own FFN:
+
+* mixer ``attention``: softmax attention with grouped queries
+  (``heads`` query heads over ``kv_heads`` KV heads of ``head_dim``), an
+  optional element-wise sigmoid output gate before the output
+  projection; its per-stream state is K/V PAGES;
+* mixer ``kda``: gated-delta-rule linear attention with a per-channel
+  decay (``heads`` heads of ``head_dim``, a depthwise short convolution
+  of ``conv`` taps on q, k and v, low-rank decay and output-gate maps of
+  rank ``head_dim``, ``neg_eigval``: beta in (0, 2)); its per-stream
+  state is a SLOT: the (heads, head_dim, head_dim) float32 state and the
+  convolution's last ``conv - 1`` inputs;
+* ffn ``dense``: a gated (SiLU) feed-forward of ``width``;
+* ffn ``moe``: ``experts`` routed experts of ``width``, ``top_k`` a
+  token with normalised sigmoid scores, plus ``shared`` always-on
+  experts; this program HOLDS ``experts_held`` of the routed experts,
+  from ``first_expert`` on — the share of one chip of an expert-parallel
+  deployment — and adds only their part (``ops/hybrid.py`` MoEFFN).
+
+:class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
+takes: from the layer list it derives the feeds, the pools (pages for
+attention layers, slots for kda layers) and the prefill and decode
+symbols.  The equations are in ``benchmark/reference/solar_open2.py``,
+the plain reference this family is held to.
+"""
+
+from .. import symbol as sym
+from ..base import MXNetError
+
+MIXERS = ("attention", "kda")
+FFNS = ("dense", "moe")
+COUNTERS = "moe_counters"
+
+
+def _fc(x, width, name):
+    return sym.FullyConnected(x, num_hidden=width, flatten=False,
+                              no_bias=True, name=name,
+                              weight=sym.Variable(f"{name}_weight"))
+
+
+def _norm(x, name, eps):
+    return sym.RMSNorm(x, sym.Variable(f"{name}_gamma"), eps=eps,
+                       name=name)
+
+
+def _gated_ffn(h, width, d_model, name):
+    g = sym.Activation(_fc(h, width, f"{name}_gate"), act_type="silu")
+    return _fc(g * _fc(h, width, f"{name}_up"), d_model, f"{name}_down")
+
+
+class HybridSpec:
+    """The model ``DecodeEngine`` is given: sizes and the layer list.
+
+    ``layers``: one dict a layer, ``{"mixer": {"kind": ...}, "ffn":
+    {"kind": ...}}`` with the keys the module doc names.  Plain data: a
+    spec round-trips through JSON (:meth:`to_dict`)."""
+
+    def __init__(self, vocab_size, d_model, layers, norm_eps=1e-5):
+        self.vocab_size = int(vocab_size)
+        self.d_model = int(d_model)
+        self.norm_eps = float(norm_eps)
+        self.layers = [dict(mixer=dict(ly["mixer"]), ffn=dict(ly["ffn"]))
+                       for ly in layers]
+        for i, ly in enumerate(self.layers):
+            m, f = ly["mixer"], ly["ffn"]
+            if m.get("kind") not in MIXERS or f.get("kind") not in FFNS:
+                raise MXNetError(
+                    f"layer {i}: mixer kind {m.get('kind')!r} must be one "
+                    f"of {MIXERS} and ffn kind {f.get('kind')!r} one of "
+                    f"{FFNS}")
+            if m["kind"] == "attention" and \
+                    int(m["heads"]) % int(m.get("kv_heads", m["heads"])):
+                raise MXNetError(
+                    f"layer {i}: {m['kv_heads']} KV heads do not divide "
+                    f"{m['heads']} query heads")
+            if f["kind"] == "moe":
+                held = int(f.get("experts_held", f["experts"]))
+                first = int(f.get("first_expert", 0))
+                if held < 1 or first < 0 or first + held > int(f["experts"]):
+                    raise MXNetError(
+                        f"layer {i}: experts {first}..{first + held - 1} "
+                        f"are not among the {f['experts']} routed")
+        pages = {(int(ly["mixer"].get("kv_heads", ly["mixer"]["heads"])),
+                  int(ly["mixer"]["head_dim"]))
+                 for ly in self.layers if ly["mixer"]["kind"] == "attention"}
+        if len(pages) > 1:
+            raise MXNetError(
+                f"attention layers of different K/V widths {sorted(pages)} "
+                f"would need a page pool each; one width is built")
+        # the K/V page geometry (what the engine sizes its pools by)
+        self.kv_heads, self.head_dim = pages.pop() if pages else (0, 0)
+
+    # -- what the engine asks --------------------------------------------
+    @property
+    def num_layers(self):
+        return len(self.layers)
+
+    def mixer_kinds(self):
+        return tuple(ly["mixer"]["kind"] for ly in self.layers)
+
+    def cache_kinds(self):
+        """Per layer, the kind of its per-stream state: ``pages`` (K/V,
+        through the block table) or ``slots`` (one row a stream)."""
+        return tuple("slots" if k == "kda" else "pages"
+                     for k in self.mixer_kinds())
+
+    def has_moe(self):
+        return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
+
+    feeds = ("data", "lengths", "block_table", "slots")
+
+    def pools(self, cache_blocks, kv_block, slots, dtype, kv_dtype="fp32"):
+        """The per-stream state arrays a program carries, in the order
+        its symbols return them: ``(name, shape, dtype, fill)``.  Pages
+        take ``dtype`` (no quantized pools here: the engine refuses
+        them for this family); slot state is float32 whatever the
+        model's."""
+        from ..kv_cache import (conv_tail_shape, state_pool_shape,
+                                value_pool_shape)
+
+        out = []
+        for i, ly in enumerate(self.layers):
+            m = ly["mixer"]
+            if m["kind"] == "attention":
+                shape = value_pool_shape(cache_blocks, kv_block,
+                                         self.kv_heads, self.head_dim)
+                out += [(f"layer{i}_kpool", shape, dtype, 0),
+                        (f"layer{i}_vpool", shape, dtype, 0)]
+            else:
+                H, D = int(m["heads"]), int(m["head_dim"])
+                out += [(f"layer{i}_state",
+                         state_pool_shape(slots, H, D), "float32", 0),
+                        (f"layer{i}_tail",
+                         conv_tail_shape(slots, int(m["conv"]), 3 * H * D),
+                         "float32", 0)]
+        if self.has_moe():
+            out.append((COUNTERS, (4,), "int32", 0))
+        return out
+
+    def symbol(self, which, kv_block=16, **unused):
+        """The ``prefill`` or ``decode`` symbol.  This family builds no
+        suffix-prefill and no verify symbol."""
+        if which not in ("prefill", "decode"):
+            raise MXNetError(
+                f"the hybrid family (layers {sorted(set(self.mixer_kinds()))}"
+                f") builds no {which!r} symbol")
+        return _trunk(self, step=(which == "decode"))
+
+    def to_dict(self):
+        return {"vocab_size": self.vocab_size, "d_model": self.d_model,
+                "norm_eps": self.norm_eps, "layers": self.layers}
+
+    @classmethod
+    def from_dict(cls, d):
+        return cls(d["vocab_size"], d["d_model"], d["layers"],
+                   d.get("norm_eps", 1e-5))
+
+
+def _attention(spec, h, i, m, step, feeds):
+    H, Hkv, D = int(m["heads"]), int(m.get("kv_heads", m["heads"])), \
+        int(m["head_dim"])
+    name = f"layer{i}"
+    q = _fc(h, H * D, f"{name}_q")
+    k = _fc(h, Hkv * D, f"{name}_k")
+    v = _fc(h, Hkv * D, f"{name}_v")
+    op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
+    att = op(q, k, v, sym.Variable(f"{name}_kpool"),
+             sym.Variable(f"{name}_vpool"), feeds["block_table"],
+             feeds["lengths"], num_heads=H, kv_heads=Hkv,
+             name=f"{name}_attn")
+    out = att[0]
+    if m.get("gate"):
+        out = out * sym.Activation(_fc(h, H * D, f"{name}_gate"),
+                                   act_type="sigmoid")
+    return _fc(out, spec.d_model, f"{name}_o"), [att[1], att[2]]
+
+
+def _kda(spec, h, i, m, step, feeds):
+    H, D = int(m["heads"]), int(m["head_dim"])
+    name = f"layer{i}"
+    conv = sym.ShortConv(
+        _fc(h, 3 * H * D, f"{name}_qkv"),
+        sym.Variable(f"{name}_conv_weight"), sym.Variable(f"{name}_tail"),
+        feeds["slots"], feeds["lengths"], step=step, name=f"{name}_conv")
+    decay = _fc(_fc(h, D, f"{name}_a_down"), H * D, f"{name}_a_up")
+    op = sym.KDAStep if step else sym.KDAChunk
+    rec = op(conv[0], decay, _fc(h, H, f"{name}_beta"),
+             sym.Variable(f"{name}_a_log"), sym.Variable(f"{name}_dt_bias"),
+             sym.Variable(f"{name}_state"), feeds["slots"],
+             feeds["lengths"], num_heads=H,
+             neg_eigval=bool(m.get("neg_eigval", False)),
+             name=f"{name}_kda")
+    gate = _fc(_fc(h, D, f"{name}_g_down"), H * D, f"{name}_g_up")
+    out = sym.GatedRMSNorm(rec[0], gate,
+                           sym.Variable(f"{name}_onorm_gamma"),
+                           eps=spec.norm_eps, num_groups=H,
+                           name=f"{name}_onorm")
+    return _fc(out, spec.d_model, f"{name}_o"), [rec[1], conv[1]]
+
+
+def _ffn(spec, h, i, f, step, feeds, counters):
+    name = f"layer{i}"
+    if f["kind"] == "dense":
+        return _gated_ffn(h, int(f["width"]), spec.d_model,
+                          f"{name}_ffn"), counters
+    routed = sym.MoEFFN(
+        h, sym.Variable(f"{name}_router_weight"),
+        sym.Variable(f"{name}_experts_gate_weight"),
+        sym.Variable(f"{name}_experts_up_weight"),
+        sym.Variable(f"{name}_experts_down_weight"), feeds["lengths"],
+        counters, top_k=int(f["top_k"]),
+        first_expert=int(f.get("first_expert", 0)), step=step,
+        count=step, name=f"{name}_moe")
+    out = routed[0]
+    if int(f.get("shared", 0)):
+        out = out + _gated_ffn(h, int(f["width"]) * int(f["shared"]),
+                               spec.d_model, f"{name}_shared")
+    # decode steps count; a prefill hands the counters on as they are
+    return out, (routed[1] if step else counters)
+
+
+def _trunk(spec, step):
+    """Token ids (B, S) -> ``[logits (B, S, vocab)] + spec.pools()``'s
+    arrays, updated.  ``step``: one token a stream against its state
+    (decode); else a whole (padded) prompt from nothing (prefill)."""
+    feeds = {k: sym.Variable(k) for k in spec.feeds}
+    x = sym.Embedding(feeds["data"], input_dim=spec.vocab_size,
+                      output_dim=spec.d_model, name="tok_embed",
+                      weight=sym.Variable("tok_embed_weight"))
+    counters = sym.Variable(COUNTERS) if spec.has_moe() else None
+    state = []
+    for i, ly in enumerate(spec.layers):
+        h = _norm(x, f"layer{i}_norm1", spec.norm_eps)
+        mix = _attention if ly["mixer"]["kind"] == "attention" else _kda
+        out, st = mix(spec, h, i, ly["mixer"], step, feeds)
+        state += st
+        x = x + out
+        h = _norm(x, f"layer{i}_norm2", spec.norm_eps)
+        out, counters = _ffn(spec, h, i, ly["ffn"], step, feeds, counters)
+        x = x + out
+    x = _norm(x, "final_norm", spec.norm_eps)
+    logits = _fc(x, spec.vocab_size, "head")
+    if counters is not None:
+        state.append(counters)
+    return sym.Group([logits] + state)

@@ -24,6 +24,7 @@ parallelism".
 
 from .. import symbol as sym
 from ..attribute import AttrScope
+from ..base import MXNetError
 from ..parallel import logical_axes
 
 
@@ -455,3 +456,59 @@ def transformer_lm_decode(vocab_size, num_layers=4, num_heads=4,
 
     return _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block,
                      attend_for, vocab_size, lora=lora)
+
+
+class DenseSpec:
+    """This family as ``DecodeEngine`` sees a model: sizes, the kind of
+    each layer's per-stream state, the pools and the serving symbols.
+    What ``DecodeEngine(params, vocab_size=..., num_layers=...,
+    num_heads=..., d_model=...)`` builds for itself; a family with other
+    layers brings its own (``models/hybrid_lm.py`` ``HybridSpec``)."""
+
+    feeds = ("data", "positions", "lengths", "block_table", "start")
+    _BUILDERS = {"prefill": transformer_lm_prefill,
+                 "decode": transformer_lm_decode,
+                 "prefix_prefill": transformer_lm_prefix_prefill,
+                 "verify": transformer_lm_verify}
+
+    def __init__(self, vocab_size, num_layers, num_heads, d_model,
+                 d_ff=None):
+        if d_model % num_heads:
+            raise MXNetError(f"d_model {d_model} % num_heads "
+                             f"{num_heads} != 0")
+        self.vocab_size = int(vocab_size)
+        self.num_layers = int(num_layers)
+        self.num_heads = self.kv_heads = int(num_heads)
+        self.d_model = int(d_model)
+        self.d_ff = d_ff
+        self.head_dim = self.d_model // self.num_heads
+
+    def cache_kinds(self):
+        return ("pages",) * self.num_layers
+
+    def pools(self, cache_blocks, kv_block, slots, dtype,
+              kv_dtype="fp32"):
+        """Per layer [k, v] or, quantized, [k, v, k_scale, v_scale]:
+        ``(name, shape, dtype, fill)`` in the symbols' output order."""
+        from ..kv_cache import value_pool_shape
+
+        shape = value_pool_shape(cache_blocks, kv_block, self.num_heads,
+                                 self.head_dim)
+        out = []
+        for i in range(self.num_layers):
+            out += [(f"layer{i}_kpool", shape, dtype, 0),
+                    (f"layer{i}_vpool", shape, dtype, 0)]
+            if _kv_quant(kv_dtype):
+                scale = shape[:2] + (self.num_heads,)
+                out += [(f"layer{i}_kscale", scale, "float32", 1),
+                        (f"layer{i}_vscale", scale, "float32", 1)]
+        return out
+
+    def symbol(self, which, kv_block=16, kv_dtype="fp32", lora=None):
+        kw = dict(vocab_size=self.vocab_size, num_layers=self.num_layers,
+                  num_heads=self.num_heads, d_model=self.d_model,
+                  d_ff=self.d_ff, kv_block=kv_block, kv_dtype=kv_dtype,
+                  lora=lora)
+        if which in ("prefill", "decode"):
+            kw["paged"] = True
+        return self._BUILDERS[which](**kw)

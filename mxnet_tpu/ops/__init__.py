@@ -23,5 +23,6 @@ from . import spatial  # noqa: F401
 from . import optimizer_op  # noqa: F401
 from . import attention  # noqa: F401
 from . import adapter  # noqa: F401
+from . import hybrid  # noqa: F401
 
 __all__ = ["OpContext", "OpDef", "get_op", "invoke", "list_ops", "register"]

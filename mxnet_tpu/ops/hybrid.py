@@ -1,0 +1,517 @@
+"""Ops of the hybrid language-model family (``models/hybrid_lm.py``):
+RMS normalisation, grouped-query attention over the paged K/V cache,
+the KDA linear-attention layer (short convolution with a carried tail,
+the gated delta rule with a per-channel decay) over per-stream state
+SLOTS, and the routed-expert feed-forward layer that is told which
+experts it holds.
+
+Two kinds of per-stream state live side by side in a serving program:
+K/V PAGES (``kv_cache.value_pool_shape``, addressed through a block
+table; attention layers) and SLOTS (``kv_cache.state_pool_shape`` /
+``conv_tail_shape``, one row per live stream, row 0 scratch; KDA
+layers).  Every op here that touches a pool takes it in and hands it
+back, so that a jitted step donates it and updates in place.
+
+Forward only: training this family fits no chip the benchmark has, so no
+op here defines a gradient of its kernels (the lax fallbacks
+differentiate as any ``jax.numpy`` does).
+"""
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ..base import MXNetError, attr_bool, attr_float, attr_int
+from .registry import register
+
+HI = lax.Precision.HIGHEST
+
+
+# ---------------------------------------------------------------------------
+# RMSNorm
+# ---------------------------------------------------------------------------
+
+def rms_norm(x, gamma, eps, groups=1):
+    """``x * rsqrt(mean(x^2) + eps) * gamma`` over the last axis, or —
+    ``groups`` > 1 — over each of its ``groups`` equal spans (a head's
+    lanes), ``gamma`` one span long.  Float32 inside."""
+    shape = x.shape
+    xf = x.astype(jnp.float32)
+    if groups > 1:
+        xf = xf.reshape(shape[:-1] + (groups, shape[-1] // groups))
+    y = xf * lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    y = y * gamma.astype(jnp.float32)
+    return y.reshape(shape).astype(x.dtype)
+
+
+def _rms_infer(attrs, in_shapes):
+    d = in_shapes[0]
+    if d is None:
+        return in_shapes, [None], []
+    g = attr_int(attrs.get("num_groups", 1), 1)
+    return [tuple(d), (d[-1] // g,)], [tuple(d)], []
+
+
+@register("RMSNorm", arg_names=("data", "gamma"), infer_shape=_rms_infer,
+          doc="Root-mean-square normalisation over the last axis (no "
+              "mean, no bias), float32 inside; attrs: eps (1e-5), "
+              "num_groups (1; > 1 normalises each of that many equal "
+              "spans of the last axis with one gamma of a span's length)")
+def _rms_norm(op_ctx, attrs, inputs, aux):
+    x, gamma = inputs
+    return [rms_norm(x, gamma, attr_float(attrs.get("eps", 1e-5), 1e-5),
+                     attr_int(attrs.get("num_groups", 1), 1))]
+
+
+@register("GatedRMSNorm", arg_names=("data", "gate", "gamma"),
+          infer_shape=lambda attrs, s: (
+              [s[0], s[0], None if s[0] is None else (
+                  s[0][-1] // attr_int(attrs.get("num_groups", 1), 1),)],
+              [s[0]], []),
+          doc="RMSNorm(data) * sigmoid(gate): a KDA layer's output norm "
+              "per head under its gate; attrs as RMSNorm")
+def _gated_rms_norm(op_ctx, attrs, inputs, aux):
+    x, gate, gamma = inputs
+    y = rms_norm(x.astype(jnp.float32), gamma,
+                 attr_float(attrs.get("eps", 1e-5), 1e-5),
+                 attr_int(attrs.get("num_groups", 1), 1))
+    return [(y * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)]
+
+
+# ---------------------------------------------------------------------------
+# Grouped-query attention over the paged cache
+# ---------------------------------------------------------------------------
+
+def _repeat_heads(x, H, Hkv):
+    """(..., Hkv·D) -> (..., H, D): KV head j serves the query heads
+    j·G .. j·G + G - 1."""
+    D = x.shape[-1] // Hkv
+    x = x.reshape(x.shape[:-1] + (Hkv, D))
+    return jnp.repeat(x, H // Hkv, axis=-2)
+
+
+def _gqa_heads(attrs, q, k):
+    H = attr_int(attrs.get("num_heads", 1), 1)
+    Hkv = attr_int(attrs.get("kv_heads", H), H)
+    if H % Hkv or q.shape[-1] % H or \
+            q.shape[-1] // H != k.shape[-1] // Hkv:
+        raise MXNetError(
+            f"grouped-query attention: query rows {q.shape[-1]} over "
+            f"{H} heads and K/V rows {k.shape[-1]} over {Hkv} heads do "
+            f"not share a head size, or {Hkv} does not divide {H}")
+    return H, Hkv
+
+
+def _gqa_infer(attrs, in_shapes):
+    q, kp = in_shapes[0], in_shapes[3]
+    if q is None or kp is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(q), tuple(kp), tuple(kp)], []
+
+
+_GQA_ARGS = ("query", "key", "value", "k_pool", "v_pool", "block_table",
+             "lengths")
+_GQA_OUTS = ("output", "new_k_pool", "new_v_pool")
+
+
+@register("GQAPrefillAttention", arg_names=_GQA_ARGS, out_names=_GQA_OUTS,
+          infer_shape=_gqa_infer,
+          doc="Causal grouped-query attention over a (padded) prompt "
+              "that also writes its K/V rows into the paged pools: "
+              "query (B, T, H*D), key/value (B, T, Hkv*D), pools "
+              "(P, KVB, Hkv*D) -> output (B, T, H*D) + pools.  Query "
+              "head i reads KV head i // (H / Hkv); no rotation.  "
+              "attrs: num_heads, kv_heads")
+def _gqa_prefill(op_ctx, attrs, inputs, aux):
+    from .attention import blockwise_attention, paged_prefill_write
+
+    q, k, v, k_pool, v_pool, table, lengths = inputs
+    H, Hkv = _gqa_heads(attrs, q, k)
+    B, T, HD = q.shape
+    out = blockwise_attention(
+        q.reshape(B, T, H, HD // H), _repeat_heads(k, H, Hkv),
+        _repeat_heads(v, H, Hkv), causal=True)
+    pools = paged_prefill_write(k, v, k_pool, v_pool,
+                                table.astype(jnp.int32),
+                                lengths.astype(jnp.int32))
+    return [out.reshape(B, T, HD), pools[0], pools[1]]
+
+
+@register("GQAPagedDecode", arg_names=_GQA_ARGS, out_names=_GQA_OUTS,
+          infer_shape=_gqa_infer,
+          doc="One decode step of grouped-query attention over the "
+              "paged cache: query (B, 1, H*D), key/value (B, 1, Hkv*D) "
+              "of the current token, pools (P, KVB, Hkv*D), lengths "
+              "counting the token -> output (B, 1, H*D) + pools.  The "
+              "paged kernel with query row i on KV span i // (H / Hkv) "
+              "on TPU, a lax gather elsewhere.  attrs: num_heads, "
+              "kv_heads")
+def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
+    from . import pallas_kernels as pk
+    from .attention import decode_attention, paged_cache_update
+
+    q, k, v, k_pool, v_pool, table, lengths = inputs
+    H, Hkv = _gqa_heads(attrs, q, k)
+    if q.shape[1] != 1:
+        raise MXNetError(f"GQAPagedDecode feeds ONE position a step; "
+                         f"got query {tuple(q.shape)}")
+    lengths = lengths.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    kp, vp = paged_cache_update(k_pool, v_pool, k, v, table, lengths)
+    if pk.enabled():
+        out = pk._paged_attention(q, kp, vp, (), table, lengths - 1, H,
+                                  kv_heads=Hkv)
+        return [out, kp, vp]
+    B, MB = table.shape
+    KVB = kp.shape[1]
+    kg = _repeat_heads(kp[table].reshape(B, MB * KVB, -1), H, Hkv)
+    vg = _repeat_heads(vp[table].reshape(B, MB * KVB, -1), H, Hkv)
+    out = decode_attention(q.reshape(B, 1, H, -1), kg, vg, lengths, KVB)
+    return [out.reshape(q.shape), kp, vp]
+
+
+# ---------------------------------------------------------------------------
+# ShortConv: depthwise causal convolution with a carried tail
+# ---------------------------------------------------------------------------
+
+def short_conv(x, w, left):
+    """y[t, c] = SiLU(sum_j w[c, j] * xp[t + j, c]) with ``xp`` = the
+    ``K - 1`` rows of ``left`` before ``x``: tap ``K - 1`` is on the
+    current token.  x (B, S, C); w (C, K); left (B, K - 1, C).  Float32
+    inside."""
+    K = w.shape[1]
+    xp = jnp.concatenate([left.astype(jnp.float32),
+                          x.astype(jnp.float32)], axis=1)
+    S = x.shape[1]
+    wf = w.astype(jnp.float32)
+    y = sum(xp[:, j:j + S] * wf[:, j] for j in range(K))
+    return jax.nn.silu(y), xp
+
+
+def _conv_infer(attrs, in_shapes):
+    d, tail = in_shapes[0], in_shapes[2]
+    if d is None or tail is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(d), tuple(tail)], []
+
+
+@register("ShortConv",
+          arg_names=("data", "weight", "tail_pool", "slots", "lengths"),
+          out_names=("output", "new_tail_pool"), infer_shape=_conv_infer,
+          doc="Depthwise causal convolution of kernel K with SiLU, its "
+              "last K - 1 inputs carried per stream: data (B, S, C), "
+              "weight (C, K), tail_pool (slots, 8, W) float32 "
+              "(kv_cache.conv_tail_shape: a slot's rows back to back, "
+              "in whole tiles), "
+              "slots (B,) int32 (0 = scratch).  step=0 (prefill): the "
+              "sequence starts from nothing and the K - 1 inputs before "
+              "position lengths[b] are written to the slot; step=1 "
+              "(decode, S = 1): the slot's tail precedes the token and "
+              "is shifted by it.  -> output (B, S, C), the pool")
+def _short_conv(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    x, w, pool, slots, lengths = inputs
+    step = attr_bool(attrs.get("step", False), False)
+    K = w.shape[1]
+    slots = slots.astype(jnp.int32)
+    B, S, C = x.shape
+    run = (K - 1) * C               # a slot's numbers, then padding
+    if step:
+        left = pool[slots].reshape(B, -1)[:, :run].reshape(B, K - 1, C)
+        y, xp = short_conv(x, w, left)
+        tail = xp[:, 1:]
+    else:
+        y, xp = short_conv(x, w, jnp.zeros((B, K - 1, C), jnp.float32))
+        n = lengths.astype(jnp.int32)
+        tail = jax.vmap(lambda row, at: lax.dynamic_slice_in_dim(
+            row, at, K - 1, axis=0))(xp, n)
+    rows = jnp.pad(tail.reshape(B, run).astype(pool.dtype),
+                   ((0, 0), (0, pool.shape[1] * pool.shape[2] - run)))
+    rows = rows.reshape((B,) + pool.shape[1:])
+    if pk.enabled():
+        return [y.astype(x.dtype), ph.slot_rows_write(pool, rows, slots)]
+    return [y.astype(x.dtype), pool.at[slots].set(rows)]
+
+
+# ---------------------------------------------------------------------------
+# KDA: the gated delta rule with a per-channel decay
+# ---------------------------------------------------------------------------
+
+def kda_gates(a_raw, b_raw, a_log, dt_bias, H, neg_eigval):
+    """(alpha, beta): the per-channel decay
+    ``exp(-exp(A_h) * softplus(a_raw + dt_bias))`` in (0, 1), shaped
+    (..., H, D), and the step ``sigmoid(b_raw)`` (doubled where negative
+    eigenvalues are allowed: (0, 2)), shaped (..., H).  Float32."""
+    a = a_raw.astype(jnp.float32) + dt_bias.astype(jnp.float32)
+    a = a.reshape(a.shape[:-1] + (H, a.shape[-1] // H))
+    g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(a)
+    beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
+    return jnp.exp(g), (2.0 * beta if neg_eigval else beta)
+
+
+def kda_qkv(c, H):
+    """The conv's output (..., 3·H·D) as q, k, v (..., H, D) float32:
+    q and k L2-normalised per head, q scaled by D^-1/2."""
+    D = c.shape[-1] // (3 * H)
+    q, k, v = (t.reshape(t.shape[:-1] + (H, D)).astype(jnp.float32)
+               for t in jnp.split(c, 3, axis=-1))
+
+    def unit(t):
+        return t * lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * (float(D) ** -0.5), unit(k), v
+
+
+def kda_scan(q, k, v, alpha, beta, state):
+    """The recurrence token by token (``lax.scan``), from ``state``.
+    q, k, v, alpha (B, T, H, D), beta (B, T, H); state (B, H, D, D)
+    float32, head states transposed (``pallas_hybrid``'s module doc).
+    -> (o (B, T, H, D), the last state)."""
+    def one(st, xs):
+        qt, kt, vt, at, bt = xs                      # (B, H, D) / (B, H)
+        st = st * at[:, :, None, :]
+        u = jnp.sum(st * kt[:, :, None, :], axis=-1)
+        st = st + (bt[..., None] * (vt - u))[..., None] * kt[:, :, None, :]
+        return st, jnp.sum(st * qt[:, :, None, :], axis=-1)
+
+    xs = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, alpha, beta))
+    state, o = lax.scan(one, state, xs)
+    return jnp.moveaxis(o, 0, 1), state
+
+
+def _kda_infer(attrs, in_shapes):
+    c, pool = in_shapes[0], in_shapes[5]
+    if c is None or pool is None:
+        return in_shapes, None, None
+    return in_shapes, [(c[0], c[1], c[2] // 3), tuple(pool)], []
+
+
+_KDA_ARGS = ("qkv", "decay", "beta", "a_log", "dt_bias", "state_pool",
+             "slots", "lengths")
+_KDA_OUTS = ("output", "new_state_pool")
+_KDA_DOC = (
+    "qkv (B, S, 3*H*D): the short convolution's output; decay "
+    "(B, S, H*D) and beta (B, S, H): the raw gate projections; a_log "
+    "(H,), dt_bias (H*D,); state_pool (slots, H, D, D) float32, a "
+    "head's state transposed; slots (B,) int32 (0 = scratch) -> output "
+    "(B, S, H*D) + the pool.  S_t = (I - beta k k^T) Diag(alpha) "
+    "S_{t-1} + beta k v^T, o_t = S_t^T q_t, q and k L2-normalised, "
+    "alpha = exp(-exp(a_log) softplus(decay + dt_bias)) per channel, "
+    "beta = sigmoid (x 2 with neg_eigval).  attrs: num_heads, "
+    "neg_eigval")
+
+
+def _kda_inputs(attrs, inputs):
+    c, a_raw, b_raw, a_log, dt_bias, pool, slots, lengths = inputs
+    H = attr_int(attrs.get("num_heads", 1), 1)
+    neg = attr_bool(attrs.get("neg_eigval", False), False)
+    q, k, v = kda_qkv(c, H)
+    alpha, beta = kda_gates(a_raw, b_raw, a_log, dt_bias, H, neg)
+    return (q, k, v, alpha, beta, pool, slots.astype(jnp.int32),
+            lengths.astype(jnp.int32), c)
+
+
+@register("KDAChunk", arg_names=_KDA_ARGS, out_names=_KDA_OUTS,
+          infer_shape=_kda_infer,
+          doc="KDA over a (padded) prompt from the zero state; the state "
+              "after position lengths[b] - 1 is written to the slot.  "
+              + _KDA_DOC)
+def _kda_chunk(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    q, k, v, alpha, beta, pool, slots, n, c = _kda_inputs(attrs, inputs)
+    B, T, H, D = q.shape
+    # a padded position leaves the state as it is: decay 1, step 0
+    live = jnp.arange(T)[None, :] < n[:, None]
+    alpha = jnp.where(live[..., None, None], alpha, 1.0)
+    beta = jnp.where(live[..., None], beta, 0.0)
+    if pk.enabled():
+        def rows(t):                                  # -> (B·H, T, D)
+            return jnp.transpose(t, (0, 2, 1, 3)).reshape(B * H, T, D)
+
+        o, last = ph.kda_chunk(
+            rows(q), rows(k), rows(alpha),
+            jnp.transpose(v, (0, 2, 3, 1)).reshape(B * H, D, T),
+            jnp.transpose(beta, (0, 2, 1)).reshape(B * H, 1, T))
+        o = jnp.transpose(o.reshape(B, H, D, T), (0, 3, 1, 2))
+        last = last.reshape(B, H, D, D)
+    else:
+        o, last = kda_scan(q, k, v, alpha, beta,
+                           jnp.zeros((B, H, D, D), jnp.float32))
+    return [o.reshape(B, T, H * D).astype(c.dtype),
+            pool.at[slots].set(last.astype(pool.dtype))]
+
+
+@register("KDAStep", arg_names=_KDA_ARGS, out_names=_KDA_OUTS,
+          infer_shape=_kda_infer,
+          doc="KDA for ONE token per stream against the slot's state, "
+              "updated in place (S = 1; a padded row sits on slot 0).  "
+              + _KDA_DOC)
+def _kda_step(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    q, k, v, alpha, beta, pool, slots, _, c = _kda_inputs(attrs, inputs)
+    B, S, H, D = q.shape
+    if S != 1:
+        raise MXNetError(f"KDAStep feeds ONE position a step; got qkv "
+                         f"{tuple(c.shape)}")
+    if pk.enabled():
+        o, pool = ph.kda_step(
+            q[:, 0], k[:, 0], alpha[:, 0], v[:, 0],
+            jnp.broadcast_to(beta[:, 0, :, None], (B, H, D)),
+            pool.astype(jnp.float32), slots)
+        o = o[:, None]
+    else:
+        o, st = kda_scan(q, k, v, alpha, beta,
+                         pool[slots].astype(jnp.float32))
+        pool = pool.at[slots].set(st.astype(pool.dtype))
+    return [o.reshape(B, 1, H * D).astype(c.dtype), pool]
+
+
+# ---------------------------------------------------------------------------
+# MoEFFN: routed experts, the share held here
+# ---------------------------------------------------------------------------
+
+MOE_COUNTERS = ("moe_pairs_here", "moe_pairs_elsewhere", "moe_experts_hit",
+                "moe_load_max")
+
+
+def moe_route(x2, router_w, top_k):
+    """Scores, the ``top_k`` experts of each token and their normalised
+    weights, all float32: sigmoid scores over ALL experts, weights
+    ``s_e / sum_top s``.  x2 (N, d); router_w (E, d)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x2.astype(jnp.float32), router_w.astype(jnp.float32).T,
+        precision=HI))
+    topv, topi = lax.top_k(scores, top_k)
+    return topi, topv / jnp.sum(topv, axis=-1, keepdims=True)
+
+
+def _tile_rows(n_pairs):
+    """Rows of a tile of the grouped matmul: a decode batch's few rows
+    an expert want small tiles, a prompt's want the MXU's."""
+    return 128 if n_pairs >= 4096 else 16
+
+
+def moe_dispatch(topi, valid, first, held, tm):
+    """Lay the token-expert pairs whose expert is held here
+    (``first <= e < first + held``, token valid) out in rows sorted by
+    expert, each expert's run padded to whole tiles of ``tm``.
+
+    Returns ``here`` (N, k) bool; ``pair_row`` (N, k) the row of each
+    pair (meaningless where not ``here``); ``row_token`` (M,) the token
+    a row holds; ``tile_expert`` (M // tm,); ``n_used`` (1,) tiles that
+    hold rows; ``sizes`` (held,) pairs per expert.  M covers every pair
+    landing here: nothing is dropped."""
+    N, k = topi.shape
+    local = topi - first
+    here = (local >= 0) & (local < held) & valid[:, None]
+    key = jnp.where(here, local, held).reshape(-1)
+    P = N * k
+    M = -(-N * min(k, held) // tm) * tm + held * tm
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    padded = -(-sizes[:held] // tm) * tm
+    ends = jnp.cumsum(padded)
+    starts = jnp.concatenate([ends - padded, jnp.zeros((1,), jnp.int32)])
+    plain = jnp.cumsum(sizes) - sizes
+    order = jnp.argsort(key, stable=True)
+    skey = key[order]
+    row = jnp.where(skey < held,
+                    starts[skey] + jnp.arange(P) - plain[skey], M)
+    pair_row = jnp.zeros((P,), jnp.int32).at[order].set(row)
+    row_token = jnp.zeros((M,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    # the expert whose padded run holds a tile's first row: the runs
+    # that END at or before it (a comparison, not a search: a search is
+    # a loop of tiny programs on the chip)
+    first_row = jnp.arange(M // tm, dtype=jnp.int32) * tm
+    tile_expert = jnp.minimum(
+        jnp.sum((ends[None, :] <= first_row[:, None]).astype(jnp.int32),
+                axis=1), held - 1)
+    n_used = (ends[-1:] // tm).astype(jnp.int32)
+    return (here, pair_row.reshape(N, k), row_token, tile_expert, n_used,
+            sizes[:held])
+
+
+def moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert, n_used,
+                tm):
+    """The held experts on the dispatched rows -> (M, d) float32 (rows
+    of unused tiles hold anything)."""
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    xs = x2[row_token]
+    if pk.enabled():
+        h = ph.moe_gmm_gate_up(xs, w_gate, w_up, tile_expert, n_used, tm)
+        return ph.moe_gmm_down(h, w_down, tile_expert, n_used, tm)
+    e = jnp.repeat(tile_expert, tm)
+
+    def mm(a, w):
+        return jnp.einsum("mk,mkn->mn", a, w[e],
+                          preferred_element_type=jnp.float32)
+
+    h = (jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
+    return mm(h, w_down)
+
+
+def _moe_infer(attrs, in_shapes):
+    d = in_shapes[0]
+    if d is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(d), (len(MOE_COUNTERS),)], []
+
+
+@register("MoEFFN",
+          arg_names=("data", "router_weight", "gate_weight", "up_weight",
+                     "down_weight", "lengths", "counters"),
+          out_names=("output", "new_counters"), infer_shape=_moe_infer,
+          doc="The routed experts' part of a mixture-of-experts layer, "
+              "for the experts HELD here: data (B, S, d); router_weight "
+              "(experts, d) float32 over ALL experts; gate/up_weight "
+              "(held, d, w), down_weight (held, w, d): experts "
+              "first_expert .. first_expert + held - 1.  Sigmoid scores, "
+              "the top_k largest, weights s_e / sum_top s (float32); "
+              "output = sum over a token's chosen experts that are held "
+              "here of w_e E_e(x), E_e = W_down (SiLU(W_gate x) * W_up "
+              "x).  What the other experts would add belongs to other "
+              "chips and is left out.  No pair is dropped.  lengths "
+              "(B,) masks padding (step=1: rows with lengths 0; step=0: "
+              "positions >= lengths).  counters (4,) int32 — pairs "
+              "computed here, pairs left elsewhere, held experts hit, "
+              "the largest expert's load — is added to where count=1.  "
+              "attrs: top_k, first_expert, step, count")
+def _moe_ffn(op_ctx, attrs, inputs, aux):
+    x, router_w, w_gate, w_up, w_down, lengths, counters = inputs
+    top_k = attr_int(attrs.get("top_k", 1), 1)
+    first = attr_int(attrs.get("first_expert", 0), 0)
+    step = attr_bool(attrs.get("step", False), False)
+    count = attr_bool(attrs.get("count", False), False)
+    B, S, d = x.shape
+    held = w_gate.shape[0]
+    if first < 0 or first + held > router_w.shape[0]:
+        raise MXNetError(
+            f"MoEFFN holds experts {first}..{first + held - 1} of the "
+            f"{router_w.shape[0]} the router scores")
+    n = lengths.astype(jnp.int32)
+    valid = (jnp.broadcast_to(n[:, None] > 0, (B, S)) if step
+             else jnp.arange(S)[None, :] < n[:, None]).reshape(-1)
+    x2 = x.reshape(B * S, d)
+    topi, wts = moe_route(x2, router_w, top_k)
+    tm = _tile_rows(B * S * min(top_k, held))
+    here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
+        topi, valid, first, held, tm)
+    ys = moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert,
+                     n_used, tm)
+    got = ys[jnp.minimum(pair_row, ys.shape[0] - 1)]       # (N, k, d)
+    y = jnp.sum(jnp.where(here[..., None], got * wts[..., None], 0.0),
+                axis=1)
+    if count:
+        pairs = jnp.sum(here.astype(jnp.int32))
+        counters = counters + jnp.stack([
+            pairs, jnp.sum(valid.astype(jnp.int32)) * top_k - pairs,
+            jnp.sum((sizes > 0).astype(jnp.int32)), jnp.max(sizes)])
+    return [y.reshape(B, S, d).astype(x.dtype), counters]

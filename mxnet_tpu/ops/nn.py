@@ -111,6 +111,8 @@ def _activation(op_ctx, attrs, inputs, aux):
         return [jax.nn.softplus(x)]
     if act == "softsign":
         return [jax.nn.soft_sign(x)]
+    if act == "silu":
+        return [jax.nn.silu(x)]
     if act == "gelu":
         # MXNet 1.x exposes GELU via LeakyReLU(act_type='gelu')
         # (leaky_relu-inl.h kGELU, erf formulation); accepted here too

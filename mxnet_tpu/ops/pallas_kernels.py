@@ -1369,7 +1369,7 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
 
 
 def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, kvb, nb, w, h, d, hp, quant):
+                  scale, kvb, nb, w, h, d, hp, quant, g=1):
     if quant:
         ks_ref, vs_ref, o_ref, qx_scr, acc_scr, m_scr, l_scr = rest
     else:
@@ -1385,13 +1385,30 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
         row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
         return (lane >= row * d) & (lane < (row + 1) * d)
 
+    def group_span(rows):
+        # grouped queries (g > 1): row r is query head r % hp, on the
+        # lane span of KV head (r % hp) // g
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+        head = row if w == 1 else row % hp
+        kv = sum((head >= i * g).astype(jnp.int32) for i in range(1, h))
+        return (lane >= kv * d) & (lane < (kv + 1) * d)
+
     @pl.when(j == 0)
     def _init():
-        span = head_span(hp)
-        q = q_ref[0].astype(jnp.float32)              # (W, H·D)
-        for i in range(w):
-            qx_scr[i * hp:(i + 1) * hp, :] = jnp.where(
-                span, q[i:i + 1, :], 0.0).astype(qx_scr.dtype)
+        if g > 1:
+            # q arrives as (W·Hq, D) rows, one query head each: every
+            # row is repeated over the KV spans and kept on its own
+            q = q_ref[0].astype(jnp.float32)
+            qx_scr[...] = jnp.where(
+                group_span(w * hp), jnp.concatenate([q] * h, axis=1),
+                0.0).astype(qx_scr.dtype)
+        else:
+            span = head_span(hp)
+            q = q_ref[0].astype(jnp.float32)              # (W, H·D)
+            for i in range(w):
+                qx_scr[i * hp:(i + 1) * hp, :] = jnp.where(
+                    span, q[i:i + 1, :], 0.0).astype(qx_scr.dtype)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -1450,6 +1467,13 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j == nb - 1)
     def _finish():
+        if g > 1:
+            # row r keeps the D lanes of its KV span
+            out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+            keep = jnp.where(group_span(w * hp), out, 0.0)
+            o_ref[0] = sum(keep[:, i * d:(i + 1) * d]
+                           for i in range(h)).astype(o_ref.dtype)
+            return
         span = head_span(hp)
         out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         rows = [jnp.sum(jnp.where(span, out[i * hp:(i + 1) * hp, :], 0.0),
@@ -1459,10 +1483,16 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
-                     num_heads):
+                     num_heads, kv_heads=None):
     """q (B, W, H·D) at absolute positions ``start[b] + i``; pools
     (P, KVB, H·D); scales is () or (k_scale, v_scale), each
-    (P, KVB, H) float32."""
+    (P, KVB, H) float32.  ``kv_heads`` < ``num_heads``: grouped
+    queries, pools (P, KVB, kv_heads·D), query head i on KV head
+    ``i // (num_heads // kv_heads)``."""
+    if kv_heads is not None and int(kv_heads) != int(num_heads):
+        return _paged_attention_grouped(q, k_pool, v_pool, scales,
+                                        block_table, start,
+                                        int(num_heads), int(kv_heads))
     B, W, HD = q.shape
     H = int(num_heads)
     D = HD // H
@@ -1520,6 +1550,66 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
         name="paged_attention_q" if scales else "paged_attention",
     )(block_table.astype(jnp.int32), start.astype(jnp.int32),
       q, k_pool, v_pool, *scales)
+
+
+def _paged_attention_grouped(q, k_pool, v_pool, scales, block_table,
+                             start, Hq, Hkv):
+    """The paged kernel with ``Hq`` query heads over ``Hkv`` KV heads:
+    the same grid, pages and online-softmax state; the spread query has
+    a row per QUERY head on its KV head's span of the (Hkv·D)-lane page
+    rows (W·Hq rows of Hkv·D lanes), so one matmul a page still gives
+    every head's scores.  q enters and leaves as (B, W·Hq, D) rows —
+    the (B, W, Hq·D) activation seen through a free reshape."""
+    B, W, HD = q.shape
+    D = HD // Hq
+    KVB = k_pool.shape[1]
+    MB = block_table.shape[1]
+    KD = Hkv * D
+    if scales or Hq % Hkv or Hq % 16 or k_pool.shape[2] != KD:
+        raise MXNetError(
+            f"paged_attention: {Hq} query heads over {Hkv} KV heads x "
+            f"{D} wants unquantized (P, KVB, {KD}) pools, {Hkv} | {Hq} "
+            f"and whole sublane tiles of query heads ({Hq} % 16 == 0); "
+            f"got pools {tuple(k_pool.shape)}, scales {len(scales)}")
+    rows_q = W * Hq
+    vmem = (rows_q * KD * (q.dtype.itemsize + 4 + 4)
+            + 2 * KVB * KD * 2 * k_pool.dtype.itemsize)
+    if vmem > _PAGED_VMEM_BUDGET:
+        raise MXNetError(
+            f"paged_attention: {Hq} query heads over {Hkv} x {D} with a "
+            f"{W}-row window needs about {vmem >> 20} MB of VMEM, more "
+            f"than the {_PAGED_VMEM_BUDGET >> 20} MB it may count on")
+    kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
+                             kvb=KVB, nb=MB, w=W, h=Hkv, d=D, hp=Hq,
+                             quant=False, g=Hq // Hkv)
+
+    def page():
+        return _vmem_spec((1, KVB, KD),
+                          lambda b, j, tr, sr: (tr[b, j], 0, 0))
+
+    def rows():
+        return _vmem_spec((1, rows_q, D), lambda b, j, tr, sr: (b, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, MB),
+        in_specs=[rows(), page(), page()],
+        out_specs=rows(),
+        scratch_shapes=[pltpu.VMEM((rows_q, KD), q.dtype),
+                        pltpu.VMEM((rows_q, KD), jnp.float32),
+                        pltpu.VMEM((rows_q, 128), jnp.float32),
+                        pltpu.VMEM((rows_q, 128), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, rows_q, D), q.dtype),
+        compiler_params=_compiler_params("parallel", "arbitrary"),
+        interpret=_interpret(),
+        name="paged_attention",
+    )(block_table.astype(jnp.int32), start.astype(jnp.int32),
+      q.reshape(B, rows_q, D), k_pool, v_pool)
+    return out.reshape(B, W, HD)
 
 
 def paged_attention_decode(q, k_pool, v_pool, block_table, lengths,

@@ -1388,8 +1388,11 @@ def register_statusz(name: str, fn):
     _statusz_providers[str(name)] = fn
 
 
-def unregister_statusz(name: str):
-    _statusz_providers.pop(str(name), None)
+def unregister_statusz(name: str, fn=None):
+    """Drop a section; with ``fn``, only if it is still that provider's
+    (a closed engine must not take down its successor's section)."""
+    if fn is None or _statusz_providers.get(str(name)) == fn:
+        _statusz_providers.pop(str(name), None)
 
 
 def statusz() -> dict:

@@ -920,7 +920,7 @@ class _Stream:
                  "resume", "t_submit", "t_admit", "trace", "t_enqueue",
                  "cached_len", "await_first", "t_chunk0", "slo_class",
                  "canary", "cost", "migrate", "tenant", "adapter",
-                 "adapter_bucket", "adapter_slot")
+                 "adapter_bucket", "adapter_slot", "slot", "keep_state")
 
     def __init__(self, sid, prompt, max_new, temp, eos, future, seed,
                  trace=None, slo_class="interactive", canary=False,
@@ -951,6 +951,8 @@ class _Stream:
         self.adapter = adapter        # published adapter name | None
         self.adapter_bucket = None    # rank bucket (set on acquire)
         self.adapter_slot = None      # pool slot id (set on acquire)
+        self.slot = 0                 # recurrent-state slot held (0: none)
+        self.keep_state = False       # resolve with the slot's state too
         self.cost = _slo.CostRecord(sid, slo_class, canary,
                                     tenant=tenant, adapter_id=adapter)
         self.cost.prompt_tokens = int(prompt.size)
@@ -1031,8 +1033,9 @@ class DecodeEngine:
         override via ``submit``.
     """
 
-    def __init__(self, params, *, vocab_size, num_layers, num_heads,
-                 d_model, d_ff=None, max_len=None, kv_block=None,
+    def __init__(self, params, *, vocab_size=None, num_layers=None,
+                 num_heads=None, d_model=None, d_ff=None, model=None,
+                 max_len=None, kv_block=None,
                  max_streams=None, cache_blocks=None,
                  decode_buckets=None, cache_buckets=None,
                  prefill_buckets=None, temperature=0.0, seed=0,
@@ -1045,17 +1048,36 @@ class DecodeEngine:
 
         from .kv_cache import (BlockAllocator, blocks_for_tokens,
                                bucket_ladder, kv_quantized,
-                               kv_storage_dtype, value_pool_shape)
+                               kv_storage_dtype)
         from .executor import build_graph_fn
-        from .models.transformer import (transformer_lm_decode,
-                                         transformer_lm_prefill,
-                                         transformer_lm_prefix_prefill,
-                                         transformer_lm_verify)
+        from .models.transformer import DenseSpec
         from .prefix_cache import EVICT_POLICIES, PrefixCache
-        from .kv_cache import KV_DTYPES
+        from .kv_cache import KV_DTYPES, SlotAllocator
         from .speculative import PROPOSERS, make_proposer
 
         self._blocks_for = blocks_for_tokens
+
+        # -- the model: a spec, or the dense keywords that build one ----
+        dense_kw = (vocab_size, num_layers, num_heads, d_model)
+        if model is None:
+            if None in dense_kw:
+                raise MXNetError(
+                    "DecodeEngine needs model=<spec> or all of "
+                    "vocab_size, num_layers, num_heads, d_model (the "
+                    "transformer_lm family)")
+            model = DenseSpec(vocab_size, num_layers, num_heads, d_model,
+                              d_ff)
+        elif any(v is not None for v in dense_kw + (d_ff,)):
+            raise MXNetError(
+                "give DecodeEngine model=<spec> OR the dense keywords "
+                "(vocab_size, num_layers, num_heads, d_model, d_ff), "
+                "not both")
+        self._spec = model
+        dense = isinstance(model, DenseSpec)
+        vocab_size, num_layers = model.vocab_size, model.num_layers
+        # the K/V page geometry (a spec without attention layers has
+        # none: 0 heads, and its pools hold no pages)
+        num_heads, d_model = model.kv_heads, model.d_model
 
         # -- prefix cache / KV storage configuration --------------------
         # (loud at-construction validation, the MXNET_CKPT_* pattern)
@@ -1066,6 +1088,9 @@ class DecodeEngine:
                 f"kv_dtype {self._kv_dtype!r} must be one of {KV_DTYPES}")
         self._quant = kv_quantized(self._kv_dtype)
         kv_store_dtype = kv_storage_dtype(self._kv_dtype)  # may raise
+        if prefix_cache is None and not dense \
+                and get_env("MXNET_SERVING_PREFIX_CACHE", None, str) is None:
+            prefix_cache = 0  # the catalog's default (on) is the dense LM's
         if prefix_cache is None:
             prefix_cache = _read_env_int("MXNET_SERVING_PREFIX_CACHE",
                                          lo=0)
@@ -1111,10 +1136,7 @@ class DecodeEngine:
         self._vocab = int(vocab_size)
         self._L = int(num_layers)
         self._H = int(num_heads)
-        if d_model % num_heads:
-            raise MXNetError(f"d_model {d_model} % num_heads "
-                             f"{num_heads} != 0")
-        self._D = int(d_model) // int(num_heads)
+        self._D = int(model.head_dim)
 
         self._kv_block = kv_block if kv_block is not None else \
             _read_env_int("MXNET_SERVING_KV_BLOCK")
@@ -1160,6 +1182,33 @@ class DecodeEngine:
                 f"num_layers {self._L} — pipeline stages hold equal "
                 f"layer slabs")
         n_mesh = self._tp * self._pp
+        if not dense:
+            # a family that brings its own symbols has only what it
+            # builds: every feature below needs a symbol, a pool or a
+            # state transfer this spec has none of — refused here, by
+            # name, none built and none failing silently
+            kinds = "/".join(sorted(set(model.mixer_kinds())))
+            slots = "slots" in model.cache_kinds()
+            asked = {
+                "prefix_cache": self._prefix_on,
+                "prefill_chunk": bool(self._chunk),
+                "spec_tokens": bool(self._spec_k),
+                f"kv_dtype={self._kv_dtype!r}": self._quant,
+                f"tp={self._tp}": self._tp > 1,
+                f"pp={self._pp}": self._pp > 1,
+                "adapters": bool(adapters),
+            }
+            for feature, on in asked.items():
+                if on:
+                    raise MXNetError(
+                        f"{feature} is not built for a model spec with "
+                        f"{kinds} layers"
+                        + (": a kda layer's per-stream state lives in "
+                           "a slot, which this feature would have to "
+                           "share, cut, roll back, quantize or shard"
+                           if slots else ""))
+            if adapters is None:
+                adapters = False  # (the env default is for the dense LM)
         if devices is None:
             devices = os.environ.get("MXNET_SERVING_DEVICES") or None
         if isinstance(devices, str):
@@ -1229,20 +1278,36 @@ class DecodeEngine:
         self._device = dev
 
         def to_dev(v):
+            if isinstance(v, jax.Array):
+                # already on a device: moved there (or kept, where it
+                # is) without a trip through the host and, on the same
+                # device, without a second copy of the weights
+                return jax.device_put(v, dev)
             arr = v.asnumpy() if hasattr(v, "asnumpy") else np.asarray(v)
             return jax.device_put(arr, dev)
 
         host_params = {k: v for k, v in params.items()}
-        if "pos_embed_weight" not in host_params:
-            raise MXNetError(
-                "params has no 'pos_embed_weight' — DecodeEngine serves "
-                "the transformer_lm family (models/transformer.py)")
-        pos_rows = int(host_params["pos_embed_weight"].shape[0])
-        self._max_len = int(max_len) if max_len is not None else pos_rows
-        if self._max_len > pos_rows:
-            raise MXNetError(
-                f"max_len {self._max_len} exceeds the model's learned "
-                f"positions ({pos_rows} pos_embed_weight rows)")
+        if not dense:
+            if max_len is None:
+                raise MXNetError(
+                    "a model spec without learned positions needs "
+                    "max_len")
+            self._max_len = int(max_len)
+        else:
+            if "pos_embed_weight" not in host_params:
+                raise MXNetError(
+                    "params has no 'pos_embed_weight' — the dense "
+                    "keywords serve the transformer_lm family "
+                    "(models/transformer.py); another family comes as "
+                    "model=<spec>")
+            pos_rows = int(host_params["pos_embed_weight"].shape[0])
+            self._max_len = int(max_len) if max_len is not None \
+                else pos_rows
+            if self._max_len > pos_rows:
+                raise MXNetError(
+                    f"max_len {self._max_len} exceeds the model's "
+                    f"learned positions ({pos_rows} pos_embed_weight "
+                    f"rows)")
 
         self._max_blocks_seq = blocks_for_tokens(self._max_len,
                                                  self._kv_block)
@@ -1348,30 +1413,35 @@ class DecodeEngine:
                 f"target must share a tokenizer")
 
         # -- graphs + pools ---------------------------------------------
-        kw = dict(vocab_size=vocab_size, num_layers=num_layers,
-                  num_heads=num_heads, d_model=d_model, d_ff=d_ff,
-                  kv_block=self._kv_block, paged=True,
-                  kv_dtype=self._kv_dtype, lora=self._lora)
-        dec_sym = transformer_lm_decode(**kw)
-        pre_sym = transformer_lm_prefill(**kw)
+        kw = dict(kv_block=self._kv_block, kv_dtype=self._kv_dtype,
+                  lora=self._lora)
+        dec_sym = model.symbol("decode", **kw)
         self._dec_gfn = build_graph_fn(dec_sym)
-        self._pre_gfn = build_graph_fn(pre_sym)
+        self._pre_gfn = build_graph_fn(model.symbol("prefill", **kw))
         self._pfx_gfn = None
-        pkw = dict(kw)
-        pkw.pop("paged")
         if self._prefix_on or self._chunk:
             # a chunk is a suffix-prefill continuation, so chunked
             # prefill needs this graph even with the prefix cache off
             self._pfx_gfn = build_graph_fn(
-                transformer_lm_prefix_prefill(**pkw))
-        self._ver_gfn = build_graph_fn(transformer_lm_verify(**pkw)) \
+                model.symbol("prefix_prefill", **kw))
+        self._ver_gfn = build_graph_fn(model.symbol("verify", **kw)) \
             if self._spec_k else None
-        feed = {"data", "positions", "lengths", "block_table", "start"}
-        feed |= {f"layer{i}_{t}pool" for i in range(self._L)
-                 for t in "kv"}
-        if self._quant:
-            feed |= {f"layer{i}_{t}scale" for i in range(self._L)
-                     for t in "kv"}
+        # per-stream state the programs carry, by graph-argument name,
+        # in the order the symbols hand it back: K/V pages for
+        # attention layers ([k, v] or, quantized, [k, v, k_scale,
+        # v_scale] a layer), slots (state, conv tail) for kda layers
+        n_slots = 1 + self._max_streams
+        layout = model.pools(int(cache_blocks), self._kv_block, n_slots,
+                             self._np_dtype, self._kv_dtype)
+        self._pool_names = tuple(n for n, _, _, _ in layout)
+        self._slot_alloc = SlotAllocator(self._max_streams) \
+            if "slots" in model.cache_kinds() else None
+        self._counters_at = self._pool_names.index("moe_counters") \
+            if "moe_counters" in self._pool_names else None
+        # the pools are donated to every program; stats() reads the
+        # counters among them from another thread, under this lock
+        self._pools_lock = threading.Lock()
+        feed = set(model.feeds) | set(self._pool_names)
         if self._lora:
             # adapter slabs + slot vectors are RUNTIME args (like the
             # pools), never baked params — publish stays drain-free
@@ -1397,22 +1467,27 @@ class DecodeEngine:
         if self._mesh is not None:
             self._pools = self._mesh.init_pools(int(cache_blocks))
         else:
-            pool_shape = value_pool_shape(cache_blocks, self._kv_block,
-                                          self._H, self._D)
-            pool_zero = np.zeros(pool_shape, self._np_dtype)
-            scale_one = np.ones(pool_shape[:2] + (self._H,), np.float32)
+            filled = {}  # one host array per (shape, dtype, fill)
             pools = []
-            for _ in range(self._L):
-                pools.append(jax.device_put(pool_zero, dev))
-                pools.append(jax.device_put(pool_zero, dev))
-                if self._quant:
-                    pools.append(jax.device_put(scale_one, dev))
-                    pools.append(jax.device_put(scale_one, dev))
+            for _, shape, dt, fill in layout:
+                key = (tuple(shape), np.dtype(dt).name, fill)
+                if key not in filled:
+                    filled[key] = np.full(shape, fill, np.dtype(dt)) \
+                        if fill else np.zeros(shape, np.dtype(dt))
+                pools.append(jax.device_put(filled[key], dev))
+            del filled
             self._pools = tuple(pools)
-        self._pool_bytes = sum(int(np.prod(np.shape(p)))
-                               * np.dtype(p.dtype).itemsize
-                               for p in self._pools)
+
+        sizes = [int(np.prod(np.shape(p))) * np.dtype(p.dtype).itemsize
+                 for p in self._pools]
+        # slot state (kda layers) apart from the K/V pages
+        self._state_pool_bytes = 0 if self._mesh is not None else sum(
+            b for n, b in zip(self._pool_names, sizes)
+            if n.endswith(("_state", "_tail")))
+        self._pool_bytes = sum(sizes) - self._state_pool_bytes
         profiler.set_gauge("serving.kv_pool_bytes", self._pool_bytes)
+        profiler.set_gauge("serving.state_pool_bytes",
+                           self._state_pool_bytes)
         self._cow_fn = None  # lazily-jitted copy-on-write page copy
 
         if donate is None:
@@ -1488,7 +1563,7 @@ class DecodeEngine:
                eos_id=None, seed=None, trace=None,
                slo_class="interactive", canary=False,
                prefill_only=False, tenant=None,
-               adapter=None) -> Future:
+               adapter=None, return_state=False) -> Future:
         """Enqueue one generation; the Future resolves to the np.int32
         array of generated token ids (eos, when hit, is included).
 
@@ -1516,7 +1591,16 @@ class DecodeEngine:
         ``trace``: optional :class:`profiler.TraceContext` — the
         stream's queue wait, prefill, and every decode-step batch it
         rides in become child spans of it (propagated over the fleet
-        wire; purely an observer)."""
+        wire; purely an observer).
+
+        ``return_state=True`` (a model with slot state only): the
+        Future resolves to ``{"tokens": ..., "state": {pool name:
+        array}}`` — beside the tokens, what the stream's slot holds in
+        every ``layer<i>_state`` pool when it retires, read off the
+        device before the slot is freed: per kda layer the (heads, d_v,
+        d_k) float32 state after prompt + all generated tokens but the
+        last (sampled, never fed).  For holding the engine to a
+        reference on the state itself, which logits hardly show."""
         _slo.check_class(slo_class)
         prompt = np.asarray(prompt)
         if prompt.ndim != 1 or prompt.size < 1:
@@ -1543,6 +1627,16 @@ class DecodeEngine:
             raise MXNetError(
                 f"request needs {need} cache blocks but the pool only "
                 f"has {self._alloc.capacity}")
+        if prefill_only and self._slot_alloc is not None:
+            raise MXNetError(
+                "prefill_only page export is not built for a model spec "
+                "with kda layers: a stream's state is pages AND a slot, "
+                "and only pages have a wire format")
+        if return_state and (self._slot_alloc is None or prefill_only):
+            raise MXNetError(
+                "return_state reads a stream's slot at retirement: the "
+                "model must have layers with slot state (kda), and the "
+                "stream must decode here (not prefill_only)")
         if prefill_only and self._mesh is not None:
             raise MXNetError(
                 "prefill_only export from a tp/pp-meshed engine is "
@@ -1584,6 +1678,7 @@ class DecodeEngine:
                             tenant=tenant, adapter=adapter)
                 s.adapter_bucket, s.adapter_slot = ad_bucket, ad_slot
                 s.migrate = bool(prefill_only)
+                s.keep_state = bool(return_state)
                 self._next_sid += 1
                 self._pending.append(s)
                 self._owned.add(fut)
@@ -1787,6 +1882,36 @@ class DecodeEngine:
             self._tenants.clear()
         if self._prefix is not None:
             self._prefix.reset_counters()
+        if self._counters_at is not None:
+            import jax
+
+            at = self._counters_at
+            with self._pools_lock:
+                zero = jax.device_put(np.zeros((4,), np.int32),
+                                      self._device)
+                self._pools = self._pools[:at] + (zero,) \
+                    + self._pools[at + 1:]
+
+    def _state_stats(self) -> dict:
+        """Slots and routing: the second kind of per-stream state, and
+        what the expert layers did with the decode steps' tokens.  The
+        routing counters live in a device array the decode step carries
+        and are read HERE only (under the lock the dispatch holds: the
+        array is donated to the next step)."""
+        from .ops.hybrid import MOE_COUNTERS
+
+        live = self._slot_alloc.live if self._slot_alloc is not None else 0
+        out = {"state_slots": self._slot_alloc.num_slots
+               if self._slot_alloc is not None else 0,
+               "state_slots_live": live,
+               "state_pool_bytes": self._state_pool_bytes}
+        if self._counters_at is None:
+            out.update({k: 0 for k in MOE_COUNTERS})
+        else:
+            with self._pools_lock:
+                c = np.asarray(self._pools[self._counters_at])
+            out.update({k: int(v) for k, v in zip(MOE_COUNTERS, c)})
+        return out
 
     def stats(self) -> dict:
         summ = self._metrics.summary()
@@ -1826,6 +1951,7 @@ class DecodeEngine:
         out["cache_blocks_cached"] = self._alloc.parked_blocks
         out["shared_blocks"] = self._alloc.shared_blocks
         out["kv_dtype"] = self._kv_dtype
+        out.update(self._state_stats())
         out["prefix_cache"] = int(self._prefix_on)
         if self._prefix is not None:
             out.update(self._prefix.stats())
@@ -1929,6 +2055,9 @@ class DecodeEngine:
             # clause poisons them at the step boundary instead.
             return
         self._fail_outstanding(EngineClosedError("DecodeEngine closed"))
+        # the /statusz section held the engine — weights and pools —
+        # alive after close; a closed engine has nothing to report
+        profiler.unregister_statusz("engine", self.stats)
 
     def __enter__(self):
         return self
@@ -1966,6 +2095,7 @@ class DecodeEngine:
             if s.blocks:
                 self._release_pages(s.blocks)
                 s.blocks = []
+            self._release_slot(s)
             self._release_adapter(s)
             if s.future.set_running_or_notify_cancel():
                 s.future.set_exception(exc)
@@ -2029,12 +2159,12 @@ class DecodeEngine:
             gkey = self._graph_key
 
             def step(params, tokens, positions, lengths, table, temps,
-                     seeds, steps, pools, *adapter):
+                     seeds, steps, pools, *extra):
                 args = dict(params)
                 args.update(data=tokens, positions=positions,
                             lengths=lengths, block_table=table)
                 self._pool_args(args, pools)
-                self._adapter_bind(args, adapter)
+                self._runtime_bind(args, extra)
                 outs, _ = gfn(args, {}, gkey, False)
                 toks = self._sample(outs[0][:, 0, :], temps, seeds,
                                     steps)
@@ -2053,7 +2183,7 @@ class DecodeEngine:
                      self._arg_spec((bb,), i32),
                      self._arg_spec((bb,), i32),
                      self._spec_of(self._pools)) \
-                + self._adapter_specs(bb)
+                + self._runtime_specs(bb)
             with profiler.scope(f"serving.compile.decode.b{bb}x{mb}",
                                 "serving", args={"batch": bb,
                                                  "blocks": mb}):
@@ -2090,13 +2220,13 @@ class DecodeEngine:
             base = self._base_key
 
             def step(params, tokens, positions, start, lengths, table,
-                     temps, seeds, steps0, pools, *adapter):
+                     temps, seeds, steps0, pools, *extra):
                 args = dict(params)
                 args.update(data=tokens, positions=positions,
                             start=start, lengths=lengths,
                             block_table=table)
                 self._pool_args(args, pools)
-                self._adapter_bind(args, adapter)
+                self._runtime_bind(args, extra)
                 outs, _ = gfn(args, {}, gkey, False)
                 emit = verify_sample(base, outs[0], tokens,
                                      lengths - start, temps, seeds,
@@ -2117,7 +2247,7 @@ class DecodeEngine:
                      self._arg_spec((bb,), i32),
                      self._arg_spec((bb,), i32),
                      self._spec_of(self._pools)) \
-                + self._adapter_specs(bb)
+                + self._runtime_specs(bb)
             with profiler.scope(
                     f"serving.compile.verify.b{bb}x{mb}w{W}",
                     "serving", args={"batch": bb, "blocks": mb,
@@ -2149,12 +2279,12 @@ class DecodeEngine:
             mb = tp // self._kv_block
 
             def prefill(params, tokens, positions, lengths, table,
-                        temps, seeds, steps, pools, *adapter):
+                        temps, seeds, steps, pools, *extra):
                 args = dict(params)
                 args.update(data=tokens, positions=positions,
                             lengths=lengths, block_table=table)
                 self._pool_args(args, pools)
-                self._adapter_bind(args, adapter)
+                self._runtime_bind(args, extra)
                 outs, _ = gfn(args, {}, gkey, False)
                 logits = outs[0]          # (1, Tp, V)
                 last = logits[jnp.arange(logits.shape[0]),
@@ -2175,7 +2305,7 @@ class DecodeEngine:
                      self._arg_spec((1,), i32),
                      self._arg_spec((1,), i32),
                      self._spec_of(self._pools)) \
-                + self._adapter_specs(1)
+                + self._runtime_specs(1)
             with profiler.scope(f"serving.compile.prefill.t{tp}",
                                 "serving", args={"tokens": tp}):
                 jitted = jax.jit(
@@ -2188,16 +2318,35 @@ class DecodeEngine:
             return exe
 
     def _pool_args(self, args, pools):
-        """Bind the flat pools tuple into graph args — per-layer
-        stride 2 ([k, v]) or 4 ([k, v, k_scale, v_scale])."""
-        st = self._pool_stride
-        for i in range(self._L):
-            args[f"layer{i}_kpool"] = pools[st * i]
-            args[f"layer{i}_vpool"] = pools[st * i + 1]
-            if self._quant:
-                args[f"layer{i}_kscale"] = pools[st * i + 2]
-                args[f"layer{i}_vscale"] = pools[st * i + 3]
+        """Bind the flat pools tuple into graph args, by the names the
+        model's spec gave them (``spec.pools``)."""
+        args.update(zip(self._pool_names, pools))
         return args
+
+    # -- the runtime tail of every program: after the pools, the slot
+    # ids (a model whose spec feeds ``slots``), then the adapter args
+    def _runtime_bind(self, args, extra):
+        if self._slot_alloc is not None:
+            args["slots"], extra = extra[0], extra[1:]
+        return self._adapter_bind(args, extra)
+
+    def _runtime_specs(self, bb: int) -> tuple:
+        slots = (self._arg_spec((bb,), np.dtype(np.int32)),) \
+            if self._slot_alloc is not None else ()
+        return slots + self._adapter_specs(bb)
+
+    def _runtime_args(self, streams, bb: int) -> tuple:
+        """The slot each row's stream holds (pad rows: 0, the scratch
+        slot), staged like the other feeds; then the adapter args."""
+        if self._slot_alloc is None:
+            return self._adapter_args(streams, bb)
+        from .io import stage_array
+
+        vec = np.zeros(bb, np.int32)
+        for i, s in enumerate(streams):
+            vec[i] = s.slot
+        return (stage_array(vec, self._device),) \
+            + self._adapter_args(streams, bb)
 
     def _adapter_bind(self, args, adapter):
         """Bind the flat adapter runtime args — per rank bucket a
@@ -2215,9 +2364,9 @@ class DecodeEngine:
         """AOT input specs for the adapter args at batch bucket
         ``bb`` — slab shapes are fixed by the pool, so the executable
         matrix gains NO new dimension from multi-tenancy."""
+        i32 = np.dtype(np.int32)
         if not self._lora:
             return ()
-        i32 = np.dtype(np.int32)
         specs = []
         slabs = self._adapter_pool.slabs()
         for j, rb in enumerate(self._lora):
@@ -2267,13 +2416,13 @@ class DecodeEngine:
             gkey = self._graph_key
 
             def prefill(params, tokens, positions, start, lengths,
-                        table, temps, seeds, steps, pools, *adapter):
+                        table, temps, seeds, steps, pools, *extra):
                 args = dict(params)
                 args.update(data=tokens, positions=positions,
                             start=start, lengths=lengths,
                             block_table=table)
                 self._pool_args(args, pools)
-                self._adapter_bind(args, adapter)
+                self._runtime_bind(args, extra)
                 outs, _ = gfn(args, {}, gkey, False)
                 logits = outs[0]          # (1, Ts, V) — SUFFIX rows
                 last = logits[jnp.arange(logits.shape[0]),
@@ -2295,7 +2444,7 @@ class DecodeEngine:
                      self._arg_spec((1,), i32),
                      self._arg_spec((1,), i32),
                      self._spec_of(self._pools)) \
-                + self._adapter_specs(1)
+                + self._runtime_specs(1)
             with profiler.scope(
                     f"serving.compile.prefix_prefill.t{tp}x{mb}",
                     "serving", args={"tokens": tp, "blocks": mb}):
@@ -2599,7 +2748,7 @@ class DecodeEngine:
                 stage_array(lengths, dev), stage_array(table, dev),
                 stage_array(temps, dev), stage_array(seeds, dev),
                 stage_array(steps, dev), self._pools,
-                *self._adapter_args([s], 1))
+                *self._runtime_args([s], 1))
         s.cost.flops_est += self._exe_flops.get(
             ("prefix_prefill", tp, mb), 0.0)
         return toks, tp
@@ -2636,17 +2785,32 @@ class DecodeEngine:
             lengths = np.asarray([n], np.int32)
             table = np.zeros((1, mb), np.int32)
             table[0, :len(pages)] = pages
+            if self._slot_alloc is not None:
+                # one slot from admission to retirement; the prefill
+                # below writes it anew (a re-prefill after preemption
+                # too), so a reused slot never shows its last owner
+                with profiler.scope("serving.slot_alloc", "serving",
+                                    args={"sids": s.sid}):
+                    s.slot = self._slot_alloc.alloc(owner=s.sid)
+                    profiler.set_gauge("serving.state_slots_live",
+                                       self._slot_alloc.live)
+                if s.slot is None:  # pragma: no cover - defensive
+                    s.slot = 0
+                    raise MXNetError(
+                        "admission raced the slot allocator: no state "
+                        "slot for an admitted stream")
             with profiler.scope(f"serving.prefill.t{tp}", "serving",
                                 args={"sids": s.sid, "tokens": n,
                                       "bucket": tp,
                                       "resume": s.resume}):
-                toks, self._pools = exe(
-                    self._params, stage_array(tokens, dev),
-                    stage_array(positions, dev),
-                    stage_array(lengths, dev),
-                    stage_array(table, dev), stage_array(temps, dev),
-                    stage_array(seeds, dev), stage_array(steps, dev),
-                    self._pools, *self._adapter_args([s], 1))
+                with self._pools_lock:
+                    toks, self._pools = exe(
+                        self._params, stage_array(tokens, dev),
+                        stage_array(positions, dev),
+                        stage_array(lengths, dev),
+                        stage_array(table, dev), stage_array(temps, dev),
+                        stage_array(seeds, dev), stage_array(steps, dev),
+                        self._pools, *self._runtime_args([s], 1))
                 with profiler.scope("serving.d2h_sync", "serving",
                                     args={"sids": s.sid}):
                     first = int(np.asarray(toks)[0])
@@ -2807,6 +2971,7 @@ class DecodeEngine:
                 s.cost.book_pages(len(s.blocks))
                 self._release_pages(s.blocks)
                 s.blocks = []
+                self._release_slot(s)
                 if not s.canary:
                     self._slo.observe_avail(s.slo_class, False)
                 if s.future.set_running_or_notify_cancel():
@@ -2893,6 +3058,7 @@ class DecodeEngine:
         victim.cost.book_pages(len(victim.blocks))
         self._release_pages(victim.blocks)
         victim.blocks = []
+        self._release_slot(victim)  # recompute: re-prefill writes anew
         victim.length = 0
         victim.cached_len = 0
         # a full-hit stream preempted BEFORE its first sampled token
@@ -2904,6 +3070,19 @@ class DecodeEngine:
             self._active.remove(victim)
             self._pending.insert(0, victim)
         self._count("preempted")
+
+    def _release_slot(self, s: _Stream):
+        """Hand back the stream's state slot (retirement, preemption,
+        shutdown); its contents stay until the next owner's prefill
+        overwrites them."""
+        if self._slot_alloc is None or not s.slot:
+            return
+        with profiler.scope("serving.slot_free", "serving",
+                            args={"sids": s.sid}):
+            self._slot_alloc.free(s.slot)
+            s.slot = 0
+            profiler.set_gauge("serving.state_slots_live",
+                               self._slot_alloc.live)
 
     def _release_adapter(self, s: _Stream):
         """Drop the stream's adapter-pool reference exactly once (the
@@ -2924,13 +3103,21 @@ class DecodeEngine:
         if s.blocks:
             self._release_pages(s.blocks)
             s.blocks = []
+        result = np.asarray(s.generated, np.int32)
+        if s.keep_state:
+            with self._pools_lock:  # the pools are donated step by step
+                result = {"tokens": result, "state": {
+                    n: np.asarray(p[s.slot])
+                    for n, p in zip(self._pool_names, self._pools)
+                    if n.endswith("_state")}}
+        self._release_slot(s)
         self._release_adapter(s)
         if s.tenant is not None and not s.canary:
             self._tenant_count(s.tenant, "tokens",
                                len(s.generated) + len(s.prompt))
             self._tenant_count(s.tenant, "generations")
         if s.future.set_running_or_notify_cancel():
-            s.future.set_result(np.asarray(s.generated, np.int32))
+            s.future.set_result(result)
         self._count("generations")
         self._cost_agg.add(s.cost)
         if s.cost.flops_est:
@@ -3050,6 +3237,11 @@ class DecodeEngine:
         Thread-safe; the splice itself runs on the scheduler thread.
         The Future resolves to the FULL generated token array
         (including tokens the exporter's prefill already emitted)."""
+        if self._slot_alloc is not None:
+            raise MXNetError(
+                "page import is not built for a model spec with kda "
+                "layers: an imported stream would arrive without the "
+                "state its slot holds")
         if self._mesh is not None:
             raise MXNetError(
                 "KV page migration onto a tp/pp-meshed engine is not "
@@ -3323,19 +3515,19 @@ class DecodeEngine:
                      stage_array(start, dev), stage_array(lengths, dev),
                      stage_array(table, dev), stage_array(temps, dev),
                      stage_array(seeds, dev), stage_array(steps0, dev))
-            adapter = self._adapter_args(streams, bb)
+            extra = self._runtime_args(streams, bb)
         self._count("context_tokens", int(lengths.sum()))
         with profiler.scope(f"serving.verify_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
                                   "blocks": mb, "window": W}):
             emit, self._pools = exe(self._params, *feeds, self._pools,
-                                    *adapter)
+                                    *extra)
         # the staged inputs die here, as call temporaries would: freeing
         # device arrays lets other threads run, and WHERE that happens
         # decides whether a caller's next request makes the next
         # admission (kept to the function's end, ttft halved)
-        del feeds, adapter
+        del feeds, extra
         with profiler.scope("serving.d2h_sync", "serving",
                             args={"active": n}):
             emit = np.asarray(emit)  # ONE (B, W) D2H for k+1 tokens
@@ -3469,7 +3661,7 @@ class DecodeEngine:
         # one adapter snapshot serves both halves of a pipelined pair
         # (the batch composition is pinned, so the slot vectors are
         # identical; a concurrent publish lands at the next pair)
-        adapter = self._adapter_args(streams, bb)
+        extra = self._runtime_args(streams, bb)
         span_args = {"sids": self._sids(streams), "active": n,
                      "pipelined": pipeline}
         dev = self._device
@@ -3501,8 +3693,9 @@ class DecodeEngine:
                             args={"active": n, "batch": bb,
                                   "blocks": mb,
                                   "pipelined": pipeline}):
-            toks_dev, self._pools = exe(self._params, *feeds,
-                                        self._pools, *adapter)
+            with self._pools_lock:
+                toks_dev, self._pools = exe(self._params, *feeds,
+                                            self._pools, *extra)
         # the staged inputs die here, as call temporaries would: freeing
         # device arrays lets other threads run, and WHERE that happens
         # decides whether a caller's next request makes the next
@@ -3535,8 +3728,9 @@ class DecodeEngine:
                             "serving",
                             args={"active": n, "batch": bb,
                                   "blocks": mb, "pipelined": True}):
-            toks2_dev, self._pools = exe(self._params, *feeds2,
-                                         self._pools, *adapter)
+            with self._pools_lock:
+                toks2_dev, self._pools = exe(self._params, *feeds2,
+                                             self._pools, *extra)
         del feeds2
         with profiler.scope("serving.d2h_sync", "serving",
                             args=span_args):

@@ -211,6 +211,114 @@ def test_pool_ops_update_the_pools_in_place(on_chip, one_chip, ops, heads):
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 
+# The hybrid family (models/hybrid_lm.py) at the benchmark's widths:
+# 128 streams, 64 query heads over 8 KV heads of 128, 64 KDA heads of
+# 128, 40 held experts of 4096 x 1280, a 2048-token prompt.
+
+def test_paged_attention_grouped_queries(on_chip, one_chip):
+    pool = ((20481, 16, 8 * 128), bf16)
+    _compile(lambda q, kp, vp, t, s: pk._paged_attention(
+        q, kp, vp, (), t, s, 64, kv_heads=8), one_chip,
+        ((128, 1, 64 * 128), bf16), pool, pool, ((128, 160), i32),
+        ((128,), i32))
+
+
+def test_paged_attention_same_kernel_at_equal_heads(on_chip):
+    """The guard on shared code: where query and KV heads are equal the
+    generalised ``_paged_attention`` traces the kernel the parent traced
+    — the doc cell's decode step (48 x 64 pages, 20 heads of 64) and a
+    5-row verify window, by the hash of the kernel's jaxpr as the parent
+    (PR 26) printed it.  (The Mosaic payload itself carries source
+    lines, which move with every edit of the file.)"""
+    import hashlib
+
+    golden = {
+        1: "3381220e256694533dfab71e6129656b9172cca8e2711e7f9bb6041a099a89c4",
+        5: "ed38f449664e3e81cc6d5c654e5af951285f788c23fd9f3fa5b41c5acbe29135",
+    }
+    pool = jax.ShapeDtypeStruct((3073, 16, 1280), bf16)
+    for w, want in golden.items():
+        text = str(jax.make_jaxpr(lambda q, kp, vp, t, s: pk._paged_attention(
+            q, kp, vp, (), t, s, 20))(
+                jax.ShapeDtypeStruct((48, w, 1280), bf16), pool, pool,
+                jax.ShapeDtypeStruct((48, 64), i32),
+                jax.ShapeDtypeStruct((48,), i32)))
+        text = re.sub(r"at 0x[0-9a-f]+", "", text)
+        assert hashlib.sha256(text.encode()).hexdigest() == want, w
+
+
+def _hybrid(monkeypatch):
+    from mxnet_tpu.ops import pallas_hybrid as ph
+
+    monkeypatch.setattr(ph, "_interpret", lambda: False)
+    return ph
+
+
+def test_kda_step(on_chip, one_chip, monkeypatch):
+    ph = _hybrid(monkeypatch)
+    row = ((128, 64, 128), f32)
+    _compile(ph.kda_step, one_chip, row, row, row, row, row,
+             ((129, 64, 128, 128), f32), ((128,), i32))
+
+
+def test_kda_chunk(on_chip, one_chip, monkeypatch):
+    ph = _hybrid(monkeypatch)
+    row = ((64, 2048, 128), f32)
+    _compile(ph.kda_chunk, one_chip, row, row, row,
+             ((64, 128, 2048), f32), ((64, 1, 2048), f32))
+
+
+@pytest.mark.parametrize("rows, tm", [(1024 + 40 * 16, 16),
+                                      (16384 + 40 * 128, 128)])
+def test_moe_gmm(on_chip, one_chip, monkeypatch, rows, tm):
+    ph = _hybrid(monkeypatch)
+    tiles = [((rows // tm,), i32), ((1,), i32)]
+    _compile(lambda x, g, u, te, nu: ph.moe_gmm_gate_up(x, g, u, te, nu,
+                                                        tm),
+             one_chip, ((rows, 4096), bf16), ((40, 4096, 1280), bf16),
+             ((40, 4096, 1280), bf16), *tiles)
+    _compile(lambda x, w, te, nu: ph.moe_gmm_down(x, w, te, nu, tm),
+             one_chip, ((rows, 1280), bf16), ((40, 1280, 4096), bf16),
+             *tiles)
+
+
+def test_hybrid_decode_slots_update_in_place(on_chip, one_chip,
+                                             monkeypatch):
+    """A decode step's slot pools — the conv tail, lane-dense, and the
+    KDA state through the kernel's aliased operand — are written where
+    they lie: no pool-shaped copy, next to nothing beside them."""
+    from mxnet_tpu.kv_cache import conv_tail_shape, state_pool_shape
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    _hybrid(monkeypatch)
+    H, D, K, B = 64, 128, 4, 128
+    state = (state_pool_shape(129, H, D), f32)
+    tail = (conv_tail_shape(129, K, 3 * H * D), f32)
+
+    def step(x, w, decay, beta, a_log, dt, tail_pool, state_pool, slots,
+             lengths):
+        ctx = OpContext(is_train=False, rng=None)
+        c, tail_pool = get_op("ShortConv").compute(
+            ctx, {"step": "True"}, [x, w, tail_pool, slots, lengths], [])
+        o, state_pool = get_op("KDAStep").compute(
+            ctx, {"num_heads": str(H), "neg_eigval": "True"},
+            [c, decay, beta, a_log, dt, state_pool, slots, lengths], [])
+        return o, tail_pool, state_pool
+
+    shapes = [((B, 1, 3 * H * D), bf16), ((3 * H * D, K), bf16),
+              ((B, 1, H * D), bf16), ((B, 1, H), bf16), ((H,), f32),
+              ((H * D,), f32), tail, state, ((B,), i32), ((B,), i32)]
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(step, donate_argnums=(6, 7)).lower(*args).compile()
+    text = compiled.as_text()
+    for shape, _ in (state, tail):
+        dims = ",".join(str(n) for n in shape)
+        copies = re.findall(rf"= f32\[{dims}\]\S* copy\(.*", text)
+        assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 def test_lstm_scan_ptb(on_chip, one_chip):
     # PTB LSTM (tools/bench_secondary.py): T=32, batch 32, hidden 200
     T, B, H = 32, 32, 200

@@ -109,13 +109,15 @@ def check_mha(T, block, causal, B=2, H=8, D=128):
     return ok
 
 
-def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16):
+def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     """The paged kernel over (P, KVB, H·D) pools (``kv_cache.
     value_pool_shape``) against a lax gather of the pages and the
     fallbacks' blockwise body: W = 1 is the decode step, W > 1 a
     verify window, ``int8`` the quantized pools.  Streams of every
     length from one token to a full table, pages handed out in a
-    shuffled order, one idle row."""
+    shuffled order, one idle row.  ``Hq``: grouped queries, that many
+    query heads over the H KV heads."""
+    Hq = Hq or H
     from mxnet_tpu.kv_cache import value_pool_shape
     from mxnet_tpu.ops import attention as att
     from mxnet_tpu.ops import pallas_kernels as pk
@@ -138,11 +140,12 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16):
     if W == 1:
         start[1] = -1
     start = jnp.asarray(start)
-    q = jnp.asarray(rng.randn(B, W, H * D).astype(np.float32)
+    q = jnp.asarray(rng.randn(B, W, Hq * D).astype(np.float32)
                     * 0.5).astype(jnp.bfloat16)
     scales = pools[2:]
     got = jax.jit(lambda q, t, s, *p: pk._paged_attention(
-        q, p[0], p[1], p[2:], t, s, H))(q, table, start, *pools)
+        q, p[0], p[1], p[2:], t, s, Hq, kv_heads=H))(
+            q, table, start, *pools)
 
     def gather_and_attend(q, t, s, *p):
         # the gathered ROWS take the head dim, never a pool
@@ -150,11 +153,13 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16):
         if scales:
             kg = att.dequantize_kv(kg, p[2][t].reshape(B, MB * KVB, H))
             vg = att.dequantize_kv(vg, p[3][t].reshape(B, MB * KVB, H))
+        # query head i reads KV head i // (Hq / H)
+        kg, vg = (jnp.repeat(x, Hq // H, axis=2) for x in (kg, vg))
         o, m, l = att._blockwise_attention_partial_lax(
-            q.reshape(B, W, H, D), kg, vg, False, KVB, 0,
+            q.reshape(B, W, Hq, D), kg, vg, False, KVB, 0,
             lengths=s + 1, diagonal=True)
         return att.normalize_attention_state(o, m, l, q.dtype).reshape(
-            B, W, H * D)
+            B, W, Hq * D)
 
     want = jax.jit(gather_and_attend)(q, table, start, *pools)
     got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
@@ -163,7 +168,7 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16):
                 / max(np.abs(want[live]).max(), 1e-9))
     ok = err < TOL and bool(np.isfinite(got).all()) \
         and not np.abs(got[~live]).any()
-    print(f"{'OK ' if ok else 'FAIL'} paged  H={H} D={D} W={W} "
+    print(f"{'OK ' if ok else 'FAIL'} paged  H={Hq}/{H} D={D} W={W} "
           f"pools={kv_dtype}{' +scales' if scales else ''}: "
           f"fwd={err:.4f}", flush=True)
     return ok
@@ -178,6 +183,10 @@ def main():
                  [(20, 64), (16, 64), (12, 64), (4, 32), (3, 48)]):
         for W, kv in ((1, "bf16"), (5, "bf16"), (1, "int8")):
             results.append(check_paged(H, D, W, kv))
+    # grouped queries: 64 query heads over 8 KV heads of 128, the
+    # hybrid family's attention layers (decode and a 2-row window)
+    for W in (1, 2):
+        results.append(check_paged(8, 128, W, "bf16", Hq=64))
     if "--paged" in sys.argv:
         return _report(results)
     # packed: sweep revisit counts, block sizes, head counts, causality
