@@ -240,15 +240,17 @@ def _short_conv(op_ctx, attrs, inputs, aux):
 # ---------------------------------------------------------------------------
 
 def kda_gates(a_raw, b_raw, a_log, dt_bias, H, neg_eigval):
-    """(alpha, beta): the per-channel decay
-    ``exp(-exp(A_h) * softplus(a_raw + dt_bias))`` in (0, 1), shaped
-    (..., H, D), and the step ``sigmoid(b_raw)`` (doubled where negative
-    eigenvalues are allowed: (0, 2)), shaped (..., H).  Float32."""
+    """(alpha, beta, g): the per-channel decay ``alpha = exp(g)`` in
+    (0, 1] with its logarithm ``g = -exp(A_h) * softplus(a_raw +
+    dt_bias)`` as computed (the chunk kernels want g itself: an alpha
+    that underflowed to 0 has no logarithm), both shaped (..., H, D),
+    and the step ``sigmoid(b_raw)`` (doubled where negative eigenvalues
+    are allowed: (0, 2)), shaped (..., H).  Float32."""
     a = a_raw.astype(jnp.float32) + dt_bias.astype(jnp.float32)
     a = a.reshape(a.shape[:-1] + (H, a.shape[-1] // H))
     g = -jnp.exp(a_log.astype(jnp.float32))[:, None] * jax.nn.softplus(a)
     beta = jax.nn.sigmoid(b_raw.astype(jnp.float32))
-    return jnp.exp(g), (2.0 * beta if neg_eigval else beta)
+    return jnp.exp(g), (2.0 * beta if neg_eigval else beta), g
 
 
 def kda_qkv(c, H):
@@ -308,37 +310,35 @@ def _kda_inputs(attrs, inputs):
     H = attr_int(attrs.get("num_heads", 1), 1)
     neg = attr_bool(attrs.get("neg_eigval", False), False)
     q, k, v = kda_qkv(c, H)
-    alpha, beta = kda_gates(a_raw, b_raw, a_log, dt_bias, H, neg)
-    return (q, k, v, alpha, beta, pool, slots.astype(jnp.int32),
+    alpha, beta, g = kda_gates(a_raw, b_raw, a_log, dt_bias, H, neg)
+    return (q, k, v, alpha, beta, g, pool, slots.astype(jnp.int32),
             lengths.astype(jnp.int32), c)
 
 
 @register("KDAChunk", arg_names=_KDA_ARGS, out_names=_KDA_OUTS,
           infer_shape=_kda_infer,
           doc="KDA over a (padded) prompt from the zero state; the state "
-              "after position lengths[b] - 1 is written to the slot.  "
-              + _KDA_DOC)
+              "after position lengths[b] - 1 is written to the slot.  The "
+              "recurrence below token by token (lax.scan), or on TPU its "
+              "chunk (WY) form in matrix products "
+              "(pallas_hybrid.kda_chunk): the same float32 state and "
+              "outputs.  " + _KDA_DOC)
 def _kda_chunk(op_ctx, attrs, inputs, aux):
     from . import pallas_hybrid as ph
     from . import pallas_kernels as pk
 
-    q, k, v, alpha, beta, pool, slots, n, c = _kda_inputs(attrs, inputs)
+    q, k, v, alpha, beta, g, pool, slots, n, c = _kda_inputs(attrs, inputs)
     B, T, H, D = q.shape
     # a padded position leaves the state as it is: decay 1, step 0
     live = jnp.arange(T)[None, :] < n[:, None]
-    alpha = jnp.where(live[..., None, None], alpha, 1.0)
     beta = jnp.where(live[..., None], beta, 0.0)
     if pk.enabled():
-        def rows(t):                                  # -> (B·H, T, D)
-            return jnp.transpose(t, (0, 2, 1, 3)).reshape(B * H, T, D)
-
-        o, last = ph.kda_chunk(
-            rows(q), rows(k), rows(alpha),
-            jnp.transpose(v, (0, 2, 3, 1)).reshape(B * H, D, T),
-            jnp.transpose(beta, (0, 2, 1)).reshape(B * H, 1, T))
-        o = jnp.transpose(o.reshape(B, H, D, T), (0, 3, 1, 2))
-        last = last.reshape(B, H, D, D)
+        # the chunk form of the same recurrence, from the conv's output
+        # as it is and the log-decay
+        g = jnp.where(live[..., None, None], g, 0.0)
+        o, last = ph.kda_chunk(c, g.reshape(B, T, H * D), beta)
     else:
+        alpha = jnp.where(live[..., None, None], alpha, 1.0)
         o, last = kda_scan(q, k, v, alpha, beta,
                            jnp.zeros((B, H, D, D), jnp.float32))
     return [o.reshape(B, T, H * D).astype(c.dtype),
@@ -354,7 +354,7 @@ def _kda_step(op_ctx, attrs, inputs, aux):
     from . import pallas_hybrid as ph
     from . import pallas_kernels as pk
 
-    q, k, v, alpha, beta, pool, slots, _, c = _kda_inputs(attrs, inputs)
+    q, k, v, alpha, beta, _, pool, slots, _, c = _kda_inputs(attrs, inputs)
     B, S, H, D = q.shape
     if S != 1:
         raise MXNetError(f"KDAStep feeds ONE position a step; got qkv "

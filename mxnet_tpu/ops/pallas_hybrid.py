@@ -1,7 +1,7 @@
 """Pallas TPU kernels of the hybrid language-model family
 (``models/hybrid_lm.py``): the KDA recurrence, one token against a
-stream's state (``kda_step``) and a whole prompt with the state resident
-in VMEM (``kda_chunk``), and the grouped matmuls of the routed experts
+stream's state (``kda_step``) and a whole prompt in the chunk (WY) form
+(``kda_chunk``), and the grouped matmuls of the routed experts
 (``moe_gmm_gate_up``, ``moe_gmm_down``).  Helpers and conventions are
 ``pallas_kernels``'s: every ``pallas_call`` carries a ``name=``, which is
 what a device trace shows.
@@ -17,7 +17,57 @@ the decay then scales lanes by a row vector, and both contractions with
 
 which is ``S_t = (I - beta k k^T) Diag(alpha) S_{t-1} + beta k v^T``,
 ``o_t = S_t^T q_t`` exactly, in float32 on the VPU; no matmul, no
-transpose.
+transpose.  That is ``kda_step``, one token a stream.
+
+``kda_chunk`` is the same recurrence over a prompt, rearranged so that
+the work is matrix products (Kimi Linear, arXiv 2510.26692; the gated
+delta rule's WY form with a per-channel decay).  With g_t = log alpha_t
+<= 0 and G_t the sum of g over a span of tokens up to t, the tokens of
+a span that starts from the state S0 obey
+
+    (I + Diag(beta) tril(A, -1)) Delta = Diag(beta) (V - (K . e^G) S0)
+    A_ij = sum_d k_i k_j e^(G_i - G_j)                      (j < i)
+    B_ij = sum_d q_i k_j e^(G_i - G_j)                      (j <= i)
+    O    = (Q . e^G) S0 + B Delta
+    S_end = Diag(e^G_end) S0 + (K . e^(G_end - G))^T Delta
+
+(Delta_i = beta_i (v_i - S_{i-1}^T Diag(alpha_i) k_i), the value the
+delta rule really writes).  Solved for Delta = U - W S0 that is
+U = T V, W = T (K . e^G), T = (I + Diag(beta) tril(A, -1))^-1
+Diag(beta).  Three kernels, two levels:
+
+* ``kda_chunk_intra``: every SUB-BLOCK of 16 tokens as a span of its
+  own.  Its A and B are formed directly, pair by pair, on the VPU:
+  e^(G_i - G_j) with j and i 1..15 tokens apart has no factoring
+  e^(G_i - r) e^(r - G_j) with both exponents <= 0, and e^(-G) alone
+  overflows float32 within a few tokens at the decays a model can
+  draw (g down to -128 a token).  The sub-blocks sit on the LANES
+  (token i of sub-block b is element [i][d, b]), so the 16 x 16
+  algebra — A, B, T by forward substitution, U = T V, W = T (K . e^G),
+  and what the span's outputs need, B U and Q . e^G - B W — is scalar
+  code over whole registers, and a sum over d is a sum of registers.
+* ``kda_chunk_wy``: the four sub-blocks of a CHUNK of 64 joined.
+  Sub-block s starts from S_s = Diag(e^(G to s)) S0 + sum_{s' < s}
+  Kd_{s'->s}^T Delta_s', with Kd the keys decayed from their token to
+  the start of s; putting that into Delta_s = U_s - W_s S_s gives U, W
+  of the chunk by block forward substitution with the 16 x 16 blocks
+  W_s Kd^T, and the strictly-lower blocks of B as (Q . e^G - B W)_s
+  Kd^T.  Here the reference IS between j and i — the sub-block
+  boundaries — and every factor is an exponent of a sum of log-decays
+  over a span of tokens, each summed as such.
+* ``kda_chunk_state``: the only pass that is sequential, chunk after
+  chunk against the (d_v, d_k) state, eight heads a grid step:
+  Delta = U - W S0, O = B U + (Q' . e^G) S0 + B_lower Delta, S_end.
+
+EVERY EXPONENT FORMED IS <= 0: each is the sum of g over a span of
+tokens (inside a sub-block, over whole sub-blocks, or to a chunk's
+end), computed as that sum and never as a difference of two longer
+ones; the kernels take g itself, never log(alpha) (an alpha that
+underflowed to 0 has no logarithm), and clamp nothing.  EVERY PRODUCT
+IS FLOAT32: the VPU's are, and each MXU contraction runs at
+``Precision.HIGHEST`` with float32 operands — the state, Delta, U, W
+and T never pass through bfloat16 (``state_dtype: float32``): the
+speed comes from the form, not from the precision.
 """
 
 from __future__ import annotations
@@ -126,57 +176,339 @@ def slot_rows_write(pool, rows, slots):
 
 
 # ---------------------------------------------------------------------------
-# kda_chunk: a prompt, chunk by chunk, the state resident in VMEM
+# kda_chunk: a prompt in the chunk (WY) form of the recurrence
 # ---------------------------------------------------------------------------
 
-def _kda_chunk_kernel(q_ref, k_ref, a_ref, vt_ref, b_ref, o_ref, sT_ref,
-                      s_scr, *, tc, nt):
-    j = pl.program_id(1)
+_SUB = 16      # tokens of a sub-block: the diagonal blocks, on the VPU
+_CHUNK = 64    # tokens of a chunk: one step of the sequential pass
+
+
+def _dot(a, b, dims=((1,), (0,))):
+    """A float32 contraction at float32 precision on the MXU."""
+    return jax.lax.dot_general(a, b, (dims, ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _unit(x, total):
+    """x over its L2 norm along d (``total`` sums over d), as
+    ``ops/hybrid.py kda_qkv`` normalises q and k."""
+    return x * jax.lax.rsqrt(total(x * x) + 1e-6)
+
+
+def _bsum(x):
+    """(D/8, 8, L) -> (8, L): the sum over d, on every sublane (whole
+    registers added, then one sublane reduction)."""
+    s = jnp.sum(x, axis=0)
+    return jnp.broadcast_to(jnp.sum(s, axis=0, keepdims=True), s.shape)
+
+
+def _kda_intra_kernel(q_ref, k_ref, g_ref, v_ref, b_ref,
+                      ul_ref, wl_ref, qp_ref, ou_ref,
+                      kT, qT, vT, gT, kgT, uT, wT, A, Bm, Tm, stage,
+                      *, hpt, nbt, D):
+    """Every sub-block of 16 tokens from ITS OWN start, the sub-blocks
+    on the lanes: token i of sub-block b is element [i][d, b], so the
+    16 x 16 algebra of a sub-block is scalar code over whole registers
+    and a sum over d is a sum of registers."""
+    L, Dg = hpt * nbt, D // 8
+    f32 = jnp.float32
+
+    # a strided read or write wants a ref one head wide, so a head's
+    # slab goes through ``stage``
+    def bring(ref, into):
+        for hh in range(hpt):
+            stage[hh] = ref[0, :, hh * D:(hh + 1) * D].astype(f32)
+        for i in range(_SUB):
+            x = jnp.concatenate(
+                [stage[hh, pl.ds(i, nbt, stride=_SUB), :]
+                 for hh in range(hpt)], axis=0)
+            into[i] = x.T.reshape(Dg, 8, L)
+
+    def send(of, ref):
+        for i in range(_SUB):
+            y = of[i].reshape(D, L).T
+            for hh in range(hpt):
+                stage[hh, pl.ds(i, nbt, stride=_SUB), :] = \
+                    y[hh * nbt:(hh + 1) * nbt]
+        for hh in range(hpt):
+            ref[0, :, hh * D:(hh + 1) * D] = stage[hh]
+
+    bring(k_ref, kT)
+    bring(q_ref, qT)
+    bring(v_ref, vT)
+    bring(g_ref, gT)
+    for i in range(_SUB):            # ops/hybrid.py kda_qkv, in here
+        kT[i] = _unit(kT[i], _bsum)
+        qT[i] = _unit(qT[i], _bsum) * (float(D) ** -0.5)
+    zero = jnp.zeros((Dg, 8, L), f32)
+
+    def ab_row(i, _):
+        ki, qi = kT[i], qT[i]
+        Bm[i * _SUB + i] = _bsum(qi * ki)
+
+        def ab_col(jj, d):
+            # j = i-1 .. 0; d = G_i - G_j = g_{j+1} + .. + g_i <= 0,
+            # summed as such (G_i - G_j from two long sums would lose
+            # the digits that matter when both are large)
+            j = i - 1 - jj
+            d = d + gT[j + 1]
+            p = kT[j] * jnp.exp(d)
+            A[i * _SUB + j] = _bsum(ki * p)
+            Bm[i * _SUB + j] = _bsum(qi * p)
+            return d
+
+        jax.lax.fori_loop(0, i, ab_col, zero)
+        return 0
+
+    jax.lax.fori_loop(0, _SUB, ab_row, 0)
+    for i in range(1, _SUB):         # from here on gT is the running sum
+        gT[i] = gT[i - 1] + gT[i]
+    for i in range(_SUB):
+        kgT[i] = kT[i] * jnp.exp(gT[i])
+
+    def t_row(i, _):
+        # (I + Diag(beta) tril(A, -1)) T = Diag(beta), row i by forward
+        # substitution: the recurrence's own order, no power of A
+        bi = jnp.broadcast_to(b_ref[0, 0, 0, pl.ds(i, 1), :], (8, L))
+
+        def t_col(c, _):
+            acc = jax.lax.fori_loop(
+                c, i, lambda j, a: a + A[i * _SUB + j] * Tm[j * _SUB + c],
+                jnp.zeros((8, L), f32))
+            Tm[i * _SUB + c] = -bi * acc
+            return 0
+
+        jax.lax.fori_loop(0, i, t_col, 0)
+        Tm[i * _SUB + i] = bi
+        return 0
+
+    jax.lax.fori_loop(0, _SUB, t_row, 0)
+
+    def uw_row(i, _):
+        def uw_j(j, c):
+            t = Tm[i * _SUB + j]
+            return c[0] + t * vT[j], c[1] + t * kgT[j]
+
+        uT[i], wT[i] = jax.lax.fori_loop(0, i + 1, uw_j, (zero, zero))
+        return 0
+
+    jax.lax.fori_loop(0, _SUB, uw_row, 0)
+
+    def o_row(i, _):
+        def o_j(j, c):
+            b = Bm[i * _SUB + j]
+            return c[0] + b * uT[j], c[1] + b * wT[j]
+
+        ou, ow = jax.lax.fori_loop(0, i + 1, o_j, (zero, zero))
+        kgT[i] = ou                  # kgT and vT are read no more
+        vT[i] = qT[i] * jnp.exp(gT[i]) - ow
+        return 0
+
+    jax.lax.fori_loop(0, _SUB, o_row, 0)
+    send(uT, ul_ref)
+    send(wT, wl_ref)
+    send(kgT, ou_ref)
+    send(vT, qp_ref)
+
+
+def _rows16(parts):
+    """``len(parts)`` rows (1, D), each repeated over its sub-block's 16
+    rows -> (16 * len(parts), D)."""
+    return jnp.concatenate(
+        [jnp.broadcast_to(p, (_SUB, p.shape[-1])) for p in parts], axis=0)
+
+
+def _kda_wy_kernel(k_ref, g_ref, ul_ref, wl_ref, qp_ref,
+                   u_ref, w_ref, qg_ref, kd_ref, bs_ref, eg_ref, *, cb, D):
+    """A chunk's four sub-blocks joined: what each sub-block's start
+    state owes the chunk's, by block forward substitution."""
+    ns = _CHUNK // _SUB
+    f32 = jnp.float32
+    r = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (_CHUNK, _CHUNK), 1)
+    after = ((c > r) & (c // _SUB == r // _SUB)).astype(f32)
+    whole = (c[:8] // _SUB == r[:8]).astype(f32)     # rows ns.. are empty
+    zrow = jnp.zeros((1, D), f32)
+    for ci in range(cb):
+        at = slice(ci * _CHUNK, (ci + 1) * _CHUNK)
+        k = _unit(k_ref[0, at].astype(f32),
+                  lambda t: jnp.sum(t, axis=1, keepdims=True))
+        ul, wl, qp = ul_ref[0, at], wl_ref[0, at], qp_ref[0, at]
+        # the sum of g over each whole sub-block, and inside it after
+        # each row (0/1 weights: the products are exact, the sums
+        # float32): every one a sum of log-decays, <= 0
+        g = g_ref[0, at]
+        sums = _dot(whole, g)
+        tot = [sums[s:s + 1] for s in range(ns)]
+
+        def span(lo, hi):            # sum of tot[lo:hi], a (1, D) row <= 0
+            out = zrow
+            for s in range(lo, hi):
+                out = out + tot[s]
+            return out
+
+        # k_j decayed from j to the end of its own sub-block
+        ksrc = k * jnp.exp(_dot(after, g))
+        x = [jnp.concatenate([ul[:_SUB], wl[:_SUB]], axis=1)]
+        bst = [jnp.zeros((_SUB, _CHUNK), f32)]
+        for s in range(1, ns):
+            rows = slice(s * _SUB, (s + 1) * _SUB)
+            # ... and on over the whole sub-blocks between it and s;
+            # rows of s and after it count nothing
+            on = jnp.concatenate(
+                [jnp.exp(_rows16([span(sp + 1, s) for sp in range(s)])),
+                 jnp.zeros(((ns - s) * _SUB, D), f32)], axis=0)
+            p = _dot(jnp.concatenate([wl[rows], qp[rows]], axis=0),
+                     ksrc * on, ((1,), (1,)))          # (32, 64)
+            bst.append(p[_SUB:])
+            have = jnp.concatenate(
+                x + [jnp.zeros(((ns - s) * _SUB, 2 * D), f32)], axis=0)
+            rhs = jnp.concatenate(
+                [ul[rows], wl[rows] * jnp.exp(span(0, s))], axis=1)
+            x.append(rhs - _dot(p[:_SUB], have))
+        x = jnp.concatenate(x, axis=0)
+        u_ref[0, at] = x[:, :D]
+        w_ref[0, at] = x[:, D:]
+        qg_ref[0, at] = qp * jnp.exp(
+            _rows16([span(0, s) for s in range(ns)]))
+        kd_ref[0, at] = ksrc * jnp.exp(
+            _rows16([span(s + 1, ns) for s in range(ns)]))
+        bs_ref[0, 0, at] = jnp.concatenate(bst, axis=0)
+        eg_ref[0, ci] = jnp.exp(span(0, ns))
+
+
+def _kda_state_kernel(u_ref, w_ref, qg_ref, kd_ref, ou_ref, bs_ref, eg_ref,
+                      o_ref, sT_ref, s_scr, *, hb, nt, D):
+    """The only pass that is sequential over chunks: three products a
+    chunk and head against the (d_v, d_k) state, ``hb`` heads a grid
+    step so that their chains interleave."""
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         s_scr[...] = jnp.zeros_like(s_scr)
 
-    st = s_scr[...]
-    for t in range(tc):
-        st, o_col = _kda_token(
-            st, q_ref[0, t:t + 1, :], k_ref[0, t:t + 1, :],
-            a_ref[0, t:t + 1, :], vt_ref[0, :, t:t + 1],
-            b_ref[0, :, t:t + 1])
-        o_ref[0, :, t:t + 1] = o_col
-    s_scr[...] = st
+    for h in range(hb):
+        at = slice(h * D, (h + 1) * D)
+        st = s_scr[h]
+        wq = _dot(jnp.concatenate([w_ref[0, :, at], qg_ref[0, :, at]],
+                                  axis=0), st, ((1,), (1,)))
+        delta = u_ref[0, :, at] - wq[:_CHUNK]
+        o_ref[0, :, at] = (ou_ref[0, :, at] + wq[_CHUNK:] +
+                           _dot(bs_ref[0, h], delta)).astype(o_ref.dtype)
+        s_scr[h] = st * eg_ref[0, 0, :, at] + \
+            _dot(delta, kd_ref[0, :, at], ((0,), (0,)))
 
     @pl.when(j == nt - 1)
     def _last():
-        sT_ref[0] = st
+        sT_ref[0] = s_scr[...]
 
 
-def kda_chunk(q, k, alpha, vt, beta):
-    """A whole prompt from the zero state.  q, k, alpha (N, T, D)
-    float32 with N = batch x heads (q, k normalised and scaled; a
-    padded position carries alpha 1 and beta 0 and leaves the state as
-    it is); vt (N, D, T): v transposed, so that a token's v is a
-    column; beta (N, 1, T) -> (o (N, D, T), the last state (N, D, D),
-    transposed as the module doc says).  The recurrence is exact, token
-    by token; a chunk of ``tc`` tokens is one grid step."""
-    N, T, D = q.shape
-    tc = 128 if T % 128 == 0 else T
-    nt = T // tc
-    row = _vmem_spec((1, tc, D), lambda n, j: (n, j, 0))
-    col = _vmem_spec((1, D, tc), lambda n, j: (n, 0, j))
-    return pl.pallas_call(
-        functools.partial(_kda_chunk_kernel, tc=tc, nt=nt),
-        grid=(N, nt),
-        in_specs=[row, row, row, col,
-                  _vmem_spec((1, 1, tc), lambda n, j: (n, 0, j))],
-        out_specs=[col, _vmem_spec((1, D, D), lambda n, j: (n, 0, 0))],
-        out_shape=[jax.ShapeDtypeStruct((N, D, T), jnp.float32),
-                   jax.ShapeDtypeStruct((N, D, D), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((D, D), jnp.float32)],
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+def _divisor_at_most(n, most):
+    return max(d for d in range(1, min(n, most) + 1) if n % d == 0)
+
+
+def kda_chunk(c, g, beta):
+    """A whole prompt from the zero state, in the chunk (WY) form of
+    the recurrence (module doc: the equations, the three kernels, why
+    every exponent is <= 0 and every product float32).
+
+    c (B, T, 3*H*D): the short convolution's output, q | k | v, each H
+    heads of D, of any float type (q and k are L2-normalised per head
+    and q scaled by D^-1/2 in here, in float32, as ``kda_qkv`` does);
+    g (B, T, H*D) float32: the per-channel LOG-decay, <= 0, as
+    ``kda_gates`` has it before its exp; beta (B, T, H) float32.  A
+    padded position carries g 0 and beta 0, which leaves the state as
+    it is and makes its row of T zero; T is padded in here with more
+    such positions up to a whole number of chunk PAIRS (128 tokens: a
+    tile's rows are whole registers) -> (o (B, T, H*D) of c's type, the
+    last state (B, H, D, D) float32, transposed as the module doc
+    says)."""
+    B, live, H = beta.shape
+    D = g.shape[-1] // H
+    if D % 8:
+        raise ValueError(f"kda_chunk wants D a multiple of 8; got {D}")
+    pad = ((0, 0), (0, -live % (2 * _CHUNK)), (0, 0))
+    c, g, beta = jnp.pad(c, pad), jnp.pad(g, pad), jnp.pad(beta, pad)
+    T = beta.shape[1]
+    f32 = jnp.float32
+    wide = jax.ShapeDtypeStruct((B, T, H * D), f32)
+
+    # -- sub-blocks, each from its own start: 128 of them on the lanes
+    nb = T // _SUB
+    nbt = min(nb, 128)
+    hpt = _divisor_at_most(H, 128 // nbt)
+    L = hpt * nbt
+    beta_l = jnp.transpose(
+        beta.reshape(B, nb // nbt, nbt, _SUB, H // hpt, hpt),
+        (0, 4, 1, 3, 5, 2)).reshape(B, H // hpt, nb // nbt, _SUB, L)
+
+    def tile(first):                 # head h of q (0), k (1) or v (2)
+        return _vmem_spec((1, nbt * _SUB, hpt * D),
+                          lambda b, h, j: (b, j, first * (H // hpt) + h))
+
+    big = pltpu.VMEM((_SUB, D // 8, 8, L), f32)
+    small = pltpu.VMEM((_SUB * _SUB, 8, L), f32)
+    ul, wl, qp, ou = pl.pallas_call(
+        functools.partial(_kda_intra_kernel, hpt=hpt, nbt=nbt, D=D),
+        grid=(B, H // hpt, nb // nbt),
+        in_specs=[tile(0), tile(1), tile(0), tile(2),
+                  _vmem_spec((1, 1, 1, _SUB, L),
+                             lambda b, h, j: (b, h, j, 0, 0))],
+        out_specs=[tile(0)] * 4, out_shape=[wide] * 4,
+        scratch_shapes=[big] * 7 + [small] * 3 + [
+            pltpu.VMEM((hpt, nbt * _SUB, D), f32)],
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel",
+            vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-        name="kda_chunk",
-    )(q, k, alpha, vt, beta)
+        name="kda_chunk_intra",
+    )(c, c, g, c, beta_l)
+
+    # -- chunks, each from its own start
+    nt = T // _CHUNK
+    cb = _divisor_at_most(nt, 4)
+    rows = _vmem_spec((1, cb * _CHUNK, D), lambda b, h, j: (b, j, h))
+    k_rows = _vmem_spec((1, cb * _CHUNK, D), lambda b, h, j: (b, j, H + h))
+    u, w, qg, kd, bst, eg = pl.pallas_call(
+        functools.partial(_kda_wy_kernel, cb=cb, D=D),
+        grid=(B, H, nt // cb),
+        in_specs=[k_rows] + [rows] * 4,
+        out_specs=[rows] * 4 + [
+            _vmem_spec((1, 1, cb * _CHUNK, _CHUNK),
+                       lambda b, h, j: (b, h, j, 0)),
+            _vmem_spec((1, cb, 1, D), lambda b, h, j: (b, j, 0, h))],
+        out_shape=[wide] * 4 + [
+            jax.ShapeDtypeStruct((B, H, T, _CHUNK), f32),
+            jax.ShapeDtypeStruct((B, nt, 1, H * D), f32)],
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "parallel"),
+        interpret=_interpret(),
+        name="kda_chunk_wy",
+    )(c, g, ul, wl, qp)
+
+    # -- the state, chunk after chunk
+    hb = 8 if H % 8 == 0 else H
+    rows = _vmem_spec((1, _CHUNK, hb * D), lambda b, h, j: (b, j, h))
+    o, last = pl.pallas_call(
+        functools.partial(_kda_state_kernel, hb=hb, nt=nt, D=D),
+        grid=(B, H // hb, nt),
+        in_specs=[rows] * 5 + [
+            _vmem_spec((1, hb, _CHUNK, _CHUNK),
+                       lambda b, h, j: (b, h, j, 0)),
+            _vmem_spec((1, 1, 1, hb * D), lambda b, h, j: (b, j, 0, h))],
+        out_specs=[rows, _vmem_spec((1, hb, D, D),
+                                    lambda b, h, j: (b, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, H * D), c.dtype),
+                   jax.ShapeDtypeStruct((B, H, D, D), f32)],
+        scratch_shapes=[pltpu.VMEM((hb, D, D), f32)],
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
+        interpret=_interpret(),
+        name="kda_chunk_state",
+    )(u, w, qg, kd, ou, bst, eg)
+    return o[:, :live], last
 
 
 # ---------------------------------------------------------------------------

@@ -261,11 +261,18 @@ def test_kda_step(on_chip, one_chip, monkeypatch):
              ((129, 64, 128, 128), f32), ((128,), i32))
 
 
-def test_kda_chunk(on_chip, one_chip, monkeypatch):
+@pytest.mark.parametrize("T", [2048, 1024])
+def test_kda_chunk(on_chip, one_chip, monkeypatch, T):
+    """The chunk form at both prefill buckets of the reason cell: three
+    Mosaic kernels, each named so that ``kda_chunk_roofline.serve``
+    (a substring match) reads it."""
     ph = _hybrid(monkeypatch)
-    row = ((64, 2048, 128), f32)
-    _compile(ph.kda_chunk, one_chip, row, row, row,
-             ((64, 128, 2048), f32), ((64, 1, 2048), f32))
+    text = _compile(ph.kda_chunk, one_chip, ((1, T, 3 * 64 * 128), bf16),
+                    ((1, T, 64 * 128), f32), ((1, T, 64), f32)).as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(kernels) == 3, kernels
+    assert all("kda_chunk" in name for name in kernels), kernels
 
 
 @pytest.mark.parametrize("rows, tm", [(1024 + 40 * 16, 16),
