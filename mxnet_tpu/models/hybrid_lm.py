@@ -94,13 +94,25 @@ class HybridSpec:
         # the K/V page geometry (what the engine sizes its pools by)
         self.kv_heads, self.head_dim = pages.pop() if pages else (0, 0)
 
-    # -- what the engine asks --------------------------------------------
+    # -- what the engine asks (the protocol: DecodeEngine's docstring) ---
+    feeds = ("data", "lengths", "block_table", "slots")
+    phases = ("prefill", "decode")    # no suffix-prefill, no verify symbol
+    kv_dtypes = ("fp32", "bf16")      # no quantized pages
+    positions = None                  # no learned positions: max_len given
+    partition_rules = None            # no tp/pp placement
+    lora_width = 0                    # no LoRA epilogue
+
     @property
     def num_layers(self):
         return len(self.layers)
 
     def mixer_kinds(self):
         return tuple(ly["mixer"]["kind"] for ly in self.layers)
+
+    @property
+    def name(self):
+        return (f"a model spec with "
+                f"{'/'.join(sorted(set(self.mixer_kinds())))} layers")
 
     def cache_kinds(self):
         """Per layer, the kind of its per-stream state: ``pages`` (K/V,
@@ -111,7 +123,15 @@ class HybridSpec:
     def has_moe(self):
         return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
 
-    feeds = ("data", "lengths", "block_table", "slots")
+    def pool_kinds(self, kv_dtype="fp32"):
+        """The kind of each of :meth:`pools`' rows: a kda layer's state
+        is what ``return_state`` reads, its convolution's tail rides in
+        the same slot."""
+        out = []
+        for k in self.cache_kinds():
+            out += ["pages", "pages"] if k == "pages" \
+                else ["slots", "slots_aux"]
+        return tuple(out) + (("counters",) if self.has_moe() else ())
 
     def pools(self, cache_blocks, kv_block, slots, dtype, kv_dtype="fp32"):
         """The per-stream state arrays a program carries, in the order
@@ -142,12 +162,9 @@ class HybridSpec:
         return out
 
     def symbol(self, which, kv_block=16, **unused):
-        """The ``prefill`` or ``decode`` symbol.  This family builds no
-        suffix-prefill and no verify symbol."""
-        if which not in ("prefill", "decode"):
-            raise MXNetError(
-                f"the hybrid family (layers {sorted(set(self.mixer_kinds()))}"
-                f") builds no {which!r} symbol")
+        if which not in self.phases:
+            raise MXNetError(f"{self.name} builds no {which!r} symbol (it "
+                             f"builds {self.phases})")
         return _trunk(self, step=(which == "decode"))
 
     def to_dict(self):

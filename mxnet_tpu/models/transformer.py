@@ -22,9 +22,12 @@ and pipeline stages from ``MeshPlan(pp=...)``, with **zero** per-op
 parallelism".
 """
 
+import functools
+
 from .. import symbol as sym
 from ..attribute import AttrScope
 from ..base import MXNetError
+from ..kv_cache import KV_DTYPES, kv_quantized
 from ..parallel import logical_axes
 
 
@@ -47,21 +50,37 @@ def lm_partition_rules(sequence_parallel: bool = False):
     )
 
 
-def _block(x, d_model, num_heads, d_ff, name, causal, dropout,
-           block_size):
-    # attention sublayer (pre-LN).  The fused QKV projection output
-    # feeds QKVSelfAttention DIRECTLY — the packed-heads Pallas kernel
-    # slices heads by lane span, so no reshape/slice/transpose ops
-    # exist between the two matmuls (they measured ~20 ms/step at
-    # GPT-2-small scale; tools/profile_transformer.py, PERF.md)
+def _block(x, d_model, d_ff, name, attend, dropout=0.0, lora=(), layer=0):
+    """One pre-LN residual block, the attention sublayer given by
+    ``attend(qkv) -> (att_out, cache_outs)``: ``QKVSelfAttention`` for
+    the training symbol, an op over the K/V cache for a serving one.
+    The fused QKV projection output feeds ``attend`` DIRECTLY — the
+    packed-heads Pallas kernel slices heads by lane span, so no
+    reshape/slice/transpose ops exist between the two matmuls (they
+    measured ~20 ms/step at GPT-2-small scale;
+    tools/profile_transformer.py, PERF.md).
+
+    ``lora``: rank buckets (ints).  Each bucket adds a per-stream
+    LoRA epilogue on the fused QKV projection — the adapter slabs
+    ``adapter_a_r{rb}``/``adapter_b_r{rb}`` (N, L, d, rb)/(N, L, rb,
+    3d) are gathered by the ``adapter_slots_r{rb}`` (B,) id vector,
+    slot 0 selecting the base bits exactly (``ops/adapter.py``).  An
+    empty tuple builds the pre-adapter graph byte-identically."""
     h = sym.LayerNorm(x, name=f"{name}_ln1")
     qkv = sym.FullyConnected(
         h, num_hidden=3 * d_model, flatten=False, name=f"{name}_qkv",
         weight=sym.Variable(f"{name}_qkv_weight",
                             attr=logical_axes("qkv", "embed")),
         bias=sym.Variable(f"{name}_qkv_bias", attr=logical_axes("qkv")))
-    att = sym.QKVSelfAttention(qkv, num_heads=num_heads, causal=causal,
-                               block_size=block_size, name=f"{name}_attn")
+    for rb in (lora or ()):
+        # a stream lives in at most one bucket (slot 0 elsewhere), so
+        # chaining buckets is exact: slot-0 rows pass base bits through
+        qkv = sym.LoraGatherDelta(
+            qkv, h, sym.Variable(f"adapter_a_r{rb}"),
+            sym.Variable(f"adapter_b_r{rb}"),
+            sym.Variable(f"adapter_slots_r{rb}"),
+            layer=layer, name=f"{name}_lora_r{rb}")
+    att, cache_outs = attend(qkv)
     att = sym.FullyConnected(
         att, num_hidden=d_model, flatten=False, name=f"{name}_proj",
         weight=sym.Variable(f"{name}_proj_weight",
@@ -86,7 +105,24 @@ def _block(x, d_model, num_heads, d_ff, name, causal, dropout,
         bias=sym.Variable(f"{name}_ff2_bias", attr=logical_axes("embed")))
     if dropout > 0:
         h = sym.Dropout(h, p=dropout, name=f"{name}_ff_drop")
-    return x + h
+    return x + h, cache_outs
+
+
+def _embed(vocab_size, d_model):
+    return sym.Embedding(sym.Variable("data"), input_dim=vocab_size,
+                         output_dim=d_model, name="tok_embed",
+                         weight=sym.Variable(
+                             "tok_embed_weight",
+                             attr=logical_axes("vocab", "embed")))
+
+
+def _head(x, vocab_size):
+    x = sym.LayerNorm(x, name="ln_f")
+    return sym.FullyConnected(
+        x, num_hidden=vocab_size, flatten=False, name="head",
+        weight=sym.Variable("head_weight",
+                            attr=logical_axes("vocab", "embed")),
+        bias=sym.Variable("head_bias", attr=logical_axes("vocab")))
 
 
 def transformer_lm(vocab_size, seq_len, num_layers=4, num_heads=4,
@@ -109,13 +145,8 @@ def transformer_lm(vocab_size, seq_len, num_layers=4, num_heads=4,
     if d_model % num_heads:
         raise ValueError(f"d_model {d_model} % num_heads {num_heads} != 0")
     d_ff = d_ff or 4 * d_model
-    data = sym.Variable("data")
     label = sym.Variable("softmax_label")
-    x = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
-                      name="tok_embed",
-                      weight=sym.Variable(
-                          "tok_embed_weight",
-                          attr=logical_axes("vocab", "embed")))
+    x = _embed(vocab_size, d_model)
     if dtype != "float32":
         x = sym.Cast(x, dtype=dtype, name="embed_cast")
     # learned positional embedding: a (T, d) parameter broadcast over
@@ -132,14 +163,13 @@ def transformer_lm(vocab_size, seq_len, num_layers=4, num_heads=4,
         # so MeshPlan(pp=S) can cut the graph into S stages
         # (mxnet_tpu.pp.split_blocks)
         with AttrScope(__pp_block__=str(i)):
-            x = _block(x, d_model, num_heads, d_ff, f"layer{i}", causal,
-                       dropout, block_size)
-    x = sym.LayerNorm(x, name="ln_f")
-    logits = sym.FullyConnected(
-        x, num_hidden=vocab_size, flatten=False, name="head",
-        weight=sym.Variable("head_weight",
-                            attr=logical_axes("vocab", "embed")),
-        bias=sym.Variable("head_bias", attr=logical_axes("vocab")))
+            x, _ = _block(
+                x, d_model, d_ff, f"layer{i}",
+                lambda qkv, i=i: (sym.QKVSelfAttention(
+                    qkv, num_heads=num_heads, causal=causal,
+                    block_size=block_size, name=f"layer{i}_attn"), []),
+                dropout=dropout)
+    logits = _head(x, vocab_size)
     if head == "ce":
         return sym.SoftmaxCELoss(logits, label, use_ignore=True,
                                  ignore_label=0, name="softmax")
@@ -169,107 +199,91 @@ def get_symbol(vocab_size=10000, seq_len=128, num_layers=4, num_heads=4,
 # ---------------------------------------------------------------------------
 
 
-def _decode_block(x, d_model, num_heads, d_ff, name, kv_block, attend,
-                  lora=(), layer=0):
-    """One pre-LN transformer block with the attention sublayer
-    replaced by ``attend(qkv) -> (att_out, *cache_outs)``.
-
-    ``lora``: rank buckets (ints).  Each bucket adds a per-stream
-    LoRA epilogue on the fused QKV projection — the adapter slabs
-    ``adapter_a_r{rb}``/``adapter_b_r{rb}`` (N, L, d, rb)/(N, L, rb,
-    3d) are gathered by the ``adapter_slots_r{rb}`` (B,) id vector,
-    slot 0 selecting the base bits exactly (``ops/adapter.py``).  An
-    empty tuple builds the pre-adapter graph byte-identically."""
-    h = sym.LayerNorm(x, name=f"{name}_ln1")
-    qkv = sym.FullyConnected(
-        h, num_hidden=3 * d_model, flatten=False, name=f"{name}_qkv",
-        weight=sym.Variable(f"{name}_qkv_weight",
-                            attr=logical_axes("qkv", "embed")),
-        bias=sym.Variable(f"{name}_qkv_bias", attr=logical_axes("qkv")))
-    for rb in (lora or ()):
-        # a stream lives in at most one bucket (slot 0 elsewhere), so
-        # chaining buckets is exact: slot-0 rows pass base bits through
-        qkv = sym.LoraGatherDelta(
-            qkv, h, sym.Variable(f"adapter_a_r{rb}"),
-            sym.Variable(f"adapter_b_r{rb}"),
-            sym.Variable(f"adapter_slots_r{rb}"),
-            layer=layer, name=f"{name}_lora_r{rb}")
-    att, cache_outs = attend(qkv)
-    att = sym.FullyConnected(
-        att, num_hidden=d_model, flatten=False, name=f"{name}_proj",
-        weight=sym.Variable(f"{name}_proj_weight",
-                            attr=logical_axes("embed", "heads")),
-        bias=sym.Variable(f"{name}_proj_bias",
-                          attr=logical_axes("embed")))
-    x = x + att
-    h = sym.LayerNorm(x, name=f"{name}_ln2")
-    h = sym.FullyConnected(
-        h, num_hidden=d_ff, flatten=False, name=f"{name}_ff1",
-        weight=sym.Variable(f"{name}_ff1_weight",
-                            attr=logical_axes("ffn", "embed")),
-        bias=sym.Variable(f"{name}_ff1_bias", attr=logical_axes("ffn")))
-    h = sym.Activation(h, act_type="gelu", name=f"{name}_gelu")
-    h = sym.FullyConnected(
-        h, num_hidden=d_model, flatten=False, name=f"{name}_ff2",
-        weight=sym.Variable(f"{name}_ff2_weight",
-                            attr=logical_axes("embed", "ffn")),
-        bias=sym.Variable(f"{name}_ff2_bias", attr=logical_axes("embed")))
-    return x + h, cache_outs
-
-
-def kv_pool_var(name: str):
-    """A KV value-pool Variable (P, KVB, H·D) —
+def _cache_var(name: str):
+    """A K/V pool Variable: values (P, KVB, H·D) —
     ``kv_cache.value_pool_shape``: lane-dense, because D = 64 is half
     a lane tile and a (…, H, D) pool is re-laid-out whole by every
-    program that touches it.  The last dim is 'heads', the pool's
-    tensor-parallel shard axis: heads are contiguous D-lane spans, so
-    the rules table's 'tp' split still hands each device H/tp whole
-    heads, exactly like the attention."""
+    program that touches it — or a quantized pool's (P, KVB, H) float32
+    scales.  The last dim is 'heads', the pool's tensor-parallel shard
+    axis: heads are contiguous D-lane spans, so the rules table's 'tp'
+    split still hands each device H/tp whole heads, exactly like the
+    attention, and the scales shard alongside the values they scale."""
     return sym.Variable(name, attr=logical_axes(None, None, "heads"))
-
-
-def kv_scale_var(name: str):
-    """A quantized pool's (P, KVB, H) float32 scale Variable — sharded
-    head-wise alongside the values it scales."""
-    return sym.Variable(name, attr=logical_axes(None, None, "heads"))
-
-
-def _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block, attend_for,
-              vocab_size, lora=None):
-    """Embedding -> blocks -> ln_f -> head logits, with per-layer
-    attention provided by ``attend_for(layer_idx)``."""
-    d_ff = d_ff or 4 * d_model
-    data = sym.Variable("data")            # (B, S) token ids
-    positions = sym.Variable("positions")  # (B, S) absolute positions
-    x = sym.Embedding(data, input_dim=vocab_size, output_dim=d_model,
-                      name="tok_embed",
-                      weight=sym.Variable(
-                          "tok_embed_weight",
-                          attr=logical_axes("vocab", "embed")))
-    pos = sym.Variable("pos_embed_weight",
-                       attr=logical_axes("length", "embed"))
-    x = x + sym.take(pos, positions, name="pos_lookup")
-    caches = []
-    for i in range(num_layers):
-        x, cache_outs = _decode_block(x, d_model, num_heads, d_ff,
-                                      f"layer{i}", kv_block,
-                                      attend_for(i), lora=lora, layer=i)
-        caches.extend(cache_outs)
-    x = sym.LayerNorm(x, name="ln_f")
-    logits = sym.FullyConnected(
-        x, num_hidden=vocab_size, flatten=False, name="head",
-        weight=sym.Variable("head_weight",
-                            attr=logical_axes("vocab", "embed")),
-        bias=sym.Variable("head_bias", attr=logical_axes("vocab")))
-    return sym.Group([logits] + caches)
 
 
 def _kv_quant(kv_dtype):
-    from ..kv_cache import KV_DTYPES, kv_quantized
-
     if kv_dtype not in KV_DTYPES:
         raise ValueError(f"kv_dtype {kv_dtype!r} not in {KV_DTYPES}")
     return kv_quantized(kv_dtype)
+
+
+# phase -> (the op that meets the paged cache, its quantize-on-write
+# twin, the feeds it takes after the block table).  ``prefill`` attends
+# the prompt alone (``QKVSelfAttentionPrefill``) and its op WRITES the
+# K/V that returned; the others attend THROUGH the cache.
+PHASES = {
+    "prefill": ("PagedCacheWrite", "PagedCacheWriteQ", ("lengths",)),
+    "decode": ("QKVPagedAttentionDecode", "QKVPagedAttentionDecodeQ",
+               ("lengths",)),
+    "prefix_prefill": ("QKVPagedPrefillAttend", "QKVPagedPrefillAttendQ",
+                       ("start", "lengths")),
+    "verify": ("QKVPagedVerifyAttend", "QKVPagedVerifyAttendQ",
+               ("start", "lengths")),
+}
+
+
+def _serving_lm(phase, vocab_size, num_layers, num_heads, d_model, d_ff,
+                kv_block, kv_dtype, lora, paged=True):
+    """Embedding -> blocks -> ln_f -> head logits for one serving phase:
+    ``[logits] + [updated caches ...]``, a layer's caches [k, v] or,
+    quantized, [k, v, k_scale, v_scale]."""
+    if phase not in PHASES:
+        raise MXNetError(f"the transformer_lm family builds no {phase!r} "
+                         f"symbol (it builds {tuple(PHASES)})")
+    quant = _kv_quant(kv_dtype)
+    op = getattr(sym, PHASES[phase][1 if quant else 0])
+    feeds = {n: sym.Variable(n) for n in PHASES[phase][2]}
+    n_cache = 4 if quant else 2
+
+    def cache(name):
+        pools = ("kpool", "vpool", "kscale", "vscale")[:n_cache]
+        return [_cache_var(f"{name}_{p}") for p in pools] \
+            + [sym.Variable("block_table")] + list(feeds.values())
+
+    def attend(name, qkv):
+        if phase == "prefill":
+            att = sym.QKVSelfAttentionPrefill(
+                qkv, num_heads=num_heads, block_size=kv_block,
+                name=f"{name}_attn")
+            if not paged:
+                return att[0], [att[1], att[2]]
+            pools = op(att[1], att[2], *cache(name),
+                       name=f"{name}_cache_write")
+            return att[0], [pools[j] for j in range(n_cache)]
+        if not paged:  # decode against a contiguous (B, C, H, D) cache
+            att = sym.QKVSelfAttentionDecode(
+                qkv, sym.Variable(f"{name}_kcache"),
+                sym.Variable(f"{name}_vcache"), feeds["lengths"],
+                num_heads=num_heads, block_size=kv_block,
+                name=f"{name}_attn")
+            return att[0], [att[1], att[2]]
+        att = op(qkv, *cache(name), num_heads=num_heads,
+                 name=f"{name}_attn")
+        return att[0], [att[1 + j] for j in range(n_cache)]
+
+    d_ff = d_ff or 4 * d_model
+    x = _embed(vocab_size, d_model)  # data: (B, S) token ids
+    pos = sym.Variable("pos_embed_weight",
+                       attr=logical_axes("length", "embed"))
+    # positions: (B, S) absolute, so one symbol serves every bucket
+    x = x + sym.take(pos, sym.Variable("positions"), name="pos_lookup")
+    caches = []
+    for i in range(num_layers):
+        x, cache_outs = _block(x, d_model, d_ff, f"layer{i}",
+                               functools.partial(attend, f"layer{i}"),
+                               lora=lora, layer=i)
+        caches.extend(cache_outs)
+    return sym.Group([_head(x, vocab_size)] + caches)
 
 
 def transformer_lm_prefill(vocab_size, num_layers=4, num_heads=4,
@@ -291,36 +305,8 @@ def transformer_lm_prefill(vocab_size, num_layers=4, num_heads=4,
     ``layer{i}_kscale``/``layer{i}_vscale`` (P, KVB, H) float32 scale
     pools, making each layer contribute FOUR cache outputs.
     """
-    lengths = sym.Variable("lengths")
-    quant = _kv_quant(kv_dtype)
-
-    def attend_for(i):
-        def attend(qkv):
-            att = sym.QKVSelfAttentionPrefill(
-                qkv, num_heads=num_heads, block_size=kv_block,
-                name=f"layer{i}_attn")
-            out, k, v = att[0], att[1], att[2]
-            if not paged:
-                return out, [k, v]
-            if quant:
-                pools = sym.PagedCacheWriteQ(
-                    k, v, kv_pool_var(f"layer{i}_kpool"),
-                    kv_pool_var(f"layer{i}_vpool"),
-                    kv_scale_var(f"layer{i}_kscale"),
-                    kv_scale_var(f"layer{i}_vscale"),
-                    sym.Variable("block_table"), lengths,
-                    name=f"layer{i}_cache_write")
-                return out, [pools[0], pools[1], pools[2], pools[3]]
-            pools = sym.PagedCacheWrite(
-                k, v, kv_pool_var(f"layer{i}_kpool"),
-                kv_pool_var(f"layer{i}_vpool"),
-                sym.Variable("block_table"), lengths,
-                name=f"layer{i}_cache_write")
-            return out, [pools[0], pools[1]]
-        return attend
-
-    return _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block,
-                     attend_for, vocab_size, lora=lora)
+    return _serving_lm("prefill", vocab_size, num_layers, num_heads,
+                       d_model, d_ff, kv_block, kv_dtype, lora, paged)
 
 
 def transformer_lm_prefix_prefill(vocab_size, num_layers=4, num_heads=4,
@@ -339,31 +325,8 @@ def transformer_lm_prefix_prefill(vocab_size, num_layers=4, num_heads=4,
     Bit-identical (lax path, fp32 pools) to the matching rows of the
     full causal forward — see ``ops.attention.prefix_suffix_attention``.
     """
-    lengths = sym.Variable("lengths")
-    start = sym.Variable("start")
-    quant = _kv_quant(kv_dtype)
-
-    def attend_for(i):
-        def attend(qkv):
-            if quant:
-                att = sym.QKVPagedPrefillAttendQ(
-                    qkv, kv_pool_var(f"layer{i}_kpool"),
-                    kv_pool_var(f"layer{i}_vpool"),
-                    kv_scale_var(f"layer{i}_kscale"),
-                    kv_scale_var(f"layer{i}_vscale"),
-                    sym.Variable("block_table"), start, lengths,
-                    num_heads=num_heads, name=f"layer{i}_attn")
-                return att[0], [att[1], att[2], att[3], att[4]]
-            att = sym.QKVPagedPrefillAttend(
-                qkv, kv_pool_var(f"layer{i}_kpool"),
-                kv_pool_var(f"layer{i}_vpool"),
-                sym.Variable("block_table"), start, lengths,
-                num_heads=num_heads, name=f"layer{i}_attn")
-            return att[0], [att[1], att[2]]
-        return attend
-
-    return _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block,
-                     attend_for, vocab_size, lora=lora)
+    return _serving_lm("prefix_prefill", vocab_size, num_layers,
+                       num_heads, d_model, d_ff, kv_block, kv_dtype, lora)
 
 
 def transformer_lm_verify(vocab_size, num_layers=4, num_heads=4,
@@ -383,31 +346,8 @@ def transformer_lm_verify(vocab_size, num_layers=4, num_heads=4,
     (lax path) to the single-token decode step at length
     ``start + 1 + i`` over the same cache bytes — see
     ``ops.attention.QKVPagedVerifyAttend``."""
-    lengths = sym.Variable("lengths")
-    start = sym.Variable("start")
-    quant = _kv_quant(kv_dtype)
-
-    def attend_for(i):
-        def attend(qkv):
-            if quant:
-                att = sym.QKVPagedVerifyAttendQ(
-                    qkv, kv_pool_var(f"layer{i}_kpool"),
-                    kv_pool_var(f"layer{i}_vpool"),
-                    kv_scale_var(f"layer{i}_kscale"),
-                    kv_scale_var(f"layer{i}_vscale"),
-                    sym.Variable("block_table"), start, lengths,
-                    num_heads=num_heads, name=f"layer{i}_attn")
-                return att[0], [att[1], att[2], att[3], att[4]]
-            att = sym.QKVPagedVerifyAttend(
-                qkv, kv_pool_var(f"layer{i}_kpool"),
-                kv_pool_var(f"layer{i}_vpool"),
-                sym.Variable("block_table"), start, lengths,
-                num_heads=num_heads, name=f"layer{i}_attn")
-            return att[0], [att[1], att[2]]
-        return attend
-
-    return _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block,
-                     attend_for, vocab_size, lora=lora)
+    return _serving_lm("verify", vocab_size, num_layers, num_heads,
+                       d_model, d_ff, kv_block, kv_dtype, lora)
 
 
 def transformer_lm_decode(vocab_size, num_layers=4, num_heads=4,
@@ -425,51 +365,23 @@ def transformer_lm_decode(vocab_size, num_layers=4, num_heads=4,
     Prefill + N decode steps is bit-identical (lax path) to the
     full-sequence forward — the page size is the attention block size.
     """
-    lengths = sym.Variable("lengths")
-    quant = _kv_quant(kv_dtype)
-
-    def attend_for(i):
-        def attend(qkv):
-            if paged and quant:
-                att = sym.QKVPagedAttentionDecodeQ(
-                    qkv, kv_pool_var(f"layer{i}_kpool"),
-                    kv_pool_var(f"layer{i}_vpool"),
-                    kv_scale_var(f"layer{i}_kscale"),
-                    kv_scale_var(f"layer{i}_vscale"),
-                    sym.Variable("block_table"), lengths,
-                    num_heads=num_heads, name=f"layer{i}_attn")
-                return att[0], [att[1], att[2], att[3], att[4]]
-            elif paged:
-                att = sym.QKVPagedAttentionDecode(
-                    qkv, kv_pool_var(f"layer{i}_kpool"),
-                    kv_pool_var(f"layer{i}_vpool"),
-                    sym.Variable("block_table"), lengths,
-                    num_heads=num_heads, name=f"layer{i}_attn")
-            else:
-                att = sym.QKVSelfAttentionDecode(
-                    qkv, sym.Variable(f"layer{i}_kcache"),
-                    sym.Variable(f"layer{i}_vcache"), lengths,
-                    num_heads=num_heads, block_size=kv_block,
-                    name=f"layer{i}_attn")
-            return att[0], [att[1], att[2]]
-        return attend
-
-    return _lm_trunk(num_layers, num_heads, d_model, d_ff, kv_block,
-                     attend_for, vocab_size, lora=lora)
+    return _serving_lm("decode", vocab_size, num_layers, num_heads,
+                       d_model, d_ff, kv_block, kv_dtype, lora, paged)
 
 
 class DenseSpec:
-    """This family as ``DecodeEngine`` sees a model: sizes, the kind of
-    each layer's per-stream state, the pools and the serving symbols.
-    What ``DecodeEngine(params, vocab_size=..., num_layers=...,
-    num_heads=..., d_model=...)`` builds for itself; a family with other
-    layers brings its own (``models/hybrid_lm.py`` ``HybridSpec``)."""
+    """This family as ``DecodeEngine`` sees a model (the protocol is in
+    its docstring): what ``DecodeEngine(params, vocab_size=...,
+    num_layers=..., num_heads=..., d_model=...)`` builds for itself; a
+    family with other layers brings its own (``models/hybrid_lm.py``
+    ``HybridSpec``)."""
 
+    name = "the transformer_lm family"
     feeds = ("data", "positions", "lengths", "block_table", "start")
-    _BUILDERS = {"prefill": transformer_lm_prefill,
-                 "decode": transformer_lm_decode,
-                 "prefix_prefill": transformer_lm_prefix_prefill,
-                 "verify": transformer_lm_verify}
+    phases = tuple(PHASES)
+    kv_dtypes = KV_DTYPES
+    positions = "pos_embed_weight"
+    partition_rules = staticmethod(lm_partition_rules)
 
     def __init__(self, vocab_size, num_layers, num_heads, d_model,
                  d_ff=None):
@@ -482,9 +394,12 @@ class DenseSpec:
         self.d_model = int(d_model)
         self.d_ff = d_ff
         self.head_dim = self.d_model // self.num_heads
+        self.lora_width = 3 * self.d_model  # the fused QKV projection
 
-    def cache_kinds(self):
-        return ("pages",) * self.num_layers
+    def pool_kinds(self, kv_dtype="fp32"):
+        layer = ("pages", "pages") + (("scales", "scales")
+                                      if _kv_quant(kv_dtype) else ())
+        return layer * self.num_layers
 
     def pools(self, cache_blocks, kv_block, slots, dtype,
               kv_dtype="fp32"):
@@ -505,10 +420,6 @@ class DenseSpec:
         return out
 
     def symbol(self, which, kv_block=16, kv_dtype="fp32", lora=None):
-        kw = dict(vocab_size=self.vocab_size, num_layers=self.num_layers,
-                  num_heads=self.num_heads, d_model=self.d_model,
-                  d_ff=self.d_ff, kv_block=kv_block, kv_dtype=kv_dtype,
-                  lora=lora)
-        if which in ("prefill", "decode"):
-            kw["paged"] = True
-        return self._BUILDERS[which](**kw)
+        return _serving_lm(which, self.vocab_size, self.num_layers,
+                           self.num_heads, self.d_model, self.d_ff,
+                           kv_block, kv_dtype, lora)

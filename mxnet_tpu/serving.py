@@ -1009,10 +1009,47 @@ class DecodeEngine:
         Parameter arrays by training-symbol name (``Module.get_params``
         arg dict, merged aux, or a ``Predictor``'s weights).
     vocab_size, num_layers, num_heads, d_model, d_ff : int
-        Architecture of the served ``transformer_lm``.
+        Architecture of the served ``transformer_lm``: the keywords
+        build ``model=models.transformer.DenseSpec(...)``.
+    model : spec, optional
+        Any other family.  ALL the engine asks of a served model, and
+        it asks by these names only (no class is looked at):
+
+        * ``vocab_size``, ``num_layers``, ``d_model``; ``kv_heads``,
+          ``head_dim`` (the K/V page geometry; 0 without pages);
+          ``name`` (how a refusal calls it);
+        * ``phases``: which of ``prefill``, ``decode``,
+          ``prefix_prefill``, ``verify`` ``symbol(phase, kv_block=,
+          kv_dtype=, lora=)`` builds (it refuses the rest).  The first
+          two are required; ``prefix_cache`` and ``prefill_chunk`` need
+          ``prefix_prefill`` and ``spec_tokens`` needs ``verify``, and
+          all three need every layer's state in pages;
+        * ``feeds``: the symbols' arguments that are no parameter:
+          of ``data positions lengths block_table start slots``;
+        * ``pools(cache_blocks, kv_block, slots, dtype, kv_dtype)``:
+          the per-stream state the programs carry, ``(name, shape,
+          dtype, fill)`` in the symbols' output order, and
+          ``pool_kinds(kv_dtype)``, the kind of each beside it:
+          ``pages`` / ``scales`` (rows by page; a migration frame),
+          ``slots`` (rows by the ``slots`` feed, one a stream; what
+          ``return_state`` reads) / ``slots_aux`` (the same, read by
+          the programs only), ``counters`` (``ops.hybrid.MOE_COUNTERS``,
+          read by ``stats()``);
+        * ``kv_dtypes``: the K/V storage types it can hold;
+          ``lora_width``: the width of the projection that takes LoRA
+          epilogues, 0 for none; ``partition_rules``: None, or a
+          callable giving the rules table of a ``tp`` x ``pp`` mesh
+          (served by ``serving_mesh.MeshPrograms``, which is written for
+          the ``transformer_lm`` block and reads ``num_heads``, ``d_ff``);
+          ``positions``: the parameter whose rows are the learned
+          positions and bound ``max_len``, or None (``max_len``
+          required).
+
+        A feature the spec cannot carry is refused at construction, by
+        name; its catalog default is taken only where it can.
     max_len : int, optional
         Longest prompt+generation a stream may reach.  Default: the
-        ``pos_embed_weight`` row count.
+        learned positions' row count.
     kv_block : int
         Cache page size in tokens (env ``MXNET_SERVING_KV_BLOCK``,
         default 16).  Also the attention block size.
@@ -1047,8 +1084,7 @@ class DecodeEngine:
         import jax
 
         from .kv_cache import (BlockAllocator, blocks_for_tokens,
-                               bucket_ladder, kv_quantized,
-                               kv_storage_dtype)
+                               bucket_ladder, kv_storage_dtype)
         from .executor import build_graph_fn
         from .models.transformer import DenseSpec
         from .prefix_cache import EVICT_POLICIES, PrefixCache
@@ -1073,7 +1109,6 @@ class DecodeEngine:
                 "(vocab_size, num_layers, num_heads, d_model, d_ff), "
                 "not both")
         self._spec = model
-        dense = isinstance(model, DenseSpec)
         vocab_size, num_layers = model.vocab_size, model.num_layers
         # the K/V page geometry (a spec without attention layers has
         # none: 0 heads, and its pools hold no pages)
@@ -1086,11 +1121,15 @@ class DecodeEngine:
         if self._kv_dtype not in KV_DTYPES:
             raise MXNetError(
                 f"kv_dtype {self._kv_dtype!r} must be one of {KV_DTYPES}")
-        self._quant = kv_quantized(self._kv_dtype)
         kv_store_dtype = kv_storage_dtype(self._kv_dtype)  # may raise
-        if prefix_cache is None and not dense \
+        self._pool_kinds = tuple(model.pool_kinds(self._kv_dtype))
+        slots = "slots" in self._pool_kinds
+        # a suffix prefill continues from pages alone; a verify step
+        # rolls back pages alone
+        suffix = "prefix_prefill" in model.phases and not slots
+        if prefix_cache is None and not suffix \
                 and get_env("MXNET_SERVING_PREFIX_CACHE", None, str) is None:
-            prefix_cache = 0  # the catalog's default (on) is the dense LM's
+            prefix_cache = 0  # the catalog's default (on): where carried
         if prefix_cache is None:
             prefix_cache = _read_env_int("MXNET_SERVING_PREFIX_CACHE",
                                          lo=0)
@@ -1171,44 +1210,28 @@ class DecodeEngine:
         if self._pp < 1:
             raise MXNetError(
                 f"MXNET_SERVING_PP={self._pp} must be >= 1")
-        if self._H % self._tp:
-            raise MXNetError(
-                f"MXNET_SERVING_TP={self._tp} does not divide "
-                f"num_heads {self._H} — attention heads shard over "
-                f"'tp' whole")
-        if self._L % self._pp:
-            raise MXNetError(
-                f"MXNET_SERVING_PP={self._pp} does not divide "
-                f"num_layers {self._L} — pipeline stages hold equal "
-                f"layer slabs")
         n_mesh = self._tp * self._pp
-        if not dense:
-            # a family that brings its own symbols has only what it
-            # builds: every feature below needs a symbol, a pool or a
-            # state transfer this spec has none of — refused here, by
-            # name, none built and none failing silently
-            kinds = "/".join(sorted(set(model.mixer_kinds())))
-            slots = "slots" in model.cache_kinds()
-            asked = {
-                "prefix_cache": self._prefix_on,
-                "prefill_chunk": bool(self._chunk),
-                "spec_tokens": bool(self._spec_k),
-                f"kv_dtype={self._kv_dtype!r}": self._quant,
-                f"tp={self._tp}": self._tp > 1,
-                f"pp={self._pp}": self._pp > 1,
-                "adapters": bool(adapters),
-            }
-            for feature, on in asked.items():
-                if on:
-                    raise MXNetError(
-                        f"{feature} is not built for a model spec with "
-                        f"{kinds} layers"
-                        + (": a kda layer's per-stream state lives in "
-                           "a slot, which this feature would have to "
-                           "share, cut, roll back, quantize or shard"
-                           if slots else ""))
-            if adapters is None:
-                adapters = False  # (the env default is for the dense LM)
+        # a feature needs a symbol, a pool or a state transfer of the
+        # spec: what it does not list is refused here, by name, none
+        # built and none failing silently
+        if adapters is None and not model.lora_width:
+            adapters = False  # the env's default: where carried
+        for feature, asked, carried in (
+                ("prefix_cache", self._prefix_on, suffix),
+                ("prefill_chunk", self._chunk, suffix),
+                ("spec_tokens", self._spec_k,
+                 "verify" in model.phases and not slots),
+                (f"kv_dtype={self._kv_dtype!r}", True,
+                 self._kv_dtype in model.kv_dtypes),
+                (f"tp={self._tp}", self._tp > 1, model.partition_rules),
+                (f"pp={self._pp}", self._pp > 1, model.partition_rules),
+                ("adapters", adapters, model.lora_width)):
+            if asked and not carried:
+                raise MXNetError(
+                    f"{feature} is not built for {model.name}"
+                    + (": a layer's per-stream state lives in a slot, "
+                       "which this feature would have to share, cut, "
+                       "roll back, quantize or shard" if slots else ""))
         if devices is None:
             devices = os.environ.get("MXNET_SERVING_DEVICES") or None
         if isinstance(devices, str):
@@ -1257,17 +1280,13 @@ class DecodeEngine:
             else kv_store_dtype
         self._mesh = None
         if n_mesh > 1:
-            from .models.transformer import lm_partition_rules
             from .parallel import MeshPlan
             from .serving_mesh import MeshPrograms
             self._mesh = MeshPrograms(
                 MeshPlan(mesh_devs, dp=1, tp=self._tp, pp=self._pp,
-                         rules=lm_partition_rules()),
-                num_layers=self._L, num_heads=self._H,
-                d_model=int(d_model), d_ff=d_ff,
-                vocab_size=self._vocab, kv_block=self._kv_block,
-                kv_dtype=self._kv_dtype, pool_dtype=self._np_dtype,
-                seed=int(seed))
+                         rules=model.partition_rules()),
+                model, kv_block=self._kv_block, kv_dtype=self._kv_dtype,
+                pool_dtype=self._np_dtype, seed=int(seed))
             # every feed lands replicated; pools/params carry their
             # own NamedShardings
             dev = self._mesh.replicated
@@ -1287,26 +1306,26 @@ class DecodeEngine:
             return jax.device_put(arr, dev)
 
         host_params = {k: v for k, v in params.items()}
-        if not dense:
+        if model.positions is None:
             if max_len is None:
                 raise MXNetError(
                     "a model spec without learned positions needs "
                     "max_len")
             self._max_len = int(max_len)
         else:
-            if "pos_embed_weight" not in host_params:
+            if model.positions not in host_params:
                 raise MXNetError(
-                    "params has no 'pos_embed_weight' — the dense "
+                    f"params has no {model.positions!r} — the dense "
                     "keywords serve the transformer_lm family "
                     "(models/transformer.py); another family comes as "
                     "model=<spec>")
-            pos_rows = int(host_params["pos_embed_weight"].shape[0])
+            pos_rows = int(host_params[model.positions].shape[0])
             self._max_len = int(max_len) if max_len is not None \
                 else pos_rows
             if self._max_len > pos_rows:
                 raise MXNetError(
                     f"max_len {self._max_len} exceeds the model's "
-                    f"learned positions ({pos_rows} pos_embed_weight "
+                    f"learned positions ({pos_rows} {model.positions} "
                     f"rows)")
 
         self._max_blocks_seq = blocks_for_tokens(self._max_len,
@@ -1371,7 +1390,8 @@ class DecodeEngine:
         if adapters is None:
             adapters = _adapters.adapters_enabled()
         if adapters is True:
-            adapters = _adapters.pool_from_env(self._L, int(d_model))
+            adapters = _adapters.pool_from_env(self._L, int(d_model),
+                                               model.lora_width)
         elif adapters is False:
             adapters = None
         if adapters is not None \
@@ -1388,12 +1408,12 @@ class DecodeEngine:
                     "the rules-table sharding the base weights get")
             pl = self._adapter_pool
             if pl.num_layers != self._L or pl.d_model != int(d_model) \
-                    or pl.d_out != 3 * int(d_model):
+                    or pl.d_out != model.lora_width:
                 raise MXNetError(
                     f"AdapterPool geometry (layers={pl.num_layers}, "
                     f"d_model={pl.d_model}, d_out={pl.d_out}) does not "
                     f"match the engine (layers={self._L}, d_model="
-                    f"{int(d_model)}, d_out={3 * int(d_model)})")
+                    f"{int(d_model)}, d_out={model.lora_width})")
         self._lora = tuple(self._adapter_pool.rank_buckets) \
             if self._adapter_pool is not None else None
         if tenant_quota is None:
@@ -1415,39 +1435,40 @@ class DecodeEngine:
         # -- graphs + pools ---------------------------------------------
         kw = dict(kv_block=self._kv_block, kv_dtype=self._kv_dtype,
                   lora=self._lora)
-        dec_sym = model.symbol("decode", **kw)
-        self._dec_gfn = build_graph_fn(dec_sym)
-        self._pre_gfn = build_graph_fn(model.symbol("prefill", **kw))
-        self._pfx_gfn = None
+        # a chunk is a suffix-prefill continuation, so chunked prefill
+        # needs that graph even with the prefix cache off
+        phases = ["decode", "prefill"]
         if self._prefix_on or self._chunk:
-            # a chunk is a suffix-prefill continuation, so chunked
-            # prefill needs this graph even with the prefix cache off
-            self._pfx_gfn = build_graph_fn(
-                model.symbol("prefix_prefill", **kw))
-        self._ver_gfn = build_graph_fn(model.symbol("verify", **kw)) \
-            if self._spec_k else None
+            phases.append("prefix_prefill")
+        if self._spec_k:
+            phases.append("verify")
+        syms = {ph: model.symbol(ph, **kw) for ph in phases}
+        self._gfn = {ph: build_graph_fn(s) for ph, s in syms.items()}
         # per-stream state the programs carry, by graph-argument name,
-        # in the order the symbols hand it back: K/V pages for
-        # attention layers ([k, v] or, quantized, [k, v, k_scale,
-        # v_scale] a layer), slots (state, conv tail) for kda layers
+        # in the order the symbols hand it back, and each pool's kind
+        # beside it: K/V pages ([k, v] or, quantized, [k, v, k_scale,
+        # v_scale] a layer), slots, counters
         n_slots = 1 + self._max_streams
         layout = model.pools(int(cache_blocks), self._kv_block, n_slots,
                              self._np_dtype, self._kv_dtype)
         self._pool_names = tuple(n for n, _, _, _ in layout)
         self._slot_alloc = SlotAllocator(self._max_streams) \
-            if "slots" in model.cache_kinds() else None
-        self._counters_at = self._pool_names.index("moe_counters") \
-            if "moe_counters" in self._pool_names else None
+            if slots else None
+        self._counters_at = self._pool_kinds.index("counters") \
+            if "counters" in self._pool_kinds else None
         # the pools are donated to every program; stats() reads the
         # counters among them from another thread, under this lock
         self._pools_lock = threading.Lock()
-        feed = set(model.feeds) | set(self._pool_names)
-        if self._lora:
-            # adapter slabs + slot vectors are RUNTIME args (like the
-            # pools), never baked params — publish stays drain-free
-            feed |= {f"adapter_{t}_r{rb}" for rb in self._lora
-                     for t in ("a", "b", "slots")}
-        self._param_names = [n for n in dec_sym.list_arguments()
+        # the runtime tail of every program, after the pools: the slot
+        # ids (a spec with slots), then per rank bucket the adapter
+        # slabs + slot vector — RUNTIME args (like the pools), never
+        # baked params: publish stays drain-free
+        self._runtime_names = (("slots",) if slots else ()) + tuple(
+            f"adapter_{t}_r{rb}" for rb in self._lora or ()
+            for t in ("a", "b", "slots"))
+        feed = set(model.feeds) | set(self._pool_names) \
+            | set(self._runtime_names)
+        self._param_names = [n for n in syms["decode"].list_arguments()
                              if n not in feed]
         missing = [n for n in self._param_names if n not in host_params]
         if missing:
@@ -1460,10 +1481,10 @@ class DecodeEngine:
         else:
             self._params = {n: to_dev(host_params[n])
                             for n in self._param_names}
-        # per-layer pool stride in self._pools: [k, v] or, quantized,
-        # [k, v, k_scale, v_scale]; on a mesh the pools are STACKED
-        # (L, pages, ...) slabs instead, sharded pp x tp
-        self._pool_stride = 4 if self._quant else 2
+        # pools a layer in self._pools (what a migration frame holds);
+        # on a mesh the pools are STACKED (L, pages, ...) slabs
+        # instead, sharded pp x tp
+        self._pool_stride = len(self._pool_kinds) // self._L
         if self._mesh is not None:
             self._pools = self._mesh.init_pools(int(cache_blocks))
         else:
@@ -1480,10 +1501,10 @@ class DecodeEngine:
 
         sizes = [int(np.prod(np.shape(p))) * np.dtype(p.dtype).itemsize
                  for p in self._pools]
-        # slot state (kda layers) apart from the K/V pages
-        self._state_pool_bytes = 0 if self._mesh is not None else sum(
-            b for n, b in zip(self._pool_names, sizes)
-            if n.endswith(("_state", "_tail")))
+        # slot state apart from the K/V pages
+        self._state_pool_bytes = sum(
+            b for k, b in zip(self._pool_kinds, sizes)
+            if k in ("slots", "slots_aux"))
         self._pool_bytes = sum(sizes) - self._state_pool_bytes
         profiler.set_gauge("serving.kv_pool_bytes", self._pool_bytes)
         profiler.set_gauge("serving.state_pool_bytes",
@@ -1629,9 +1650,9 @@ class DecodeEngine:
                 f"has {self._alloc.capacity}")
         if prefill_only and self._slot_alloc is not None:
             raise MXNetError(
-                "prefill_only page export is not built for a model spec "
-                "with kda layers: a stream's state is pages AND a slot, "
-                "and only pages have a wire format")
+                f"prefill_only page export is not built for "
+                f"{self._spec.name}: a stream's state is pages AND a "
+                f"slot, and only pages have a wire format")
         if return_state and (self._slot_alloc is None or prefill_only):
             raise MXNetError(
                 "return_state reads a stream's slot at retirement: the "
@@ -1841,13 +1862,13 @@ class DecodeEngine:
         (seconds), which is exactly the p99 a decode tier cares
         about."""
         for tp in self._prefill_buckets:
-            self._prefill_exe(tp)
+            self._exe("prefill", tp)
         for bb in self._decode_buckets:
             for mb in self._cache_buckets:
-                self._decode_exe(bb, mb)
+                self._exe("decode", bb, mb)
                 if self._spec_k:
-                    self._verify_exe(bb, mb)
-        if self._pfx_gfn is not None:
+                    self._exe("verify", bb, mb, self._spec_k + 1)
+        if "prefix_prefill" in self._gfn:
             # suffix-prefill matrix (prefix-cache hits AND prefill
             # chunks): a table bucket narrower than the suffix itself
             # can never occur (the table covers prefix + suffix
@@ -1855,7 +1876,7 @@ class DecodeEngine:
             for tp in self._prefill_buckets:
                 for mb in self._cache_buckets:
                     if mb * self._kv_block >= tp:
-                        self._prefix_prefill_exe(tp, mb)
+                        self._exe("prefix_prefill", tp, mb)
 
     def _count(self, name, value=1.0):
         self._metrics.inc(name, value)
@@ -1887,7 +1908,8 @@ class DecodeEngine:
 
             at = self._counters_at
             with self._pools_lock:
-                zero = jax.device_put(np.zeros((4,), np.int32),
+                old = self._pools[at]
+                zero = jax.device_put(np.zeros(old.shape, old.dtype),
                                       self._device)
                 self._pools = self._pools[:at] + (zero,) \
                     + self._pools[at + 1:]
@@ -1900,10 +1922,9 @@ class DecodeEngine:
         array is donated to the next step)."""
         from .ops.hybrid import MOE_COUNTERS
 
-        live = self._slot_alloc.live if self._slot_alloc is not None else 0
-        out = {"state_slots": self._slot_alloc.num_slots
-               if self._slot_alloc is not None else 0,
-               "state_slots_live": live,
+        num, live = (0, 0) if self._slot_alloc is None else \
+            (self._slot_alloc.num_slots, self._slot_alloc.live)
+        out = {"state_slots": num, "state_slots_live": live,
                "state_pool_bytes": self._state_pool_bytes}
         if self._counters_at is None:
             out.update({k: 0 for k in MOE_COUNTERS})
@@ -2109,10 +2130,6 @@ class DecodeEngine:
                 return b
         raise MXNetError(f"{what} {n} exceeds ladder {ladder}")
 
-    def _sample(self, logits, temps, seeds, steps):
-        return sample_tokens(self._base_key, logits, temps, seeds,
-                             steps)
-
     def _spec_of(self, tree):
         """AOT input specs for a params/pools pytree — on a mesh the
         spec carries each leaf's NamedSharding so the lowered
@@ -2137,15 +2154,38 @@ class DecodeEngine:
                                         sharding=self._device)
         return jax.ShapeDtypeStruct(shape, dtype)
 
-    @staticmethod
-    def _program(fn, name):
-        """``fn`` under the name its program carries in a device trace
-        (``jit_<name>``: ``jit_step_decode_b48x64``, ``jit_prefill_t1024``)."""
-        fn.__name__ = fn.__qualname__ = name
-        return fn
+    # phase -> (program name, compile span's suffix, what the key's
+    # tail counts — the span's args —, the arguments before the runtime
+    # tail, by the names they carry in the program's text)
+    _PROGRAMS = {
+        "decode": ("step_decode_b{}x{}", "decode.b{}x{}",
+                   ("batch", "blocks"),
+                   "params tokens positions lengths table temps seeds "
+                   "steps pools"),
+        "verify": ("step_verify_b{}x{}w{}", "verify.b{}x{}w{}",
+                   ("batch", "blocks", "window"),
+                   "params tokens positions start lengths table temps "
+                   "seeds steps0 pools"),
+        "prefill": ("prefill_t{}", "prefill.t{}", ("tokens",),
+                    "params tokens positions lengths table temps seeds "
+                    "steps pools"),
+        "prefix_prefill": ("prefill_suffix_t{}x{}", "prefix_prefill.t{}x{}",
+                           ("tokens", "blocks"),
+                           "params tokens positions start lengths table "
+                           "temps seeds steps pools"),
+    }
 
-    def _decode_exe(self, bb: int, mb: int):
-        key = ("decode", bb, mb)
+    def _exe(self, phase: str, *dims):
+        """The compiled program of one phase at one bucket, built once:
+        ``(params, tokens, positions, [start,] lengths, table, temps,
+        seeds, steps, pools, *runtime) -> (sampled, pools)`` with the
+        pools donated.  ``("decode", bb, mb)`` advances ``bb`` streams a
+        token; ``("verify", bb, mb, W)`` scores W = 1 + spec_tokens
+        queries a stream (keyed apart from the plain step, which stays
+        the zero-draft fast path); ``("prefill", tp)`` runs a prompt
+        padded to ``tp``; ``("prefix_prefill", tp, mb)`` a suffix padded
+        to ``tp`` over a table of ``mb`` pages (prefix + suffix)."""
+        key = (phase,) + dims
         exe = self._exe_cache.get(key)
         if exe is not None:
             return exe
@@ -2153,310 +2193,125 @@ class DecodeEngine:
             exe = self._exe_cache.get(key)
             if exe is not None:
                 return exe
+            import inspect
+
             import jax
-
-            gfn, L = self._dec_gfn, self._L
-            gkey = self._graph_key
-
-            def step(params, tokens, positions, lengths, table, temps,
-                     seeds, steps, pools, *extra):
-                args = dict(params)
-                args.update(data=tokens, positions=positions,
-                            lengths=lengths, block_table=table)
-                self._pool_args(args, pools)
-                self._runtime_bind(args, extra)
-                outs, _ = gfn(args, {}, gkey, False)
-                toks = self._sample(outs[0][:, 0, :], temps, seeds,
-                                    steps)
-                return toks, tuple(outs[1:])
-
-            if self._mesh is not None:
-                step = self._mesh.decode_step()
-
-            i32 = np.dtype(np.int32)
-            specs = (self._spec_of(self._params),
-                     self._arg_spec((bb, 1), i32),
-                     self._arg_spec((bb, 1), i32),
-                     self._arg_spec((bb,), i32),
-                     self._arg_spec((bb, mb), i32),
-                     self._arg_spec((bb,), np.dtype(np.float32)),
-                     self._arg_spec((bb,), i32),
-                     self._arg_spec((bb,), i32),
-                     self._spec_of(self._pools)) \
-                + self._runtime_specs(bb)
-            with profiler.scope(f"serving.compile.decode.b{bb}x{mb}",
-                                "serving", args={"batch": bb,
-                                                 "blocks": mb}):
-                jitted = jax.jit(
-                    self._program(step, f"step_decode_b{bb}x{mb}"),
-                    donate_argnums=(8,) if self._donate else ())
-                exe = jitted.lower(*specs).compile()
-            self._exe_cache[key] = exe
-            self._exe_flops[key] = _slo.executable_flops(exe)
-            self.compiles[key] = self.compiles.get(key, 0) + 1
-            return exe
-
-    def _verify_exe(self, bb: int, mb: int):
-        """Speculative verify step at batch bucket ``bb`` x table
-        bucket ``mb``: W = 1 + spec_tokens queries per stream, one
-        emission per query (the AOT bucket matrix's k dimension —
-        keyed separately from the plain decode step, which stays the
-        zero-draft fast path)."""
-        W = self._spec_k + 1
-        key = ("verify", bb, mb, W)
-        exe = self._exe_cache.get(key)
-        if exe is not None:
-            return exe
-        with self._compile_lock:
-            exe = self._exe_cache.get(key)
-            if exe is not None:
-                return exe
-            import jax
+            import jax.numpy as jnp
 
             from .speculative import verify_sample
 
-            gfn = self._ver_gfn
-            gkey = self._graph_key
-            base = self._base_key
+            name, span, counted, named = self._PROGRAMS[phase]
+            named = named.split()
+            dim = dict(zip(counted, dims))
+            # feeds are (rows, tokens) over a table of mb pages: a
+            # prompt is one row, its table as wide as its bucket
+            rows = dim.get("batch", 1)
+            toks = dim.get("tokens") or dim.get("window", 1)
+            mb = dim.get("blocks") or toks // self._kv_block
+            gfn, gkey, base = self._gfn[phase], self._graph_key, \
+                self._base_key
 
-            def step(params, tokens, positions, start, lengths, table,
-                     temps, seeds, steps0, pools, *extra):
-                args = dict(params)
-                args.update(data=tokens, positions=positions,
-                            start=start, lengths=lengths,
-                            block_table=table)
-                self._pool_args(args, pools)
-                self._runtime_bind(args, extra)
-                outs, _ = gfn(args, {}, gkey, False)
-                emit = verify_sample(base, outs[0], tokens,
-                                     lengths - start, temps, seeds,
-                                     steps0)
-                return emit, tuple(outs[1:])
-
-            if self._mesh is not None:
-                step = self._mesh.verify_step()
-
-            i32 = np.dtype(np.int32)
-            specs = (self._spec_of(self._params),
-                     self._arg_spec((bb, W), i32),
-                     self._arg_spec((bb, W), i32),
-                     self._arg_spec((bb,), i32),
-                     self._arg_spec((bb,), i32),
-                     self._arg_spec((bb, mb), i32),
-                     self._arg_spec((bb,), np.dtype(np.float32)),
-                     self._arg_spec((bb,), i32),
-                     self._arg_spec((bb,), i32),
-                     self._spec_of(self._pools)) \
-                + self._runtime_specs(bb)
-            with profiler.scope(
-                    f"serving.compile.verify.b{bb}x{mb}w{W}",
-                    "serving", args={"batch": bb, "blocks": mb,
-                                     "window": W}):
-                jitted = jax.jit(
-                    self._program(step,
-                                  f"step_verify_b{bb}x{mb}w{W}"),
-                    donate_argnums=(9,) if self._donate else ())
-                exe = jitted.lower(*specs).compile()
-            self._exe_cache[key] = exe
-            self._exe_flops[key] = _slo.executable_flops(exe)
-            self.compiles[key] = self.compiles.get(key, 0) + 1
-            return exe
-
-    def _prefill_exe(self, tp: int):
-        key = ("prefill", tp)
-        exe = self._exe_cache.get(key)
-        if exe is not None:
-            return exe
-        with self._compile_lock:
-            exe = self._exe_cache.get(key)
-            if exe is not None:
-                return exe
-            import jax
-            import jax.numpy as jnp
-
-            gfn, L = self._pre_gfn, self._L
-            gkey = self._graph_key
-            mb = tp // self._kv_block
-
-            def prefill(params, tokens, positions, lengths, table,
-                        temps, seeds, steps, pools, *extra):
+            def program(*flat):
+                (params, tokens, positions, *start, lengths, table, temps,
+                 seeds, steps, pools) = flat[:len(named)]
                 args = dict(params)
                 args.update(data=tokens, positions=positions,
                             lengths=lengths, block_table=table)
-                self._pool_args(args, pools)
-                self._runtime_bind(args, extra)
+                if start:
+                    args["start"], = start
+                args.update(zip(self._pool_names, pools))
+                args.update(zip(self._runtime_names, flat[len(named):]))
                 outs, _ = gfn(args, {}, gkey, False)
-                logits = outs[0]          # (1, Tp, V)
-                last = logits[jnp.arange(logits.shape[0]),
-                              lengths - 1]
-                toks = self._sample(last, temps, seeds, steps)
-                return toks, tuple(outs[1:])
+                logits = outs[0]
+                if phase == "verify":
+                    return verify_sample(base, logits, tokens,
+                                         lengths - start[0], temps, seeds,
+                                         steps), tuple(outs[1:])
+                if phase == "decode":
+                    last = logits[:, 0, :]
+                else:  # the last real row of the prompt (of the suffix)
+                    last = logits[
+                        jnp.arange(logits.shape[0]),
+                        (lengths - start[0] if start else lengths) - 1]
+                return sample_tokens(base, last, temps, seeds, steps), \
+                    tuple(outs[1:])
 
+            # jax names a program's parameters by this signature
+            P = inspect.Parameter
+            program.__signature__ = inspect.Signature(
+                [P(n, P.POSITIONAL_OR_KEYWORD) for n in named]
+                + [P("extra", P.VAR_POSITIONAL)])
             if self._mesh is not None:
-                prefill = self._mesh.prefill_step()
+                program = getattr(self._mesh, f"{phase}_step")()
 
             i32 = np.dtype(np.int32)
-            specs = (self._spec_of(self._params),
-                     self._arg_spec((1, tp), i32),
-                     self._arg_spec((1, tp), i32),
-                     self._arg_spec((1,), i32),
-                     self._arg_spec((1, mb), i32),
-                     self._arg_spec((1,), np.dtype(np.float32)),
-                     self._arg_spec((1,), i32),
-                     self._arg_spec((1,), i32),
-                     self._spec_of(self._pools)) \
-                + self._runtime_specs(1)
-            with profiler.scope(f"serving.compile.prefill.t{tp}",
-                                "serving", args={"tokens": tp}):
+            mat = self._arg_spec((rows, toks), i32)
+            shaped = {"params": self._spec_of(self._params),
+                      "tokens": mat, "positions": mat,
+                      "table": self._arg_spec((rows, mb), i32),
+                      "temps": self._arg_spec((rows,),
+                                              np.dtype(np.float32)),
+                      "pools": self._spec_of(self._pools)}
+            row = self._arg_spec((rows,), i32)  # every other: one a row
+            specs = tuple(shaped.get(n, row) for n in named) \
+                + self._runtime_specs(rows)
+            # the name the program carries in a device trace
+            # (``jit_step_decode_b48x64``, ``jit_prefill_t1024``)
+            program.__name__ = program.__qualname__ = name.format(*dims)
+            with profiler.scope(f"serving.compile.{span.format(*dims)}",
+                                "serving", args=dim):
                 jitted = jax.jit(
-                    self._program(prefill, f"prefill_t{tp}"),
-                    donate_argnums=(8,) if self._donate else ())
+                    program, donate_argnums=(len(named) - 1,)
+                    if self._donate else ())
                 exe = jitted.lower(*specs).compile()
             self._exe_cache[key] = exe
             self._exe_flops[key] = _slo.executable_flops(exe)
             self.compiles[key] = self.compiles.get(key, 0) + 1
             return exe
-
-    def _pool_args(self, args, pools):
-        """Bind the flat pools tuple into graph args, by the names the
-        model's spec gave them (``spec.pools``)."""
-        args.update(zip(self._pool_names, pools))
-        return args
-
-    # -- the runtime tail of every program: after the pools, the slot
-    # ids (a model whose spec feeds ``slots``), then the adapter args
-    def _runtime_bind(self, args, extra):
-        if self._slot_alloc is not None:
-            args["slots"], extra = extra[0], extra[1:]
-        return self._adapter_bind(args, extra)
 
     def _runtime_specs(self, bb: int) -> tuple:
-        slots = (self._arg_spec((bb,), np.dtype(np.int32)),) \
-            if self._slot_alloc is not None else ()
-        return slots + self._adapter_specs(bb)
-
-    def _runtime_args(self, streams, bb: int) -> tuple:
-        """The slot each row's stream holds (pad rows: 0, the scratch
-        slot), staged like the other feeds; then the adapter args."""
-        if self._slot_alloc is None:
-            return self._adapter_args(streams, bb)
-        from .io import stage_array
-
-        vec = np.zeros(bb, np.int32)
-        for i, s in enumerate(streams):
-            vec[i] = s.slot
-        return (stage_array(vec, self._device),) \
-            + self._adapter_args(streams, bb)
-
-    def _adapter_bind(self, args, adapter):
-        """Bind the flat adapter runtime args — per rank bucket a
-        (a_slab, b_slab, slot_vector) triple, in rank_buckets order.
-        A no-adapter engine passes () and binds nothing."""
-        if not self._lora:
-            return args
-        for j, rb in enumerate(self._lora):
-            args[f"adapter_a_r{rb}"] = adapter[3 * j]
-            args[f"adapter_b_r{rb}"] = adapter[3 * j + 1]
-            args[f"adapter_slots_r{rb}"] = adapter[3 * j + 2]
-        return args
-
-    def _adapter_specs(self, bb: int) -> tuple:
-        """AOT input specs for the adapter args at batch bucket
-        ``bb`` — slab shapes are fixed by the pool, so the executable
-        matrix gains NO new dimension from multi-tenancy."""
-        i32 = np.dtype(np.int32)
-        if not self._lora:
-            return ()
-        specs = []
-        slabs = self._adapter_pool.slabs()
-        for j, rb in enumerate(self._lora):
-            specs.append(self._spec_of(slabs[2 * j]))
-            specs.append(self._spec_of(slabs[2 * j + 1]))
-            specs.append(self._arg_spec((bb,), i32))
-        return tuple(specs)
-
-    def _adapter_args(self, streams, bb: int) -> tuple:
-        """Call-time adapter args for one step: the pool's CURRENT
-        slabs (fetched once — an atomic snapshot, so a concurrent
-        publish lands next step, never mid-step) plus per-bucket slot
-        vectors gathered from the batch.  Rows without an adapter —
-        pad rows included — carry slot 0, the exact no-op."""
-        if not self._lora:
-            return ()
-        import jax
-
-        slabs = self._adapter_pool.slabs()
-        out = []
-        for j, rb in enumerate(self._lora):
-            vec = np.zeros(bb, np.int32)
-            for i, s in enumerate(streams):
-                if s is not None and s.adapter_slot is not None \
-                        and s.adapter_bucket == rb:
-                    vec[i] = s.adapter_slot
-            out.extend((slabs[2 * j], slabs[2 * j + 1],
-                        jax.device_put(vec, self._device)))
+        """AOT specs of the runtime tail (``self._runtime_names``) at
+        batch bucket ``bb`` — slab shapes are fixed by the adapter pool,
+        so the executable matrix gains NO new dimension from
+        multi-tenancy."""
+        vec = self._arg_spec((bb,), np.dtype(np.int32))
+        out = [vec] if self._slot_alloc is not None else []
+        if self._lora:
+            slabs = self._adapter_pool.slabs()
+            for j in range(len(self._lora)):
+                out += [self._spec_of(slabs[2 * j]),
+                        self._spec_of(slabs[2 * j + 1]), vec]
         return tuple(out)
 
-    def _prefix_prefill_exe(self, tp: int, mb: int):
-        """Suffix-prefill executable for a prefix-cache hit: suffix
-        padded to ``tp`` tokens, block table padded to ``mb`` pages
-        (prefix + suffix chains)."""
-        key = ("prefix_prefill", tp, mb)
-        exe = self._exe_cache.get(key)
-        if exe is not None:
-            return exe
-        with self._compile_lock:
-            exe = self._exe_cache.get(key)
-            if exe is not None:
-                return exe
+    def _runtime_args(self, streams, bb: int) -> tuple:
+        """Call-time runtime tail for one step.  The slot each row's
+        stream holds (pad rows: 0, the scratch slot), staged like the
+        other feeds; then, per rank bucket, the adapter pool's CURRENT
+        slabs (fetched once — an atomic snapshot, so a concurrent
+        publish lands next step, never mid-step) and the slot vector
+        gathered from the batch: rows without an adapter — pad rows
+        included — carry slot 0, the exact no-op."""
+        out = []
+        if self._slot_alloc is not None:
+            from .io import stage_array
+
+            vec = np.zeros(bb, np.int32)
+            for i, s in enumerate(streams):
+                vec[i] = s.slot
+            out.append(stage_array(vec, self._device))
+        if self._lora:
             import jax
-            import jax.numpy as jnp
 
-            gfn, L = self._pfx_gfn, self._L
-            gkey = self._graph_key
-
-            def prefill(params, tokens, positions, start, lengths,
-                        table, temps, seeds, steps, pools, *extra):
-                args = dict(params)
-                args.update(data=tokens, positions=positions,
-                            start=start, lengths=lengths,
-                            block_table=table)
-                self._pool_args(args, pools)
-                self._runtime_bind(args, extra)
-                outs, _ = gfn(args, {}, gkey, False)
-                logits = outs[0]          # (1, Ts, V) — SUFFIX rows
-                last = logits[jnp.arange(logits.shape[0]),
-                              lengths - start - 1]
-                toks = self._sample(last, temps, seeds, steps)
-                return toks, tuple(outs[1:])
-
-            if self._mesh is not None:
-                prefill = self._mesh.prefix_prefill_step()
-
-            i32 = np.dtype(np.int32)
-            specs = (self._spec_of(self._params),
-                     self._arg_spec((1, tp), i32),
-                     self._arg_spec((1, tp), i32),
-                     self._arg_spec((1,), i32),
-                     self._arg_spec((1,), i32),
-                     self._arg_spec((1, mb), i32),
-                     self._arg_spec((1,), np.dtype(np.float32)),
-                     self._arg_spec((1,), i32),
-                     self._arg_spec((1,), i32),
-                     self._spec_of(self._pools)) \
-                + self._runtime_specs(1)
-            with profiler.scope(
-                    f"serving.compile.prefix_prefill.t{tp}x{mb}",
-                    "serving", args={"tokens": tp, "blocks": mb}):
-                jitted = jax.jit(
-                    self._program(prefill,
-                                  f"prefill_suffix_t{tp}x{mb}"),
-                    donate_argnums=(9,) if self._donate else ())
-                exe = jitted.lower(*specs).compile()
-            self._exe_cache[key] = exe
-            self._exe_flops[key] = _slo.executable_flops(exe)
-            self.compiles[key] = self.compiles.get(key, 0) + 1
-            return exe
+            slabs = self._adapter_pool.slabs()
+            for j, rb in enumerate(self._lora):
+                vec = np.zeros(bb, np.int32)
+                for i, s in enumerate(streams):
+                    if s is not None and s.adapter_slot is not None \
+                            and s.adapter_bucket == rb:
+                        vec[i] = s.adapter_slot
+                out.extend((slabs[2 * j], slabs[2 * j + 1],
+                            jax.device_put(vec, self._device)))
+        return tuple(out)
 
     def _cow_exe(self):
         """One jitted page copy for copy-on-write: every pool (values
@@ -2713,55 +2568,57 @@ class DecodeEngine:
                              kind: str, extra: dict):
         """Launch the suffix-prefill executable over
         ``seq[done:end]`` (absolute token offsets, ``done``
-        block-aligned): the ONE feed builder behind both a
-        prefix-cache hit's one-shot suffix and every chunk of a
-        chunked prefill, so the two paths cannot drift apart (both
-        bit-identity contracts are pinned against the same monolithic
-        prefill).  Returns the sampled-token DEVICE array (meaningful
-        only when ``end`` covers the full sequence — the caller
-        decides whether to fetch it) and the prefill bucket used."""
-        from .io import stage_array
-
-        dev = self._device
-        n = len(seq)
+        block-aligned): the one launch behind both a prefix-cache
+        hit's one-shot suffix and every chunk of a chunked prefill
+        (both bit-identity contracts are pinned against the same
+        monolithic prefill).  Returns the sampled-token DEVICE array
+        (meaningful only when ``end`` covers the full sequence — the
+        caller decides whether to fetch it) and the prefill bucket
+        used."""
         csize = end - done
         tp = self._bucket(self._prefill_buckets, csize, label)
         mb = self._bucket(self._cache_buckets, len(s.blocks),
                           "cache blocks")
-        exe = self._prefix_prefill_exe(tp, mb)
-        tokens = np.zeros((1, tp), np.int32)
-        tokens[0, :csize] = seq[done:end]
-        positions = (done + np.arange(tp, dtype=np.int32))[None]
-        start = np.asarray([done], np.int32)
-        lengths = np.asarray([end], np.int32)
-        table = np.zeros((1, mb), np.int32)
-        table[0, :len(s.blocks)] = s.blocks
-        temps = np.asarray([s.temp], np.float32)
-        seeds = np.asarray([s.seed], np.int32)
-        steps = np.asarray([n - 1], np.int32)  # sampling position
+        exe = self._exe("prefix_prefill", tp, mb)
         with profiler.scope(f"serving.prefill.{kind}.t{tp}",
                             "serving",
                             args=dict(extra, tokens=csize, bucket=tp)):
             toks, self._pools = exe(
-                self._params, stage_array(tokens, dev),
-                stage_array(positions, dev), stage_array(start, dev),
-                stage_array(lengths, dev), stage_array(table, dev),
-                stage_array(temps, dev), stage_array(seeds, dev),
-                stage_array(steps, dev), self._pools,
-                *self._runtime_args([s], 1))
+                self._params,
+                *self._prompt_feeds(s, seq, done, end, tp, mb, s.blocks,
+                                    True),
+                self._pools, *self._runtime_args([s], 1))
         s.cost.flops_est += self._exe_flops.get(
             ("prefix_prefill", tp, mb), 0.0)
         return toks, tp
 
-    def _prefill(self, s: _Stream, seq: np.ndarray, pages: List[int]):
+    def _prompt_feeds(self, s: _Stream, seq: np.ndarray, done: int,
+                      end: int, tp: int, mb: int, pages,
+                      suffix: bool) -> tuple:
+        """The staged feeds of one prompt row over ``seq[done:end]``
+        padded to ``tp``, its table ``pages`` padded to ``mb``: tokens,
+        positions, ``start`` (the ``suffix`` program's), lengths, table,
+        temps, seeds, steps — the ONE feed builder behind monolithic
+        prefill, a prefix hit's suffix and every chunk, so the three
+        cannot drift apart."""
         from .io import stage_array
 
+        tokens = np.zeros((1, tp), np.int32)
+        tokens[0, :end - done] = seq[done:end]
+        table = np.zeros((1, mb), np.int32)
+        table[0, :len(pages)] = pages
+        feeds = [tokens, (done + np.arange(tp, dtype=np.int32))[None],
+                 np.asarray([end], np.int32), table,
+                 np.asarray([s.temp], np.float32),
+                 np.asarray([s.seed], np.int32),
+                 np.asarray([len(seq) - 1], np.int32)]  # sampling position
+        if suffix:
+            feeds.insert(2, np.asarray([done], np.int32))
+        return tuple(stage_array(a, self._device) for a in feeds)
+
+    def _prefill(self, s: _Stream, seq: np.ndarray, pages: List[int]):
         n = len(seq)
         c = s.cached_len  # block-aligned prefix already in the cache
-        dev = self._device
-        temps = np.asarray([s.temp], np.float32)
-        seeds = np.asarray([s.seed], np.int32)
-        steps = np.asarray([n - 1], np.int32)  # sampling position
         t_pre0 = time.perf_counter()
         if c:
             # prefix hit: prefill ONLY the uncached suffix, attending
@@ -2778,13 +2635,7 @@ class DecodeEngine:
             ns = n
             tp = self._bucket(self._prefill_buckets, n, "prompt length")
             mb = tp // self._kv_block
-            exe = self._prefill_exe(tp)
-            tokens = np.zeros((1, tp), np.int32)
-            tokens[0, :n] = seq
-            positions = np.arange(tp, dtype=np.int32)[None]
-            lengths = np.asarray([n], np.int32)
-            table = np.zeros((1, mb), np.int32)
-            table[0, :len(pages)] = pages
+            exe = self._exe("prefill", tp)
             if self._slot_alloc is not None:
                 # one slot from admission to retirement; the prefill
                 # below writes it anew (a re-prefill after preemption
@@ -2805,11 +2656,9 @@ class DecodeEngine:
                                       "resume": s.resume}):
                 with self._pools_lock:
                     toks, self._pools = exe(
-                        self._params, stage_array(tokens, dev),
-                        stage_array(positions, dev),
-                        stage_array(lengths, dev),
-                        stage_array(table, dev), stage_array(temps, dev),
-                        stage_array(seeds, dev), stage_array(steps, dev),
+                        self._params,
+                        *self._prompt_feeds(s, seq, 0, n, tp, mb, pages,
+                                            False),
                         self._pools, *self._runtime_args([s], 1))
                 with profiler.scope("serving.d2h_sync", "serving",
                                     args={"sids": s.sid}):
@@ -3075,7 +2924,7 @@ class DecodeEngine:
         """Hand back the stream's state slot (retirement, preemption,
         shutdown); its contents stay until the next owner's prefill
         overwrites them."""
-        if self._slot_alloc is None or not s.slot:
+        if not s.slot:
             return
         with profiler.scope("serving.slot_free", "serving",
                             args={"sids": s.sid}):
@@ -3108,8 +2957,9 @@ class DecodeEngine:
             with self._pools_lock:  # the pools are donated step by step
                 result = {"tokens": result, "state": {
                     n: np.asarray(p[s.slot])
-                    for n, p in zip(self._pool_names, self._pools)
-                    if n.endswith("_state")}}
+                    for n, k, p in zip(self._pool_names,
+                                       self._pool_kinds, self._pools)
+                    if k == "slots"}}
         self._release_slot(s)
         self._release_adapter(s)
         if s.tenant is not None and not s.canary:
@@ -3136,7 +2986,7 @@ class DecodeEngine:
         as the pool's (n, KVB, H·D) rows, so frames read as they did
         when the pools were 4-D; a quantized pool's scales travel as
         they lie, (n, KVB, H)."""
-        if i % self._pool_stride < 2:
+        if self._pool_kinds[i] == "pages":
             return (n, self._kv_block, self._H, self._D)
         return (n, self._kv_block, self._H)
 
@@ -3239,9 +3089,9 @@ class DecodeEngine:
         (including tokens the exporter's prefill already emitted)."""
         if self._slot_alloc is not None:
             raise MXNetError(
-                "page import is not built for a model spec with kda "
-                "layers: an imported stream would arrive without the "
-                "state its slot holds")
+                f"page import is not built for {self._spec.name}: an "
+                f"imported stream would arrive without the state its "
+                f"slot holds")
         if self._mesh is not None:
             raise MXNetError(
                 "KV page migration onto a tp/pp-meshed engine is not "
@@ -3473,7 +3323,7 @@ class DecodeEngine:
         mb = self._bucket(self._cache_buckets,
                           max(len(s.blocks) for s in streams),
                           "cache blocks")
-        exe = self._verify_exe(bb, mb)
+        exe = self._exe("verify", bb, mb, W)
         with profiler.scope("serving.stage", "serving",
                             args={"sids": self._sids(streams),
                                   "active": n}):
@@ -3655,7 +3505,7 @@ class DecodeEngine:
         mb = self._bucket(self._cache_buckets,
                           max(len(s.blocks) for s in streams),
                           "cache blocks")
-        exe = self._decode_exe(bb, mb)
+        exe = self._exe("decode", bb, mb)
         # the batch program's FLOPs, split evenly across the riders
         fl = self._exe_flops.get(("decode", bb, mb), 0.0) / n
         # one adapter snapshot serves both halves of a pipelined pair

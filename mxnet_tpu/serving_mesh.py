@@ -119,14 +119,12 @@ class MeshPrograms:
     pools and feeds change.
     """
 
-    def __init__(self, plan, *, num_layers, num_heads, d_model,
-                 d_ff=None, vocab_size, kv_block, kv_dtype="fp32",
+    def __init__(self, plan, spec, *, kv_block, kv_dtype="fp32",
                  pool_dtype=np.float32, seed=0):
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from .kv_cache import kv_quantized
-        from .models.transformer import transformer_lm_decode
         from .parallel import parse_logical
 
         if plan.dp != 1:
@@ -138,11 +136,11 @@ class MeshPrograms:
         self.mesh = plan.mesh
         self.tp = int(plan.tp)
         self.pp = int(plan.pp)
-        self.L = int(num_layers)
-        self.H = int(num_heads)
-        self.V = int(vocab_size)
-        self.dm = int(d_model)
-        self.dff = int(d_ff) if d_ff else 4 * self.dm
+        self.L = spec.num_layers
+        self.H = spec.num_heads
+        self.V = spec.vocab_size
+        self.dm = spec.d_model
+        self.dff = int(spec.d_ff) if spec.d_ff else 4 * self.dm
         self.kvb = int(kv_block)
         if self.H % self.tp:
             raise MXNetError(
@@ -152,10 +150,7 @@ class MeshPrograms:
             raise MXNetError(
                 f"pp={self.pp} does not divide num_layers={self.L} — "
                 f"pipeline stages hold equal layer slabs")
-        if self.dm % self.H:
-            raise MXNetError(
-                f"d_model {self.dm} % num_heads {self.H} != 0")
-        self.D = self.dm // self.H
+        self.D = spec.head_dim
         self.Hl = self.H // self.tp
         self.Ll = self.L // self.pp
         self._quant = kv_quantized(kv_dtype)
@@ -165,10 +160,7 @@ class MeshPrograms:
         # logical axis names come off the DECODE symbol itself — the
         # annotations in models/transformer.py, resolved through the
         # plan's rules table (one table drives training AND serving)
-        dec = transformer_lm_decode(
-            self.V, num_layers=self.L, num_heads=self.H,
-            d_model=self.dm, d_ff=self.dff, kv_block=self.kvb,
-            paged=True, kv_dtype=kv_dtype)
+        dec = spec.symbol("decode", kv_block=self.kvb, kv_dtype=kv_dtype)
         self._axes: Dict[str, tuple] = {}
         for name, attrs in dec.attr_dict().items():
             logical = attrs.get("__logical__")

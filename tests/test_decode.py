@@ -674,10 +674,10 @@ def test_prefill_failure_fails_the_admitted_future(lm):
     params, _, _ = lm
     eng = _engine(params)
     try:
-        def boom(tp):
+        def boom(*key):
             raise RuntimeError("injected prefill failure")
 
-        eng._prefill_exe = boom
+        eng._exe = boom
         fut = eng.submit(np.arange(1, 5, dtype=np.int32), 4)
         with pytest.raises(mx.EngineClosedError, match="died"):
             fut.result(timeout=60)
