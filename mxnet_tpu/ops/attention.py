@@ -561,13 +561,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, H):
     runs the same blockwise body with block == KVB, so the result is
     bit-identical to the contiguous-cache decode (pages hold the same
     values; page boundaries ARE block boundaries).  The Pallas kernel
-    (pallas_kernels.paged_attention_decode) gathers page-by-page in
-    VMEM instead and never materializes the full cache.
+    (pallas_kernels.paged_attention_decode) gathers a chunk of pages
+    at a time in VMEM instead and never materializes the full cache.
     """
     from . import pallas_kernels as pk
 
     KVB = k_pool.shape[1]
-    if pk.enabled():
+    if pk.paged_enabled(k_pool.shape[2]):
         out = pk.paged_attention_decode(q[:, 0], k_pool, v_pool,
                                         block_table, lengths, H)
         return out[:, None]
@@ -588,7 +588,7 @@ def paged_decode_attention_q(q, k_pool, v_pool, k_scale, v_scale,
 
     KVB = k_pool.shape[1]
     H = k_scale.shape[2]
-    if pk.enabled():
+    if pk.paged_enabled(k_pool.shape[2]):
         out = pk.paged_attention_decode_quant(
             q[:, 0], k_pool, v_pool, k_scale, v_scale, block_table,
             lengths, H)
@@ -955,7 +955,7 @@ def paged_verify_attention(q, k_pool, v_pool, block_table, start, H):
     from . import pallas_kernels as pk
 
     KVB = k_pool.shape[1]
-    if pk.enabled():
+    if pk.paged_enabled(k_pool.shape[2]):
         return pk.paged_attention_verify(q, k_pool, v_pool, block_table,
                                          start, H)
     B, MB = block_table.shape

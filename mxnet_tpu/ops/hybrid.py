@@ -158,7 +158,7 @@ def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
     lengths = lengths.astype(jnp.int32)
     table = table.astype(jnp.int32)
     kp, vp = paged_cache_update(k_pool, v_pool, k, v, table, lengths)
-    if pk.enabled():
+    if pk.paged_enabled(kp.shape[2]):
         out = pk._paged_attention(q, kp, vp, (), table, lengths - 1, H,
                                   kv_heads=Hkv)
         return [out, kp, vp]

@@ -1328,7 +1328,7 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
 
 # ---------------------------------------------------------------------------
 # Paged attention: W query positions per stream attending a KV cache
-# scattered over fixed-size pages, gathered page-by-page INTO VMEM
+# scattered over fixed-size pages, gathered chunk-by-chunk INTO VMEM
 # through a scalar-prefetched block table (the PagedAttention pattern,
 # Kwon et al. SOSP '23).  The gathered cache never materializes in
 # HBM — HBM traffic per step is exactly the pages a stream actually
@@ -1345,38 +1345,110 @@ def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
 # runs in place and the kernel takes the pool as it is.
 #
 # ONE kernel family serves the decode step (W = 1), the quantized-
-# cache decode step (W = 1 plus per-slot scales dequantized in VMEM) and
-# the speculative-verify window (W = 1 + k).  Grid (B, MB): each step
-# DMAs ONE page of K and V and folds it into an online-softmax state
-# under the DIAGONAL mask k_pos < start[b] + 1 + w — row w reproduces
-# exactly the mask (and block chain) of a single-query decode at
-# length start[b] + 1 + w.  A page fully masked for a row is an exact
-# no-op of that row's state merge (alpha == 1, p == 0).
+# cache decode step (W = 1 plus per-slot scales dequantized in VMEM),
+# the speculative-verify window (W = 1 + k) and grouped queries (Hq
+# query heads over Hkv KV heads).  Grid (B,): a grid step is a ROW, and
+# inside it a loop walks the row's keys a CHUNK of C = K pages x KVB at
+# a time — only the cdiv(start[b] + W, C) chunks a window position can
+# see, so a page past the live length costs no copy and no step.  The
+# pools stay in HBM; the K pages of a chunk are scattered there, so
+# they arrive as K copies of K and K of V (page ids from the prefetched
+# table) into one (K, KVB, H·D) buffer each, and a second buffer takes
+# chunk c + 1 — or the next row's first chunk — while chunk c is
+# multiplied.  A page of the last chunk that lies past the live length
+# is not copied: its rows of the buffer keep what an earlier chunk left
+# there (the buffers are zeroed once, so that is never a NaN), and the
+# DIAGONAL mask k_pos < start[b] + 1 + w gives those keys a probability
+# of exactly 0.  Row w under that mask reproduces the mask and the chain
+# of merges of a single-query decode at length start[b] + 1 + w: a
+# chunk fully masked for a row is an exact no-op of that row's state
+# (alpha == 1, p == 0).
 #
-# Heads are contracted WITHOUT reshaping the page: at the first page
-# of a stream the W query rows are spread over HP = H rounded up to a
-# sublane tile rows each, row (w, h) holding q[w] on head h's lane
-# span and exact zeros elsewhere.  One (W·HP, H·D) x (KVB, H·D)^T
-# matmul then gives every head's scores (the zeros add nothing to the
-# float32 accumulation), and one (W·HP, KVB) x (KVB, H·D) matmul every
+# One online-softmax merge a chunk.  Heads are contracted WITHOUT
+# reshaping the page rows: at the start of a row the W query rows are
+# spread over HP = H rounded up to a sublane tile rows each, row (w, h)
+# holding q[w] on head h's lane span and exact zeros elsewhere.  One
+# (W·HP, H·D) x (C, H·D)^T matmul then gives every head's scores (the
+# zeros add nothing to the float32 accumulation) as a (W·HP, C) tile —
+# lane-dense at C >= 128 — and one (W·HP, C) x (C, H·D) matmul every
 # head's P·V on its own span of the row; the finish keeps row (w, h)'s
-# span h and sums the rows of a w.  The MXU does H times the needed
-# multiplies, which it has to spare at one page a step; what the step
-# is short of is instructions, and this form has two matmuls and a
-# dozen whole-tile vector ops where a loop over heads has 2·H matmuls
-# on half-tile lane slices.
+# span h and sums the rows of a w.  The MXU multiplies H times the
+# needed products, but what a matmul with so few rows costs is loading
+# its weight tiles, and K and V are the weights: each of their
+# 128 x 128 tiles is loaded once a chunk in this form and once in a
+# loop over heads (twice there at D = 64, a head being half a tile), so
+# the all-heads form is the one with the fewest tile loads at every
+# shape, and with two matmuls and a dozen whole-tile vector ops a chunk
+# it is the one with the fewest instructions.  What it costs is VMEM,
+# W·HP rows of H·D lanes three times over; that is what the chunk gives
+# way to and, past the budget, what is refused by name.
 # ---------------------------------------------------------------------------
 
+# Keys a chunk, where VMEM allows.  Read on a v5e, a layer's kernel over
+# the serving cells' rows (48 x ~760 keys of 20 x 64; 128 x ~1,500 of
+# 64 / 8 x 128): 0.349 / 1.55 ms at 128 keys, 0.297 / 1.25 at 256,
+# 0.289 / 1.15 at 512, beside 0.276 / 1.09 for the copies alone and a
+# need of 0.228 / 0.966 (PERF.md §6, PR 30).  At 128 the merge is not
+# yet hidden under the copies; from 256 on the copies bind, and a larger
+# chunk buys its last few percent with twice the VMEM and with keys
+# multiplied past the live length.
+_PAGED_CHUNK_KEYS = 256
 
-def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
-                  scale, kvb, nb, w, h, d, hp, quant, g=1):
+
+def paged_enabled(lanes) -> bool:
+    """Use the paged kernel over pools whose page rows are ``lanes``
+    wide?  Compiled for the chip it copies a page's rows by hand, and
+    Mosaic slices an HBM array in whole lane tiles only: a width that
+    is no multiple of 128 (a toy model, 5 heads x 64 of a tp shard)
+    takes the callers' lax body there.  Interpreted, any width goes."""
+    return enabled() and (_interpret() or lanes % 128 == 0)
+
+
+def _paged_pages_per_chunk(w, heads, kv_heads, d, kvb, table_pages, q_bytes,
+                           kv_bytes, quant=False):
+    """(HP, K, bytes of VMEM): the rows a window position's heads are
+    spread over, the pages a chunk holds and what the kernel then keeps
+    in VMEM — the ONE place K is derived.
+
+    The spread query is ``rows`` x ``lanes`` = W·HP x Hkv·D (HP: the
+    heads in whole sublane tiles of a 16-bit q; grouped queries come in
+    whole tiles already).  It is held three times over whatever the
+    chunk: itself, the float32 accumulator and the P·V product (a
+    fourth, q in float32, over quantized pools).  A chunk of C = K·KVB keys adds two buffers each
+    of K and V pages and the (rows, C) scores, probabilities and mask;
+    over quantized pools the two float32 dequantized chunks too, and
+    the scales of the row's table in whole chunks, K's and V's, twice
+    each and a lane tile wide.  K is the most pages up to
+    ``_PAGED_CHUNK_KEYS`` keys (and the row's table) that keeps the sum
+    inside ``_PAGED_VMEM_BUDGET``, halved until it does; a kernel that
+    is over at K = 1 is the caller's to refuse."""
+    hp = heads if kv_heads != heads else -(-heads // 16) * 16
+    rows, lanes = w * hp, kv_heads * d
+    fixed = rows * lanes * (q_bytes + 4 + 4 + 4 * quant)
+
+    def need(pages):
+        keys = pages * kvb
+        chunk = 2 * 2 * keys * lanes * kv_bytes
+        if quant:
+            chunk += 2 * keys * lanes * 4
+            chunk += 2 * 2 * -(-table_pages // pages) * keys * 128 * 4
+        return fixed + chunk + 3 * rows * max(keys, 128) * 4
+
+    pages = max(1, min(_PAGED_CHUNK_KEYS // kvb, table_pages))
+    while pages > 1 and need(pages) > _PAGED_VMEM_BUDGET:
+        pages //= 2
+    return hp, pages, need(pages)
+
+
+def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
+                  kvb, pages, mb, w, h, d, hp, quant, g=1):
     if quant:
-        ks_ref, vs_ref, o_ref, qx_scr, acc_scr, m_scr, l_scr = rest
-    else:
-        o_ref, qx_scr, acc_scr, m_scr, l_scr = rest
+        ks_ref, vs_ref, *rest = rest
+    o_ref, k_buf, v_buf, sem, turn_scr, qx_scr, acc_scr, m_scr, l_scr = rest
     b = pl.program_id(0)
-    j = pl.program_id(1)
+    nb = pl.num_programs(0)
     hd = h * d
+    keys = pages * kvb
 
     def head_span(rows):
         # (rows, H·D) bool: lane belongs to the row's head (rows >= H,
@@ -1394,57 +1466,111 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
         kv = sum((head >= i * g).astype(jnp.int32) for i in range(1, h))
         return (lane >= kv * d) & (lane < (kv + 1) * d)
 
-    @pl.when(j == 0)
-    def _init():
-        if g > 1:
-            # q arrives as (W·Hq, D) rows, one query head each: every
-            # row is repeated over the KV spans and kept on its own
-            q = q_ref[0].astype(jnp.float32)
-            qx_scr[...] = jnp.where(
-                group_span(w * hp), jnp.concatenate([q] * h, axis=1),
-                0.0).astype(qx_scr.dtype)
-        else:
-            span = head_span(hp)
-            q = q_ref[0].astype(jnp.float32)              # (W, H·D)
-            for i in range(w):
-                qx_scr[i * hp:(i + 1) * hp, :] = jnp.where(
-                    span, q[i:i + 1, :], 0.0).astype(qx_scr.dtype)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
-        m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[...] = jnp.zeros_like(l_scr)
+    def live(row):
+        # keys some window position of the row can see
+        return jnp.clip(start_ref[row] + w, 0, mb * kvb)
 
-    # pages past the window's last visible position hold nothing any
-    # row can see — skip their matmuls entirely (the block table pads
-    # them to the scratch page, so the prefetch itself is always a
-    # valid page id)
-    @pl.when(j * kvb < start_ref[b] + w)
-    def _compute():
+    def copies(row, chunk, slot, op):
+        # the chunk's live pages, a copy of K and one of V a page, page
+        # i into span i of the slot's buffers.  ``op`` is "start" or
+        # "wait": the same pages, by the same count, whichever it is.
+        # A loop the compiler keeps rolled: this body is traced three
+        # times a kernel, not three times a page
+        first = chunk * pages
+
+        def page(i, _):
+            pid = table_ref[row, first + i]
+            for j, (pool, buf) in enumerate(((k_hbm, k_buf), (v_hbm, v_buf))):
+                getattr(pltpu.make_async_copy(
+                    pool.at[pid], buf.at[slot, i], sem.at[j, slot]), op)()
+
+        jax.lax.fori_loop(
+            0, jnp.clip(pl.cdiv(live(row), kvb) - first, 0, pages), page,
+            None)
+
+    @pl.when(b == 0)
+    def _first_row():
+        turn_scr[0] = 0
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    # chunks walked before this row: chunk c of the row is in buffer
+    # (turn + c) % 2.  Who starts whose copies: a row its chunk c + 1
+    # under chunk c and, under its last chunk, the NEXT row's first —
+    # which a row with no chunk of its own starts at once, and row 0
+    # starts for itself
+    turn = turn_scr[0]
+    n = pl.cdiv(live(b), keys)
+    after = jnp.minimum(b + 1, nb - 1)
+
+    @pl.when((b == 0) | ((n == 0) & (b + 1 < nb)))
+    def _first_chunk():
+        copies(jnp.where(n == 0, after, b), 0, turn % 2, "start")
+
+    if g > 1:
+        # q arrives as (W·Hq, D) rows, one query head each: every
+        # row is repeated over the KV spans and kept on its own
+        q = q_ref[0].astype(jnp.float32)
+        qx_scr[...] = jnp.where(
+            group_span(w * hp), jnp.concatenate([q] * h, axis=1),
+            0.0).astype(qx_scr.dtype)
+    else:
+        span = head_span(hp)
+        q = q_ref[0].astype(jnp.float32)              # (W, H·D)
+        for i in range(w):
+            qx_scr[i * hp:(i + 1) * hp, :] = jnp.where(
+                span, q[i:i + 1, :], 0.0).astype(qx_scr.dtype)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+
+    def chunk_step(c, _):
+        slot = (turn + c) % 2
+        more = c + 1 < n
+
+        @pl.when(more | (b + 1 < nb))
+        def _ahead():
+            copies(jnp.where(more, b, after), jnp.where(more, c + 1, 0),
+                   1 - slot, "start")
+
+        copies(b, c, slot, "wait")
         qx = qx_scr[...]                              # (W·HP, H·D)
-        k = k_ref[0]                                  # (KVB, H·D)
-        v = v_ref[0]
+        k = k_buf[slot]                               # (K, KVB, H·D)
+        v = v_buf[slot]
         if quant:
-            # pages arrive as int8/fp8 plus their (KVB, H) float32
-            # scales and are dequantized to float32 right after the
-            # DMA — the narrow dtype is what crosses HBM.  A scale
-            # reaches its head's D lanes through an exact 0/1 matmul
-            # (one non-zero term a lane), and from here on every
-            # operand is float32: q, the values, the probabilities
+            # pages arrive as int8/fp8 and are dequantized to float32
+            # right after the DMA — the narrow dtype is what crosses
+            # HBM — by the chunk's rows of the row's (MB·KVB, H)
+            # float32 scales.  A scale reaches its head's D lanes
+            # through an exact 0/1 matmul (one non-zero term a lane),
+            # and from here on every operand is float32: q, the values,
+            # the probabilities.  The scales came for the whole table:
+            # past the live length they are whatever the table's
+            # padding points at, and count as 0
             lanes = head_span(h).astype(jnp.float32)      # (H, H·D)
+            at = pl.ds(pl.multiple_of(c * keys, keys), keys)
+            seen = c * keys + jax.lax.broadcasted_iota(
+                jnp.int32, (keys, h), 0) < live(b)
 
             def dequant(x, scale_ref):
-                return x.astype(jnp.float32) * jax.lax.dot_general(
-                    scale_ref[0], lanes, (((1,), (0,)), ((), ())),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
+                return (x.astype(jnp.float32).reshape(keys, hd)
+                        * jax.lax.dot_general(
+                            jnp.where(seen, scale_ref[0, at, :], 0.0), lanes,
+                            (((1,), (0,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32))
 
             k = dequant(k, ks_ref)
             v = dequant(v, vs_ref)
             qx = qx.astype(jnp.float32)
+        else:
+            k = k.reshape(keys, hd)
+            v = v.reshape(keys, hd)
         # s[(w, h), t] = q[w, span h] . k[t, span h]
         s = jax.lax.dot_general(
             qx, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        k_pos = j * kvb + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_pos = c * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         win = sum((row >= i * hp).astype(jnp.int32) for i in range(1, w))
         valid = k_pos < start_ref[b] + 1 + win
@@ -1465,17 +1591,17 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_ref, v_ref, *rest,
         acc_scr[...] = acc_scr[...] * alpha + pv
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(j == nb - 1)
-    def _finish():
-        if g > 1:
-            # row r keeps the D lanes of its KV span
-            out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
-            keep = jnp.where(group_span(w * hp), out, 0.0)
-            o_ref[0] = sum(keep[:, i * d:(i + 1) * d]
-                           for i in range(h)).astype(o_ref.dtype)
-            return
+    jax.lax.fori_loop(0, n, chunk_step, None)
+    turn_scr[0] = turn + n
+
+    out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+    if g > 1:
+        # row r keeps the D lanes of its KV span
+        keep = jnp.where(group_span(w * hp), out, 0.0)
+        o_ref[0] = sum(keep[:, i * d:(i + 1) * d]
+                       for i in range(h)).astype(o_ref.dtype)
+    else:
         span = head_span(hp)
-        out = acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
         rows = [jnp.sum(jnp.where(span, out[i * hp:(i + 1) * hp, :], 0.0),
                         axis=0, keepdims=True) for i in range(w)]
         out = jnp.concatenate(rows, axis=0) if w > 1 else rows[0]
@@ -1488,127 +1614,103 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
     (P, KVB, H·D); scales is () or (k_scale, v_scale), each
     (P, KVB, H) float32.  ``kv_heads`` < ``num_heads``: grouped
     queries, pools (P, KVB, kv_heads·D), query head i on KV head
-    ``i // (num_heads // kv_heads)``."""
-    if kv_heads is not None and int(kv_heads) != int(num_heads):
-        return _paged_attention_grouped(q, k_pool, v_pool, scales,
-                                        block_table, start,
-                                        int(num_heads), int(kv_heads))
+    ``i // (num_heads // kv_heads)``: the same walk, chunks and
+    online-softmax state, the spread query a row per QUERY head on its
+    KV head's span of the (kv_heads·D)-lane page rows, so one matmul a
+    chunk still gives every head's scores."""
     B, W, HD = q.shape
-    H = int(num_heads)
-    D = HD // H
-    KVB = k_pool.shape[1]
-    MB = block_table.shape[1]
-    HP = -(-H // 16) * 16  # whole sublane tiles of a 16-bit q
-    # The all-heads form holds W·HP rows of H·D lanes three times over
-    # (the spread q, the float32 accumulator, the P·V product; a
-    # fourth, q in float32, over quantized pools), so its
-    # VMEM grows as W·H²·D: 0.4 MB at the benchmark's 20 x 64, W = 1;
-    # 2 MB at W = 5; every GPT-2 size at W <= 8 stays under 5 MB.  It
-    # does NOT fit every head count — 64 heads x 128 at W = 5 would
-    # want 26 MB of the 16 MB a kernel is given — and is refused here
-    # by name rather than by a Mosaic allocation error.  A model that
-    # wide needs the loop-over-heads form (H-fold less MXU work and
-    # VMEM, more instructions a step: 2.97 ms against 1.55 ms a layer
-    # at 20 x 64, PERF.md §6 PR 26).
-    quant = bool(scales)
-    vmem = (W * HP * HD * (q.dtype.itemsize + 4 + 4 + 4 * quant)
-            + 2 * KVB * HD * (2 * k_pool.dtype.itemsize + 4 * quant))
-    if vmem > _PAGED_VMEM_BUDGET:
-        raise MXNetError(
-            f"paged_attention: {H} heads x {D} with a {W}-row window "
-            f"needs about {vmem >> 20} MB of VMEM in the all-heads "
-            f"form ({W * HP} rows of {HD} lanes, three times over), "
-            f"more than the {_PAGED_VMEM_BUDGET >> 20} MB it may count "
-            f"on; fewer window rows fit, or heads sharded over tp")
-    kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
-                             kvb=KVB, nb=MB, w=W, h=H, d=D, hp=HP,
-                             quant=quant)
-
-    def page(width):
-        return _vmem_spec((1, KVB, width),
-                          lambda b, j, tr, sr: (tr[b, j], 0, 0))
-
-    def rows():
-        return _vmem_spec((1, W, HD), lambda b, j, tr, sr: (b, 0, 0))
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, MB),
-        in_specs=[rows(), page(HD), page(HD)] + [page(H) for _ in scales],
-        out_specs=rows(),
-        scratch_shapes=[pltpu.VMEM((W * HP, HD), q.dtype),
-                        pltpu.VMEM((W * HP, HD), jnp.float32),
-                        pltpu.VMEM((W * HP, 128), jnp.float32),
-                        pltpu.VMEM((W * HP, 128), jnp.float32)],
-    )
-    return pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, W, HD), q.dtype),
-        compiler_params=_compiler_params("parallel", "arbitrary"),
-        interpret=_interpret(),
-        name="paged_attention_q" if scales else "paged_attention",
-    )(block_table.astype(jnp.int32), start.astype(jnp.int32),
-      q, k_pool, v_pool, *scales)
-
-
-def _paged_attention_grouped(q, k_pool, v_pool, scales, block_table,
-                             start, Hq, Hkv):
-    """The paged kernel with ``Hq`` query heads over ``Hkv`` KV heads:
-    the same grid, pages and online-softmax state; the spread query has
-    a row per QUERY head on its KV head's span of the (Hkv·D)-lane page
-    rows (W·Hq rows of Hkv·D lanes), so one matmul a page still gives
-    every head's scores.  q enters and leaves as (B, W·Hq, D) rows —
-    the (B, W, Hq·D) activation seen through a free reshape."""
-    B, W, HD = q.shape
+    Hq = int(num_heads)
+    Hkv = Hq if kv_heads is None else int(kv_heads)
     D = HD // Hq
+    KD = Hkv * D
     KVB = k_pool.shape[1]
     MB = block_table.shape[1]
-    KD = Hkv * D
-    if scales or Hq % Hkv or Hq % 16 or k_pool.shape[2] != KD:
+    quant = bool(scales)
+    if Hkv == Hq:
+        what = f"{Hq} heads x {D}"
+        q_block = (1, W, HD)
+    else:
+        what = f"{Hq} query heads over {Hkv} KV heads x {D}"
+        if quant or Hq % Hkv or Hq % 16 or k_pool.shape[2] != KD:
+            raise MXNetError(
+                f"paged_attention: {what} wants unquantized (P, KVB, "
+                f"{KD}) pools, {Hkv} | {Hq} and whole sublane tiles of "
+                f"query heads ({Hq} % 16 == 0); got pools "
+                f"{tuple(k_pool.shape)}, scales {len(scales)}")
+        # q enters and leaves as (B, W·Hq, D) rows — the (B, W, Hq·D)
+        # activation seen through a free reshape
+        q_block = (1, W * Hq, D)
+    if KD % 128 and not _interpret():
         raise MXNetError(
-            f"paged_attention: {Hq} query heads over {Hkv} KV heads x "
-            f"{D} wants unquantized (P, KVB, {KD}) pools, {Hkv} | {Hq} "
-            f"and whole sublane tiles of query heads ({Hq} % 16 == 0); "
-            f"got pools {tuple(k_pool.shape)}, scales {len(scales)}")
-    rows_q = W * Hq
-    vmem = (rows_q * KD * (q.dtype.itemsize + 4 + 4)
-            + 2 * KVB * KD * 2 * k_pool.dtype.itemsize)
+            f"paged_attention: {what} are page rows of {KD} lanes, and the "
+            f"kernel copies page rows in whole lane tiles (a multiple of "
+            f"128); ops.attention's lax body serves such a width "
+            f"(pallas_kernels.paged_enabled)")
+    # The all-heads form's VMEM grows as W·H²·D: 0.4 MB of spread
+    # query, accumulator and product at the benchmark's 20 x 64, W = 1,
+    # beside 2.6 MB of page buffers at 256 keys a chunk (3.1 MB in
+    # all; 2.9 MB at 64 / 8 x 128); every GPT-2 size at W <= 8 stays
+    # under 9 MB.  It does NOT fit every head count — 64 heads x 128 at
+    # W = 5 would want 26 MB of the 16 MB a kernel is given — and is
+    # refused here by name rather than by a Mosaic allocation error.  A
+    # model that wide needs its heads sharded over tp, or fewer window
+    # rows.
+    HP, pages, vmem = _paged_pages_per_chunk(
+        W, Hq, Hkv, D, KVB, MB, q.dtype.itemsize, k_pool.dtype.itemsize,
+        quant)
+    rows = W * HP
     if vmem > _PAGED_VMEM_BUDGET:
         raise MXNetError(
-            f"paged_attention: {Hq} query heads over {Hkv} x {D} with a "
-            f"{W}-row window needs about {vmem >> 20} MB of VMEM, more "
-            f"than the {_PAGED_VMEM_BUDGET >> 20} MB it may count on")
+            f"paged_attention: {what} with a {W}-row window needs about "
+            f"{vmem >> 20} MB of VMEM in the all-heads form ({rows} rows "
+            f"of {KD} lanes, three times over, beside one page of {KVB} "
+            f"keys a chunk), more than the {_PAGED_VMEM_BUDGET >> 20} MB "
+            f"it may count on; fewer window rows fit, or heads sharded "
+            f"over tp")
     kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
-                             kvb=KVB, nb=MB, w=W, h=Hkv, d=D, hp=Hq,
-                             quant=False, g=Hq // Hkv)
+                             kvb=KVB, pages=pages, mb=MB, w=W, h=Hkv, d=D,
+                             hp=HP, quant=quant, g=Hq // Hkv)
 
-    def page():
-        return _vmem_spec((1, KVB, KD),
-                          lambda b, j, tr, sr: (tr[b, j], 0, 0))
+    def rows_spec():
+        return _vmem_spec(q_block, lambda b, tr, sr: (b, 0, 0))
 
-    def rows():
-        return _vmem_spec((1, rows_q, D), lambda b, j, tr, sr: (b, 0, 0))
-
+    table = block_table.astype(jnp.int32)
+    if quant:
+        # a (KVB, H) page of scales has no lane-aligned slice to copy
+        # by hand, so the scales of a row's table are gathered out here
+        # and arrive with the row; the table is padded (scratch page)
+        # to whole chunks, so that every chunk has its rows of them
+        whole = jnp.pad(table, ((0, 0), (0, -MB % pages)))
+        scales = tuple(s[whole].reshape(B, -1, Hkv) for s in scales)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, MB),
-        in_specs=[rows(), page(), page()],
-        out_specs=rows(),
-        scratch_shapes=[pltpu.VMEM((rows_q, KD), q.dtype),
-                        pltpu.VMEM((rows_q, KD), jnp.float32),
-                        pltpu.VMEM((rows_q, 128), jnp.float32),
-                        pltpu.VMEM((rows_q, 128), jnp.float32)],
+        grid=(B,),
+        in_specs=[rows_spec(), pl.BlockSpec(memory_space=pltpu.HBM),
+                  pl.BlockSpec(memory_space=pltpu.HBM)] + [
+            _vmem_spec((1,) + s.shape[1:], lambda b, tr, sr: (b, 0, 0))
+            for s in scales],
+        out_specs=rows_spec(),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages) + k_pool.shape[1:], k_pool.dtype),
+            pltpu.VMEM((2, pages) + v_pool.shape[1:], v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((rows, KD), q.dtype),
+            pltpu.VMEM((rows, KD), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32),
+            pltpu.VMEM((rows, 128), jnp.float32)],
     )
+    # rows in order ("arbitrary"): a row starts the next row's first
+    # copies and hands on which buffer they went to
     out = pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, rows_q, D), q.dtype),
-        compiler_params=_compiler_params("parallel", "arbitrary"),
+        out_shape=jax.ShapeDtypeStruct((B,) + q_block[1:], q.dtype),
+        compiler_params=_compiler_params("arbitrary"),
         interpret=_interpret(),
-        name="paged_attention",
-    )(block_table.astype(jnp.int32), start.astype(jnp.int32),
-      q.reshape(B, rows_q, D), k_pool, v_pool)
+        name="paged_attention_q" if scales else "paged_attention",
+    )(table, start.astype(jnp.int32), q.reshape((B,) + q_block[1:]),
+      k_pool, v_pool, *scales)
     return out.reshape(B, W, HD)
 
 

@@ -340,6 +340,122 @@ def test_paged_quant_kernel_float32_operands_under_bf16_query(
     assert differ.mean() < 0.02, differ.mean()
 
 
+def _chunk_case(shape, kind, lengths_of, rng, nan_past_live=False):
+    """Pools, table, queries and the float32 gather-and-softmax answer
+    for rows whose live lengths ``lengths_of(C, bucket)`` names, C the
+    kernel's own chunk at this shape.  ``nan_past_live``: every page no
+    row position can see — the scratch page too — holds NaN."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as att, pallas_kernels as pk
+
+    Hq, Hkv, D = shape
+    W = {"w1": 1, "w5": 5, "int8": 1}[kind]
+    page, MB = 16, 40                       # a 640-token bucket
+    C = page * pk._paged_pages_per_chunk(
+        W, Hq, Hkv, D, page, MB, 4, 1 if kind == "int8" else 4,
+        kind == "int8")[1]
+    assert C < MB * page, "the bucket must hold more than one chunk"
+    live = np.asarray(lengths_of(C, MB * page), np.int32)
+    B = len(live)
+    start = live - W                        # cached before the window
+    P = B * MB + 1
+    kp, vp = (rng.randn(P, page, Hkv * D).astype(np.float32)
+              for _ in range(2))
+    table = (1 + rng.permutation(P - 1)).reshape(B, MB).astype(np.int32)
+    q = rng.randn(B, W, Hq * D).astype(np.float32)
+    scales = ()
+    if kind == "int8":
+        (kp, ks), (vp, vs) = ([np.array(a) for a in att._quantize_rows(
+            jnp.asarray(x), Hkv, jnp.int8)] for x in (kp, vp))
+        scales = (ks, vs)
+
+    def cache(pool, scale=None):
+        g = pool[table].reshape(B, MB * page, Hkv, D).astype(np.float64)
+        if scale is not None:
+            g = g * scale[table].reshape(B, MB * page, Hkv)[..., None]
+        return np.repeat(g, Hq // Hkv, axis=2)
+
+    kc, vc = cache(kp, *scales[:1]), cache(vp, *scales[1:])
+    want = np.zeros((B, W, Hq, D))
+    for b in range(B):
+        for w in range(W):
+            n = start[b] + 1 + w            # keys row w sees
+            if n <= 0:
+                continue
+            qh = q[b, w].reshape(Hq, D).astype(np.float64)
+            s = np.einsum("hd,thd->ht", qh, kc[b, :n]) / np.sqrt(D)
+            e = np.exp(s - s.max(axis=1, keepdims=True))
+            want[b, w] = np.einsum("ht,thd->hd",
+                                   e / e.sum(axis=1, keepdims=True),
+                                   vc[b, :n])
+    if nan_past_live:
+        dead = np.ones(P, bool)
+        for b in range(B):
+            dead[table[b, :-(-int(live[b]) // page)]] = False
+        table = np.where(np.arange(MB)[None] * page < live[:, None],
+                         table, 0).astype(np.int32)
+        for pool in (kp, vp) + scales:
+            if pool.dtype != np.int8:
+                pool[dead] = np.nan
+    args = [jnp.asarray(x) for x in (q, kp, vp)]
+    run = lambda: np.asarray(pk._paged_attention(          # noqa: E731
+        *args, tuple(jnp.asarray(x) for x in scales), jnp.asarray(table),
+        jnp.asarray(start), Hq, kv_heads=Hkv))
+    return run, want.reshape(B, W, Hq * D), live, C
+
+
+_CHUNK_LENGTHS = {
+    # the ends of a chunk: one key, one short of a chunk, a whole
+    # chunk, one over
+    "chunk-ends": lambda C, bucket: [1, C - 1, C, C + 1],
+    # a whole bucket, and an idle row between two full ones
+    "bucket": lambda C, bucket: [bucket, 0, bucket],
+}
+
+
+@pytest.mark.parametrize("lengths", sorted(_CHUNK_LENGTHS))
+@pytest.mark.parametrize("shape,kind", [
+    ((20, 20, 64), "w1"), ((20, 20, 64), "w5"), ((20, 20, 64), "int8"),
+    ((64, 8, 128), "w1"), ((64, 8, 128), "w5"), ((4, 4, 32), "w5")])
+def test_paged_kernel_walks_live_chunks(monkeypatch, shape, kind, lengths):
+    """The kernel takes a row's pages a chunk of C keys at a time and
+    walks only the chunks its live length reaches: rows that end one
+    key into a chunk, one short of it, on it and one past it, a whole
+    bucket, and an idle row beside full ones — the decode step, a
+    5-row window and int8 pools, at the doc cell's 20 x 64, the
+    grouped 64 / 8 x 128 and a width a quarter of a lane tile — each
+    against a float64 gather-and-softmax over the gathered pages."""
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    run, want, live, C = _chunk_case(shape, kind, _CHUNK_LENGTHS[lengths],
+                                     np.random.RandomState(5))
+    out = run()
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+    assert not out[live == 0].any()
+
+
+@pytest.mark.parametrize("shape,kind", [
+    ((20, 20, 64), "w1"), ((20, 20, 64), "int8"), ((64, 8, 128), "w5")])
+def test_paged_kernel_reads_no_page_past_the_live_length(monkeypatch, shape,
+                                                         kind):
+    """Every page past a row's live length, and the scratch page the
+    table pads with, filled with NaN (the int8 pools' scales, there):
+    the walk is bounded and the mask right, so the output is finite and
+    equal to the clean pools' — a NaN that reached a buffer would come
+    through P·V however exactly its probability is 0."""
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    mixed = lambda C, bucket: [C + 1, 0, 3, bucket, C - 16]  # noqa: E731
+    clean, want, _, _ = _chunk_case(shape, kind, mixed,
+                                    np.random.RandomState(7))
+    dirty, _, _, _ = _chunk_case(shape, kind, mixed,
+                                 np.random.RandomState(7),
+                                 nan_past_live=True)
+    out = dirty()
+    assert np.isfinite(out).all()
+    np.testing.assert_array_equal(out, clean())
+    np.testing.assert_allclose(out, want, rtol=1e-6, atol=1e-6)
+
+
 def test_paged_op_pallas_vs_lax_path(monkeypatch, lm):
     """QKVPagedAttentionDecode end to end: the kernel path equals the
     lax path at tolerance on identical pools/tables."""

@@ -114,9 +114,10 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     value_pool_shape``) against a lax gather of the pages and the
     fallbacks' blockwise body: W = 1 is the decode step, W > 1 a
     verify window, ``int8`` the quantized pools.  Streams of every
-    length from one token to a full table, pages handed out in a
-    shuffled order, one idle row.  ``Hq``: grouped queries, that many
-    query heads over the H KV heads."""
+    length from one token to a full table — three of them ending one
+    key short of the kernel's first chunk, on it and one past it —
+    pages handed out in a shuffled order, one idle row.  ``Hq``:
+    grouped queries, that many query heads over the H KV heads."""
     Hq = Hq or H
     from mxnet_tpu.kv_cache import value_pool_shape
     from mxnet_tpu.ops import attention as att
@@ -137,15 +138,33 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     # tokens cached before the window: row 0 none, the last row all
     # but the window, row 1 idle (a decode step's lengths == 0)
     start = np.linspace(0, MB * KVB - W, B).astype(np.int32)
+    chunk = KVB * pk._paged_pages_per_chunk(
+        W, Hq, H, D, KVB, MB, 2, 1 if kv_dtype == "int8" else 2,
+        kv_dtype == "int8")[1]
+    if chunk + 1 <= MB * KVB:
+        start[2:5] = np.arange(chunk - 1, chunk + 2) - W
     if W == 1:
         start[1] = -1
     start = jnp.asarray(start)
     q = jnp.asarray(rng.randn(B, W, Hq * D).astype(np.float32)
                     * 0.5).astype(jnp.bfloat16)
     scales = pools[2:]
-    got = jax.jit(lambda q, t, s, *p: pk._paged_attention(
-        q, p[0], p[1], p[2:], t, s, Hq, kv_heads=H))(
-            q, table, start, *pools)
+    kernel = jax.jit(lambda q, t, s, *p: pk._paged_attention(
+        q, p[0], p[1], p[2:], t, s, Hq, kv_heads=H))
+    if H * D % 128 and not pk._interpret():
+        # compiled, the kernel copies page rows in whole lane tiles and
+        # refuses another width by name (ops.attention's lax body
+        # serves it: pk.paged_enabled)
+        from mxnet_tpu.base import MXNetError
+        try:
+            kernel(q, table, start, *pools)
+            ok = False
+        except MXNetError as e:
+            ok = "whole lane tiles" in str(e)
+        print(f"{'OK ' if ok else 'FAIL'} paged  H={Hq}/{H} D={D} W={W}: "
+              f"{H * D} lanes refused by name", flush=True)
+        return ok
+    got = kernel(q, table, start, *pools)
 
     def gather_and_attend(q, t, s, *p):
         # the gathered ROWS take the head dim, never a pool
@@ -169,6 +188,7 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     ok = err < TOL and bool(np.isfinite(got).all()) \
         and not np.abs(got[~live]).any()
     print(f"{'OK ' if ok else 'FAIL'} paged  H={Hq}/{H} D={D} W={W} "
+          f"B={B} MB={MB} chunk={chunk} "
           f"pools={kv_dtype}{' +scales' if scales else ''}: "
           f"fwd={err:.4f}", flush=True)
     return ok
@@ -187,6 +207,11 @@ def main():
     # hybrid family's attention layers (decode and a 2-row window)
     for W in (1, 2):
         results.append(check_paged(8, 128, W, "bf16", Hq=64))
+    # the serving cells' own decode steps: 48 rows over 64-page tables,
+    # and 160-page tables of 64 / 8 x 128 (16 rows of the cell's 128:
+    # the reference gathers every row's table at 64 heads, 84 MB a row)
+    results.append(check_paged(20, 64, 1, "bf16", B=48, MB=64))
+    results.append(check_paged(8, 128, 1, "bf16", B=16, MB=160, Hq=64))
     if "--paged" in sys.argv:
         return _report(results)
     # packed: sweep revisit counts, block sizes, head counts, causality
