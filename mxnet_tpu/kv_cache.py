@@ -120,15 +120,18 @@ def value_pool_shape(pages: int, kv_block: int, num_heads: int,
     return (int(pages), int(kv_block), int(num_heads) * int(d_head))
 
 
-def state_pool_shape(slots: int, num_heads: int, d_head: int) -> tuple:
-    """Shape of a recurrent layer's STATE pool: one (heads, d_head,
-    d_head) float32 matrix stack per slot — the second kind of
-    per-stream state, beside the K/V pages.  A stream holds ONE slot
-    from admission to retirement, whatever its length; slot 0 is
-    scratch (padded batch rows land there), so ``slots`` = live streams
-    + 1.  A head's matrix is held transposed, (d_v, d_k)
-    (``ops/pallas_hybrid.py``)."""
-    return (int(slots), int(num_heads), int(d_head), int(d_head))
+def state_pool_shape(slots: int, head_state) -> tuple:
+    """Shape of a recurrent layer's STATE pool: per slot the mixer's
+    ``head_state`` = (heads, rows, lanes) float32 stack of matrices —
+    the second kind of per-stream state, beside the K/V pages.  What a
+    head's matrix is, is the mixer's to say (``models/hybrid_lm.py
+    mixer_state``): a kda head's (d_v, d_k), held transposed; a mamba2
+    head's (head_dim, d_state), not square (``ops/pallas_hybrid.py``).
+    A stream holds ONE slot from admission to retirement, whatever its
+    length; slot 0 is scratch (padded batch rows land there), so
+    ``slots`` = live streams + 1."""
+    heads, rows, lanes = (int(n) for n in head_state)
+    return (int(slots), heads, rows, lanes)
 
 
 def conv_tail_shape(slots: int, kernel: int, channels: int) -> tuple:

@@ -5,8 +5,9 @@ no biases, no positions) in which every layer names its own MIXER and
 its own FFN:
 
 * mixer ``attention``: softmax attention with grouped queries
-  (``heads`` query heads over ``kv_heads`` KV heads of ``head_dim``), an
-  optional element-wise sigmoid output gate before the output
+  (``heads`` query heads over ``kv_heads`` KV heads of ``head_dim``;
+  ``scale``: the scores' multiplier where it is not ``head_dim^-1/2``),
+  an optional element-wise sigmoid output gate before the output
   projection; its per-stream state is K/V PAGES;
 * mixer ``kda``: gated-delta-rule linear attention with a per-channel
   decay (``heads`` heads of ``head_dim``, a depthwise short convolution
@@ -14,24 +15,38 @@ its own FFN:
   rank ``head_dim``, ``neg_eigval``: beta in (0, 2)); its per-stream
   state is a SLOT: the (heads, head_dim, head_dim) float32 state and the
   convolution's last ``conv - 1`` inputs;
+* mixer ``mamba2``: a Mamba-2 state-space layer, ``heads`` heads of
+  ``head_dim`` over a state of ``d_state`` (one fused input map to gate
+  | x, B, C | dt; a depthwise short convolution of ``conv`` taps, with
+  a bias where ``conv_bias``, over x, B and C; a scalar decay a head; B
+  and C shared by all heads — ONE group; the gate BEFORE the output
+  norm); its slot: the (heads, head_dim, d_state)
+  float32 state and the convolution's last inputs;
 * ffn ``dense``: a gated (SiLU) feed-forward of ``width``;
 * ffn ``moe``: ``experts`` routed experts of ``width``, ``top_k`` a
-  token with normalised sigmoid scores, plus ``shared`` always-on
-  experts; this program HOLDS ``experts_held`` of the routed experts,
+  token (``score``: ``sigmoid`` — normalised sigmoid scores, the
+  default — or ``softmax_topk`` — a softmax over the chosen logits),
+  plus ``shared`` always-on experts (of ``shared_width`` together;
+  default ``width`` each); this program HOLDS ``experts_held`` of the routed experts,
   from ``first_expert`` on — the share of one chip of an expert-parallel
   deployment — and adds only their part (``ops/hybrid.py`` MoEFFN).
 
 :class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
 takes: from the layer list it derives the feeds, the pools (pages for
-attention layers, slots for kda layers) and the prefill and decode
-symbols.  The equations are in ``benchmark/reference/solar_open2.py``,
-the plain reference this family is held to.
+attention layers, slots for kda and mamba2 layers) and the prefill and
+decode symbols.  Four multipliers and the head's weights are data of
+the spec too: ``embed_scale`` (on the token rows), ``residual_scale``
+(on every block's output before it is added), ``logits_scale`` (on the
+last norm's output before the head) and ``tied_head`` (the head is the
+token table).  The equations are in ``benchmark/reference/
+solar_open2.py`` and ``granitemoehybrid.py``, the plain references
+this family is held to.
 """
 
 from .. import symbol as sym
 from ..base import MXNetError
 
-MIXERS = ("attention", "kda")
+MIXERS = ("attention", "kda", "mamba2")
 FFNS = ("dense", "moe")
 COUNTERS = "moe_counters"
 
@@ -52,6 +67,19 @@ def _gated_ffn(h, width, d_model, name):
     return _fc(g * _fc(h, width, f"{name}_up"), d_model, f"{name}_down")
 
 
+def mixer_state(m):
+    """What a recurrent mixer keeps a stream: ((heads, rows, lanes) of
+    its float32 state, the channels its short convolution carries); None
+    for a mixer whose state is pages."""
+    if m["kind"] == "kda":
+        H, D = int(m["heads"]), int(m["head_dim"])
+        return (H, D, D), 3 * H * D
+    if m["kind"] == "mamba2":
+        H, P, N = int(m["heads"]), int(m["head_dim"]), int(m["d_state"])
+        return (H, P, N), H * P + 2 * N
+    return None
+
+
 class HybridSpec:
     """The model ``DecodeEngine`` is given: sizes and the layer list.
 
@@ -59,10 +87,16 @@ class HybridSpec:
     {"kind": ...}}`` with the keys the module doc names.  Plain data: a
     spec round-trips through JSON (:meth:`to_dict`)."""
 
-    def __init__(self, vocab_size, d_model, layers, norm_eps=1e-5):
+    def __init__(self, vocab_size, d_model, layers, norm_eps=1e-5,
+                 embed_scale=1.0, residual_scale=1.0, logits_scale=1.0,
+                 tied_head=False):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.norm_eps = float(norm_eps)
+        self.embed_scale = float(embed_scale)
+        self.residual_scale = float(residual_scale)
+        self.logits_scale = float(logits_scale)
+        self.tied_head = bool(tied_head)
         self.layers = [dict(mixer=dict(ly["mixer"]), ffn=dict(ly["ffn"]))
                        for ly in layers]
         for i, ly in enumerate(self.layers):
@@ -77,6 +111,11 @@ class HybridSpec:
                 raise MXNetError(
                     f"layer {i}: {m['kv_heads']} KV heads do not divide "
                     f"{m['heads']} query heads")
+            if m["kind"] == "mamba2" and int(m.get("groups", 1)) != 1:
+                raise MXNetError(
+                    f"layer {i}: a mamba2 mixer of {m['groups']} groups "
+                    f"of B and C; one group, shared by all heads, is "
+                    f"built")
             if f["kind"] == "moe":
                 held = int(f.get("experts_held", f["experts"]))
                 first = int(f.get("first_expert", 0))
@@ -117,16 +156,16 @@ class HybridSpec:
     def cache_kinds(self):
         """Per layer, the kind of its per-stream state: ``pages`` (K/V,
         through the block table) or ``slots`` (one row a stream)."""
-        return tuple("slots" if k == "kda" else "pages"
+        return tuple("pages" if k == "attention" else "slots"
                      for k in self.mixer_kinds())
 
     def has_moe(self):
         return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
 
     def pool_kinds(self, kv_dtype="fp32"):
-        """The kind of each of :meth:`pools`' rows: a kda layer's state
-        is what ``return_state`` reads, its convolution's tail rides in
-        the same slot."""
+        """The kind of each of :meth:`pools`' rows: a recurrent layer's
+        state is what ``return_state`` reads, its convolution's tail
+        rides in the same slot."""
         out = []
         for k in self.cache_kinds():
             out += ["pages", "pages"] if k == "pages" \
@@ -151,11 +190,11 @@ class HybridSpec:
                 out += [(f"layer{i}_kpool", shape, dtype, 0),
                         (f"layer{i}_vpool", shape, dtype, 0)]
             else:
-                H, D = int(m["heads"]), int(m["head_dim"])
+                head_state, channels = mixer_state(m)
                 out += [(f"layer{i}_state",
-                         state_pool_shape(slots, H, D), "float32", 0),
+                         state_pool_shape(slots, head_state), "float32", 0),
                         (f"layer{i}_tail",
-                         conv_tail_shape(slots, int(m["conv"]), 3 * H * D),
+                         conv_tail_shape(slots, int(m["conv"]), channels),
                          "float32", 0)]
         if self.has_moe():
             out.append((COUNTERS, (4,), "int32", 0))
@@ -167,14 +206,18 @@ class HybridSpec:
                              f"builds {self.phases})")
         return _trunk(self, step=(which == "decode"))
 
+    _SCALARS = ("norm_eps", "embed_scale", "residual_scale",
+                "logits_scale", "tied_head")
+
     def to_dict(self):
         return {"vocab_size": self.vocab_size, "d_model": self.d_model,
-                "norm_eps": self.norm_eps, "layers": self.layers}
+                "layers": self.layers,
+                **{k: getattr(self, k) for k in self._SCALARS}}
 
     @classmethod
     def from_dict(cls, d):
         return cls(d["vocab_size"], d["d_model"], d["layers"],
-                   d.get("norm_eps", 1e-5))
+                   **{k: d[k] for k in cls._SCALARS if k in d})
 
 
 def _attention(spec, h, i, m, step, feeds):
@@ -185,10 +228,11 @@ def _attention(spec, h, i, m, step, feeds):
     k = _fc(h, Hkv * D, f"{name}_k")
     v = _fc(h, Hkv * D, f"{name}_v")
     op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
+    scale = {"scale": float(m["scale"])} if m.get("scale") else {}
     att = op(q, k, v, sym.Variable(f"{name}_kpool"),
              sym.Variable(f"{name}_vpool"), feeds["block_table"],
              feeds["lengths"], num_heads=H, kv_heads=Hkv,
-             name=f"{name}_attn")
+             name=f"{name}_attn", **scale)
     out = att[0]
     if m.get("gate"):
         out = out * sym.Activation(_fc(h, H * D, f"{name}_gate"),
@@ -219,11 +263,44 @@ def _kda(spec, h, i, m, step, feeds):
     return _fc(out, spec.d_model, f"{name}_o"), [rec[1], conv[1]]
 
 
+def _mamba2(spec, h, i, m, step, feeds):
+    (H, P, N), channels = mixer_state(m)
+    name = f"layer{i}"
+    # one fused input map: gate | x, B, C | dt
+    widths = (H * P, channels, H)
+    proj = _fc(h, sum(widths), f"{name}_in")
+    at = (0, widths[0], widths[0] + channels, sum(widths))
+    z, xbc, dt = (sym.slice_axis(proj, axis=-1, begin=at[j], end=at[j + 1])
+                  for j in range(3))
+    conv_args = [xbc, sym.Variable(f"{name}_conv_weight"),
+                 sym.Variable(f"{name}_tail"), feeds["slots"],
+                 feeds["lengths"]]
+    bias = {}
+    if m.get("conv_bias"):
+        conv_args.append(sym.Variable(f"{name}_conv_bias"))
+        bias = {"bias": True}
+    conv = sym.ShortConv(*conv_args, step=step, name=f"{name}_conv", **bias)
+    op = sym.Mamba2Step if step else sym.Mamba2Chunk
+    rec = op(conv[0], dt, sym.Variable(f"{name}_a_log"),
+             sym.Variable(f"{name}_dt_bias"), sym.Variable(f"{name}_d_skip"),
+             sym.Variable(f"{name}_state"), feeds["slots"],
+             feeds["lengths"], num_heads=H, d_state=N,
+             name=f"{name}_mamba2")
+    out = sym.GatedRMSNorm(rec[0], z, sym.Variable(f"{name}_onorm_gamma"),
+                           eps=spec.norm_eps, gate="silu_first",
+                           name=f"{name}_onorm")
+    return _fc(out, spec.d_model, f"{name}_out"), [rec[1], conv[1]]
+
+
+_MIXER_BUILDERS = {"attention": _attention, "kda": _kda, "mamba2": _mamba2}
+
+
 def _ffn(spec, h, i, f, step, feeds, counters):
     name = f"layer{i}"
     if f["kind"] == "dense":
         return _gated_ffn(h, int(f["width"]), spec.d_model,
                           f"{name}_ffn"), counters
+    score = {"score": f["score"]} if f.get("score") else {}
     routed = sym.MoEFFN(
         h, sym.Variable(f"{name}_router_weight"),
         sym.Variable(f"{name}_experts_gate_weight"),
@@ -231,11 +308,12 @@ def _ffn(spec, h, i, f, step, feeds, counters):
         sym.Variable(f"{name}_experts_down_weight"), feeds["lengths"],
         counters, top_k=int(f["top_k"]),
         first_expert=int(f.get("first_expert", 0)), step=step,
-        count=step, name=f"{name}_moe")
+        count=step, name=f"{name}_moe", **score)
     out = routed[0]
     if int(f.get("shared", 0)):
-        out = out + _gated_ffn(h, int(f["width"]) * int(f["shared"]),
-                               spec.d_model, f"{name}_shared")
+        width = int(f.get("shared_width",
+                          int(f["width"]) * int(f["shared"])))
+        out = out + _gated_ffn(h, width, spec.d_model, f"{name}_shared")
     # decode steps count; a prefill hands the counters on as they are
     return out, (routed[1] if step else counters)
 
@@ -245,22 +323,31 @@ def _trunk(spec, step):
     arrays, updated.  ``step``: one token a stream against its state
     (decode); else a whole (padded) prompt from nothing (prefill)."""
     feeds = {k: sym.Variable(k) for k in spec.feeds}
-    x = sym.Embedding(feeds["data"], input_dim=spec.vocab_size,
-                      output_dim=spec.d_model, name="tok_embed",
-                      weight=sym.Variable("tok_embed_weight"))
+    def scaled(t, by):       # a multiplier of 1 adds no node
+        return t if by == 1.0 else t * by
+
+    table = sym.Variable("tok_embed_weight")
+    x = scaled(sym.Embedding(feeds["data"], input_dim=spec.vocab_size,
+                             output_dim=spec.d_model, name="tok_embed",
+                             weight=table), spec.embed_scale)
     counters = sym.Variable(COUNTERS) if spec.has_moe() else None
     state = []
     for i, ly in enumerate(spec.layers):
         h = _norm(x, f"layer{i}_norm1", spec.norm_eps)
-        mix = _attention if ly["mixer"]["kind"] == "attention" else _kda
-        out, st = mix(spec, h, i, ly["mixer"], step, feeds)
+        out, st = _MIXER_BUILDERS[ly["mixer"]["kind"]](
+            spec, h, i, ly["mixer"], step, feeds)
         state += st
-        x = x + out
+        x = x + scaled(out, spec.residual_scale)
         h = _norm(x, f"layer{i}_norm2", spec.norm_eps)
         out, counters = _ffn(spec, h, i, ly["ffn"], step, feeds, counters)
-        x = x + out
-    x = _norm(x, "final_norm", spec.norm_eps)
-    logits = _fc(x, spec.vocab_size, "head")
+        x = x + scaled(out, spec.residual_scale)
+    x = scaled(_norm(x, "final_norm", spec.norm_eps), spec.logits_scale)
+    if spec.tied_head:
+        logits = sym.FullyConnected(x, num_hidden=spec.vocab_size,
+                                    flatten=False, no_bias=True,
+                                    name="head", weight=table)
+    else:
+        logits = _fc(x, spec.vocab_size, "head")
     if counters is not None:
         state.append(counters)
     return sym.Group([logits] + state)

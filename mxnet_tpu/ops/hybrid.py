@@ -1,15 +1,17 @@
 """Ops of the hybrid language-model family (``models/hybrid_lm.py``):
 RMS normalisation, grouped-query attention over the paged K/V cache,
-the KDA linear-attention layer (short convolution with a carried tail,
-the gated delta rule with a per-channel decay) over per-stream state
-SLOTS, and the routed-expert feed-forward layer that is told which
-experts it holds.
+the recurrent mixers over per-stream state SLOTS — the KDA
+linear-attention layer (short convolution with a carried tail, the gated
+delta rule with a per-channel decay) and the Mamba-2 state-space layer
+(the same convolution with a bias, a per-head scalar decay, B and C
+shared by a group's heads) — and the routed-expert feed-forward layer
+that is told which experts it holds.
 
 Two kinds of per-stream state live side by side in a serving program:
 K/V PAGES (``kv_cache.value_pool_shape``, addressed through a block
 table; attention layers) and SLOTS (``kv_cache.state_pool_shape`` /
-``conv_tail_shape``, one row per live stream, row 0 scratch; KDA
-layers).  Every op here that touches a pool takes it in and hands it
+``conv_tail_shape``, one row per live stream, row 0 scratch; KDA and
+Mamba-2 layers).  Every op here that touches a pool takes it in and hands it
 back, so that a jitted step donates it and updates in place.
 
 Forward only: training this family fits no chip the benchmark has, so no
@@ -68,14 +70,25 @@ def _rms_norm(op_ctx, attrs, inputs, aux):
               [s[0], s[0], None if s[0] is None else (
                   s[0][-1] // attr_int(attrs.get("num_groups", 1), 1),)],
               [s[0]], []),
-          doc="RMSNorm(data) * sigmoid(gate): a KDA layer's output norm "
-              "per head under its gate; attrs as RMSNorm")
+          doc="A recurrent layer's output norm under its gate.  "
+              "gate='sigmoid' (default): RMSNorm(data) * sigmoid(gate), "
+              "the gate AFTER the norm (KDA, per head); gate='silu_first': "
+              "RMSNorm(data * SiLU(gate)), the gate BEFORE it (Mamba-2, "
+              "over all channels).  Other attrs as RMSNorm")
 def _gated_rms_norm(op_ctx, attrs, inputs, aux):
     x, gate, gamma = inputs
-    y = rms_norm(x.astype(jnp.float32), gamma,
-                 attr_float(attrs.get("eps", 1e-5), 1e-5),
-                 attr_int(attrs.get("num_groups", 1), 1))
-    return [(y * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(x.dtype)]
+    eps = attr_float(attrs.get("eps", 1e-5), 1e-5)
+    groups = attr_int(attrs.get("num_groups", 1), 1)
+    form = str(attrs.get("gate", "sigmoid"))
+    xf, gf = x.astype(jnp.float32), gate.astype(jnp.float32)
+    if form == "silu_first":
+        y = rms_norm(xf * jax.nn.silu(gf), gamma, eps, groups)
+    elif form == "sigmoid":
+        y = rms_norm(xf, gamma, eps, groups) * jax.nn.sigmoid(gf)
+    else:
+        raise MXNetError(f"GatedRMSNorm: gate {form!r} is neither "
+                         f"'sigmoid' nor 'silu_first'")
+    return [y.astype(x.dtype)]
 
 
 # ---------------------------------------------------------------------------
@@ -88,6 +101,17 @@ def _repeat_heads(x, H, Hkv):
     D = x.shape[-1] // Hkv
     x = x.reshape(x.shape[:-1] + (Hkv, D))
     return jnp.repeat(x, H // Hkv, axis=-2)
+
+
+def _gqa_scaled(attrs, q, H):
+    """``q`` such that the attention bodies' own ``head_dim^-1/2`` makes
+    the scores ``scale * q.k``: the ``scale`` attr (0 = head_dim^-1/2,
+    q as it is) is folded into q, in float32, once."""
+    scale = attr_float(attrs.get("scale", 0.0), 0.0)
+    if not scale:
+        return q
+    d = q.shape[-1] // H
+    return (q.astype(jnp.float32) * (scale * float(d) ** 0.5)).astype(q.dtype)
 
 
 def _gqa_heads(attrs, q, k):
@@ -121,12 +145,14 @@ _GQA_OUTS = ("output", "new_k_pool", "new_v_pool")
               "query (B, T, H*D), key/value (B, T, Hkv*D), pools "
               "(P, KVB, Hkv*D) -> output (B, T, H*D) + pools.  Query "
               "head i reads KV head i // (H / Hkv); no rotation.  "
-              "attrs: num_heads, kv_heads")
+              "attrs: num_heads, kv_heads, scale (the scores' multiplier; "
+              "0 = head_dim^-1/2)")
 def _gqa_prefill(op_ctx, attrs, inputs, aux):
     from .attention import blockwise_attention, paged_prefill_write
 
     q, k, v, k_pool, v_pool, table, lengths = inputs
     H, Hkv = _gqa_heads(attrs, q, k)
+    q = _gqa_scaled(attrs, q, H)
     B, T, HD = q.shape
     out = blockwise_attention(
         q.reshape(B, T, H, HD // H), _repeat_heads(k, H, Hkv),
@@ -145,7 +171,7 @@ def _gqa_prefill(op_ctx, attrs, inputs, aux):
               "counting the token -> output (B, 1, H*D) + pools.  The "
               "paged kernel with query row i on KV span i // (H / Hkv) "
               "on TPU, a lax gather elsewhere.  attrs: num_heads, "
-              "kv_heads")
+              "kv_heads, scale (as GQAPrefillAttention)")
 def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
     from . import pallas_kernels as pk
     from .attention import decode_attention, paged_cache_update
@@ -155,6 +181,7 @@ def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
     if q.shape[1] != 1:
         raise MXNetError(f"GQAPagedDecode feeds ONE position a step; "
                          f"got query {tuple(q.shape)}")
+    q = _gqa_scaled(attrs, q, H)
     lengths = lengths.astype(jnp.int32)
     table = table.astype(jnp.int32)
     kp, vp = paged_cache_update(k_pool, v_pool, k, v, table, lengths)
@@ -174,17 +201,19 @@ def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
 # ShortConv: depthwise causal convolution with a carried tail
 # ---------------------------------------------------------------------------
 
-def short_conv(x, w, left):
-    """y[t, c] = SiLU(sum_j w[c, j] * xp[t + j, c]) with ``xp`` = the
-    ``K - 1`` rows of ``left`` before ``x``: tap ``K - 1`` is on the
-    current token.  x (B, S, C); w (C, K); left (B, K - 1, C).  Float32
-    inside."""
+def short_conv(x, w, left, bias=None):
+    """y[t, c] = SiLU(sum_j w[c, j] * xp[t + j, c] (+ bias[c])) with
+    ``xp`` = the ``K - 1`` rows of ``left`` before ``x``: tap ``K - 1``
+    is on the current token.  x (B, S, C); w (C, K); left (B, K - 1, C);
+    bias (C,) or None.  Float32 inside."""
     K = w.shape[1]
     xp = jnp.concatenate([left.astype(jnp.float32),
                           x.astype(jnp.float32)], axis=1)
     S = x.shape[1]
     wf = w.astype(jnp.float32)
     y = sum(xp[:, j:j + S] * wf[:, j] for j in range(K))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y), xp
 
 
@@ -195,8 +224,13 @@ def _conv_infer(attrs, in_shapes):
     return in_shapes, [tuple(d), tuple(tail)], []
 
 
+_CONV_ARGS = ("data", "weight", "tail_pool", "slots", "lengths")
+
+
 @register("ShortConv",
-          arg_names=("data", "weight", "tail_pool", "slots", "lengths"),
+          arg_names=lambda attrs: _CONV_ARGS + (
+              ("bias",) if attr_bool(attrs.get("bias", False), False)
+              else ()),
           out_names=("output", "new_tail_pool"), infer_shape=_conv_infer,
           doc="Depthwise causal convolution of kernel K with SiLU, its "
               "last K - 1 inputs carried per stream: data (B, S, C), "
@@ -207,12 +241,14 @@ def _conv_infer(attrs, in_shapes):
               "sequence starts from nothing and the K - 1 inputs before "
               "position lengths[b] are written to the slot; step=1 "
               "(decode, S = 1): the slot's tail precedes the token and "
-              "is shifted by it.  -> output (B, S, C), the pool")
+              "is shifted by it.  bias=1: a sixth input, bias (C,), "
+              "added before the SiLU.  -> output (B, S, C), the pool")
 def _short_conv(op_ctx, attrs, inputs, aux):
     from . import pallas_hybrid as ph
     from . import pallas_kernels as pk
 
-    x, w, pool, slots, lengths = inputs
+    x, w, pool, slots, lengths = inputs[:5]
+    bias = inputs[5] if len(inputs) > 5 else None
     step = attr_bool(attrs.get("step", False), False)
     K = w.shape[1]
     slots = slots.astype(jnp.int32)
@@ -220,10 +256,11 @@ def _short_conv(op_ctx, attrs, inputs, aux):
     run = (K - 1) * C               # a slot's numbers, then padding
     if step:
         left = pool[slots].reshape(B, -1)[:, :run].reshape(B, K - 1, C)
-        y, xp = short_conv(x, w, left)
+        y, xp = short_conv(x, w, left, bias)
         tail = xp[:, 1:]
     else:
-        y, xp = short_conv(x, w, jnp.zeros((B, K - 1, C), jnp.float32))
+        y, xp = short_conv(x, w, jnp.zeros((B, K - 1, C), jnp.float32),
+                           bias)
         n = lengths.astype(jnp.int32)
         tail = jax.vmap(lambda row, at: lax.dynamic_slice_in_dim(
             row, at, K - 1, axis=0))(xp, n)
@@ -373,6 +410,181 @@ def _kda_step(op_ctx, attrs, inputs, aux):
 
 
 # ---------------------------------------------------------------------------
+# Mamba-2: a state-space layer with a per-head scalar decay
+# ---------------------------------------------------------------------------
+
+def mamba2_split(xbc, H, N):
+    """The conv's output (..., H·P + 2·N) as x (..., H, P) and B, C
+    (..., N), which every head shares (one group); in xbc's type."""
+    di = xbc.shape[-1] - 2 * N
+    if di <= 0 or di % H:
+        raise MXNetError(
+            f"Mamba-2: {xbc.shape[-1]} channels are not {H} heads of x "
+            f"and 2 x {N} (x | B | C)")
+    x = xbc[..., :di].reshape(xbc.shape[:-1] + (H, di // H))
+    return x, xbc[..., di:di + N], xbc[..., di + N:]
+
+
+def mamba2_gates(dt_raw, a_log, dt_bias):
+    """(Delta, log a), float32, shaped (..., H): the step ``Delta =
+    softplus(dt_raw + dt_bias)`` and the decay's logarithm ``-Delta *
+    exp(a_log)`` <= 0 (``a = exp`` of it, a number a head and token)."""
+    dt = jax.nn.softplus(dt_raw.astype(jnp.float32)
+                         + dt_bias.astype(jnp.float32))
+    return dt, -dt * jnp.exp(a_log.astype(jnp.float32))
+
+
+def mamba2_chunked(dx, bm, cm, la):
+    """The recurrence ``S_t = a_t S_{t-1} + dx_t B_t^T``, ``y_t = S_t
+    C_t`` over a prompt from the zero state, regrouped in chunks of
+    ``Q`` tokens (the SSD form): with l = the running sum of log a
+    inside a chunk, ``Y = ((C B^T) . exp(l_i - l_j)[i >= j]) dX +
+    exp(l_i) C_i S_prev``, a chunk's own state ``sum_j exp(l_Q - l_j)
+    dx_j B_j^T``, carried on with ``exp(l_Q)``.  Every exponent is a sum
+    of log-decays over a span of tokens, <= 0.
+
+    dx (B, T, H, P) = Delta . x and bm, cm (B, T, N) in the model's
+    type (the products run in it, sums float32); la (B, T, H) float32.
+    -> (y (B, T, H, P) float32, the last state (B, H, P, N) float32)."""
+    from .pallas_hybrid import MAMBA2_CHUNK as Q   # one chunk length
+
+    B, T, H, P = dx.shape
+    N = bm.shape[-1]
+    pad = -T % Q
+    if pad:       # a = 1, dx = 0: the state stands still
+        dx = jnp.pad(dx, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        bm, cm, la = (jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
+                      for t in (bm, cm, la))
+    nc = (T + pad) // Q
+    f32 = jnp.float32
+    prec = HI if dx.dtype == jnp.float32 else None
+    # chunks first: (nc, B, Q, ...)
+    dxc = jnp.moveaxis(dx.reshape(B, nc, Q, H, P), 1, 0)
+    bc, cc = (jnp.moveaxis(t.reshape(B, nc, Q, N), 1, 0) for t in (bm, cm))
+    cum = jnp.moveaxis(jnp.cumsum(la.reshape(B, nc, Q, H), axis=2), 1, 0)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def chunk(S, xs):
+        dxq, bq, cq, l = xs           # (B, Q, H, P), (B, Q, N), (B, Q, H)
+        lh = jnp.moveaxis(l, 2, 1)                        # (B, H, Q)
+        diff = lh[:, :, :, None] - lh[:, :, None, :]      # l_i - l_j
+        decay = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+        cb = jnp.einsum("bin,bjn->bij", cq, bq, precision=prec,
+                        preferred_element_type=f32)       # all heads'
+        y = jnp.einsum("bhij,bjhp->bihp",
+                       (cb[:, None] * decay).astype(dxq.dtype), dxq,
+                       precision=prec, preferred_element_type=f32)
+        y = y + jnp.exp(l)[..., None] * jnp.einsum(
+            "bin,bhpn->bihp", cq, S.astype(cq.dtype), precision=prec,
+            preferred_element_type=f32)
+        tot = l[:, -1]                                    # (B, H)
+        wb = (jnp.exp(tot[:, None] - l)[..., None]
+              * bq.astype(f32)[:, :, None, :]).astype(bq.dtype)
+        S = jnp.exp(tot)[:, :, None, None] * S + jnp.einsum(
+            "bjhp,bjhn->bhpn", dxq, wb, precision=prec,
+            preferred_element_type=f32)
+        return S, y
+
+    last, y = lax.scan(chunk, jnp.zeros((B, H, P, N), f32),
+                       (dxc, bc, cc, cum))
+    return jnp.moveaxis(y, 0, 1).reshape(B, T + pad, H, P)[:, :T], last
+
+
+def _mamba2_infer(attrs, in_shapes):
+    c, pool = in_shapes[0], in_shapes[5]
+    if c is None or pool is None:
+        return in_shapes, None, None
+    return in_shapes, [(c[0], c[1], pool[1] * pool[2]), tuple(pool)], []
+
+
+_MAMBA2_ARGS = ("xbc", "dt", "a_log", "dt_bias", "d_skip", "state_pool",
+                "slots", "lengths")
+_MAMBA2_DOC = (
+    "xbc (B, S, H*P + 2*N): the short convolution's output, x | B | C; "
+    "dt (B, S, H): the raw step projection; a_log, dt_bias, d_skip "
+    "(H,); state_pool (slots, H, P, N) float32; slots (B,) int32 (0 = "
+    "scratch) -> output (B, S, H*P) + the pool.  Delta = softplus(dt + "
+    "dt_bias), a = exp(-Delta exp(a_log)) a head; S_t = a_t S_{t-1} + "
+    "Delta_t x_t B_t^T, y_t = S_t C_t + d_skip x_t; B and C serve "
+    "every head (one group).  The state and every sum float32, the "
+    "products in xbc's type.  attrs: num_heads, d_state")
+
+
+def _mamba2_inputs(attrs, inputs):
+    xbc, dt_raw, a_log, dt_bias, d_skip, pool, slots, lengths = inputs
+    H = attr_int(attrs.get("num_heads", 1), 1)
+    N = attr_int(attrs.get("d_state", 1), 1)
+    x, bm, cm = mamba2_split(xbc, H, N)
+    if tuple(pool.shape[1:]) != (H, x.shape[-1], N):
+        raise MXNetError(
+            f"Mamba-2: a slot of the state pool {tuple(pool.shape)} is "
+            f"not ({H}, {x.shape[-1]}, {N}) (heads, head_dim, d_state)")
+    dt, la = mamba2_gates(dt_raw, a_log, dt_bias)
+    skip = d_skip.astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    return (x, bm, cm, dt, la, skip, pool, slots.astype(jnp.int32),
+            lengths.astype(jnp.int32))
+
+
+@register("Mamba2Chunk", arg_names=_MAMBA2_ARGS, out_names=_KDA_OUTS,
+          infer_shape=_mamba2_infer,
+          doc="Mamba-2 over a (padded) prompt from the zero state, in "
+              "the chunk (SSD) form of the recurrence — matrix products "
+              "over chunks of tokens (mamba2_chunked; on TPU the kernel "
+              "pallas_hybrid.mamba2_chunk); the state after position "
+              "lengths[b] - 1 is written to the slot.  " + _MAMBA2_DOC)
+def _mamba2_chunk(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    x, bm, cm, dt, la, skip, pool, slots, n = _mamba2_inputs(attrs, inputs)
+    B, T, H, P = x.shape
+    # a padded position leaves the state as it is: a = 1, dx = 0
+    live = (jnp.arange(T)[None, :] < n[:, None])[..., None]
+    la = jnp.where(live, la, 0.0)
+    dx = (jnp.where(live, dt, 0.0)[..., None]
+          * x.astype(jnp.float32)).astype(x.dtype)
+    if pk.enabled():
+        y, last = ph.mamba2_chunk(dx.reshape(B, T, H * P), inputs[0], la)
+        y = y.reshape(B, T, H, P)
+    else:
+        y, last = mamba2_chunked(dx, bm, cm, la)
+    y = (y.astype(jnp.float32) + skip).reshape(B, T, H * P)
+    return [y.astype(inputs[0].dtype),
+            pool.at[slots].set(last.astype(pool.dtype))]
+
+
+@register("Mamba2Step", arg_names=_MAMBA2_ARGS, out_names=_KDA_OUTS,
+          infer_shape=_mamba2_infer,
+          doc="Mamba-2 for ONE token per stream against the slot's "
+              "state, updated in place (S = 1; a padded row sits on slot "
+              "0).  " + _MAMBA2_DOC)
+def _mamba2_step(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    x, bm, cm, dt, la, skip, pool, slots, _ = _mamba2_inputs(attrs, inputs)
+    B, S, H, P = x.shape
+    if S != 1:
+        raise MXNetError(f"Mamba2Step feeds ONE position a step; got xbc "
+                         f"{tuple(inputs[0].shape)}")
+    f32 = jnp.float32
+    # the products in the model's type, as the chunk form has them
+    dx = (dt[..., None] * x.astype(f32)).astype(x.dtype).astype(f32)[:, 0]
+    a = jnp.exp(la[:, 0])
+    b_row, c_row = bm[:, 0].astype(f32), cm[:, 0].astype(f32)   # (B, N)
+    if pk.enabled():
+        y, pool = ph.mamba2_step(dx, a, b_row, c_row, pool.astype(f32),
+                                 slots)
+    else:
+        st = pool[slots].astype(f32) * a[:, :, None, None] \
+            + dx[..., None] * b_row[:, None, None, :]
+        y = jnp.sum(st * c_row[:, None, None, :], axis=-1)
+        pool = pool.at[slots].set(st.astype(pool.dtype))
+    y = (y[:, None] + skip).reshape(B, 1, H * P)
+    return [y.astype(inputs[0].dtype), pool]
+
+
+# ---------------------------------------------------------------------------
 # MoEFFN: routed experts, the share held here
 # ---------------------------------------------------------------------------
 
@@ -380,14 +592,24 @@ MOE_COUNTERS = ("moe_pairs_here", "moe_pairs_elsewhere", "moe_experts_hit",
                 "moe_load_max")
 
 
-def moe_route(x2, router_w, top_k):
-    """Scores, the ``top_k`` experts of each token and their normalised
-    weights, all float32: sigmoid scores over ALL experts, weights
-    ``s_e / sum_top s``.  x2 (N, d); router_w (E, d)."""
-    scores = jax.nn.sigmoid(jnp.dot(
-        x2.astype(jnp.float32), router_w.astype(jnp.float32).T,
-        precision=HI))
-    topv, topi = lax.top_k(scores, top_k)
+ROUTER_SCORES = ("sigmoid", "softmax_topk")
+
+
+def moe_route(x2, router_w, top_k, score="sigmoid"):
+    """The ``top_k`` experts of each token and their weights, float32,
+    from the router's logits over ALL experts.  ``score``: ``sigmoid`` —
+    sigmoid scores, the largest, weights ``s_e / sum_top s``;
+    ``softmax_topk`` — the largest LOGITS, weights a softmax over those
+    ``top_k`` alone.  x2 (N, d); router_w (E, d)."""
+    if score not in ROUTER_SCORES:
+        raise MXNetError(f"router score {score!r} is none of "
+                         f"{ROUTER_SCORES}")
+    logits = jnp.dot(x2.astype(jnp.float32),
+                     router_w.astype(jnp.float32).T, precision=HI)
+    if score == "softmax_topk":
+        topv, topi = lax.top_k(logits, top_k)
+        return topi, jax.nn.softmax(topv, axis=-1)
+    topv, topi = lax.top_k(jax.nn.sigmoid(logits), top_k)
     return topi, topv / jnp.sum(topv, axis=-1, keepdims=True)
 
 
@@ -473,8 +695,10 @@ def _moe_infer(attrs, in_shapes):
               "for the experts HELD here: data (B, S, d); router_weight "
               "(experts, d) float32 over ALL experts; gate/up_weight "
               "(held, d, w), down_weight (held, w, d): experts "
-              "first_expert .. first_expert + held - 1.  Sigmoid scores, "
-              "the top_k largest, weights s_e / sum_top s (float32); "
+              "first_expert .. first_expert + held - 1.  score='sigmoid' "
+              "(default): sigmoid scores, the top_k largest, weights s_e / "
+              "sum_top s; score='softmax_topk': the top_k largest logits, "
+              "weights their softmax (float32 either way); "
               "output = sum over a token's chosen experts that are held "
               "here of w_e E_e(x), E_e = W_down (SiLU(W_gate x) * W_up "
               "x).  What the other experts would add belongs to other "
@@ -483,7 +707,7 @@ def _moe_infer(attrs, in_shapes):
               "positions >= lengths).  counters (4,) int32 — pairs "
               "computed here, pairs left elsewhere, held experts hit, "
               "the largest expert's load — is added to where count=1.  "
-              "attrs: top_k, first_expert, step, count")
+              "attrs: top_k, first_expert, step, count, score")
 def _moe_ffn(op_ctx, attrs, inputs, aux):
     x, router_w, w_gate, w_up, w_down, lengths, counters = inputs
     top_k = attr_int(attrs.get("top_k", 1), 1)
@@ -500,7 +724,8 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
     valid = (jnp.broadcast_to(n[:, None] > 0, (B, S)) if step
              else jnp.arange(S)[None, :] < n[:, None]).reshape(-1)
     x2 = x.reshape(B * S, d)
-    topi, wts = moe_route(x2, router_w, top_k)
+    topi, wts = moe_route(x2, router_w, top_k,
+                          str(attrs.get("score", "sigmoid")))
     tm = _tile_rows(B * S * min(top_k, held))
     here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
         topi, valid, first, held, tm)

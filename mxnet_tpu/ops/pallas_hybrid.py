@@ -1,8 +1,10 @@
 """Pallas TPU kernels of the hybrid language-model family
 (``models/hybrid_lm.py``): the KDA recurrence, one token against a
 stream's state (``kda_step``) and a whole prompt in the chunk (WY) form
-(``kda_chunk``), and the grouped matmuls of the routed experts
-(``moe_gmm_gate_up``, ``moe_gmm_down``).  Helpers and conventions are
+(``kda_chunk``); the Mamba-2 recurrence the same two ways
+(``mamba2_step``, ``mamba2_chunk``: their own section below); and the
+grouped matmuls of the routed experts (``moe_gmm_gate_up``,
+``moe_gmm_down``).  Helpers and conventions are
 ``pallas_kernels``'s: every ``pallas_call`` carries a ``name=``, which is
 what a device trace shows.
 
@@ -509,6 +511,173 @@ def kda_chunk(c, g, beta):
         name="kda_chunk_state",
     )(u, w, qg, kd, ou, bst, eg)
     return o[:, :live], last
+
+
+# ---------------------------------------------------------------------------
+# mamba2: S_t = a_t S_{t-1} + dx_t B_t^T, y_t = S_t C_t — a head's state
+# (P, N) float32 with the state dimension N on the lanes, a_t a number a
+# head, B_t and C_t rows of N shared by every head (one group).
+#
+# ``mamba2_step``: one token a stream, on the VPU in float32.  dx comes
+# as a row and scales the state's ROWS, y leaves as a row and is a sum
+# over the state's LANES: both turns go through the identity mask and a
+# reduction, as in ``kda_step``.
+#
+# ``mamba2_chunk_scan``: a prompt in chunks of Q tokens (the SSD form,
+# ``ops/hybrid.py mamba2_chunked``): per chunk ONE product C B^T for all
+# heads, per head the decay mask exp(l_i - l_j)[i >= j] from the running
+# sum l of log a inside the chunk (given as rows; the column by the
+# identity mask), then three products — (C B^T . mask) dX, C S_prev^T,
+# dX^T (exp(l_Q - l) . B) — in the model's type with float32 sums; the
+# (P, N) float32 state is carried chunk to chunk in VMEM.  Every
+# exponent is a sum of log-decays over a span of tokens, <= 0.
+# ---------------------------------------------------------------------------
+
+def _mamba2_step_kernel(slots_ref, a_ref, dx_ref, b_ref, c_ref, s_ref,
+                        y_ref, so_ref, *, hb):
+    del slots_ref  # used by the index maps
+    b, h = pl.program_id(0), pl.program_id(1)
+    eye = _eye(s_ref.shape[-2])
+    b_row, c_row = b_ref[0], c_ref[0]                  # (1, N)
+    for i in range(hb):
+        dx_col = jnp.sum(eye * dx_ref[0, i:i + 1, :], axis=1, keepdims=True)
+        # the decay is a NUMBER a head: a scalar, from SMEM
+        st = s_ref[0, i] * a_ref[b, h * hb + i] + dx_col * b_row
+        so_ref[0, i] = st
+        y_col = jnp.sum(st * c_row, axis=1, keepdims=True)
+        y_ref[0, i:i + 1, :] = jnp.sum(eye * y_col, axis=0, keepdims=True)
+
+
+def mamba2_step(dx, a, bm, cm, state, slots):
+    """dx (B, H, P) = Delta . x, a (B, H) the decay, bm, cm (B, N), all
+    float32; state (S, H, P, N) float32; slots (B,) int32 -> (y (B, H,
+    P) float32 = S_new C, the state with the B slots advanced).  The
+    state operand is aliased to its output: donated under jit, the
+    update is in place."""
+    B, H, P = dx.shape
+    N = state.shape[-1]
+    hb = _divisor_at_most(H, 16)
+    if hb % 8 and hb != H:
+        hb = H
+    row = _vmem_spec((1, hb, P), lambda b, h, sl, a: (b, h, 0))
+    vec = _vmem_spec((1, 1, N), lambda b, h, sl, a: (b, 0, 0))
+    st = _vmem_spec((1, hb, P, N), lambda b, h, sl, a: (sl[b], h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, H // hb),
+        in_specs=[row, vec, vec, st], out_specs=[row, st])
+    y, new_state = pl.pallas_call(
+        functools.partial(_mamba2_step_kernel, hb=hb),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, H, P), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype)],
+        input_output_aliases={5: 1},
+        compiler_params=_compiler_params("arbitrary", "arbitrary"),
+        interpret=_interpret(),
+        name="mamba2_step",
+    )(slots.astype(jnp.int32), a, dx, bm[:, None], cm[:, None], state)
+    return y, new_state
+
+
+MAMBA2_CHUNK = 128    # tokens of a chunk: a row of l fills the lanes
+
+
+def _mamba2_chunk_kernel(dx_ref, b_ref, c_ref, l_ref, y_ref, s_ref, s_scr,
+                         *, hb, nt, P):
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    f32 = jnp.float32
+    Q = dx_ref.shape[1]
+    mm = dx_ref.dtype
+    prec = jax.lax.Precision.HIGHEST if mm == f32 else None
+
+    def dot(a, b, dims):
+        return jax.lax.dot_general(a, b, (dims, ((), ())), precision=prec,
+                                   preferred_element_type=f32)
+
+    bq, cq = b_ref[0], c_ref[0]                        # (Q, N)
+    cb = dot(cq, bq, ((1,), (1,)))                     # (Q, Q), all heads'
+    r = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+    eye = (r == c).astype(f32)
+    last = c == Q - 1
+    last_p = jax.lax.broadcasted_iota(jnp.int32, (P, Q), 1) == Q - 1
+    bf = bq.astype(f32)
+    for i in range(hb):
+        at = slice(i * P, (i + 1) * P)
+        l_row = l_ref[0, i:i + 1, :]                   # (1, Q): l_j
+        l_col = jnp.sum(eye * l_row, axis=1, keepdims=True)      # l_i
+        decay = jnp.where(r >= c,
+                          jnp.exp(jnp.minimum(l_col - l_row, 0.0)), 0.0)
+        xh = dx_ref[0, :, at]                          # (Q, P)
+        st = s_scr[i]                                  # (P, N)
+        y_ref[0, :, at] = dot((cb * decay).astype(mm), xh, ((1,), (0,))) \
+            + jnp.exp(l_col) * dot(cq, st.astype(mm), ((1,), (1,)))
+        # l_Q, the chunk's whole sum, on every row of a column (Q rows
+        # for the keys, P for the state)
+        tot = jnp.sum(jnp.where(last, l_row, 0.0), axis=1, keepdims=True)
+        tot_p = jnp.sum(jnp.where(last_p, l_row, 0.0), axis=1,
+                        keepdims=True)
+        wb = (jnp.exp(tot - l_col) * bf).astype(mm)    # (Q, N)
+        s_scr[i] = jnp.exp(tot_p) * st + dot(xh, wb, ((0,), (0,)))
+
+    @pl.when(j == nt - 1)
+    def _last():
+        s_ref[0] = s_scr[...]
+
+
+def mamba2_chunk(dx, xbc, la):
+    """A whole prompt from the zero state in the chunk (SSD) form.
+
+    dx (B, T, H*P) = Delta . x in the model's type; xbc (B, T, H*P +
+    2*N): the short convolution's output, whose last 2 N columns are B
+    and C (one group; read in place, a block of N columns each); la
+    (B, T, H) float32: log a <= 0.  A padded position carries la 0 and
+    dx 0, which leaves the state as it is; T is padded in here with
+    more such positions up to whole chunks -> (y (B, T, H*P) float32,
+    the last state (B, H, P, N) float32)."""
+    B, live, H = la.shape
+    di = dx.shape[-1]
+    P, N = di // H, (xbc.shape[-1] - di) // 2
+    Q = MAMBA2_CHUNK
+    pad = ((0, 0), (0, -live % Q), (0, 0))
+    dx, xbc, la = jnp.pad(dx, pad), jnp.pad(xbc, pad), jnp.pad(la, pad)
+    T = la.shape[1]
+    nt = T // Q
+    # the running sum of log a inside each chunk, a head's on a row
+    cum = jnp.cumsum(la.reshape(B, nt, Q, H), axis=2)
+    cum = jnp.transpose(cum.reshape(B, T, H), (0, 2, 1))
+    hb = _divisor_at_most(H, 8)
+    if (hb % 8 or (hb * P) % 128) and hb != H:
+        hb = H
+    if di % N == 0:
+        bm = cm = xbc
+        b_at, c_at = di // N, di // N + 1
+    else:   # B and C do not start on a block of N columns: cut them out
+        bm, cm = xbc[..., di:di + N], xbc[..., di + N:]
+        b_at = c_at = 0
+    rows = _vmem_spec((1, Q, hb * P), lambda b, h, j: (b, j, h))
+    y, last = pl.pallas_call(
+        functools.partial(_mamba2_chunk_kernel, hb=hb, nt=nt, P=P),
+        grid=(B, H // hb, nt),
+        in_specs=[rows,
+                  _vmem_spec((1, Q, N), lambda b, h, j: (b, j, b_at)),
+                  _vmem_spec((1, Q, N), lambda b, h, j: (b, j, c_at)),
+                  _vmem_spec((1, hb, Q), lambda b, h, j: (b, h, j))],
+        out_specs=[rows, _vmem_spec((1, hb, P, N),
+                                    lambda b, h, j: (b, h, 0, 0))],
+        out_shape=[jax.ShapeDtypeStruct((B, T, di), jnp.float32),
+                   jax.ShapeDtypeStruct((B, H, P, N), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((hb, P, N), jnp.float32)],
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary"),
+        interpret=_interpret(),
+        name="mamba2_chunk_scan",
+    )(dx, bm, cm, cum)
+    return y[:, :live], last
 
 
 # ---------------------------------------------------------------------------

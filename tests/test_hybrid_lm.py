@@ -265,14 +265,27 @@ class StubSpec:
         return self._dense.symbol(which, **kw)
 
 
+def granite_family():
+    """The second spec of ``HybridSpec``'s layer list (mamba2 mixers,
+    multipliers, a tied head): its reference and tiny configuration."""
+    from benchmark.reference import granitemoehybrid
+    from test_mamba2 import CFG as GRANITE
+
+    return granitemoehybrid, GRANITE
+
+
+FAMILIES = ["dense", "hybrid", "granite", "stub"]
+
+
 def seam_family(family):
     """(spec, params, engine keywords) of one family, tiny."""
     from benchmark.reference import gpt2
     from mxnet_tpu.models.transformer import DenseSpec
 
-    if family == "hybrid":
-        drawn = ref.draw(CFG, 7, embed_dtype="float32", dtype="float32")
-        return ref.spec(CFG), ref.program_names(drawn), dict(
+    if family in ("hybrid", "granite"):
+        r, cfg = (ref, CFG) if family == "hybrid" else granite_family()
+        drawn = r.draw(cfg, 7, embed_dtype="float32", dtype="float32")
+        return r.spec(cfg), r.program_names(drawn), dict(
             max_len=96, kv_block=4, max_streams=2, prefill_buckets=(16, 96))
     dense = DenseSpec(32, 1, 2, 16)
     return (dense if family == "dense" else StubSpec(dense)), \
@@ -281,7 +294,7 @@ def seam_family(family):
 
 
 @pytest.mark.parametrize("phase", PHASES)
-@pytest.mark.parametrize("family", ["dense", "hybrid", "stub"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_a_spec_builds_the_phases_it_lists_and_refuses_the_rest(family,
                                                                 phase):
     spec, _, _ = seam_family(family)
@@ -319,7 +332,7 @@ FEATURES = {
 
 
 @pytest.mark.parametrize("feature", list(FEATURES))
-@pytest.mark.parametrize("family", ["dense", "hybrid", "stub"])
+@pytest.mark.parametrize("family", FAMILIES)
 def test_a_feature_is_taken_or_refused_from_the_protocol_alone(family,
                                                                feature):
     """No engine edit for a third family: ``StubSpec`` is served, and

@@ -23,7 +23,11 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from mxnet_tpu import hlo, parallel
 from mxnet_tpu.kv_cache import value_pool_shape
 from mxnet_tpu.models import transformer
-from mxnet_tpu.ops import attention, pallas_kernels as pk
+# pallas_hybrid is imported HERE, before a fixture patches
+# ``pk._interpret``: it binds that name at import, and a first import
+# under the patch would keep the patched one for the worker's life
+# (every later interpreted hybrid kernel test on it would fail)
+from mxnet_tpu.ops import attention, pallas_hybrid, pallas_kernels as pk  # noqa: F401
 
 bf16, f32, i8, i32 = jnp.bfloat16, jnp.float32, jnp.int8, jnp.int32
 
@@ -341,7 +345,7 @@ def test_hybrid_decode_slots_update_in_place(on_chip, one_chip,
 
     _hybrid(monkeypatch)
     H, D, K, B = 64, 128, 4, 128
-    state = (state_pool_shape(129, H, D), f32)
+    state = (state_pool_shape(129, (H, D, D)), f32)
     tail = (conv_tail_shape(129, K, 3 * H * D), f32)
 
     def step(x, w, decay, beta, a_log, dt, tail_pool, state_pool, slots,
@@ -366,6 +370,26 @@ def test_hybrid_decode_slots_update_in_place(on_chip, one_chip,
         copies = re.findall(rf"= f32\[{dims}\]\S* copy\(.*", text)
         assert not copies, copies
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def test_mamba2_kernels_granite_cell(on_chip, one_chip, monkeypatch):
+    # granite-4.0-h-small: 128 heads, a state of (64, 128) a head, one
+    # group; a 64-row decode step over 65 slots and a 2048-token prompt
+    from mxnet_tpu.kv_cache import state_pool_shape
+
+    ph = _hybrid(monkeypatch)
+    H, P, N, B, T = 128, 64, 128, 64, 2048
+    step = jax.jit(ph.mamba2_step, donate_argnums=(4,)).lower(*[
+        jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+            ((B, H, P), f32), ((B, H), f32), ((B, N), f32), ((B, N), f32),
+            (state_pool_shape(B + 1, (H, P, N)), f32), ((B,), i32))]
+    ).compile()
+    text = step.as_text()
+    assert "tpu_custom_call" in text
+    dims = ",".join(str(n) for n in state_pool_shape(B + 1, (H, P, N)))
+    assert not re.findall(rf"= f32\[{dims}\]\S* copy\(.*", text)
+    _compile(ph.mamba2_chunk, one_chip, ((1, T, H * P), bf16),
+             ((1, T, H * P + 2 * N), bf16), ((1, T, H), f32))
 
 
 def test_lstm_scan_ptb(on_chip, one_chip):

@@ -14,6 +14,7 @@ after ANY kernel change:
     python tools/verify_kernels.py          # full matrix (~5 min)
     python tools/verify_kernels.py --quick  # smoke subset
     python tools/verify_kernels.py --paged  # the paged kernel alone
+    python tools/verify_kernels.py --mamba2 # the Mamba-2 kernels alone
 """
 
 import os
@@ -194,9 +195,81 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     return ok
 
 
+def _time_ms(fn, *args, n=5):
+    import time
+
+    jax.block_until_ready(fn(*args))
+    t = time.perf_counter()
+    for _ in range(n):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return 1e3 * (time.perf_counter() - t) / n
+
+
+def check_mamba2(T, n, B=1, H=128, P=64, N=128):
+    """``Mamba2Step`` (T = 1: B rows against their slots) or
+    ``Mamba2Chunk`` (a prompt of n live tokens padded to T) through the
+    registered op, the Mosaic kernel against the op's lax body on the
+    same bfloat16 inputs: the outputs' and the slots' largest gaps over
+    the lax body's largest value, and both bodies' time."""
+    from mxnet_tpu.kv_cache import state_pool_shape
+    from mxnet_tpu.ops import hybrid  # noqa: F401 — registers the ops
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    rng = np.random.RandomState(T + n)
+    bf = lambda *s: jnp.asarray(rng.randn(*s).astype(np.float32)) \
+        .astype(jnp.bfloat16)
+    f32 = lambda x: jnp.asarray(np.asarray(x, np.float32))
+    step = T == 1
+    xbc, dt = bf(B, T, H * P + 2 * N), bf(B, T, H)
+    a_log = f32(np.log(rng.uniform(1, 16, H)))
+    dt_bias = f32(np.log(np.expm1(np.exp(rng.uniform(
+        np.log(1e-3), np.log(1e-1), H)))))
+    pool = f32(rng.randn(*state_pool_shape(B + 1, (H, P, N))) * 0.1)
+    slots = jnp.asarray(1 + rng.permutation(B).astype(np.int32))
+    lengths = jnp.full((B,), n, jnp.int32)
+    op = get_op("Mamba2Step" if step else "Mamba2Chunk")
+    attrs = {"num_heads": str(H), "d_state": str(N)}
+
+    def run(flag):
+        os.environ["MXNET_PALLAS"] = flag
+        fn = jax.jit(lambda *a: op.compute(
+            OpContext(is_train=False, rng=None), attrs, list(a), []))
+        args = (xbc, dt, a_log, dt_bias, jnp.ones((H,), jnp.float32), pool,
+                slots, lengths)
+        out = [np.asarray(x.astype(jnp.float32)) for x in fn(*args)]
+        return out, _time_ms(fn, *args)
+
+    try:
+        (y_k, s_k), ms_k = run("1")
+        (y_l, s_l), ms_l = run("0")
+    finally:
+        os.environ.pop("MXNET_PALLAS", None)
+    live = slice(0, n)
+    e_y = float(np.abs(y_k[:, live] - y_l[:, live]).max()
+                / max(np.abs(y_l[:, live]).max(), 1e-9))
+    rows = np.asarray(slots)
+    e_s = float(np.abs(s_k[rows] - s_l[rows]).max()
+                / max(np.abs(s_l[rows]).max(), 1e-9))
+    ok = e_y < TOL and e_s < TOL and bool(np.isfinite(y_k).all()) \
+        and np.array_equal(s_k[0], s_l[0])
+    print(f"{'OK ' if ok else 'FAIL'} mamba2 "
+          f"{'step' if step else 'chunk'} B={B} T={T} n={n} "
+          f"state=({H},{P},{N}): y={e_y:.4f} state={e_s:.4f} "
+          f"kernel={ms_k:.3f}ms lax={ms_l:.3f}ms", flush=True)
+    return ok
+
+
 def main():
     quick = "--quick" in sys.argv
     results = []
+    if "--mamba2" in sys.argv:
+        # the granite cell's own shapes: a 64-row decode step, prompts
+        # in the 1024 and 2048 buckets (whole and ending inside a chunk)
+        results.append(check_mamba2(1, 1, B=64))
+        for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
+            results.append(check_mamba2(T, n))
+        return _report(results)
     # the cells' widths (20 and 16 heads of 64), a head a quarter of a
     # lane tile, and H·D not a multiple of 128
     for H, D in ([(20, 64)] if quick else
