@@ -267,7 +267,7 @@ def _check_qkv_packing(last_dim, num_heads, shape):
             f"heads' d_head lanes (got shape {tuple(shape)})")
 
 
-def _flash_mha_packed_on_plan(qkv, H, causal, block):
+def _flash_mha_packed_on_plan(qkv, H, causal):
     """The packed-heads kernel, on one device or across the mesh the
     graph is traced for.
 
@@ -289,7 +289,7 @@ def _flash_mha_packed_on_plan(qkv, H, causal, block):
 
     plan = traced_plan()
     if plan is None or plan.num_devices == 1:
-        return pk.flash_mha_packed(qkv, H, causal=causal, block_size=block)
+        return pk.flash_mha_packed(qkv, H, causal=causal)
     sizes = dict(plan.mesh.shape)
     B, _T, HD3 = qkv.shape
     HD = HD3 // 3
@@ -309,8 +309,7 @@ def _flash_mha_packed_on_plan(qkv, H, causal, block):
                 [jax.lax.dynamic_slice_in_dim(x, part * HD + lo, span,
                                               axis=2)
                  for part in range(3)], axis=2)
-        return pk.flash_mha_packed(x, H // n_h, causal=causal,
-                                   block_size=block)
+        return pk.flash_mha_packed(x, H // n_h, causal=causal)
 
     return jax.shard_map(local, mesh=plan.mesh,
                          in_specs=P(b_ax, None, None),
@@ -335,7 +334,8 @@ def _qkv_infer(attrs, in_shapes):
           doc="Self-attention straight off the fused QKV projection: "
               "qkv (B, T, 3*H*D) packed [q|k|v] per head -> (B, T, H*D)."
               " On TPU this is the packed-heads Pallas kernel with zero "
-              "layout changes anywhere (PERF.md); attrs: num_heads, "
+              "layout changes anywhere (PERF.md), on tiles of its own "
+              "choice; block_size is the lax body's; attrs: num_heads, "
               "causal, block_size")
 def _qkv_attention(op_ctx, attrs, inputs, aux):
     (qkv,) = inputs
@@ -350,7 +350,7 @@ def _qkv_attention(op_ctx, attrs, inputs, aux):
     _check_qkv_packing(HD3, H, qkv.shape)
     D = HD3 // (3 * H)
     if pk.enabled():
-        return [_flash_mha_packed_on_plan(qkv, H, causal, block)]
+        return [_flash_mha_packed_on_plan(qkv, H, causal)]
     # lax fallback: unpack → blockwise attention → repack
     q, k, v = (jnp.reshape(x, (B, T, H, D))
                for x in jnp.split(qkv, 3, axis=-1))
@@ -645,7 +645,11 @@ def _qkv_prefill_infer(attrs, in_shapes):
               "ALSO returns the (B, T, H, D) key/value state for a KV "
               "cache — the prefill half of incremental decode.  Output "
               "is bit-identical to QKVSelfAttention at the same "
-              "block_size; attrs: num_heads, block_size")
+              "block_size.  block_size governs the lax body only (the "
+              "CPU contract: prefill + decode equal the full forward at "
+              "one block size); the Mosaic kernel chooses its tiles "
+              "from the shape (pallas_kernels._mhap_tiles), so a page "
+              "size never sets them; attrs: num_heads, block_size")
 def _qkv_attention_prefill(op_ctx, attrs, inputs, aux):
     (qkv,) = inputs
     if qkv.ndim != 3:
@@ -657,7 +661,7 @@ def _qkv_attention_prefill(op_ctx, attrs, inputs, aux):
     from . import pallas_kernels as pk
 
     if pk.enabled():
-        out = pk.flash_mha_packed(qkv, H, causal=True, block_size=block)
+        out = pk.flash_mha_packed(qkv, H, causal=True)
         return [out, k, v]
     o, m, l = _blockwise_attention_partial_lax(q, k, v, True, block or 512,
                                                0)
@@ -686,8 +690,10 @@ def _qkv_decode_infer(attrs, in_shapes):
               "counting the current token -> output (B, 1, H*D) plus "
               "the in-place-updated caches (donate them under jit).  "
               "block_size must equal the prefill/full-forward block "
-              "size for bit-identical decode; attrs: num_heads, "
-              "block_size")
+              "size for bit-identical decode — of the lax bodies, which "
+              "alone read it (this op has no kernel; the prefill's and "
+              "the full forward's Mosaic kernels choose their own "
+              "tiles); attrs: num_heads, block_size")
 def _qkv_attention_decode(op_ctx, attrs, inputs, aux):
     qkv, cache_k, cache_v, lengths = inputs
     H = attr_int(attrs.get("num_heads", 1), 1)

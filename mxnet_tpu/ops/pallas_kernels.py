@@ -34,6 +34,7 @@ may change that reader.
 from __future__ import annotations
 
 import functools
+import math
 import os
 
 import jax
@@ -774,19 +775,21 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _mha_block(block_size, t):
     if int(block_size) <= 0:  # auto: larger tiles amortize the online-
-        # softmax state updates; 1024 measured fastest at T>=2048
-        # (block sweep in PERF.md), 512 below
+        # softmax state updates — 1024 from T = 2048 on, 512 below.  This
+        # family has no sweep of its own on record; the packed family's
+        # (PERF.md §6, PR 32, `tools/verify_kernels.py --tiles`) reads
+        # the same way: the wider tile wins wherever it fits
         block_size = 1024 if t >= 2048 else 512
     b = max(128, min(2048, (int(block_size) // 128) * 128 or 128))
     return min(b, max(128, ((t + 127) // 128) * 128))
 
 
 def _mha_blocks(block_size, tq, tk):
-    """(block_q, block_k) for the normalized flash_mha kernels.
-    Symmetric; auto picks 1024 at T>=2048 (the r5 sweep, PERF.md).
-    An asymmetric bq=2048/bk=1024 probe once measured 1.96 ms fwd at
-    T=4096 but was 3.37 ms when reproduced through this API in A/B
-    runs — unreproducible wins don't ship."""
+    """(block_q, block_k) for the normalized flash_mha kernels:
+    symmetric.  What block_q != block_k buys was measured on the packed
+    family (PERF.md §6, PR 32: at T = 4096 a (512, 2048) tile runs the
+    forward in 2.27 ms beside the square 1024's 2.95); nothing of the
+    kind has been reproduced through this API."""
     return (_mha_block(block_size, tq), _mha_block(block_size, tk))
 
 
@@ -953,166 +956,322 @@ def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
 # relayout entirely: q, k, v are LANE-BLOCK VIEWS of the fused QKV
 # projection output (B, T, 3·H·D) — the same array is passed three
 # times with different lane-block index maps — and every head occupies
-# its own 64/128-lane span inside the block.  The kernel loops over
-# heads per (q-block, k-block) tile, keeping each head's online-softmax
-# state broadcast over that head's lane span in VMEM scratch.  The
-# output is written directly in (B, T, H·D) — the layout the following
-# projection matmul wants.  Zero transposes in forward or backward.
+# its own 64/128-lane span inside the block.  A grid step holds one
+# HEAD GROUP — the heads of whole lane tiles, a pair at D = 64 — and
+# loops over its heads per (q-block, k-block) tile, keeping each head's
+# online-softmax state broadcast over that head's lane span in VMEM
+# scratch.  The output is written directly in (B, T, H·D) — the layout
+# the following projection matmul wants.  Zero transposes in forward
+# or backward.
+#
+# The schedule is the kernels' own choice (`_mhap_tiles`, from (T, H·D,
+# D) alone; the kernel-alone table that decided it is in PERF.md §6,
+# PR 32, and `tools/verify_kernels.py --tiles` prints it again).  The
+# grid is (row, head group, q tile, k tile) — (…, k tile, q tile) in
+# dkv — and a grid step is a (block_q, block_k) tile of a head group's
+# scores.  Under the causal mask a tile wholly above the diagonal is
+# skipped, a tile wholly under it runs the unmasked body, and a tile
+# the diagonal crosses is WALKED in sub-blocks of `sub` rows (columns,
+# in dkv): a sub-block multiplies only the columns it can see — the
+# span under the diagonal unmasked, the one `sub`-wide span on it under
+# a triangle — in ONE update of its rows' state, so a sub-tile wholly
+# above the diagonal is never computed.  All slices are static: the
+# walk is unrolled once for every offset a crossing tile of the grid
+# can have (one, 0, when block_q == block_k).
+#
+# Padding rows need no mask under the causal mask: q, do, o and lse are
+# padded with zeros, a real row sees no padded column, and a padded row
+# adds p·(0 − 0) to dk and pᵀ·0 to dv with p = exp2(0 − 0) finite.
 # ---------------------------------------------------------------------------
+
+_LOG2E = 1.4426950408889634
+
+
+def _crossing_offsets(block_q, block_k):
+    """The offsets (tile's first column − tile's first row) at which the
+    diagonal crosses a (block_q, block_k) tile of the grid.  Further
+    left (≤ −block_k) the tile is wholly visible, further right
+    (≥ block_q) wholly masked."""
+    g = math.gcd(block_q, block_k)
+    return range(-block_k + g, block_q, g)
+
+
+def _walk(off, n_sub, n_full, sub, transposed=False):
+    """The sub-blocks of a crossing tile: ``[(sub-block, whole span or
+    None, diagonal span or None)]`` as slices.  A sub-block is ``sub``
+    of the tile's ``n_sub`` rows and the spans are of its ``n_full``
+    columns; ``transposed`` (dkv) walks columns against rows, where a
+    column sees the rows FROM its own to the tile's last.  ``off`` is
+    the tile's first column less its first row."""
+    out = []
+    for b0 in range(0, n_sub, sub):
+        # where the diagonal enters this sub-block, on the other axis
+        lo = b0 + off if transposed else b0 - off
+        blk = slice(b0, b0 + sub)
+        if transposed:
+            if lo >= n_full:
+                continue  # no row of the tile sees these columns
+            if lo + sub <= 0:
+                out.append((blk, slice(0, n_full), None))
+                continue
+            rest = slice(lo + sub, n_full) if lo + sub < n_full else None
+            out.append((blk, rest, slice(lo, lo + sub)))
+        else:
+            if lo + sub <= 0:
+                continue  # wholly above the diagonal: never computed
+            if lo >= n_full:
+                out.append((blk, slice(0, n_full), None))
+                continue
+            out.append((blk, slice(0, lo) if lo else None,
+                        slice(lo, lo + sub)))
+    return out
+
+
+def _mhap_scores(t, block_q, block_k, sub, causal):
+    """Scores a head-row computes under the schedule, and the scores it
+    needs (on or under the diagonal) — what the counter
+    ``flash.scores_computed_over_needed`` divides."""
+    tp = t + (-t) % max(block_q, block_k)
+    if not causal:
+        return tp * tp, t * t
+    crossing = _crossing_offsets(block_q, block_k)
+    done = 0
+    for r0 in range(0, tp, block_q):
+        for c0 in range(0, tp, block_k):
+            off = c0 - r0
+            if off <= -block_k:
+                done += block_q * block_k
+            elif off in crossing:
+                done += sum(
+                    sub * ((w.stop - w.start if w else 0)
+                           + (d.stop - d.start if d else 0))
+                    for _, w, d in _walk(off, block_q, block_k, sub))
+    return done, t * (t + 1) // 2
+
+
+def _mhap_tiles(t, hd, d):
+    """(block_q, block_k, sub, lanes) of the packed kernels for T
+    positions of H·D lanes in heads of D: the shape decides, nothing
+    else (a page size, an option and a model's name never reach here).
+    Each choice is a row of the kernel-alone table in PERF.md §6, PR 32.
+
+    ``lanes``: the heads a grid step holds, as lanes of q (of k, of v):
+    whole lane tiles of whole heads — a pair at D = 64 — so a step's
+    body is unrolled over two heads, not twenty (Mosaic compiles it in
+    seconds, not minutes), and the next group's q, k and v arrive
+    while this one is multiplied; H·D itself where the heads do not
+    fill lane tiles.  The tile: the widest that fits — a row's m / l /
+    acc update costs as much as a good part of a tile's scores, so
+    fewer, wider updates win — and T ≤ 1024 is ONE tile: a row meets
+    all its keys in one update and carries no state at all; 512 where
+    that pads a longer T less; narrower where the operands of the
+    widest kernel (dkv: five bf16 and two outputs double-buffered,
+    lse, three float32 scratches — 48 bytes a row and lane) would not
+    fit VMEM.  ``sub``: 256."""
+    g = math.lcm(d, 128)
+    lanes = g if hd % g == 0 else hd
+    fits = [b for b in (1024, 512, 256, 128)
+            if b * lanes * 48 <= (_VMEM_LIMIT * 3) // 4] or [128]
+    t128 = t + (-t) % 128
+    if t128 <= fits[0]:
+        tile = t128
+    else:
+        tile = min(fits[:2], key=lambda b: (t + (-t) % b, -b))
+    sub = 256 if tile % 256 == 0 else 128
+    return tile, tile, sub, lanes
+
+
+def _tri(sub):
+    """The diagonal span's mask: column ≤ row, (sub, sub)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+            <= jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0))
+
+
+def _tile_schedule(qi, kj, H, *, causal, block_q, block_k, sub, nq, nk,
+                   t_valid, update, mask_rows=True, transposed=False):
+    """Run ``update(h, block, pieces)`` for every head over the tile
+    (qi, kj) as the schedule above says: ``block`` is the slice of q
+    rows (of k columns, ``transposed``) a head's state is updated for,
+    ``pieces`` the ``[(slice of the other axis, mask or None)]`` it
+    multiplies.  Without the causal mask, where the ``t_valid``
+    positions do not fill their tiles, the edge tiles run under a mask
+    of the padded columns — and rows, unless the kernel's padded rows
+    are cut off anyway (``mask_rows`` False: the forward)."""
+    n_blk, n_other = ((block_k, block_q) if transposed
+                      else (block_q, block_k))
+    blk, span = slice(0, n_blk), slice(0, n_other)
+
+    def run(walk, mask=None):
+        m = mask() if mask is not None else None
+        for h in range(H):
+            for b, whole, diag in walk:
+                update(h, b, [(sp, mk) for sp, mk in
+                              ((whole, None), (diag, m)) if sp is not None])
+
+    if causal:
+        off = kj * block_k - qi * block_q
+        # only what this grid can reach is built: at T = one tile there
+        # is no tile under the diagonal, and its body is not compiled
+        reach = {c * block_k - r * block_q
+                 for r in range(nq) for c in range(nk)}
+        if min(reach) <= -block_k:
+            pl.when(off <= -block_k)(
+                functools.partial(run, [(blk, span, None)]))
+        for o in _crossing_offsets(block_q, block_k):
+            if o in reach:
+                pl.when(off == o)(functools.partial(
+                    run, _walk(o, n_blk, n_other, sub, transposed),
+                    functools.partial(_tri, sub)))
+    elif not (t_valid % block_k or (mask_rows and t_valid % block_q)):
+        run([(blk, span, None)])
+    else:
+        edge = (kj == nk - 1) | (qi == nq - 1)
+        pl.when(edge)(functools.partial(
+            run, [(blk, None, span)], functools.partial(
+                _pad_valid, qi, kj, block_q, block_k, t_valid, mask_rows)))
+        pl.when(jnp.logical_not(edge))(
+            functools.partial(run, [(blk, span, None)]))
+
+
+def _dot(a, b, ca, cb):
+    return jax.lax.dot_general(a, b, (((ca,), (cb,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _pad_valid(qi, kj, block_q, block_k, t_valid, mask_rows):
+    k_pos = kj * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, (block_q, block_k), 1)
+    valid = k_pos < t_valid
+    if mask_rows:
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        valid = valid & (q_pos < t_valid)
+    return valid
 
 
 def _mhap_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref,
-                     l_ref, *, H, D, causal, block_q, block_k, tq_valid,
-                     tk_valid, scale, nk):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+                     l_ref, *, H, D, causal, block_q, block_k, sub,
+                     t_valid, scale, nq, nk):
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    # one k tile: a row meets all its keys in ONE update, so there is no
+    # state to start, rescale or finish — the scratches go unused
+    carried = nk > 1
 
-    @pl.when(kj == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    if carried:
+        @pl.when(kj == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros_like(acc_ref)
+            m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+            l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal:
-        run = (kj * block_k) <= (qi * block_q + block_q - 1)
-        last_kj = jnp.minimum(nk - 1, (qi * block_q + block_q - 1)
-                              // block_k)
-    else:
-        run = kj >= 0
-        last_kj = nk - 1
-
-    # Static tile specialization: interior tiles need NO masking at all
-    # (the dominant VPU cost after exp), only diagonal tiles (causal)
-    # and edge tiles (T-padding) take the masked path.
-    need_pad = (tk_valid % block_k) != 0
-    mask_cond = jnp.bool_(False)
-    if causal:
-        mask_cond |= (kj == qi) if block_q == block_k else run
-    if need_pad:
-        mask_cond |= (kj == nk - 1)
-
-    def _body(masked):
-        valid = None
-        if masked:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            valid = k_pos < tk_valid
-            if causal:
-                q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                    jnp.int32, (block_q, block_k), 0)
-                valid = valid & (k_pos <= q_pos)
-        for h in range(H):
-            sl = slice(h * D, (h + 1) * D)
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-                * (scale * 1.4426950408889634)  # exp2 domain
-            if masked:
-                s = jnp.where(valid, s, -jnp.inf)
-            m_prev = m_ref[:, h * D]
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-            m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+    def update(h, rows, pieces):
+        sl = slice(h * D, (h + 1) * D)
+        n = rows.stop - rows.start
+        q = q_ref[0, rows, sl]
+        ss = []
+        for cols, mask in pieces:
+            s = _dot(q, k_ref[0, cols, sl], 1, 1) \
+                * (scale * _LOG2E)  # exp2 domain
+            ss.append(s if mask is None else jnp.where(mask, s, -jnp.inf))
+        m_new = m_prev = m_ref[rows, h * D] if carried else None
+        for s in ss:
+            m = jnp.max(s, axis=1)
+            m_new = m if m_new is None else jnp.maximum(m_new, m)
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        l_new = pv = None
+        for s, (cols, _) in zip(ss, pieces):
             # masked entries hold -inf, so exp2 gives exactly 0 — no
             # second where needed.  (bf16 exp was tried and measured
             # slower: Mosaic upcasts transcendentals, so the converts
             # were pure overhead.)
             p = jnp.exp2(s - m_safe[:, None])
-            alpha = jnp.where(m_prev == -jnp.inf, 0.0,
-                              jnp.exp2(m_prev - m_safe))
-            l_new = l_ref[:, h * D] * alpha + jnp.sum(p, axis=1)
-            l_ref[:, sl] = jnp.broadcast_to(l_new[:, None], (block_q, D))
-            pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[:, sl] = acc_ref[:, sl] * alpha[:, None] + pv
-            m_ref[:, sl] = jnp.broadcast_to(m_new[:, None], (block_q, D))
+            l = jnp.sum(p, axis=1)
+            l_new = l if l_new is None else l_new + l
+            v = v_ref[0, cols, sl]
+            d = _dot(p.astype(v.dtype), v, 1, 0)
+            pv = d if pv is None else pv + d
+        if not carried:
+            l = jnp.maximum(l_new, 1e-30)
+            o_ref[0, rows, sl] = (pv / l[:, None]).astype(o_ref.dtype)
+            lse_ref[0, rows, sl] = jnp.broadcast_to(
+                (m_new + jnp.log2(l))[:, None], (n, D))
+            return
+        alpha = jnp.where(m_prev == -jnp.inf, 0.0,
+                          jnp.exp2(m_prev - m_safe))
+        l_new = l_ref[rows, h * D] * alpha + l_new
+        l_ref[rows, sl] = jnp.broadcast_to(l_new[:, None], (n, D))
+        acc_ref[rows, sl] = acc_ref[rows, sl] * alpha[:, None] + pv
+        m_ref[rows, sl] = jnp.broadcast_to(m_new[:, None], (n, D))
 
-    @pl.when(run & mask_cond)
-    def _compute_masked():
-        _body(True)
+    _tile_schedule(qi, kj, H, causal=causal, block_q=block_q,
+                   block_k=block_k, sub=sub, nq=nq, nk=nk,
+                   t_valid=t_valid, update=update, mask_rows=False)
 
-    @pl.when(run & jnp.logical_not(mask_cond))
-    def _compute_full():
-        _body(False)
+    if carried:
+        if causal:
+            last_kj = jnp.minimum(nk - 1, (qi * block_q + block_q - 1)
+                                  // block_k)
+        else:
+            last_kj = nk - 1
 
-    @pl.when(kj == last_kj)
-    def _finalize():
-        l = l_ref[...]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-        lse_ref[0] = m_ref[...] + jnp.log2(jnp.maximum(l, 1e-30))
+        @pl.when(kj == last_kj)
+        def _finalize():
+            l = jnp.maximum(l_ref[...], 1e-30)
+            o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+            lse_ref[0] = m_ref[...] + jnp.log2(l)
+
+
+def _mhap_delta(delta_ref, do_ref, o_ref, H, D):
+    """Δ = per-(row, head) rowsum(do ∘ o) of the q tile into scratch,
+    instead of materializing a (B, T, H·D) f32 broadcast tensor in
+    HBM."""
+    prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+    for h in range(H):
+        sl = slice(h * D, (h + 1) * D)
+        dh = jnp.sum(prod[:, sl], axis=1)
+        delta_ref[:, sl] = jnp.broadcast_to(dh[:, None],
+                                            (prod.shape[0], D))
 
 
 def _mhap_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
                         dq_ref, acc_ref, delta_ref, *, H, D, causal,
-                        block_q, block_k, tq_valid, tk_valid, scale, nk):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
+                        block_q, block_k, sub, t_valid, scale, nq, nk):
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
 
     @pl.when(kj == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        # Δ = per-(row, head) rowsum(do ∘ o), computed once per q-block
-        # into scratch instead of materializing a (B, T, H·D) f32
-        # broadcast tensor in HBM
-        prod = do_ref[0].astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        for h in range(H):
-            sl = slice(h * D, (h + 1) * D)
-            dh = jnp.sum(prod[:, sl], axis=1)
-            delta_ref[:, sl] = jnp.broadcast_to(dh[:, None], (block_q, D))
+        _mhap_delta(delta_ref, do_ref, o_ref, H, D)  # once per q-block
 
     if causal:
-        run = (kj * block_k) <= (qi * block_q + block_q - 1)
         last_kj = jnp.minimum(nk - 1, (qi * block_q + block_q - 1)
                               // block_k)
     else:
-        run = kj >= 0
         last_kj = nk - 1
 
-    need_pad = (tk_valid % block_k) != 0 or (tq_valid % block_q) != 0
-    mask_cond = jnp.bool_(False)
-    if causal:
-        mask_cond |= (kj == qi) if block_q == block_k else run
-    if need_pad:
-        mask_cond |= (kj == nk - 1) | (qi == pl.num_programs(1) - 1)
+    def update(h, rows, pieces):
+        sl = slice(h * D, (h + 1) * D)
+        q = q_ref[0, rows, sl]
+        do = do_ref[0, rows, sl]
+        lse = lse_ref[0, rows, h * D][:, None]
+        delta = delta_ref[rows, h * D][:, None]
+        dq = None
+        for cols, mask in pieces:
+            k = k_ref[0, cols, sl]
+            s = _dot(q, k, 1, 1) * (scale * _LOG2E)  # exp2-domain lse
+            p = jnp.exp2(s - lse)
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            ds = p * (_dot(do, v_ref[0, cols, sl], 1, 1) - delta)
+            d = _dot(ds.astype(k.dtype), k, 1, 0)
+            dq = d if dq is None else dq + d
+        acc_ref[rows, sl] += dq * scale
 
-    def _body(masked):
-        valid = None
-        if masked:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = (k_pos < tk_valid) & (q_pos < tq_valid)
-            if causal:
-                valid = valid & (k_pos <= q_pos)
-        for h in range(H):
-            sl = slice(h * D, (h + 1) * D)
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            do = do_ref[0, :, sl]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-                * (scale * 1.4426950408889634)  # exp2-domain lse
-            p = jnp.exp2(s - lse_ref[0, :, h * D][:, None])
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            ds = p * (dov - delta_ref[:, h * D][:, None])
-            acc_ref[:, sl] += jax.lax.dot_general(
-                ds.astype(k.dtype), k, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-
-    @pl.when(run & mask_cond)
-    def _compute_masked():
-        _body(True)
-
-    @pl.when(run & jnp.logical_not(mask_cond))
-    def _compute_full():
-        _body(False)
+    _tile_schedule(qi, kj, H, causal=causal, block_q=block_q,
+                   block_k=block_k, sub=sub, nq=nq, nk=nk,
+                   t_valid=t_valid, update=update)
 
     @pl.when(kj == last_kj)
     def _finalize():
@@ -1120,73 +1279,49 @@ def _mhap_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
 
 
 def _mhap_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
-                         dk_ref, dv_ref, dka_ref, dva_ref, *, H, D, causal,
-                         block_q, block_k, tq_valid, tk_valid, scale, nq):
-    kj = pl.program_id(1)
-    qi = pl.program_id(2)
+                         dk_ref, dv_ref, dka_ref, dva_ref, delta_ref, *,
+                         H, D, causal, block_q, block_k, sub, t_valid,
+                         scale, nq, nk):
+    kj = pl.program_id(2)
+    qi = pl.program_id(3)
 
     @pl.when(qi == 0)
     def _init():
         dka_ref[...] = jnp.zeros_like(dka_ref)
         dva_ref[...] = jnp.zeros_like(dva_ref)
 
+    run = (kj * block_k < (qi + 1) * block_q) if causal else None
 
-    if causal:
-        run = (kj * block_k) <= (qi * block_q + block_q - 1)
+    # Δ rows of this q-block: qi is the inner axis, so they are made
+    # again for every tile that runs — once, not once a sub-block
+    if run is None:
+        _mhap_delta(delta_ref, do_ref, o_ref, H, D)
     else:
-        run = qi >= 0
+        pl.when(run)(lambda: _mhap_delta(delta_ref, do_ref, o_ref, H, D))
 
-    need_pad = (tk_valid % block_k) != 0 or (tq_valid % block_q) != 0
-    mask_cond = jnp.bool_(False)
-    if causal:
-        mask_cond |= (kj == qi) if block_q == block_k else run
-    if need_pad:
-        mask_cond |= (kj == pl.num_programs(1) - 1) | (qi == nq - 1)
+    def update(h, cols, pieces):
+        sl = slice(h * D, (h + 1) * D)
+        k = k_ref[0, cols, sl]
+        v = v_ref[0, cols, sl]
+        dv = dk = None
+        for rows, mask in pieces:
+            q = q_ref[0, rows, sl]
+            do = do_ref[0, rows, sl]
+            s = _dot(q, k, 1, 1) * (scale * _LOG2E)  # exp2-domain lse
+            p = jnp.exp2(s - lse_ref[0, rows, h * D][:, None])
+            if mask is not None:
+                p = jnp.where(mask, p, 0.0)
+            d = _dot(p.astype(do.dtype), do, 0, 0)
+            dv = d if dv is None else dv + d
+            ds = p * (_dot(do, v, 1, 1) - delta_ref[rows, h * D][:, None])
+            d = _dot(ds.astype(q.dtype), q, 0, 0)
+            dk = d if dk is None else dk + d
+        dva_ref[cols, sl] += dv
+        dka_ref[cols, sl] += dk * scale
 
-    def _body(masked):
-        valid = None
-        if masked:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = (k_pos < tk_valid) & (q_pos < tq_valid)
-            if causal:
-                valid = valid & (k_pos <= q_pos)
-        for h in range(H):
-            sl = slice(h * D, (h + 1) * D)
-            q = q_ref[0, :, sl]
-            k = k_ref[0, :, sl]
-            v = v_ref[0, :, sl]
-            do = do_ref[0, :, sl]
-            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32) \
-                * (scale * 1.4426950408889634)  # exp2-domain lse
-            p = jnp.exp2(s - lse_ref[0, :, h * D][:, None])
-            if masked:
-                p = jnp.where(valid, p, 0.0)
-            pT = p.astype(do.dtype)
-            dva_ref[:, sl] += jax.lax.dot_general(
-                pT, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            dov = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                      preferred_element_type=jnp.float32)
-            # Δ rows for this q-block: cheap in-register rowsum (qi is
-            # the inner axis, so no per-q-block scratch caching here)
-            dh = jnp.sum(do.astype(jnp.float32)
-                         * o_ref[0, :, sl].astype(jnp.float32), axis=1)
-            ds = p * (dov - dh[:, None])
-            dka_ref[:, sl] += jax.lax.dot_general(
-                ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-
-    @pl.when(run & mask_cond)
-    def _compute_masked():
-        _body(True)
-
-    @pl.when(run & jnp.logical_not(mask_cond))
-    def _compute_full():
-        _body(False)
+    _tile_schedule(qi, kj, H, causal=causal, block_q=block_q,
+                   block_k=block_k, sub=sub, nq=nq, nk=nk,
+                   t_valid=t_valid, update=update, transposed=True)
 
     @pl.when(qi == nq - 1)
     def _finalize():
@@ -1195,131 +1330,158 @@ def _mhap_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, o_ref,
 
 
 @functools.lru_cache(maxsize=None)
-def _flash_mha_packed_fn(H, D, causal, block_size):
+def _flash_mha_packed_fn(H, D, causal, tiles, interpret):
+    """custom_vjp per (H, D, causal, tiles), jitted: a model's layers
+    call it with one shape, so the kernels' bodies — unrolled over heads
+    and sub-blocks — are traced and lowered once a program, not once a
+    layer.  ``interpret`` is in the key because jit keeps the trace: one
+    made for the chip must not answer a later interpreted call."""
     @jax.custom_vjp
     def f(qkv):
-        o, _ = _mhap_fwd(qkv, H, D, causal, block_size)
+        o, _ = _mhap_fwd(qkv, H, D, causal, tiles, interpret)
         return o
 
     def fwd(qkv):
-        o, lse = _mhap_fwd(qkv, H, D, causal, block_size)
+        o, lse = _mhap_fwd(qkv, H, D, causal, tiles, interpret)
         return o, (qkv, o, lse)
 
     def bwd(res, do):
         qkv, o, lse = res
-        return (_mhap_bwd(qkv, o, lse, do, H, D, causal, block_size),)
+        return (_mhap_bwd(qkv, o, lse, do, H, D, causal, tiles, interpret),)
 
     f.defvjp(fwd, bwd)
-    return f
+    return jax.jit(f)
 
 
-def flash_mha_packed(qkv, num_heads, causal=False, block_size=512):
+def flash_mha_packed(qkv, num_heads, causal=False):
     """Fused-QKV flash attention: qkv (B, T, 3·H·D) — the raw output of
     the fused projection matmul, laid out [q | k | v] with each head on
     its own D-lane span — → (B, T, H·D).  Differentiable; the qkv
-    cotangent comes back packed the same way."""
+    cotangent comes back packed the same way.  The tiles are the
+    kernels' own choice from (T, H·D, D): ``_mhap_tiles``."""
     B, T, HD3 = qkv.shape
     if HD3 % (3 * num_heads):
         raise ValueError(f"qkv last dim {HD3} not 3*H*D for H={num_heads}")
     D = HD3 // (3 * num_heads)
+    HD = HD3 // 3
+    bq, bk, sub, lanes = tiles = tuple(
+        int(x) for x in _mhap_tiles(T, HD, D))
+    if max(bq, bk) % min(bq, bk) or math.gcd(bq, bk) % sub or sub % 8 \
+            or HD % lanes or lanes % D or (lanes % 128 and lanes != HD):
+        raise ValueError(
+            f"packed flash tiles {tiles}: one of block_q / block_k must "
+            f"divide the other, sub both, and lanes be whole heads of "
+            f"{D} in whole lane tiles of {HD}")
+    _mhap_record(T, tiles, causal)
     return _flash_mha_packed_fn(int(num_heads), int(D), bool(causal),
-                                int(block_size))(qkv)
+                                tiles, _interpret())(qkv)
 
 
-def _mhap_fwd(qkv, H, D, causal, block_size):
+def _mhap_record(T, tiles, causal):
+    """The schedule a build chose, on ``/metrics`` (every trace of a
+    program that holds the kernels passes here; the jitted bodies are
+    traced once)."""
+    from .. import profiler
+
+    bq, bk, sub, lanes = tiles
+    done, needed = _mhap_scores(T, bq, bk, sub, causal)
+    profiler.set_gauge("flash.tile_q", bq)
+    profiler.set_gauge("flash.tile_k", bk)
+    profiler.set_gauge("flash.subtile", sub)
+    profiler.set_gauge("flash.head_group_lanes", lanes)
+    profiler.set_gauge("flash.scores_computed_over_needed", done / needed)
+
+
+def _mhap_specs(lanes, HD, order):
+    """BlockSpec makers of a packed kernel's operands over the grid
+    (row, head group, *``order``): ``part`` 0 / 1 / 2 is the q / k / v
+    third of the packed dim (0 too for an operand H·D wide), ``axis``
+    whether the block follows the grid's q tiles or its k tiles."""
+    n = HD // lanes
+    at = {name: i for i, name in enumerate(order)}
+
+    def spec(block, axis, part=0):
+        return _vmem_spec(
+            (1, block, lanes),
+            lambda b, g, *ij: (b, ij[at[axis]], part * n + g))
+    return n, spec
+
+
+def _mhap_fwd(qkv, H, D, causal, tiles, interpret):
     B, T, _ = qkv.shape
     HD = H * D
     scale = 1.0 / float(D) ** 0.5
-    bq = bk = _mha_block(block_size, T)
-    qkvf = _pad_to(qkv, 1, bq)
+    bq, bk, sub, lanes = tiles
+    qkvf = _pad_to(qkv, 1, max(bq, bk))
     Tp = qkvf.shape[1]
-    nq = nk = Tp // bq
+    nq, nk = Tp // bq, Tp // bk
+    n, spec = _mhap_specs(lanes, HD, "qk")
     kern = functools.partial(
-        _mhap_fwd_kernel, H=H, D=D, causal=causal, block_q=bq, block_k=bk,
-        tq_valid=T, tk_valid=T, scale=scale, nk=nk)
+        _mhap_fwd_kernel, H=lanes // D, D=D, causal=causal, block_q=bq,
+        block_k=bk, sub=sub, t_valid=T, scale=scale, nq=nq, nk=nk)
     o, lse = pl.pallas_call(
         kern,
-        grid=(B, nq, nk),
-        in_specs=[
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-            _vmem_spec((1, bk, HD), lambda b, qi, kj: (b, kj, 1)),
-            _vmem_spec((1, bk, HD), lambda b, qi, kj: (b, kj, 2)),
-        ],
-        out_specs=[
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-        ],
+        grid=(B, n, nq, nk),
+        in_specs=[spec(bq, "q", 0), spec(bk, "k", 1), spec(bk, "k", 2)],
+        out_specs=[spec(bq, "q"), spec(bq, "q")],
         out_shape=[jax.ShapeDtypeStruct((B, Tp, HD), qkv.dtype),
                    jax.ShapeDtypeStruct((B, Tp, HD), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((bq, HD), jnp.float32),
-                        pltpu.VMEM((bq, HD), jnp.float32),
-                        pltpu.VMEM((bq, HD), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)] * 3,
         compiler_params=_compiler_params(
-            "parallel", "parallel", "arbitrary",
+            "parallel", "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_fwd_packed",
     )(qkvf, qkvf, qkvf)
     return o[:, :T], lse[:, :T]
 
 
-def _mhap_bwd(qkv, o, lse, do, H, D, causal, block_size):
+def _mhap_bwd(qkv, o, lse, do, H, D, causal, tiles, interpret):
     B, T, _ = qkv.shape
     HD = H * D
     scale = 1.0 / float(D) ** 0.5
-    bq = bk = _mha_block(block_size, T)
-    qkvf = _pad_to(qkv, 1, bq)
-    dof = _pad_to(do.astype(qkv.dtype), 1, bq)
-    of = _pad_to(o, 1, bq)  # Δ = rowsum(do∘o) computed inside the kernels
-    lsef = _pad_to(lse, 1, bq)
+    bq, bk, sub, lanes = tiles
+    pad = max(bq, bk)
+    qkvf = _pad_to(qkv, 1, pad)
+    dof = _pad_to(do.astype(qkv.dtype), 1, pad)
+    of = _pad_to(o, 1, pad)  # Δ = rowsum(do∘o) computed inside the kernels
+    lsef = _pad_to(lse, 1, pad)
     Tp = qkvf.shape[1]
-    nq = nk = Tp // bq
-    kw = dict(H=H, D=D, causal=causal, block_q=bq, block_k=bk,
-              tq_valid=T, tk_valid=T, scale=scale)
-    cparams = _compiler_params("parallel", "parallel", "arbitrary",
-                               vmem_limit_bytes=_VMEM_LIMIT)
+    nq, nk = Tp // bq, Tp // bk
+    kw = dict(H=lanes // D, D=D, causal=causal, block_q=bq, block_k=bk,
+              sub=sub, t_valid=T, scale=scale, nq=nq, nk=nk)
+    cparams = _compiler_params("parallel", "parallel", "parallel",
+                               "arbitrary", vmem_limit_bytes=_VMEM_LIMIT)
 
+    def operands(spec):
+        return [spec(bq, "q", 0), spec(bk, "k", 1), spec(bk, "k", 2),
+                spec(bq, "q"), spec(bq, "q"), spec(bq, "q")]
+
+    n, spec = _mhap_specs(lanes, HD, "qk")
     dq = pl.pallas_call(
-        functools.partial(_mhap_bwd_dq_kernel, nk=nk, **kw),
-        grid=(B, nq, nk),
-        in_specs=[
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-            _vmem_spec((1, bk, HD), lambda b, qi, kj: (b, kj, 1)),
-            _vmem_spec((1, bk, HD), lambda b, qi, kj: (b, kj, 2)),
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-            _vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0)),
-        ],
-        out_specs=[_vmem_spec((1, bq, HD), lambda b, qi, kj: (b, qi, 0))],
+        functools.partial(_mhap_bwd_dq_kernel, **kw),
+        grid=(B, n, nq, nk),
+        in_specs=operands(spec),
+        out_specs=[spec(bq, "q")],
         out_shape=[jax.ShapeDtypeStruct((B, Tp, HD), qkv.dtype)],
-        scratch_shapes=[pltpu.VMEM((bq, HD), jnp.float32),
-                        pltpu.VMEM((bq, HD), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, lanes), jnp.float32)] * 2,
         compiler_params=cparams,
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_transpose_dq_packed",
     )(qkvf, qkvf, qkvf, dof, lsef, of)[0]
 
+    n, spec = _mhap_specs(lanes, HD, "kq")
     dk, dv = pl.pallas_call(
-        functools.partial(_mhap_bwd_dkv_kernel, nq=nq, **kw),
-        grid=(B, nk, nq),
-        in_specs=[
-            _vmem_spec((1, bq, HD), lambda b, kj, qi: (b, qi, 0)),
-            _vmem_spec((1, bk, HD), lambda b, kj, qi: (b, kj, 1)),
-            _vmem_spec((1, bk, HD), lambda b, kj, qi: (b, kj, 2)),
-            _vmem_spec((1, bq, HD), lambda b, kj, qi: (b, qi, 0)),
-            _vmem_spec((1, bq, HD), lambda b, kj, qi: (b, qi, 0)),
-            _vmem_spec((1, bq, HD), lambda b, kj, qi: (b, qi, 0)),
-        ],
-        out_specs=[
-            _vmem_spec((1, bk, HD), lambda b, kj, qi: (b, kj, 0)),
-            _vmem_spec((1, bk, HD), lambda b, kj, qi: (b, kj, 0)),
-        ],
-        out_shape=[jax.ShapeDtypeStruct((B, Tp, HD), qkv.dtype),
-                   jax.ShapeDtypeStruct((B, Tp, HD), qkv.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, HD), jnp.float32),
-                        pltpu.VMEM((bk, HD), jnp.float32)],
+        functools.partial(_mhap_bwd_dkv_kernel, **kw),
+        grid=(B, n, nk, nq),
+        in_specs=operands(spec),
+        out_specs=[spec(bk, "k"), spec(bk, "k")],
+        out_shape=[jax.ShapeDtypeStruct((B, Tp, HD), qkv.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((bk, lanes), jnp.float32),
+                        pltpu.VMEM((bk, lanes), jnp.float32),
+                        pltpu.VMEM((bq, lanes), jnp.float32)],
         compiler_params=cparams,
-        interpret=_interpret(),
+        interpret=interpret,
         name="flash_transpose_dkv_packed",
     )(qkvf, qkvf, qkvf, dof, lsef, of)
 

@@ -25,24 +25,155 @@ def test_flash_mha_parity(monkeypatch, causal, shape):
     for a, b in zip(gk, gl):
         assert float(jnp.abs(a-b).max()) < 1e-5
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_packed_qkv_parity(monkeypatch, causal):
+def _lax_packed(qkv, H, causal):
+    B, T, HD3 = qkv.shape
+    D = HD3 // (3 * H)
+    q, k, v = (jnp.reshape(x, (B, T, H, D)) for x in jnp.split(qkv, 3, -1))
+    o, m, l = att._blockwise_attention_partial_lax(q, k, v, causal, 256, 0)
+    return jnp.reshape(att.normalize_attention_state(o, m, l, qkv.dtype),
+                       (B, T, H * D))
+
+
+def _packed_parity(monkeypatch, shape, tiles, causal):
+    """Interpreted parity of the packed forward and its three gradients
+    against the lax body, under the tile schedule ``tiles`` = (block_q,
+    block_k, sub, lanes of a head group) — the private override: the
+    chooser patched."""
     monkeypatch.setenv("MXNET_PALLAS", "1")
-    from mxnet_tpu.ops import pallas_kernels as pk2
-    B, T, H, D = 2, 384, 3, 64
-    rng = np.random.RandomState(1)
-    qkv = jnp.asarray(rng.randn(B, T, 3*H*D).astype(np.float32))
-    def f_kern(qkv):
-        return pk2.flash_mha_packed(qkv, H, causal=causal, block_size=256)
-    def f_lax(qkv):
-        q, k, v = (jnp.reshape(x, (B, T, H, D)) for x in jnp.split(qkv, 3, -1))
-        o, m, l = att._blockwise_attention_partial_lax(q, k, v, causal, 256, 0)
-        return jnp.reshape(att.normalize_attention_state(o, m, l, qkv.dtype), (B, T, H*D))
-    ok, ol = f_kern(qkv), f_lax(qkv)
-    assert float(jnp.abs(ok - ol).max()) < 1e-5
+    monkeypatch.setattr(pk, "_mhap_tiles", lambda t, hd, d: tiles)
+    B, T, H, D = shape
+    qkv = jnp.asarray(np.random.RandomState(1).randn(B, T, 3 * H * D)
+                      .astype(np.float32))
+    f_kern = lambda x: pk.flash_mha_packed(x, H, causal=causal)
+    f_lax = lambda x: _lax_packed(x, H, causal)
+    assert float(jnp.abs(f_kern(qkv) - f_lax(qkv)).max()) < 1e-5
     gk = jax.grad(lambda x: jnp.sum(jnp.sin(f_kern(x))))(qkv)
     gl = jax.grad(lambda x: jnp.sum(jnp.sin(f_lax(x))))(qkv)
-    assert float(jnp.abs(gk - gl).max()) < 1e-5
+    for part, (a, b) in enumerate(zip(jnp.split(gk, 3, -1),
+                                      jnp.split(gl, 3, -1))):
+        assert float(jnp.abs(a - b).max()) < 1e-5, "dq dk dv".split()[part]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+def test_packed_qkv_parity(monkeypatch, causal):
+    # tile 256 over T = 384: a padded edge, one sub-block a tile
+    _packed_parity(monkeypatch, (2, 384, 3, 64), (256, 256, 256, 192), causal)
+
+
+@pytest.mark.parametrize("sub", [128, 256])
+@pytest.mark.parametrize("T", [384, 1024, 1536])
+def test_packed_subtile_walk_parity(monkeypatch, T, sub):
+    """A tile the diagonal crosses is walked in sub-blocks, each against
+    the columns it sees: T = 384 is one padded tile, 1024 two rows of
+    tiles with one under the diagonal, 1536 three."""
+    _packed_parity(monkeypatch, (1, T, 2, 64), (512, 512, sub, 128), True)
+
+
+@pytest.mark.parametrize("tiles", [(512, 256, 128, 128),
+                                   (256, 512, 128, 128)])
+def test_packed_subtile_walk_parity_rectangular(monkeypatch, tiles):
+    """block_q != block_k: the diagonal crosses a tile at more than one
+    offset, and the walk is unrolled for each."""
+    assert len(pk._crossing_offsets(*tiles[:2])) == 2
+    _packed_parity(monkeypatch, (1, 1000, 2, 64), tiles, True)
+
+
+@pytest.mark.parametrize("case", ["one_tile", "pairs_of_four", "d128",
+                                  "d32_not_lane_tiles"])
+def test_packed_head_groups_parity(monkeypatch, case):
+    """A grid step holds a lane tile's heads (a pair at D = 64), H·D
+    where heads do not fill lane tiles; T in one tile carries no
+    state."""
+    shape, tiles = {
+        "one_tile": ((1, 1024, 2, 64), (1024, 1024, 256, 128)),
+        "pairs_of_four": ((2, 640, 4, 64), (256, 256, 128, 128)),
+        "d128": ((1, 640, 3, 128), (256, 256, 128, 128)),
+        "d32_not_lane_tiles": ((1, 384, 2, 32), (256, 256, 128, 64)),
+    }[case]
+    assert pk._mhap_tiles(shape[1], shape[2] * shape[3], shape[3])[3] \
+        == tiles[3]
+    _packed_parity(monkeypatch, shape, tiles, True)
+
+
+def test_packed_one_padded_tile_without_the_causal_mask(monkeypatch):
+    """T = 1000 under the chosen schedule: one tile, no state carried,
+    the padded edge under its own mask."""
+    shape = (1, 1000, 2, 64)
+    tiles = pk._mhap_tiles(1000, 128, 64)
+    assert tiles[:2] == (1024, 1024)
+    _packed_parity(monkeypatch, shape, tiles, False)
+
+
+def test_packed_tiles_come_from_the_shape():
+    """The chooser sees (T, H·D, D) and nothing else; what it picks
+    divides, fits and pads least."""
+    for t, hd, d in [(1024, 1280, 64), (1024, 1024, 64), (4096, 768, 64),
+                     (1536, 768, 64), (384, 192, 64), (100, 128, 128),
+                     (2048, 4096, 128), (1024, 640, 64), (512, 96, 32)]:
+        bq, bk, sub, lanes = pk._mhap_tiles(t, hd, d)
+        assert bq == bk and bq % sub == 0 and sub in (128, 256), (t, hd)
+        assert hd % lanes == 0 and lanes % d == 0, (t, hd, d, lanes)
+        assert lanes % 128 == 0 or lanes == hd, (t, hd, d, lanes)
+        assert bq * lanes * 48 <= pk._VMEM_LIMIT, (t, hd)
+        assert (-t) % bq < 128, (t, hd, bq)
+    # one tile up to 1,024 positions, whatever the width; 1,024s beyond,
+    # 512s where those pad less
+    assert pk._mhap_tiles(1024, 1280, 64) == pk._mhap_tiles(1024, 1024, 64) \
+        == (1024, 1024, 256, 128)
+    assert pk._mhap_tiles(4096, 768, 64)[0] == 1024
+    assert pk._mhap_tiles(1536, 768, 64)[0] == 512
+
+
+def test_packed_scores_counter(monkeypatch):
+    """Each build records its schedule and the scores it computes over
+    those the mask keeps: 1.50 for the masked 512 tile at T = 1024,
+    1.125 walked in 128s."""
+    assert pk._mhap_scores(1024, 512, 512, 512, True) == (786432, 524800)
+    assert pk._mhap_scores(1024, 512, 512, 128, True)[0] == 589824
+    assert pk._mhap_scores(1024, 1024, 1024, 256, True)[0] == 655360
+    assert pk._mhap_scores(1024, 128, 128, 128, True)[0] == 589824
+    assert pk._mhap_scores(1000, 512, 512, 512, False) == (1 << 20, 10 ** 6)
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    monkeypatch.setattr(pk, "_mhap_tiles",
+                        lambda t, hd, d: (256, 256, 128, 64))
+    mx.profiler.reset_metrics()
+    pk.flash_mha_packed(jnp.zeros((1, 512, 3 * 64), jnp.float32), 1,
+                        causal=True)
+    g = mx.profiler.metrics_summary()["gauges"]
+    assert (g["flash.tile_q"], g["flash.tile_k"], g["flash.subtile"],
+            g["flash.head_group_lanes"]) == (256, 256, 128, 64)
+    done, needed = pk._mhap_scores(512, 256, 256, 128, True)
+    assert g["flash.scores_computed_over_needed"] == done / needed
+    # the next program that holds the kernel says so again, though the
+    # jitted body is not traced again
+    mx.profiler.reset_metrics()
+    pk.flash_mha_packed(jnp.zeros((1, 512, 3 * 64), jnp.float32), 1,
+                        causal=True)
+    assert mx.profiler.metrics_summary()["gauges"]["flash.subtile"] == 128
+
+
+def test_packed_trace_for_the_chip_does_not_answer_an_interpreted_call(
+        monkeypatch):
+    """The custom_vjp is jitted and jit keeps its trace: one made with
+    the Mosaic call (as ``tests/test_tpu_compile.py`` makes them, in the
+    same worker) must not be handed to an interpreted call at the same
+    shape."""
+    monkeypatch.setattr(pk, "_mhap_tiles",
+                        lambda t, hd, d: (128, 128, 128, 128))
+    x = jnp.ones((1, 128, 3 * 128), jnp.float32)
+    f = lambda x: pk.flash_mha_packed(x, 2, causal=True)
+    with monkeypatch.context() as m:
+        m.setattr(pk, "_interpret", lambda: False)
+        assert "interpret=False" in str(jax.make_jaxpr(f)(x))
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    assert float(jnp.abs(f(x) - _lax_packed(x, 2, True)).max()) < 1e-5
+
+
+def test_packed_tiles_refused_by_name(monkeypatch):
+    monkeypatch.setattr(pk, "_mhap_tiles",
+                        lambda t, hd, d: (512, 384, 128, 64))
+    with pytest.raises(ValueError, match="must divide"):
+        pk.flash_mha_packed(jnp.zeros((1, 512, 192), jnp.float32), 1)
 
 
 def test_softmax_ce_loss_head():
@@ -150,12 +281,15 @@ def test_packed_qkv_on_a_mesh_plan_matches_one_device(monkeypatch, mesh):
     rng = np.random.RandomState(2)
     qkv = jnp.asarray(rng.randn(B, T, 3 * H * D).astype(np.float32))
 
+    monkeypatch.setattr(pk, "_mhap_tiles",
+                        lambda t, hd, d: (128, 128, 128, 128))
+
     def one(x):
-        return pk.flash_mha_packed(x, H, causal=True, block_size=128)
+        return pk.flash_mha_packed(x, H, causal=True)
 
     def meshed(x):
         with parallel.tracing_for(plan):
-            return att._flash_mha_packed_on_plan(x, H, True, 128)
+            return att._flash_mha_packed_on_plan(x, H, True)
 
     x_mesh = jax.device_put(qkv, NamedSharding(plan.mesh, P("dp", None, None)))
     got = jax.jit(meshed)(x_mesh)

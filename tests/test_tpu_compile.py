@@ -92,6 +92,45 @@ def test_flash_mha_packed_grad(on_chip, one_chip):
     _compile(jax.grad(loss), one_chip, _QKV)
 
 
+@pytest.mark.parametrize("cell", ["doc_prefill", "train_step"])
+def test_flash_mha_packed_at_the_cells_shapes(on_chip, one_chip, cell):
+    """gpt2-large's 1,024 prefill (forward) and gpt2-medium's training
+    step (forward + gradient) under the tiles the chooser picks."""
+    shape, heads = {"doc_prefill": ((1, 1024, 3840), 20),
+                    "train_step": ((8, 1024, 3072), 16)}[cell]
+    bq, bk, sub, lanes = pk._mhap_tiles(shape[1], shape[2] // 3, 64)
+    assert bq >= 512 and bq % sub == 0, "wide q rows, whatever a page holds"
+
+    def fwd(x):
+        return pk.flash_mha_packed(x, heads, causal=True)
+
+    fn = fwd if cell == "doc_prefill" else jax.grad(
+        lambda x: fwd(x).astype(f32).sum())
+    text = _compile(fn, one_chip, (shape, bf16)).as_text()
+    assert len(re.findall(r'custom_call_target="tpu_custom_call"', text)) \
+        == (1 if cell == "doc_prefill" else 3)
+
+
+def test_page_size_does_not_reach_the_prefill_kernel(on_chip, one_chip):
+    """``block_size`` — the engine passes its page size, 16 in the doc
+    cell — governs the op's lax body; the Mosaic call is the same
+    program with it or without."""
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    op = get_op("QKVSelfAttentionPrefill")
+    x = jax.ShapeDtypeStruct((1, 1024, 3840), bf16, sharding=one_chip)
+
+    def compiled(block):
+        attrs = {"num_heads": "20", "block_size": str(block)}
+        return jax.jit(lambda a: op.compute(
+            OpContext(is_train=False, rng=None), attrs, [a], [])).lower(
+                x).compile().as_text()
+
+    # one call site: the executable's text carries source lines
+    with_page, without = (compiled(block) for block in (16, 0))
+    assert "tpu_custom_call" in with_page and with_page == without
+
+
 def test_flash_mha(on_chip, one_chip):
     s = ((8 * 12, 1024, 64), bf16)
     _compile(lambda q, k, v: pk.flash_mha(q, k, v, causal=True),
@@ -432,7 +471,7 @@ def test_bare_kernel_cannot_be_partitioned(on_chip, plan):
 def test_flash_mha_packed_grad_on_dp2_tp2_mesh(on_chip, plan):
     def loss(x):
         with parallel.tracing_for(plan):
-            out = attention._flash_mha_packed_on_plan(x, 12, True, 0)
+            out = attention._flash_mha_packed_on_plan(x, 12, True)
         return out.astype(f32).sum()
 
     text = jax.jit(jax.grad(loss)).lower(_qkv_on(plan)).compile().as_text()

@@ -5,8 +5,10 @@ Round 5's fused-backward incident (PERF.md): a kernel passed a hardware
 probe, interpret-mode parity, AND the benchmark shape, yet returned
 ~100% wrong dk at other grid shapes.  Interpret mode cannot catch
 Mosaic-level races, so this tool exists: it sweeps the packed and
-per-head flash kernels across a (T, block, causal, H) matrix ON THE
-CHIP and compares forward + all input gradients against the lax
+per-head flash kernels across a (T, tiles, causal, H) matrix ON THE
+CHIP — the packed ones at the benchmark cells' own shapes too, with the
+ms a call of each kernel — and compares forward + all input gradients
+against the lax
 formulation, and the paged kernel — decode, verify window, int8 pools
 — over lane-dense (P, KVB, H·D) pools against the lax gather.  Run it
 after ANY kernel change:
@@ -15,8 +17,13 @@ after ANY kernel change:
     python tools/verify_kernels.py --quick  # smoke subset
     python tools/verify_kernels.py --paged  # the paged kernel alone
     python tools/verify_kernels.py --mamba2 # the Mamba-2 kernels alone
+    python tools/verify_kernels.py --packed # the packed flash kernels alone
+    python tools/verify_kernels.py --tiles  # the packed kernels' tile
+                                            # schedules at the cells'
+                                            # shapes, ms a call each
 """
 
+import functools
 import os
 import sys
 
@@ -44,36 +51,127 @@ def _lax_packed(qkv, B, T, H, D, causal):
                        (B, T, H * D))
 
 
-def check_packed(T, block, causal, H, B=2, D=64):
-    from mxnet_tpu.ops import pallas_kernels as pk
-
+@functools.lru_cache(maxsize=2)
+def _packed_case(B, T, H, D, causal, grad):
+    """One shape's input and the lax body's answers, made once however
+    many tile schedules are held against them."""
     rng = np.random.RandomState(0)
     qkv = jnp.asarray(rng.randn(B, T, 3 * H * D).astype(np.float32)
                       * 0.5).astype(jnp.bfloat16)
-    HD = H * D
-
-    def f_kern(x):
-        return pk.flash_mha_packed(x, H, causal=causal, block_size=block)
-
-    fwd_k = jax.jit(f_kern)(qkv).astype(jnp.float32)
-    fwd_l = jax.jit(lambda x: _lax_packed(x, B, T, H, D, causal))(
+    fwd = jax.jit(lambda x: _lax_packed(x, B, T, H, D, causal))(
         qkv).astype(jnp.float32)
-    gk = jax.jit(jax.grad(lambda x: jnp.sum(
-        f_kern(x).astype(jnp.float32))))(qkv).astype(jnp.float32)
-    gl = jax.jit(jax.grad(lambda x: jnp.sum(
+    g = jax.jit(jax.grad(lambda x: jnp.sum(
         _lax_packed(x, B, T, H, D, causal).astype(jnp.float32))))(
-            qkv).astype(jnp.float32)
-    errs = {"fwd": float(jnp.abs(fwd_k - fwd_l).max()
-                         / jnp.maximum(jnp.abs(fwd_l).max(), 1e-9))}
-    for name, s0 in (("dq", 0), ("dk", HD), ("dv", 2 * HD)):
-        a, b = gk[:, :, s0:s0 + HD], gl[:, :, s0:s0 + HD]
-        errs[name] = float(jnp.abs(a - b).max()
-                           / jnp.maximum(jnp.abs(b).max(), 1e-9))
+            qkv).astype(jnp.float32) if grad else None
+    return qkv, fwd, g
+
+
+def _kernel_ms(fn, x, n=10):
+    """Device ms a call of each Mosaic kernel ``fn(x)`` runs, by the
+    ``name=`` it carries, from a profiler trace of n calls read with the
+    benchmark's own reader; {} where the trace has no device plane."""
+    import shutil
+    import tempfile
+
+    from benchmark.trace_reduce import KERNEL_TAG, Trace
+
+    jax.block_until_ready(fn(x))
+    d = tempfile.mkdtemp(prefix="verify_kernels_")
+    try:
+        jax.profiler.start_trace(d)
+        for _ in range(n):
+            out = fn(x)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        out = {}
+        for name, sec in Trace.from_dir(d).op_seconds().items():
+            if KERNEL_TAG in name:
+                key = name.split(KERNEL_TAG)[0].strip("%_ ").split(".")[0]
+                out[key] = out.get(key, 0.0) + 1e3 * sec / n
+        return out
+    except (Exception, SystemExit) as e:  # noqa: BLE001 — timing only
+        print(f"   (no device trace: {e})", flush=True)
+        return {}
+    finally:
+        shutil.rmtree(d, ignore_errors=True)
+
+
+def check_packed(T, tiles, causal, H, B=2, D=64, grad=True):
+    """The packed kernels at one shape under one tile schedule —
+    ``tiles`` = (block_q, block_k, sub[, lanes]), None for the one
+    ``pk._mhap_tiles`` picks — against the lax body; beside the verdict
+    the ms a call: host clock of the forward and of forward + backward,
+    and each kernel's own device time from a trace."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    qkv, fwd_l, gl = _packed_case(B, T, H, D, causal, grad)
+    HD = H * D
+    chooser = pk._mhap_tiles
+    if tiles is not None:
+        if len(tiles) == 3:  # the chosen head group
+            tiles = (*tiles, chooser(T, HD, D)[3])
+        pk._mhap_tiles = lambda t, hd, d: tiles
+    try:
+        f_kern = jax.jit(lambda x: pk.flash_mha_packed(x, H, causal=causal))
+        g_kern = jax.jit(jax.grad(lambda x: jnp.sum(
+            pk.flash_mha_packed(x, H, causal=causal).astype(jnp.float32))))
+        errs = {"fwd": float(jnp.abs(f_kern(qkv).astype(jnp.float32) - fwd_l)
+                             .max() / jnp.maximum(jnp.abs(fwd_l).max(), 1e-9))}
+        ms = {"fwd": _time_ms(f_kern, qkv, n=10)}
+        if grad:
+            gk = g_kern(qkv).astype(jnp.float32)
+            for name, s0 in (("dq", 0), ("dk", HD), ("dv", 2 * HD)):
+                a, b = gk[:, :, s0:s0 + HD], gl[:, :, s0:s0 + HD]
+                errs[name] = float(jnp.abs(a - b).max()
+                                   / jnp.maximum(jnp.abs(b).max(), 1e-9))
+            ms["fwd+bwd"] = _time_ms(g_kern, qkv, n=10)
+        ms.update(_kernel_ms(g_kern if grad else f_kern, qkv))
+    finally:
+        pk._mhap_tiles = chooser
+    bq, bk, sub, lanes = tiles or chooser(T, HD, D)
+    done, needed = pk._mhap_scores(T, bq, bk, sub, causal)
     ok = all(e < TOL for e in errs.values())
-    print(f"{'OK ' if ok else 'FAIL'} packed T={T} block={block or 'auto'} "
-          f"causal={causal} H={H}: "
-          + " ".join(f"{k}={v:.4f}" for k, v in errs.items()), flush=True)
+    print(f"{'OK ' if ok else 'FAIL'} packed ({B}, {T}, {3 * HD}) H={H} "
+          f"causal={causal} tiles={bq}x{bk}/{sub} lanes={lanes}"
+          f"{'' if tiles else ' (chosen)'} scores x{done / needed:.3f}: "
+          + " ".join(f"{k}={v:.4f}" for k, v in errs.items()) + " | ms: "
+          + " ".join(f"{k}={v:.3f}" for k, v in ms.items()), flush=True)
     return ok
+
+
+# the packed kernels' own cells — gpt2-large's 1,024 prefill (forward
+# only), gpt2-medium's training step — and the tools' benches' T = 4096
+CELL_SHAPES = (((1, 1024, 20), False), ((8, 1024, 16), True),
+               ((4, 4096, 12), True))
+
+
+def sweep_tiles():
+    """The kernel-alone table of PERF.md (PR 32): every tile schedule
+    worth holding against the chosen one, at the cells' shapes.  A
+    schedule (q, q, q) masks its whole diagonal tile, as the kernels did
+    before they walked it; 128 is what a 16-token page once gave the
+    1,024 prefill; a fourth number is the lanes of a grid step's head
+    group (all of H·D: every head unrolled in one step, as before)."""
+    results = []
+    for (B, T, H), grad in CELL_SHAPES:
+        hd = H * 64
+        cands = [None, (512, 512, 256), (512, 512, 512), (1024, 1024, 128),
+                 (1024, 1024, 256, 256), (1024, 1024, 256, hd)]
+        if T == 1024:
+            cands += [(1024, 1024, 512), (256, 256, 256), (128, 128, 128)]
+        else:
+            cands += [(512, 512, 128), (1024, 1024, 256),
+                      (1024, 1024, 1024), (512, 1024, 256),
+                      (512, 2048, 256)]
+        for tiles in cands:
+            try:
+                results.append(check_packed(T, tiles, True, H, B=B,
+                                            grad=grad))
+            except Exception as e:  # noqa: BLE001 — the compiler's refusal
+                print(f"FAIL packed ({B}, {T}) H={H} tiles={tiles}: "
+                      f"{type(e).__name__}: {str(e)[:300]}", flush=True)
+                results.append(False)
+    return results
 
 
 def check_mha(T, block, causal, B=2, H=8, D=128):
@@ -260,16 +358,8 @@ def check_mamba2(T, n, B=1, H=128, P=64, N=128):
     return ok
 
 
-def main():
-    quick = "--quick" in sys.argv
+def _paged_matrix(quick):
     results = []
-    if "--mamba2" in sys.argv:
-        # the granite cell's own shapes: a 64-row decode step, prompts
-        # in the 1024 and 2048 buckets (whole and ending inside a chunk)
-        results.append(check_mamba2(1, 1, B=64))
-        for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
-            results.append(check_mamba2(T, n))
-        return _report(results)
     # the cells' widths (20 and 16 heads of 64), a head a quarter of a
     # lane tile, and H·D not a multiple of 128
     for H, D in ([(20, 64)] if quick else
@@ -285,18 +375,42 @@ def main():
     # the reference gathers every row's table at 64 heads, 84 MB a row)
     results.append(check_paged(20, 64, 1, "bf16", B=48, MB=64))
     results.append(check_paged(8, 128, 1, "bf16", B=16, MB=160, Hq=64))
+    return results
+
+
+def main():
+    quick = "--quick" in sys.argv
+    results = []
+    if "--tiles" in sys.argv:
+        return _report(sweep_tiles())
+    if "--mamba2" in sys.argv:
+        # the granite cell's own shapes: a 64-row decode step, prompts
+        # in the 1024 and 2048 buckets (whole and ending inside a chunk)
+        results.append(check_mamba2(1, 1, B=64))
+        for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
+            results.append(check_mamba2(T, n))
+        return _report(results)
+    if "--packed" not in sys.argv:
+        results += _paged_matrix(quick)
     if "--paged" in sys.argv:
         return _report(results)
-    # packed: sweep revisit counts, block sizes, head counts, causality
-    matrix = [(1024, 0, True, 12), (4096, 0, True, 12)] if quick else [
-        (1024, 0, True, 12), (1024, 0, False, 12),
-        (2048, 0, True, 12), (3072, 0, True, 12),
-        (4096, 0, True, 12), (4096, 0, False, 12),
-        (4096, 512, True, 12), (4096, 1024, True, 4),
-        (1536, 512, True, 8),
+    # packed: the cells' own shapes under the chosen tiles, then revisit
+    # counts, tile schedules (block_q, block_k, sub), head counts,
+    # causality
+    for (B, T, H), grad in CELL_SHAPES[:1] if quick else CELL_SHAPES:
+        results.append(check_packed(T, None, True, H, B=B, grad=grad))
+    matrix = [(1024, None, True, 12), (4096, None, True, 12)] if quick else [
+        (1024, None, True, 12), (1024, None, False, 12),
+        (2048, None, True, 12), (3072, None, True, 12),
+        (4096, None, True, 12), (4096, None, False, 12),
+        (4096, (512, 512, 128), True, 12), (4096, (1024, 1024, 1024), True, 4),
+        (1536, (512, 512, 256), True, 8), (1000, (512, 256, 128), True, 8),
+        (1000, None, False, 8),
     ]
-    for T, block, causal, H in matrix:
-        results.append(check_packed(T, block, causal, H))
+    for T, tiles, causal, H in matrix:
+        results.append(check_packed(T, tiles, causal, H))
+    if "--packed" in sys.argv:
+        return _report(results)
     for T, block, causal in ([(4096, 0, True)] if quick else
                              [(1024, 0, True), (4096, 0, True),
                               (4096, 1024, False), (2048, 512, True)]):
