@@ -20,7 +20,14 @@ Design (PagedAttention, Kwon et al. SOSP '23):
 * **page 0 is reserved scratch**: padded batch slots and padded
   prompt positions write there, which keeps every scatter in the
   decode program mask-free — reads of scratch are always masked by
-  the per-stream length.
+  the per-stream length;
+* a page id addresses the same page in the pools of every layer of one
+  KIND.  Layers that see only the last W keys (a sliding window) keep
+  theirs in pools of a second kind, ``window_pages``: a second
+  :class:`BlockAllocator` with page ids of its own and a table of its
+  own, in which a stream holds the pages its window still reaches and
+  frees each page that falls wholly behind it (``DecodeEngine``; the
+  entries of given-back blocks are the scratch page).
 
 Prefix sharing (RadixAttention, Zheng et al. '23) adds **reference
 counting**: a page holding a fully-written block of a common prompt

@@ -1,14 +1,20 @@
 """Hybrid decoder-only language models, built from a LAYER LIST.
 
 A model of this family is a stack of pre-norm residual blocks (RMSNorm,
-no biases, no positions) in which every layer names its own MIXER and
-its own FFN:
+no biases, no learned positions) in which every layer names its own
+MIXER and its own FFN:
 
 * mixer ``attention``: softmax attention with grouped queries
   (``heads`` query heads over ``kv_heads`` KV heads of ``head_dim``;
   ``scale``: the scores' multiplier where it is not ``head_dim^-1/2``),
   an optional element-wise sigmoid output gate before the output
-  projection; its per-stream state is K/V PAGES;
+  projection; ``rope_theta``: q and k rotated by their positions
+  (rotate-half over the whole head; absent = no positions at all);
+  ``window``: a query sees its own key and the ``window - 1`` before it
+  (absent = every key).  Its per-stream state is K/V PAGES; a windowed
+  layer's are pages of a SECOND pool (kind ``window_pages``, addressed
+  through the ``window_table`` feed), in which a stream holds only the
+  pages its window still reaches;
 * mixer ``kda``: gated-delta-rule linear attention with a per-channel
   decay (``heads`` heads of ``head_dim``, a depthwise short convolution
   of ``conv`` taps on q, k and v, low-rank decay and output-gate maps of
@@ -25,7 +31,11 @@ its own FFN:
 * ffn ``dense``: a gated (SiLU) feed-forward of ``width``;
 * ffn ``moe``: ``experts`` routed experts of ``width``, ``top_k`` a
   token (``score``: ``sigmoid`` — normalised sigmoid scores, the
-  default — or ``softmax_topk`` — a softmax over the chosen logits),
+  default — or ``softmax_topk`` — a softmax over the chosen logits;
+  ``act``: the experts' gate, ``silu`` — the default — or ``relu``;
+  ``router_input``: ``ffn`` — the router scores the rows the experts
+  get, the default — or ``block`` — the block's un-normalised input,
+  before attention),
   plus ``shared`` always-on experts (of ``shared_width`` together;
   default ``width`` each); this program HOLDS ``experts_held`` of the routed experts,
   from ``first_expert`` on — the share of one chip of an expert-parallel
@@ -34,20 +44,36 @@ its own FFN:
 :class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
 takes: from the layer list it derives the feeds, the pools (pages for
 attention layers, slots for kda and mamba2 layers) and the prefill and
-decode symbols.  Four multipliers and the head's weights are data of
+decode symbols (a prefill's logits are those of each prompt's LAST row
+alone, (B, 1, vocab): the engine samples one token of it).  Four multipliers and the head's weights are data of
 the spec too: ``embed_scale`` (on the token rows), ``residual_scale``
 (on every block's output before it is added), ``logits_scale`` (on the
 last norm's output before the head) and ``tied_head`` (the head is the
 token table).  The equations are in ``benchmark/reference/
-solar_open2.py`` and ``granitemoehybrid.py``, the plain references
-this family is held to.
+solar_open2.py``, ``granitemoehybrid.py`` and ``smallthinker.py``, the
+plain references this family is held to.
 """
 
 from .. import symbol as sym
 from ..base import MXNetError
+from ..ops.hybrid import EXPERT_ACTS
 
-MIXERS = ("attention", "kda", "mamba2")
-FFNS = ("dense", "moe")
+# every key a mixer or an FFN dict may hold, by kind: another is a typo
+# that would silently build a model without the mechanism it names
+MIXERS = {
+    "attention": ("kind", "heads", "kv_heads", "head_dim", "scale", "gate",
+                  "rope_theta", "window"),
+    "kda": ("kind", "heads", "head_dim", "conv", "neg_eigval"),
+    "mamba2": ("kind", "heads", "head_dim", "d_state", "groups", "conv",
+               "conv_bias"),
+}
+FFNS = {
+    "dense": ("kind", "width"),
+    "moe": ("kind", "experts", "top_k", "width", "score", "shared",
+            "shared_width", "experts_held", "first_expert", "act",
+            "router_input"),
+}
+ROUTER_INPUTS = ("ffn", "block")
 COUNTERS = "moe_counters"
 
 
@@ -104,8 +130,29 @@ class HybridSpec:
             if m.get("kind") not in MIXERS or f.get("kind") not in FFNS:
                 raise MXNetError(
                     f"layer {i}: mixer kind {m.get('kind')!r} must be one "
-                    f"of {MIXERS} and ffn kind {f.get('kind')!r} one of "
-                    f"{FFNS}")
+                    f"of {tuple(MIXERS)} and ffn kind {f.get('kind')!r} "
+                    f"one of {tuple(FFNS)}")
+            for what, d, known in (("mixer", m, MIXERS[m["kind"]]),
+                                   ("ffn", f, FFNS[f["kind"]])):
+                unknown = sorted(set(d) - set(known))
+                if unknown:
+                    raise MXNetError(
+                        f"layer {i}: {d['kind']} {what} has no key "
+                        f"{unknown} (it takes {known})")
+            if m["kind"] == "attention" and (
+                    float(m.get("rope_theta") or 0) < 0
+                    or int(m.get("window") or 0) < 0):
+                raise MXNetError(
+                    f"layer {i}: rope_theta {m.get('rope_theta')!r} and "
+                    f"window {m.get('window')!r} must be positive where "
+                    f"given")
+            if f["kind"] == "moe" and (
+                    f.get("act", "silu") not in EXPERT_ACTS
+                    or f.get("router_input", "ffn") not in ROUTER_INPUTS):
+                raise MXNetError(
+                    f"layer {i}: moe act {f.get('act')!r} must be one of "
+                    f"{tuple(EXPERT_ACTS)} and router_input "
+                    f"{f.get('router_input')!r} one of {ROUTER_INPUTS}")
             if m["kind"] == "attention" and \
                     int(m["heads"]) % int(m.get("kv_heads", m["heads"])):
                 raise MXNetError(
@@ -132,9 +179,22 @@ class HybridSpec:
                 f"would need a page pool each; one width is built")
         # the K/V page geometry (what the engine sizes its pools by)
         self.kv_heads, self.head_dim = pages.pop() if pages else (0, 0)
+        windows = {int(ly["mixer"]["window"]) for ly in self.layers
+                   if ly["mixer"].get("window")}
+        if len(windows) > 1:
+            raise MXNetError(
+                f"attention layers of different windows {sorted(windows)} "
+                f"would need a windowed pool (and its allocator) each; "
+                f"one window is built")
+        # the keys a windowed layer's pools keep a stream (0: no such
+        # layer): what the engine sizes the second page pool by
+        self.window = windows.pop() if windows else 0
+        rotary = any(ly["mixer"].get("rope_theta") for ly in self.layers)
+        self.feeds = ("data", "lengths", "block_table", "slots") \
+            + (("positions",) if rotary else ()) \
+            + (("window_table",) if self.window else ())
 
     # -- what the engine asks (the protocol: DecodeEngine's docstring) ---
-    feeds = ("data", "lengths", "block_table", "slots")
     phases = ("prefill", "decode")    # no suffix-prefill, no verify symbol
     kv_dtypes = ("fp32", "bf16")      # no quantized pages
     positions = None                  # no learned positions: max_len given
@@ -151,13 +211,17 @@ class HybridSpec:
     @property
     def name(self):
         return (f"a model spec with "
-                f"{'/'.join(sorted(set(self.mixer_kinds())))} layers")
+                f"{'/'.join(sorted(set(self.mixer_kinds())))} layers"
+                + (" and windowed pools" if self.window else ""))
 
     def cache_kinds(self):
         """Per layer, the kind of its per-stream state: ``pages`` (K/V,
-        through the block table) or ``slots`` (one row a stream)."""
-        return tuple("pages" if k == "attention" else "slots"
-                     for k in self.mixer_kinds())
+        through the block table), ``window_pages`` (K/V of a windowed
+        layer, through the window table) or ``slots`` (one row a
+        stream)."""
+        return tuple("slots" if ly["mixer"]["kind"] != "attention" else
+                     "window_pages" if ly["mixer"].get("window") else "pages"
+                     for ly in self.layers)
 
     def has_moe(self):
         return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
@@ -168,16 +232,17 @@ class HybridSpec:
         rides in the same slot."""
         out = []
         for k in self.cache_kinds():
-            out += ["pages", "pages"] if k == "pages" \
-                else ["slots", "slots_aux"]
+            out += ["slots", "slots_aux"] if k == "slots" else [k, k]
         return tuple(out) + (("counters",) if self.has_moe() else ())
 
-    def pools(self, cache_blocks, kv_block, slots, dtype, kv_dtype="fp32"):
+    def pools(self, cache_blocks, kv_block, slots, dtype, kv_dtype="fp32",
+              window_blocks=0):
         """The per-stream state arrays a program carries, in the order
         its symbols return them: ``(name, shape, dtype, fill)``.  Pages
         take ``dtype`` (no quantized pools here: the engine refuses
-        them for this family); slot state is float32 whatever the
-        model's."""
+        them for this family); a windowed layer's pools hold
+        ``window_blocks`` pages, a page-id space of their own; slot
+        state is float32 whatever the model's."""
         from ..kv_cache import (conv_tail_shape, state_pool_shape,
                                 value_pool_shape)
 
@@ -185,8 +250,9 @@ class HybridSpec:
         for i, ly in enumerate(self.layers):
             m = ly["mixer"]
             if m["kind"] == "attention":
-                shape = value_pool_shape(cache_blocks, kv_block,
-                                         self.kv_heads, self.head_dim)
+                shape = value_pool_shape(
+                    window_blocks if m.get("window") else cache_blocks,
+                    kv_block, self.kv_heads, self.head_dim)
                 out += [(f"layer{i}_kpool", shape, dtype, 0),
                         (f"layer{i}_vpool", shape, dtype, 0)]
             else:
@@ -228,11 +294,17 @@ def _attention(spec, h, i, m, step, feeds):
     k = _fc(h, Hkv * D, f"{name}_k")
     v = _fc(h, Hkv * D, f"{name}_v")
     op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
-    scale = {"scale": float(m["scale"])} if m.get("scale") else {}
-    att = op(q, k, v, sym.Variable(f"{name}_kpool"),
-             sym.Variable(f"{name}_vpool"), feeds["block_table"],
-             feeds["lengths"], num_heads=H, kv_heads=Hkv,
-             name=f"{name}_attn", **scale)
+    attrs = {"scale": float(m["scale"])} if m.get("scale") else {}
+    args = [q, k, v, sym.Variable(f"{name}_kpool"),
+            sym.Variable(f"{name}_vpool"),
+            feeds["window_table" if m.get("window") else "block_table"],
+            feeds["lengths"]]
+    if m.get("window"):
+        attrs["window"] = int(m["window"])
+    if m.get("rope_theta"):
+        attrs["rope_theta"] = float(m["rope_theta"])
+        args.append(feeds["positions"])
+    att = op(*args, num_heads=H, kv_heads=Hkv, name=f"{name}_attn", **attrs)
     out = att[0]
     if m.get("gate"):
         out = out * sym.Activation(_fc(h, H * D, f"{name}_gate"),
@@ -295,20 +367,26 @@ def _mamba2(spec, h, i, m, step, feeds):
 _MIXER_BUILDERS = {"attention": _attention, "kda": _kda, "mamba2": _mamba2}
 
 
-def _ffn(spec, h, i, f, step, feeds, counters):
+def _ffn(spec, h, x_in, i, f, step, feeds, counters):
+    """``h``: the rows the FFN takes; ``x_in``: the block's input, which
+    a router with ``router_input: block`` scores in their place."""
     name = f"layer{i}"
     if f["kind"] == "dense":
         return _gated_ffn(h, int(f["width"]), spec.d_model,
                           f"{name}_ffn"), counters
-    score = {"score": f["score"]} if f.get("score") else {}
+    attrs = {k: f[k] for k in ("score", "act") if f.get(k)}
+    args = [h, sym.Variable(f"{name}_router_weight"),
+            sym.Variable(f"{name}_experts_gate_weight"),
+            sym.Variable(f"{name}_experts_up_weight"),
+            sym.Variable(f"{name}_experts_down_weight"), feeds["lengths"],
+            counters]
+    if f.get("router_input", "ffn") == "block":
+        attrs["router_data"] = True
+        args.append(x_in)
     routed = sym.MoEFFN(
-        h, sym.Variable(f"{name}_router_weight"),
-        sym.Variable(f"{name}_experts_gate_weight"),
-        sym.Variable(f"{name}_experts_up_weight"),
-        sym.Variable(f"{name}_experts_down_weight"), feeds["lengths"],
-        counters, top_k=int(f["top_k"]),
+        *args, top_k=int(f["top_k"]),
         first_expert=int(f.get("first_expert", 0)), step=step,
-        count=step, name=f"{name}_moe", **score)
+        count=step, name=f"{name}_moe", **attrs)
     out = routed[0]
     if int(f.get("shared", 0)):
         width = int(f.get("shared_width",
@@ -319,9 +397,12 @@ def _ffn(spec, h, i, f, step, feeds, counters):
 
 
 def _trunk(spec, step):
-    """Token ids (B, S) -> ``[logits (B, S, vocab)] + spec.pools()``'s
-    arrays, updated.  ``step``: one token a stream against its state
-    (decode); else a whole (padded) prompt from nothing (prefill)."""
+    """Token ids (B, S) -> ``[logits] + spec.pools()``'s arrays,
+    updated.  ``step``: one token a stream against its state (decode),
+    logits (B, 1, vocab); else a whole (padded) prompt from nothing
+    (prefill), logits (B, 1, vocab) of row ``lengths[b] - 1`` alone:
+    the head over every position of a long prompt is more work and
+    memory than the layers, and one row of it is read."""
     feeds = {k: sym.Variable(k) for k in spec.feeds}
     def scaled(t, by):       # a multiplier of 1 adds no node
         return t if by == 1.0 else t * by
@@ -333,14 +414,20 @@ def _trunk(spec, step):
     counters = sym.Variable(COUNTERS) if spec.has_moe() else None
     state = []
     for i, ly in enumerate(spec.layers):
+        x_in = x
         h = _norm(x, f"layer{i}_norm1", spec.norm_eps)
         out, st = _MIXER_BUILDERS[ly["mixer"]["kind"]](
             spec, h, i, ly["mixer"], step, feeds)
         state += st
         x = x + scaled(out, spec.residual_scale)
         h = _norm(x, f"layer{i}_norm2", spec.norm_eps)
-        out, counters = _ffn(spec, h, i, ly["ffn"], step, feeds, counters)
+        out, counters = _ffn(spec, h, x_in, i, ly["ffn"], step, feeds,
+                             counters)
         x = x + scaled(out, spec.residual_scale)
+    if not step:
+        x = sym.expand_dims(sym.SequenceLast(
+            sym.SwapAxis(x, dim1=0, dim2=1), feeds["lengths"],
+            use_sequence_length=True, name="last_row"), axis=1)
     x = scaled(_norm(x, "final_norm", spec.norm_eps), spec.logits_scale)
     if spec.tied_head:
         logits = sym.FullyConnected(x, num_hidden=spec.vocab_size,

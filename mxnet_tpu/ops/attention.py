@@ -59,7 +59,8 @@ def blockwise_attention_partial(q, k, v, causal=False, block_size=512,
 
 def _blockwise_attention_partial_lax(q, k, v, causal, block_size,
                                      kv_offset, lengths=None,
-                                     init_state=None, diagonal=False):
+                                     init_state=None, diagonal=False,
+                                     window=0):
     """The pure lax.scan formulation — reference semantics and the
     remat backward for the Pallas forward.
 
@@ -88,7 +89,12 @@ def _blockwise_attention_partial_lax(q, k, v, causal, block_size,
     the single-query decode step at length ``lengths[b] + i`` — rows
     of the blockwise body are arithmetically independent, so one
     diagonal-masked scan is bit-identical to W sequential decode
-    steps over the same cache bytes."""
+    steps over the same cache bytes.
+
+    ``window`` > 0 (causal, or one limit per stream): a query sees
+    itself and the ``window - 1`` keys before it, no others — a lower
+    bound on the keys beside the upper one; a block wholly below it is
+    the same exact no-op of the merge as one wholly above."""
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
     scale = 1.0 / jnp.sqrt(jnp.asarray(D, q.dtype))
@@ -116,9 +122,15 @@ def _blockwise_attention_partial_lax(q, k, v, causal, block_size,
         elif lengths is not None:
             mask = mask & (k_pos[None, None, None, :]
                            < lengths[:, None, None, None])
+            if window:
+                mask = mask & (k_pos[None, None, None, :]
+                               >= lengths[:, None, None, None] - window)
         elif causal:
             mask = mask & (k_pos[None, None, None, :]
                            <= q_pos[None, None, :, None])
+            if window:
+                mask = mask & (k_pos[None, None, None, :]
+                               > q_pos[None, None, :, None] - window)
         s = jnp.where(mask, s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # guard fully-masked rows (m_new = -inf): exp(-inf - -inf)
@@ -371,16 +383,18 @@ def _qkv_attention(op_ctx, attrs, inputs, aux):
 # ---------------------------------------------------------------------------
 
 
-def decode_attention(q, k_cache, v_cache, lengths, block_size):
+def decode_attention(q, k_cache, v_cache, lengths, block_size, window=0):
     """One-query-position attention over a padded KV cache.
 
     q: (B, 1, H, D) — the current token's query, sitting at absolute
     position ``lengths[b] - 1``; k_cache/v_cache: (B, C, H, D) with
     positions >= lengths[b] ignored (masked exactly); lengths: (B,)
-    int32 INCLUDING the current token.  Returns (B, 1, H, D).
+    int32 INCLUDING the current token; ``window`` > 0: positions below
+    ``lengths[b] - window`` ignored too.  Returns (B, 1, H, D).
     """
     o, m, l = _blockwise_attention_partial_lax(
-        q, k_cache, v_cache, True, block_size or 512, 0, lengths=lengths)
+        q, k_cache, v_cache, True, block_size or 512, 0, lengths=lengths,
+        window=window)
     return normalize_attention_state(o, m, l, q.dtype)
 
 

@@ -136,64 +136,127 @@ def _gqa_infer(attrs, in_shapes):
 _GQA_ARGS = ("query", "key", "value", "k_pool", "v_pool", "block_table",
              "lengths")
 _GQA_OUTS = ("output", "new_k_pool", "new_v_pool")
+_GQA_ATTRS = (
+    "attrs: num_heads, kv_heads, scale (the scores' multiplier; 0 = "
+    "head_dim^-1/2), rope_theta (0 = no rotation; else an eighth input, "
+    "positions (B, S) int32, and q and k are rotated by them — rotate-half "
+    "pairs (i, i + D/2) over the whole head, base rope_theta — before the "
+    "scores and before k goes into the pages), window (0 = every key up "
+    "to the query; else the query's own key and the window - 1 before it: "
+    "block_table is then the table of a WINDOWED pool, whose entries "
+    "behind the window may be the scratch page)")
 
 
-@register("GQAPrefillAttention", arg_names=_GQA_ARGS, out_names=_GQA_OUTS,
+def _gqa_args(attrs):
+    return _GQA_ARGS + (
+        ("positions",) if attr_float(attrs.get("rope_theta", 0.0), 0.0)
+        else ())
+
+
+def rotate_half(x, positions, theta, heads):
+    """Rotary positions: x (B, S, heads·D) with each head's lanes in
+    pairs (i, i + D/2), pair i turned by ``positions * theta^(-2i/D)``;
+    float32 inside, x's type out."""
+    B, S, HD = x.shape
+    D = HD // heads
+    inv = jnp.asarray(theta, jnp.float32) ** (
+        -jnp.arange(0, D, 2, dtype=jnp.float32) / D)            # (D/2,)
+    ang = positions.astype(jnp.float32)[..., None] * inv        # (B, S, D/2)
+    cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
+    x1, x2 = jnp.split(x.reshape(B, S, heads, D).astype(jnp.float32), 2,
+                       axis=-1)
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    return out.reshape(B, S, HD).astype(x.dtype)
+
+
+def _gqa_rotated(attrs, inputs, H, Hkv):
+    """(q, k) of the op's inputs, rotated where the op rotates."""
+    q, k = inputs[0], inputs[1]
+    theta = attr_float(attrs.get("rope_theta", 0.0), 0.0)
+    if not theta:
+        return q, k
+    positions = inputs[7]
+    return rotate_half(q, positions, theta, H), \
+        rotate_half(k, positions, theta, Hkv)
+
+
+@register("GQAPrefillAttention", arg_names=_gqa_args, out_names=_GQA_OUTS,
           infer_shape=_gqa_infer,
           doc="Causal grouped-query attention over a (padded) prompt "
               "that also writes its K/V rows into the paged pools: "
               "query (B, T, H*D), key/value (B, T, Hkv*D), pools "
               "(P, KVB, Hkv*D) -> output (B, T, H*D) + pools.  Query "
-              "head i reads KV head i // (H / Hkv); no rotation.  "
-              "attrs: num_heads, kv_heads, scale (the scores' multiplier; "
-              "0 = head_dim^-1/2)")
+              "head i reads KV head i // (H / Hkv).  " + _GQA_ATTRS)
 def _gqa_prefill(op_ctx, attrs, inputs, aux):
-    from .attention import blockwise_attention, paged_prefill_write
+    from . import pallas_kernels as pk
+    from .attention import (_blockwise_attention_partial_lax,
+                            blockwise_attention, normalize_attention_state,
+                            paged_prefill_write)
 
-    q, k, v, k_pool, v_pool, table, lengths = inputs
+    q, k, v, k_pool, v_pool, table, lengths = inputs[:7]
     H, Hkv = _gqa_heads(attrs, q, k)
+    q, k = _gqa_rotated(attrs, inputs, H, Hkv)
     q = _gqa_scaled(attrs, q, H)
+    window = attr_int(attrs.get("window", 0), 0)
     B, T, HD = q.shape
-    out = blockwise_attention(
-        q.reshape(B, T, H, HD // H), _repeat_heads(k, H, Hkv),
-        _repeat_heads(v, H, Hkv), causal=True)
+    D = HD // H
+    if window and pk.enabled():
+        def heads_first(x, n):
+            return x.reshape(B, T, n, D).transpose(0, 2, 1, 3) \
+                .reshape(B * n, T, D)
+
+        out = pk.flash_mha_window(
+            heads_first(q, H), heads_first(k, Hkv), heads_first(v, Hkv),
+            window, H, Hkv).reshape(B, H, T, D).transpose(0, 2, 1, 3)
+    elif window:
+        q4 = q.reshape(B, T, H, D)
+        out = normalize_attention_state(
+            *_blockwise_attention_partial_lax(
+                q4, _repeat_heads(k, H, Hkv), _repeat_heads(v, H, Hkv),
+                True, 512, 0, window=window), q.dtype)
+    else:
+        out = blockwise_attention(
+            q.reshape(B, T, H, D), _repeat_heads(k, H, Hkv),
+            _repeat_heads(v, H, Hkv), causal=True)
     pools = paged_prefill_write(k, v, k_pool, v_pool,
                                 table.astype(jnp.int32),
                                 lengths.astype(jnp.int32))
     return [out.reshape(B, T, HD), pools[0], pools[1]]
 
 
-@register("GQAPagedDecode", arg_names=_GQA_ARGS, out_names=_GQA_OUTS,
+@register("GQAPagedDecode", arg_names=_gqa_args, out_names=_GQA_OUTS,
           infer_shape=_gqa_infer,
           doc="One decode step of grouped-query attention over the "
               "paged cache: query (B, 1, H*D), key/value (B, 1, Hkv*D) "
               "of the current token, pools (P, KVB, Hkv*D), lengths "
               "counting the token -> output (B, 1, H*D) + pools.  The "
               "paged kernel with query row i on KV span i // (H / Hkv) "
-              "on TPU, a lax gather elsewhere.  attrs: num_heads, "
-              "kv_heads, scale (as GQAPrefillAttention)")
+              "on TPU, a lax gather elsewhere.  " + _GQA_ATTRS)
 def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
     from . import pallas_kernels as pk
     from .attention import decode_attention, paged_cache_update
 
-    q, k, v, k_pool, v_pool, table, lengths = inputs
+    q, k, v, k_pool, v_pool, table, lengths = inputs[:7]
     H, Hkv = _gqa_heads(attrs, q, k)
     if q.shape[1] != 1:
         raise MXNetError(f"GQAPagedDecode feeds ONE position a step; "
                          f"got query {tuple(q.shape)}")
+    q, k = _gqa_rotated(attrs, inputs, H, Hkv)
     q = _gqa_scaled(attrs, q, H)
+    window = attr_int(attrs.get("window", 0), 0)
     lengths = lengths.astype(jnp.int32)
     table = table.astype(jnp.int32)
     kp, vp = paged_cache_update(k_pool, v_pool, k, v, table, lengths)
     if pk.paged_enabled(kp.shape[2]):
         out = pk._paged_attention(q, kp, vp, (), table, lengths - 1, H,
-                                  kv_heads=Hkv)
+                                  kv_heads=Hkv, window=window)
         return [out, kp, vp]
     B, MB = table.shape
     KVB = kp.shape[1]
     kg = _repeat_heads(kp[table].reshape(B, MB * KVB, -1), H, Hkv)
     vg = _repeat_heads(vp[table].reshape(B, MB * KVB, -1), H, Hkv)
-    out = decode_attention(q.reshape(B, 1, H, -1), kg, vg, lengths, KVB)
+    out = decode_attention(q.reshape(B, 1, H, -1), kg, vg, lengths, KVB,
+                           window)
     return [out.reshape(q.shape), kp, vp]
 
 
@@ -659,16 +722,20 @@ def moe_dispatch(topi, valid, first, held, tm):
             sizes[:held])
 
 
+EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
 def moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert, n_used,
-                tm):
+                tm, act="silu"):
     """The held experts on the dispatched rows -> (M, d) float32 (rows
-    of unused tiles hold anything)."""
+    of unused tiles hold anything).  ``act``: the gate's activation."""
     from . import pallas_hybrid as ph
     from . import pallas_kernels as pk
 
     xs = x2[row_token]
     if pk.enabled():
-        h = ph.moe_gmm_gate_up(xs, w_gate, w_up, tile_expert, n_used, tm)
+        h = ph.moe_gmm_gate_up(xs, w_gate, w_up, tile_expert, n_used, tm,
+                               act)
         return ph.moe_gmm_down(h, w_down, tile_expert, n_used, tm)
     e = jnp.repeat(tile_expert, tm)
 
@@ -676,7 +743,7 @@ def moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert, n_used,
         return jnp.einsum("mk,mkn->mn", a, w[e],
                           preferred_element_type=jnp.float32)
 
-    h = (jax.nn.silu(mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
+    h = (EXPERT_ACTS[act](mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
     return mm(h, w_down)
 
 
@@ -687,9 +754,14 @@ def _moe_infer(attrs, in_shapes):
     return in_shapes, [tuple(d), (len(MOE_COUNTERS),)], []
 
 
+_MOE_ARGS = ("data", "router_weight", "gate_weight", "up_weight",
+             "down_weight", "lengths", "counters")
+
+
 @register("MoEFFN",
-          arg_names=("data", "router_weight", "gate_weight", "up_weight",
-                     "down_weight", "lengths", "counters"),
+          arg_names=lambda attrs: _MOE_ARGS + (
+              ("router_data",)
+              if attr_bool(attrs.get("router_data", False), False) else ()),
           out_names=("output", "new_counters"), infer_shape=_moe_infer,
           doc="The routed experts' part of a mixture-of-experts layer, "
               "for the experts HELD here: data (B, S, d); router_weight "
@@ -700,16 +772,25 @@ def _moe_infer(attrs, in_shapes):
               "sum_top s; score='softmax_topk': the top_k largest logits, "
               "weights their softmax (float32 either way); "
               "output = sum over a token's chosen experts that are held "
-              "here of w_e E_e(x), E_e = W_down (SiLU(W_gate x) * W_up "
-              "x).  What the other experts would add belongs to other "
+              "here of w_e E_e(x), E_e = W_down (act(W_gate x) * W_up "
+              "x), act='silu' (default) or 'relu'.  router_data=1: an "
+              "eighth input, router_data (B, S, d), is what the router "
+              "scores in place of data (a router that reads the block's "
+              "input, before attention).  What the other experts would "
+              "add belongs to other "
               "chips and is left out.  No pair is dropped.  lengths "
               "(B,) masks padding (step=1: rows with lengths 0; step=0: "
               "positions >= lengths).  counters (4,) int32 — pairs "
               "computed here, pairs left elsewhere, held experts hit, "
               "the largest expert's load — is added to where count=1.  "
-              "attrs: top_k, first_expert, step, count, score")
+              "attrs: top_k, first_expert, step, count, score, act, "
+              "router_data")
 def _moe_ffn(op_ctx, attrs, inputs, aux):
-    x, router_w, w_gate, w_up, w_down, lengths, counters = inputs
+    x, router_w, w_gate, w_up, w_down, lengths, counters = inputs[:7]
+    act = str(attrs.get("act", "silu"))
+    if act not in EXPERT_ACTS:
+        raise MXNetError(f"MoEFFN: act {act!r} is none of "
+                         f"{tuple(EXPERT_ACTS)}")
     top_k = attr_int(attrs.get("top_k", 1), 1)
     first = attr_int(attrs.get("first_expert", 0), 0)
     step = attr_bool(attrs.get("step", False), False)
@@ -724,13 +805,14 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
     valid = (jnp.broadcast_to(n[:, None] > 0, (B, S)) if step
              else jnp.arange(S)[None, :] < n[:, None]).reshape(-1)
     x2 = x.reshape(B * S, d)
-    topi, wts = moe_route(x2, router_w, top_k,
+    routed = inputs[7].reshape(B * S, d) if len(inputs) > 7 else x2
+    topi, wts = moe_route(routed, router_w, top_k,
                           str(attrs.get("score", "sigmoid")))
     tm = _tile_rows(B * S * min(top_k, held))
     here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
         topi, valid, first, held, tm)
     ys = moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert,
-                     n_used, tm)
+                     n_used, tm, act)
     got = ys[jnp.minimum(pair_row, ys.shape[0] - 1)]       # (N, k, d)
     y = jnp.sum(jnp.where(here[..., None], got * wts[..., None], 0.0),
                 axis=1)

@@ -707,7 +707,7 @@ def _gmm_maps(nk):
 
 
 def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
-                        accg, accu, *, nk):
+                        accg, accu, *, nk, act):
     del te_ref
     t, kk = pl.program_id(0), pl.program_id(1)
     used = t < nu_ref[0]
@@ -728,7 +728,8 @@ def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
     @pl.when(used & (kk == nk - 1))
     def _out():
         g = accg[...]
-        o_ref[...] = (g * jax.nn.sigmoid(g) * accu[...]).astype(o_ref.dtype)
+        g = jnp.maximum(g, 0.0) if act == "relu" else g * jax.nn.sigmoid(g)
+        o_ref[...] = (g * accu[...]).astype(o_ref.dtype)
 
 
 def _gmm_down_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, acc, *, nk):
@@ -754,11 +755,11 @@ def _k_block(K, want):
     return want if K % want == 0 else K
 
 
-def moe_gmm_gate_up(x, w_gate, w_up, tile_expert, n_used, tm):
+def moe_gmm_gate_up(x, w_gate, w_up, tile_expert, n_used, tm, act="silu"):
     """x (M, K) rows sorted by expert in tiles of ``tm``; w_gate, w_up
-    (E, K, N) -> SiLU(x W_gate[e]) * (x W_up[e]), (M, N) in x.dtype,
+    (E, K, N) -> act(x W_gate[e]) * (x W_up[e]), (M, N) in x.dtype,
     for the rows of the first ``n_used[0]`` tiles (the rest is not
-    written)."""
+    written).  ``act``: ``silu`` or ``relu`` (static)."""
     M, K = x.shape
     N = w_gate.shape[2]
     tk = _k_block(K, 512)
@@ -773,13 +774,13 @@ def moe_gmm_gate_up(x, w_gate, w_up, tile_expert, n_used, tm):
         scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32),
                         pltpu.VMEM((tm, N), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_gmm_gate_up_kernel, nk=nk),
+        functools.partial(_gmm_gate_up_kernel, nk=nk, act=act),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=_compiler_params(
             "arbitrary", "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-        name="moe_gmm_gate_up",
+        name="moe_gmm_gate_up" if act == "silu" else f"moe_gmm_gate_up_{act}",
     )(tile_expert, n_used, x, w_gate, w_up)
 
 

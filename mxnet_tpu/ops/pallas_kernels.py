@@ -874,6 +874,123 @@ def _mha_fwd(q, k, v, causal, block_size):
     return o[:, :Tq], lse[:, :Tq]
 
 
+# ---------------------------------------------------------------------------
+# Windowed flash MHA — causal attention in which a query sees itself and
+# the W - 1 keys before it.  The normalized kernel above with a lower
+# bound beside the diagonal: a query tile walks only the key tiles its
+# band [first query - W + 1, last query] touches (the grid's key axis is
+# as long as the widest band, counted from the band's first tile), masks
+# the two edge tiles, and never fetches a tile outside the band.  Grouped
+# queries need no repeated K/V: query head h reads KV head h // group
+# through the index map.  Forward only (the family that has windows
+# serves, it does not train).
+# ---------------------------------------------------------------------------
+
+
+def _window_first_tile(qi, block_q, block_k, window):
+    """The key tile that holds the lowest key query tile ``qi`` sees."""
+    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+
+
+def _mha_window_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
+                       block_q, block_k, tk_valid, scale, nk, window):
+    qi = pl.program_id(1)
+    step = pl.program_id(2)
+    kj = _window_first_tile(qi, block_q, block_k, window) + step
+    last_kj = jnp.minimum(nk - 1, (qi * block_q + block_q - 1) // block_k)
+
+    @pl.when(step == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    @pl.when(kj <= last_kj)
+    def _compute():
+        q = q_ref[0]
+        k = k_ref[0]
+        v = v_ref[0]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        k_pos = kj * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        q_pos = qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 0)
+        valid = (k_pos < tk_valid) & (k_pos <= q_pos) \
+            & (k_pos > q_pos - window)
+        s = jnp.where(valid, s, -jnp.inf)
+        m_prev = m_ref[:, 0]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.where(valid, jnp.exp(s - m_safe[:, None]), 0.0)
+        alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
+        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
+        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        pv = jax.lax.dot_general(p.astype(v.dtype), v,
+                                 (((1,), (0,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
+        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+
+    @pl.when(kj == last_kj)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+                    ).astype(o_ref.dtype)
+
+
+def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
+    """Causal attention under a sliding window: q (B·H, T, D); k, v
+    (B·Hkv, T, D), query head h on KV head ``h // (H / Hkv)``; query i
+    sees keys ``i - window + 1 .. i`` -> (B·H, T, D) in q.dtype."""
+    BH, T, D = q.shape
+    window = int(window)
+    group = int(heads) // int(kv_heads)
+    if window < 1 or int(heads) % int(kv_heads) \
+            or k.shape[0] * group != BH:
+        raise MXNetError(
+            f"flash_mha_window: window {window} must be >= 1 and q "
+            f"{tuple(q.shape)} hold {heads} query heads over the "
+            f"{kv_heads} KV heads of k {tuple(k.shape)}")
+    bq, bk = _mha_blocks(0, T, T)       # the tiles flash_mha picks itself
+    qf = _pad_to(q, 1, bq)
+    kf = _pad_to(k, 1, bk)
+    vf = _pad_to(v, 1, bk)
+    nq, nk = qf.shape[1] // bq, kf.shape[1] // bk
+    # the tiles the widest band touches: window + block_q - 1 keys
+    steps = min(nk, (window + bq - 2) // bk + 2)
+
+    def kv_map(bh, qi, step):
+        kj = _window_first_tile(qi, bq, bk, window) + step
+        last = jnp.minimum(nk - 1, (qi * bq + bq - 1) // bk)
+        # past the band the index stands still: no tile is fetched
+        return ((bh // heads) * kv_heads + (bh % heads) // group,
+                jnp.minimum(kj, last), 0)
+
+    kern = functools.partial(
+        _mha_window_kernel, block_q=bq, block_k=bk, tk_valid=T,
+        scale=1.0 / float(D) ** 0.5, nk=nk, window=window)
+    o = pl.pallas_call(
+        kern,
+        grid=(BH, nq, steps),
+        in_specs=[
+            _vmem_spec((1, bq, D), lambda bh, qi, step: (bh, qi, 0)),
+            _vmem_spec((1, bk, D), kv_map),
+            _vmem_spec((1, bk, D), kv_map),
+        ],
+        out_specs=_vmem_spec((1, bq, D), lambda bh, qi, step: (bh, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32),
+                        pltpu.VMEM((bq, 128), jnp.float32)],
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "arbitrary",
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="flash_fwd_window",
+    )(qf, kf, vf)
+    return o[:, :T]
+
+
 def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
     BH, Tq, D = q.shape
     Tk = k.shape[1]
@@ -1573,8 +1690,7 @@ def _paged_pages_per_chunk(w, heads, kv_heads, d, kvb, table_pages, q_bytes,
     in VMEM — the ONE place K is derived.
 
     The spread query is ``rows`` x ``lanes`` = W·HP x Hkv·D (HP: the
-    heads in whole sublane tiles of a 16-bit q; grouped queries come in
-    whole tiles already).  It is held three times over whatever the
+    heads in whole sublane tiles of a 16-bit q).  It is held three times over whatever the
     chunk: itself, the float32 accumulator and the P·V product (a
     fourth, q in float32, over quantized pools).  A chunk of C = K·KVB keys adds two buffers each
     of K and V pages and the (rows, C) scores, probabilities and mask;
@@ -1584,7 +1700,7 @@ def _paged_pages_per_chunk(w, heads, kv_heads, d, kvb, table_pages, q_bytes,
     ``_PAGED_CHUNK_KEYS`` keys (and the row's table) that keeps the sum
     inside ``_PAGED_VMEM_BUDGET``, halved until it does; a kernel that
     is over at K = 1 is the caller's to refuse."""
-    hp = heads if kv_heads != heads else -(-heads // 16) * 16
+    hp = -(-heads // 16) * 16
     rows, lanes = w * hp, kv_heads * d
     fixed = rows * lanes * (q_bytes + 4 + 4 + 4 * quant)
 
@@ -1603,7 +1719,7 @@ def _paged_pages_per_chunk(w, heads, kv_heads, d, kvb, table_pages, q_bytes,
 
 
 def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
-                  kvb, pages, mb, w, h, d, hp, quant, g=1):
+                  kvb, pages, mb, w, h, d, hp, quant, g=1, window=0):
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, k_buf, v_buf, sem, turn_scr, qx_scr, acc_scr, m_scr, l_scr = rest
@@ -1632,6 +1748,19 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
         # keys some window position of the row can see
         return jnp.clip(start_ref[row] + w, 0, mb * kvb)
 
+    def lowest(row):
+        # the lowest key the row's query sees: 0, or under a sliding
+        # window (w = 1) the query's own position less window - 1
+        if not window:
+            return 0
+        return jnp.maximum(start_ref[row] + 1 - window, 0)
+
+    def first_chunk(row):
+        # the walk starts at the chunk that holds that key: the pages
+        # behind it are never touched (their table entries may be the
+        # scratch page: the engine gives such pages back)
+        return lowest(row) // keys
+
     def copies(row, chunk, slot, op):
         # the chunk's live pages, a copy of K and one of V a page, page
         # i into span i of the slot's buffers.  ``op`` is "start" or
@@ -1647,7 +1776,8 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
                     pool.at[pid], buf.at[slot, i], sem.at[j, slot]), op)()
 
         jax.lax.fori_loop(
-            0, jnp.clip(pl.cdiv(live(row), kvb) - first, 0, pages), page,
+            jnp.clip(lowest(row) // kvb - first, 0, pages),
+            jnp.clip(pl.cdiv(live(row), kvb) - first, 0, pages), page,
             None)
 
     @pl.when(b == 0)
@@ -1662,12 +1792,14 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
     # which a row with no chunk of its own starts at once, and row 0
     # starts for itself
     turn = turn_scr[0]
-    n = pl.cdiv(live(b), keys)
+    c0 = first_chunk(b)
+    n = pl.cdiv(live(b), keys) - c0
     after = jnp.minimum(b + 1, nb - 1)
 
     @pl.when((b == 0) | ((n == 0) & (b + 1 < nb)))
     def _first_chunk():
-        copies(jnp.where(n == 0, after, b), 0, turn % 2, "start")
+        row = jnp.where(n == 0, after, b)
+        copies(row, first_chunk(row), turn % 2, "start")
 
     if g > 1:
         # q arrives as (W·Hq, D) rows, one query head each: every
@@ -1686,13 +1818,15 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
     m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
     l_scr[...] = jnp.zeros_like(l_scr)
 
-    def chunk_step(c, _):
-        slot = (turn + c) % 2
-        more = c + 1 < n
+    def chunk_step(i, _):
+        slot = (turn + i) % 2
+        more = i + 1 < n
+        c = c0 + i                  # the chunk's place in the row's table
 
         @pl.when(more | (b + 1 < nb))
         def _ahead():
-            copies(jnp.where(more, b, after), jnp.where(more, c + 1, 0),
+            copies(jnp.where(more, b, after),
+                   jnp.where(more, c + 1, first_chunk(after)),
                    1 - slot, "start")
 
         copies(b, c, slot, "wait")
@@ -1736,6 +1870,8 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
         row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         win = sum((row >= i * hp).astype(jnp.int32) for i in range(1, w))
         valid = k_pos < start_ref[b] + 1 + win
+        if window:
+            valid &= k_pos >= lowest(b)
         s = jnp.where(valid, s, -jnp.inf)
         m_prev = m_scr[:, :1]                         # (W·HP, 1)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -1771,7 +1907,7 @@ def _paged_kernel(table_ref, start_ref, q_ref, k_hbm, v_hbm, *rest, scale,
 
 
 def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
-                     num_heads, kv_heads=None):
+                     num_heads, kv_heads=None, window=0):
     """q (B, W, H·D) at absolute positions ``start[b] + i``; pools
     (P, KVB, H·D); scales is () or (k_scale, v_scale), each
     (P, KVB, H) float32.  ``kv_heads`` < ``num_heads``: grouped
@@ -1779,7 +1915,11 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
     ``i // (num_heads // kv_heads)``: the same walk, chunks and
     online-softmax state, the spread query a row per QUERY head on its
     KV head's span of the (kv_heads·D)-lane page rows, so one matmul a
-    chunk still gives every head's scores."""
+    chunk still gives every head's scores (query heads that fill no
+    whole sublane tiles are padded to them with rows of zeros, which
+    are dropped on the way out).  ``window`` > 0 (W = 1, unquantized):
+    the query sees its own key and the ``window - 1`` before it; the
+    walk starts at the chunk that holds the lowest of them."""
     B, W, HD = q.shape
     Hq = int(num_heads)
     Hkv = Hq if kv_heads is None else int(kv_heads)
@@ -1793,15 +1933,20 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
         q_block = (1, W, HD)
     else:
         what = f"{Hq} query heads over {Hkv} KV heads x {D}"
-        if quant or Hq % Hkv or Hq % 16 or k_pool.shape[2] != KD:
+        if quant or Hq % Hkv or k_pool.shape[2] != KD:
             raise MXNetError(
                 f"paged_attention: {what} wants unquantized (P, KVB, "
-                f"{KD}) pools, {Hkv} | {Hq} and whole sublane tiles of "
-                f"query heads ({Hq} % 16 == 0); got pools "
+                f"{KD}) pools and {Hkv} | {Hq}; got pools "
                 f"{tuple(k_pool.shape)}, scales {len(scales)}")
-        # q enters and leaves as (B, W·Hq, D) rows — the (B, W, Hq·D)
+        # q enters and leaves as (B, W·HP, D) rows, HP the query heads
+        # in whole sublane tiles — at Hq % 16 == 0 the (B, W, Hq·D)
         # activation seen through a free reshape
-        q_block = (1, W * Hq, D)
+        q_block = (1, W * (-(-Hq // 16) * 16), D)
+    if window and (W != 1 or quant):
+        raise MXNetError(
+            f"paged_attention: a sliding window of {window} keys is built "
+            f"for the one-query decode step over unquantized pools; got "
+            f"a {W}-row window, scales {len(scales)}")
     if KD % 128 and not _interpret():
         raise MXNetError(
             f"paged_attention: {what} are page rows of {KD} lanes, and the "
@@ -1831,12 +1976,16 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
             f"over tp")
     kern = functools.partial(_paged_kernel, scale=1.0 / float(D) ** 0.5,
                              kvb=KVB, pages=pages, mb=MB, w=W, h=Hkv, d=D,
-                             hp=HP, quant=quant, g=Hq // Hkv)
+                             hp=HP, quant=quant, g=Hq // Hkv,
+                             window=int(window))
 
     def rows_spec():
         return _vmem_spec(q_block, lambda b, tr, sr: (b, 0, 0))
 
     table = block_table.astype(jnp.int32)
+    rows_in = q.reshape((B,) + q_block[1:]) if Hkv == Hq or HP == Hq else \
+        jnp.pad(q.reshape(B, W, Hq, D),
+                ((0, 0), (0, 0), (0, HP - Hq), (0, 0))).reshape(B, rows, D)
     if quant:
         # a (KVB, H) page of scales has no lane-aligned slice to copy
         # by hand, so the scales of a row's table are gathered out here
@@ -1862,6 +2011,10 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
             pltpu.VMEM((rows, 128), jnp.float32),
             pltpu.VMEM((rows, 128), jnp.float32)],
     )
+    # a windowed walk is sized by the window, not the context: the
+    # readers of the full walk must not take it for one
+    kernel_name = "paged_window" if window else \
+        "paged_attention_q" if scales else "paged_attention"
     # rows in order ("arbitrary"): a row starts the next row's first
     # copies and hands on which buffer they went to
     out = pl.pallas_call(
@@ -1870,9 +2023,10 @@ def _paged_attention(q, k_pool, v_pool, scales, block_table, start,
         out_shape=jax.ShapeDtypeStruct((B,) + q_block[1:], q.dtype),
         compiler_params=_compiler_params("arbitrary"),
         interpret=_interpret(),
-        name="paged_attention_q" if scales else "paged_attention",
-    )(table, start.astype(jnp.int32), q.reshape((B,) + q_block[1:]),
-      k_pool, v_pool, *scales)
+        name=kernel_name,
+    )(table, start.astype(jnp.int32), rows_in, k_pool, v_pool, *scales)
+    if Hkv != Hq and HP != Hq:
+        out = out.reshape(B, W, HP, D)[:, :, :Hq]
     return out.reshape(B, W, HD)
 
 

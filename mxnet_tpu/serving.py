@@ -920,7 +920,8 @@ class _Stream:
                  "resume", "t_submit", "t_admit", "trace", "t_enqueue",
                  "cached_len", "await_first", "t_chunk0", "slo_class",
                  "canary", "cost", "migrate", "tenant", "adapter",
-                 "adapter_bucket", "adapter_slot", "slot", "keep_state")
+                 "adapter_bucket", "adapter_slot", "slot", "keep_state",
+                 "wblocks", "wfirst")
 
     def __init__(self, sid, prompt, max_new, temp, eos, future, seed,
                  trace=None, slo_class="interactive", canary=False,
@@ -952,6 +953,11 @@ class _Stream:
         self.adapter_bucket = None    # rank bucket (set on acquire)
         self.adapter_slot = None      # pool slot id (set on acquire)
         self.slot = 0                 # recurrent-state slot held (0: none)
+        # windowed layers' pages, by logical block like ``blocks``; the
+        # entries before ``wfirst`` lie behind the window: given back,
+        # or never taken (0, the scratch page)
+        self.wblocks: List[int] = []
+        self.wfirst = 0
         self.keep_state = False       # resolve with the slot's state too
         self.cost = _slo.CostRecord(sid, slo_class, canary,
                                     tenant=tenant, adapter_id=adapter)
@@ -1023,14 +1029,22 @@ class DecodeEngine:
           kv_dtype=, lora=)`` builds (it refuses the rest).  The first
           two are required; ``prefix_cache`` and ``prefill_chunk`` need
           ``prefix_prefill`` and ``spec_tokens`` needs ``verify``, and
-          all three need every layer's state in pages;
+          all three need every layer's state in ordinary pages (no
+          slots, no windowed pools);
         * ``feeds``: the symbols' arguments that are no parameter:
-          of ``data positions lengths block_table start slots``;
+          of ``data positions lengths block_table start slots
+          window_table``;
         * ``pools(cache_blocks, kv_block, slots, dtype, kv_dtype)``:
           the per-stream state the programs carry, ``(name, shape,
           dtype, fill)`` in the symbols' output order, and
           ``pool_kinds(kv_dtype)``, the kind of each beside it:
           ``pages`` / ``scales`` (rows by page; a migration frame),
+          ``window_pages`` (K/V pages of layers that see only the last
+          ``window`` keys — the spec's ``window``: a second page-id
+          space with its own allocator, ``pools(..., window_blocks=)``
+          pages a pool, addressed through the ``window_table`` feed (as
+          wide as ``block_table``); a stream holds the pages its window
+          still reaches and gives the others back as it grows),
           ``slots`` (rows by the ``slots`` feed, one a stream; what
           ``return_state`` reads) / ``slots_aux`` (the same, read by
           the programs only), ``counters`` (``ops.hybrid.MOE_COUNTERS``,
@@ -1124,9 +1138,11 @@ class DecodeEngine:
         kv_store_dtype = kv_storage_dtype(self._kv_dtype)  # may raise
         self._pool_kinds = tuple(model.pool_kinds(self._kv_dtype))
         slots = "slots" in self._pool_kinds
+        windowed = "window_pages" in self._pool_kinds
         # a suffix prefill continues from pages alone; a verify step
-        # rolls back pages alone
-        suffix = "prefix_prefill" in model.phases and not slots
+        # rolls back pages alone: pages that are all still there
+        plain = not slots and not windowed
+        suffix = "prefix_prefill" in model.phases and plain
         if prefix_cache is None and not suffix \
                 and get_env("MXNET_SERVING_PREFIX_CACHE", None, str) is None:
             prefix_cache = 0  # the catalog's default (on): where carried
@@ -1220,7 +1236,7 @@ class DecodeEngine:
                 ("prefix_cache", self._prefix_on, suffix),
                 ("prefill_chunk", self._chunk, suffix),
                 ("spec_tokens", self._spec_k,
-                 "verify" in model.phases and not slots),
+                 "verify" in model.phases and plain),
                 (f"kv_dtype={self._kv_dtype!r}", True,
                  self._kv_dtype in model.kv_dtypes),
                 (f"tp={self._tp}", self._tp > 1, model.partition_rules),
@@ -1231,7 +1247,11 @@ class DecodeEngine:
                     f"{feature} is not built for {model.name}"
                     + (": a layer's per-stream state lives in a slot, "
                        "which this feature would have to share, cut, "
-                       "roll back, quantize or shard" if slots else ""))
+                       "roll back, quantize or shard" if slots else
+                       ": a windowed layer's pool gives back the pages "
+                       "behind its window, which this feature would "
+                       "have to share, continue from, roll back, "
+                       "quantize or shard" if windowed else ""))
         if devices is None:
             devices = os.environ.get("MXNET_SERVING_DEVICES") or None
         if isinstance(devices, str):
@@ -1335,6 +1355,18 @@ class DecodeEngine:
         if int(cache_blocks) < 2:
             raise MXNetError(f"cache_blocks {cache_blocks} must be >= 2")
         self._alloc = BlockAllocator(int(cache_blocks), self._kv_block)
+        # the second kind of pages: a windowed layer's pools, their own
+        # page ids.  A stream holds the blocks its window reaches — at
+        # most the window's blocks and one (a window that starts inside
+        # a block) — and one more while a step crosses a page boundary
+        # before the block behind the window is given back (gauges
+        # ``serving.window.cache_util`` and the family beside it)
+        self._window = int(model.window) if windowed else 0
+        self._walloc = BlockAllocator(
+            1 + self._max_streams * (
+                blocks_for_tokens(self._window, self._kv_block) + 2),
+            self._kv_block, gauge_prefix="serving.window") \
+            if windowed else None
         self._prefix = PrefixCache(self._alloc,
                                    policy=self._evict_policy) \
             if self._prefix_on else None
@@ -1449,8 +1481,10 @@ class DecodeEngine:
         # beside it: K/V pages ([k, v] or, quantized, [k, v, k_scale,
         # v_scale] a layer), slots, counters
         n_slots = 1 + self._max_streams
-        layout = model.pools(int(cache_blocks), self._kv_block, n_slots,
-                             self._np_dtype, self._kv_dtype)
+        layout = model.pools(
+            int(cache_blocks), self._kv_block, n_slots, self._np_dtype,
+            self._kv_dtype, **({"window_blocks": self._walloc.num_blocks}
+                               if windowed else {}))
         self._pool_names = tuple(n for n, _, _, _ in layout)
         self._slot_alloc = SlotAllocator(self._max_streams) \
             if slots else None
@@ -1460,10 +1494,12 @@ class DecodeEngine:
         # counters among them from another thread, under this lock
         self._pools_lock = threading.Lock()
         # the runtime tail of every program, after the pools: the slot
-        # ids (a spec with slots), then per rank bucket the adapter
+        # ids (a spec with slots), the windowed pools' table (a spec
+        # with such pools), then per rank bucket the adapter
         # slabs + slot vector — RUNTIME args (like the pools), never
         # baked params: publish stays drain-free
-        self._runtime_names = (("slots",) if slots else ()) + tuple(
+        self._runtime_names = (("slots",) if slots else ()) + (
+            ("window_table",) if windowed else ()) + tuple(
             f"adapter_{t}_r{rb}" for rb in self._lora or ()
             for t in ("a", "b", "slots"))
         feed = set(model.feeds) | set(self._pool_names) \
@@ -1648,11 +1684,13 @@ class DecodeEngine:
             raise MXNetError(
                 f"request needs {need} cache blocks but the pool only "
                 f"has {self._alloc.capacity}")
-        if prefill_only and self._slot_alloc is not None:
+        if prefill_only and (self._slot_alloc is not None
+                             or self._walloc is not None):
             raise MXNetError(
                 f"prefill_only page export is not built for "
                 f"{self._spec.name}: a stream's state is pages AND a "
-                f"slot, and only pages have a wire format")
+                f"slot or a windowed pool's pages, and only whole "
+                f"tables of ordinary pages have a wire format")
         if return_state and (self._slot_alloc is None or prefill_only):
             raise MXNetError(
                 "return_state reads a stream's slot at retirement: the "
@@ -1914,9 +1952,10 @@ class DecodeEngine:
                 self._pools = self._pools[:at] + (zero,) \
                     + self._pools[at + 1:]
 
-    def _state_stats(self) -> dict:
+    def _state_stats(self, c) -> dict:
         """Slots and routing: the second kind of per-stream state, and
-        what the expert layers did with the decode steps' tokens.  The
+        what the expert layers did with the decode steps' tokens (``c``:
+        the engine's counters).  The
         routing counters live in a device array the decode step carries
         and are read HERE only (under the lock the dispatch holds: the
         array is donated to the next step)."""
@@ -1926,6 +1965,20 @@ class DecodeEngine:
             (self._slot_alloc.num_slots, self._slot_alloc.live)
         out = {"state_slots": num, "state_slots_live": live,
                "state_pool_bytes": self._state_pool_bytes}
+        # the windowed pools: pages there are, pages held now, and over
+        # the decode steps the pages their rows held beside the pages
+        # the same rows hold in the ordinary pools (what a windowed
+        # layer would hold with no window): 1.0 = nothing given back
+        wa = self._walloc
+        out.update(
+            window_pages=wa.capacity if wa else 0,
+            window_pages_live=wa.used_blocks if wa else 0,
+            window_pages_held_share=(
+                c.get("window_page_steps", 0) / c["page_steps"]
+                if wa and c.get("page_steps") else None),
+            **{k: int(c.get(k, 0)) for k in (
+                "window_pages_released", "window_context_tokens",
+                "window_prefill_pairs")})
         if self._counters_at is None:
             out.update({k: 0 for k in MOE_COUNTERS})
         else:
@@ -1972,7 +2025,7 @@ class DecodeEngine:
         out["cache_blocks_cached"] = self._alloc.parked_blocks
         out["shared_blocks"] = self._alloc.shared_blocks
         out["kv_dtype"] = self._kv_dtype
-        out.update(self._state_stats())
+        out.update(self._state_stats(c))
         out["prefix_cache"] = int(self._prefix_on)
         if self._prefix is not None:
             out.update(self._prefix.stats())
@@ -2116,6 +2169,7 @@ class DecodeEngine:
             if s.blocks:
                 self._release_pages(s.blocks)
                 s.blocks = []
+            self._release_window(s)
             self._release_slot(s)
             self._release_adapter(s)
             if s.future.set_running_or_notify_cancel():
@@ -2227,7 +2281,9 @@ class DecodeEngine:
                     return verify_sample(base, logits, tokens,
                                          lengths - start[0], temps, seeds,
                                          steps), tuple(outs[1:])
-                if phase == "decode":
+                if logits.shape[1] == 1:
+                    # a decode step's row; a prefill that computed the
+                    # head at each prompt's last row alone
                     last = logits[:, 0, :]
                 else:  # the last real row of the prompt (of the suffix)
                     last = logits[
@@ -2254,7 +2310,7 @@ class DecodeEngine:
                       "pools": self._spec_of(self._pools)}
             row = self._arg_spec((rows,), i32)  # every other: one a row
             specs = tuple(shaped.get(n, row) for n in named) \
-                + self._runtime_specs(rows)
+                + self._runtime_specs(rows, mb)
             # the name the program carries in a device trace
             # (``jit_step_decode_b48x64``, ``jit_prefill_t1024``)
             program.__name__ = program.__qualname__ = name.format(*dims)
@@ -2269,13 +2325,15 @@ class DecodeEngine:
             self.compiles[key] = self.compiles.get(key, 0) + 1
             return exe
 
-    def _runtime_specs(self, bb: int) -> tuple:
+    def _runtime_specs(self, bb: int, mb: int) -> tuple:
         """AOT specs of the runtime tail (``self._runtime_names``) at
-        batch bucket ``bb`` — slab shapes are fixed by the adapter pool,
-        so the executable matrix gains NO new dimension from
-        multi-tenancy."""
+        batch bucket ``bb`` over tables of ``mb`` pages — slab shapes
+        are fixed by the adapter pool, so the executable matrix gains NO
+        new dimension from multi-tenancy."""
         vec = self._arg_spec((bb,), np.dtype(np.int32))
         out = [vec] if self._slot_alloc is not None else []
+        if self._walloc is not None:
+            out.append(self._arg_spec((bb, mb), np.dtype(np.int32)))
         if self._lora:
             slabs = self._adapter_pool.slabs()
             for j in range(len(self._lora)):
@@ -2283,22 +2341,29 @@ class DecodeEngine:
                         self._spec_of(slabs[2 * j + 1]), vec]
         return tuple(out)
 
-    def _runtime_args(self, streams, bb: int) -> tuple:
+    def _runtime_args(self, streams, bb: int, mb: int) -> tuple:
         """Call-time runtime tail for one step.  The slot each row's
         stream holds (pad rows: 0, the scratch slot), staged like the
-        other feeds; then, per rank bucket, the adapter pool's CURRENT
+        other feeds; the windowed pools' table, ``mb`` wide (pad rows
+        and blocks behind a row's window: 0, the scratch page); then,
+        per rank bucket, the adapter pool's CURRENT
         slabs (fetched once — an atomic snapshot, so a concurrent
         publish lands next step, never mid-step) and the slot vector
         gathered from the batch: rows without an adapter — pad rows
         included — carry slot 0, the exact no-op."""
+        from .io import stage_array
+
         out = []
         if self._slot_alloc is not None:
-            from .io import stage_array
-
             vec = np.zeros(bb, np.int32)
             for i, s in enumerate(streams):
                 vec[i] = s.slot
             out.append(stage_array(vec, self._device))
+        if self._walloc is not None:
+            table = np.zeros((bb, mb), np.int32)
+            for i, s in enumerate(streams):
+                table[i, :len(s.wblocks)] = s.wblocks
+            out.append(stage_array(table, self._device))
         if self._lora:
             import jax
 
@@ -2491,6 +2556,12 @@ class DecodeEngine:
                 avail = self._alloc.free_blocks - parked_matched
                 if avail < min(need + 1, max(lifetime_new, 1)):
                     return  # not enough cache: hold the FIFO line
+                if self._walloc is not None \
+                        and self._walloc.free_blocks < min(
+                            self._window_need(len(seq)) + 1,
+                            max(self._window_need(
+                                len(s.prompt) + s.max_new), 1)):
+                    return  # the windowed pools are short: the same hold
                 self._pending.pop(pick)
                 self._admitting = s  # visible to _fail_outstanding
             # On failure _admitting must STAY set until the loop's
@@ -2519,6 +2590,23 @@ class DecodeEngine:
                     f"unavailable after the capacity check")
             s.cost.book_pages(len(s.blocks))
             s.blocks = pages + new_pages
+            if self._walloc is not None:
+                # the windowed pools' pages of the prompt: none for the
+                # blocks the first decode step's window no longer
+                # reaches (their K/V goes to the scratch page)
+                with profiler.scope("serving.window_alloc", "serving",
+                                    args={"sids": s.sid}):
+                    held = self._window_need(len(seq))
+                    wpages = self._walloc.alloc(held, owner=s.sid)
+                    if wpages is None:  # pragma: no cover - defensive
+                        raise MXNetError(
+                            f"admission raced the windowed pools' "
+                            f"allocator: {held} pages unavailable after "
+                            f"the capacity check")
+                    s.wfirst = len(s.blocks) - held
+                    s.wblocks = [0] * s.wfirst + wpages
+                self._count("window_prefill_pairs",
+                            self._band_pairs(len(seq)))
             if cached == len(seq) and cached > 0:
                 self._full_hit(s, seq)
                 if s.migrate:
@@ -2587,7 +2675,7 @@ class DecodeEngine:
                 self._params,
                 *self._prompt_feeds(s, seq, done, end, tp, mb, s.blocks,
                                     True),
-                self._pools, *self._runtime_args([s], 1))
+                self._pools, *self._runtime_args([s], 1, mb))
         s.cost.flops_est += self._exe_flops.get(
             ("prefix_prefill", tp, mb), 0.0)
         return toks, tp
@@ -2659,7 +2747,7 @@ class DecodeEngine:
                         self._params,
                         *self._prompt_feeds(s, seq, 0, n, tp, mb, pages,
                                             False),
-                        self._pools, *self._runtime_args([s], 1))
+                        self._pools, *self._runtime_args([s], 1, mb))
                 with profiler.scope("serving.d2h_sync", "serving",
                                     args={"sids": s.sid}):
                     first = int(np.asarray(toks)[0])
@@ -2797,13 +2885,15 @@ class DecodeEngine:
         return sum(1 for p in v.blocks
                    if self._alloc.refcount(p) == 1)
 
-    def _alloc_with_preempt(self, s: _Stream,
-                            n: int) -> Optional[List[int]]:
+    def _alloc_with_preempt(self, s: _Stream, n: int,
+                            alloc=None) -> Optional[List[int]]:
         """Pages for active stream ``s``, preempting the youngest
         other stream when the pool (including evictable cached pages)
-        is exhausted.  None: ``s`` itself was failed and removed."""
+        is exhausted.  None: ``s`` itself was failed and removed.
+        ``alloc``: another pool's allocation (the windowed pools');
+        a preemption frees a victim's pages in every pool."""
         while True:
-            pages = self._palloc(n, owner=s.sid)
+            pages = (alloc or self._palloc)(n, owner=s.sid)
             if pages is not None:
                 return pages
             # a victim must be able to COME BACK: its resume
@@ -2820,6 +2910,7 @@ class DecodeEngine:
                 s.cost.book_pages(len(s.blocks))
                 self._release_pages(s.blocks)
                 s.blocks = []
+                self._release_window(s)
                 self._release_slot(s)
                 if not s.canary:
                     self._slo.observe_avail(s.slo_class, False)
@@ -2865,7 +2956,49 @@ class DecodeEngine:
             return False
         s.cost.book_pages(len(s.blocks))
         s.blocks.extend(pages)
+        if self._walloc is not None:
+            # the same logical blocks in the windowed pools
+            wpages = self._alloc_with_preempt(s, need, self._walloc.alloc)
+            if wpages is None:
+                return False
+            s.wblocks.extend(wpages)
         return True
+
+    # -- the windowed pools' pages --------------------------------------
+    def _window_first(self, length: int) -> int:
+        """The first logical block the query at position ``length``
+        (the next decode step's) still sees under the window: the
+        blocks before it are wholly behind ``length - window + 1``."""
+        return max(length - self._window + 1, 0) // self._kv_block
+
+    def _window_need(self, tokens: int) -> int:
+        """Windowed-pool pages a stream holds once ``tokens`` are
+        cached."""
+        return self._blocks_for(max(tokens, 1), self._kv_block) \
+            - self._window_first(tokens)
+
+    def _band_pairs(self, n: int) -> int:
+        """Query-key pairs a windowed layer's prefill of ``n`` tokens
+        needs: row i sees min(i + 1, window) keys."""
+        w = min(n, self._window)
+        return w * (w + 1) // 2 + (n - w) * self._window
+
+    def _release_window(self, s: _Stream, upto: Optional[int] = None):
+        """Give back the stream's windowed-pool pages of the logical
+        blocks before ``upto`` (all of them at retirement, preemption
+        and shutdown); returns how many."""
+        if self._walloc is None:
+            return 0
+        end = len(s.wblocks) if upto is None else min(upto, len(s.wblocks))
+        pages = [p for p in s.wblocks[s.wfirst:end] if p]
+        if pages:
+            self._walloc.free(pages)
+        if upto is None:
+            s.wblocks, s.wfirst = [], 0
+        elif end > s.wfirst:
+            s.wblocks[s.wfirst:end] = [0] * (end - s.wfirst)
+            s.wfirst = end
+        return len(pages)
 
     def _maybe_cow(self, s: _Stream) -> bool:
         """Copy-on-write probe before this step's cache write: if the
@@ -2907,6 +3040,7 @@ class DecodeEngine:
         victim.cost.book_pages(len(victim.blocks))
         self._release_pages(victim.blocks)
         victim.blocks = []
+        self._release_window(victim)
         self._release_slot(victim)  # recompute: re-prefill writes anew
         victim.length = 0
         victim.cached_len = 0
@@ -2952,6 +3086,7 @@ class DecodeEngine:
         if s.blocks:
             self._release_pages(s.blocks)
             s.blocks = []
+        self._release_window(s)
         result = np.asarray(s.generated, np.int32)
         if s.keep_state:
             with self._pools_lock:  # the pools are donated step by step
@@ -3087,11 +3222,11 @@ class DecodeEngine:
         Thread-safe; the splice itself runs on the scheduler thread.
         The Future resolves to the FULL generated token array
         (including tokens the exporter's prefill already emitted)."""
-        if self._slot_alloc is not None:
+        if self._slot_alloc is not None or self._walloc is not None:
             raise MXNetError(
                 f"page import is not built for {self._spec.name}: an "
                 f"imported stream would arrive without the state its "
-                f"slot holds")
+                f"slot or its windowed pools hold")
         if self._mesh is not None:
             raise MXNetError(
                 "KV page migration onto a tp/pp-meshed engine is not "
@@ -3365,7 +3500,7 @@ class DecodeEngine:
                      stage_array(start, dev), stage_array(lengths, dev),
                      stage_array(table, dev), stage_array(temps, dev),
                      stage_array(seeds, dev), stage_array(steps0, dev))
-            extra = self._runtime_args(streams, bb)
+            extra = self._runtime_args(streams, bb, mb)
         self._count("context_tokens", int(lengths.sum()))
         with profiler.scope(f"serving.verify_step.b{bb}x{mb}",
                             "serving",
@@ -3511,7 +3646,7 @@ class DecodeEngine:
         # one adapter snapshot serves both halves of a pipelined pair
         # (the batch composition is pinned, so the slot vectors are
         # identical; a concurrent publish lands at the next pair)
-        extra = self._runtime_args(streams, bb)
+        extra = self._runtime_args(streams, bb, mb)
         span_args = {"sids": self._sids(streams), "active": n,
                      "pipelined": pipeline}
         dev = self._device
@@ -3538,6 +3673,8 @@ class DecodeEngine:
                      stage_array(steps, dev))
         # the paged kernel's need: the live context this step attends
         self._count("context_tokens", int(lengths.sum()))
+        if self._walloc is not None:
+            self._count_window_step(streams, lengths)
         with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
@@ -3574,6 +3711,8 @@ class DecodeEngine:
                       stage_array(seeds, dev),
                       stage_array(steps2, dev))
         self._count("context_tokens", int(lengths2.sum()))
+        if self._walloc is not None:
+            self._count_window_step(streams, lengths2)
         with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
@@ -3597,6 +3736,16 @@ class DecodeEngine:
         self._count("d2h_syncs")
         t_done = time.perf_counter()
         self._absorb_step(streams, toks2, t_mid, t_done, bb, n, fl)
+
+    def _count_window_step(self, streams, lengths):
+        """A decode step's need in the windowed layers (the context
+        each row's window holds), and the pages its rows hold in the
+        windowed pools beside those they hold in the ordinary ones."""
+        self._count("window_context_tokens",
+                    int(np.minimum(lengths, self._window).sum()))
+        self._count("window_page_steps",
+                    sum(len(s.wblocks) - s.wfirst for s in streams))
+        self._count("page_steps", sum(len(s.blocks) for s in streams))
 
     def _absorb_step(self, streams, toks, t0, t_done, bb, n,
                      fl: float = 0.0):
@@ -3651,6 +3800,15 @@ class DecodeEngine:
                           "batch": bb, "active": n})
             if s.done():
                 retired.append(s)
+        if self._walloc is not None:
+            # a step that carried a row's window past a page boundary
+            # leaves a page wholly behind it: back to the windowed pool
+            behind = [(s, self._window_first(s.length)) for s in streams]
+            if any(first > s.wfirst for s, first in behind):
+                with profiler.scope("serving.window_release", "serving"):
+                    self._count("window_pages_released", sum(
+                        self._release_window(s, first)
+                        for s, first in behind))
         if retired:
             with self._lock:
                 for s in retired:

@@ -227,6 +227,13 @@ def test_zombie_late_answer_is_dropped_not_double_delivered(tmp_path):
         time.sleep(0.06)
         reps[0].kill()
         outs = _results(futs, timeout=30.0)
+        # the survivors may answer the retries before the zombie's worker
+        # is through the request it was serving when the freeze landed:
+        # wait until it holds every answer it still owes
+        deadline = time.monotonic() + 5.0
+        while len(reps[0]._held) < reps[0].inflight() \
+                and time.monotonic() < deadline:
+            time.sleep(0.005)
         held = len(reps[0]._held)
         assert held > 0, "zombie held nothing — the fault never fired"
         # now the zombie's held answers arrive late

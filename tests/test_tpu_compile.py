@@ -311,6 +311,47 @@ def test_paged_attention_at_the_cells_shapes(on_chip, one_chip, cell):
         q, kp, vp, (), t, s, Hq, kv_heads=Hkv), one_chip, *shapes)
 
 
+# The layer-list family's windowed layers at the mixed cell's widths:
+# 48 rows over 544-page tables, 28 query heads (no whole sublane tiles)
+# over 4 KV heads of 128, a window of 4,096 keys; the windowed pools
+# hold 258 pages a stream
+_MIXED = (48, 544, 28, 4, 128, 4096)
+
+
+def _kernel_names(text):
+    """The Mosaic kernels of a compiled program, by their HLO names."""
+    return [line.split(" = ")[0].strip() for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+@pytest.mark.parametrize("window", [0, _MIXED[-1]])
+def test_paged_attention_at_the_mixed_cells_shapes(on_chip, one_chip,
+                                                   window):
+    B, MB, Hq, Hkv, D, W = _MIXED
+    pages = 1 + B * (W // _KVB + 2 if window else MB)
+    pool = ((pages, _KVB, Hkv * D), bf16)
+    text = _compile(lambda q, kp, vp, t, s: pk._paged_attention(
+        q, kp, vp, (), t, s, Hq, kv_heads=Hkv, window=window), one_chip,
+        ((B, 1, Hq * D), bf16), pool, pool, ((B, MB), i32),
+        ((B,), i32)).as_text()
+    kernels = _kernel_names(text)
+    # the windowed walk carries a name the full walk's readers (a
+    # substring match on ``paged_attention``) do not find
+    assert len(kernels) == 1 and ("paged_window" in kernels[0]) == bool(
+        window) and ("paged_attention" in kernels[0]) != bool(window)
+
+
+@pytest.mark.parametrize("T", [8192, 1024])
+def test_flash_mha_window_at_the_mixed_cells_shapes(on_chip, one_chip, T):
+    _, _, Hq, Hkv, D, W = _MIXED
+    text = _compile(lambda q, k, v: pk.flash_mha_window(
+        q, k, v, W, Hq, Hkv), one_chip, ((Hq, T, D), bf16),
+        ((Hkv, T, D), bf16), ((Hkv, T, D), bf16)).as_text()
+    kernels = _kernel_names(text)
+    assert len(kernels) == 1 and "flash_fwd_window" in kernels[0]
+    assert "flash_fwd_mha" not in kernels[0]
+
+
 def test_paged_attention_same_kernel_at_equal_heads(on_chip):
     """The guard on shared code: grouped queries are a parameter of the
     one kernel, not a second one — asked for with as many KV heads as
@@ -354,8 +395,7 @@ def test_kda_chunk(on_chip, one_chip, monkeypatch, T):
     ph = _hybrid(monkeypatch)
     text = _compile(ph.kda_chunk, one_chip, ((1, T, 3 * 64 * 128), bf16),
                     ((1, T, 64 * 128), f32), ((1, T, 64), f32)).as_text()
-    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     assert len(kernels) == 3, kernels
     assert all("kda_chunk" in name for name in kernels), kernels
 
@@ -372,6 +412,23 @@ def test_moe_gmm(on_chip, one_chip, monkeypatch, rows, tm):
     _compile(lambda x, w, te, nu: ph.moe_gmm_down(x, w, te, nu, tm),
              one_chip, ((rows, 1280), bf16), ((40, 1280, 4096), bf16),
              *tiles)
+
+
+@pytest.mark.parametrize("rows, tm", [(288 + 64 * 16, 16),
+                                      (49152 + 64 * 128, 128)])
+def test_moe_gmm_relu_at_the_mixed_cells_shapes(on_chip, one_chip,
+                                                monkeypatch, rows, tm):
+    """64 held experts of 2560 x 768, a 48-row decode step's and an
+    8,192-token prompt's pairs; the ReLU gate is its own kernel, under a
+    name the ``moe_gmm`` readers find."""
+    ph = _hybrid(monkeypatch)
+    text = _compile(
+        lambda x, g, u, te, nu: ph.moe_gmm_gate_up(x, g, u, te, nu, tm,
+                                                   "relu"),
+        one_chip, ((rows, 2560), bf16), ((64, 2560, 768), bf16),
+        ((64, 2560, 768), bf16), ((rows // tm,), i32),
+        ((1,), i32)).as_text()
+    assert "moe_gmm_gate_up_relu" in text
 
 
 def test_hybrid_decode_slots_update_in_place(on_chip, one_chip,

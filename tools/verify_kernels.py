@@ -17,6 +17,8 @@ after ANY kernel change:
     python tools/verify_kernels.py --quick  # smoke subset
     python tools/verify_kernels.py --paged  # the paged kernel alone
     python tools/verify_kernels.py --mamba2 # the Mamba-2 kernels alone
+    python tools/verify_kernels.py --window # the sliding-window kernels
+                                            # (prefill band, decode walk)
     python tools/verify_kernels.py --packed # the packed flash kernels alone
     python tools/verify_kernels.py --tiles  # the packed kernels' tile
                                             # schedules at the cells'
@@ -208,7 +210,7 @@ def check_mha(T, block, causal, B=2, H=8, D=128):
     return ok
 
 
-def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
+def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None, window=0):
     """The paged kernel over (P, KVB, H·D) pools (``kv_cache.
     value_pool_shape``) against a lax gather of the pages and the
     fallbacks' blockwise body: W = 1 is the decode step, W > 1 a
@@ -216,7 +218,10 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
     length from one token to a full table — three of them ending one
     key short of the kernel's first chunk, on it and one past it —
     pages handed out in a shuffled order, one idle row.  ``Hq``:
-    grouped queries, that many query heads over the H KV heads."""
+    grouped queries, that many query heads over the H KV heads.
+    ``window`` (W = 1): a sliding window of that many keys; the table
+    then names the scratch page for every page wholly behind a row's
+    window, as the engine's does once it has given them back."""
     Hq = Hq or H
     from mxnet_tpu.kv_cache import value_pool_shape
     from mxnet_tpu.ops import attention as att
@@ -244,12 +249,16 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
         start[2:5] = np.arange(chunk - 1, chunk + 2) - W
     if W == 1:
         start[1] = -1
+    if window:
+        first = np.maximum(start + 1 - window, 0) // KVB
+        table = jnp.where(jnp.arange(MB)[None, :] < first[:, None], 0,
+                          table)
     start = jnp.asarray(start)
     q = jnp.asarray(rng.randn(B, W, Hq * D).astype(np.float32)
                     * 0.5).astype(jnp.bfloat16)
     scales = pools[2:]
     kernel = jax.jit(lambda q, t, s, *p: pk._paged_attention(
-        q, p[0], p[1], p[2:], t, s, Hq, kv_heads=H))
+        q, p[0], p[1], p[2:], t, s, Hq, kv_heads=H, window=window))
     if H * D % 128 and not pk._interpret():
         # compiled, the kernel copies page rows in whole lane tiles and
         # refuses another width by name (ops.attention's lax body
@@ -275,7 +284,7 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
         kg, vg = (jnp.repeat(x, Hq // H, axis=2) for x in (kg, vg))
         o, m, l = att._blockwise_attention_partial_lax(
             q.reshape(B, W, Hq, D), kg, vg, False, KVB, 0,
-            lengths=s + 1, diagonal=True)
+            lengths=s + 1, diagonal=not window, window=window)
         return att.normalize_attention_state(o, m, l, q.dtype).reshape(
             B, W, Hq * D)
 
@@ -286,10 +295,52 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None):
                 / max(np.abs(want[live]).max(), 1e-9))
     ok = err < TOL and bool(np.isfinite(got).all()) \
         and not np.abs(got[~live]).any()
+    ms = _kernel_ms(lambda x: kernel(x, table, start, *pools), q) \
+        if window else {}
     print(f"{'OK ' if ok else 'FAIL'} paged  H={Hq}/{H} D={D} W={W} "
           f"B={B} MB={MB} chunk={chunk} "
-          f"pools={kv_dtype}{' +scales' if scales else ''}: "
-          f"fwd={err:.4f}", flush=True)
+          f"pools={kv_dtype}{' +scales' if scales else ''}"
+          f"{f' window={window}' if window else ''}: fwd={err:.4f}"
+          + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
+    return ok
+
+
+def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
+    """The windowed prefill kernel (one prompt, ``Hq`` query heads over
+    ``Hkv`` KV heads) against the lax body under the same band, with
+    the kernel's ms a call beside the causal kernel's at the shape."""
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(T + window)
+    q, k, v = (jnp.asarray(rng.randn(1, T, n, D).astype(np.float32) * 0.5)
+               .astype(jnp.bfloat16) for n in (Hq, Hkv, Hkv))
+
+    def heads_first(x):
+        return x[0].transpose(1, 0, 2)
+
+    kern = jax.jit(lambda q, k, v: pk.flash_mha_window(
+        heads_first(q), heads_first(k), heads_first(v), window, Hq, Hkv))
+    full = jax.jit(lambda q, k, v: pk.flash_mha(
+        heads_first(q), jnp.repeat(heads_first(k), Hq // Hkv, 0),
+        jnp.repeat(heads_first(v), Hq // Hkv, 0), causal=True,
+        block_size=0))
+
+    def lax_body(q, k, v):
+        o, m, l = att._blockwise_attention_partial_lax(
+            q, jnp.repeat(k, Hq // Hkv, 2), jnp.repeat(v, Hq // Hkv, 2),
+            True, 512, 0, window=window)
+        return att.normalize_attention_state(o, m, l, q.dtype)
+
+    got = np.asarray(kern(q, k, v).astype(jnp.float32)).transpose(1, 0, 2)
+    want = np.asarray(jax.jit(lax_body)(q, k, v).astype(jnp.float32))[0]
+    err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
+    ok = err < TOL and bool(np.isfinite(got).all())
+    ms = _kernel_ms(lambda x: kern(x, k, v), q)
+    ms.update(_kernel_ms(lambda x: full(x, k, v), q))
+    print(f"{'OK ' if ok else 'FAIL'} window T={T} window={window} "
+          f"H={Hq}/{Hkv} D={D}: fwd={err:.4f}"
+          + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
     return ok
 
 
@@ -389,6 +440,22 @@ def main():
         results.append(check_mamba2(1, 1, B=64))
         for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
             results.append(check_mamba2(T, n))
+        return _report(results)
+    if "--window" in sys.argv:
+        # the mixed cell's shapes: 48 rows of 28 / 4 heads x 128 over
+        # 544-page tables and a window of 4,096 keys, prompts in every
+        # prefill bucket; then windows and lengths that end inside tiles
+        # and chunks
+        results.append(check_paged(4, 128, 1, "bf16", B=48, MB=544, Hq=28,
+                                   window=4096))
+        results.append(check_paged(4, 128, 1, "bf16", B=48, MB=544, Hq=28))
+        results.append(check_paged(4, 128, 1, "bf16", B=8, MB=64, Hq=28,
+                                   window=300))
+        results.append(check_paged(8, 128, 1, "bf16", B=8, MB=64, Hq=64,
+                                   window=256))
+        for T, w in ((8192, 4096), (4096, 4096), (2048, 4096), (1024, 4096),
+                     (3000, 1000), (1024, 100)):
+            results.append(check_window_flash(T, w))
         return _report(results)
     if "--packed" not in sys.argv:
         results += _paged_matrix(quick)
