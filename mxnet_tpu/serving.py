@@ -33,12 +33,13 @@ the batch axis) do not mix rows.
 
 from __future__ import annotations
 
+import collections
 import os
 import queue as _queue
 import threading
 import time
 from concurrent.futures import Future
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Deque, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -52,6 +53,9 @@ __all__ = ["InferenceEngine", "DecodeEngine", "EngineClosedError",
            "ReplicaHarness"]
 
 _DEFAULT_BUCKETS = (1, 8, 32, 128)
+# prefills a decode engine keeps unfetched at most: as many first tokens
+# as one decode step can take from the device (``DecodeEngine._feed_exe``)
+_FIRSTS_AHEAD = 4
 
 
 def _phase_breakdown(summ: dict, phases: Dict[str, str]) -> dict:
@@ -921,7 +925,7 @@ class _Stream:
                  "cached_len", "await_first", "t_chunk0", "slo_class",
                  "canary", "cost", "migrate", "tenant", "adapter",
                  "adapter_bucket", "adapter_slot", "slot", "keep_state",
-                 "wblocks", "wfirst")
+                 "wblocks", "wfirst", "ahead", "feed")
 
     def __init__(self, sid, prompt, max_new, temp, eos, future, seed,
                  trace=None, slo_class="interactive", canary=False,
@@ -935,8 +939,16 @@ class _Stream:
         self.seed = seed
         self.generated: List[int] = []
         self.blocks: List[int] = []   # page ids held (host block table)
-        self.length = 0               # tokens currently cached
-        self.next_token = -1          # sampled, not yet fed
+        # SCHEDULING state, advanced when a program is dispatched:
+        self.length = 0               # tokens cached, or being cached by
+        #                               a program already dispatched
+        self.ahead = 0                # tokens sampled on the device and
+        #                               not fetched yet
+        self.feed = None              # (device tokens, row) of the newest
+        #                               of them: the next step's input
+        # DELIVERY state, advanced when a program's tokens are fetched
+        # (``generated`` above, and):
+        self.next_token = -1          # sampled, fetched, not yet fed
         self.resume = False           # re-prefill after preemption
         self.t_submit = time.perf_counter()
         self.t_admit = 0.0
@@ -978,6 +990,27 @@ class _Stream:
                 or (self.eos is not None and self.generated
                     and self.generated[-1] == self.eos))
 
+    def last_by_count(self) -> bool:
+        """Its tokens in flight are its last: known without reading
+        one, so the next batch is composed without it."""
+        return len(self.generated) + self.ahead >= self.max_new
+
+
+class _Flight:
+    """One dispatched program whose sampled tokens are still on the
+    device: a decode step's (one a row of ``streams``), or a prefill's
+    first (``prefill``: what its booking needs; None for a step)."""
+
+    __slots__ = ("toks", "streams", "t0", "bb", "fl", "prefill")
+
+    def __init__(self, toks, streams, t0, bb=1, fl=0.0, prefill=None):
+        self.toks = toks
+        self.streams = streams
+        self.t0 = t0
+        self.bb = bb
+        self.fl = fl
+        self.prefill = prefill
+
 
 class DecodeEngine:
     """Continuous-batching autoregressive serving over a paged KV cache.
@@ -1003,6 +1036,52 @@ class DecodeEngine:
       pool empty, the YOUNGEST stream is preempted — its pages freed,
       its progress re-queued for re-prefill (recompute-style
       preemption; ``serving.preempted`` counts them).
+
+    **The loop runs one program ahead of what it has read**: the chip's
+    queue is never empty while a stream is active.  A stream's
+    SCHEDULING state (its length, its pages, whether it is in the next
+    batch) advances when a program is dispatched; its DELIVERY state
+    (``generated``, metrics, retirement, the ``Future``) advances when
+    the program's tokens are fetched — after the next program is
+    queued.  A turn: admit and dispatch prefills without reading their
+    first tokens; dispatch decode step t+1, its token feed gathered ON
+    the device from step t's tokens and the new rows' first tokens (one
+    small program a batch bucket, ``jit_next_tokens_b<B>``, built by
+    ``warmup()``); THEN fetch and book what lies before step t+1, up to
+    step t (``serving.d2h_sync``, ``serving.absorb``), while the chip
+    runs.  A stream whose tokens in flight are its last by count is out
+    of the next batch without a token read; a row it frees is refilled
+    one step later than a synchronous loop would.  A stream with ``eos``
+    rides ahead too: if step t's token is its ``eos``, its row of step
+    t+1 is an *overshoot* — the token is dropped, the K/V it wrote lies
+    on a page the stream owned (pages follow the scheduled length), and
+    pages and slot go back at once: a page is written again only by a
+    program dispatched later, which the device runs later.  Sampling is
+    keyed (seed, stream, position), so the served tokens are the
+    synchronous loop's.
+
+    Where it may NOT run ahead the loop first fetches and books what is
+    in flight — a *drain*, counted by reason
+    (``serving.run_ahead.drain.<reason>``) — and goes on as a
+    synchronous loop would.  Decided from what is in front of it, no
+    option: a verify window (``verify``), a chunked prefill in flight
+    (``chunk``: every step between chunks is fetched at once), a page
+    growth or copy-on-write about to PREEMPT (``preempt``: a victim is
+    rewound to what it has delivered), a page export or import
+    (``export``, ``import``), a stream with ``eos`` whose result hands
+    back its slot's state (``state_eos``: an overshoot would alter it),
+    a step whose batch bucket differs from the one in flight
+    (``bucket``), ``swap_params``, ``reset_stats``, ``close`` (tokens
+    already sampled are delivered, the rest fails), and the tail, when
+    nothing is left to dispatch (``idle``).  A device error surfaces at
+    the fetch and fails every stream, those in no list but a program's
+    record too.  ``stats()``: ``steps_run_ahead`` (decode programs
+    dispatched while an earlier program's tokens were unread),
+    ``run_ahead_share`` (of ``steps``), ``run_ahead_drains`` and
+    ``run_ahead_drain_reasons``, ``overshoot_row_steps``,
+    ``prefill_first_deferred``; ``d2h_syncs`` counts fetches (one a
+    program), ``d2h_syncs_saved`` those made with a newer program
+    already queued.
 
     Decode numerics: prefill + N decode steps is bit-identical (lax
     path) to the full-sequence causal forward of
@@ -1574,6 +1653,15 @@ class DecodeEngine:
         self._imports: List[tuple] = []
         self._admitting: Optional[_Stream] = None
         self._prefilling: Optional[_Stream] = None  # mid-chunked-prefill
+        # programs dispatched and not fetched, oldest first (the loop
+        # thread's alone); other threads' requests to have them fetched
+        # and booked before they go on: (reason, Event)
+        self._inflight: Deque[_Flight] = collections.deque()
+        self._asks: List[tuple] = []
+        self._booking = False  # inside _fetch_one (callbacks run there)
+        self._t_booked = 0.0   # when the last decode step was booked
+        # fills the first-token arguments of ``_feed_exe`` no row reads
+        self._no_first = jax.device_put(np.zeros(1, np.int32), dev)
         self._accepting = True
         self._reject = None  # drain(): submit's refusal message
         self._alive = True
@@ -1804,6 +1892,7 @@ class DecodeEngine:
         missing = [n for n in self._param_names if n not in host]
         if missing:
             raise MXNetError(f"swap_params: params missing {missing}")
+        self._settle("swap_params")  # no token in flight across a swap
         if self._mesh is not None:
             clean = {}
             for n in self._param_names:
@@ -1902,6 +1991,7 @@ class DecodeEngine:
         for tp in self._prefill_buckets:
             self._exe("prefill", tp)
         for bb in self._decode_buckets:
+            self._feed_exe(bb)
             for mb in self._cache_buckets:
                 self._exe("decode", bb, mb)
                 if self._spec_k:
@@ -1934,7 +2024,10 @@ class DecodeEngine:
     def reset_stats(self):
         """Zero the engine-local counters/histograms so the next
         :meth:`stats` covers only work from this point on (benchmarks
-        isolate sweep points; lifetime percentiles blend loads)."""
+        isolate sweep points; lifetime percentiles blend loads).  What
+        is in flight is fetched and booked first: a step counts whole
+        on one side of the reset."""
+        self._settle("reset_stats")
         self._metrics.reset()
         self._cost_agg.reset()
         with self._lock:
@@ -1995,7 +2088,20 @@ class DecodeEngine:
                 "preempted", "prefills", "steps", "stream_steps",
                 "prefill_chunks", "spec_steps", "spec_proposed",
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
-                "d2h_syncs_saved", "context_tokens")}
+                "d2h_syncs_saved", "context_tokens", "steps_run_ahead",
+                "run_ahead_drains", "overshoot_row_steps",
+                "prefill_first_deferred")}
+        # how the loop ran: the share of decode programs dispatched
+        # while an earlier program's tokens were still unread, and why
+        # it fetched everything before going on, when it did
+        out["run_ahead_share"] = round(
+            out["steps_run_ahead"] / out["steps"], 4) \
+            if out["steps"] else 0.0
+        profiler.set_gauge("serving.run_ahead_share",
+                           out["run_ahead_share"])
+        out["run_ahead_drain_reasons"] = {
+            k[len("run_ahead.drain."):]: int(v) for k, v in c.items()
+            if k.startswith("run_ahead.drain.")}
         # speculative-decoding headline ratios: how much of what the
         # proposer offered the target model verified, and how many
         # tokens ONE target-model evaluation of one stream commits
@@ -2159,8 +2265,17 @@ class DecodeEngine:
                 if self._prefilling not in streams:
                     streams.append(self._prefilling)
                 self._prefilling = None
+            # a stream whose last tokens are in flight is in no list
+            # but its program's record
+            for rec in self._inflight:
+                streams.extend(s for s in rec.streams if s not in streams
+                               and not s.future.done())
+            self._inflight.clear()
             self._pending, self._active = [], []
             imports, self._imports = self._imports, []
+            asks, self._asks = self._asks, []
+        for _, settled in asks:  # nothing is in flight any more
+            settled.set()
         for item in imports:  # queued page imports never spliced
             fut = item[2]
             if fut.set_running_or_notify_cancel():
@@ -2378,6 +2493,45 @@ class DecodeEngine:
                             jax.device_put(vec, self._device)))
         return tuple(out)
 
+    def _feed_exe(self, bb: int):
+        """The program that builds a decode step's ``(bb, 1)`` token
+        feed ON the device, from tokens no one has read yet: row ``i``
+        is ``concat(prev, *firsts, host)[index[i]]`` — ``prev`` the
+        last step's ``(bb,)`` sampled tokens, ``firsts`` the
+        ``_FIRSTS_AHEAD`` newest prefills' ``(1,)`` first tokens,
+        ``host`` the tokens the scheduler knows.  One a batch bucket,
+        built by ``warmup()``."""
+        key = ("feed", bb)
+        exe = self._exe_cache.get(key)
+        if exe is not None:
+            return exe
+        with self._compile_lock:
+            exe = self._exe_cache.get(key)
+            if exe is not None:
+                return exe
+            import jax
+            import jax.numpy as jnp
+
+            def next_tokens(prev, firsts, host, index):
+                return jnp.concatenate(
+                    (prev,) + tuple(firsts) + (host,))[index][:, None]
+
+            next_tokens.__name__ = next_tokens.__qualname__ = \
+                f"next_tokens_b{bb}"
+            i32 = np.dtype(np.int32)
+            row = self._arg_spec((bb,), i32)
+            with profiler.scope(f"serving.compile.feed.b{bb}", "serving",
+                                args={"batch": bb}):
+                exe = jax.jit(
+                    next_tokens,
+                    out_shardings=self._device if self._mesh is not None
+                    else None).lower(
+                        row, (self._arg_spec((1,), i32),) * _FIRSTS_AHEAD,
+                        row, row).compile()
+            self._exe_cache[key] = exe
+            self.compiles[key] = self.compiles.get(key, 0) + 1
+            return exe
+
     def _cow_exe(self):
         """One jitted page copy for copy-on-write: every pool (values
         and scales) copies row ``src`` into row ``dst``; src/dst are
@@ -2419,6 +2573,87 @@ class DecodeEngine:
             self._alloc.free(pages)
 
     # ------------------------------------------------------------------
+    # running ahead: programs dispatched whose tokens are not read yet
+    # ------------------------------------------------------------------
+    def _hold_back(self, streams) -> Optional[str]:
+        """Why the loop may not dispatch past these streams' unread
+        tokens — the name of the drain — or None: it may.  Read from
+        what is in front of it: a chunked prefill in flight runs as it
+        always did, between synchronous steps; a stream that stops at
+        ``eos`` AND hands back its slot's state would have that state
+        altered by the step dispatched past its last token."""
+        if self._prefilling is not None:
+            return "chunk"
+        if any(s.keep_state and s.eos is not None for s in streams):
+            return "state_eos"
+        return None
+
+    @staticmethod
+    def _read(toks) -> np.ndarray:
+        """The device's tokens on the host: where the loop waits for
+        the device, and where a device error surfaces."""
+        return np.asarray(toks)
+
+    def _fetch_one(self) -> _Flight:
+        """Fetch the oldest program's tokens and book them: a step's
+        under ``serving.absorb``, a prefill's first token as
+        ``_deliver_first`` does."""
+        rec = self._inflight[0]
+        with profiler.scope("serving.d2h_sync", "serving",
+                            args={"active": len(rec.streams)}):
+            toks = self._read(rec.toks)
+        self._inflight.popleft()
+        self._count("d2h_syncs")
+        if self._inflight:  # a newer program is queued behind it
+            self._count("d2h_syncs_saved")
+        t_done = time.perf_counter()
+        self._booking = True
+        try:
+            if rec.prefill is not None:
+                self._deliver_first(rec, int(toks[0]), t_done)
+            else:
+                span_args = {"active": len(rec.streams), "retired": 0}
+                with profiler.scope("serving.absorb", "serving",
+                                    args=span_args):
+                    span_args["retired"] = self._book_step(rec, toks,
+                                                           t_done)
+        finally:
+            self._booking = False
+        return rec
+
+    def _fetch_all(self):
+        while self._inflight:
+            self._fetch_one()
+
+    def _drain(self, reason: str):
+        """Fetch and book everything in flight before something that
+        needs the streams' delivery state whole; counted by reason
+        (``serving.run_ahead.drain.<reason>``)."""
+        if not self._inflight:
+            return
+        self._count("run_ahead_drains")
+        self._count(f"run_ahead.drain.{reason}")
+        with profiler.scope("serving.drain", "serving",
+                            args={"reason": reason,
+                                  "programs": len(self._inflight)}):
+            self._fetch_all()
+
+    def _settle(self, reason: str, timeout: float = 30.0):
+        """From any thread: have the loop drain, and wait until it
+        has."""
+        if threading.current_thread() is self._thread:
+            if not self._booking:  # (a caller's callback, mid-fetch)
+                self._drain(reason)
+            return
+        settled = threading.Event()
+        with self._cond:
+            if not self._alive:
+                return
+            self._asks.append((reason, settled))
+            self._cond.notify_all()
+        settled.wait(timeout)
+
+    # ------------------------------------------------------------------
     # scheduler
     # ------------------------------------------------------------------
     def _loop(self):
@@ -2429,15 +2664,28 @@ class DecodeEngine:
                     while self._alive and not self._pending \
                             and not self._active \
                             and not self._imports \
+                            and not self._inflight \
+                            and not self._asks \
                             and self._prefilling is None:
                         with profiler.scope("serving.idle", "serving"):
                             self._cond.wait(timeout=0.5)
-                    if not self._alive:
-                        return
+                    alive = self._alive
+                    asks, self._asks = self._asks, []
+                if not alive:
+                    # tokens already sampled are delivered; what they
+                    # do not finish fails in the ``finally`` below
+                    self._drain("close")
+                    for _, settled in asks:
+                        settled.set()
+                    return
+                for reason, settled in asks:
+                    self._drain(reason)
+                    settled.set()
                 if self._imports:
                     # splice migrated-in KV pages FIRST: an imported
                     # stream is past its prefill, so it joins the very
                     # next decode batch (migration adds no queue wait)
+                    self._drain("import")
                     self._absorb_imports()
                 if self._pending:
                     with profiler.scope(
@@ -2454,6 +2702,10 @@ class DecodeEngine:
                     self._prefill_chunk()
                 if self._active:
                     self._decode_step()
+                elif self._inflight:
+                    # nothing to dispatch behind them: the last tokens
+                    # of streams that end by count, or first tokens
+                    self._drain("idle")
                 elif self._pending and self._prefilling is None:
                     # head-of-line request can't be admitted and no
                     # stream is decoding (transient: submit racing the
@@ -2562,6 +2814,11 @@ class DecodeEngine:
                             max(self._window_need(
                                 len(s.prompt) + s.max_new), 1)):
                     return  # the windowed pools are short: the same hold
+                if self._slot_alloc is not None and self._slot_alloc.live \
+                        >= self._slot_alloc.num_slots:
+                    # every slot is held: streams whose last tokens are
+                    # in flight keep theirs until those are fetched
+                    return
                 self._pending.pop(pick)
                 self._admitting = s  # visible to _fail_outstanding
             # On failure _admitting must STAY set until the loop's
@@ -2616,6 +2873,7 @@ class DecodeEngine:
                     # first token with the same (seed, position) key
                     with self._lock:
                         self._active.remove(s)
+                    self._drain("export")
                     self._export_stream(s)
             else:
                 self._prefill(s, seq, s.blocks)
@@ -2705,8 +2963,21 @@ class DecodeEngine:
         return tuple(stage_array(a, self._device) for a in feeds)
 
     def _prefill(self, s: _Stream, seq: np.ndarray, pages: List[int]):
+        """Dispatch the prompt's program and go on: the sampled first
+        token stays on the device (``s.feed``) until the loop fetches
+        it behind a later decode step.  Where it may not run ahead
+        (``_hold_back``; a stream to export), what is in flight is
+        fetched first and this prefill's token right after."""
         n = len(seq)
         c = s.cached_len  # block-aligned prefix already in the cache
+        why = "export" if s.migrate else self._hold_back([s])
+        if why:
+            self._drain(why)
+        # a decode step takes _FIRSTS_AHEAD first tokens from the
+        # device: the oldest programs are fetched before one more
+        while sum(r.prefill is not None for r in self._inflight) \
+                >= _FIRSTS_AHEAD:
+            self._fetch_one()
         t_pre0 = time.perf_counter()
         if c:
             # prefix hit: prefill ONLY the uncached suffix, attending
@@ -2716,9 +2987,6 @@ class DecodeEngine:
             toks, tp = self._suffix_prefill_call(
                 s, seq, c, n, "suffix length", "suffix",
                 {"cached": c, "resume": s.resume})
-            with profiler.scope("serving.d2h_sync", "serving",
-                                args={"sids": s.sid}):
-                first = int(np.asarray(toks)[0])
         else:
             ns = n
             tp = self._bucket(self._prefill_buckets, n, "prompt length")
@@ -2748,25 +3016,23 @@ class DecodeEngine:
                         *self._prompt_feeds(s, seq, 0, n, tp, mb, pages,
                                             False),
                         self._pools, *self._runtime_args([s], 1, mb))
-                with profiler.scope("serving.d2h_sync", "serving",
-                                    args={"sids": s.sid}):
-                    first = int(np.asarray(toks)[0])
             s.cost.flops_est += self._exe_flops.get(("prefill", tp),
                                                     0.0)
-        # both branches just fetched the sampled first token
-        self._count("d2h_syncs")
-        s.cost.d2h_syncs += 1
         s.blocks = pages
         s.length = n
-        self._finish_prefill(s, first, n, ns, c, tp, t_pre0,
-                             time.perf_counter())
+        self._launch_prefill(s, toks, n, ns, c, tp, t_pre0)
+        if why:
+            self._fetch_all()
+        else:
+            self._count("prefill_first_deferred")
 
-    def _finish_prefill(self, s: _Stream, first: int, n: int, ns: int,
-                        c: int, tp: int, t_pre0: float, t_done: float):
-        """Shared completion tail of monolithic, suffix, and (final-
-        chunk) chunked prefill: register the prompt's pages, book the
-        timing/TTFT metrics, deliver the first token, activate or
-        retire."""
+    def _launch_prefill(self, s: _Stream, toks, n: int, ns: int, c: int,
+                        tp: int, t_pre0: float):
+        """The scheduling half of a prefill's completion (monolithic,
+        suffix, final chunk), at its DISPATCH: the prompt's pages are
+        registered, the stream joins the next decode batch — its first
+        token fed from the device — and the program's record joins the
+        ones in flight.  ``_deliver_first`` is the other half."""
         if self._prefix is not None and not s.migrate:
             # the prompt's full pages become shareable; blocks already
             # indexed keep the incumbent page (ours stays private) — a
@@ -2774,6 +3040,29 @@ class DecodeEngine:
             # so they never enter the index
             self._prefix.register(s.prompt, s.blocks,
                                   salt=_prefix_salt(s))
+        self._count("prefills")
+        self._count("prefill_tokens", ns)  # uncached tokens only
+        s.cost.prefill_tokens += ns
+        s.t_admit = time.perf_counter()
+        self._inflight.append(_Flight(
+            toks, [s], t_pre0, prefill=(n, c, tp, s.resume)))
+        if s.resume:
+            s.resume = False  # next_token survives preemption
+        else:
+            s.ahead, s.feed = 1, (toks, 0)
+            s.await_first = False  # first token delivered via prefill
+        if not s.migrate and not s.last_by_count():
+            with self._lock:
+                self._active.append(s)
+
+    def _deliver_first(self, rec: _Flight, first: int, t_done: float):
+        """The delivery half, when the prefill's token is fetched: the
+        timing and TTFT metrics, the first token into ``generated``,
+        and retirement or export where the stream ends there."""
+        s, = rec.streams
+        n, c, tp, resume = rec.prefill
+        t_pre0 = rec.t0
+        s.cost.d2h_syncs += 1
         prefill_ms = (t_done - t_pre0) * 1e3
         self._metrics.observe("prefill_ms", prefill_ms)
         profiler.observe("serving.prefill_ms", prefill_ms)
@@ -2786,23 +3075,22 @@ class DecodeEngine:
             profiler.add_trace_event(
                 "serving.queue", s.t_enqueue, t_pre0 - s.t_enqueue,
                 s.trace.child(), cat="serving",
-                args={"sid": s.sid, "resume": s.resume})
+                args={"sid": s.sid, "resume": resume})
             profiler.add_trace_event(
                 "serving.prefill", t_pre0, t_done - t_pre0,
                 s.trace.child(), cat="serving",
                 args={"sid": s.sid, "tokens": n, "bucket": tp,
-                      "resume": s.resume})
+                      "resume": resume})
         wait_ms = (t_pre0 - s.t_enqueue) * 1e3
         self._metrics.observe("queue_wait_ms", wait_ms)
         profiler.observe("serving.queue_wait_ms", wait_ms)
-        s.t_admit = t_done
-        if s.resume:
-            s.resume = False  # next_token survives preemption
-        else:
+        if not resume:  # (a resumed stream's token was its pending one)
+            s.ahead -= 1
+            if s.feed[0] is rec.toks:
+                s.feed = None
             s.next_token = first
             s.generated.append(first)
-            s.await_first = False  # first token delivered via prefill
-            ttft = (s.t_admit - s.t_submit) * 1e3
+            ttft = (t_done - s.t_submit) * 1e3
             self._metrics.observe("ttft_ms", ttft)
             profiler.observe("serving.ttft_ms", ttft)
             # hit/miss TTFT split: a hit's first token cost only the
@@ -2813,16 +3101,21 @@ class DecodeEngine:
             self._slo.observe_ttft(s.slo_class, ttft)
             self._count("tokens")
             s.cost.tokens += 1  # same site as the engine counter
-        self._count("prefills")
-        self._count("prefill_tokens", ns)  # uncached tokens only
-        s.cost.prefill_tokens += ns
         if s.migrate:
             self._export_stream(s)
         elif s.done():  # max_new == 1 or instant eos
-            self._retire(s)
-        else:
-            with self._lock:
-                self._active.append(s)
+            self._end(s)
+
+    def _end(self, s: _Stream):
+        """Retire a stream its fetched tokens finished: one that ended
+        by count left the batch when its last program was dispatched,
+        one that read ``eos`` leaves it now."""
+        with self._lock:
+            try:
+                self._active.remove(s)
+            except ValueError:
+                pass
+        self._retire(s)
 
     def _prefill_chunk(self):
         """Advance the in-flight chunked prefill by ONE fixed-size
@@ -2834,6 +3127,7 @@ class DecodeEngine:
         A chunk that cannot get its pages simply waits for the next
         iteration (decode retirements refill the pool); only the FINAL
         chunk samples the first token and activates the stream."""
+        self._drain("chunk")  # chunks run between synchronous steps
         s = self._prefilling
         seq = s.prefill_seq()
         n = len(seq)
@@ -2858,23 +3152,17 @@ class DecodeEngine:
         # exists to bound.  Non-final chunks stay async: the
         # interleaved decode step queues behind them on the device (so
         # chunk_ms here times the launch, not the compute, for those).
+        s.length = end
         if end >= n:
-            with profiler.scope("serving.d2h_sync", "serving",
-                                args={"sids": s.sid}):
-                first = int(np.asarray(toks)[0])
-            self._count("d2h_syncs")
-            s.cost.d2h_syncs += 1  # the final chunk's token fetch
+            self._prefilling = None
+            self._launch_prefill(s, toks, n, n - s.cached_len,
+                                 s.cached_len, tp, s.t_chunk0)
+            self._fetch_all()  # the final chunk's token fetch
         t_done = time.perf_counter()
         self._count("prefill_chunks")
         self._metrics.observe("prefill_chunk_ms", (t_done - t0) * 1e3)
         profiler.observe("serving.prefill_chunk_ms",
                          (t_done - t0) * 1e3)
-        s.length = end
-        if end < n:
-            return  # more chunks to go; a decode step runs in between
-        self._prefilling = None
-        self._finish_prefill(s, first, n, n - s.cached_len,
-                             s.cached_len, tp, s.t_chunk0, t_done)
 
     def _reclaimable(self, v: _Stream) -> int:
         """Pages preempting ``v`` would actually return to the pool:
@@ -2896,6 +3184,15 @@ class DecodeEngine:
             pages = (alloc or self._palloc)(n, owner=s.sid)
             if pages is not None:
                 return pages
+            if self._inflight:
+                # a victim is rewound to what it has DELIVERED: its
+                # tokens in flight are booked first (which may free
+                # pages, or end ``s`` itself), then the pool is asked
+                # again
+                self._drain("preempt")
+                if s not in self._active:
+                    return None
+                continue
             # a victim must be able to COME BACK: its resume
             # re-prefill (prompt + progress = its cached tokens) has
             # to fit the prefill ladder — unless chunked prefill is
@@ -2943,10 +3240,11 @@ class DecodeEngine:
 
     def _ensure_capacity(self, s: _Stream, ahead: int = 1) -> bool:
         """Grow ``s`` to hold ``ahead`` more tokens' pages if needed
-        (1 = the classic next-token page; a verify window or the
-        pipelined double-step needs more); preempt the youngest other
-        stream when the pool is exhausted.  False when ``s`` itself
-        could not be kept resident."""
+        (1 = the next token's page, counted from the SCHEDULED length:
+        a step dispatched past an unread ``eos`` still writes a page
+        the stream owns; a verify window needs more); preempt the
+        youngest other stream when the pool is exhausted.  False when
+        ``s`` itself could not be kept resident."""
         need = self._blocks_for(s.length + ahead, self._kv_block) \
             - len(s.blocks)
         if need <= 0:
@@ -3044,6 +3342,7 @@ class DecodeEngine:
         self._release_slot(victim)  # recompute: re-prefill writes anew
         victim.length = 0
         victim.cached_len = 0
+        victim.ahead, victim.feed = 0, None  # (drained: nothing unread)
         # a full-hit stream preempted BEFORE its first sampled token
         # re-admits as a fresh request (there is no pending progress
         # to resume; prefill_seq would otherwise drop the last token)
@@ -3407,8 +3706,12 @@ class DecodeEngine:
         # fast-window burn alert must catch before conviction would
         get_chaos().on_decode_step()
         if self._spec_k:
+            # a draft continues the tokens a stream has DELIVERED
+            self._drain("verify")
             with self._lock:
                 streams = list(self._active)
+            if not streams:
+                return
             drafts = {s.sid: self._propose(s) for s in streams}
             if any(d.size for d in drafts.values()):
                 with profiler.scope("serving.step", "serving",
@@ -3593,9 +3896,23 @@ class DecodeEngine:
             span_args["retired"] = len(retired)
 
     def _plain_step(self):
+        """Dispatch one decode step and THEN fetch the one before it.
+
+        The batch is composed from the scheduling state: every active
+        stream at its scheduled length, a row's token taken from the
+        device where no one has read it yet (``_feed_exe``).  A stream
+        whose tokens in flight are its last by count leaves the batch
+        here, without a token read.  Then the oldest programs' tokens
+        are fetched and booked, up to the previous decode step's —
+        while the chip runs the step just queued.  Where the loop may
+        not run ahead (``_hold_back``) it drains first and fetches this
+        step's tokens at once: the synchronous loop."""
         from .io import stage_array
 
         t0 = time.perf_counter()
+        why = self._hold_back(self._active)
+        if why:
+            self._drain(why)
         for s in list(self._active):
             if s in self._active:
                 self._ensure_capacity(s)
@@ -3605,53 +3922,33 @@ class DecodeEngine:
                     self._maybe_cow(s)
         with self._lock:
             streams = list(self._active)
+        # the newest step in flight: the rows that rode it feed from
+        # its (bb,) tokens, which the feed program takes at ITS bucket
+        prev = next((r for r in reversed(self._inflight)
+                     if r.prefill is None), None)
+        if prev is not None and streams and prev.bb != self._bucket(
+                self._decode_buckets, len(streams), "active streams"):
+            self._drain("bucket")  # (may end streams that read eos)
+            prev = None
+            with self._lock:
+                streams = list(self._active)
         if not streams:
             return
-        # Double-buffered fetch: when the next step's batch is
-        # provably THIS one's (nothing pending, no chunked prefill in
-        # flight, no stream can retire, pages already cover two more
-        # tokens, the next write cannot COW), launch step t+1 straight
-        # from step t's still-on-device tokens and only then copy step
-        # t's (B,) result to the host — the copy overlaps step t+1's
-        # compute instead of gating the loop.  Sampling is keyed
-        # (seed, stream, position), so the pipelined pair emits the
-        # same bits the two sequential steps would.
-        pipeline = (not self._pending and self._prefilling is None
-                    and all(s.eos is None
-                            and len(s.generated) + 2 <= s.max_new
-                            for s in streams))
-        if pipeline:
-            for s in streams:
-                if s not in self._active \
-                        or not self._ensure_capacity(s, ahead=2):
-                    pipeline = False
-                    break
-            with self._lock:
-                cur = list(self._active)
-            if cur != streams:
-                # growing two-ahead preempted someone: re-snapshot and
-                # run this iteration unpipelined
-                streams = cur
-                pipeline = False
-                if not streams:
-                    return
+        bb = self._bucket(self._decode_buckets, len(streams),
+                          "active streams")
         n = len(streams)
-        bb = self._bucket(self._decode_buckets, n, "active streams")
         mb = self._bucket(self._cache_buckets,
                           max(len(s.blocks) for s in streams),
                           "cache blocks")
         exe = self._exe("decode", bb, mb)
         # the batch program's FLOPs, split evenly across the riders
         fl = self._exe_flops.get(("decode", bb, mb), 0.0) / n
-        # one adapter snapshot serves both halves of a pipelined pair
-        # (the batch composition is pinned, so the slot vectors are
-        # identical; a concurrent publish lands at the next pair)
         extra = self._runtime_args(streams, bb, mb)
+        ahead = bool(self._inflight)
         span_args = {"sids": self._sids(streams), "active": n,
-                     "pipelined": pipeline}
+                     "ahead": ahead}
         dev = self._device
         with profiler.scope("serving.stage", "serving", args=span_args):
-            tokens = np.zeros((bb, 1), np.int32)
             positions = np.zeros((bb, 1), np.int32)
             lengths = np.zeros((bb,), np.int32)
             table = np.zeros((bb, mb), np.int32)
@@ -3659,18 +3956,21 @@ class DecodeEngine:
             seeds = np.zeros((bb,), np.int32)
             steps = np.zeros((bb,), np.int32)
             for i, s in enumerate(streams):
-                tokens[i, 0] = s.next_token
                 positions[i, 0] = s.length
                 lengths[i] = s.length + 1
                 table[i, :len(s.blocks)] = s.blocks
                 temps[i] = s.temp
                 seeds[i] = s.seed
                 steps[i] = s.length  # the position being sampled FROM
-            feeds = (stage_array(tokens, dev),
+            feeds = (self._token_feed(streams, bb, prev),
                      stage_array(positions, dev),
                      stage_array(lengths, dev), stage_array(table, dev),
                      stage_array(temps, dev), stage_array(seeds, dev),
                      stage_array(steps, dev))
+        self._count("steps")
+        self._count("stream_steps", n)
+        if ahead:
+            self._count("steps_run_ahead")
         # the paged kernel's need: the live context this step attends
         self._count("context_tokens", int(lengths.sum()))
         if self._walloc is not None:
@@ -3678,64 +3978,65 @@ class DecodeEngine:
         with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
                             "serving",
                             args={"active": n, "batch": bb,
-                                  "blocks": mb,
-                                  "pipelined": pipeline}):
+                                  "blocks": mb, "ahead": ahead}):
             with self._pools_lock:
-                toks_dev, self._pools = exe(self._params, *feeds,
-                                            self._pools, *extra)
+                toks, self._pools = exe(self._params, *feeds,
+                                        self._pools, *extra)
         # the staged inputs die here, as call temporaries would: freeing
         # device arrays lets other threads run, and WHERE that happens
         # decides whether a caller's next request makes the next
         # admission (kept to the function's end, ttft halved)
         del feeds
-        if not pipeline:
-            with profiler.scope("serving.d2h_sync", "serving",
-                                args=span_args):
-                toks = np.asarray(toks_dev)
-            self._count("d2h_syncs")
-            t_done = time.perf_counter()
-            self._absorb_step(streams, toks, t0, t_done, bb, n, fl)
+        # the scheduling state moves NOW: the next batch is composed
+        # from it, whether or not this step's tokens have been read
+        rec = _Flight(toks, streams, t0, bb, fl)
+        self._inflight.append(rec)
+        done = []
+        for i, s in enumerate(streams):
+            s.length += 1
+            s.ahead += 1
+            s.feed = (toks, i)
+            if s.last_by_count():
+                done.append(s)
+        if done:
+            with self._lock:
+                for s in done:
+                    self._active.remove(s)
+        if why:
+            self._fetch_all()
             return
-        # step t+1, fed from the device: live rows advance one
-        # position; pad rows stay dead (lengths 0 keeps their write on
-        # the scratch page and their mask empty)
-        with profiler.scope("serving.stage", "serving", args=span_args):
-            live = lengths > 0
-            positions2 = positions + live[:, None].astype(np.int32)
-            lengths2 = np.where(live, lengths + 1, 0).astype(np.int32)
-            steps2 = steps + 1
-            feeds2 = (toks_dev.reshape(bb, 1),
-                      stage_array(positions2, dev),
-                      stage_array(lengths2, dev),
-                      stage_array(table, dev), stage_array(temps, dev),
-                      stage_array(seeds, dev),
-                      stage_array(steps2, dev))
-        self._count("context_tokens", int(lengths2.sum()))
-        if self._walloc is not None:
-            self._count_window_step(streams, lengths2)
-        with profiler.scope(f"serving.decode_step.b{bb}x{mb}",
-                            "serving",
-                            args={"active": n, "batch": bb,
-                                  "blocks": mb, "pipelined": True}):
-            with self._pools_lock:
-                toks2_dev, self._pools = exe(self._params, *feeds2,
-                                             self._pools, *extra)
-        del feeds2
-        with profiler.scope("serving.d2h_sync", "serving",
-                            args=span_args):
-            toks = np.asarray(toks_dev)  # overlaps step t+1's compute
-        self._count("d2h_syncs")
-        self._count("d2h_syncs_saved")
-        t_mid = time.perf_counter()
-        # no retires possible (predicate): t+1's assumed composition
-        # held, so its results are the real step t+1
-        self._absorb_step(streams, toks, t0, t_mid, bb, n, fl)
-        with profiler.scope("serving.d2h_sync", "serving",
-                            args=span_args):
-            toks2 = np.asarray(toks2_dev)
-        self._count("d2h_syncs")
-        t_done = time.perf_counter()
-        self._absorb_step(streams, toks2, t_mid, t_done, bb, n, fl)
+        while self._inflight[0] is not rec:
+            if self._fetch_one().prefill is None:
+                break
+
+    def _token_feed(self, streams, bb: int, prev: Optional[_Flight]):
+        """The staged ``(bb, 1)`` token feed of a decode step.  A row
+        whose token the scheduler knows takes it from the host; one
+        that rode ``prev`` (the step in flight) takes its row of that
+        step's tokens, one admitted since its prefill's first token —
+        both still on the device, gathered there by ``_feed_exe``."""
+        from .io import stage_array
+
+        tokens = np.zeros((bb,), np.int32)
+        # where row i's token lies in concat(prev, firsts, host)
+        index = np.arange(bb + _FIRSTS_AHEAD, 2 * bb + _FIRSTS_AHEAD,
+                          dtype=np.int32)
+        firsts = []
+        for i, s in enumerate(streams):
+            if s.feed is None:
+                tokens[i] = s.next_token
+            elif prev is not None and s.feed[0] is prev.toks:
+                index[i] = s.feed[1]
+            else:
+                index[i] = bb + len(firsts)
+                firsts.append(s.feed[0])
+        if prev is None and not firsts:
+            return stage_array(tokens[:, None], self._device)
+        host = stage_array(tokens, self._device)
+        firsts += [self._no_first] * (_FIRSTS_AHEAD - len(firsts))
+        return self._feed_exe(bb)(
+            host if prev is None else prev.toks, tuple(firsts), host,
+            stage_array(index, self._device))
 
     def _count_window_step(self, streams, lengths):
         """A decode step's need in the windowed layers (the context
@@ -3747,31 +4048,35 @@ class DecodeEngine:
                     sum(len(s.wblocks) - s.wfirst for s in streams))
         self._count("page_steps", sum(len(s.blocks) for s in streams))
 
-    def _absorb_step(self, streams, toks, t0, t_done, bb, n,
-                     fl: float = 0.0):
-        """Book one plain decode step's results into the scheduler
-        (under the ``serving.absorb`` span: counters, per-stream token
-        append, full-hit TTFT, trace spans, retirement with the
-        futures' callbacks)."""
-        span_args = {"active": n, "retired": 0}
-        with profiler.scope("serving.absorb", "serving", args=span_args):
-            span_args["retired"] = self._book_step(
-                streams, toks, t0, t_done, bb, n, fl)
-
-    def _book_step(self, streams, toks, t0, t_done, bb, n, fl):
-        """:meth:`_absorb_step`'s work; returns how many retired."""
+    def _book_step(self, rec: _Flight, toks: np.ndarray,
+                   t_done: float) -> int:
+        """The delivery half of one decode step, when its tokens are
+        fetched (under ``serving.absorb``): per-stream token append,
+        full-hit TTFT, trace spans, retirement with the futures'
+        callbacks.  A row whose stream had already read its ``eos``
+        when this step was dispatched past it is an overshoot: the
+        token is dropped.  Returns how many retired."""
+        streams, bb, fl, n = rec.streams, rec.bb, rec.fl, len(rec.streams)
+        # the step's wall is the time since the last booking, where one
+        # lies behind its dispatch: a token's cadence, not the life of
+        # a program that queued behind another
+        t0 = max(rec.t0, self._t_booked)
+        self._t_booked = t_done
         step_ms = (t_done - t0) * 1e3
-        self._count("steps")
-        self._count("stream_steps", n)
-        self._count("tokens", n)
         self._metrics.observe("step_ms", step_ms)
         profiler.observe("serving.decode_step_ms", step_ms)
         retired = []
+        overshoot = 0
         for i, s in enumerate(streams):
+            if s.done():
+                overshoot += 1
+                continue
             tok = int(toks[i])
             s.generated.append(tok)
-            s.length += 1
             s.next_token = tok
+            s.ahead -= 1
+            if s.feed[0] is rec.toks:
+                s.feed = None  # the newest of its tokens: read now
             s.cost.tokens += 1  # same site as the engine counter
             s.cost.decode_steps += 1
             s.cost.d2h_syncs += 1
@@ -3796,25 +4101,28 @@ class DecodeEngine:
                 profiler.add_trace_event(
                     "serving.decode_step", t0, t_done - t0,
                     s.trace.child(), cat="serving",
-                    args={"sid": s.sid, "position": s.length,
+                    args={"sid": s.sid, "position": s.length - s.ahead,
                           "batch": bb, "active": n})
             if s.done():
                 retired.append(s)
+        self._count("tokens", n - overshoot)
+        if overshoot:
+            self._count("overshoot_row_steps", overshoot)
         if self._walloc is not None:
             # a step that carried a row's window past a page boundary
             # leaves a page wholly behind it: back to the windowed pool
-            behind = [(s, self._window_first(s.length)) for s in streams]
+            # (by the SCHEDULED length: a step in flight reads the page
+            # ids it was staged with, and a page given back is written
+            # again only by a program dispatched after it)
+            behind = [(s, self._window_first(s.length)) for s in streams
+                      if s.wblocks]
             if any(first > s.wfirst for s, first in behind):
                 with profiler.scope("serving.window_release", "serving"):
                     self._count("window_pages_released", sum(
                         self._release_window(s, first)
                         for s, first in behind))
-        if retired:
-            with self._lock:
-                for s in retired:
-                    self._active.remove(s)
-            for s in retired:
-                self._retire(s)
+        for s in retired:
+            self._end(s)
         return len(retired)
 
 

@@ -21,7 +21,8 @@ from mxnet_tpu import models, profiler
 V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
 ENGINE_SPANS = ("serving.admit", "serving.prefill", "serving.step",
                 "serving.stage", "serving.decode_step",
-                "serving.d2h_sync", "serving.absorb", "serving.idle")
+                "serving.d2h_sync", "serving.absorb", "serving.drain",
+                "serving.idle")
 
 
 def family(name):
@@ -122,41 +123,56 @@ def test_engine_spans_land_in_a_running_jax_trace(engine, tmp_path):
     def inside(child, parent):
         return parent[1] <= child[1] and child[2] <= parent[2]
 
-    # children lie inside their parents
+    # children lie inside their parents.  A step stages and dispatches
+    # its program, THEN fetches and books the one before it: the spans
+    # of the fetch (``d2h_sync``, ``absorb``) lie in the step that
+    # dispatched the next program, or in the ``serving.drain`` that
+    # fetched with nothing left to dispatch (a request's last step)
     steps = by_name["serving.step"]
-    for child in ("serving.stage", "serving.decode_step",
-                  "serving.absorb"):
+    drains = by_name["serving.drain"]
+    for child in ("serving.stage", "serving.decode_step"):
         assert all(any(inside(c, p) for p in steps)
                    for c in by_name[child]), child
-    parents = steps + by_name["serving.prefill"]
-    for c in by_name["serving.d2h_sync"]:
-        assert any(inside(c, p) for p in parents), (c, parents)
+    for child in ("serving.absorb", "serving.d2h_sync"):
+        assert all(any(inside(c, p) for p in steps + drains)
+                   for c in by_name[child]), child
     assert all(any(inside(c, p) for p in by_name["serving.admit"])
                for c in by_name["serving.prefill"])
+    # a prefill span covers the dispatch alone: no fetch inside it
+    assert not any(inside(c, p) for c in by_name["serving.d2h_sync"]
+                   for p in by_name["serving.prefill"])
     # an idle wait is no part of a step or an admission
     assert not any(inside(i, p) for i in by_name["serving.idle"]
                    for p in steps + by_name["serving.admit"])
-    # one prefill per request; a pipelined pair is ONE step, two
-    # programs, two stagings, two fetches, two bookings
+    # one prefill per request; one step per decode program: one
+    # staging, one dispatch, and — in a later step or a drain — one
+    # fetch and one booking; a prefill's first token is one fetch more
     st = engine.stats()
     assert len(by_name["serving.prefill"]) == len(SCRIPT)
     assert len(by_name["serving.decode_step"]) == st["steps"]
     assert len(by_name["serving.absorb"]) == st["steps"]
     assert len(by_name["serving.stage"]) == st["steps"]
-    assert len(steps) == st["steps"] - st["d2h_syncs_saved"]
-    assert len(by_name["serving.d2h_sync"]) == st["d2h_syncs"]
+    assert len(steps) == st["steps"]
+    assert len(by_name["serving.d2h_sync"]) == st["d2h_syncs"] \
+        == st["steps"] + st["prefills"]
+    # each request's last fetch had no program queued behind it: it is
+    # the drain's, and the only one ``d2h_syncs_saved`` leaves out
+    assert len(drains) == len(SCRIPT) == st["run_ahead_drains"]
+    assert st["d2h_syncs_saved"] == st["d2h_syncs"] - len(SCRIPT)
+    assert all(d[3]["reason"] == "idle" for d in drains)
     # what a span is about rides on it
     stage = by_name["serving.stage"][0][3]
-    assert {"sids", "active", "pipelined"} <= set(stage)
+    assert {"sids", "active", "ahead"} <= set(stage)
     assert int(stage["active"]) == 1
     assert "retired" in by_name["serving.absorb"][0][3]
 
 
 def test_untraced_run_books_the_same_spans_and_no_more_syncs(engine):
     """No trace running: the flight recorder gets the same spans, and
-    the engine does what the parent tree does for this script — the
-    pinned counters were read from the parent commit (69910ef) running
-    ``scripted_run``."""
+    the engine fetches what the parent tree fetched for this script —
+    14 steps' tokens and 3 first tokens — all but each request's last
+    with a newer program already queued (the parent, commit 0887c7e,
+    read 6 of the 17 that way: its pairs)."""
     since = (time.perf_counter()
              - profiler.clock_anchor()["perf_counter_s"]) * 1e6
     served = scripted_run(engine)
@@ -166,7 +182,10 @@ def test_untraced_run_books_the_same_spans_and_no_more_syncs(engine):
     assert set(ENGINE_SPANS) <= {family(e["name"]) for e in booked}
     st = engine.stats()
     assert (st["steps"], st["d2h_syncs"], st["d2h_syncs_saved"],
-            st["prefills"], st["tokens"]) == (14, 17, 6, 3, 17)
+            st["prefills"], st["tokens"]) == (14, 17, 14, 3, 17)
+    assert (st["steps_run_ahead"], st["run_ahead_share"],
+            st["prefill_first_deferred"], st["overshoot_row_steps"],
+            st["run_ahead_drain_reasons"]) == (14, 1.0, 3, 0, {"idle": 3})
     assert [len(s) for s in served] == [6, 3, 8]
     # the recorder's form of a span carries what was known at its END
     absorbed = [e for e in booked if e["name"] == "serving.absorb"]
