@@ -21,7 +21,10 @@ tests can gate on structure rather than on wall-clock luck:
   collective groups are separated by compute);
 - :func:`collective_bytes` — bytes written by collective ops (the
   numerator of the in-program comm fraction the GoodputTracker books);
-- :func:`shape_bytes` — size of one HLO shape literal.
+- :func:`shape_bytes` — size of one HLO shape literal;
+- :func:`scope_table` — every executed instruction with the name the
+  program gave it (``jax.named_scope``), what it is made of and a
+  class: what a device trace's ``_fusion.229`` is looked up in.
 """
 
 from __future__ import annotations
@@ -30,7 +33,8 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 __all__ = ["collective_summary", "overlap_report", "collective_bytes",
-           "shape_bytes", "COLLECTIVE_OPS"]
+           "shape_bytes", "COLLECTIVE_OPS", "scope_table", "ScopeTable",
+           "RELAYOUT_OPS"]
 
 # synchronous collective op names (scheduled HLO, SPMD-partitioned)
 COLLECTIVE_OPS = ("all-reduce", "all-gather", "reduce-scatter",
@@ -203,3 +207,236 @@ def collective_bytes(hlo_text: str) -> int:
                 continue
         total += shape_bytes(shape)
     return total
+
+
+# ---------------------------------------------------------------------
+# instruction -> the scope the program gave it
+# ---------------------------------------------------------------------
+
+# opcodes that compute nothing: an instruction (or a fusion) made of
+# these alone moves or re-types data
+RELAYOUT_OPS = frozenset((
+    "copy", "copy-start", "copy-done", "transpose", "reshape", "bitcast",
+    "convert", "broadcast", "slice", "slice-start", "slice-done",
+    "dynamic-slice", "concatenate", "pad", "tuple", "get-tuple-element",
+    "parameter", "constant", "iota"))
+
+_COLLECTIVES = frozenset(
+    op + half for op in COLLECTIVE_OPS for half in ("", "-start", "-done"))
+_KERNEL_TARGET = 'custom_call_target="tpu_custom_call"'
+
+_COMPUTATION = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT\s+)?%?([\w.\-]+) = (.*)$")
+_OPCODE = re.compile(r"\s*([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply|true_computation|"
+    r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_WRAPPED = re.compile(r"^([\w.\-]+)\((.*)\)$")
+_LAYER_NUMBER = re.compile(r"\d+")
+_COMMENT = re.compile(r"/\*.*?\*/")    # a long tuple's /*index=5*/
+# an instruction that RUNS the computations it names (a fusion's body
+# is what the fusion is made of; a reduce's ``to_apply`` is a lambda)
+_RUNS = {"while": ("body", "condition"), "call": ("to_apply",),
+         "conditional": ("true_computation", "false_computation",
+                         "branch"),
+         "async-start": ("calls",)}
+
+
+class ScopeTable(dict):
+    """``{instruction: record}`` of one program, with the program's
+    own name (the ``HloModule`` line's: what a trace's ``XLA Modules``
+    event carries before its fingerprint), the size of the text it was
+    read from and, where a caller timed it, the seconds that took."""
+
+    program = ""
+    text_bytes = 0
+    seconds = 0.0
+
+
+def _split_path(path: str) -> List[str]:
+    """``a/f(b/c)/d`` -> [a, f(b/c), d]: the slashes outside brackets."""
+    parts, depth, at = [], 0, 0
+    for i, ch in enumerate(path):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth = max(0, depth - 1)
+        elif ch == "/" and depth == 0:
+            parts.append(path[at:i])
+            at = i + 1
+    parts.append(path[at:])
+    return parts
+
+
+def _components(path: str) -> List[str]:
+    """The names of an ``op_name`` path, a transform's brackets taken
+    off (``transpose(jvp(layer3_ff1))`` is ``layer3_ff1``'s backward)
+    and every ``jit(<function>)`` left out."""
+    out: List[str] = []
+    for part in _split_path(path):
+        m = _WRAPPED.match(part)
+        if m is None:
+            if part:
+                out.append(part)
+        elif m.group(1) not in ("jit", "pjit"):
+            out += _components(m.group(2))
+    return out
+
+
+def _scope_of(op_name: str) -> str:
+    """The path between ``jit(<program>)/`` and the primitive; ``""``
+    for a name jax gave no program (a parameter's, a reducer's)."""
+    if not op_name.startswith(("jit(", "pjit(")):
+        return ""
+    return "/".join(_components(op_name)[:-1])
+
+
+def _group_of(scope: str) -> str:
+    """``layer3_ff1`` -> ``layer*_ff1``: the path's first number — the
+    layer's — is what a table is printed without (``ff1`` and
+    ``layer3_mix/mamba2_step`` keep theirs)."""
+    return _LAYER_NUMBER.sub("*", scope, count=1)
+
+
+def _after_shape(rest: str) -> str:
+    """What follows an instruction's shape (a tuple's may hold blanks)."""
+    if not rest.startswith("("):
+        return rest.partition(" ")[2]
+    depth = 0
+    for i, ch in enumerate(rest):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+            if depth == 0:
+                return rest[i + 1:]
+    return ""
+
+
+def _computations(hlo_text: str):
+    """(entry's name, {computation: [(instruction, opcode, scope,
+    {attribute: [computations]}, is a Mosaic kernel)]})."""
+    entry, comps, rows = None, {}, None
+    for line in hlo_text.splitlines():
+        if rows is None:
+            m = _COMPUTATION.match(line)
+            if m and " = " not in line.split("(", 1)[0]:
+                rows = comps.setdefault(m.group(2), [])
+                if m.group(1):
+                    entry = m.group(2)
+            continue
+        if line.startswith("}"):
+            rows = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        name, rest = m.groups()
+        op = _OPCODE.match(_after_shape(
+            _COMMENT.sub("", rest) if rest.startswith("(") else rest))
+        if op is None:
+            continue
+        called: Dict[str, List[str]] = {}
+        if "=%" in rest or "={%" in rest:
+            for attr, comp in _CALLED.findall(rest):
+                called.setdefault(attr, []).append(comp)
+            b = _BRANCHES.search(rest)
+            if b:
+                called["branch"] = [c.strip().lstrip("%")
+                                    for c in b.group(1).split(",")]
+        meta = _OP_NAME.search(rest)
+        rows.append((name, op.group(1),
+                     _scope_of(meta.group(1)) if meta else "", called,
+                     _KERNEL_TARGET in rest))
+    return entry, comps
+
+
+def _klass(opcodes, kernel: bool) -> str:
+    if kernel:
+        return "kernel"
+    if not _COLLECTIVES.isdisjoint(opcodes):
+        return "collective"
+    if "dot" in opcodes or "convolution" in opcodes:
+        return "matmul"
+    if RELAYOUT_OPS.issuperset(opcodes):
+        return "relayout"
+    return "other"
+
+
+def scope_table(hlo_text: str) -> ScopeTable:
+    """``{instruction: record}`` for every instruction a compiled
+    program RUNS: the ENTRY computation's and, through them, those of
+    the bodies of ``while`` / ``call`` / ``conditional`` (a scanned
+    stack is not one opaque row).  The key is the instruction's name
+    without its ``%`` — what a device trace's ``XLA Ops`` event is
+    called; the record:
+
+    - ``scope``: the ``op_name`` path the program's ``named_scope``s
+      wrote, ``jit(<program>)/`` and the primitive taken off, a
+      transform's brackets too (``layer3_ff1``,
+      ``optimizer_update/layer3_ff1_weight``); where the instruction
+      itself has none, the commonest scope inside its fusion; ``""``
+      where XLA left no name at all (layout copies);
+    - ``group``: ``scope`` without its first number, the layer's
+      (``layer*_ff1``, ``optimizer_update/layer*_ff1_weight``);
+    - ``opcodes``: a fusion's — the sorted opcodes of the computation
+      it ``calls=`` — else the instruction's own;
+    - ``scopes``: a fusion's every distinct scope inside it, sorted (a
+      matmul fused with its Adam update has two), else ``[scope]``;
+    - ``klass``, first match wins: ``kernel`` (a ``tpu_custom_call``),
+      ``collective`` (:data:`COLLECTIVE_OPS`, ``-start`` / ``-done``
+      too), ``matmul`` (a ``dot`` or ``convolution`` among the
+      opcodes), ``relayout`` (nothing but :data:`RELAYOUT_OPS`: it
+      computes nothing), ``other``;
+    - ``optimizer``: some scope starts with ``optimizer_update``.
+
+    Pure text in, dict out: nothing here touches jax."""
+    entry, comps = _computations(hlo_text)
+    table = ScopeTable()
+    head = re.match(r"\s*HloModule\s+([\w.\-]+)", hlo_text)
+    table.program = head.group(1) if head else ""
+    table.text_bytes = len(hlo_text)
+
+    def made_of(comp, seen):
+        """(opcodes, {scope: count}) of a fusion's body, nested
+        fusions' included."""
+        ops, scopes = set(), {}
+        for _, op, scope, called, _ in comps.get(comp, ()):
+            ops.add(op)
+            if scope:
+                scopes[scope] = scopes.get(scope, 0) + 1
+            for inner in called.get("calls", ()):
+                if inner not in seen:
+                    seen.add(inner)
+                    more, inner_scopes = made_of(inner, seen)
+                    ops |= more
+                    for k, n in inner_scopes.items():
+                        scopes[k] = scopes.get(k, 0) + n
+        return ops, scopes
+
+    todo, seen = [entry] if entry else [], {entry}
+    while todo:
+        for name, op, scope, called, kernel in comps.get(todo.pop(), ()):
+            opcodes, inside = {op}, {scope: 1} if scope else {}
+            if op == "fusion":
+                for comp in called.get("calls", ()):
+                    opcodes, inside = made_of(comp, {comp})
+                if not scope and inside:
+                    scope = max(sorted(inside), key=inside.get)
+                if scope:
+                    inside.setdefault(scope, 1)
+            for attr in _RUNS.get(op, ()):
+                for comp in called.get(attr, ()):
+                    if comp not in seen:
+                        seen.add(comp)
+                        todo.append(comp)
+            scopes = sorted(inside)
+            table[name] = {
+                "scope": scope, "group": _group_of(scope),
+                "opcodes": sorted(opcodes), "scopes": scopes,
+                "klass": _klass(opcodes, kernel),
+                "optimizer": any(s.startswith("optimizer_update")
+                                 for s in scopes)}
+    return table

@@ -158,6 +158,7 @@ class Module(BaseModule):
 
         self._use_fused = _os.environ.get("MXNET_FUSED_STEP", "1") != "0"
         self._fused_step = None
+        _prof.hold_programs(self)  # weakly: for fused_program_scopes()
         self._fused_warm = False  # first fused run = compile (telemetry)
         self._fused_state = None
         # ZeRO-1 (MXNET_ZERO): optimizer state sharded over the 'dp'
@@ -2029,6 +2030,34 @@ class Module(BaseModule):
         Reads the executable the step runs (no compile of its own);
         call after at least one fused step has run."""
         return self._fused_compiled().as_text()
+
+    def _programs_held(self) -> dict:
+        """The fused step as the profiler's registry asks for it: the
+        executable the kept lowering compiled, and nothing lowered
+        anew (``__del__`` asks too)."""
+        kept = getattr(self, "_fused_lowered", None)
+        if kept is None or kept[0] is not getattr(self, "_fused_step",
+                                                  None):
+            return {}
+        return {"fused_step": kept[1].compile()}  # cached on it
+
+    def fused_program_scopes(self) -> dict:
+        """{``jit_step_train``: {instruction: record}}: every operation
+        of the fused step under the name the symbol's node — or
+        ``optimizer_update/<param>`` — gave it (``hlo.scope_table``
+        lists the record's fields).  The text is read and parsed when
+        this is first asked, once an executable; call after at least
+        one fused step has run."""
+        self._fused_compiled()  # raises without a built step
+        return _prof.holder_scopes(self)
+
+    def __del__(self):
+        # a trace taken while the step ran can still be named once the
+        # module is gone: the executable alone is handed over
+        try:
+            _prof.retire_programs(self)
+        except Exception:  # noqa: BLE001 — interpreter shutdown
+            pass
 
     def fused_memory_analysis(self):
         """Per-device compiled memory breakdown of the fused step

@@ -80,6 +80,7 @@ import os
 import struct
 import threading
 import time
+import weakref
 from contextlib import contextmanager
 
 # host-side only: neither import opens a backend (the package has
@@ -102,7 +103,9 @@ __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "PEAK_BY_DEVICE_KIND", "MetricsServer",
            "start_metrics_server", "maybe_start_metrics_server",
            "metrics_server_running",
-           "register_statusz", "unregister_statusz", "statusz"]
+           "register_statusz", "unregister_statusz", "statusz",
+           "program_scopes", "scope_tables", "holder_scopes",
+           "hold_programs", "retire_programs"]
 
 
 def process_rank() -> int:
@@ -746,6 +749,75 @@ def record_program(name, compiled, cat="exec", args=None):
                         "compile" if compiled else cat, args=ev_args)
 
 
+# -- the names the program gave its device operations --------------------
+# Who holds compiled programs (a DecodeEngine, a Module with a fused
+# step) is known here weakly.  A holder that goes hands over its
+# programs — the executables alone: no weights, no pools — so that a
+# trace taken while it ran can still be named once it is closed: only
+# the last one gone is kept, and its executables only until its tables
+# have been asked for.
+_program_holders = weakref.WeakSet()
+_retired = {"programs": {}, "cache": {}, "tables": {}}
+
+
+def hold_programs(holder):
+    """``holder._programs_held()`` is {key: compiled executable}."""
+    _program_holders.add(holder)
+
+
+def retire_programs(holder):
+    """``holder`` goes (an engine's ``close()``): what it ran stays
+    nameable.  Builds nothing."""
+    held = holder._programs_held()
+    if held:
+        _retired.update(
+            programs=held, tables={},
+            cache=holder.__dict__.get("_scope_tables", {}))
+
+
+def scope_tables(programs, cache):
+    """{program name: ``hlo.ScopeTable``} of {key: executable}.  An
+    executable's text is asked for (``as_text()``) and parsed HERE,
+    when someone asks, once an executable (``cache``: key -> (the
+    runtime's executable, table)); a table's ``seconds`` says what
+    that took."""
+    from . import hlo
+
+    out = {}
+    for key, exe in programs.items():
+        loaded, kept = exe.runtime_executable(), cache.get(key)
+        if kept is None or kept[0] is not loaded:
+            t0 = time.perf_counter()
+            table = hlo.scope_table(exe.as_text())
+            table.seconds = time.perf_counter() - t0
+            kept = cache[key] = (loaded, table)
+        out[kept[1].program] = kept[1]
+    return out
+
+
+def holder_scopes(holder):
+    """``scope_tables`` of what ``holder`` holds, kept on the holder."""
+    return scope_tables(holder._programs_held(),
+                        holder.__dict__.setdefault("_scope_tables", {}))
+
+
+def program_scopes():
+    """{program name as a device trace's ``XLA Modules`` carry it
+    (``jit_prefill_t1024``): {instruction: record}} over every live
+    holder and the last one gone — what a reader of a trace looks a
+    device operation up in (the record: ``hlo.scope_table``) without a
+    handle on any engine.  Lazy: no text is read or parsed before
+    someone calls this."""
+    if _retired["programs"]:
+        _retired.update(
+            tables=scope_tables(_retired["programs"], _retired["cache"]),
+            programs={}, cache={})
+    out = dict(_retired["tables"])
+    for holder in list(_program_holders):
+        out.update(holder_scopes(holder))
+    return out
+
+
 # -- counters / gauges / histograms -------------------------------------
 class MetricsRegistry:
     """Lightweight serving/runtime metrics: named monotonic counters,
@@ -820,7 +892,9 @@ class MetricsRegistry:
                     100.0, 250.0, 500.0, 1000.0, 2500.0, 5000.0,
                     10000.0, 30000.0, 60000.0)
 
-    def observe(self, name, value):
+    def observe(self, name, value, n=1):
+        """One sample, or ``n`` equal ones at the price of one (a
+        decode step books its rows' shared cadence once)."""
         import bisect
 
         with self._lock:
@@ -834,12 +908,12 @@ class MetricsRegistry:
                     self._deque(maxlen=self._reservoir), 0, 0.0,
                     [0] * len(self.BUCKET_BOUNDS)]
             v = float(value)
-            h[0].append(v)
-            h[1] += 1
-            h[2] += v
+            h[0].extend((v,) * n)
+            h[1] += n
+            h[2] += n * v
             i = bisect.bisect_left(self.BUCKET_BOUNDS, v)
             if i < len(self.BUCKET_BOUNDS):
-                h[3][i] += 1
+                h[3][i] += n
 
     def summary(self):
         """→ {'counters': {...}, 'rates': {name: per-second since
@@ -925,12 +999,12 @@ def gauge_generation():
     return _metrics.generation
 
 
-def observe(name, value):
-    """Record one histogram sample (e.g. ``serving.latency_ms``).
-    Samples also land in the flight recorder as Chrome counter
-    events, so a post-mortem carries the metric timeline next to the
-    spans."""
-    _metrics.observe(name, value)
+def observe(name, value, n=1):
+    """Record one histogram sample (e.g. ``serving.latency_ms``), or
+    ``n`` equal ones at once.  Samples also land in the flight
+    recorder as Chrome counter events (one event, whatever ``n``), so
+    a post-mortem carries the metric timeline next to the spans."""
+    _metrics.observe(name, value, n)
     rec = _flight_if_enabled()
     if rec is not None:
         rec.record({"name": name, "ph": "C",

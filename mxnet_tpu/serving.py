@@ -1635,6 +1635,7 @@ class DecodeEngine:
         self._eos = eos_id
 
         self._exe_cache: Dict[tuple, Any] = {}
+        profiler.hold_programs(self)  # weakly: for program_scopes()
         self._compile_lock = threading.Lock()
         self.compiles: Dict[tuple, int] = {}
         # per-executable FLOPs (XLA cost analysis, cached at compile)
@@ -2210,6 +2211,21 @@ class DecodeEngine:
         engine's twin of ``Module.fused_hlo_text``."""
         return self._exe_cache[key].as_text()
 
+    def _programs_held(self) -> dict:
+        return dict(self._exe_cache)
+
+    def program_scopes(self) -> dict:
+        """{program name as a device trace's ``XLA Modules`` carry it
+        (``jit_prefill_t1024``, ``jit_step_decode_b48x64``,
+        ``jit_next_tokens_b48``): {instruction: record}} — every
+        operation of every executable this engine holds under the name
+        the model's symbol gave it (``hlo.scope_table`` lists the
+        record's fields).  Each executable's text is read and parsed
+        when this is first asked, once; an engine nobody asks reads
+        none.  ``profiler.program_scopes()`` is the same over every
+        holder in the process."""
+        return profiler.holder_scopes(self)
+
     # ------------------------------------------------------------------
     # lifecycle
     # ------------------------------------------------------------------
@@ -2238,6 +2254,8 @@ class DecodeEngine:
         # the /statusz section held the engine — weights and pools —
         # alive after close; a closed engine has nothing to report
         profiler.unregister_statusz("engine", self.stats)
+        # what it ran stays nameable in a trace taken while it did
+        profiler.retire_programs(self)
 
     def __enter__(self):
         return self
@@ -4067,6 +4085,7 @@ class DecodeEngine:
         profiler.observe("serving.decode_step_ms", step_ms)
         retired = []
         overshoot = 0
+        by_class: Dict[str, int] = {}
         for i, s in enumerate(streams):
             if s.done():
                 overshoot += 1
@@ -4091,9 +4110,7 @@ class DecodeEngine:
                 self._metrics.observe("ttft_hit_ms", ttft)
                 profiler.observe("serving.ttft_hit_ms", ttft)
                 self._slo.observe_ttft(s.slo_class, ttft)
-            self._metrics.observe("time_per_token_ms", step_ms)
-            profiler.observe("serving.time_per_token_ms", step_ms)
-            self._slo.observe_tpt(s.slo_class, step_ms)
+            by_class[s.slo_class] = by_class.get(s.slo_class, 0) + 1
             if s.trace is not None:
                 # every decode-step batch this stream rode in becomes
                 # one child span — a request's flame graph shows its
@@ -4105,6 +4122,15 @@ class DecodeEngine:
                           "batch": bb, "active": n})
             if s.done():
                 retired.append(s)
+        # every row's cadence is the step's: one locked insert a
+        # registry (a class, for the SLO windows), not three a row
+        if n > overshoot:
+            self._metrics.observe("time_per_token_ms", step_ms,
+                                  n - overshoot)
+            profiler.observe("serving.time_per_token_ms", step_ms,
+                             n - overshoot)
+            for slo_class, rows in by_class.items():
+                self._slo.observe_tpt(slo_class, step_ms, n=rows)
         self._count("tokens", n - overshoot)
         if overshoot:
             self._count("overshoot_row_steps", overshoot)

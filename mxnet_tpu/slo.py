@@ -197,10 +197,10 @@ class _Window:
         self.events: Deque[Tuple[float, bool]] = collections.deque()
         self.bad = 0
 
-    def add(self, t: float, good: bool):
-        self.events.append((t, good))
+    def add(self, t: float, good: bool, n: int = 1):
+        self.events.extend(((t, good),) * n)
         if not good:
-            self.bad += 1
+            self.bad += n
         self.prune(t)
 
     def prune(self, now: float):
@@ -288,8 +288,10 @@ class SloTracker:
     def observe_ttft(self, slo_class: str, ms: float, now=None):
         self._observe(slo_class, "ttft", ms, now)
 
-    def observe_tpt(self, slo_class: str, ms: float, now=None):
-        self._observe(slo_class, "tpt", ms, now)
+    def observe_tpt(self, slo_class: str, ms: float, now=None, n=1):
+        """``n`` tokens of one cadence (a decode step's rows of one
+        class) are booked at once."""
+        self._observe(slo_class, "tpt", ms, now, n)
 
     def observe_avail(self, slo_class: str, ok: bool, now=None):
         """One delivery outcome (real request or canary probe)."""
@@ -299,13 +301,14 @@ class SloTracker:
                 self._windows[(slo_class, "avail", w)].add(now, bool(ok))
         self._maybe_check(now)
 
-    def _observe(self, slo_class: str, metric: str, ms: float, now):
+    def _observe(self, slo_class: str, metric: str, ms: float, now,
+                 n: int = 1):
         target = self.config.target_ms(slo_class, metric)
         good = ms <= target
         now = time.perf_counter() if now is None else now
         with self._lock:
             for w in ("fast", "slow"):
-                self._windows[(slo_class, metric, w)].add(now, good)
+                self._windows[(slo_class, metric, w)].add(now, good, n)
         self._maybe_check(now)
 
     # -- readout --------------------------------------------------------
