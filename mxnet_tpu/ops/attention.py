@@ -503,14 +503,69 @@ def _paged_write_coords(block_table, lengths, T, KVB, start=None):
     return page, slot, live
 
 
+def _live_pages(block_table, lengths, blocks, KVB):
+    """(B, blocks) page ids of a prompt's blocks: the table's where the
+    block holds a position below ``lengths[b]``, else 0 (nowhere)."""
+    live = jnp.arange(blocks)[None, :] * KVB < lengths[:, None]
+    return jnp.where(live, block_table[:, :blocks], 0)
+
+
+def _writes_whole_pages(k, k_pool, start):
+    """May a (B, T, W) run of rows reach the (P, KVB, W) pools a page a
+    copy (``pallas_kernels.kv_pages_write``)?  Decided from what the
+    call can see: the run starts at position 0 (``start`` is traced and
+    need not be page-aligned), T is whole pages, a page is whole
+    sublane tiles of the pool's type (16 rows of bfloat16, 8 of
+    float32: a copy moves whole tiles) and the paged kernels run over
+    rows this wide at all (``pallas_kernels.paged_enabled``: lanes in
+    whole tiles when compiled).  Counted on ``/metrics`` once a traced
+    op: ``kv_write.page_kernel_calls`` / ``kv_write.row_scatter_calls``,
+    and the pages of the last such call, ``kv_write.pages_per_call``."""
+    from .. import profiler
+    from . import pallas_kernels as pk
+
+    B, T = k.shape[:2]
+    KVB, W = k_pool.shape[1:]
+    whole = (start is None and T % KVB == 0
+             and KVB % (32 // jnp.dtype(k_pool.dtype).itemsize) == 0
+             and pk.paged_enabled(W))
+    profiler.inc_counter("kv_write.page_kernel_calls", whole)
+    profiler.inc_counter("kv_write.row_scatter_calls", not whole)
+    if whole:
+        profiler.set_gauge("kv_write.pages_per_call", B * (T // KVB))
+    return whole
+
+
 def paged_prefill_write(k, v, k_pool, v_pool, block_table, lengths,
                         start=None):
-    """Scatter a prompt's (or — with ``start`` — a prompt suffix's)
-    K/V rows (B, T, H·D) into the (P, KVB, H·D) paged pools.
-    Positions >= lengths[b] (padding) are routed to the scratch page 0
-    instead of being masked out of the scatter."""
+    """Write a prompt's (or — with ``start`` — a prompt suffix's) K/V
+    rows (B, T, H·D) into the (P, KVB, H·D) paged pools.
+
+    A whole prompt from position 0 in whole pages goes page by page
+    (:func:`_writes_whole_pages`; one Mosaic kernel for K and V): block
+    j of row b lands on page ``block_table[b, j]`` where
+    ``j·KVB < lengths[b]`` and nowhere otherwise (the scratch page 0 is
+    not written; a windowed table's blocks behind the window hold 0
+    too).  THE LAST LIVE PAGE then holds the prompt's padding rows at
+    its slots >= ``lengths[b]`` — where the page's previous owner's
+    bytes were before: no reader may depend on a slot at or past the
+    length, and none does (attention masks by length, the decode step
+    writes slot ``length`` before anything reads it, the prefix index
+    registers FULL pages only, an exported frame's tail is masked by
+    its importer the same way).
+
+    Every other run (``start`` given: a suffix, a chunk, a verify
+    window; T or KVB off the tiles) is a row-wise scatter: positions
+    >= lengths[b] are routed to the scratch page 0 instead of being
+    masked out of the scatter."""
     KVB = k_pool.shape[1]
     T = k.shape[1]
+    if _writes_whole_pages(k, k_pool, start):
+        from . import pallas_kernels as pk
+
+        return tuple(pk.kv_pages_write(
+            k, v, k_pool, v_pool,
+            _live_pages(block_table, lengths, T // KVB, KVB)))
     page, slot, _ = _paged_write_coords(block_table, lengths, T, KVB,
                                         start)
     return (k_pool.at[page, slot].set(k.astype(k_pool.dtype)),
@@ -815,8 +870,15 @@ def _qkv_paged_attention_decode(op_ctx, attrs, inputs, aux):
           doc="Scatter a prefilled prompt's (B, T, H, D) key/value "
               "state, as (B, T, H*D) rows, into the (P, KVB, H*D) "
               "paged pools through each stream's block "
-              "table; positions >= lengths[b] land on the scratch page "
-              "0.  The prefill half of paged incremental decode.")
+              "table.  The prefill half of paged incremental decode.  "
+              "Whole pages of a whole prompt go a page a copy (one "
+              "Mosaic kernel for K and V) where the shapes allow "
+              "(ops.attention.paged_prefill_write): the LAST live page "
+              "then holds the prompt's padding rows at its slots >= "
+              "lengths[b], and no reader may depend on a slot at or "
+              "past the length; blocks past the length are not "
+              "written.  Otherwise row by row: positions >= lengths[b] "
+              "land on the scratch page 0.")
 def _paged_cache_write(op_ctx, attrs, inputs, aux):
     k, v, k_pool, v_pool, block_table, lengths = inputs
     new_kp, new_vp = paged_prefill_write(

@@ -186,7 +186,13 @@ def _gqa_rotated(attrs, inputs, H, Hkv):
               "that also writes its K/V rows into the paged pools: "
               "query (B, T, H*D), key/value (B, T, Hkv*D), pools "
               "(P, KVB, Hkv*D) -> output (B, T, H*D) + pools.  Query "
-              "head i reads KV head i // (H / Hkv).  " + _GQA_ATTRS)
+              "head i reads KV head i // (H / Hkv).  The write is "
+              "ops.attention.paged_prefill_write's: in whole pages "
+              "where the shapes allow, the LAST live page then holding "
+              "the prompt's padding rows at its slots >= lengths[b] "
+              "(no reader may depend on a slot at or past the length); "
+              "a windowed pool's table holds 0 for the blocks behind "
+              "the window, which are not written.  " + _GQA_ATTRS)
 def _gqa_prefill(op_ctx, attrs, inputs, aux):
     from . import pallas_kernels as pk
     from .attention import (_blockwise_attention_partial_lax,

@@ -159,7 +159,9 @@ def slot_rows_write(pool, rows, slots):
     row b written to slot ``slots[b]`` (padded rows share the scratch
     slot; the last writer wins).  One whole-tile DMA a row into the
     aliased pool: as an XLA scatter this is a loop of B row updates
-    (2.3 ms for 128 rows of 295 KB on the chip, PERF.md section 6)."""
+    (2.3 ms for 128 rows of 295 KB on the chip, PERF.md section 6).
+    ``pallas_kernels.kv_pages_write`` is the sibling that writes a
+    prompt's K/V rows into the paged pools, a page a copy."""
     B = rows.shape[0]
     blk = (1,) + tuple(rows.shape[1:])
     grid_spec = pltpu.PrefetchScalarGridSpec(

@@ -2066,3 +2066,105 @@ def paged_attention_verify(q, k_pool, v_pool, block_table, start,
     at length ``start[b] + i + 1``."""
     return _paged_attention(q, k_pool, v_pool, (), block_table, start,
                             num_heads)
+
+
+# ---------------------------------------------------------------------------
+# kv_pages_write: a prompt's K/V rows into the pools, a page a copy
+# ---------------------------------------------------------------------------
+
+# Page copies in flight, K's and V's each (never more than the call
+# has).  A copy goes straight from the rows to the pool, wherever XLA
+# keeps the rows (a page is one whole tile row of both arrays: no
+# buffer of the kernel's own between them); a few in flight hide a
+# copy's latency, and the rest of the loop only issues.
+_KV_WRITE_DEPTH = 8
+
+
+def _kv_pages_kernel(pages_ref, k_hbm, v_hbm, k_pool, v_pool, k_out, v_out,
+                     sem, *, kvb, blocks, depth):
+    del k_pool, v_pool  # aliased to the outputs: written through those
+    total = pages_ref.shape[0] * blocks
+
+    def copies(i, op):
+        # block i of the call: rows j·KVB .. of row b to their page.
+        # The scratch page is nobody's: a block that would go there
+        # (padding past the length, a block behind a window) is not
+        # copied at all
+        b, j = i // blocks, i % blocks
+        page = pages_ref[b, j]
+
+        @pl.when(page > 0)
+        def _():
+            rows = pl.ds(pl.multiple_of(j * kvb, kvb), kvb)
+            for n, (src, dst) in enumerate(((k_hbm, k_out),
+                                            (v_hbm, v_out))):
+                getattr(pltpu.make_async_copy(
+                    src.at[b, rows], dst.at[page], sem.at[n]), op)()
+
+    def issue(i, _):
+        @pl.when(i >= depth)
+        def _():
+            copies(i - depth, "wait")
+
+        copies(i, "start")
+
+    jax.lax.fori_loop(0, total, issue, None)
+    jax.lax.fori_loop(total - depth, total,
+                      lambda i, _: copies(i, "wait"), None)
+
+
+@functools.lru_cache(maxsize=None)
+def _kv_pages_write_fn(interpret):
+    """The call, jitted: a model's layers make it with one shape, so
+    the kernel is traced and lowered once a program, not once a layer
+    (``_flash_mha_packed_fn`` says why ``interpret`` is in the key)."""
+    def write(pages, k, v, k_pool, v_pool):
+        blocks = pages.shape[1]
+        hbm = pl.BlockSpec(memory_space=pl.ANY)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(1,),
+            in_specs=[hbm, hbm, hbm, hbm], out_specs=[hbm, hbm],
+            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))])
+        return pl.pallas_call(
+            functools.partial(
+                _kv_pages_kernel, kvb=k_pool.shape[1], blocks=blocks,
+                depth=min(_KV_WRITE_DEPTH, pages.shape[0] * blocks)),
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                       jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+            input_output_aliases={3: 0, 4: 1},
+            compiler_params=_compiler_params("arbitrary"),
+            interpret=interpret,
+            name="kv_pages_write",
+        )(pages, k, v, k_pool, v_pool)
+
+    return jax.jit(write)
+
+
+def kv_pages_write(k, v, k_pool, v_pool, pages):
+    """k, v (B, T, W) rows; pools (P, KVB, W), T a multiple of KVB;
+    pages (B, T / KVB) int32 -> the pools with block j of row b (rows
+    ``j·KVB .. (j+1)·KVB - 1``, one whole page) written to page
+    ``pages[b, j]``, K's and V's in ONE call.  A block whose page is 0,
+    the scratch page, is skipped.  The pools are aliased to the
+    outputs: donated under jit, the write is in place.
+
+    Each page is one copy of a whole (KVB, W) tile row from the rows to
+    the pool, a few in flight: as an XLA scatter the same write is a
+    loop of B·T row updates (0.147 ms for 1,024 rows of 2.5 KB on the
+    chip, 18 GB/s: PERF.md section 6, PR 37).
+    ``pallas_hybrid.slot_rows_write`` is the sibling that writes one
+    row a STREAM into a slot pool.
+
+    The name holds no ``paged_attention``: the benchmark's readers book
+    every kernel so named to the decode step's attention."""
+    B, T, _ = k.shape
+    kvb = k_pool.shape[1]
+    if T % kvb or pages.shape != (B, T // kvb):
+        raise MXNetError(
+            f"kv_pages_write: rows {tuple(k.shape)} want whole pages of "
+            f"{kvb} and a (B, T / {kvb}) table; got pages "
+            f"{tuple(pages.shape)}")
+    return _kv_pages_write_fn(_interpret())(
+        pages.astype(jnp.int32), k.astype(k_pool.dtype),
+        v.astype(v_pool.dtype), k_pool, v_pool)

@@ -256,23 +256,35 @@ def _decode_pool_ops(heads, pool):
                           ((_CB, _MB), i32), ((_CB,), i32)]
 
 
-def _prefill_pool_ops(heads, pool):
-    rows = ((1, 1024, heads * _D), bf16)
+def _prefill_pool_ops(heads, pool, T=1024):
+    rows = ((1, T, heads * _D), bf16)
     return attention.paged_prefill_write, (2, 3), [
-        rows, rows, pool, pool, ((1, _MB), i32), ((1,), i32)]
+        rows, rows, pool, pool, ((1, T // _KVB), i32), ((1,), i32)]
 
 
-@pytest.mark.parametrize("heads", [20, 16])
-@pytest.mark.parametrize("ops", [_decode_pool_ops, _prefill_pool_ops])
-def test_pool_ops_update_the_pools_in_place(on_chip, one_chip, ops, heads):
-    pool = _pool(bf16, _CP, heads)
-    fn, donated, shapes = ops(heads, pool)
+@pytest.mark.parametrize("ops,heads,pages,T", [
+    (_decode_pool_ops, 20, _CP, 1), (_decode_pool_ops, 16, _CP, 1),
+    (_prefill_pool_ops, 20, _CP, 1024), (_prefill_pool_ops, 16, _CP, 1024),
+    # the mixed cell's longest bucket over its ordinary pools (4 KV
+    # heads x 128 = 512 lanes) and the reason cell's (8 x 128)
+    (_prefill_pool_ops, 8, 26113, 8192), (_prefill_pool_ops, 16, 20481, 2048),
+], ids=lambda v: getattr(v, "__name__", str(v)).strip("_"))
+def test_pool_ops_update_the_pools_in_place(on_chip, one_chip, ops, heads,
+                                            pages, T):
+    pool = _pool(bf16, pages, heads)
+    fn, donated, shapes = ops(heads, pool) if ops is _decode_pool_ops \
+        else ops(heads, pool, T)
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
             for s, d in shapes]
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     text = compiled.as_text()
-    if ops is _decode_pool_ops:
-        assert "tpu_custom_call" in text, "no paged kernel in the step"
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the program"
+    if ops is _prefill_pool_ops:
+        # a prompt goes in a page a copy (kv_pages_write), K and V in
+        # one kernel: no row-wise scatter is left
+        (kernel,) = _kernel_names(text)
+        assert "kv_pages_write" in kernel
+        assert not re.findall(r"\bscatter\(", text)
     dims = ",".join(str(n) for n in pool[0])
     # no instruction COPIES something pool-shaped ...
     copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(.*", text)
@@ -282,7 +294,7 @@ def test_pool_ops_update_the_pools_in_place(on_chip, one_chip, ops, heads):
                  if "entry_computation_layout" in ln)
     assert entry.count(f"bf16[{dims}]{{2,1,0") == 2 * len(donated), entry
     # ... and what the program needs beside them is less than one pool
-    pool_bytes = 2 * _CP * _KVB * heads * _D
+    pool_bytes = 2 * pages * _KVB * heads * _D
     assert compiled.memory_analysis().temp_size_in_bytes < pool_bytes
 
 

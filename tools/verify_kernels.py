@@ -23,6 +23,9 @@ after ANY kernel change:
     python tools/verify_kernels.py --tiles  # the packed kernels' tile
                                             # schedules at the cells'
                                             # shapes, ms a call each
+    python tools/verify_kernels.py --pages  # a prefill's K/V write: the
+                                            # page kernel against the row
+                                            # scatter at the cells' shapes
 """
 
 import functools
@@ -409,6 +412,118 @@ def check_mamba2(T, n, B=1, H=128, P=64, N=128):
     return ok
 
 
+def _grid_pages_write(k, v, k_pool, v_pool, pages):
+    """The other form of the page write (ISSUE 37): a grid step a page
+    through VMEM, the out BlockSpec's index map reading the page id, as
+    ``pallas_hybrid.slot_rows_write`` writes its rows; a dead block goes
+    to the scratch page.  Kept here for the comparison alone."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    B, T, W = k.shape
+    kvb = k_pool.shape[1]
+
+    def kernel(pages_ref, k_ref, v_ref, kp, vp, k_out, v_out):
+        del pages_ref, kp, vp
+        k_out[...] = k_ref[...]
+        v_out[...] = v_ref[...]
+
+    rows = pk._vmem_spec((1, kvb, W), lambda b, j, pg: (b, j, 0))
+    page = pk._vmem_spec((1, kvb, W), lambda b, j, pg: (pg[b, j], 0, 0))
+    pool = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(B, T // kvb),
+            in_specs=[rows, rows, pool, pool], out_specs=[page, page]),
+        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
+                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
+        input_output_aliases={3: 0, 4: 1},
+        compiler_params=pk._compiler_params("arbitrary", "arbitrary"),
+        interpret=pk._interpret(), name="kv_pages_write_grid",
+    )(pages, k, v, k_pool, v_pool)
+
+
+def _write_ms(fn, k, v, pools, table, lengths, n=10):
+    """Device ms a call of ``fn`` (every op of its program, summed) over
+    n calls that hand the donated pools on; (ms, the pools after)."""
+    import shutil
+    import tempfile
+
+    from benchmark.trace_reduce import Trace
+
+    pools = fn(k, v, *pools, table, lengths)
+    jax.block_until_ready(pools)
+    d = tempfile.mkdtemp(prefix="verify_kernels_")
+    try:
+        jax.profiler.start_trace(d)
+        for _ in range(n):
+            pools = fn(k, v, *pools, table, lengths)
+        jax.block_until_ready(pools)
+        jax.profiler.stop_trace()
+        return 1e3 * sum(Trace.from_dir(d).op_seconds().values()) / n, pools
+    finally:
+        shutil.rmtree(d, ignore_errors=True)
+
+
+def check_pages_write(T, W, P, live, behind=0, KVB=16):
+    """A (1, T, W) prompt of ``live`` tokens into bfloat16 pools of P
+    pages, its first ``behind`` blocks behind a window (table 0): the
+    page kernel ``paged_prefill_write`` takes, bit for bit against the
+    row scatter on every live slot and every page the table does not
+    name, and the device ms a call of the row scatter, the kernel and
+    the grid form."""
+    from mxnet_tpu.ops import attention as att
+
+    rng = np.random.RandomState(T + W)
+    k, v = (jnp.asarray(rng.randn(1, T, W).astype(np.float32))
+            .astype(jnp.bfloat16) for _ in range(2))
+    blocks = T // KVB
+    table = np.zeros((1, blocks), np.int32)
+    table[0, behind:] = 1 + rng.permutation(P - 1)[:blocks - behind]
+    table, lengths = jnp.asarray(table), jnp.asarray([live], jnp.int32)
+
+    def fresh():
+        return [jnp.full((P, KVB, W), 7, jnp.bfloat16) for _ in range(2)]
+
+    def pages_of(lengths):
+        return att._live_pages(table, lengths, blocks, KVB)
+
+    forms = {
+        "row_scatter": lambda k, v, kp, vp, t, n: att.paged_prefill_write(
+            k, v, kp, vp, t, n, start=jnp.zeros_like(n)),
+        "kv_pages_write": att.paged_prefill_write,
+        "grid_form": lambda k, v, kp, vp, t, n: tuple(_grid_pages_write(
+            k, v, kp, vp, pages_of(n))),
+    }
+    ms, pools = {}, {}
+    for name, fn in forms.items():
+        ms[name], pools[name] = _write_ms(
+            jax.jit(fn, donate_argnums=(2, 3)), k, v, fresh(), table,
+            lengths)
+    named = np.zeros((P,), bool)
+    named[np.asarray(pages_of(lengths))[0]] = True
+    named[0] = True                     # the scratch page is nobody's
+    ok = True
+    for rows, want, got in zip((k, v), pools["row_scatter"],
+                               pools["kv_pages_write"]):
+        there = got[table[0]].reshape(T, W)[behind * KVB:live]
+        ok &= bool(jnp.array_equal(there, rows[0, behind * KVB:live]))
+        ok &= bool(jnp.array_equal(
+            there, want[table[0]].reshape(T, W)[behind * KVB:live]))
+        ok &= bool(jnp.all(jnp.where(jnp.asarray(named)[:, None, None],
+                                     True, got == 7)))
+    moved = 2 * 2 * (live - behind * KVB) * W * 2
+    print(f"{'OK ' if ok else 'FAIL'} pages  T={T} W={W} P={P} live={live} "
+          f"behind={behind}: "
+          + " ".join(f"{k}={v:.4f}ms" for k, v in ms.items())
+          + f" ({moved / 1e6:.1f} MB read + written: "
+          f"{moved / 819e9 * 1e3:.4f}ms at 819 GB/s)", flush=True)
+    return ok
+
+
 def _paged_matrix(quick):
     results = []
     # the cells' widths (20 and 16 heads of 64), a head a quarter of a
@@ -434,6 +549,19 @@ def main():
     results = []
     if "--tiles" in sys.argv:
         return _report(sweep_tiles())
+    if "--pages" in sys.argv:
+        # a prompt's K/V write at the serving cells' shapes: doc's one
+        # bucket (a whole and a typical prompt), reason's two, mixed's
+        # four over its ordinary pools and, past the window, over its
+        # windowed pools (the blocks behind the window go nowhere)
+        for T, W, P, live, behind in (
+                (1024, 1280, 3073, 1024, 0), (1024, 1280, 3073, 760, 0),
+                (1024, 1024, 20481, 900, 0), (2048, 1024, 20481, 1531, 0),
+                (1024, 512, 26113, 700, 0), (2048, 512, 26113, 1531, 0),
+                (4096, 512, 26113, 3000, 0), (8192, 512, 26113, 8192, 0),
+                (8192, 512, 12385, 8000, 244)):
+            results.append(check_pages_write(T, W, P, live, behind))
+        return _report(results)
     if "--mamba2" in sys.argv:
         # the granite cell's own shapes: a 64-row decode step, prompts
         # in the 1024 and 2048 buckets (whole and ending inside a chunk)
