@@ -60,7 +60,7 @@ from .base import MXNetError
 __all__ = ["BlockAllocator", "SlotAllocator", "blocks_for_tokens",
            "bucket_ladder", "trim_blocks", "kv_storage_dtype",
            "kv_quantized", "pool_device_bytes", "value_pool_shape",
-           "state_pool_shape", "conv_tail_shape", "KV_DTYPES", "KV_QMAX"]
+           "latent_pool_shape", "state_pool_shape", "conv_tail_shape", "KV_DTYPES", "KV_QMAX"]
 
 SCRATCH_PAGE = 0
 
@@ -127,6 +127,24 @@ def value_pool_shape(pages: int, kv_block: int, num_heads: int,
     return (int(pages), int(kv_block), int(num_heads) * int(d_head))
 
 
+LANE_TILE = 128
+
+
+def latent_pool_shape(pages: int, kv_block: int, kv_rank: int,
+                      rope_dim: int) -> tuple:
+    """The shape of a latent-attention layer's ONE pool: ``(pages,
+    kv_block, lanes)``, a token's row = [the compressed latent
+    (``kv_rank``) | its rotated positional key (``rope_dim``) | zeros]
+    with ``lanes`` the next whole number of 128-lane tiles: 512 + 64 =
+    576 values are 4.5 tiles and are held as 640 (11% padding), because
+    the paged kernels copy a page's rows in whole lane tiles
+    (``ops/pallas_kernels.paged_enabled``) and a row that is one span of
+    one array is one copy a page, one matmul a chunk — the zero lanes
+    add nothing to a score and are never read as a value."""
+    need = int(kv_rank) + int(rope_dim)
+    return (int(pages), int(kv_block), -(-need // LANE_TILE) * LANE_TILE)
+
+
 def state_pool_shape(slots: int, head_state) -> tuple:
     """Shape of a recurrent layer's STATE pool: per slot the mixer's
     ``head_state`` = (heads, rows, lanes) float32 stack of matrices —
@@ -157,14 +175,21 @@ def conv_tail_shape(slots: int, kernel: int, channels: int) -> tuple:
 def pool_device_bytes(cache_blocks: int, kv_block: int,
                       num_layers: int, num_heads: int, d_model: int,
                       kv_dtype: str = "fp32", tp: int = 1,
-                      pp: int = 1) -> int:
+                      pp: int = 1, latent_row=None) -> int:
     """Bytes of K/V pool (values + quantization scales) EACH device
     holds for a serving engine meshed ``tp x pp``: the stacked layer
     dim shards over 'pp' (stage-resident slabs) and the head dim over
     'tp', so per-device bytes fall as 1/(tp*pp).  ``tp=pp=1`` is the
     single-device total — capacity planners (and bench_serving's
     --tp sizing) compare the two to prove a model's pool doesn't fit
-    one chip."""
+    one chip.  ``latent_row`` = (kv_rank, rope_dim): the layers keep one
+    latent row a token (:func:`latent_pool_shape`: ONE pool a layer,
+    shared by all heads — nothing for ``tp`` to cut) in place of K and V
+    rows of ``d_model``."""
+    if latent_row is not None:
+        pool = latent_pool_shape(cache_blocks, kv_block, *latent_row)
+        return int(num_layers) * int(np.prod(pool)) \
+            * kv_storage_dtype(kv_dtype).itemsize // int(pp)
     pool = value_pool_shape(cache_blocks, kv_block, num_heads,
                             int(d_model) // int(num_heads))
     per_layer = int(np.prod(pool)) * kv_storage_dtype(kv_dtype).itemsize

@@ -15,6 +15,20 @@ MIXER and its own FFN:
   layer's are pages of a SECOND pool (kind ``window_pages``, addressed
   through the ``window_table`` feed), in which a stream holds only the
   pages its window still reaches;
+* mixer ``mla``: multi-head latent attention — ``heads`` heads whose
+  queries come through a rank-``q_rank`` bottleneck (with its own norm)
+  and whose keys and values are up-projections of ONE compressed row a
+  token, ``kv_rank`` values (normalised) beside a positional key of
+  ``rope_dim`` shared by every head; a head's query and key are
+  ``nope_dim`` lanes without positions beside ``rope_dim`` rotated ones
+  (rotate-half, base ``rope_theta``, frequencies rescaled by
+  ``rope_scaling`` — the published dict, ``type`` yarn — where given),
+  its value ``v_dim``; ``scale``: the scores' multiplier (derived from
+  the widths and ``rope_scaling`` where absent).  Its per-stream state
+  is PAGES of that row (``kv_cache.latent_pool_shape``: ONE pool a
+  layer); a prefill up-projects and attends, a decode step absorbs the
+  up-projection into the query and the output and attends over the rows
+  as they lie (``ops/hybrid.py``);
 * mixer ``kda``: gated-delta-rule linear attention with a per-channel
   decay (``heads`` heads of ``head_dim``, a depthwise short convolution
   of ``conv`` taps on q, k and v, low-rank decay and output-gate maps of
@@ -35,7 +49,11 @@ MIXER and its own FFN:
   ``act``: the experts' gate, ``silu`` — the default — or ``relu``;
   ``router_input``: ``ffn`` — the router scores the rows the experts
   get, the default — or ``block`` — the block's un-normalised input,
-  before attention),
+  before attention; under ``sigmoid``, ``select_bias``: a learned bias
+  (parameter ``router_bias``) moves the CHOICE and not the weights,
+  ``groups`` / ``top_groups``: the choice is made inside the best
+  ``top_groups`` of ``groups`` groups of consecutive experts,
+  ``routed_scale``: the normalised weights' multiplier),
   plus ``shared`` always-on experts (of ``shared_width`` together;
   default ``width`` each); this program HOLDS ``experts_held`` of the routed experts,
   from ``first_expert`` on — the share of one chip of an expert-parallel
@@ -50,19 +68,21 @@ the spec too: ``embed_scale`` (on the token rows), ``residual_scale``
 (on every block's output before it is added), ``logits_scale`` (on the
 last norm's output before the head) and ``tied_head`` (the head is the
 token table).  The equations are in ``benchmark/reference/
-solar_open2.py``, ``granitemoehybrid.py`` and ``smallthinker.py``, the
-plain references this family is held to.
+solar_open2.py``, ``granitemoehybrid.py``, ``smallthinker.py`` and
+``deepseek_v3.py``, the plain references this family is held to.
 """
 
 from .. import symbol as sym
 from ..base import MXNetError
-from ..ops.hybrid import EXPERT_ACTS
+from ..ops.hybrid import EXPERT_ACTS, mla_scale
 
 # every key a mixer or an FFN dict may hold, by kind: another is a typo
 # that would silently build a model without the mechanism it names
 MIXERS = {
     "attention": ("kind", "heads", "kv_heads", "head_dim", "scale", "gate",
                   "rope_theta", "window"),
+    "mla": ("kind", "heads", "q_rank", "kv_rank", "nope_dim", "rope_dim",
+            "v_dim", "rope_theta", "rope_scaling", "scale"),
     "kda": ("kind", "heads", "head_dim", "conv", "neg_eigval"),
     "mamba2": ("kind", "heads", "head_dim", "d_state", "groups", "conv",
                "conv_bias"),
@@ -71,7 +91,8 @@ FFNS = {
     "dense": ("kind", "width"),
     "moe": ("kind", "experts", "top_k", "width", "score", "shared",
             "shared_width", "experts_held", "first_expert", "act",
-            "router_input"),
+            "router_input", "groups", "top_groups", "routed_scale",
+            "select_bias"),
 }
 ROUTER_INPUTS = ("ffn", "block")
 COUNTERS = "moe_counters"
@@ -158,6 +179,23 @@ class HybridSpec:
                 raise MXNetError(
                     f"layer {i}: {m['kv_heads']} KV heads do not divide "
                     f"{m['heads']} query heads")
+            if m["kind"] == "mla":
+                rs = m.get("rope_scaling")
+                if rs is not None and (
+                        not isinstance(rs, dict)
+                        or rs.get("type", "yarn") != "yarn"
+                        or (float(rs.get("factor", 1.0)) > 1.0 and not
+                            rs.get("original_max_position_embeddings"))):
+                    raise MXNetError(
+                        f"layer {i}: rope_scaling {rs!r} is no dict of "
+                        f"type 'yarn' (the one rescaling built) with its "
+                        f"original_max_position_embeddings")
+                if int(m["rope_dim"]) % 2:
+                    raise MXNetError(
+                        f"layer {i}: rope_dim {m['rope_dim']} is rotated "
+                        f"in pairs")
+                m.setdefault("scale", mla_scale(
+                    int(m["nope_dim"]), int(m["rope_dim"]), rs))
             if m["kind"] == "mamba2" and int(m.get("groups", 1)) != 1:
                 raise MXNetError(
                     f"layer {i}: a mamba2 mixer of {m['groups']} groups "
@@ -179,6 +217,25 @@ class HybridSpec:
                 f"would need a page pool each; one width is built")
         # the K/V page geometry (what the engine sizes its pools by)
         self.kv_heads, self.head_dim = pages.pop() if pages else (0, 0)
+        latents = {(int(ly["mixer"]["kv_rank"]), int(ly["mixer"]["rope_dim"]))
+                   for ly in self.layers if ly["mixer"]["kind"] == "mla"}
+        if len(latents) > 1 or (latents and self.kv_heads):
+            raise MXNetError(
+                f"mla layers of latent rows {sorted(latents)} beside "
+                f"attention layers of K/V width "
+                f"{(self.kv_heads, self.head_dim)}: one page geometry a "
+                f"spec is built (a second would need a page pool and its "
+                f"frames each)")
+        # a spec of mla layers caches ONE row a token and layer, shared
+        # by all heads: (the values it needs, the lanes its pool spends)
+        self.latent_row = None
+        if latents:
+            from ..kv_cache import latent_pool_shape
+
+            rank, rope = latents.pop()
+            lanes = latent_pool_shape(1, 1, rank, rope)[2]
+            self.latent_row = (rank + rope, lanes)
+            self.kv_heads, self.head_dim = 1, lanes
         windows = {int(ly["mixer"]["window"]) for ly in self.layers
                    if ly["mixer"].get("window")}
         if len(windows) > 1:
@@ -189,7 +246,8 @@ class HybridSpec:
         # the keys a windowed layer's pools keep a stream (0: no such
         # layer): what the engine sizes the second page pool by
         self.window = windows.pop() if windows else 0
-        rotary = any(ly["mixer"].get("rope_theta") for ly in self.layers)
+        rotary = any(ly["mixer"].get("rope_theta")
+                     or ly["mixer"]["kind"] == "mla" for ly in self.layers)
         self.feeds = ("data", "lengths", "block_table", "slots") \
             + (("positions",) if rotary else ()) \
             + (("window_table",) if self.window else ())
@@ -219,7 +277,8 @@ class HybridSpec:
         through the block table), ``window_pages`` (K/V of a windowed
         layer, through the window table) or ``slots`` (one row a
         stream)."""
-        return tuple("slots" if ly["mixer"]["kind"] != "attention" else
+        return tuple("pages" if ly["mixer"]["kind"] == "mla" else
+                     "slots" if ly["mixer"]["kind"] != "attention" else
                      "window_pages" if ly["mixer"].get("window") else "pages"
                      for ly in self.layers)
 
@@ -231,8 +290,9 @@ class HybridSpec:
         state is what ``return_state`` reads, its convolution's tail
         rides in the same slot."""
         out = []
-        for k in self.cache_kinds():
-            out += ["slots", "slots_aux"] if k == "slots" else [k, k]
+        for k, ly in zip(self.cache_kinds(), self.layers):
+            out += ["slots", "slots_aux"] if k == "slots" else \
+                [k] if ly["mixer"]["kind"] == "mla" else [k, k]
         return tuple(out) + (("counters",) if self.has_moe() else ())
 
     def pools(self, cache_blocks, kv_block, slots, dtype, kv_dtype="fp32",
@@ -243,13 +303,17 @@ class HybridSpec:
         them for this family); a windowed layer's pools hold
         ``window_blocks`` pages, a page-id space of their own; slot
         state is float32 whatever the model's."""
-        from ..kv_cache import (conv_tail_shape, state_pool_shape,
-                                value_pool_shape)
+        from ..kv_cache import (conv_tail_shape, latent_pool_shape,
+                                state_pool_shape, value_pool_shape)
 
         out = []
         for i, ly in enumerate(self.layers):
             m = ly["mixer"]
-            if m["kind"] == "attention":
+            if m["kind"] == "mla":
+                out.append((f"layer{i}_latent_pool", latent_pool_shape(
+                    cache_blocks, kv_block, m["kv_rank"], m["rope_dim"]),
+                    dtype, 0))
+            elif m["kind"] == "attention":
                 shape = value_pool_shape(
                     window_blocks if m.get("window") else cache_blocks,
                     kv_block, self.kv_heads, self.head_dim)
@@ -312,6 +376,52 @@ def _attention(spec, h, i, m, step, feeds):
     return _fc(out, spec.d_model, f"{name}_o"), [att[1], att[2]]
 
 
+def _mla(spec, h, i, m, step, feeds):
+    H, R = int(m["heads"]), int(m["kv_rank"])
+    n, r, dv = int(m["nope_dim"]), int(m["rope_dim"]), int(m["v_dim"])
+    name = f"layer{i}"
+    rs = m.get("rope_scaling") or {}
+    attrs = dict(num_heads=H, nope_dim=n, rope_dim=r, v_dim=dv, kv_rank=R)
+    rot = dict(scale=float(m["scale"]),
+               rope_theta=float(m.get("rope_theta", 10000.0)))
+    if float(rs.get("factor", 1.0)) > 1.0:
+        rot.update(
+            rope_factor=float(rs["factor"]),
+            rope_orig_len=float(rs["original_max_position_embeddings"]),
+            rope_beta_fast=float(rs.get("beta_fast", 32)),
+            rope_beta_slow=float(rs.get("beta_slow", 1)))
+    # rows: [every head's q_n | every head's q_r]
+    q = _fc(_norm(_fc(h, int(m["q_rank"]), f"{name}_q_down"),
+                  f"{name}_q_norm", spec.norm_eps),
+            H * (n + r), f"{name}_q_up")
+    kv = _fc(h, R + r, f"{name}_kv_down")
+    c = _norm(sym.slice_axis(kv, axis=-1, begin=0, end=R),
+              f"{name}_kv_norm", spec.norm_eps)
+    k_r = sym.slice_axis(kv, axis=-1, begin=R, end=R + r)
+    tail = [sym.Variable(f"{name}_latent_pool"), feeds["block_table"],
+            feeds["lengths"], feeds["positions"]]
+    # rows: [every head's W_uk | every head's W_uv]; a prefill applies
+    # it to the latents, a decode step to the query and the output
+    w_up = sym.Variable(f"{name}_kv_up_weight")
+    if step:
+        qa = sym.MLAAbsorb(
+            sym.slice_axis(q, axis=-1, begin=0, end=H * n), w_up,
+            name=f"{name}_absorb_k", **attrs)
+        att = sym.MLAPagedDecode(
+            qa, sym.slice_axis(q, axis=-1, begin=H * n, end=H * (n + r)),
+            c, k_r, *tail, name=f"{name}_attn", **attrs, **rot)
+        out = sym.MLAAbsorb(att[0], w_up, value=True,
+                            name=f"{name}_absorb_v", **attrs)
+    else:
+        up = sym.FullyConnected(c, num_hidden=H * (n + dv), flatten=False,
+                                no_bias=True, name=f"{name}_kv_up",
+                                weight=w_up)
+        att = sym.MLAPrefillAttention(q, up, c, k_r, *tail,
+                                      name=f"{name}_attn", **attrs, **rot)
+        out = att[0]
+    return _fc(out, spec.d_model, f"{name}_o"), [att[1]]
+
+
 def _kda(spec, h, i, m, step, feeds):
     H, D = int(m["heads"]), int(m["head_dim"])
     name = f"layer{i}"
@@ -364,7 +474,8 @@ def _mamba2(spec, h, i, m, step, feeds):
     return _fc(out, spec.d_model, f"{name}_out"), [rec[1], conv[1]]
 
 
-_MIXER_BUILDERS = {"attention": _attention, "kda": _kda, "mamba2": _mamba2}
+_MIXER_BUILDERS = {"attention": _attention, "mla": _mla, "kda": _kda,
+                   "mamba2": _mamba2}
 
 
 def _ffn(spec, h, x_in, i, f, step, feeds, counters):
@@ -383,6 +494,13 @@ def _ffn(spec, h, x_in, i, f, step, feeds, counters):
     if f.get("router_input", "ffn") == "block":
         attrs["router_data"] = True
         args.append(x_in)
+    if f.get("select_bias"):
+        attrs["select_bias"] = True
+        args.append(sym.Variable(f"{name}_router_bias"))
+    for k, cast in (("groups", int), ("top_groups", int),
+                    ("routed_scale", float)):
+        if f.get(k):
+            attrs[k] = cast(f[k])
     routed = sym.MoEFFN(
         *args, top_k=int(f["top_k"]),
         first_expert=int(f.get("first_expert", 0)), step=step,

@@ -475,15 +475,22 @@ def paged_cache_update(k_pool, v_pool, k_t, v_t, block_table, lengths):
     the reserved scratch page: inactive streams (lengths == 0) land
     there, so the scatter needs no masking and never corrupts a live
     page."""
-    KVB = k_pool.shape[1]
+    page, slot = _step_write_coords(block_table, lengths, k_pool.shape[1])
+    return (k_pool.at[page, slot].set(k_t[:, 0].astype(k_pool.dtype)),
+            v_pool.at[page, slot].set(v_t[:, 0].astype(v_pool.dtype)))
+
+
+def _step_write_coords(block_table, lengths, KVB):
+    """(page, slot) of the current token's row, position ``lengths - 1``
+    of each stream; a stream with lengths == 0 lands on the scratch
+    page's slot 0."""
     pos = jnp.maximum(lengths - 1, 0)
     B = block_table.shape[0]
     rows = jnp.arange(B)
     page = jnp.where(lengths > 0,
                      block_table[rows, pos // KVB], 0)
     slot = jnp.where(lengths > 0, pos % KVB, 0)
-    return (k_pool.at[page, slot].set(k_t[:, 0].astype(k_pool.dtype)),
-            v_pool.at[page, slot].set(v_t[:, 0].astype(v_pool.dtype)))
+    return page, slot
 
 
 def _paged_write_coords(block_table, lengths, T, KVB, start=None):
@@ -570,6 +577,32 @@ def paged_prefill_write(k, v, k_pool, v_pool, block_table, lengths,
                                         start)
     return (k_pool.at[page, slot].set(k.astype(k_pool.dtype)),
             v_pool.at[page, slot].set(v.astype(v_pool.dtype)))
+
+
+def latent_cache_update(pool, row, block_table, lengths):
+    """:func:`paged_cache_update` for a layer that keeps ONE row a token
+    (a compressed latent: ``kv_cache.latent_pool_shape``): row (B, 1, W)
+    into pool (P, KVB, W) at position ``lengths - 1``; rows with
+    lengths == 0 land on the scratch page."""
+    page, slot = _step_write_coords(block_table, lengths, pool.shape[1])
+    return pool.at[page, slot].set(row[:, 0].astype(pool.dtype))
+
+
+def latent_prefill_write(rows, pool, block_table, lengths):
+    """:func:`paged_prefill_write` for ONE pool: a whole prompt's rows
+    (B, T, W) into (P, KVB, W), a page a copy under the same rule
+    (:func:`_writes_whole_pages`; ``pallas_kernels.latent_pages_write``)
+    — the last live page then holds the padding's rows past the length,
+    which no reader depends on — and a row-wise scatter otherwise."""
+    KVB = pool.shape[1]
+    T = rows.shape[1]
+    if _writes_whole_pages(rows, pool, None):
+        from . import pallas_kernels as pk
+
+        return pk.latent_pages_write(
+            rows, pool, _live_pages(block_table, lengths, T // KVB, KVB))
+    page, slot, _ = _paged_write_coords(block_table, lengths, T, KVB)
+    return pool.at[page, slot].set(rows.astype(pool.dtype))
 
 
 def _quantize_rows(x, H, qdtype):

@@ -1,6 +1,8 @@
 """Ops of the hybrid language-model family (``models/hybrid_lm.py``):
 RMS normalisation, grouped-query attention over the paged K/V cache,
-the recurrent mixers over per-stream state SLOTS — the KDA
+multi-head latent attention over pages of ONE compressed row a token
+(a prefill that up-projects it, a decode step that absorbs the
+up-projection), the recurrent mixers over per-stream state SLOTS — the KDA
 linear-attention layer (short convolution with a carried tail, the gated
 delta rule with a per-channel decay) and the Mamba-2 state-space layer
 (the same convolution with a bias, a per-head scalar decay, B and C
@@ -8,8 +10,8 @@ shared by a group's heads) — and the routed-expert feed-forward layer
 that is told which experts it holds.
 
 Two kinds of per-stream state live side by side in a serving program:
-K/V PAGES (``kv_cache.value_pool_shape``, addressed through a block
-table; attention layers) and SLOTS (``kv_cache.state_pool_shape`` /
+K/V PAGES (``kv_cache.value_pool_shape`` — or ``latent_pool_shape``,
+one pool a layer — addressed through a block table; attention layers) and SLOTS (``kv_cache.state_pool_shape`` /
 ``conv_tail_shape``, one row per live stream, row 0 scratch; KDA and
 Mamba-2 layers).  Every op here that touches a pool takes it in and hands it
 back, so that a jitted step donates it and updates in place.
@@ -153,14 +155,17 @@ def _gqa_args(attrs):
         else ())
 
 
-def rotate_half(x, positions, theta, heads):
+def rotate_half(x, positions, theta, heads, inv_freq=None):
     """Rotary positions: x (B, S, heads·D) with each head's lanes in
-    pairs (i, i + D/2), pair i turned by ``positions * theta^(-2i/D)``;
-    float32 inside, x's type out."""
+    pairs (i, i + D/2), pair i turned by ``positions * theta^(-2i/D)``
+    — or by ``positions * inv_freq[i]`` where the (D/2,) frequencies
+    come as data (rescaled ones: :func:`yarn_inv_freq`); float32
+    inside, x's type out."""
     B, S, HD = x.shape
     D = HD // heads
     inv = jnp.asarray(theta, jnp.float32) ** (
-        -jnp.arange(0, D, 2, dtype=jnp.float32) / D)            # (D/2,)
+        -jnp.arange(0, D, 2, dtype=jnp.float32) / D) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = positions.astype(jnp.float32)[..., None] * inv        # (B, S, D/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
     x1, x2 = jnp.split(x.reshape(B, S, heads, D).astype(jnp.float32), 2,
@@ -264,6 +269,272 @@ def _gqa_paged_decode(op_ctx, attrs, inputs, aux):
     out = decode_attention(q.reshape(B, 1, H, -1), kg, vg, lengths, KVB,
                            window)
     return [out.reshape(q.shape), kp, vp]
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention: one compressed row a token, two paths
+# ---------------------------------------------------------------------------
+#
+# A token leaves ONE row in a layer's cache, shared by every head: the
+# normalised latent c (``kv_rank`` values) and the rotated positional key
+# k_r (``rope_dim``), padded to whole lane tiles
+# (``kv_cache.latent_pool_shape``).  A head's keys and values are
+# up-projections of c by ``kv_up`` — rows [every head's k_n | every head's
+# v] — and a query is [q_n (nope_dim) | q_r (rope_dim)] a head, laid out
+# [every head's q_n | every head's q_r].  Scores: (q_n . k_n + q_r . k_r)
+# x scale.
+#
+# * a PREFILL up-projects (the ``kv_up`` FullyConnected before the op) and
+#   attends causally with qk width nope_dim + rope_dim and v width v_dim,
+#   k_r one key for all heads (``MLAPrefillAttention``);
+# * a DECODE step never up-projects the cache: W_uk is absorbed into the
+#   query (``MLAAbsorb``: q~ = q_n W_uk, kv_rank wide), the scores are
+#   [q~ | q_r] . [c | k_r] over the cached rows, the value is the row's
+#   first kv_rank lanes again, and W_uv is applied to the attended latent
+#   (``MLAAbsorb`` value=1) (``MLAPagedDecode``).
+
+def yarn_inv_freq(dim, theta, factor=0.0, orig_len=0.0, beta_fast=32.0,
+                  beta_slow=1.0):
+    """The (dim/2,) inverse frequencies of a rotary span of ``dim``
+    lanes, float32 numpy: ``theta^(-2i/dim)``, rescaled (YaRN,
+    arXiv:2309.00071) where ``factor`` > 1: pair i keeps its frequency
+    below ``low``, has it divided by ``factor`` above ``high`` and
+    blends linearly between, ``low`` / ``high`` the pairs that turn
+    ``beta_fast`` / ``beta_slow`` times over ``orig_len`` positions."""
+    import math
+
+    import numpy as np
+
+    f = float(theta) ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if not factor or float(factor) == 1.0:
+        return f.astype(np.float32)
+
+    def turns(beta):
+        return dim * math.log(orig_len / (2 * math.pi * beta)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns(beta_fast)), 0)
+    high = min(math.ceil(turns(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3),
+                   0.0, 1.0)
+    return (f * ((1.0 - ramp) + ramp / float(factor))).astype(np.float32)
+
+
+def mla_scale(nope_dim, rope_dim, rope_scaling=None):
+    """The scores' multiplier: ``(nope_dim + rope_dim)^-1/2``, times
+    ``(0.1 mscale_all_dim ln factor + 1)^2`` under rescaled positions."""
+    import math
+
+    scale = float(nope_dim + rope_dim) ** -0.5
+    rs = rope_scaling or {}
+    if rs.get("mscale_all_dim") and float(rs.get("factor", 1.0)) > 1.0:
+        scale *= (0.1 * float(rs["mscale_all_dim"])
+                  * math.log(float(rs["factor"])) + 1.0) ** 2
+    return scale
+
+
+_MLA_ATTRS = (
+    "attrs: num_heads, nope_dim, rope_dim, v_dim, kv_rank, scale (the "
+    "scores' multiplier), rope_theta and — rescaled positions — "
+    "rope_factor, rope_orig_len, rope_beta_fast, rope_beta_slow "
+    "(yarn_inv_freq)")
+
+
+def _mla_dims(attrs):
+    return tuple(attr_int(attrs.get(k, 0), 0) for k in
+                 ("num_heads", "nope_dim", "rope_dim", "v_dim", "kv_rank"))
+
+
+def _mla_rotate(attrs, x, positions, heads, rope):
+    inv = yarn_inv_freq(
+        rope, attr_float(attrs.get("rope_theta", 10000.0), 10000.0),
+        attr_float(attrs.get("rope_factor", 0.0), 0.0),
+        attr_float(attrs.get("rope_orig_len", 0.0), 0.0),
+        attr_float(attrs.get("rope_beta_fast", 32.0), 32.0),
+        attr_float(attrs.get("rope_beta_slow", 1.0), 1.0))
+    return rotate_half(x, positions, 0.0, heads, inv_freq=inv)
+
+
+def _mla_row(c, k_r, lanes):
+    """What a token leaves in the cache: [c | k_r rotated | zeros]."""
+    row = jnp.concatenate([c, k_r.astype(c.dtype)], axis=-1)
+    return jnp.pad(row, ((0, 0), (0, 0), (0, lanes - row.shape[-1])))
+
+
+def mla_causal(q_n, q_r, k_n, k_r, v, H, scale, block=256):
+    """The plain body of a prefill's attention, a block of queries at a
+    time: q_n, k_n (B, T, H·n), q_r (B, T, H·r), k_r (B, T, r) — ONE key
+    for all heads — v (B, T, H·dv) -> (B, T, H·dv).  Float32 inside."""
+    B, T, _ = q_n.shape
+    f32 = jnp.float32
+    prec = HI if q_n.dtype == jnp.float32 else None
+    bq = block if T % block == 0 else T
+    k4 = k_n.reshape(B, T, H, -1)
+    v4 = v.reshape(B, T, H, -1)
+    j = jnp.arange(T)
+
+    def one(xs):
+        qn, qr, i = xs                       # (B, bq, H, n), (B, bq, H, r)
+        s = jnp.einsum("bqhn,bkhn->bhqk", qn, k4, precision=prec,
+                       preferred_element_type=f32) \
+            + jnp.einsum("bqhr,bkr->bhqk", qr, k_r, precision=prec,
+                         preferred_element_type=f32)
+        s = jnp.where(j[None, :] <= i[:, None], s * scale, -jnp.inf)
+        p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v4,
+                          precision=prec, preferred_element_type=f32)
+
+    nq = T // bq
+    out = lax.map(one, (
+        jnp.moveaxis(q_n.reshape(B, nq, bq, H, -1), 1, 0),
+        jnp.moveaxis(q_r.reshape(B, nq, bq, H, -1), 1, 0),
+        j.reshape(nq, bq)))
+    return jnp.moveaxis(out, 0, 1).reshape(B, T, -1).astype(v.dtype)
+
+
+def _mla_prefill_infer(attrs, in_shapes):
+    q, pool = in_shapes[0], in_shapes[4]
+    if q is None or pool is None:
+        return in_shapes, None, None
+    H, _, _, dv, _ = _mla_dims(attrs)
+    return in_shapes, [(q[0], q[1], H * dv), tuple(pool)], []
+
+
+@register("MLAPrefillAttention",
+          arg_names=("query", "key_value", "latent", "rope_key", "pool",
+                     "block_table", "lengths", "positions"),
+          out_names=("output", "new_pool"), infer_shape=_mla_prefill_infer,
+          doc="Causal multi-head latent attention over a (padded) prompt "
+              "that also writes the prompt's cache rows: query (B, T, "
+              "H*(nope+rope)) = [every head's q_n | every head's q_r]; "
+              "key_value (B, T, H*(nope+v)) = [every head's k_n | every "
+              "head's v], the latent's up-projection; latent (B, T, "
+              "kv_rank), normalised; rope_key (B, T, rope), ONE positional "
+              "key for all heads, unrotated; pool (P, KVB, lanes) -> "
+              "output (B, T, H*v) + the pool.  q_r and rope_key are "
+              "rotated by positions; scores (q_n . k_n + q_r . k_r) x "
+              "scale; the row written is [latent | rotated rope_key | "
+              "zeros], in whole pages where "
+              "ops.attention.latent_prefill_write's rule allows.  "
+              + _MLA_ATTRS)
+def _mla_prefill(op_ctx, attrs, inputs, aux):
+    from . import pallas_kernels as pk
+    from .attention import latent_prefill_write
+
+    q, kv, c, k_r, pool, table, lengths, positions = inputs
+    H, n, r, dv, _ = _mla_dims(attrs)
+    scale = attr_float(attrs.get("scale", 0.0), 0.0)
+    q_r = _mla_rotate(attrs, q[..., H * n:], positions, H, r)
+    k_r = _mla_rotate(attrs, k_r, positions, 1, r)
+    if pk.mla_flash_enabled(H, n, r, dv):
+        out = pk.mla_flash(q, q_r, kv, k_r, H, n, dv, scale)
+    else:
+        out = mla_causal(q[..., :H * n], q_r, kv[..., :H * n], k_r,
+                         kv[..., H * n:], H, scale)
+    pool = latent_prefill_write(
+        _mla_row(c, k_r, pool.shape[2]), pool, table.astype(jnp.int32),
+        lengths.astype(jnp.int32))
+    return [out, pool]
+
+
+def _mla_absorb_infer(attrs, in_shapes):
+    x = in_shapes[0]
+    if x is None:
+        return in_shapes, None, None
+    H, _, _, dv, R = _mla_dims(attrs)
+    wide = dv if attr_bool(attrs.get("value", False), False) else R
+    return in_shapes, [tuple(x[:2]) + (H * wide,)], []
+
+
+@register("MLAAbsorb", arg_names=("data", "weight"),
+          infer_shape=_mla_absorb_infer,
+          doc="The latent's up-projection kv_up, weight (H*(nope+v), "
+              "kv_rank) = [every head's W_uk rows | every head's W_uv "
+              "rows], applied a head at a time to the OTHER side of the "
+              "attention, so that a decode step attends over the cached "
+              "latents as they are.  value=0: data (B, S, H*nope), a "
+              "head's q_n -> (B, S, H*kv_rank), q_n W_uk; value=1: data "
+              "(B, S, H*kv_rank), a head's attended latent -> (B, S, "
+              "H*v), W_uv of it.  attrs: num_heads, nope_dim, v_dim, "
+              "kv_rank, value")
+def _mla_absorb(op_ctx, attrs, inputs, aux):
+    x, w = inputs
+    H, n, _, dv, R = _mla_dims(attrs)
+    B, S, _ = x.shape
+    prec = HI if x.dtype == jnp.float32 else None
+    if attr_bool(attrs.get("value", False), False):
+        out = jnp.einsum("bshr,hvr->bshv", x.reshape(B, S, H, R),
+                         w[H * n:].reshape(H, dv, R), precision=prec,
+                         preferred_element_type=jnp.float32)
+    else:
+        out = jnp.einsum("bshn,hnr->bshr", x.reshape(B, S, H, n),
+                         w[:H * n].reshape(H, n, R), precision=prec,
+                         preferred_element_type=jnp.float32)
+    return [out.reshape(B, S, -1).astype(x.dtype)]
+
+
+def _mla_decode_infer(attrs, in_shapes):
+    qa, pool = in_shapes[0], in_shapes[4]
+    if qa is None or pool is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(qa), tuple(pool)], []
+
+
+@register("MLAPagedDecode",
+          arg_names=("query_latent", "query_rope", "latent", "rope_key",
+                     "pool", "block_table", "lengths", "positions"),
+          out_names=("output", "new_pool"), infer_shape=_mla_decode_infer,
+          doc="One decode step of multi-head latent attention over the "
+              "cached rows: query_latent (B, 1, H*kv_rank), a head's q_n "
+              "with W_uk absorbed (MLAAbsorb); query_rope (B, 1, H*rope), "
+              "unrotated; latent (B, 1, kv_rank) and rope_key (B, 1, "
+              "rope) of the current token; pool (P, KVB, lanes); lengths "
+              "counting the token -> output (B, 1, H*kv_rank), the "
+              "attended latent a head (MLAAbsorb value=1 makes values of "
+              "it) + the pool.  The token's row [latent | rotated "
+              "rope_key | zeros] is written first; a head's scores are "
+              "[q~ | q_r] . row x scale and its value the row's first "
+              "kv_rank lanes: the H heads of a stream are the rows of ONE "
+              "matmul a chunk of pages (pallas_kernels.mla_paged_decode "
+              "on TPU, a lax gather elsewhere).  " + _MLA_ATTRS)
+def _mla_paged_decode(op_ctx, attrs, inputs, aux):
+    from . import pallas_kernels as pk
+    from .attention import latent_cache_update
+
+    qa, q_r, c, k_r, pool, table, lengths, positions = inputs
+    H, _, r, _, R = _mla_dims(attrs)
+    if qa.shape[1] != 1:
+        raise MXNetError(f"MLAPagedDecode feeds ONE position a step; got "
+                         f"query {tuple(qa.shape)}")
+    scale = attr_float(attrs.get("scale", 0.0), 0.0)
+    B = qa.shape[0]
+    lanes = pool.shape[2]
+    lengths = lengths.astype(jnp.int32)
+    table = table.astype(jnp.int32)
+    q_r = _mla_rotate(attrs, q_r, positions, H, r)
+    k_r = _mla_rotate(attrs, k_r, positions, 1, r)
+    pool = latent_cache_update(pool, _mla_row(c, k_r, lanes), table,
+                               lengths)
+    # a head's whole query against a row: [q~ | q_r | zeros]
+    qx = jnp.pad(jnp.concatenate(
+        [qa.reshape(B, H, R), q_r.reshape(B, H, r)], axis=-1),
+        ((0, 0), (0, 0), (0, lanes - R - r)))
+    if pk.mla_paged_enabled(H, lanes, R):
+        out = pk.mla_paged_decode(qx, pool, table, lengths - 1, R, scale)
+    else:
+        MB, KVB = table.shape[1], pool.shape[1]
+        rows = pool[table].reshape(B, MB * KVB, lanes)
+        prec = HI if qx.dtype == jnp.float32 else None
+        s = jnp.einsum("bhw,btw->bht", qx, rows, precision=prec,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(MB * KVB)[None, None, :] < lengths[:, None, None]
+        p = jax.nn.softmax(jnp.where(seen, s, -jnp.inf), axis=-1)
+        # a padded batch row (length 0) sees nothing: zeros, not NaN
+        p = jnp.where(seen, p, 0.0)
+        out = jnp.einsum("bht,btr->bhr", p.astype(rows.dtype),
+                         rows[..., :R], precision=prec,
+                         preferred_element_type=jnp.float32)
+    return [out.reshape(B, 1, H * R).astype(qa.dtype), pool]
 
 
 # ---------------------------------------------------------------------------
@@ -664,22 +935,58 @@ MOE_COUNTERS = ("moe_pairs_here", "moe_pairs_elsewhere", "moe_experts_hit",
 ROUTER_SCORES = ("sigmoid", "softmax_topk")
 
 
-def moe_route(x2, router_w, top_k, score="sigmoid"):
+def moe_route(x2, router_w, top_k, score="sigmoid", select_bias=None,
+              groups=0, top_groups=0, routed_scale=1.0):
     """The ``top_k`` experts of each token and their weights, float32,
     from the router's logits over ALL experts.  ``score``: ``sigmoid`` —
     sigmoid scores, the largest, weights ``s_e / sum_top s``;
     ``softmax_topk`` — the largest LOGITS, weights a softmax over those
-    ``top_k`` alone.  x2 (N, d); router_w (E, d)."""
+    ``top_k`` alone.  x2 (N, d); router_w (E, d).
+
+    Under ``sigmoid`` the CHOICE may be moved without moving the
+    weights: ``select_bias`` (E,) is added to the scores it is made by;
+    ``groups`` > 0 limits it to the ``top_groups`` groups (of E / groups
+    consecutive experts) whose two largest biased scores sum highest;
+    the weights stay the UNBIASED scores of the chosen, normalised, times
+    ``routed_scale``.  None of the three given: the plain form above,
+    unchanged."""
     if score not in ROUTER_SCORES:
         raise MXNetError(f"router score {score!r} is none of "
                          f"{ROUTER_SCORES}")
     logits = jnp.dot(x2.astype(jnp.float32),
                      router_w.astype(jnp.float32).T, precision=HI)
+    moved = select_bias is not None or groups or routed_scale != 1.0
+    if moved and score != "sigmoid":
+        raise MXNetError(
+            f"router score {score!r} takes no selection bias, group limit "
+            f"or scale; 'sigmoid' does")
     if score == "softmax_topk":
         topv, topi = lax.top_k(logits, top_k)
         return topi, jax.nn.softmax(topv, axis=-1)
-    topv, topi = lax.top_k(jax.nn.sigmoid(logits), top_k)
-    return topi, topv / jnp.sum(topv, axis=-1, keepdims=True)
+    if not moved:
+        topv, topi = lax.top_k(jax.nn.sigmoid(logits), top_k)
+        return topi, topv / jnp.sum(topv, axis=-1, keepdims=True)
+    s = jax.nn.sigmoid(logits)
+    choice = s if select_bias is None else \
+        s + select_bias.astype(jnp.float32)
+    if groups:
+        N, E = s.shape
+        if E % groups or not 0 < top_groups <= groups or E // groups < 2:
+            raise MXNetError(
+                f"router: {E} experts in {groups} groups of which "
+                f"{top_groups} are kept (a group's score is the sum of "
+                f"its two largest)")
+        by_group = choice.reshape(N, groups, E // groups)
+        best = jnp.sum(lax.top_k(by_group, 2)[0], axis=-1)    # (N, groups)
+        kept = lax.top_k(best, top_groups)[1]                 # (N, top_g)
+        in_kept = jnp.any(
+            kept[:, :, None] == jnp.arange(groups)[None, None, :], axis=1)
+        choice = jnp.where(in_kept[:, :, None], by_group,
+                           -jnp.inf).reshape(N, E)
+    topi = lax.top_k(choice, top_k)[1]
+    topv = jnp.take_along_axis(s, topi, axis=-1)
+    return topi, topv / jnp.sum(topv, axis=-1, keepdims=True) \
+        * jnp.float32(routed_scale)
 
 
 def _tile_rows(n_pairs):
@@ -764,10 +1071,13 @@ _MOE_ARGS = ("data", "router_weight", "gate_weight", "up_weight",
              "down_weight", "lengths", "counters")
 
 
-@register("MoEFFN",
-          arg_names=lambda attrs: _MOE_ARGS + (
-              ("router_data",)
-              if attr_bool(attrs.get("router_data", False), False) else ()),
+def _moe_args(attrs):
+    return _MOE_ARGS + tuple(
+        k for k in ("router_data", "select_bias")
+        if attr_bool(attrs.get(k, False), False))
+
+
+@register("MoEFFN", arg_names=_moe_args,
           out_names=("output", "new_counters"), infer_shape=_moe_infer,
           doc="The routed experts' part of a mixture-of-experts layer, "
               "for the experts HELD here: data (B, S, d); router_weight "
@@ -782,7 +1092,14 @@ _MOE_ARGS = ("data", "router_weight", "gate_weight", "up_weight",
               "x), act='silu' (default) or 'relu'.  router_data=1: an "
               "eighth input, router_data (B, S, d), is what the router "
               "scores in place of data (a router that reads the block's "
-              "input, before attention).  What the other experts would "
+              "input, before attention).  select_bias=1: a further input, "
+              "select_bias (experts,) float32, added to the sigmoid scores "
+              "the CHOICE is made by; groups / top_groups: the choice is "
+              "limited to the top_groups of groups groups of consecutive "
+              "experts (by the sum of a group's two largest biased "
+              "scores); routed_scale multiplies the weights, which stay "
+              "the unbiased scores normalised (moe_route).  "
+              "What the other experts would "
               "add belongs to other "
               "chips and is left out.  No pair is dropped.  lengths "
               "(B,) masks padding (step=1: rows with lengths 0; step=0: "
@@ -790,7 +1107,7 @@ _MOE_ARGS = ("data", "router_weight", "gate_weight", "up_weight",
               "computed here, pairs left elsewhere, held experts hit, "
               "the largest expert's load — is added to where count=1.  "
               "attrs: top_k, first_expert, step, count, score, act, "
-              "router_data")
+              "router_data, select_bias, groups, top_groups, routed_scale")
 def _moe_ffn(op_ctx, attrs, inputs, aux):
     x, router_w, w_gate, w_up, w_down, lengths, counters = inputs[:7]
     act = str(attrs.get("act", "silu"))
@@ -811,9 +1128,15 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
     valid = (jnp.broadcast_to(n[:, None] > 0, (B, S)) if step
              else jnp.arange(S)[None, :] < n[:, None]).reshape(-1)
     x2 = x.reshape(B * S, d)
-    routed = inputs[7].reshape(B * S, d) if len(inputs) > 7 else x2
-    topi, wts = moe_route(routed, router_w, top_k,
-                          str(attrs.get("score", "sigmoid")))
+    extra = dict(zip(_moe_args(attrs)[7:], inputs[7:]))
+    routed = extra["router_data"].reshape(B * S, d) \
+        if "router_data" in extra else x2
+    topi, wts = moe_route(
+        routed, router_w, top_k, str(attrs.get("score", "sigmoid")),
+        select_bias=extra.get("select_bias"),
+        groups=attr_int(attrs.get("groups", 0), 0),
+        top_groups=attr_int(attrs.get("top_groups", 0), 0),
+        routed_scale=attr_float(attrs.get("routed_scale", 1.0), 1.0))
     tm = _tile_rows(B * S * min(top_k, held))
     here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
         topi, valid, first, held, tm)

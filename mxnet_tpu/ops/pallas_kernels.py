@@ -2080,9 +2080,10 @@ def paged_attention_verify(q, k_pool, v_pool, block_table, start,
 _KV_WRITE_DEPTH = 8
 
 
-def _kv_pages_kernel(pages_ref, k_hbm, v_hbm, k_pool, v_pool, k_out, v_out,
-                     sem, *, kvb, blocks, depth):
-    del k_pool, v_pool  # aliased to the outputs: written through those
+def _pages_kernel(pages_ref, *refs, n, kvb, blocks, depth):
+    # refs: n arrays of rows, the n pools (aliased to the outputs:
+    # written through those), the n outputs, the semaphores
+    srcs, outs, sem = refs[:n], refs[2 * n:3 * n], refs[3 * n]
     total = pages_ref.shape[0] * blocks
 
     def copies(i, op):
@@ -2096,10 +2097,9 @@ def _kv_pages_kernel(pages_ref, k_hbm, v_hbm, k_pool, v_pool, k_out, v_out,
         @pl.when(page > 0)
         def _():
             rows = pl.ds(pl.multiple_of(j * kvb, kvb), kvb)
-            for n, (src, dst) in enumerate(((k_hbm, k_out),
-                                            (v_hbm, v_out))):
+            for at, (src, dst) in enumerate(zip(srcs, outs)):
                 getattr(pltpu.make_async_copy(
-                    src.at[b, rows], dst.at[page], sem.at[n]), op)()
+                    src.at[b, rows], dst.at[page], sem.at[at]), op)()
 
     def issue(i, _):
         @pl.when(i >= depth)
@@ -2114,31 +2114,48 @@ def _kv_pages_kernel(pages_ref, k_hbm, v_hbm, k_pool, v_pool, k_out, v_out,
 
 
 @functools.lru_cache(maxsize=None)
-def _kv_pages_write_fn(interpret):
-    """The call, jitted: a model's layers make it with one shape, so
-    the kernel is traced and lowered once a program, not once a layer
-    (``_flash_mha_packed_fn`` says why ``interpret`` is in the key)."""
-    def write(pages, k, v, k_pool, v_pool):
+def _pages_write_fn(n, name, interpret):
+    """The call for ``n`` pools under the kernel name ``name``, jitted:
+    a model's layers make it with one shape, so the kernel is traced and
+    lowered once a program, not once a layer (``_flash_mha_packed_fn``
+    says why ``interpret`` is in the key)."""
+    def write(pages, *rows_and_pools):
+        pools = rows_and_pools[n:]
         blocks = pages.shape[1]
         hbm = pl.BlockSpec(memory_space=pl.ANY)
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(1,),
-            in_specs=[hbm, hbm, hbm, hbm], out_specs=[hbm, hbm],
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))])
+            in_specs=[hbm] * (2 * n), out_specs=[hbm] * n,
+            scratch_shapes=[pltpu.SemaphoreType.DMA((n,))])
         return pl.pallas_call(
             functools.partial(
-                _kv_pages_kernel, kvb=k_pool.shape[1], blocks=blocks,
+                _pages_kernel, n=n, kvb=pools[0].shape[1], blocks=blocks,
                 depth=min(_KV_WRITE_DEPTH, pages.shape[0] * blocks)),
             grid_spec=grid_spec,
-            out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                       jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-            input_output_aliases={3: 0, 4: 1},
+            out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype)
+                       for p in pools],
+            input_output_aliases={1 + n + i: i for i in range(n)},
             compiler_params=_compiler_params("arbitrary"),
             interpret=interpret,
-            name="kv_pages_write",
-        )(pages, k, v, k_pool, v_pool)
+            name=name,
+        )(pages, *rows_and_pools)
 
     return jax.jit(write)
+
+
+def _pages_write(name, rows, pools, pages):
+    """``rows[i]`` (B, T, W) into ``pools[i]`` (P, KVB, W) a page a
+    copy, every pool in ONE call named ``name``."""
+    B, T, _ = rows[0].shape
+    kvb = pools[0].shape[1]
+    if T % kvb or pages.shape != (B, T // kvb):
+        raise MXNetError(
+            f"{name}: rows {tuple(rows[0].shape)} want whole pages of "
+            f"{kvb} and a (B, T / {kvb}) table; got pages "
+            f"{tuple(pages.shape)}")
+    return _pages_write_fn(len(pools), name, _interpret())(
+        pages.astype(jnp.int32),
+        *(r.astype(p.dtype) for r, p in zip(rows, pools)), *pools)
 
 
 def kv_pages_write(k, v, k_pool, v_pool, pages):
@@ -2158,13 +2175,333 @@ def kv_pages_write(k, v, k_pool, v_pool, pages):
 
     The name holds no ``paged_attention``: the benchmark's readers book
     every kernel so named to the decode step's attention."""
-    B, T, _ = k.shape
-    kvb = k_pool.shape[1]
-    if T % kvb or pages.shape != (B, T // kvb):
+    return tuple(_pages_write("kv_pages_write", (k, v), (k_pool, v_pool),
+                              pages))
+
+
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (ops/hybrid.py MLAPrefillAttention /
+# MLAPagedDecode): a prefill kernel with two head widths and one shared
+# positional key, a paged decode kernel over ONE pool of latent rows in
+# which the value is a lane span of the key, and the one-pool page write.
+#
+# The names hold none of ``paged_attention``, ``paged_window``,
+# ``flash_fwd_mha``, ``flash_fwd_window``, ``kv_pages_write``: the
+# benchmark's accepted readers book a kernel to a metric by SUBSTRING.
+# ---------------------------------------------------------------------------
+
+_MLA_BLOCK = 512
+
+
+def _mla_heads_per_step(heads, rope_dim):
+    """Heads a grid step of the prefill kernel takes: their rotary query
+    lanes (``hb·rope_dim``) must be whole lane tiles when compiled — 4 at
+    the published 64 — and the ONE rotary key tile is then fetched once
+    for the group, not once a head."""
+    for hb in (4, 2, 8, 16):
+        if heads % hb == 0 and (hb * rope_dim) % 128 == 0:
+            return hb
+    return 0
+
+
+def mla_flash_enabled(heads, nope_dim, rope_dim, v_dim) -> bool:
+    """Use ``mla_flash``?  Compiled, a head's spans must be whole lane
+    tiles (nope_dim, v_dim multiples of 128; the rotary lanes of a
+    step's heads: ``_mla_heads_per_step``); interpreted, any width."""
+    if not enabled():
+        return False
+    return _interpret() or (
+        nope_dim % 128 == 0 and v_dim % 128 == 0
+        and _mla_heads_per_step(heads, rope_dim) > 0)
+
+
+def _mla_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
+                      m_ref, l_ref, *, hb, n, r, dv, block_q, block_k,
+                      t_valid, scale):
+    qi = pl.program_id(2)
+    kj = pl.program_id(3)
+    last_kj = (qi * block_q + block_q - 1) // block_k
+
+    @pl.when(kj == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    def tile(masked):
+        # a tile wholly below the diagonal, without padded keys, needs
+        # no mask: every key is seen, every row's maximum is finite
+        if masked:
+            k_pos = kj * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1)
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+            valid = (k_pos < t_valid) & (k_pos <= q_pos)
+        kr = kr_ref[0]                          # (block_k, r): every head's
+        cross = (((1,), (1,)), ((), ()))
+        for i in range(hb):
+            s = jax.lax.dot_general(
+                qn_ref[0, :, i * n:(i + 1) * n],
+                kn_ref[0, :, i * n:(i + 1) * n], cross,
+                preferred_element_type=jnp.float32)
+            s = (s + jax.lax.dot_general(
+                qr_ref[0, :, i * r:(i + 1) * r], kr, cross,
+                preferred_element_type=jnp.float32)) * scale
+            m_prev = m_ref[i, :, :1]
+            if masked:
+                s = jnp.where(valid, s, -jnp.inf)
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+                p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+                alpha = jnp.where(m_prev == -jnp.inf, 0.0,
+                                  jnp.exp(m_prev - m_safe))
+            else:
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m_prev - m_new)     # exp(-inf) = 0 at first
+            l_ref[i] = jnp.broadcast_to(
+                l_ref[i, :, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+                l_ref.shape[1:])
+            vv = v_ref[0, :, i * dv:(i + 1) * dv]
+            pv = jax.lax.dot_general(p.astype(vv.dtype), vv,
+                                     (((1,), (0,)), ((), ())),
+                                     preferred_element_type=jnp.float32)
+            acc_ref[i] = acc_ref[i] * alpha + pv
+            m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+
+    below = (kj * block_k + block_k <= qi * block_q) \
+        & (kj * block_k + block_k <= t_valid)
+    pl.when(below)(lambda: tile(False))
+    pl.when((kj <= last_kj) & jnp.logical_not(below))(lambda: tile(True))
+
+    @pl.when(kj == last_kj)
+    def _finalize():
+        for i in range(hb):
+            o_ref[0, :, i * dv:(i + 1) * dv] = (
+                acc_ref[i] / jnp.maximum(l_ref[i, :, :1], 1e-30)
+            ).astype(o_ref.dtype)
+
+
+def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale):
+    """Causal attention of ``heads`` heads with qk width n + r and v
+    width dv: q (B, T, H·n + ...), its first H·n lanes every head's
+    q_n (what follows is not read: the projection's unrotated q_r);
+    q_r (B, T, H·r), rotated; kv (B, T, H·n + H·dv) = [every head's k_n
+    | every head's v], the latent's up-projection as it leaves its
+    matmul; k_r (B, T, r), rotated — ONE key for all heads, never
+    repeated a head -> (B, T, H·dv).  Scores (q_n . k_n + q_r . k_r) x
+    ``scale``.  Token-major rows as the projections leave them: a head
+    is a lane span (k_n and v are two windows on ONE array, no slice is
+    copied out), a grid step takes ``_mla_heads_per_step`` heads over
+    one (query tile, key tile) and the key tiles above the diagonal are
+    neither fetched nor computed."""
+    B, T, _ = q.shape
+    H, n, dv = int(heads), int(nope_dim), int(v_dim)
+    r = q_r.shape[2] // H
+    hb = _mla_heads_per_step(H, r) or math.gcd(H, 4)
+    bq = bk = _mha_block(_MLA_BLOCK, T)
+    v_in = kv
+    if (H * n) % (hb * dv):       # v's lanes start inside a block of it
+        v_in = kv[..., H * n:]
+    v_first = 0 if v_in is not kv else H * n // (hb * dv)
+    qf, qr, kf, kr, vf = (_pad_to(x, 1, bq) for x in (q, q_r, kv, k_r,
+                                                      v_in))
+    nq = nk = qf.shape[1] // bq
+
+    def q_map(b, g, qi, kj):
+        return (b, qi, g)
+
+    def seen(qi, kj):
+        # above the diagonal the index stands still: no tile is fetched
+        return jnp.minimum(kj, (qi * bq + bq - 1) // bk)
+
+    kern = functools.partial(
+        _mla_flash_kernel, hb=hb, n=n, r=r, dv=dv, block_q=bq, block_k=bk,
+        t_valid=T, scale=float(scale))
+    o = pl.pallas_call(
+        kern,
+        grid=(B, H // hb, nq, nk),
+        in_specs=[
+            _vmem_spec((1, bq, hb * n), q_map),
+            _vmem_spec((1, bq, hb * r), q_map),
+            _vmem_spec((1, bk, hb * n),
+                       lambda b, g, qi, kj: (b, seen(qi, kj), g)),
+            _vmem_spec((1, bk, r),
+                       lambda b, g, qi, kj: (b, seen(qi, kj), 0)),
+            _vmem_spec((1, bk, hb * dv),
+                       lambda b, g, qi, kj: (b, seen(qi, kj), v_first + g)),
+        ],
+        out_specs=_vmem_spec((1, bq, hb * dv), q_map),
+        out_shape=jax.ShapeDtypeStruct((B, qf.shape[1], H * dv), kv.dtype),
+        scratch_shapes=[pltpu.VMEM((hb, bq, dv), jnp.float32),
+                        pltpu.VMEM((hb, bq, 128), jnp.float32),
+                        pltpu.VMEM((hb, bq, 128), jnp.float32)],
+        compiler_params=_compiler_params(
+            "parallel", "parallel", "parallel", "arbitrary",
+            vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="mla_flash_fwd",
+    )(qf, qr, kf, kr, vf)
+    return o[:, :T]
+
+
+# Keys a chunk of the latent paged kernel.  VMEM at the published widths
+# (128 heads, rows of 640 lanes, bfloat16): two buffers of 512 rows, 1.3
+# MB; the (128, 512) float32 scores and probabilities, 0.5 MB; q, the
+# (128, 512) float32 accumulator and the output block, 0.7 MB: 2.5 MB of
+# the 16 a kernel is given.  A chunk is two matmuls, (128 x 640) x
+# (640 x 512) and (128 x 512) x (512 x 512): 242 FLOP a byte of cache.
+_MLA_PAGED_CHUNK_KEYS = 512
+
+
+def mla_paged_enabled(heads, lanes, rank) -> bool:
+    """Use ``mla_paged_decode`` over a latent pool of ``lanes``-wide
+    rows?  The rule of :func:`paged_enabled` for the rows, and —
+    compiled — the value span (``rank``) in whole lane tiles and the
+    heads in whole sublane tiles."""
+    return paged_enabled(lanes) and (
+        _interpret() or (rank % 128 == 0 and heads % 16 == 0))
+
+
+def _mla_paged_kernel(table_ref, start_ref, q_ref, pool_hbm, o_ref, buf, sem,
+                      turn_scr, acc_scr, m_scr, l_scr, *, scale, kvb, pages,
+                      mb, rank):
+    b = pl.program_id(0)
+    nb = pl.num_programs(0)
+    keys = pages * kvb
+    lanes = buf.shape[-1]
+
+    def live(row):
+        return jnp.clip(start_ref[row] + 1, 0, mb * kvb)
+
+    def copies(row, chunk, slot, op):
+        # the chunk's live pages, page i into span i of the slot's
+        # buffer; "start" or "wait", the same pages by the same count
+        first = chunk * pages
+
+        def page(i, _):
+            getattr(pltpu.make_async_copy(
+                pool_hbm.at[table_ref[row, first + i]], buf.at[slot, i],
+                sem.at[slot]), op)()
+
+        jax.lax.fori_loop(
+            0, jnp.clip(pl.cdiv(live(row), kvb) - first, 0, pages), page,
+            None)
+
+    @pl.when(b == 0)
+    def _first_row():
+        turn_scr[0] = 0
+        buf[...] = jnp.zeros_like(buf)
+
+    # the walk of ``_paged_kernel``: chunk c of the row is in buffer
+    # (turn + c) % 2; a row starts its chunk c + 1 under chunk c and,
+    # under its last chunk, the NEXT row's first — which a row with no
+    # chunk of its own starts at once, and row 0 starts for itself
+    turn = turn_scr[0]
+    n = pl.cdiv(live(b), keys)
+    after = jnp.minimum(b + 1, nb - 1)
+
+    @pl.when((b == 0) | ((n == 0) & (b + 1 < nb)))
+    def _first_chunk():
+        copies(jnp.where(n == 0, after, b), 0, turn % 2, "start")
+
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    m_scr[...] = jnp.full_like(m_scr, -jnp.inf)
+    l_scr[...] = jnp.zeros_like(l_scr)
+
+    def chunk_step(c, _):
+        slot = (turn + c) % 2
+        more = c + 1 < n
+
+        @pl.when(more | (b + 1 < nb))
+        def _ahead():
+            copies(jnp.where(more, b, after), jnp.where(more, c + 1, 0),
+                   1 - slot, "start")
+
+        copies(b, c, slot, "wait")
+        q = q_ref[0]                                  # (H, lanes)
+        k = buf[slot].reshape(keys, lanes)
+        # s[h, t] = [q~_h | q_r,h | 0] . [c_t | k_r,t | 0]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        k_pos = c * keys + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        valid = k_pos < start_ref[b] + 1
+        s = jnp.where(valid, s, -jnp.inf)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
+        alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        # the value is the row's first ``rank`` lanes: the latent itself
+        pv = jax.lax.dot_general(
+            p.astype(k.dtype), k[:, :rank], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        acc_scr[...] = acc_scr[...] * alpha + pv
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    jax.lax.fori_loop(0, n, chunk_step, None)
+    turn_scr[0] = turn + n
+    o_ref[0] = (acc_scr[...] / jnp.maximum(l_scr[:, :1], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+def mla_paged_decode(q, pool, block_table, start, rank, scale):
+    """One decode step over the latent pool: q (B, H, lanes), a head's
+    whole query [q_n W_uk | q_r rotated | zeros] at position
+    ``start[b]``; pool (P, KVB, lanes), a token's row [latent | rotated
+    positional key | zeros]; block_table (B, MB) page ids (page 0 =
+    scratch) -> (B, H, rank): softmax(q . row x scale) over the keys
+    ``0 .. start[b]``, times the rows' first ``rank`` lanes.
+
+    The H heads of a stream share every cached row, so they are the
+    ROWS of the chunk's two matmuls and the cache is read once a stream,
+    not once a head; the value is a lane span of the key buffer, not a
+    second pool.  The walk over a stream's pages (two buffers, a row
+    starting the next row's first copies) is ``_paged_kernel``'s."""
+    B, H, lanes = q.shape
+    KVB = pool.shape[1]
+    MB = block_table.shape[1]
+    if pool.shape[2] != lanes or not 0 < rank <= lanes:
         raise MXNetError(
-            f"kv_pages_write: rows {tuple(k.shape)} want whole pages of "
-            f"{kvb} and a (B, T / {kvb}) table; got pages "
-            f"{tuple(pages.shape)}")
-    return _kv_pages_write_fn(_interpret())(
-        pages.astype(jnp.int32), k.astype(k_pool.dtype),
-        v.astype(v_pool.dtype), k_pool, v_pool)
+            f"mla_paged_decode: queries of {lanes} lanes over pool rows "
+            f"{tuple(pool.shape)} with a value span of {rank}")
+    pages = max(1, min(_MLA_PAGED_CHUNK_KEYS // KVB, MB))
+    kern = functools.partial(_mla_paged_kernel, scale=float(scale), kvb=KVB,
+                             pages=pages, mb=MB, rank=int(rank))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B,),
+        in_specs=[_vmem_spec((1, H, lanes), lambda b, tr, sr: (b, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=_vmem_spec((1, H, rank), lambda b, tr, sr: (b, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, pages) + pool.shape[1:], pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((1,), jnp.int32),
+            pltpu.VMEM((H, rank), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32)],
+    )
+    # rows in order ("arbitrary"): a row starts the next row's copies
+    return pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, H, int(rank)), q.dtype),
+        compiler_params=_compiler_params("arbitrary"),
+        interpret=_interpret(),
+        name="mla_paged_decode",
+    )(block_table.astype(jnp.int32), start.astype(jnp.int32), q, pool)
+
+
+def latent_pages_write(rows, pool, pages):
+    """:func:`kv_pages_write` for a layer with ONE pool: rows (B, T, W),
+    pool (P, KVB, W), T a multiple of KVB; pages (B, T / KVB) int32 ->
+    the pool with block j of row b written to page ``pages[b, j]``, a
+    page a copy, a few in flight; a block whose page is 0 is skipped."""
+    return _pages_write("mla_latent_write", (rows,), (pool,), pages)[0]

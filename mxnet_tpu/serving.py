@@ -56,6 +56,10 @@ _DEFAULT_BUCKETS = (1, 8, 32, 128)
 # prefills a decode engine keeps unfetched at most: as many first tokens
 # as one decode step can take from the device (``DecodeEngine._feed_exe``)
 _FIRSTS_AHEAD = 4
+# why a latent spec's pages neither leave nor arrive (fmt 1 frames)
+_NO_LATENT_FRAME = ("a migration frame is a K and a V slab a layer; a "
+                    "layer that keeps ONE latent row a token has no wire "
+                    "format yet")
 
 
 def _phase_breakdown(summ: dict, phases: Dict[str, str]) -> dict:
@@ -1101,7 +1105,13 @@ class DecodeEngine:
         it asks by these names only (no class is looked at):
 
         * ``vocab_size``, ``num_layers``, ``d_model``; ``kv_heads``,
-          ``head_dim`` (the K/V page geometry; 0 without pages);
+          ``head_dim`` (the geometry of a page's rows; 0 without
+          pages: K and V rows of ``kv_heads x head_dim`` — or, for a
+          spec whose ``latent_row`` is set, ONE row a token and layer
+          shared by all heads, ``kv_heads`` 1 and ``head_dim`` the
+          lanes the pool spends on it; ``latent_row`` = (values the row
+          needs, lanes it takes), which the ``mla.cache_bytes*`` gauges
+          report, and no wire format for its pages);
           ``name`` (how a refusal calls it);
         * ``phases``: which of ``prefill``, ``decode``,
           ``prefix_prefill``, ``verify`` ``symbol(phase, kv_block=,
@@ -1599,7 +1609,8 @@ class DecodeEngine:
         # pools a layer in self._pools (what a migration frame holds);
         # on a mesh the pools are STACKED (L, pages, ...) slabs
         # instead, sharded pp x tp
-        self._pool_stride = len(self._pool_kinds) // self._L
+        self._pool_stride = sum(
+            k != "counters" for k in self._pool_kinds) // self._L
         if self._mesh is not None:
             self._pools = self._mesh.init_pools(int(cache_blocks))
         else:
@@ -1624,6 +1635,18 @@ class DecodeEngine:
         profiler.set_gauge("serving.kv_pool_bytes", self._pool_bytes)
         profiler.set_gauge("serving.state_pool_bytes",
                            self._state_pool_bytes)
+        # a latent spec: what its pool spends a token and layer (the
+        # row in whole lane tiles) beside what the row needs
+        self._latent_row = getattr(model, "latent_row", None)
+        self._latent_bytes = {}
+        if self._latent_row:
+            need, lanes = self._latent_row
+            item = np.dtype(self._np_dtype).itemsize
+            self._latent_bytes = {
+                "cache_bytes_per_token": lanes * item,
+                "cache_bytes_needed_per_token": need * item}
+            for k, v in self._latent_bytes.items():
+                profiler.set_gauge(f"mla.{k}", v)
         self._cow_fn = None  # lazily-jitted copy-on-write page copy
 
         if donate is None:
@@ -1780,6 +1803,10 @@ class DecodeEngine:
                 f"{self._spec.name}: a stream's state is pages AND a "
                 f"slot or a windowed pool's pages, and only whole "
                 f"tables of ordinary pages have a wire format")
+        if prefill_only and self._latent_row:
+            raise MXNetError(
+                f"prefill_only page export is not built for "
+                f"{self._spec.name}: {_NO_LATENT_FRAME}")
         if return_state and (self._slot_alloc is None or prefill_only):
             raise MXNetError(
                 "return_state reads a stream's slot at retirement: the "
@@ -2059,6 +2086,7 @@ class DecodeEngine:
             (self._slot_alloc.num_slots, self._slot_alloc.live)
         out = {"state_slots": num, "state_slots_live": live,
                "state_pool_bytes": self._state_pool_bytes}
+        out.update({f"mla_{k}": v for k, v in self._latent_bytes.items()})
         # the windowed pools: pages there are, pages held now, and over
         # the decode steps the pages their rows held beside the pages
         # the same rows hold in the ordinary pools (what a windowed
@@ -2089,7 +2117,8 @@ class DecodeEngine:
                 "preempted", "prefills", "steps", "stream_steps",
                 "prefill_chunks", "spec_steps", "spec_proposed",
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
-                "d2h_syncs_saved", "context_tokens", "steps_run_ahead",
+                "d2h_syncs_saved", "context_tokens", "prefill_pairs",
+                "steps_run_ahead",
                 "run_ahead_drains", "overshoot_row_steps",
                 "prefill_first_deferred")}
         # how the loop ran: the share of decode programs dispatched
@@ -3060,6 +3089,10 @@ class DecodeEngine:
                                   salt=_prefix_salt(s))
         self._count("prefills")
         self._count("prefill_tokens", ns)  # uncached tokens only
+        # the query-key pairs those tokens' causal attention holds (row
+        # i sees i + 1 keys): what a prefill kernel's need is counted
+        # from
+        self._count("prefill_pairs", (n * (n + 1) - c * (c + 1)) // 2)
         s.cost.prefill_tokens += ns
         s.t_admit = time.perf_counter()
         self._inflight.append(_Flight(
@@ -3544,6 +3577,10 @@ class DecodeEngine:
                 f"page import is not built for {self._spec.name}: an "
                 f"imported stream would arrive without the state its "
                 f"slot or its windowed pools hold")
+        if self._latent_row:
+            raise MXNetError(
+                f"page import is not built for {self._spec.name}: "
+                f"{_NO_LATENT_FRAME}")
         if self._mesh is not None:
             raise MXNetError(
                 "KV page migration onto a tp/pp-meshed engine is not "

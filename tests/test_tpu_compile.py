@@ -500,6 +500,93 @@ def test_mamba2_kernels_granite_cell(on_chip, one_chip, monkeypatch):
              ((1, T, H * P + 2 * N), bf16), ((1, T, H), f32))
 
 
+# the longctx cell (deepseek-v3-ep32): 32 rows, a table of 544 pages of
+# 16, 128 heads of (128 + 64 | 128) over a latent row of 512 + 64 values
+# held as 640 lanes
+_LONGCTX = (32, 544, 128, 128, 64, 128, 512)
+_MLA_ATTRS = {"num_heads": "128", "nope_dim": "128", "rope_dim": "64",
+              "v_dim": "128", "kv_rank": "512", "scale": "0.13523",
+              "rope_theta": "10000", "rope_factor": "40",
+              "rope_orig_len": "4096", "rope_beta_fast": "32",
+              "rope_beta_slow": "1"}
+
+
+def _latent_pool(pages):
+    from mxnet_tpu.kv_cache import latent_pool_shape
+
+    return latent_pool_shape(pages, _KVB, 512, 64), bf16
+
+
+@pytest.mark.parametrize("T", [8192, 1024])
+def test_mla_flash_at_the_longctx_cells_shapes(on_chip, one_chip, T):
+    _, _, H, n, r, dv, _ = _LONGCTX
+    assert pk.mla_flash_enabled(H, n, r, dv)
+    assert pk._mla_heads_per_step(H, r) == 4
+    text = _compile(lambda q, qr, kv, kr: pk.mla_flash(
+        q, qr, kv, kr, H, n, dv, 0.13523), one_chip,
+        ((1, T, H * (n + r)), bf16), ((1, T, H * r), bf16),
+        ((1, T, H * (n + dv)), bf16), ((1, T, r), bf16)).as_text()
+    kernels = _kernel_names(text)
+    # a name no accepted reader's substring finds
+    assert len(kernels) == 1 and "mla_flash_fwd" in kernels[0]
+    assert "flash_fwd_mha" not in kernels[0]
+
+
+def test_mla_paged_decode_at_the_longctx_cells_shapes(on_chip, one_chip):
+    B, MB, H, _, _, _, R = _LONGCTX
+    pool = _latent_pool(1 + B * MB)
+    lanes = pool[0][2]
+    assert lanes == 640 and pk.mla_paged_enabled(H, lanes, R)
+    text = _compile(lambda q, p, t, s: pk.mla_paged_decode(
+        q, p, t, s, R, 0.13523), one_chip, ((B, H, lanes), bf16), pool,
+        ((B, MB), i32), ((B,), i32)).as_text()
+    kernels = _kernel_names(text)
+    assert len(kernels) == 1 and "mla_paged_decode" in kernels[0]
+    assert "paged_attention" not in kernels[0]
+
+
+@pytest.mark.parametrize("phase", ["prefill", "decode"])
+def test_mla_ops_update_the_latent_pool_in_place(on_chip, one_chip, phase):
+    """The two attention paths over the longctx cell's pool, donated: the
+    page write (``mla_latent_write``) and the decode step's row scatter
+    leave no pool-shaped copy."""
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    B, MB, H, n, r, dv, R = _LONGCTX
+    pool = _latent_pool(1 + B * MB)
+    T = 4096
+    ctx = OpContext(is_train=False, rng=None)
+    if phase == "prefill":
+        def run(q, kv, c, kr, pool, table, lengths, positions):
+            return get_op("MLAPrefillAttention").compute(
+                ctx, _MLA_ATTRS, [q, kv, c, kr, pool, table, lengths,
+                                  positions], [])
+
+        shapes = [((1, T, H * (n + r)), bf16), ((1, T, H * (n + dv)), bf16),
+                  ((1, T, R), bf16), ((1, T, r), bf16), pool,
+                  ((1, T // _KVB), i32), ((1,), i32), ((1, T), i32)]
+        want = {"mla_flash_fwd", "mla_latent_write"}
+    else:
+        def run(qa, qr, c, kr, pool, table, lengths, positions):
+            return get_op("MLAPagedDecode").compute(
+                ctx, _MLA_ATTRS, [qa, qr, c, kr, pool, table, lengths,
+                                  positions], [])
+
+        shapes = [((B, 1, H * R), bf16), ((B, 1, H * r), bf16),
+                  ((B, 1, R), bf16), ((B, 1, r), bf16), pool,
+                  ((B, MB), i32), ((B,), i32), ((B, 1), i32)]
+        want = {"mla_paged_decode"}
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in shapes]
+    compiled = jax.jit(run, donate_argnums=(4,)).lower(*args).compile()
+    text = compiled.as_text()
+    names = _kernel_names(text)
+    assert {k for k in want if any(k in nm for nm in names)} == want, names
+    dims = ",".join(str(v) for v in pool[0])
+    copies = re.findall(rf"= bf16\[{dims}\]\S* copy\(.*", text)
+    assert not copies, copies
+
+
 def test_lstm_scan_ptb(on_chip, one_chip):
     # PTB LSTM (tools/bench_secondary.py): T=32, batch 32, hidden 200
     T, B, H = 32, 32, 200

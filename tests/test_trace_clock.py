@@ -343,7 +343,9 @@ def test_every_pallas_call_is_named():
     assert len(set(names)) == len(names)  # one name per kernel
     # the accepted flash_roofline reader tells backward from forward
     # by `transpose` in the kernel's name
-    # (the windowed prefill kernel serves only: a forward, no backward)
-    flash = [n for n in names if "flash" in n and "window" not in n]
+    # (the windowed and the latent prefill kernels serve only: a
+    # forward, no backward)
+    flash = [n for n in names if "flash" in n and "window" not in n
+             and "mla" not in n]
     assert sum("transpose" in n for n in flash) == 2 * sum(
         "fwd" in n for n in flash)

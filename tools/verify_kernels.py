@@ -26,6 +26,10 @@ after ANY kernel change:
     python tools/verify_kernels.py --pages  # a prefill's K/V write: the
                                             # page kernel against the row
                                             # scatter at the cells' shapes
+    python tools/verify_kernels.py --mla    # the latent-attention kernels
+                                            # (prefill, paged decode, page
+                                            # write) at the longctx cell's
+                                            # widths, ms a call each
 """
 
 import functools
@@ -347,6 +351,108 @@ def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
     return ok
 
 
+def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
+    """The latent prefill kernel (one prompt, ``H`` heads of qk width
+    n + r and v width dv, one rotary key for all) against the lax body
+    a block of queries at a time, with the kernel's ms a call."""
+    from mxnet_tpu.ops import hybrid as hy
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(T)
+
+    def arr(lanes, s=0.5):
+        return jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
+                           * s).astype(jnp.bfloat16)
+
+    q, q_r, kv, k_r = arr(H * (n + r)), arr(H * r), arr(H * (n + dv)), \
+        arr(r, 1.5)
+    assert pk.mla_flash_enabled(H, n, r, dv)
+    kern = jax.jit(lambda q, q_r, kv, k_r: pk.mla_flash(
+        q, q_r, kv, k_r, H, n, dv, scale))
+    lax_body = jax.jit(lambda q, q_r, kv, k_r: hy.mla_causal(
+        q[..., :H * n], q_r, kv[..., :H * n], k_r, kv[..., H * n:], H,
+        scale, block=128))
+    got = np.asarray(kern(q, q_r, kv, k_r).astype(jnp.float32))
+    want = np.asarray(lax_body(q, q_r, kv, k_r).astype(jnp.float32))
+    err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
+    ok = err < TOL and bool(np.isfinite(got).all())
+    ms = _kernel_ms(lambda x: kern(x, q_r, kv, k_r), q, n=3)
+    pairs = T * (T + 1) / 2
+    least = 2.0 * H * (n + r + dv) * pairs / 197e12 * 1e3
+    print(f"{'OK ' if ok else 'FAIL'} mla_flash T={T} H={H} "
+          f"qk={n}+{r} v={dv}: fwd={err:.4f} least={least:.3f}ms"
+          + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
+    return ok
+
+
+def check_mla_paged(B, MB, lengths, H=128, R=512, r=64, KVB=16,
+                    scale=0.13523):
+    """The latent paged decode kernel over a pool of [latent | rotary
+    key | zeros] rows against a gather in jax.numpy, and the page write
+    against the row scatter, with the kernels' ms a call."""
+    from mxnet_tpu.kv_cache import latent_pool_shape
+    from mxnet_tpu.ops import attention as att
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rng = np.random.RandomState(B + MB)
+    shape = latent_pool_shape(1 + B * MB, KVB, R, r)
+    lanes = shape[2]
+    assert pk.mla_paged_enabled(H, lanes, R)
+    pool = np.zeros(shape, np.float32)
+    pool[..., :R + r] = rng.randn(*shape[:2], R + r) * 0.7
+    pool = jnp.asarray(pool).astype(jnp.bfloat16)
+    q = np.zeros((B, H, lanes), np.float32)
+    q[..., :R + r] = rng.randn(B, H, R + r) * 0.5
+    q = jnp.asarray(q).astype(jnp.bfloat16)
+    table = jnp.asarray(1 + rng.permutation(B * MB).reshape(B, MB), jnp.int32)
+    n = jnp.asarray(np.resize(np.asarray(lengths, np.int32), B))
+    kern = jax.jit(lambda q, pool: pk.mla_paged_decode(
+        q, pool, table, n - 1, R, scale))
+
+    def lax_body(q, pool):
+        rows = pool[table].reshape(B, MB * KVB, lanes)
+        s = jnp.einsum("bhw,btw->bht", q, rows,
+                       preferred_element_type=jnp.float32) * scale
+        seen = jnp.arange(MB * KVB)[None, None, :] < n[:, None, None]
+        p = jnp.where(seen, jax.nn.softmax(
+            jnp.where(seen, s, -jnp.inf), axis=-1), 0.0)
+        return jnp.einsum("bht,btr->bhr", p.astype(rows.dtype),
+                          rows[..., :R], preferred_element_type=jnp.float32)
+
+    got = np.asarray(kern(q, pool).astype(jnp.float32))
+    want = np.asarray(jax.jit(lax_body)(q, pool))
+    err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
+    ok = err < TOL and bool(np.isfinite(got).all())
+    ms = _kernel_ms(lambda x: kern(x, pool), q)
+    ctx = float(np.asarray(n).sum())
+    least = max(2.0 * H * (2 * R + r) * ctx / 197e12,
+                ctx * (R + r) * 2 / 819e9) * 1e3
+    # the page write: a whole prompt's rows, against the row scatter
+    T = min(4096, MB * KVB)
+    rows = jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
+                       ).astype(jnp.bfloat16)
+    tab = table[:1, :T // KVB]
+    live = jnp.asarray([T - 37], jnp.int32)
+    write = jax.jit(lambda rows, pool: att.latent_prefill_write(
+        rows, pool, tab, live))
+    page, slot, _ = att._paged_write_coords(tab, live, T, KVB)
+    scattered = np.array(jax.jit(
+        lambda rows, pool: pool.at[page, slot].set(rows))(rows, pool))
+    written = np.array(write(rows, pool))
+    # the last live page's slots past the length hold the padding's rows
+    # (the page write) or what was there (the scatter): no reader's
+    last = int(np.asarray(tab)[0, (T - 37 - 1) // KVB])
+    scattered[last, (T - 37) % KVB:] = written[last, (T - 37) % KVB:]
+    # (and the scratch page 0, which the scatter sends padding to)
+    same = bool((written[1:] == scattered[1:]).all())
+    ms.update(_kernel_ms(lambda x: write(x, pool), rows))
+    print(f"{'OK ' if ok and same else 'FAIL'} mla_paged B={B} MB={MB} "
+          f"H={H} row={R}+{r} in {lanes}: fwd={err:.4f} "
+          f"write_equal={same} least={least:.3f}ms"
+          + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
+    return ok and same
+
+
 def _time_ms(fn, *args, n=5):
     import time
 
@@ -568,6 +674,17 @@ def main():
         results.append(check_mamba2(1, 1, B=64))
         for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
             results.append(check_mamba2(T, n))
+        return _report(results)
+    if "--mla" in sys.argv:
+        # the longctx cell's shapes: 32 rows of 128 heads over 544-page
+        # tables of 640-lane rows at its mean context, then lengths
+        # that end inside a page and a chunk, an empty row among them;
+        # prompts in every prefill bucket, and one that fills no tile
+        results.append(check_mla_paged(32, 544, [3900]))
+        results.append(check_mla_paged(
+            32, 544, [8704, 513, 0, 1, 4095, 16, 0, 7000]))
+        for T in (1024, 2048, 4096, 8192, 1000):
+            results.append(check_mla_flash(T))
         return _report(results)
     if "--window" in sys.argv:
         # the mixed cell's shapes: 48 rows of 28 / 4 heads x 128 over
