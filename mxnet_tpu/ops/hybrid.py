@@ -995,6 +995,30 @@ def _tile_rows(n_pairs):
     return 128 if n_pairs >= 4096 else 16
 
 
+def _prompt_tiles(tm, d):
+    """Does the dispatch round run a prompt's form — output rows left
+    as slabs that ``moe_gmm_combine`` copies, weighs and adds
+    (``pallas_hybrid``'s moe_gmm section)?  Tiles of 128 rows, rows of
+    whole lane tiles, compiled kernels.  A decode step's 16-row tiles
+    keep (M, d) rows and k small gathers: there the kernels stream their
+    experts' weights, and row copies in front of a tile are latency, not
+    bandwidth (PERF.md section 6, PR 39's kernel-alone table)."""
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    return pk.enabled() and tm == 128 and ph.slab_rows(d) > 0
+
+
+def _by_index(tm, d, held, experts):
+    """Does ``moe_gmm_gate_up`` fetch a prompt's rows itself, by index?
+    Where at most half the experts are held here: the gather it saves
+    writes the worst case (every pair here), a row copy costs about
+    twice a gathered row, and with every expert held the worst case IS
+    the case (the same table: mixed loses a millisecond a layer by
+    index, longctx gains one)."""
+    return _prompt_tiles(tm, d) and 2 * held <= experts
+
+
 def moe_dispatch(topi, valid, first, held, tm):
     """Lay the token-expert pairs whose expert is held here
     (``first <= e < first + held``, token valid) out in rows sorted by
@@ -1002,27 +1026,36 @@ def moe_dispatch(topi, valid, first, held, tm):
 
     Returns ``here`` (N, k) bool; ``pair_row`` (N, k) the row of each
     pair (meaningless where not ``here``); ``row_token`` (M,) the token
-    a row holds; ``tile_expert`` (M // tm,); ``n_used`` (1,) tiles that
-    hold rows; ``sizes`` (held,) pairs per expert.  M covers every pair
-    landing here: nothing is dropped."""
+    a row holds (0 on padding); ``tile_expert`` (M // tm,); ``n_used``
+    (1,) tiles that hold rows; ``sizes`` (held,) pairs per expert.  M
+    covers every pair landing here: nothing is dropped.
+
+    Two sorts, comparisons and ONE scatter: on the chip a scatter or a
+    gather of single numbers runs an element at a time (5-9 ns each),
+    a sort of 65,536 pairs takes 0.05 ms — the scatter-add, the four
+    single-number gathers and the three scatters this replaced took
+    1.7-2.1 ms a layer at a long prompt's size, this takes 0.36-0.42
+    (PERF.md section 6, PR 39)."""
     N, k = topi.shape
     local = topi - first
     here = (local >= 0) & (local < held) & valid[:, None]
     key = jnp.where(here, local, held).reshape(-1)
     P = N * k
     M = -(-N * min(k, held) // tm) * tm + held * tm
-    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
-    padded = -(-sizes[:held] // tm) * tm
+    ids = jnp.arange(held, dtype=jnp.int32)
+    sizes = jnp.sum((key[:, None] == ids[None, :]).astype(jnp.int32), axis=0)
+    padded = -(-sizes // tm) * tm
     ends = jnp.cumsum(padded)
-    starts = jnp.concatenate([ends - padded, jnp.zeros((1,), jnp.int32)])
+    starts = ends - padded
     plain = jnp.cumsum(sizes) - sizes
-    order = jnp.argsort(key, stable=True)
-    skey = key[order]
-    row = jnp.where(skey < held,
-                    starts[skey] + jnp.arange(P) - plain[skey], M)
-    pair_row = jnp.zeros((P,), jnp.int32).at[order].set(row)
-    row_token = jnp.zeros((M,), jnp.int32).at[row].set(
-        (order // k).astype(jnp.int32), mode="drop")
+    # pairs in expert order (stable: a run keeps its tokens' order); the
+    # pair at sorted place i lies at row i + (its run's start - its
+    # expert's first place); back in pair order by a second sort
+    skey, order = lax.sort_key_val(key, jnp.arange(P, dtype=jnp.int32))
+    shift = jnp.sum(jnp.where(skey[:, None] == ids[None, :],
+                              (starts - plain)[None, :], 0), axis=1)
+    row = jnp.where(skey < held, jnp.arange(P, dtype=jnp.int32) + shift, M)
+    pair_row = lax.sort_key_val(order, row)[1]
     # the expert whose padded run holds a tile's first row: the runs
     # that END at or before it (a comparison, not a search: a search is
     # a loop of tiny programs on the chip)
@@ -1031,25 +1064,36 @@ def moe_dispatch(topi, valid, first, held, tm):
         jnp.sum((ends[None, :] <= first_row[:, None]).astype(jnp.int32),
                 axis=1), held - 1)
     n_used = (ends[-1:] // tm).astype(jnp.int32)
+    row_token = jnp.zeros((M,), jnp.int32).at[row].set(order // k,
+                                                       mode="drop")
     return (here, pair_row.reshape(N, k), row_token, tile_expert, n_used,
-            sizes[:held])
+            sizes)
 
 
 EXPERT_ACTS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
 def moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert, n_used,
-                tm, act="silu"):
-    """The held experts on the dispatched rows -> (M, d) float32 (rows
-    of unused tiles hold anything).  ``act``: the gate's activation."""
+                tm, act="silu", by_index=False):
+    """The held experts on the dispatched rows, float32 (rows of unused
+    tiles hold anything): (M, d) — or, for a prompt's tiles
+    (``_prompt_tiles``), the (M, R, 128) slabs ``moe_combine`` reads.
+    ``act``: the gate's activation; ``by_index``: the kernel fetches
+    its rows itself (``_by_index``)."""
     from . import pallas_hybrid as ph
     from . import pallas_kernels as pk
 
+    if by_index:
+        h = ph.moe_gmm_gate_up(x2, w_gate, w_up, tile_expert, n_used, tm,
+                               act, row_token=row_token)
+        return ph.moe_gmm_down(h, w_down, tile_expert, n_used, tm,
+                               slabs=True)
     xs = x2[row_token]
     if pk.enabled():
         h = ph.moe_gmm_gate_up(xs, w_gate, w_up, tile_expert, n_used, tm,
                                act)
-        return ph.moe_gmm_down(h, w_down, tile_expert, n_used, tm)
+        return ph.moe_gmm_down(h, w_down, tile_expert, n_used, tm,
+                               slabs=_prompt_tiles(tm, x2.shape[1]))
     e = jnp.repeat(tile_expert, tm)
 
     def mm(a, w):
@@ -1058,6 +1102,24 @@ def moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert, n_used,
 
     h = (EXPERT_ACTS[act](mm(xs, w_gate)) * mm(xs, w_up)).astype(xs.dtype)
     return mm(h, w_down)
+
+
+def moe_combine(ys, pair_row, here, wts, d, dtype):
+    """Token n's output: its rows ``ys[pair_row[n, j]]`` times their
+    routing weights ``wts[n, j]`` over the pairs that are ``here``,
+    added in j's order, float32 -> (N, d) in ``dtype``.  A row that is
+    not here is masked, never multiplied (an unused tile's rows hold
+    anything)."""
+    from . import pallas_hybrid as ph
+
+    if ys.ndim == 3:
+        return ph.moe_gmm_combine(ys, pair_row, here, wts, d, dtype)
+    at = jnp.minimum(pair_row, ys.shape[0] - 1)
+    y = jnp.zeros((pair_row.shape[0], d), jnp.float32)
+    for j in range(pair_row.shape[1]):
+        y = y + jnp.where(here[:, j, None], ys[at[:, j]] * wts[:, j, None],
+                          0.0)
+    return y.astype(dtype)
 
 
 def _moe_infer(attrs, in_shapes):
@@ -1109,6 +1171,8 @@ def _moe_args(attrs):
               "attrs: top_k, first_expert, step, count, score, act, "
               "router_data, select_bias, groups, top_groups, routed_scale")
 def _moe_ffn(op_ctx, attrs, inputs, aux):
+    from .. import profiler
+
     x, router_w, w_gate, w_up, w_down, lengths, counters = inputs[:7]
     act = str(attrs.get("act", "silu"))
     if act not in EXPERT_ACTS:
@@ -1140,14 +1204,15 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
     tm = _tile_rows(B * S * min(top_k, held))
     here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
         topi, valid, first, held, tm)
+    by_index = _by_index(tm, d, held, router_w.shape[0])
+    profiler.inc_counter("moe.nodes_indexed" if by_index
+                         else "moe.nodes_gathered")
     ys = moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert,
-                     n_used, tm, act)
-    got = ys[jnp.minimum(pair_row, ys.shape[0] - 1)]       # (N, k, d)
-    y = jnp.sum(jnp.where(here[..., None], got * wts[..., None], 0.0),
-                axis=1)
+                     n_used, tm, act, by_index)
+    y = moe_combine(ys, pair_row, here, wts, d, x.dtype)
     if count:
         pairs = jnp.sum(here.astype(jnp.int32))
         counters = counters + jnp.stack([
             pairs, jnp.sum(valid.astype(jnp.int32)) * top_k - pairs,
             jnp.sum((sizes > 0).astype(jnp.int32)), jnp.max(sizes)])
-    return [y.reshape(B, S, d).astype(x.dtype), counters]
+    return [y.reshape(B, S, d), counters]

@@ -693,7 +693,38 @@ def mamba2_chunk(dx, xbc, la):
 # index maps name the LAST block a used tile touched, so no block is
 # fetched or written for it.  K is cut in blocks of whole rows of the
 # (E, K, N) weights: each block is one contiguous piece of HBM.
+#
+# BY INDEX (a prompt's tiles of 128 rows): ``moe_gmm_gate_up(...,
+# row_token=)`` takes the TOKEN rows and copies a tile's 128 into VMEM
+# itself, a copy a row, started a tile ahead of the matmul that uses
+# them, for the used tiles only: the dispatched rows are never laid out
+# in HBM.  ``moe_gmm_down(..., slabs=True)`` leaves each output row so
+# that ``moe_gmm_combine`` can copy it the same way, weigh it and add a
+# token's rows.  Mosaic copies no single row of a tiled (rows, d) array,
+# so a row that is copied alone is a SLAB: (d / 128, 128) float32 in
+# whole (8, 128) tiles (``token_slabs``), one contiguous piece of HBM,
+# and row i of a tile is sublanes i·R.. of a flat (tile·R, 128) buffer:
+# lane chunk c of every row is one strided read or write of it.
 # ---------------------------------------------------------------------------
+
+_LANES = 128
+# row copies started (or awaited) a turn of the loop over a tile's rows
+_ROW_UNROLL = 4
+
+
+def slab_rows(d):
+    """Sublanes of one row of ``d`` values as a slab of whole float32
+    tiles; 0 where ``d`` is no whole number of lane tiles (no slabs)."""
+    return 0 if d % _LANES else -(-d // _LANES // 8) * 8
+
+
+def token_slabs(x):
+    """x (N, d) -> (N, R, 128) float32, R = ``slab_rows(d)``: row n's
+    values in reading order, zeros after them."""
+    n, d = x.shape
+    return jnp.pad(x.astype(jnp.float32).reshape(n, d // _LANES, _LANES),
+                   ((0, 0), (0, slab_rows(d) - d // _LANES), (0, 0)))
+
 
 def _gmm_maps(nk):
     def tile(t, nu):
@@ -702,18 +733,13 @@ def _gmm_maps(nk):
     def kblock(t, kk, nu):
         return jnp.where(t < nu[0], kk, nk - 1)
 
-    x_map = lambda t, kk, te, nu: (tile(t, nu), kblock(t, kk, nu))
-    w_map = lambda t, kk, te, nu: (te[tile(t, nu)], kblock(t, kk, nu), 0)
-    o_map = lambda t, kk, te, nu: (tile(t, nu), 0)
+    x_map = lambda t, kk, te, nu, *_: (tile(t, nu), kblock(t, kk, nu))
+    w_map = lambda t, kk, te, nu, *_: (te[tile(t, nu)], kblock(t, kk, nu), 0)
+    o_map = lambda t, kk, te, nu, *_: (tile(t, nu), 0)
     return x_map, w_map, o_map
 
 
-def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
-                        accg, accu, *, nk, act):
-    del te_ref
-    t, kk = pl.program_id(0), pl.program_id(1)
-    used = t < nu_ref[0]
-
+def _gate_up_steps(accg, accu, o_ref, x, wg_ref, wu_ref, used, kk, nk, act):
     @pl.when(used & (kk == 0))
     def _init():
         accg[...] = jnp.zeros_like(accg)
@@ -721,10 +747,10 @@ def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
 
     @pl.when(used)
     def _mul():
-        x = x_ref[...]
-        accg[...] += jnp.dot(x, wg_ref[0],
+        xk = x()
+        accg[...] += jnp.dot(xk, wg_ref[0],
                              preferred_element_type=jnp.float32)
-        accu[...] += jnp.dot(x, wu_ref[0],
+        accu[...] += jnp.dot(xk, wu_ref[0],
                              preferred_element_type=jnp.float32)
 
     @pl.when(used & (kk == nk - 1))
@@ -734,7 +760,64 @@ def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
         o_ref[...] = (g * accu[...]).astype(o_ref.dtype)
 
 
-def _gmm_down_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, acc, *, nk):
+def _gmm_gate_up_kernel(te_ref, nu_ref, x_ref, wg_ref, wu_ref, o_ref,
+                        accg, accu, *, nk, act):
+    del te_ref
+    t, kk = pl.program_id(0), pl.program_id(1)
+    _gate_up_steps(accg, accu, o_ref, lambda: x_ref[...], wg_ref, wu_ref,
+                   t < nu_ref[0], kk, nk, act)
+
+
+def _gmm_gate_up_rows_kernel(te_ref, nu_ref, rt_ref, x_hbm, wg_ref, wu_ref,
+                             o_ref, xbuf, sem, accg, accu, *, nk, tm, rp,
+                             act):
+    del te_ref
+    t, kk = pl.program_id(0), pl.program_id(1)
+    nu = nu_ref[0]
+    slot = t % 2
+
+    def copies(tile, slot, op):
+        # the tile's tm rows, each token's slab to its sublanes of the
+        # slot's buffer (a wait names any slab: it counts one's bytes),
+        # a few a turn of a loop the compiler keeps rolled
+        def rows(g, _):
+            for u in range(_ROW_UNROLL):
+                i = g * _ROW_UNROLL + u
+                token = rt_ref[tile * tm + i] if op == "start" else 0
+                getattr(pltpu.make_async_copy(
+                    x_hbm.at[token],
+                    xbuf.at[slot, pl.ds(pl.multiple_of(i * rp, 8), rp)],
+                    sem.at[slot]), op)()
+
+        jax.lax.fori_loop(0, tm // _ROW_UNROLL, rows, None)
+
+    # who starts whose copies: a tile the NEXT tile's, under its own
+    # matmuls; tile 0 its own as well
+    @pl.when((kk == 0) & (t == 0) & (nu > 0))
+    def _first():
+        copies(0, 0, "start")
+
+    @pl.when((kk == 0) & (t + 1 < nu))
+    def _ahead():
+        copies(t + 1, 1 - slot, "start")
+
+    @pl.when((kk == 0) & (t < nu))
+    def _arrived():
+        copies(t, slot, "wait")
+
+    chunks = wg_ref.shape[1] // _LANES
+
+    def x():
+        # lane chunk c of the K block: sublane c of every row's slab
+        return jnp.concatenate(
+            [xbuf[slot, pl.ds(kk * chunks + c, tm, stride=rp), :]
+             for c in range(chunks)], axis=1).astype(wg_ref.dtype)
+
+    _gate_up_steps(accg, accu, o_ref, x, wg_ref, wu_ref, t < nu, kk, nk,
+                   act)
+
+
+def _gmm_down_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, acc, *, nk, rp):
     del te_ref
     t, kk = pl.program_id(0), pl.program_id(1)
     used = t < nu_ref[0]
@@ -750,62 +833,210 @@ def _gmm_down_kernel(te_ref, nu_ref, x_ref, w_ref, o_ref, acc, *, nk):
 
     @pl.when(used & (kk == nk - 1))
     def _out():
-        o_ref[...] = acc[...].astype(o_ref.dtype)
+        if not rp:
+            o_ref[...] = acc[...]
+            return
+        tm, n = acc.shape
+        for c in range(n // _LANES):
+            o_ref[pl.ds(c, tm, stride=rp), :] = \
+                acc[:, c * _LANES:(c + 1) * _LANES]
+
+
+def _gmm_combine_kernel(at_ref, list_ref, ys_hbm, pairs_ref, wts_ref, o_ref,
+                        buf, sem, weight, summed, *, tn, rp, ib, jb):
+    t = pl.program_id(0)
+    slot = t % 2
+
+    def copies(tile, slot, op):
+        # the tile's pairs that are here, ``list_ref[at[tile]:at[tile +
+        # 1]]``, each [row | token's place in the tile | j] in one
+        # number: pair j of token i to token i's sublanes of plane j
+        # (every slab is as long: a wait names any)
+        def pair(p, _):
+            v = list_ref[p] if op == "start" else 0
+            i, j = (v >> jb) & ((1 << ib) - 1), v & ((1 << jb) - 1)
+            getattr(pltpu.make_async_copy(
+                ys_hbm.at[v >> (ib + jb)],
+                buf.at[slot, j, pl.ds(pl.multiple_of(i * rp, 8), rp)],
+                sem.at[slot]), op)()
+
+        jax.lax.fori_loop(at_ref[tile], at_ref[tile + 1], pair, None)
+
+    @pl.when(t == 0)
+    def _first():
+        copies(0, 0, "start")
+
+    @pl.when(t + 1 < pl.num_programs(0))
+    def _ahead():
+        copies(t + 1, 1 - slot, "start")
+
+    copies(t, slot, "wait")
+    # a pair's weight over its token's lanes, 0 where it is not here:
+    # broadcast once a tile, not once a lane chunk
+    k = pairs_ref.shape[1]
+    wts = jnp.where(pairs_ref[...] >= 0, wts_ref[...], 0.0)   # (tn, k)
+    for j in range(k):
+        weight[j] = jnp.broadcast_to(wts[:, j:j + 1], (tn, _LANES))
+    chunks = o_ref.shape[1] // _LANES
+
+    def chunk(c, _):
+        # lane chunk c of the tile's tokens: the pairs in j's order;
+        # what a plane holds where no pair is here is masked, not
+        # multiplied (it may be anything)
+        y = jnp.zeros((tn, _LANES), jnp.float32)
+        for j in range(k):
+            w = weight[j]
+            y = y + jnp.where(
+                w != 0.0, buf[slot, j, pl.ds(c, tn, stride=rp), :] * w, 0.0)
+        summed[c] = y
+
+    jax.lax.fori_loop(0, chunks, chunk, None)
+    for c in range(chunks):
+        o_ref[:, c * _LANES:(c + 1) * _LANES] = \
+            summed[c].astype(o_ref.dtype)
 
 
 def _k_block(K, want):
     return want if K % want == 0 else K
 
 
-def moe_gmm_gate_up(x, w_gate, w_up, tile_expert, n_used, tm, act="silu"):
-    """x (M, K) rows sorted by expert in tiles of ``tm``; w_gate, w_up
-    (E, K, N) -> act(x W_gate[e]) * (x W_up[e]), (M, N) in x.dtype,
-    for the rows of the first ``n_used[0]`` tiles (the rest is not
-    written).  ``act``: ``silu`` or ``relu`` (static)."""
-    M, K = x.shape
+@functools.partial(jax.jit, static_argnames=("tm", "act", "interpret"))
+def _gate_up(x, w_gate, w_up, tile_expert, n_used, row_token, *, tm, act,
+             interpret):
+    """``moe_gmm_gate_up``'s call, jitted: a model's layers make it with
+    one shape, so the kernel is traced and lowered once a program
+    (``pallas_kernels._flash_mha_packed_fn`` says why ``interpret`` is in
+    the key)."""
+    by_index = row_token is not None
+    M, K = (row_token if by_index else x).shape[0], x.shape[1]
     N = w_gate.shape[2]
     tk = _k_block(K, 512)
     nk = K // tk
     x_map, w_map, o_map = _gmm_maps(nk)
+    weights = [_vmem_spec((1, tk, N), w_map)] * 2
+    acc = [pltpu.VMEM((tm, N), jnp.float32)] * 2
+    if by_index:
+        rp = slab_rows(K)
+        kernel = functools.partial(_gmm_gate_up_rows_kernel, nk=nk, tm=tm,
+                                   rp=rp, act=act)
+        scalars, rows = (tile_expert, n_used, row_token), token_slabs(x)
+        in_specs = [pl.BlockSpec(memory_space=pl.ANY)] + weights
+        scratch = [pltpu.VMEM((2, tm * rp, _LANES), jnp.float32),
+                   pltpu.SemaphoreType.DMA((2,))] + acc
+    else:
+        kernel = functools.partial(_gmm_gate_up_kernel, nk=nk, act=act)
+        scalars, rows = (tile_expert, n_used), x
+        in_specs = [_vmem_spec((tm, tk), x_map)] + weights
+        scratch = acc
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(M // tm, nk),
-        in_specs=[_vmem_spec((tm, tk), x_map),
-                  _vmem_spec((1, tk, N), w_map),
-                  _vmem_spec((1, tk, N), w_map)],
-        out_specs=_vmem_spec((tm, N), o_map),
-        scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32),
-                        pltpu.VMEM((tm, N), jnp.float32)])
+        num_scalar_prefetch=len(scalars), grid=(M // tm, nk),
+        in_specs=in_specs, out_specs=_vmem_spec((tm, N), o_map),
+        scratch_shapes=scratch)
     return pl.pallas_call(
-        functools.partial(_gmm_gate_up_kernel, nk=nk, act=act),
-        grid_spec=grid_spec,
+        kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         compiler_params=_compiler_params(
             "arbitrary", "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
-        interpret=_interpret(),
+        interpret=interpret,
         name="moe_gmm_gate_up" if act == "silu" else f"moe_gmm_gate_up_{act}",
-    )(tile_expert, n_used, x, w_gate, w_up)
+    )(*scalars, rows, w_gate, w_up)
 
 
-def moe_gmm_down(x, w_down, tile_expert, n_used, tm):
+def moe_gmm_gate_up(x, w_gate, w_up, tile_expert, n_used, tm, act="silu",
+                    row_token=None):
+    """x (M, K) rows sorted by expert in tiles of ``tm``; w_gate, w_up
+    (E, K, N) -> act(x W_gate[e]) * (x W_up[e]), (M, N) in x.dtype,
+    for the rows of the first ``n_used[0]`` tiles (the rest is not
+    written).  ``act``: ``silu`` or ``relu`` (static).
+
+    ``row_token`` (M,) int32 given: x is the TOKEN rows (N, K), K whole
+    lane tiles, and row m of the result is token ``row_token[m]``'s; the
+    kernel fetches the used tiles' rows itself (section comment)."""
+    return _gate_up(x, w_gate, w_up, tile_expert, n_used, row_token, tm=tm,
+                    act=act, interpret=_interpret())
+
+
+def moe_gmm_down(x, w_down, tile_expert, n_used, tm, slabs=False):
     """x (M, K) as :func:`moe_gmm_gate_up` gives it; w_down (E, K, N)
-    -> x W_down[e], (M, N) float32, for the rows of the used tiles."""
+    -> x W_down[e], float32, for the rows of the used tiles: (M, N), or
+    with ``slabs`` (M, R, 128), each row a slab ``moe_gmm_combine``
+    copies whole (what lies past a slab's N values is not written)."""
     M, K = x.shape
     N = w_down.shape[2]
     tk = _k_block(K, 256)
     nk = K // tk
+    rp = slab_rows(N) if slabs else 0
     x_map, w_map, o_map = _gmm_maps(nk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2, grid=(M // tm, nk),
         in_specs=[_vmem_spec((tm, tk), x_map),
                   _vmem_spec((1, tk, N), w_map)],
-        out_specs=_vmem_spec((tm, N), o_map),
+        out_specs=_vmem_spec((tm * rp, _LANES) if rp else (tm, N), o_map),
         scratch_shapes=[pltpu.VMEM((tm, N), jnp.float32)])
-    return pl.pallas_call(
-        functools.partial(_gmm_down_kernel, nk=nk),
+    out = pl.pallas_call(
+        functools.partial(_gmm_down_kernel, nk=nk, rp=rp),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct(
+            (M * rp, _LANES) if rp else (M, N), jnp.float32),
         compiler_params=_compiler_params(
             "arbitrary", "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="moe_gmm_down",
     )(tile_expert, n_used, x, w_down)
+    return out.reshape(M, rp, _LANES) if rp else out
+
+
+# tokens a grid step of the combine: top_k planes of their slabs, twice
+# buffered (10 x 64 x 16 KB x 2 = 21 MB at the widest cell)
+_COMBINE_TOKENS = 64
+
+
+@functools.partial(jax.jit, static_argnames=("d", "dtype", "interpret"))
+def _combine(ys, pairs, wts, *, d, dtype, interpret):
+    n, k = pairs.shape
+    m, rp = ys.shape[:2]
+    tn = _COMBINE_TOKENS if n % _COMBINE_TOKENS == 0 else n
+    ib, jb = (tn - 1).bit_length(), (k - 1).bit_length()
+    if (m - 1).bit_length() + ib + jb > 31:
+        raise ValueError(
+            f"moe_gmm_combine: a row of {m}, a place of {tn} and a pair of "
+            f"{k} do not fit one int32")
+    # the pairs that are here, in pair order (a tile's are consecutive:
+    # ``at[t]`` says from where), each [row | place in its tile | j];
+    # a sort, not a scan of every pair by the kernel's scalar core
+    flat = pairs.reshape(-1)
+    place = jnp.arange(n * k, dtype=jnp.int32)
+    packed = (flat << (ib + jb)) | (place // k % tn << jb) | place % k
+    listed = jax.lax.sort_key_val((flat < 0).astype(jnp.int32), packed)[1]
+    at = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(jnp.sum(
+        (pairs >= 0).reshape(n // tn, tn * k).astype(jnp.int32), axis=1))])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(n // tn,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  _vmem_spec((tn, k), lambda t, *_: (t, 0)),
+                  _vmem_spec((tn, k), lambda t, *_: (t, 0))],
+        out_specs=_vmem_spec((tn, d), lambda t, *_: (t, 0)),
+        scratch_shapes=[pltpu.VMEM((2, k, tn * rp, _LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.VMEM((k, tn, _LANES), jnp.float32),
+                        pltpu.VMEM((d // _LANES, tn, _LANES), jnp.float32)])
+    return pl.pallas_call(
+        functools.partial(_gmm_combine_kernel, tn=tn, rp=rp, ib=ib, jb=jb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n, d), dtype),
+        compiler_params=_compiler_params(
+            "arbitrary", vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=interpret,
+        name="moe_gmm_combine",
+    )(at, listed, ys, pairs, wts.astype(jnp.float32))
+
+
+def moe_gmm_combine(ys, pair_row, here, wts, d, dtype):
+    """ys (M, R, 128) float32 slabs (``moe_gmm_down(..., slabs=True)``);
+    pair_row, here, wts (N, k) -> (N, d) in ``dtype``: token n's rows
+    ``ys[pair_row[n, j]]`` times ``wts[n, j]`` over the pairs that are
+    ``here``, added in j's order, float32; exactly 0 for a token with
+    none.  Only those rows are read: no (N, k, d) array is made."""
+    pairs = jnp.where(here, pair_row, -1).astype(jnp.int32)
+    return _combine(ys, pairs, wts, d=d, dtype=jnp.dtype(dtype),
+                    interpret=_interpret())

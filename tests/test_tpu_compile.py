@@ -336,6 +336,13 @@ def _kernel_names(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def _kernel_short_names(text):
+    """The same, as the benchmark's readers shorten an event's name:
+    ``ROOT %moe_gmm_down.3`` is ``moe_gmm_down``."""
+    return [re.sub(r"^(ROOT )?%|\.\d+$", "", name)
+            for name in _kernel_names(text)]
+
+
 @pytest.mark.parametrize("window", [0, _MIXED[-1]])
 def test_paged_attention_at_the_mixed_cells_shapes(on_chip, one_chip,
                                                    window):
@@ -441,6 +448,75 @@ def test_moe_gmm_relu_at_the_mixed_cells_shapes(on_chip, one_chip,
         ((64, 2560, 768), bf16), ((rows // tm,), i32),
         ((1,), i32)).as_text()
     assert "moe_gmm_gate_up_relu" in text
+
+
+# the four expert cells' largest prefill: tokens, top_k, d, the experts'
+# width, experts held, the gate
+_MOE_PREFILLS = {
+    "rag": (2048, 10, 4096, 768, 36, "silu"),
+    "mixed": (8192, 6, 2560, 768, 64, "relu"),
+    "reason": (2048, 8, 4096, 1280, 40, "silu"),
+    "longctx": (8192, 8, 7168, 2048, 8, "silu"),
+}
+
+
+@pytest.mark.parametrize("cell", list(_MOE_PREFILLS))
+def test_moe_gmm_by_index_at_the_cells_prefill_shapes(on_chip, one_chip,
+                                                      monkeypatch, cell):
+    """The kernels that take a prompt's rows by index — ``gate_up`` that
+    copies the token rows of a tile itself, ``down`` that leaves
+    slabs, the combine that copies a token's slabs, weighs and adds them — at
+    each cell's worst-case rows (every pair here + a tile of padding an
+    expert), each under the name the readers match."""
+    ph = _hybrid(monkeypatch)
+    n, k, d, w, held, act = _MOE_PREFILLS[cell]
+    tm = 128
+    rows = n * min(k, held) + held * tm
+    tiles = [((rows // tm,), i32), ((1,), i32)]
+    text = _compile(
+        lambda x, g, u, te, nu, rt: ph.moe_gmm_gate_up(
+            x, g, u, te, nu, tm, act, row_token=rt),
+        one_chip, ((n, d), bf16), ((held, d, w), bf16), ((held, d, w), bf16),
+        *tiles, ((rows,), i32)).as_text()
+    assert _kernel_short_names(text) == [
+        "moe_gmm_gate_up" + ("" if act == "silu" else "_" + act)]
+    text = _compile(
+        lambda x, wd, te, nu: ph.moe_gmm_down(x, wd, te, nu, tm, slabs=True),
+        one_chip, ((rows, w), bf16), ((held, w, d), bf16), *tiles).as_text()
+    assert _kernel_short_names(text) == ["moe_gmm_down"]
+    text = _compile(
+        lambda ys, pr, here, wts: ph.moe_gmm_combine(ys, pr, here, wts, d,
+                                                     bf16),
+        one_chip, ((rows, ph.slab_rows(d), 128), f32), ((n, k), i32),
+        ((n, k), jnp.bool_), ((n, k), f32)).as_text()
+    assert _kernel_short_names(text) == ["moe_gmm_combine"]
+
+
+def test_moe_ffn_prefill_program_by_index(on_chip, one_chip, monkeypatch):
+    """The rag cell's 2,048-token ``MoEFFN`` node as the chip's compiler
+    leaves it: the three ``moe_gmm`` kernels, no (M, d) array of
+    dispatched rows and no (N, k, d) array of gathered outputs in any
+    type — the worst case is 25,088 rows of 4,096."""
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    _hybrid(monkeypatch)
+    n, k, d, w, held, _ = _MOE_PREFILLS["rag"]
+    rows = n * min(k, held) + held * 128
+
+    def ffn(*inputs):
+        return get_op("MoEFFN").compute(
+            OpContext(is_train=False, rng=None),
+            {"top_k": str(k), "score": "softmax_topk", "count": "1"},
+            list(inputs), [])
+
+    text = _compile(
+        ffn, one_chip, ((1, n, d), bf16), ((2 * held, d), f32),
+        ((held, d, w), bf16), ((held, d, w), bf16), ((held, w, d), bf16),
+        ((1,), i32), ((4,), i32)).as_text()
+    assert sorted(_kernel_short_names(text)) == [
+        "moe_gmm_combine", "moe_gmm_down", "moe_gmm_gate_up"]
+    for shape in (f"[{rows},{d}]", f"[{n},{k},{d}]", f"[{n * k},{d}]"):
+        assert shape not in text, shape
 
 
 def test_hybrid_decode_slots_update_in_place(on_chip, one_chip,

@@ -26,6 +26,10 @@ after ANY kernel change:
     python tools/verify_kernels.py --pages  # a prefill's K/V write: the
                                             # page kernel against the row
                                             # scatter at the cells' shapes
+    python tools/verify_kernels.py --moe    # the experts' dispatch round
+                                            # at the four expert cells'
+                                            # shapes: the gathered pieces
+                                            # beside the ones by index
     python tools/verify_kernels.py --mla    # the latent-attention kernels
                                             # (prefill, paged decode, page
                                             # write) at the longctx cell's
@@ -630,6 +634,161 @@ def check_pages_write(T, W, P, live, behind=0, KVB=16):
     return ok
 
 
+def _program_ms(fn, *args, n=3):
+    """Device ms a call of jitted ``fn(*args)``: every op of its program
+    summed, from a trace of n calls (the benchmark's reader)."""
+    import shutil
+    import tempfile
+
+    from benchmark.trace_reduce import Trace
+
+    jax.block_until_ready(fn(*args))
+    d = tempfile.mkdtemp(prefix="verify_kernels_")
+    try:
+        jax.profiler.start_trace(d)
+        for _ in range(n):
+            out = fn(*args)
+        jax.block_until_ready(out)
+        jax.profiler.stop_trace()
+        return 1e3 * sum(Trace.from_dir(d).op_seconds().values()) / n
+    finally:
+        shutil.rmtree(d, ignore_errors=True)
+
+
+# the four expert cells: experts scored, held here, top_k, d, the experts'
+# width, the gate; tokens of the largest prefill bucket, rows of a decode
+# step; what moe_load_max_over_mean.* reads in the ledger (PR 38)
+MOE_CELLS = {
+    "rag": (72, 36, 10, 4096, 768, "silu", 2048, 64, 1.84),
+    "mixed": (64, 64, 6, 2560, 768, "relu", 8192, 48, 2.26),
+    "reason": (320, 40, 8, 4096, 1280, "silu", 2048, 128, 3.99),
+    "longctx": (256, 8, 8, 7168, 2048, "silu", 8192, 32, 3.24),
+}
+
+
+def _scatter_dispatch(topi, valid, first, held, tm):
+    """``ops/hybrid.py moe_dispatch`` as it stood before PR 39 — a
+    scatter-add for the sizes, single-number gathers, three scatters:
+    the form the table holds the sorts against."""
+    N, k = topi.shape
+    local = topi - first
+    here = (local >= 0) & (local < held) & valid[:, None]
+    key = jnp.where(here, local, held).reshape(-1)
+    P = N * k
+    M = -(-N * min(k, held) // tm) * tm + held * tm
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)
+    padded = -(-sizes[:held] // tm) * tm
+    ends = jnp.cumsum(padded)
+    starts = jnp.concatenate([ends - padded, jnp.zeros((1,), jnp.int32)])
+    plain = jnp.cumsum(sizes) - sizes
+    order = jnp.argsort(key, stable=True)
+    skey = key[order]
+    row = jnp.where(skey < held,
+                    starts[skey] + jnp.arange(P) - plain[skey], M)
+    pair_row = jnp.zeros((P,), jnp.int32).at[order].set(row)
+    row_token = jnp.zeros((M,), jnp.int32).at[row].set(
+        (order // k).astype(jnp.int32), mode="drop")
+    first_row = jnp.arange(M // tm, dtype=jnp.int32) * tm
+    tile_expert = jnp.minimum(
+        jnp.sum((ends[None, :] <= first_row[:, None]).astype(jnp.int32),
+                axis=1), held - 1)
+    n_used = (ends[-1:] // tm).astype(jnp.int32)
+    return (here, pair_row.reshape(N, k), row_token, tile_expert, n_used,
+            sizes[:held])
+
+
+def check_moe(cell, tokens):
+    """One expert layer's dispatch round at ``cell``'s widths over
+    ``tokens`` rows — a prefill bucket or a decode step — piece by
+    piece, device ms a call.  As it stood: the dispatch by scatters,
+    the ``xs`` gather, ``gate_up``, ``down``, the ``got`` gather +
+    reshape + weighted sum.  Beside them: the dispatch by sorts,
+    ``gate_up`` with its own row copies, ``down`` into slabs, the
+    combine as a kernel and as k gathers; the two dispatches must agree
+    number for number, and the largest float32 gap between the two
+    outputs is printed."""
+    from mxnet_tpu.ops import hybrid
+    from mxnet_tpu.ops import pallas_hybrid as ph
+
+    experts, held, k, d, w, act, _, _, _ = MOE_CELLS[cell]
+    n = tokens
+    rng = np.random.RandomState(n + d)
+    bf = jnp.bfloat16
+    x2 = jnp.asarray(rng.randn(n, d).astype(np.float32)).astype(bf)
+    wg, wu = (jnp.asarray(0.02 * rng.randn(held, d, w).astype(np.float32))
+              .astype(bf) for _ in range(2))
+    wd = jnp.asarray(0.02 * rng.randn(held, w, d).astype(np.float32)
+                     ).astype(bf)
+    # a token's k experts: the largest of popularity + noise
+    scores = 0.5 * rng.randn(experts) + rng.gumbel(size=(n, experts))
+    topi = jnp.asarray(np.argsort(-scores, axis=1)[:, :k].astype(np.int32))
+    wts = jax.nn.softmax(jnp.asarray(rng.randn(n, k).astype(np.float32)))
+    valid = jnp.ones((n,), bool)
+    tm = hybrid._tile_rows(n * min(k, held))
+    ms, out = {}, {}
+
+    def piece(name, fn, *args):
+        fn = jax.jit(fn)
+        ms[name] = _program_ms(fn, *args)
+        out[name] = fn(*args)
+        return out[name]
+
+    was = piece("scatter_dispatch", functools.partial(
+        _scatter_dispatch, first=0, held=held, tm=tm), topi, valid)
+    now = piece("dispatch", functools.partial(
+        hybrid.moe_dispatch, first=0, held=held, tm=tm), topi, valid)
+    here, pair_row, row_token, tile_expert, n_used, sizes = now
+    used = int(n_used[0]) * tm           # rows past them hold anything
+    ok = all(bool(jnp.array_equal(a, b)) for a, b in (
+        (was[0], here), (jnp.where(here, was[1], 0),
+                         jnp.where(here, pair_row, 0)),
+        (was[2][:used], row_token[:used]), (was[3], tile_expert),
+        (was[4], n_used), (was[5], sizes)))
+    pairs = int(jnp.sum(sizes))
+    load = float(jnp.max(sizes)) * held / max(pairs, 1)
+
+    def old_combine(ys, pair_row, here, wts):
+        got = ys[jnp.minimum(pair_row, ys.shape[0] - 1)]
+        return jnp.sum(jnp.where(here[..., None], got * wts[..., None],
+                                 0.0), axis=1).astype(bf)
+
+    tiles = (tile_expert, n_used)
+    xs = piece("xs_gather", lambda x, rt: x[rt], x2, row_token)
+    h = piece("gate_up", lambda xs, wg, wu, te, nu: ph.moe_gmm_gate_up(
+        xs, wg, wu, te, nu, tm, act), xs, wg, wu, *tiles)
+    ys = piece("down", lambda h, wd, te, nu: ph.moe_gmm_down(
+        h, wd, te, nu, tm), h, wd, *tiles)
+    y_old = piece("got_sum", old_combine, ys, pair_row, here, wts)
+    h2 = piece("gate_up_rows", lambda x, wg, wu, te, nu, rt:
+               ph.moe_gmm_gate_up(x, wg, wu, te, nu, tm, act, row_token=rt),
+               x2, wg, wu, *tiles, row_token)
+    ys3 = piece("down_slabs", lambda h, wd, te, nu: ph.moe_gmm_down(
+        h, wd, te, nu, tm, slabs=True), h2, wd, *tiles)
+    y_new = piece("combine", lambda ys, pr, here, wts: ph.moe_gmm_combine(
+        ys, pr, here, wts, d, bf), ys3, pair_row, here, wts)
+    y_alt = piece("k_gathers", lambda ys, pr, here, wts: hybrid.moe_combine(
+        ys, pr, here, wts, d, bf), ys, pair_row, here, wts)
+    f32 = jnp.float32
+    top = float(jnp.max(jnp.abs(y_old.astype(f32))))
+    gap = float(jnp.max(jnp.abs(y_new.astype(f32) - y_old.astype(f32))))
+    gap_alt = float(jnp.max(jnp.abs(y_alt.astype(f32) - y_old.astype(f32))))
+    ok &= bool(jnp.array_equal(h[:used], h2[:used])) \
+        and max(gap, gap_alt) <= 2.0 ** -8 * top
+    old = sum(ms[p] for p in ("scatter_dispatch", "xs_gather", "gate_up",
+                              "down", "got_sum"))
+    rows = ms["dispatch"] + ms["down_slabs"] + ms["combine"]
+    print(f"{'OK ' if ok else 'FAIL'} moe {cell} rows={n} tm={tm} "
+          f"pairs_here={pairs} of {n * k} tiles={int(n_used[0])} of "
+          f"{row_token.shape[0] // tm} load_max_over_mean={load:.2f}: "
+          + " ".join(f"{p}={v:.4f}ms" for p, v in ms.items())
+          + f" | as it stood {old:.4f}ms; rows by index "
+          f"{rows + ms['gate_up_rows']:.4f}ms, gathered "
+          f"{rows + ms['xs_gather'] + ms['gate_up']:.4f}ms; (M, d) rows and "
+          f"k gathers {old - ms['scatter_dispatch'] + ms['dispatch'] - ms['got_sum'] + ms['k_gathers']:.4f}ms; "
+          f"gap {gap:.3g} (k gathers {gap_alt:.3g}) of {top:.3g}", flush=True)
+    return ok
+
+
 def _paged_matrix(quick):
     results = []
     # the cells' widths (20 and 16 heads of 64), a head a quarter of a
@@ -667,6 +826,13 @@ def main():
                 (4096, 512, 26113, 3000, 0), (8192, 512, 26113, 8192, 0),
                 (8192, 512, 12385, 8000, 244)):
             results.append(check_pages_write(T, W, P, live, behind))
+        return _report(results)
+    if "--moe" in sys.argv:
+        # each expert cell's largest prefill bucket, then its decode step
+        for cell, shape in MOE_CELLS.items():
+            results.append(check_moe(cell, shape[6]))
+        for cell, shape in MOE_CELLS.items():
+            results.append(check_moe(cell, shape[7]))
         return _report(results)
     if "--mamba2" in sys.argv:
         # the granite cell's own shapes: a 64-row decode step, prompts
