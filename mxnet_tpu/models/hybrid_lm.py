@@ -8,7 +8,12 @@ MIXER and its own FFN:
   (``heads`` query heads over ``kv_heads`` KV heads of ``head_dim``;
   ``scale``: the scores' multiplier where it is not ``head_dim^-1/2``),
   an optional element-wise sigmoid output gate before the output
-  projection; ``rope_theta``: q and k rotated by their positions
+  projection (``gate``; it reads the block's normalised input);
+  ``qk_norm``: an RMSNorm over each head's ``head_dim`` lanes of q and
+  of k — one gain of ``head_dim`` each a layer, shared by the heads
+  (nodes ``layer{i}_q_norm`` / ``_k_norm``) — BEFORE the rotation, so K
+  goes into the pages normalised and rotated; ``rope_theta``: q and k
+  rotated by their positions
   (rotate-half over the whole head; absent = no positions at all);
   ``window``: a query sees its own key and the ``window - 1`` before it
   (absent = every key).  Its per-stream state is K/V PAGES; a windowed
@@ -67,9 +72,15 @@ alone, (B, 1, vocab): the engine samples one token of it).  Four multipliers and
 the spec too: ``embed_scale`` (on the token rows), ``residual_scale``
 (on every block's output before it is added), ``logits_scale`` (on the
 last norm's output before the head) and ``tied_head`` (the head is the
-token table).  The equations are in ``benchmark/reference/
-solar_open2.py``, ``granitemoehybrid.py``, ``smallthinker.py`` and
-``deepseek_v3.py``, the plain references this family is held to.
+token table); so is ``post_norm`` (sandwich norms: a branch's OUTPUT,
+the mixer's and the FFN's alike, goes through an RMSNorm of its own —
+nodes ``layer{i}_post_norm1`` / ``_post_norm2`` — before
+``residual_scale`` and the add).  A key that is absent builds the
+symbol it built before the key existed; a key no kind knows is refused
+by name.  The equations are in ``benchmark/reference/
+solar_open2.py``, ``granitemoehybrid.py``, ``smallthinker.py``,
+``deepseek_v3.py`` and ``afmoe.py``, the plain references this family
+is held to.
 """
 
 from .. import symbol as sym
@@ -80,7 +91,7 @@ from ..ops.hybrid import EXPERT_ACTS, mla_scale
 # that would silently build a model without the mechanism it names
 MIXERS = {
     "attention": ("kind", "heads", "kv_heads", "head_dim", "scale", "gate",
-                  "rope_theta", "window"),
+                  "rope_theta", "window", "qk_norm"),
     "mla": ("kind", "heads", "q_rank", "kv_rank", "nope_dim", "rope_dim",
             "v_dim", "rope_theta", "rope_scaling", "scale"),
     "kda": ("kind", "heads", "head_dim", "conv", "neg_eigval"),
@@ -104,9 +115,9 @@ def _fc(x, width, name):
                               weight=sym.Variable(f"{name}_weight"))
 
 
-def _norm(x, name, eps):
+def _norm(x, name, eps, **attrs):
     return sym.RMSNorm(x, sym.Variable(f"{name}_gamma"), eps=eps,
-                       name=name)
+                       name=name, **attrs)
 
 
 def _gated_ffn(h, width, d_model, name):
@@ -131,12 +142,14 @@ class HybridSpec:
     """The model ``DecodeEngine`` is given: sizes and the layer list.
 
     ``layers``: one dict a layer, ``{"mixer": {"kind": ...}, "ffn":
-    {"kind": ...}}`` with the keys the module doc names.  Plain data: a
-    spec round-trips through JSON (:meth:`to_dict`)."""
+    {"kind": ...}}`` with the keys the module doc names (an attention
+    mixer's ``qk_norm`` among them); ``post_norm``: every branch's
+    output is normalised before it is added.  Plain data: a spec
+    round-trips through JSON (:meth:`to_dict`)."""
 
     def __init__(self, vocab_size, d_model, layers, norm_eps=1e-5,
                  embed_scale=1.0, residual_scale=1.0, logits_scale=1.0,
-                 tied_head=False):
+                 tied_head=False, post_norm=False):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.norm_eps = float(norm_eps)
@@ -144,6 +157,7 @@ class HybridSpec:
         self.residual_scale = float(residual_scale)
         self.logits_scale = float(logits_scale)
         self.tied_head = bool(tied_head)
+        self.post_norm = bool(post_norm)
         self.layers = [dict(mixer=dict(ly["mixer"]), ffn=dict(ly["ffn"]))
                        for ly in layers]
         for i, ly in enumerate(self.layers):
@@ -337,7 +351,7 @@ class HybridSpec:
         return _trunk(self, step=(which == "decode"))
 
     _SCALARS = ("norm_eps", "embed_scale", "residual_scale",
-                "logits_scale", "tied_head")
+                "logits_scale", "tied_head", "post_norm")
 
     def to_dict(self):
         return {"vocab_size": self.vocab_size, "d_model": self.d_model,
@@ -357,6 +371,11 @@ def _attention(spec, h, i, m, step, feeds):
     q = _fc(h, H * D, f"{name}_q")
     k = _fc(h, Hkv * D, f"{name}_k")
     v = _fc(h, Hkv * D, f"{name}_v")
+    if m.get("qk_norm"):
+        # each head's lanes, one gain for all heads; the op rotates
+        # what it is given, so the norm comes first
+        q = _norm(q, f"{name}_q_norm", spec.norm_eps, num_groups=H)
+        k = _norm(k, f"{name}_k_norm", spec.norm_eps, num_groups=Hkv)
     op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
     attrs = {"scale": float(m["scale"])} if m.get("scale") else {}
     args = [q, k, v, sym.Variable(f"{name}_kpool"),
@@ -525,6 +544,11 @@ def _trunk(spec, step):
     def scaled(t, by):       # a multiplier of 1 adds no node
         return t if by == 1.0 else t * by
 
+    def branch(out, name):   # what a block adds to the residual stream
+        if spec.post_norm:
+            out = _norm(out, name, spec.norm_eps)
+        return scaled(out, spec.residual_scale)
+
     table = sym.Variable("tok_embed_weight")
     x = scaled(sym.Embedding(feeds["data"], input_dim=spec.vocab_size,
                              output_dim=spec.d_model, name="tok_embed",
@@ -537,11 +561,11 @@ def _trunk(spec, step):
         out, st = _MIXER_BUILDERS[ly["mixer"]["kind"]](
             spec, h, i, ly["mixer"], step, feeds)
         state += st
-        x = x + scaled(out, spec.residual_scale)
+        x = x + branch(out, f"layer{i}_post_norm1")
         h = _norm(x, f"layer{i}_norm2", spec.norm_eps)
         out, counters = _ffn(spec, h, x_in, i, ly["ffn"], step, feeds,
                              counters)
-        x = x + scaled(out, spec.residual_scale)
+        x = x + branch(out, f"layer{i}_post_norm2")
     if not step:
         x = sym.expand_dims(sym.SequenceLast(
             sym.SwapAxis(x, dim1=0, dim2=1), feeds["lengths"],

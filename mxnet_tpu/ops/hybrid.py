@@ -201,8 +201,7 @@ def _gqa_rotated(attrs, inputs, H, Hkv):
 def _gqa_prefill(op_ctx, attrs, inputs, aux):
     from . import pallas_kernels as pk
     from .attention import (_blockwise_attention_partial_lax,
-                            blockwise_attention, normalize_attention_state,
-                            paged_prefill_write)
+                            normalize_attention_state, paged_prefill_write)
 
     q, k, v, k_pool, v_pool, table, lengths = inputs[:7]
     H, Hkv = _gqa_heads(attrs, q, k)
@@ -211,7 +210,9 @@ def _gqa_prefill(op_ctx, attrs, inputs, aux):
     window = attr_int(attrs.get("window", 0), 0)
     B, T, HD = q.shape
     D = HD // H
-    if window and pk.enabled():
+    if pk.enabled():
+        # windowed or not, K and V go in at their own head count: the
+        # kernel's index map gives a KV head to its query heads
         def heads_first(x, n):
             return x.reshape(B, T, n, D).transpose(0, 2, 1, 3) \
                 .reshape(B * n, T, D)
@@ -219,16 +220,12 @@ def _gqa_prefill(op_ctx, attrs, inputs, aux):
         out = pk.flash_mha_window(
             heads_first(q, H), heads_first(k, Hkv), heads_first(v, Hkv),
             window, H, Hkv).reshape(B, H, T, D).transpose(0, 2, 1, 3)
-    elif window:
-        q4 = q.reshape(B, T, H, D)
+    else:       # the lax body, the window (0: none) a mask of its scan
         out = normalize_attention_state(
             *_blockwise_attention_partial_lax(
-                q4, _repeat_heads(k, H, Hkv), _repeat_heads(v, H, Hkv),
-                True, 512, 0, window=window), q.dtype)
-    else:
-        out = blockwise_attention(
-            q.reshape(B, T, H, D), _repeat_heads(k, H, Hkv),
-            _repeat_heads(v, H, Hkv), causal=True)
+                q.reshape(B, T, H, D), _repeat_heads(k, H, Hkv),
+                _repeat_heads(v, H, Hkv), True, 512, 0, window=window),
+            q.dtype)
     pools = paged_prefill_write(k, v, k_pool, v_pool,
                                 table.astype(jnp.int32),
                                 lengths.astype(jnp.int32))

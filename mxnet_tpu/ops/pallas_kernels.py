@@ -941,14 +941,21 @@ def _mha_window_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
     """Causal attention under a sliding window: q (B·H, T, D); k, v
     (B·Hkv, T, D), query head h on KV head ``h // (H / Hkv)``; query i
-    sees keys ``i - window + 1 .. i`` -> (B·H, T, D) in q.dtype."""
+    sees keys ``i - window + 1 .. i`` -> (B·H, T, D) in q.dtype.
+
+    ``window`` 0: every key up to the query — a grouped-query model's
+    GLOBAL layers, by the same walk with a band as wide as the prompt
+    (a tile above the diagonal neither fetched nor computed, K and V
+    read once a KV head's query heads through the index map, no
+    ``lse`` written: serving reads none), under the name the
+    normalized forward has, ``flash_fwd_mha``."""
     BH, T, D = q.shape
     window = int(window)
     group = int(heads) // int(kv_heads)
-    if window < 1 or int(heads) % int(kv_heads) \
+    if window < 0 or int(heads) % int(kv_heads) \
             or k.shape[0] * group != BH:
         raise MXNetError(
-            f"flash_mha_window: window {window} must be >= 1 and q "
+            f"flash_mha_window: window {window} must be >= 0 and q "
             f"{tuple(q.shape)} hold {heads} query heads over the "
             f"{kv_heads} KV heads of k {tuple(k.shape)}")
     bq, bk = _mha_blocks(0, T, T)       # the tiles flash_mha picks itself
@@ -956,11 +963,12 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
     kf = _pad_to(k, 1, bk)
     vf = _pad_to(v, 1, bk)
     nq, nk = qf.shape[1] // bq, kf.shape[1] // bk
-    # the tiles the widest band touches: window + block_q - 1 keys
-    steps = min(nk, (window + bq - 2) // bk + 2)
+    band = window or kf.shape[1]        # 0: as wide as the prompt
+    # the tiles the widest band touches: band + block_q - 1 keys
+    steps = min(nk, (band + bq - 2) // bk + 2)
 
     def kv_map(bh, qi, step):
-        kj = _window_first_tile(qi, bq, bk, window) + step
+        kj = _window_first_tile(qi, bq, bk, band) + step
         last = jnp.minimum(nk - 1, (qi * bq + bq - 1) // bk)
         # past the band the index stands still: no tile is fetched
         return ((bh // heads) * kv_heads + (bh % heads) // group,
@@ -968,7 +976,7 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
 
     kern = functools.partial(
         _mha_window_kernel, block_q=bq, block_k=bk, tk_valid=T,
-        scale=1.0 / float(D) ** 0.5, nk=nk, window=window)
+        scale=1.0 / float(D) ** 0.5, nk=nk, window=band)
     o = pl.pallas_call(
         kern,
         grid=(BH, nq, steps),
@@ -986,7 +994,7 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
             "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
-        name="flash_fwd_window",
+        name="flash_fwd_window" if window else "flash_fwd_mha",
     )(qf, kf, vf)
     return o[:, :T]
 
@@ -1699,7 +1707,14 @@ def _paged_pages_per_chunk(w, heads, kv_heads, d, kvb, table_pages, q_bytes,
     each and a lane tile wide.  K is the most pages up to
     ``_PAGED_CHUNK_KEYS`` keys (and the row's table) that keeps the sum
     inside ``_PAGED_VMEM_BUDGET``, halved until it does; a kernel that
-    is over at K = 1 is the caller's to refuse."""
+    is over at K = 1 is the caller's to refuse.
+
+    The widest the benchmark runs (longdoc: 48 query heads over 8 KV
+    heads of 128, 24 rows over 2,080-page tables): HP 48, K 16, 2.74 MB
+    of VMEM — 0.49 MB of spread query, accumulator and product, 2.10 MB
+    of page buffers, 0.15 MB of scores — and the whole (24, 2080) int32
+    table as ONE scalar-prefetch operand, 200 KB of SMEM, which the
+    chip's compiler takes (``tests/test_tpu_compile.py``)."""
     hp = -(-heads // 16) * 16
     rows, lanes = w * hp, kv_heads * d
     fixed = rows * lanes * (q_bytes + 4 + 4 + 4 * quant)

@@ -1085,7 +1085,11 @@ class DecodeEngine:
     ``run_ahead_drain_reasons``, ``overshoot_row_steps``,
     ``prefill_first_deferred``; ``d2h_syncs`` counts fetches (one a
     program), ``d2h_syncs_saved`` those made with a newer program
-    already queued.
+    already queued; ``prefill_bucket_tokens`` sums the ROWS of the
+    prefill programs that ran (a prompt runs the program of its bucket)
+    beside ``prefill_tokens``, the rows that held a token, and
+    ``prefill_bucket_fill`` is their ratio: what the bucket ladder's
+    padding leaves of a prefill's work.
 
     Decode numerics: prefill + N decode steps is bit-identical (lax
     path) to the full-sequence causal forward of
@@ -1149,7 +1153,11 @@ class DecodeEngine:
           required).
 
         A feature the spec cannot carry is refused at construction, by
-        name; its catalog default is taken only where it can.
+        name; its catalog default is taken only where it can.  What
+        changes a spec's symbols alone is nothing the engine asks: a
+        ``models.hybrid_lm.HybridSpec``'s ``post_norm`` and an
+        attention layer's ``qk_norm`` serve through the same pools,
+        feeds and programs as the specs without them.
     max_len : int, optional
         Longest prompt+generation a stream may reach.  Default: the
         learned positions' row count.
@@ -2118,9 +2126,16 @@ class DecodeEngine:
                 "prefill_chunks", "spec_steps", "spec_proposed",
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
                 "d2h_syncs_saved", "context_tokens", "prefill_pairs",
-                "steps_run_ahead",
+                "prefill_bucket_tokens", "steps_run_ahead",
                 "run_ahead_drains", "overshoot_row_steps",
                 "prefill_first_deferred")}
+        # the share of the prefill programs' rows that held a token:
+        # what the ladder's padding leaves of them
+        out["prefill_bucket_fill"] = round(
+            out["prefill_tokens"] / out["prefill_bucket_tokens"], 4) \
+            if out["prefill_bucket_tokens"] else 0.0
+        profiler.set_gauge("serving.prefill_bucket_fill",
+                           out["prefill_bucket_fill"])
         # how the loop ran: the share of decode programs dispatched
         # while an earlier program's tokens were still unread, and why
         # it fetched everything before going on, when it did
@@ -2983,6 +2998,7 @@ class DecodeEngine:
                 self._pools, *self._runtime_args([s], 1, mb))
         s.cost.flops_est += self._exe_flops.get(
             ("prefix_prefill", tp, mb), 0.0)
+        self._count("prefill_bucket_tokens", tp)
         return toks, tp
 
     def _prompt_feeds(self, s: _Stream, seq: np.ndarray, done: int,
@@ -3065,6 +3081,9 @@ class DecodeEngine:
                         self._pools, *self._runtime_args([s], 1, mb))
             s.cost.flops_est += self._exe_flops.get(("prefill", tp),
                                                     0.0)
+            # the rows the program ran, beside the rows that were real
+            # (prefill_tokens): the bucket ladder's padding is work
+            self._count("prefill_bucket_tokens", tp)
         s.blocks = pages
         s.length = n
         self._launch_prefill(s, toks, n, ns, c, tp, t_pre0)

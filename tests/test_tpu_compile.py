@@ -371,6 +371,53 @@ def test_flash_mha_window_at_the_mixed_cells_shapes(on_chip, one_chip, T):
     assert "flash_fwd_mha" not in kernels[0]
 
 
+# The longdoc cell (trinity-large-ep16): 24 rows over 2,080-page tables
+# (33,280 keys), 48 query heads — whole sublane tiles — over 8 KV heads
+# of 128 (page rows of 1,024 lanes), a window of 4,096 keys; prompts of
+# up to 32,768 rows
+_LONGDOC = (24, 2080, 48, 8, 128, 4096)
+
+
+@pytest.mark.parametrize("window", [0, _LONGDOC[-1]])
+def test_paged_attention_at_the_longdoc_cells_shapes(on_chip, one_chip,
+                                                     window):
+    B, MB, Hq, Hkv, D, W = _LONGDOC
+    # what the chunk is derived from (``_paged_pages_per_chunk``): 48
+    # rows x 1,024 lanes held three times, 256 keys a chunk in two
+    # buffers each of K and V; the whole table (24 x 2,080 int32 =
+    # 199,680 B) is one SMEM operand
+    hp, pages, vmem = pk._paged_pages_per_chunk(1, Hq, Hkv, D, _KVB, MB, 2, 2)
+    assert (hp, pages, vmem) == (48, 16, 2736128)
+    n_pages = 1 + B * (W // _KVB + 2 if window else MB)
+    pool = ((n_pages, _KVB, Hkv * D), bf16)
+    text = _compile(lambda q, kp, vp, t, s: pk._paged_attention(
+        q, kp, vp, (), t, s, Hq, kv_heads=Hkv, window=window), one_chip,
+        ((B, 1, Hq * D), bf16), pool, pool, ((B, MB), i32),
+        ((B,), i32)).as_text()
+    kernels = _kernel_names(text)
+    assert len(kernels) == 1 and ("paged_window" in kernels[0]) == bool(
+        window) and ("paged_attention" in kernels[0]) != bool(window)
+
+
+@pytest.mark.parametrize("T, window", [(32768, _LONGDOC[-1]), (32768, 0),
+                                       (4096, _LONGDOC[-1]), (4096, 0)])
+def test_flash_kernels_at_the_longdoc_cells_shapes(on_chip, one_chip, T,
+                                                   window):
+    """The windowed layers' band and — ``window`` 0 — the global layer's
+    every key, K and V at their 8 heads (no copy of them at 48)."""
+    _, _, Hq, Hkv, D, _ = _LONGDOC
+    compiled = _compile(lambda q, k, v: pk.flash_mha_window(
+        q, k, v, window, Hq, Hkv), one_chip, ((Hq, T, D), bf16),
+        ((Hkv, T, D), bf16), ((Hkv, T, D), bf16))
+    kernels = _kernel_names(compiled.as_text())
+    want, other = ("flash_fwd_window", "flash_fwd_mha") if window \
+        else ("flash_fwd_mha", "flash_fwd_window")
+    assert len(kernels) == 1 and want in kernels[0] \
+        and other not in kernels[0]
+    # nothing beside q, k, v and the output: no repeat, no lse
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 def test_paged_attention_same_kernel_at_equal_heads(on_chip):
     """The guard on shared code: grouped queries are a parameter of the
     one kernel, not a second one — asked for with as many KV heads as

@@ -19,6 +19,10 @@ after ANY kernel change:
     python tools/verify_kernels.py --mamba2 # the Mamba-2 kernels alone
     python tools/verify_kernels.py --window # the sliding-window kernels
                                             # (prefill band, decode walk)
+    python tools/verify_kernels.py --longdoc # the four attention kernels
+                                            # at the longdoc cell's shapes:
+                                            # 48 / 8 heads x 128, T = 32,768,
+                                            # a 2,080-page table
     python tools/verify_kernels.py --packed # the packed flash kernels alone
     python tools/verify_kernels.py --tiles  # the packed kernels' tile
                                             # schedules at the cells'
@@ -221,7 +225,8 @@ def check_mha(T, block, causal, B=2, H=8, D=128):
     return ok
 
 
-def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None, window=0):
+def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None, window=0,
+                rows=None):
     """The paged kernel over (P, KVB, H·D) pools (``kv_cache.
     value_pool_shape``) against a lax gather of the pages and the
     fallbacks' blockwise body: W = 1 is the decode step, W > 1 a
@@ -232,7 +237,9 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None, window=0):
     grouped queries, that many query heads over the H KV heads.
     ``window`` (W = 1): a sliding window of that many keys; the table
     then names the scratch page for every page wholly behind a row's
-    window, as the engine's does once it has given them back."""
+    window, as the engine's does once it has given them back.  ``rows``:
+    the streams the lax body is computed for (all of them where None;
+    a few where the gathered and repeated K/V of all would not fit)."""
     Hq = Hq or H
     from mxnet_tpu.kv_cache import value_pool_shape
     from mxnet_tpu.ops import attention as att
@@ -287,39 +294,48 @@ def check_paged(H, D, W, kv_dtype, B=8, MB=64, KVB=16, Hq=None, window=0):
 
     def gather_and_attend(q, t, s, *p):
         # the gathered ROWS take the head dim, never a pool
-        kg, vg = (x[t].reshape(B, MB * KVB, H, D) for x in p[:2])
+        n = q.shape[0]
+        kg, vg = (x[t].reshape(n, MB * KVB, H, D) for x in p[:2])
         if scales:
-            kg = att.dequantize_kv(kg, p[2][t].reshape(B, MB * KVB, H))
-            vg = att.dequantize_kv(vg, p[3][t].reshape(B, MB * KVB, H))
+            kg = att.dequantize_kv(kg, p[2][t].reshape(n, MB * KVB, H))
+            vg = att.dequantize_kv(vg, p[3][t].reshape(n, MB * KVB, H))
         # query head i reads KV head i // (Hq / H)
         kg, vg = (jnp.repeat(x, Hq // H, axis=2) for x in (kg, vg))
         o, m, l = att._blockwise_attention_partial_lax(
-            q.reshape(B, W, Hq, D), kg, vg, False, KVB, 0,
+            q.reshape(n, W, Hq, D), kg, vg, False, KVB, 0,
             lengths=s + 1, diagonal=not window, window=window)
         return att.normalize_attention_state(o, m, l, q.dtype).reshape(
-            B, W, Hq * D)
+            n, W, Hq * D)
 
-    want = jax.jit(gather_and_attend)(q, table, start, *pools)
+    at = np.arange(B) if rows is None else np.asarray(rows)
+    want = jax.jit(gather_and_attend)(q[at], table[at], start[at], *pools)
     got, want = (np.asarray(x.astype(jnp.float32)) for x in (got, want))
-    live = np.asarray(start) >= 0
+    idle = got[np.asarray(start) < 0]
+    got, live = got[at], np.asarray(start)[at] >= 0
     err = float(np.abs(got[live] - want[live]).max()
                 / max(np.abs(want[live]).max(), 1e-9))
     ok = err < TOL and bool(np.isfinite(got).all()) \
-        and not np.abs(got[~live]).any()
+        and not np.abs(idle).any()
     ms = _kernel_ms(lambda x: kernel(x, table, start, *pools), q) \
-        if window else {}
+        if window or rows is not None else {}
     print(f"{'OK ' if ok else 'FAIL'} paged  H={Hq}/{H} D={D} W={W} "
           f"B={B} MB={MB} chunk={chunk} "
           f"pools={kv_dtype}{' +scales' if scales else ''}"
-          f"{f' window={window}' if window else ''}: fwd={err:.4f}"
+          f"{f' window={window}' if window else ''}"
+          f"{f' rows={[int(r) for r in at]}' if rows is not None else ''}: "
+          f"fwd={err:.4f}"
           + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
     return ok
 
 
 def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
     """The windowed prefill kernel (one prompt, ``Hq`` query heads over
-    ``Hkv`` KV heads) against the lax body under the same band, with
-    the kernel's ms a call beside the causal kernel's at the shape."""
+    ``Hkv`` KV heads) against the lax body under the same band — and
+    the global layers' kernel (``window`` 0 of the same call: every key
+    up to the query, ``flash_fwd_mha``) against the causal lax body —
+    with both kernels' ms a call.  The lax body goes a KV head's query
+    heads at a time: the scores of all of them would not fit at
+    T = 32,768."""
     from mxnet_tpu.ops import attention as att
     from mxnet_tpu.ops import pallas_kernels as pk
 
@@ -332,25 +348,37 @@ def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
 
     kern = jax.jit(lambda q, k, v: pk.flash_mha_window(
         heads_first(q), heads_first(k), heads_first(v), window, Hq, Hkv))
-    full = jax.jit(lambda q, k, v: pk.flash_mha(
-        heads_first(q), jnp.repeat(heads_first(k), Hq // Hkv, 0),
-        jnp.repeat(heads_first(v), Hq // Hkv, 0), causal=True,
-        block_size=0))
+    full = jax.jit(lambda q, k, v: pk.flash_mha_window(
+        heads_first(q), heads_first(k), heads_first(v), 0, Hq, Hkv))
+    G = Hq // Hkv
 
-    def lax_body(q, k, v):
-        o, m, l = att._blockwise_attention_partial_lax(
-            q, jnp.repeat(k, Hq // Hkv, 2), jnp.repeat(v, Hq // Hkv, 2),
-            True, 512, 0, window=window)
-        return att.normalize_attention_state(o, m, l, q.dtype)
+    def lax_body(q, k, v, window):
+        def group(xs):                  # a KV head and its G query heads
+            qg, kg, vg = xs             # (T, G, D), (T, D), (T, D)
+            o, m, l = att._blockwise_attention_partial_lax(
+                qg[None], jnp.repeat(kg[None, :, None], G, 2),
+                jnp.repeat(vg[None, :, None], G, 2), True, 512, 0,
+                window=window)
+            return att.normalize_attention_state(o, m, l, q.dtype)[0]
 
-    got = np.asarray(kern(q, k, v).astype(jnp.float32)).transpose(1, 0, 2)
-    want = np.asarray(jax.jit(lax_body)(q, k, v).astype(jnp.float32))[0]
-    err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
-    ok = err < TOL and bool(np.isfinite(got).all())
-    ms = _kernel_ms(lambda x: kern(x, k, v), q)
-    ms.update(_kernel_ms(lambda x: full(x, k, v), q))
+        out = jax.lax.map(group, (
+            q[0].reshape(T, Hkv, G, D).transpose(1, 0, 2, 3),
+            k[0].transpose(1, 0, 2), v[0].transpose(1, 0, 2)))
+        return out.transpose(1, 0, 2, 3).reshape(T, Hq, D)
+
+    errs, ok = [], True
+    for fn, w in ((kern, window), (full, 0)):
+        got = np.asarray(fn(q, k, v).astype(jnp.float32)).transpose(1, 0, 2)
+        want = np.asarray(jax.jit(lax_body, static_argnums=3)(
+            q, k, v, w).astype(jnp.float32))
+        errs.append(float(np.abs(got - want).max()
+                          / max(np.abs(want).max(), 1e-9)))
+        ok = ok and errs[-1] < TOL and bool(np.isfinite(got).all())
+    err = errs[0]
+    ms = _kernel_ms(lambda x: kern(x, k, v), q, n=3)
+    ms.update(_kernel_ms(lambda x: full(x, k, v), q, n=3))
     print(f"{'OK ' if ok else 'FAIL'} window T={T} window={window} "
-          f"H={Hq}/{Hkv} D={D}: fwd={err:.4f}"
+          f"H={Hq}/{Hkv} D={D}: fwd={err:.4f} global={errs[1]:.4f}"
           + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
     return ok
 
@@ -851,6 +879,19 @@ def main():
             32, 544, [8704, 513, 0, 1, 4095, 16, 0, 7000]))
         for T in (1024, 2048, 4096, 8192, 1000):
             results.append(check_mla_flash(T))
+        return _report(results)
+    if "--longdoc" in sys.argv:
+        # the longdoc cell's shapes: 24 rows of 48 / 8 heads x 128 over
+        # 2,080-page tables (rows of every length up to 33,280 keys: the
+        # lax body on four of them), windowed and global; prompts in
+        # its four prefill buckets, windowed and global
+        rows = [0, 2, 12, 23]
+        results.append(check_paged(8, 128, 1, "bf16", B=24, MB=2080, Hq=48,
+                                   window=4096, rows=rows))
+        results.append(check_paged(8, 128, 1, "bf16", B=24, MB=2080, Hq=48,
+                                   rows=rows))
+        for T in (32768, 16384, 8192, 4096):
+            results.append(check_window_flash(T, 4096, Hq=48, Hkv=8))
         return _report(results)
     if "--window" in sys.argv:
         # the mixed cell's shapes: 48 rows of 28 / 4 heads x 128 over
