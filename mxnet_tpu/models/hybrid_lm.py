@@ -296,6 +296,17 @@ class HybridSpec:
                      "window_pages" if ly["mixer"].get("window") else "pages"
                      for ly in self.layers)
 
+    def prompt_attention(self):
+        """``(window, latent)`` a layer whose prefill attends the whole
+        prompt by a kernel that takes its length (``window`` 0: global;
+        ``latent``: an mla layer): what
+        ``pallas_kernels.prompt_tile_visits`` counts a prefill's tiles
+        by."""
+        return tuple((int(ly["mixer"].get("window") or 0),
+                      ly["mixer"]["kind"] == "mla")
+                     for ly in self.layers
+                     if ly["mixer"]["kind"] in ("attention", "mla"))
+
     def has_moe(self):
         return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
 

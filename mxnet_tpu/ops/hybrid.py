@@ -217,9 +217,12 @@ def _gqa_prefill(op_ctx, attrs, inputs, aux):
             return x.reshape(B, T, n, D).transpose(0, 2, 1, 3) \
                 .reshape(B * n, T, D)
 
+        # the walk stops at the prompt's last row: a query tile of the
+        # bucket's padding alone walks nothing and comes out zeros
         out = pk.flash_mha_window(
             heads_first(q, H), heads_first(k, Hkv), heads_first(v, Hkv),
-            window, H, Hkv).reshape(B, H, T, D).transpose(0, 2, 1, 3)
+            window, H, Hkv, lengths=lengths
+        ).reshape(B, H, T, D).transpose(0, 2, 1, 3)
     else:       # the lax body, the window (0: none) a mask of its scan
         out = normalize_attention_state(
             *_blockwise_attention_partial_lax(
@@ -424,7 +427,8 @@ def _mla_prefill(op_ctx, attrs, inputs, aux):
     q_r = _mla_rotate(attrs, q[..., H * n:], positions, H, r)
     k_r = _mla_rotate(attrs, k_r, positions, 1, r)
     if pk.mla_flash_enabled(H, n, r, dv):
-        out = pk.mla_flash(q, q_r, kv, k_r, H, n, dv, scale)
+        out = pk.mla_flash(q, q_r, kv, k_r, H, n, dv, scale,
+                           lengths=lengths)
     else:
         out = mla_causal(q[..., :H * n], q_r, kv[..., :H * n], k_r,
                          kv[..., H * n:], H, scale)

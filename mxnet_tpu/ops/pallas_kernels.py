@@ -887,37 +887,109 @@ def _mha_fwd(q, k, v, causal, block_size):
 # ---------------------------------------------------------------------------
 
 
-def _window_first_tile(qi, block_q, block_k, window):
-    """The key tile that holds the lowest key query tile ``qi`` sees."""
-    return jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+def _tile_of(row, block):
+    """``row // block`` of a row >= 0 on the scalar core, which has no
+    divider (PERF.md §6, PR 39): a shift where the block is a power of
+    two, as the tiles of every served bucket are."""
+    if block & (block - 1) == 0:
+        return jnp.right_shift(row, block.bit_length() - 1)
+    return jax.lax.div(row, jnp.int32(block))
 
 
-def _mha_window_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                       block_q, block_k, tk_valid, scale, nk, window):
-    qi = pl.program_id(1)
-    step = pl.program_id(2)
-    kj = _window_first_tile(qi, block_q, block_k, window) + step
-    last_kj = jnp.minimum(nk - 1, (qi * block_q + block_q - 1) // block_k)
+def _last_live_tile(length, block):
+    """The last query tile that holds a row of a prompt of ``length``
+    rows (tile 0 for an empty one): the tiles after it are DEAD — they
+    hold only the bucket's padding."""
+    return _tile_of(jnp.maximum(length - 1, 0), block)
 
-    @pl.when(step == 0)
+
+def _window_first_tile(qi, block, window, rows):
+    """The key tile that holds the lowest key query tile ``qi`` sees:
+    tile 0 under a band as wide as the ``rows`` there are."""
+    if window >= rows:
+        return 0
+    return _tile_of(jnp.maximum(qi * block - (window - 1), 0), block)
+
+
+def _walked_key_tile(qi, step, last_live, first, dead_step):
+    """The key tile whose blocks grid step (qi, step) of a prompt kernel
+    holds: a live query tile walks its band's key tiles from
+    ``first(qi)`` up to the diagonal's (key tile qi: the tiles are
+    square) and stands still there for the steps that are left; a dead
+    one stands on the last live tile's last blocks through all its
+    steps, so nothing is fetched for it.  ``dead_step``: any step count
+    past a band's width."""
+    row = jnp.minimum(qi, last_live)
+    kj = first(row) + jnp.where(qi > last_live, dead_step, step)
+    return jnp.minimum(kj, row)
+
+
+def _band_steps(rows, block, band):
+    """Grid steps a query tile of a prompt kernel: the key tiles the
+    widest band touches (``band + block - 1`` keys), all of them under
+    a band as wide as the ``rows``."""
+    return min(rows // block, (band + block - 2) // block + 2)
+
+
+def prompt_tile_visits(length, rows, window=0, latent=False):
+    """(walked, skipped) key-tile visits of one head of a prompt kernel
+    — ``flash_mha_window`` (``window`` 0: global), or ``mla_flash``
+    where ``latent`` — over a prompt of ``length`` rows in a bucket of
+    ``rows``: a live query tile walks its band's tiles up to the
+    diagonal's, a dead one (only padding) walks none, and what the dead
+    ones would have walked is ``skipped``.  Their sum is the bucket's
+    tiles, what a caller without ``lengths`` walks.  Host arithmetic
+    (the engine's ``prefill_tiles_*`` counters);
+    tests/test_prompt_lengths.py holds it to the interpreted kernels'
+    own steps."""
+    import numpy as np
+
+    blk = _mha_block(_MLA_BLOCK if latent else 0, rows)
+    nq = -(-rows // blk)
+    qi = np.arange(nq)
+    first = np.maximum(qi * blk - ((window or nq * blk) - 1), 0) // blk
+    walk = qi - first + 1
+    live = -(-min(max(int(length), 0), rows) // blk)
+    return int(walk[:live].sum()), int(walk[live:].sum())
+
+
+def _mha_window_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                       l_ref, *, block, scale, window, rows):
+    qi = pl.program_id(2)
+    step = pl.program_id(3)
+    length = len_ref[pl.program_id(0)]
+    live = qi * block < length
+    # a live tile's walk ends on the diagonal's tile, which (square
+    # tiles) is never past the tile that holds row length - 1
+    kj = _window_first_tile(qi, block, window, rows) + step
+
+    @pl.when(jnp.logical_not(live) & (step == 0))
+    def _dead():
+        # only the bucket's padding: zeros, never what the buffer held
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(live & (step == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(kj <= last_kj)
+    @pl.when(live & (kj <= qi))
     def _compute():
         q = q_ref[0]
         k = k_ref[0]
         v = v_ref[0]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        q_pos = qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0)
-        valid = (k_pos < tk_valid) & (k_pos <= q_pos) \
-            & (k_pos > q_pos - window)
+        k_pos = kj * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 1)
+        q_pos = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, block), 0)
+        # a key a row of the prompt sees is below the length (it is at
+        # or below the row): the rows past it are zeroed at the end
+        valid = k_pos <= q_pos
+        if window < rows:
+            valid &= k_pos > q_pos - window
         s = jnp.where(valid, s, -jnp.inf)
         m_prev = m_ref[:, 0]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
@@ -932,13 +1004,32 @@ def _mha_window_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
         m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
 
-    @pl.when(kj == last_kj)
+    whole = (qi + 1) * block <= length  # no row of the tile is padding
+
+    def out():
+        return acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+
+    @pl.when(whole & (kj == qi))
     def _finalize():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
-                    ).astype(o_ref.dtype)
+        o_ref[0] = out().astype(o_ref.dtype)
+
+    @pl.when(live & jnp.logical_not(whole) & (kj == qi))
+    def _finalize_last():
+        # the prompt ends inside this tile: its padding rows as zeros
+        row = qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, acc_ref.shape, 0)
+        o_ref[0] = jnp.where(row < length, out(), 0.0).astype(o_ref.dtype)
 
 
-def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
+def _prompt_lengths(lengths, batch, rows):
+    """(batch,) int32 prompt lengths within the bucket's ``rows``;
+    None: every row is the prompt's."""
+    if lengths is None:
+        return jnp.full((batch,), rows, jnp.int32)
+    return jnp.clip(lengths.astype(jnp.int32).reshape(batch), 0, rows)
+
+
+def flash_mha_window(q, k, v, window, heads=1, kv_heads=1, lengths=None):
     """Causal attention under a sliding window: q (B·H, T, D); k, v
     (B·Hkv, T, D), query head h on KV head ``h // (H / Hkv)``; query i
     sees keys ``i - window + 1 .. i`` -> (B·H, T, D) in q.dtype.
@@ -948,7 +1039,14 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
     (a tile above the diagonal neither fetched nor computed, K and V
     read once a KV head's query heads through the index map, no
     ``lse`` written: serving reads none), under the name the
-    normalized forward has, ``flash_fwd_mha``."""
+    normalized forward has, ``flash_fwd_mha``.
+
+    ``lengths`` (B,): the rows of each prompt in its bucket of T (None:
+    T), a scalar operand read at run time.  Rows below a length are
+    computed by the tiles, in the order, they are computed without it;
+    rows at and past it come out 0, and a query tile that holds only
+    such rows walks no key tile and fetches nothing
+    (:func:`prompt_tile_visits` counts both kinds)."""
     BH, T, D = q.shape
     window = int(window)
     group = int(heads) // int(kv_heads)
@@ -958,44 +1056,52 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1):
             f"flash_mha_window: window {window} must be >= 0 and q "
             f"{tuple(q.shape)} hold {heads} query heads over the "
             f"{kv_heads} KV heads of k {tuple(k.shape)}")
-    bq, bk = _mha_blocks(0, T, T)       # the tiles flash_mha picks itself
-    qf = _pad_to(q, 1, bq)
-    kf = _pad_to(k, 1, bk)
-    vf = _pad_to(v, 1, bk)
-    nq, nk = qf.shape[1] // bq, kf.shape[1] // bk
-    band = window or kf.shape[1]        # 0: as wide as the prompt
-    # the tiles the widest band touches: band + block_q - 1 keys
-    steps = min(nk, (band + bq - 2) // bk + 2)
+    blk = _mha_block(0, T)      # square tiles, as flash_mha picks them
+    qf, kf, vf = (_pad_to(x, 1, blk) for x in (q, k, v))
+    rows = qf.shape[1]
+    band = window or rows               # 0: as wide as the prompt
+    steps = _band_steps(rows, blk, band)
+    first = functools.partial(_window_first_tile, block=blk, window=band,
+                              rows=rows)
+    # one length a KV head, and the grid's first axis the KV heads: the
+    # scalar core then divides by nothing but the (power of two) tile
+    lens = jnp.repeat(_prompt_lengths(lengths, BH // int(heads), T),
+                      int(kv_heads))
 
-    def kv_map(bh, qi, step):
-        kj = _window_first_tile(qi, bq, bk, band) + step
-        last = jnp.minimum(nk - 1, (qi * bq + bq - 1) // bk)
-        # past the band the index stands still: no tile is fetched
-        return ((bh // heads) * kv_heads + (bh % heads) // group,
-                jnp.minimum(kj, last), 0)
+    def q_map(kvh, g, qi, step, len_ref):
+        return (kvh * group + g,
+                jnp.minimum(qi, _last_live_tile(len_ref[kvh], blk)), 0)
+
+    def kv_map(kvh, g, qi, step, len_ref):
+        return (kvh, _walked_key_tile(
+            qi, step, _last_live_tile(len_ref[kvh], blk), first, steps), 0)
 
     kern = functools.partial(
-        _mha_window_kernel, block_q=bq, block_k=bk, tk_valid=T,
-        scale=1.0 / float(D) ** 0.5, nk=nk, window=band)
+        _mha_window_kernel, block=blk, scale=1.0 / float(D) ** 0.5,
+        window=band, rows=rows)
     o = pl.pallas_call(
         kern,
-        grid=(BH, nq, steps),
-        in_specs=[
-            _vmem_spec((1, bq, D), lambda bh, qi, step: (bh, qi, 0)),
-            _vmem_spec((1, bk, D), kv_map),
-            _vmem_spec((1, bk, D), kv_map),
-        ],
-        out_specs=_vmem_spec((1, bq, D), lambda bh, qi, step: (bh, qi, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(BH // group, group, rows // blk, steps),
+            in_specs=[
+                _vmem_spec((1, blk, D), q_map),
+                _vmem_spec((1, blk, D), kv_map),
+                _vmem_spec((1, blk, D), kv_map),
+            ],
+            out_specs=_vmem_spec(
+                (1, blk, D),
+                lambda kvh, g, qi, step, len_ref: (kvh * group + g, qi, 0)),
+            scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32),
+                            pltpu.VMEM((blk, 128), jnp.float32),
+                            pltpu.VMEM((blk, 128), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32),
-                        pltpu.VMEM((bq, 128), jnp.float32)],
         compiler_params=_compiler_params(
-            "parallel", "parallel", "arbitrary",
+            "parallel", "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="flash_fwd_window" if window else "flash_fwd_mha",
-    )(qf, kf, vf)
+    )(lens, qf, kf, vf)
     return o[:, :T]
 
 
@@ -2230,14 +2336,20 @@ def mla_flash_enabled(heads, nope_dim, rope_dim, v_dim) -> bool:
         and _mla_heads_per_step(heads, rope_dim) > 0)
 
 
-def _mla_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
-                      m_ref, l_ref, *, hb, n, r, dv, block_q, block_k,
-                      t_valid, scale):
+def _mla_flash_kernel(len_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
+                      acc_ref, m_ref, l_ref, *, hb, n, r, dv, block, scale):
     qi = pl.program_id(2)
     kj = pl.program_id(3)
-    last_kj = (qi * block_q + block_q - 1) // block_k
+    length = len_ref[pl.program_id(0)]
+    live = qi * block < length      # its walk ends on the diagonal: key
+    # tile qi, never past the tile that holds row length - 1
 
-    @pl.when(kj == 0)
+    @pl.when(jnp.logical_not(live) & (kj == 0))
+    def _dead():
+        # only the bucket's padding: zeros, never what the buffer held
+        o_ref[0] = jnp.zeros_like(o_ref[0])
+
+    @pl.when(live & (kj == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
@@ -2247,12 +2359,13 @@ def _mla_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
         # a tile wholly below the diagonal, without padded keys, needs
         # no mask: every key is seen, every row's maximum is finite
         if masked:
-            k_pos = kj * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            valid = (k_pos < t_valid) & (k_pos <= q_pos)
-        kr = kr_ref[0]                          # (block_k, r): every head's
+            k_pos = kj * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, block), 1)
+            q_pos = qi * block + jax.lax.broadcasted_iota(
+                jnp.int32, (block, block), 0)
+            valid = k_pos <= q_pos    # a key a prompt's row sees is below
+            # the length; the rows past it are zeroed at the end
+        kr = kr_ref[0]                          # (block, r): every head's
         cross = (((1,), (1,)), ((), ()))
         for i in range(hb):
             s = jax.lax.dot_general(
@@ -2286,20 +2399,33 @@ def _mla_flash_kernel(qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref, acc_ref,
             acc_ref[i] = acc_ref[i] * alpha + pv
             m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
 
-    below = (kj * block_k + block_k <= qi * block_q) \
-        & (kj * block_k + block_k <= t_valid)
+    # wholly below the diagonal of a live tile is wholly below the
+    # length too: no mask
+    below = live & (kj < qi)
     pl.when(below)(lambda: tile(False))
-    pl.when((kj <= last_kj) & jnp.logical_not(below))(lambda: tile(True))
+    pl.when(live & (kj <= qi) & jnp.logical_not(below))(lambda: tile(True))
 
-    @pl.when(kj == last_kj)
-    def _finalize():
+    whole = (qi + 1) * block <= length  # no row of the tile is padding
+
+    def finalize(keep):
         for i in range(hb):
+            out = acc_ref[i] / jnp.maximum(l_ref[i, :, :1], 1e-30)
             o_ref[0, :, i * dv:(i + 1) * dv] = (
-                acc_ref[i] / jnp.maximum(l_ref[i, :, :1], 1e-30)
+                out if keep is None else jnp.where(keep, out, 0.0)
             ).astype(o_ref.dtype)
 
+    @pl.when(whole & (kj == qi))
+    def _finalize():
+        finalize(None)
 
-def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale):
+    @pl.when(live & jnp.logical_not(whole) & (kj == qi))
+    def _finalize_last():
+        # the prompt ends inside this tile: its padding rows as zeros
+        finalize(qi * block + jax.lax.broadcasted_iota(
+            jnp.int32, (block, dv), 0) < length)
+
+
+def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale, lengths=None):
     """Causal attention of ``heads`` heads with qk width n + r and v
     width dv: q (B, T, H·n + ...), its first H·n lanes every head's
     q_n (what follows is not read: the projection's unrotated q_r);
@@ -2311,54 +2437,63 @@ def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale):
     is a lane span (k_n and v are two windows on ONE array, no slice is
     copied out), a grid step takes ``_mla_heads_per_step`` heads over
     one (query tile, key tile) and the key tiles above the diagonal are
-    neither fetched nor computed."""
+    neither fetched nor computed.  ``lengths`` (B,): each prompt's rows
+    in its bucket of T, as :func:`flash_mha_window` takes them — rows
+    at and past a length come out 0 and a query tile of them alone
+    walks nothing."""
     B, T, _ = q.shape
     H, n, dv = int(heads), int(nope_dim), int(v_dim)
     r = q_r.shape[2] // H
     hb = _mla_heads_per_step(H, r) or math.gcd(H, 4)
-    bq = bk = _mha_block(_MLA_BLOCK, T)
+    blk = _mha_block(_MLA_BLOCK, T)
     v_in = kv
     if (H * n) % (hb * dv):       # v's lanes start inside a block of it
         v_in = kv[..., H * n:]
     v_first = 0 if v_in is not kv else H * n // (hb * dv)
-    qf, qr, kf, kr, vf = (_pad_to(x, 1, bq) for x in (q, q_r, kv, k_r,
-                                                      v_in))
-    nq = nk = qf.shape[1] // bq
+    qf, qr, kf, kr, vf = (_pad_to(x, 1, blk) for x in (q, q_r, kv, k_r,
+                                                       v_in))
+    nq = qf.shape[1] // blk
 
-    def q_map(b, g, qi, kj):
-        return (b, qi, g)
+    def q_map(b, g, qi, kj, len_ref):
+        return (b, jnp.minimum(qi, _last_live_tile(len_ref[b], blk)), g)
 
-    def seen(qi, kj):
-        # above the diagonal the index stands still: no tile is fetched
-        return jnp.minimum(kj, (qi * bq + bq - 1) // bk)
+    def seen(b, qi, kj, len_ref):
+        # above the diagonal, and through a dead tile, the index stands
+        # still: no tile is fetched
+        return _walked_key_tile(qi, kj, _last_live_tile(len_ref[b], blk),
+                                lambda qi: 0, nq)
 
     kern = functools.partial(
-        _mla_flash_kernel, hb=hb, n=n, r=r, dv=dv, block_q=bq, block_k=bk,
-        t_valid=T, scale=float(scale))
+        _mla_flash_kernel, hb=hb, n=n, r=r, dv=dv, block=blk,
+        scale=float(scale))
     o = pl.pallas_call(
         kern,
-        grid=(B, H // hb, nq, nk),
-        in_specs=[
-            _vmem_spec((1, bq, hb * n), q_map),
-            _vmem_spec((1, bq, hb * r), q_map),
-            _vmem_spec((1, bk, hb * n),
-                       lambda b, g, qi, kj: (b, seen(qi, kj), g)),
-            _vmem_spec((1, bk, r),
-                       lambda b, g, qi, kj: (b, seen(qi, kj), 0)),
-            _vmem_spec((1, bk, hb * dv),
-                       lambda b, g, qi, kj: (b, seen(qi, kj), v_first + g)),
-        ],
-        out_specs=_vmem_spec((1, bq, hb * dv), q_map),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H // hb, nq, nq),
+            in_specs=[
+                _vmem_spec((1, blk, hb * n), q_map),
+                _vmem_spec((1, blk, hb * r), q_map),
+                _vmem_spec((1, blk, hb * n), lambda b, g, qi, kj, len_ref:
+                           (b, seen(b, qi, kj, len_ref), g)),
+                _vmem_spec((1, blk, r), lambda b, g, qi, kj, len_ref:
+                           (b, seen(b, qi, kj, len_ref), 0)),
+                _vmem_spec((1, blk, hb * dv), lambda b, g, qi, kj, len_ref:
+                           (b, seen(b, qi, kj, len_ref), v_first + g)),
+            ],
+            out_specs=_vmem_spec(
+                (1, blk, hb * dv),
+                lambda b, g, qi, kj, len_ref: (b, qi, g)),
+            scratch_shapes=[pltpu.VMEM((hb, blk, dv), jnp.float32),
+                            pltpu.VMEM((hb, blk, 128), jnp.float32),
+                            pltpu.VMEM((hb, blk, 128), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((B, qf.shape[1], H * dv), kv.dtype),
-        scratch_shapes=[pltpu.VMEM((hb, bq, dv), jnp.float32),
-                        pltpu.VMEM((hb, bq, 128), jnp.float32),
-                        pltpu.VMEM((hb, bq, 128), jnp.float32)],
         compiler_params=_compiler_params(
             "parallel", "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="mla_flash_fwd",
-    )(qf, qr, kf, kr, vf)
+    )(_prompt_lengths(lengths, B, T), qf, qr, kf, kr, vf)
     return o[:, :T]
 
 

@@ -1089,7 +1089,13 @@ class DecodeEngine:
     prefill programs that ran (a prompt runs the program of its bucket)
     beside ``prefill_tokens``, the rows that held a token, and
     ``prefill_bucket_fill`` is their ratio: what the bucket ladder's
-    padding leaves of a prefill's work.
+    padding leaves of a prefill's work; ``prefill_tiles_walked`` /
+    ``prefill_tiles_skipped`` count, a layer and head, the key tiles the
+    whole-prompt attention kernels walked and the tiles of their buckets
+    they left out for knowing the prompt's length
+    (``pallas_kernels.prompt_tile_visits`` over the layers
+    ``prompt_attention()`` lists), ``prefill_tiles_skipped_share`` the
+    share of the buckets' tiles that was left out.
 
     Decode numerics: prefill + N decode steps is bit-identical (lax
     path) to the full-sequence causal forward of
@@ -1150,7 +1156,10 @@ class DecodeEngine:
           the ``transformer_lm`` block and reads ``num_heads``, ``d_ff``);
           ``positions``: the parameter whose rows are the learned
           positions and bound ``max_len``, or None (``max_len``
-          required).
+          required);
+        * optionally ``prompt_attention()``: ``(window, latent)`` a
+          layer whose prefill kernel takes the prompt's length, for the
+          ``prefill_tiles_*`` counters (absent: none counted).
 
         A feature the spec cannot carry is refused at construction, by
         name; its catalog default is taken only where it can.  What
@@ -1655,6 +1664,10 @@ class DecodeEngine:
                 "cache_bytes_needed_per_token": need * item}
             for k, v in self._latent_bytes.items():
                 profiler.set_gauge(f"mla.{k}", v)
+        # the layers whose prompt kernels stop at the prompt's last row,
+        # how many of each (window, latent); none for a family without
+        self._prompt_layers = collections.Counter(
+            getattr(model, "prompt_attention", tuple)())
         self._cow_fn = None  # lazily-jitted copy-on-write page copy
 
         if donate is None:
@@ -2126,7 +2139,8 @@ class DecodeEngine:
                 "prefill_chunks", "spec_steps", "spec_proposed",
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
                 "d2h_syncs_saved", "context_tokens", "prefill_pairs",
-                "prefill_bucket_tokens", "steps_run_ahead",
+                "prefill_bucket_tokens", "prefill_tiles_walked",
+                "prefill_tiles_skipped", "steps_run_ahead",
                 "run_ahead_drains", "overshoot_row_steps",
                 "prefill_first_deferred")}
         # the share of the prefill programs' rows that held a token:
@@ -2136,6 +2150,13 @@ class DecodeEngine:
             if out["prefill_bucket_tokens"] else 0.0
         profiler.set_gauge("serving.prefill_bucket_fill",
                            out["prefill_bucket_fill"])
+        # the share of the prompt kernels' bucket tiles they did not
+        # walk for knowing the prompt's length
+        tiles = out["prefill_tiles_walked"] + out["prefill_tiles_skipped"]
+        out["prefill_tiles_skipped_share"] = round(
+            out["prefill_tiles_skipped"] / tiles, 4) if tiles else 0.0
+        profiler.set_gauge("serving.prefill_tiles_skipped_share",
+                           out["prefill_tiles_skipped_share"])
         # how the loop ran: the share of decode programs dispatched
         # while an earlier program's tokens were still unread, and why
         # it fetched everything before going on, when it did
@@ -3084,6 +3105,7 @@ class DecodeEngine:
             # the rows the program ran, beside the rows that were real
             # (prefill_tokens): the bucket ladder's padding is work
             self._count("prefill_bucket_tokens", tp)
+            self._count_prompt_tiles(n, tp)
         s.blocks = pages
         s.length = n
         self._launch_prefill(s, toks, n, ns, c, tp, t_pre0)
@@ -4111,6 +4133,22 @@ class DecodeEngine:
         return self._feed_exe(bb)(
             host if prev is None else prev.toks, tuple(firsts), host,
             stage_array(index, self._device))
+
+    def _count_prompt_tiles(self, n: int, tp: int):
+        """The key tiles, a layer and head, that a whole prompt's
+        attention kernels walked over its ``n`` rows and left out of
+        its bucket of ``tp`` for knowing its length."""
+        from .ops import pallas_kernels as pk
+
+        if not (self._prompt_layers and pk.enabled()):
+            return
+        walked = skipped = 0
+        for (window, latent), layers in self._prompt_layers.items():
+            w, sk = pk.prompt_tile_visits(n, tp, window, latent)
+            walked += layers * w
+            skipped += layers * sk
+        self._count("prefill_tiles_walked", walked)
+        self._count("prefill_tiles_skipped", skipped)
 
     def _count_window_step(self, streams, lengths):
         """A decode step's need in the windowed layers (the context

@@ -406,6 +406,65 @@ def test_the_engine_serves_unequal_prompts_counts_its_buckets_and_gets_every_pag
     assert st["moe_pairs_here"] > 0 and st["moe_pairs_elsewhere"] > 0
 
 
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """The kernels interpreted, the prompt kernels in tiles of 32 rows:
+    a bucket of 96 is three query tiles."""
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: 32)
+
+
+@pytest.mark.parametrize("case", [0, 2], ids=["lower_half", "fills"])
+def test_prompt_kernels_given_the_length_leave_the_logits(served,
+                                                          small_tiles, case):
+    # the prompt's rows are the lax body's whether the bucket's other
+    # tiles are walked or not: prefill + six decode steps
+    drawn, runs = served
+    seq, n_prompt, lax_rows = runs[case]
+    got = Programs(drawn).serve(seq[:n_prompt + 6], n_prompt,
+                                bucket=CASES[case][2])
+    np.testing.assert_allclose(got, lax_rows[:7], atol=2e-4)
+
+
+@pytest.mark.parametrize("lengths", [(20,), (96,), (20, 96)],
+                         ids=["lower_half", "fills", "both"])
+def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
+        small_tiles, lengths):
+    eng, drawn = make_engine(prefill_buckets=(96,))
+    rng = np.random.default_rng(6)
+    ps = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
+    with eng:
+        outs = [f.result(timeout=600) for f in
+                [eng.submit(p, max_new_tokens=6) for p in ps]]
+        st = eng.stats()
+    for p, o in zip(ps, outs):
+        assert served_gap(drawn, p, o) < 1e-4
+    # four windowed layers (a band of 32 keys: two tiles a query tile
+    # past the first) and a global one (1 + 2 + 3), three query tiles
+    bucket = 4 * (1 + 2 + 2) + (1 + 2 + 3)
+    skipped = sum(bucket - 5 for n in lengths if n == 20)
+    assert (st["prefill_tiles_walked"], st["prefill_tiles_skipped"]) == (
+        len(lengths) * bucket - skipped, skipped)
+    assert st["prefill_tiles_skipped_share"] == round(
+        skipped / (len(lengths) * bucket), 4)
+    c = profiler.metrics_summary()
+    assert c["counters"]["serving.prefill_tiles_walked"] >= 5
+    assert c["gauges"]["serving.prefill_tiles_skipped_share"] == \
+        st["prefill_tiles_skipped_share"]
+
+
+def test_the_lax_bodies_count_no_prompt_tile():
+    eng, _ = make_engine()
+    with eng:
+        eng.submit(sequence(1, 20), max_new_tokens=2).result(timeout=600)
+        st = eng.stats()
+    assert st["prefill_tiles_walked"] == st["prefill_tiles_skipped"] == 0
+    assert st["prefill_tiles_skipped_share"] == 0.0
+    assert ref.spec(CFG).prompt_attention() == (
+        (W, False), (W, False), (0, False), (W, False), (W, False))
+
+
 def test_recompute_preemption_under_a_tight_pool_leaves_the_logits():
     # 13 ordinary pages for three streams that grow to 6 each: someone
     # is thrown out, gives back its pages of both pools, and comes back

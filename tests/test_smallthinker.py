@@ -212,6 +212,53 @@ def served_gap(drawn, prompt, out):
     return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
 
 
+def small_tiles(monkeypatch):
+    """From here on the kernels interpreted, the prompt kernels in tiles
+    of 32 rows: a bucket of 96 is three query tiles."""
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    from mxnet_tpu.ops import pallas_kernels as pk
+    monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: 32)
+
+
+@pytest.mark.parametrize("n_prompt", [20, 96], ids=["lower_half", "fills"])
+def test_prompt_kernels_given_the_length_leave_the_logits(monkeypatch,
+                                                          n_prompt):
+    # the prompt's rows are the lax body's whether the bucket's other
+    # tiles are walked or not: prefill + six decode steps
+    drawn, seq = draw(), sequence(31, n_prompt + 6)
+    lax_rows = Programs(drawn).serve(seq, n_prompt, bucket=96)
+    small_tiles(monkeypatch)
+    got = Programs(drawn).serve(seq, n_prompt, bucket=96)
+    np.testing.assert_allclose(got, lax_rows, atol=2e-4)
+
+
+# (the prompts alone: tests/test_afmoe.py, tests/test_deepseek_v3.py)
+@pytest.mark.parametrize("lengths", [(20, 96)], ids=["both"])
+def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
+        monkeypatch, lengths):
+    small_tiles(monkeypatch)
+    eng, drawn = make_engine(prefill_buckets=(96,))
+    rng = np.random.default_rng(6)
+    ps = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
+    with eng:
+        outs = [f.result(timeout=600) for f in
+                [eng.submit(p, max_new_tokens=6) for p in ps]]
+        st = eng.stats()
+    for p, o in zip(ps, outs):
+        assert served_gap(drawn, p, o) < 1e-4
+    # six windowed layers (a band of 32 keys: two tiles a query tile
+    # past the first) and two global ones (1 + 2 + 3), three query
+    # tiles; a prompt of 20 rows has one live tile a layer
+    bucket, live = 6 * (1 + 2 + 2) + 2 * (1 + 2 + 3), 8
+    assert ref.spec(CFG).prompt_attention() == tuple(
+        (0 if i % 4 == 0 else W, False) for i in range(8))
+    skipped = sum(bucket - live for n in lengths if n == 20)
+    assert (st["prefill_tiles_walked"], st["prefill_tiles_skipped"]) == (
+        len(lengths) * bucket - skipped, skipped)
+    assert st["prefill_tiles_skipped_share"] == round(
+        skipped / (len(lengths) * bucket), 4)
+
+
 def watch_window_pages(eng):
     """Record the most windowed pages any one owner held, and fail the
     moment a page is handed out while held."""

@@ -623,6 +623,24 @@ def test_mamba2_kernels_granite_cell(on_chip, one_chip, monkeypatch):
              ((1, T, H * P + 2 * N), bf16), ((1, T, H), f32))
 
 
+@pytest.mark.parametrize("cell, T, window", [
+    (_LONGDOC, 32768, 0), (_LONGDOC, 32768, _LONGDOC[-1]),
+    (_LONGDOC, 8192, 0), (_MIXED, 8192, 0), (_MIXED, 8192, _MIXED[-1])])
+def test_flash_kernels_take_the_prompts_length_at_the_cells_shapes(
+        on_chip, one_chip, cell, T, window):
+    """The prompt's length as a traced (1,) operand — the kernel's
+    scalar prefetch — at the longdoc and mixed cells' heads and buckets:
+    Mosaic takes the index maps that read it, the kernels keep the names
+    the accepted readers match, nothing is copied beside q, k, v."""
+    _, _, Hq, Hkv, D, _ = cell
+    compiled = _compile(lambda q, k, v, n: pk.flash_mha_window(
+        q, k, v, window, Hq, Hkv, lengths=n), one_chip, ((Hq, T, D), bf16),
+        ((Hkv, T, D), bf16), ((Hkv, T, D), bf16), ((1,), i32))
+    kernels = _kernel_short_names(compiled.as_text())
+    assert kernels == ["flash_fwd_window" if window else "flash_fwd_mha"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
 # the longctx cell (deepseek-v3-ep32): 32 rows, a table of 544 pages of
 # 16, 128 heads of (128 + 64 | 128) over a latent row of 512 + 64 values
 # held as 640 lanes
@@ -653,6 +671,18 @@ def test_mla_flash_at_the_longctx_cells_shapes(on_chip, one_chip, T):
     # a name no accepted reader's substring finds
     assert len(kernels) == 1 and "mla_flash_fwd" in kernels[0]
     assert "flash_fwd_mha" not in kernels[0]
+
+
+@pytest.mark.parametrize("T", [8192, 4096])
+def test_mla_flash_takes_the_prompts_length_at_the_longctx_cells_shapes(
+        on_chip, one_chip, T):
+    _, _, H, n, r, dv, _ = _LONGCTX
+    compiled = _compile(lambda q, qr, kv, kr, m: pk.mla_flash(
+        q, qr, kv, kr, H, n, dv, 0.13523, lengths=m), one_chip,
+        ((1, T, H * (n + r)), bf16), ((1, T, H * r), bf16),
+        ((1, T, H * (n + dv)), bf16), ((1, T, r), bf16), ((1,), i32))
+    assert _kernel_short_names(compiled.as_text()) == ["mla_flash_fwd"]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 def test_mla_paged_decode_at_the_longctx_cells_shapes(on_chip, one_chip):

@@ -38,6 +38,12 @@ after ANY kernel change:
                                             # (prefill, paged decode, page
                                             # write) at the longctx cell's
                                             # widths, ms a call each
+    python tools/verify_kernels.py --longdoc --window --mla --fill 1,0.6
+                                            # the prompt kernels alone at
+                                            # a prompt of F x T rows in
+                                            # each bucket of T (F = 1,
+                                            # then 0.6), given the length
+                                            # and not
 """
 
 import functools
@@ -415,6 +421,115 @@ def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
           f"qk={n}+{r} v={dv}: fwd={err:.4f} least={least:.3f}ms"
           + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
     return ok
+
+
+def _fill_report(what, T, n, runs, need_flops):
+    """One line a kernel of the ``--fill`` checks: ``runs`` = {name: (ms
+    given the length, without it, of a bucket cut to the live tiles —
+    what is left is the dead tiles' grid steps — and ok)};
+    ``need_flops`` = {name: the FLOP of the prompt's REAL pairs}, the
+    least time from it at the v5e's peak."""
+    ok = True
+    for name, (ms, ms_full, ms_live, good) in runs.items():
+        ok = ok and good
+        least = need_flops[name] / 197e12 * 1e3
+        print(f"{'OK ' if good else 'FAIL'} fill {what} {name} T={T} "
+              f"prompt={n} ({n / T:.3f}): lengths={ms:.3f}ms "
+              f"lengths=None {ms_full:.3f}ms live_tiles_alone="
+              f"{ms_live:.3f}ms least={least:.3f}ms "
+              f"roofline={100 * least / ms if ms else 0:.1f}%", flush=True)
+    return ok
+
+
+def _named_ms(fn, x, name):
+    """Device ms a call of the one kernel ``name`` that ``fn(x)`` runs."""
+    return _kernel_ms(fn, x, n=3).get(name, 0.0)
+
+
+def _live_rows(n, T, latent=False):
+    """The rows of a bucket of T that a prompt of n rows' live query
+    tiles hold: a bucket cut there walks what the kernel given the
+    length walks, WITHOUT the dead tiles' grid steps."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    blk = pk._mha_block(pk._MLA_BLOCK if latent else 0, T)
+    return min(T, -(-n // blk) * blk)
+
+
+def _band_pairs(n, window):
+    w = min(n, window) if window else n
+    return w * (w + 1) / 2 + (n - w) * w
+
+
+def check_fill_window(T, window, fill, Hq=28, Hkv=4, D=128):
+    """The prompt kernels of a grouped-query family alone at a prompt
+    of ``fill x T`` rows in a bucket of T: ms a call given the length,
+    the same call without it (the whole bucket walked: the parent's
+    walk), and the least time of the prompt's real pairs; rows below
+    the length must EQUAL the other call's, rows past it be 0."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    n = max(1, min(T, int(round(fill * T))))
+    rng = np.random.RandomState(T + window)
+    q, k, v = (jnp.asarray(rng.randn(n_, T, D).astype(np.float32) * 0.5)
+               .astype(jnp.bfloat16) for n_ in (Hq, Hkv, Hkv))
+    lens = jnp.asarray([n], jnp.int32)
+    runs, need = {}, {}
+    for w in (window, 0):
+        cut = jax.jit(lambda q, k, v, lens, w=w: pk.flash_mha_window(
+            q, k, v, w, Hq, Hkv, lengths=lens))
+        whole = jax.jit(lambda q, k, v, w=w: pk.flash_mha_window(
+            q, k, v, w, Hq, Hkv))
+        got, want = np.asarray(cut(q, k, v, lens).astype(jnp.float32)), \
+            np.asarray(whole(q, k, v).astype(jnp.float32))
+        good = bool((got[:, :n] == want[:, :n]).all()
+                    and (got[:, n:] == 0).all())
+        name = "flash_fwd_window" if w else "flash_fwd_mha"
+        live = _live_rows(n, T)
+        runs[name] = (
+            _named_ms(lambda x: cut(x, k, v, lens), q, name),
+            _named_ms(lambda x: whole(x, k, v), q, name),
+            _named_ms(lambda x: whole(x, k[:, :live], v[:, :live]),
+                      q[:, :live], name),
+            good)
+        need[name] = 4.0 * Hq * D * _band_pairs(n, w)
+    return _fill_report(f"H={Hq}/{Hkv} D={D} window={window}", T, n, runs,
+                        need)
+
+
+def check_fill_mla(T, fill, H=128, n=128, r=64, dv=128, scale=0.13523):
+    """:func:`check_fill_window` for the latent prefill kernel."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    rows = max(1, min(T, int(round(fill * T))))
+    rng = np.random.RandomState(T)
+
+    def arr(lanes, s=0.5):
+        return jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
+                           * s).astype(jnp.bfloat16)
+
+    q, q_r, kv, k_r = arr(H * (n + r)), arr(H * r), arr(H * (n + dv)), \
+        arr(r, 1.5)
+    lens = jnp.asarray([rows], jnp.int32)
+    cut = jax.jit(lambda q, q_r, kv, k_r, lens: pk.mla_flash(
+        q, q_r, kv, k_r, H, n, dv, scale, lengths=lens))
+    whole = jax.jit(lambda q, q_r, kv, k_r: pk.mla_flash(
+        q, q_r, kv, k_r, H, n, dv, scale))
+    got = np.asarray(cut(q, q_r, kv, k_r, lens).astype(jnp.float32))
+    want = np.asarray(whole(q, q_r, kv, k_r).astype(jnp.float32))
+    good = bool((got[:, :rows] == want[:, :rows]).all()
+                and (got[:, rows:] == 0).all())
+    name = "mla_flash_fwd"
+    live = _live_rows(rows, T, latent=True)
+    runs = {name: (
+        _named_ms(lambda x: cut(x, q_r, kv, k_r, lens), q, name),
+        _named_ms(lambda x: whole(x, q_r, kv, k_r), q, name),
+        _named_ms(lambda x: whole(x, q_r[:, :live], kv[:, :live],
+                                  k_r[:, :live]), q[:, :live], name),
+        good)}
+    return _fill_report(
+        f"H={H} qk={n}+{r} v={dv}", T, rows, runs,
+        {name: 2.0 * H * (n + r + dv) * rows * (rows + 1) / 2})
 
 
 def check_mla_paged(B, MB, lengths, H=128, R=512, r=64, KVB=16,
@@ -840,6 +955,11 @@ def _paged_matrix(quick):
 def main():
     quick = "--quick" in sys.argv
     results = []
+    # --fill F with --longdoc / --window / --mla: the prompt kernels of
+    # that cell alone at a prompt of F x T rows in each bucket of T
+    fills = [float(f) for f in
+             sys.argv[sys.argv.index("--fill") + 1].split(",")] \
+        if "--fill" in sys.argv else []
     if "--tiles" in sys.argv:
         return _report(sweep_tiles())
     if "--pages" in sys.argv:
@@ -868,6 +988,16 @@ def main():
         results.append(check_mamba2(1, 1, B=64))
         for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
             results.append(check_mamba2(T, n))
+        return _report(results)
+    for fill in fills:
+        if "--mla" in sys.argv:
+            results += [check_fill_mla(T, fill) for T in (8192, 4096)]
+        if "--longdoc" in sys.argv:
+            results += [check_fill_window(T, 4096, fill, Hq=48, Hkv=8)
+                        for T in (32768, 16384, 8192)]
+        if "--window" in sys.argv:
+            results.append(check_fill_window(8192, 4096, fill))
+    if fills:
         return _report(results)
     if "--mla" in sys.argv:
         # the longctx cell's shapes: 32 rows of 128 heads over 544-page
