@@ -33,6 +33,7 @@ may change that reader.
 
 from __future__ import annotations
 
+import collections
 import functools
 import math
 import os
@@ -775,10 +776,11 @@ def _mha_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _mha_block(block_size, t):
     if int(block_size) <= 0:  # auto: larger tiles amortize the online-
-        # softmax state updates — 1024 from T = 2048 on, 512 below.  This
-        # family has no sweep of its own on record; the packed family's
-        # (PERF.md §6, PR 32, `tools/verify_kernels.py --tiles`) reads
-        # the same way: the wider tile wins wherever it fits
+        # softmax state updates — 1024 from T = 2048 on, 512 below.  The
+        # normalized kernels have no sweep of their own on record; the
+        # packed family's (PERF.md §6, PR 32) and the windowed family's
+        # (PR 43: `_mha_window_tiles` picks its own) read the same way:
+        # the wider tile wins wherever it fits
         block_size = 1024 if t >= 2048 else 512
     b = max(128, min(2048, (int(block_size) // 128) * 128 or 128))
     return min(b, max(128, ((t + 127) // 128) * 128))
@@ -879,11 +881,16 @@ def _mha_fwd(q, k, v, causal, block_size):
 # the W - 1 keys before it.  The normalized kernel above with a lower
 # bound beside the diagonal: a query tile walks only the key tiles its
 # band [first query - W + 1, last query] touches (the grid's key axis is
-# as long as the widest band, counted from the band's first tile), masks
-# the two edge tiles, and never fetches a tile outside the band.  Grouped
-# queries need no repeated K/V: query head h reads KV head h // group
-# through the index map.  Forward only (the family that has windows
-# serves, it does not train).
+# as long as the widest band, counted from the band's first tile) and
+# never fetches a tile outside the band.  A tile wholly inside the band
+# runs without a mask; a tile an edge of the band crosses — the
+# diagonal, the window's lower edge — is walked in sub-blocks: those no
+# row can see are not computed, only those an edge cuts are masked
+# (`_band_walk`; the packed family's `_walk` with a second edge).  The
+# schedule is the kernel's own choice from the shape
+# (`_mha_window_tiles`).  Grouped queries need no repeated K/V: query
+# head h reads KV head h // group through the index map.  Forward only
+# (the family that has windows serves, it does not train).
 # ---------------------------------------------------------------------------
 
 
@@ -903,32 +910,147 @@ def _last_live_tile(length, block):
     return _tile_of(jnp.maximum(length - 1, 0), block)
 
 
-def _window_first_tile(qi, block, window, rows):
-    """The key tile that holds the lowest key query tile ``qi`` sees:
-    tile 0 under a band as wide as the ``rows`` there are."""
-    if window >= rows:
+def _window_first_tile(qi, block_q, block_k, window):
+    """The key tile that holds the lowest key query tile ``qi`` sees
+    (``window`` 0: no lower edge, tile 0)."""
+    if not window:
         return 0
-    return _tile_of(jnp.maximum(qi * block - (window - 1), 0), block)
+    return _tile_of(jnp.maximum(qi * block_q - (window - 1), 0), block_k)
 
 
-def _walked_key_tile(qi, step, last_live, first, dead_step):
+def _diagonal_tile(qi, block_q, block_k):
+    """The key tile that holds query tile ``qi``'s last row: where its
+    walk ends (``qi`` itself where the tiles are square)."""
+    if block_q == block_k:
+        return qi
+    return _tile_of(qi * block_q + (block_q - 1), block_k)
+
+
+def _walked_key_tile(qi, step, last_live, first, dead_step,
+                     last=lambda qi: qi):
     """The key tile whose blocks grid step (qi, step) of a prompt kernel
     holds: a live query tile walks its band's key tiles from
-    ``first(qi)`` up to the diagonal's (key tile qi: the tiles are
-    square) and stands still there for the steps that are left; a dead
-    one stands on the last live tile's last blocks through all its
-    steps, so nothing is fetched for it.  ``dead_step``: any step count
-    past a band's width."""
+    ``first(qi)`` up to the diagonal's (``last(qi)``: key tile qi where
+    the tiles are square) and stands still there for the steps that are
+    left; a dead one stands on the last live tile's last blocks through
+    all its steps, so nothing is fetched for it.  ``dead_step``: any
+    step count past a band's width."""
     row = jnp.minimum(qi, last_live)
     kj = first(row) + jnp.where(qi > last_live, dead_step, step)
-    return jnp.minimum(kj, row)
+    return jnp.minimum(kj, last(row))
 
 
-def _band_steps(rows, block, band):
-    """Grid steps a query tile of a prompt kernel: the key tiles the
-    widest band touches (``band + block - 1`` keys), all of them under
-    a band as wide as the ``rows``."""
-    return min(rows // block, (band + block - 2) // block + 2)
+def _band_walk(off, block_q, block_k, sub, window=0):
+    """The sub-blocks of a (block_q, block_k) tile that an edge of the
+    band crosses — ``_walk`` with a lower edge beside the diagonal:
+    ``[(rows, [(columns, cut)])]`` as slices, a row sub-block of ``sub``
+    rows with the spans of the tile's columns it multiplies.  ``off`` is
+    the tile's first column less its first row; row i sees column j
+    where ``-window < j + off - i <= 0`` (``window`` 0: every column up
+    to the diagonal).  A (sub x sub) block no row of which sees a column
+    is left out, the blocks every row sees whole are joined into one
+    span with ``cut`` None, and a block an edge cuts comes alone with
+    ``cut = (lo, hi)``: inside it local column j' is seen by local row
+    i' where ``lo < j' - i' <= hi`` (a bound that cannot cut the block
+    is None)."""
+    top = -off                                # j - i <= top: the diagonal
+    floor = top - window if window else None  # j - i > floor: the window
+    out = []
+    for b0 in range(0, block_q, sub):
+        pieces = []
+        for c0 in range(0, block_k, sub):
+            # j - i over this block: c0 - b0 - sub < j - i < c0 - b0 + sub
+            d = c0 - b0
+            if d - sub >= top or (floor is not None and d + sub - 1 <= floor):
+                continue            # no row of the block sees a column
+            hi = top - d if d + sub - 1 > top else None
+            lo = floor - d if floor is not None and d - sub < floor else None
+            if lo is None and hi is None and pieces \
+                    and pieces[-1][1] is None and pieces[-1][0].stop == c0:
+                pieces[-1] = (slice(pieces[-1][0].start, c0 + sub), None)
+            else:
+                pieces.append((slice(c0, c0 + sub),
+                               None if lo is None and hi is None
+                               else (lo, hi)))
+        if pieces:
+            out.append((slice(b0, b0 + sub), pieces))
+    return out
+
+
+def _mha_window_tiles(t, window):
+    """(block_q, block_k, sub, inner) of ``flash_mha_window`` for a
+    prompt bucket of ``t`` rows under ``window`` (0: global): the tile a
+    grid step holds, the sub-block an edge tile is walked in, and the
+    rows of a tile wholly inside the band that are updated at a time.
+    From the shape alone, each choice a row of the kernel-alone sweep in
+    PERF.md section 6, PR 43 (``tools/verify_kernels.py --gqa-tiles``
+    prints it again, patching this chooser): query tiles of 1,024 rows
+    (one tile where the bucket is smaller); key tiles of 2,048 where
+    they pad the rows no further — half the grid steps and state
+    updates of the square tile; 512 rows of an interior tile at a time;
+    edge tiles in sub-blocks of 256 (128 read 1-4% faster alone and
+    twice as long to trace and lower, which every process pays)."""
+    t128 = t + (-t) % 128
+    bq = min(1024, t128)
+    rows = t + (-t) % bq
+    return (bq, 2048 if rows % 2048 == 0 else bq,
+            256 if bq % 256 == 0 else 128, 512 if bq % 512 == 0 else bq)
+
+
+_BandSchedule = collections.namedtuple(
+    "_BandSchedule", "band first last masked scores edges interior")
+
+
+@functools.lru_cache(maxsize=None)
+def _band_schedule(rows, block_q, block_k, sub, window):
+    """What each query tile of a prompt kernel's grid does over a bucket
+    of ``rows`` (whole tiles): ``band``, the window where it is a lower
+    edge at all (0 where it is as wide as the rows, or none); numpy
+    arrays a query tile — ``first`` and ``last`` key tile of its walk,
+    the tiles of it that take a ``masked`` body (an edge of the band
+    crosses them) and the ``scores`` it computes (an edge tile only its
+    ``_band_walk``); ``edges``, the offsets (first column less first
+    row) of the grid's edge tiles, and ``interior``, whether it has a
+    tile wholly inside the band."""
+    import numpy as np
+
+    band = window if 0 < window < rows else 0
+    r0 = np.arange(rows // block_q) * block_q
+    first = np.maximum(r0 - (band - 1), 0) // block_k if band \
+        else np.zeros_like(r0)
+    last = (r0 + block_q - 1) // block_k
+    masked = np.zeros_like(r0)
+    scores = np.zeros_like(r0)
+    edges, interior = {}, False
+    for i, (row, lo, hi) in enumerate(zip(r0, first, last)):
+        for kj in range(lo, hi + 1):
+            off = int(kj * block_k - row)
+            if off <= -block_k and not (band and off < block_q - band):
+                interior = True
+                scores[i] += block_q * block_k
+                continue
+            if off not in edges:
+                edges[off] = sum(
+                    (r.stop - r.start) * (c.stop - c.start)
+                    for r, pieces in _band_walk(off, block_q, block_k, sub,
+                                                band)
+                    for c, _ in pieces)
+            masked[i] += 1
+            scores[i] += edges[off]
+    return _BandSchedule(band, first, last, masked, scores,
+                         tuple(sorted(edges)), interior)
+
+
+def _prompt_schedule(rows, window, latent):
+    """``_band_schedule`` of the kernel a layer's prefill runs over a
+    bucket of ``rows``, with its query tile: ``mla_flash`` (``latent``)
+    masks the diagonal's whole tile."""
+    if latent:
+        bq = bk = sub = _mha_block(_MLA_BLOCK, rows)
+    else:
+        bq, bk, sub, _ = _mha_window_tiles(rows, window)
+    padded = rows + (-rows) % max(bq, bk)
+    return bq, _band_schedule(padded, bq, bk, sub, 0 if latent else window)
 
 
 def prompt_tile_visits(length, rows, window=0, latent=False):
@@ -942,26 +1064,53 @@ def prompt_tile_visits(length, rows, window=0, latent=False):
     (the engine's ``prefill_tiles_*`` counters);
     tests/test_prompt_lengths.py holds it to the interpreted kernels'
     own steps."""
-    import numpy as np
-
-    blk = _mha_block(_MLA_BLOCK if latent else 0, rows)
-    nq = -(-rows // blk)
-    qi = np.arange(nq)
-    first = np.maximum(qi * blk - ((window or nq * blk) - 1), 0) // blk
-    walk = qi - first + 1
-    live = -(-min(max(int(length), 0), rows) // blk)
+    bq, plan = _prompt_schedule(rows, window, latent)
+    walk = plan.last - plan.first + 1
+    live = -(-min(max(int(length), 0), rows) // bq)
     return int(walk[:live].sum()), int(walk[live:].sum())
 
 
+def prompt_tile_work(length, rows, window=0, latent=False):
+    """(masked, computed, needed) of one head of a prompt kernel over a
+    prompt of ``length`` rows in a bucket of ``rows``: the walked tiles
+    that took a MASKED body (an edge of the band crosses them; the
+    others run without one), the score elements the schedule computes
+    (a whole tile inside the band, only the sub-blocks a row can see of
+    an edge tile; the last live tile's padding rows count) and the
+    pairs the band holds, the least there is to compute.  Host
+    arithmetic beside :func:`prompt_tile_visits` (the engine's
+    ``prefill_tiles_masked`` / ``prefill_scores_computed_over_needed``),
+    held to the interpreted kernels' own blocks by the same test."""
+    bq, plan = _prompt_schedule(rows, window, latent)
+    n = min(max(int(length), 0), rows)
+    live = -(-n // bq)
+    w = min(n, window) if window else n
+    return (int(plan.masked[:live].sum()), int(plan.scores[:live].sum()),
+            w * (w + 1) // 2 + (n - w) * w)
+
+
+def _band_mask(sub, lo, hi):
+    """A cut block's mask, (sub, sub): ``lo < column - row <= hi``."""
+    d = (jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 1)
+         - jax.lax.broadcasted_iota(jnp.int32, (sub, sub), 0))
+    if lo is None:
+        return d <= hi
+    return d > lo if hi is None else (d > lo) & (d <= hi)
+
+
 def _mha_window_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                       l_ref, *, block, scale, window, rows):
+                       l_ref, *, block_q, block_k, sub, inner, scale, window,
+                       edges, interior):
     qi = pl.program_id(2)
     step = pl.program_id(3)
     length = len_ref[pl.program_id(0)]
-    live = qi * block < length
-    # a live tile's walk ends on the diagonal's tile, which (square
-    # tiles) is never past the tile that holds row length - 1
-    kj = _window_first_tile(qi, block, window, rows) + step
+    live = qi * block_q < length
+    # a live tile's walk ends on the diagonal's tile: the sub-blocks of
+    # it that are computed lie at or before the query tile's last row
+    kj = _window_first_tile(qi, block_q, block_k, window) + step
+    last = _diagonal_tile(qi, block_q, block_k)
+    walked = live & (kj <= last)
+    off = kj * block_k - qi * block_q   # first column less first row
 
     @pl.when(jnp.logical_not(live) & (step == 0))
     def _dead():
@@ -974,49 +1123,77 @@ def _mha_window_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(live & (kj <= qi))
-    def _compute():
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        k_pos = kj * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, block), 1)
-        q_pos = qi * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, block), 0)
-        # a key a row of the prompt sees is below the length (it is at
-        # or below the row): the rows past it are zeroed at the end
-        valid = k_pos <= q_pos
-        if window < rows:
-            valid &= k_pos > q_pos - window
-        s = jnp.where(valid, s, -jnp.inf)
-        m_prev = m_ref[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.where(valid, jnp.exp(s - m_safe[:, None]), 0.0)
-        alpha = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
-        l_new = l_ref[:, 0] * alpha + jnp.sum(p, axis=1)
-        l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
-        pv = jax.lax.dot_general(p.astype(v.dtype), v,
-                                 (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
-        m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
+    def update(blk, pieces):
+        """One update of the rows ``blk``'s state over the column spans
+        ``pieces`` = [(columns, mask or None)], in the exp2 domain."""
+        q = q_ref[0, blk, :]
+        ss = []
+        for cols, mask in pieces:
+            s = _dot(q, k_ref[0, cols, :], 1, 1) * (scale * _LOG2E)
+            ss.append(s if mask is None else jnp.where(mask, s, -jnp.inf))
+        m_prev = m_ref[blk, :1]
+        m_new = m_prev
+        for s in ss:
+            m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
+        if any(mask is not None for _, mask in pieces):
+            # a row may have seen no key yet (under the window's edge)
+            m_use = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+            alpha = jnp.where(m_prev == -jnp.inf, 0.0,
+                              jnp.exp2(m_prev - m_use))
+        else:       # every key is seen: the maximum is finite, and
+            m_use = m_new       # exp2(-inf - m) is 0 at the first tile
+            alpha = jnp.exp2(m_prev - m_new)
+        l_new = l_ref[blk, :1] * alpha
+        pv = None
+        for s, (cols, _) in zip(ss, pieces):
+            p = jnp.exp2(s - m_use)     # a masked score is -inf: 0
+            l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+            v = v_ref[0, cols, :]
+            d = _dot(p.astype(v.dtype), v, 1, 0)
+            pv = d if pv is None else pv + d
+        n = blk.stop - blk.start
+        l_ref[blk, :] = jnp.broadcast_to(l_new, (n, l_ref.shape[1]))
+        m_ref[blk, :] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
+        acc_ref[blk, :] = acc_ref[blk, :] * alpha + pv
 
-    whole = (qi + 1) * block <= length  # no row of the tile is padding
+    if interior:    # wholly inside the band: no mask, no guard
+        inside = walked & (off <= -block_k)
+        if window:
+            inside &= off >= block_q - window
+
+        @pl.when(inside)
+        def _interior():
+            for r0 in range(0, block_q, inner):
+                update(slice(r0, r0 + inner), [(slice(0, block_k), None)])
+
+    # an edge of the band crosses the tile: walked in sub-blocks, only
+    # what a row can see computed, only the blocks an edge cuts masked.
+    # Static slices: one body an offset this grid can reach
+    for o in edges:
+        def _edge(o=o):
+            masks = {}
+            for blk, pieces in _band_walk(o, block_q, block_k, sub, window):
+                for _, cut in pieces:
+                    if cut is not None and cut not in masks:
+                        masks[cut] = _band_mask(sub, *cut)
+                update(blk, [(cols, cut and masks[cut])
+                             for cols, cut in pieces])
+
+        pl.when(walked & (off == o))(_edge)
+
+    whole = (qi + 1) * block_q <= length  # no row of the tile is padding
 
     def out():
-        return acc_ref[...] / jnp.maximum(l_ref[:, 0], 1e-30)[:, None]
+        return acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
 
-    @pl.when(whole & (kj == qi))
+    @pl.when(whole & (kj == last))
     def _finalize():
         o_ref[0] = out().astype(o_ref.dtype)
 
-    @pl.when(live & jnp.logical_not(whole) & (kj == qi))
+    @pl.when(live & jnp.logical_not(whole) & (kj == last))
     def _finalize_last():
         # the prompt ends inside this tile: its padding rows as zeros
-        row = qi * block + jax.lax.broadcasted_iota(
+        row = qi * block_q + jax.lax.broadcasted_iota(
             jnp.int32, acc_ref.shape, 0)
         o_ref[0] = jnp.where(row < length, out(), 0.0).astype(o_ref.dtype)
 
@@ -1041,6 +1218,13 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1, lengths=None):
     ``lse`` written: serving reads none), under the name the
     normalized forward has, ``flash_fwd_mha``.
 
+    The schedule (``_mha_window_tiles``, ``_band_schedule``): a tile
+    wholly inside the band runs without a mask; a tile an edge of the
+    band crosses — the diagonal's, and the one the window's lower edge
+    cuts — is walked in sub-blocks (``_band_walk``), of which only
+    those a row can see are computed and only those an edge cuts are
+    masked.  :func:`prompt_tile_work` counts both kinds.
+
     ``lengths`` (B,): the rows of each prompt in its bucket of T (None:
     T), a scalar operand read at run time.  Rows below a length are
     computed by the tiles, in the order, they are computed without it;
@@ -1049,52 +1233,67 @@ def flash_mha_window(q, k, v, window, heads=1, kv_heads=1, lengths=None):
     (:func:`prompt_tile_visits` counts both kinds)."""
     BH, T, D = q.shape
     window = int(window)
-    group = int(heads) // int(kv_heads)
     if window < 0 or int(heads) % int(kv_heads) \
-            or k.shape[0] * group != BH:
+            or k.shape[0] * (int(heads) // int(kv_heads)) != BH:
         raise MXNetError(
             f"flash_mha_window: window {window} must be >= 0 and q "
             f"{tuple(q.shape)} hold {heads} query heads over the "
             f"{kv_heads} KV heads of k {tuple(k.shape)}")
-    blk = _mha_block(0, T)      # square tiles, as flash_mha picks them
-    qf, kf, vf = (_pad_to(x, 1, blk) for x in (q, k, v))
+    return _flash_mha_window(
+        q, k, v, _prompt_lengths(lengths, BH // int(heads), T),
+        window=window, heads=int(heads), kv_heads=int(kv_heads),
+        tiles=_mha_window_tiles(T, window))
+
+
+# jitted: the layers of a program that call it at one shape share ONE
+# trace of the kernel and one lowered function (a windowed layer's walk
+# is a few hundred operations unrolled: PERF.md section 6, PR 43)
+@functools.partial(jax.jit, static_argnames=("window", "heads", "kv_heads",
+                                             "tiles"))
+def _flash_mha_window(q, k, v, lens, *, window, heads, kv_heads, tiles):
+    BH, T, D = q.shape
+    group = heads // kv_heads
+    bq, bk, sub, inner = tiles
+    qf, kf, vf = (_pad_to(x, 1, max(bq, bk)) for x in (q, k, v))
     rows = qf.shape[1]
-    band = window or rows               # 0: as wide as the prompt
-    steps = _band_steps(rows, blk, band)
-    first = functools.partial(_window_first_tile, block=blk, window=band,
-                              rows=rows)
+    plan = _band_schedule(rows, bq, bk, sub, window)
+    steps = int((plan.last - plan.first + 1).max())
+    first = functools.partial(_window_first_tile, block_q=bq, block_k=bk,
+                              window=plan.band)
+    last = functools.partial(_diagonal_tile, block_q=bq, block_k=bk)
     # one length a KV head, and the grid's first axis the KV heads: the
     # scalar core then divides by nothing but the (power of two) tile
-    lens = jnp.repeat(_prompt_lengths(lengths, BH // int(heads), T),
-                      int(kv_heads))
+    lens = jnp.repeat(lens, kv_heads)
 
     def q_map(kvh, g, qi, step, len_ref):
         return (kvh * group + g,
-                jnp.minimum(qi, _last_live_tile(len_ref[kvh], blk)), 0)
+                jnp.minimum(qi, _last_live_tile(len_ref[kvh], bq)), 0)
 
     def kv_map(kvh, g, qi, step, len_ref):
         return (kvh, _walked_key_tile(
-            qi, step, _last_live_tile(len_ref[kvh], blk), first, steps), 0)
+            qi, step, _last_live_tile(len_ref[kvh], bq), first, steps,
+            last), 0)
 
     kern = functools.partial(
-        _mha_window_kernel, block=blk, scale=1.0 / float(D) ** 0.5,
-        window=band, rows=rows)
+        _mha_window_kernel, block_q=bq, block_k=bk, sub=sub, inner=inner,
+        scale=1.0 / float(D) ** 0.5, window=plan.band, edges=plan.edges,
+        interior=plan.interior)
     o = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(BH // group, group, rows // blk, steps),
+            grid=(BH // group, group, rows // bq, steps),
             in_specs=[
-                _vmem_spec((1, blk, D), q_map),
-                _vmem_spec((1, blk, D), kv_map),
-                _vmem_spec((1, blk, D), kv_map),
+                _vmem_spec((1, bq, D), q_map),
+                _vmem_spec((1, bk, D), kv_map),
+                _vmem_spec((1, bk, D), kv_map),
             ],
             out_specs=_vmem_spec(
-                (1, blk, D),
+                (1, bq, D),
                 lambda kvh, g, qi, step, len_ref: (kvh * group + g, qi, 0)),
-            scratch_shapes=[pltpu.VMEM((blk, D), jnp.float32),
-                            pltpu.VMEM((blk, 128), jnp.float32),
-                            pltpu.VMEM((blk, 128), jnp.float32)]),
+            scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32),
+                            pltpu.VMEM((bq, 128), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(qf.shape, q.dtype),
         compiler_params=_compiler_params(
             "parallel", "parallel", "parallel", "arbitrary",

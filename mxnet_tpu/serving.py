@@ -1095,7 +1095,13 @@ class DecodeEngine:
     they left out for knowing the prompt's length
     (``pallas_kernels.prompt_tile_visits`` over the layers
     ``prompt_attention()`` lists), ``prefill_tiles_skipped_share`` the
-    share of the buckets' tiles that was left out.
+    share of the buckets' tiles that was left out;
+    ``prefill_tiles_masked`` counts the walked tiles that took a masked
+    body (an edge of the band crosses them: the others run without a
+    mask) and ``prefill_scores_computed_over_needed`` is the score
+    elements the kernels' schedule computed (``prefill_scores_computed``)
+    over the pairs the prompts' bands hold (``prefill_scores_needed``;
+    ``pallas_kernels.prompt_tile_work``).
 
     Decode numerics: prefill + N decode steps is bit-identical (lax
     path) to the full-sequence causal forward of
@@ -2140,7 +2146,9 @@ class DecodeEngine:
                 "spec_accepted", "spec_pages_rolled_back", "d2h_syncs",
                 "d2h_syncs_saved", "context_tokens", "prefill_pairs",
                 "prefill_bucket_tokens", "prefill_tiles_walked",
-                "prefill_tiles_skipped", "steps_run_ahead",
+                "prefill_tiles_skipped", "prefill_tiles_masked",
+                "prefill_scores_computed", "prefill_scores_needed",
+                "steps_run_ahead",
                 "run_ahead_drains", "overshoot_row_steps",
                 "prefill_first_deferred")}
         # the share of the prefill programs' rows that held a token:
@@ -2157,6 +2165,13 @@ class DecodeEngine:
             out["prefill_tiles_skipped"] / tiles, 4) if tiles else 0.0
         profiler.set_gauge("serving.prefill_tiles_skipped_share",
                            out["prefill_tiles_skipped_share"])
+        # the score elements those kernels' schedule computed over the
+        # pairs the prompts' bands hold: 1.0 = nothing but the need
+        out["prefill_scores_computed_over_needed"] = round(
+            out["prefill_scores_computed"] / out["prefill_scores_needed"],
+            4) if out["prefill_scores_needed"] else 0.0
+        profiler.set_gauge("serving.prefill_scores_computed_over_needed",
+                           out["prefill_scores_computed_over_needed"])
         # how the loop ran: the share of decode programs dispatched
         # while an earlier program's tokens were still unread, and why
         # it fetched everything before going on, when it did
@@ -4137,18 +4152,27 @@ class DecodeEngine:
     def _count_prompt_tiles(self, n: int, tp: int):
         """The key tiles, a layer and head, that a whole prompt's
         attention kernels walked over its ``n`` rows and left out of
-        its bucket of ``tp`` for knowing its length."""
+        its bucket of ``tp`` for knowing its length; of the walked
+        ones, those that took a masked body; and the scores the
+        schedule computed beside the pairs the bands hold."""
         from .ops import pallas_kernels as pk
 
         if not (self._prompt_layers and pk.enabled()):
             return
-        walked = skipped = 0
+        walked = skipped = masked = computed = needed = 0
         for (window, latent), layers in self._prompt_layers.items():
             w, sk = pk.prompt_tile_visits(n, tp, window, latent)
+            m, c, nd = pk.prompt_tile_work(n, tp, window, latent)
             walked += layers * w
             skipped += layers * sk
+            masked += layers * m
+            computed += layers * c
+            needed += layers * nd
         self._count("prefill_tiles_walked", walked)
         self._count("prefill_tiles_skipped", skipped)
+        self._count("prefill_tiles_masked", masked)
+        self._count("prefill_scores_computed", computed)
+        self._count("prefill_scores_needed", needed)
 
     def _count_window_step(self, streams, lengths):
         """A decode step's need in the windowed layers (the context

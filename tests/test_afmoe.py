@@ -263,7 +263,8 @@ def test_the_global_flash_kernel_groups_queries_through_its_index_map(
     from mxnet_tpu.ops import attention, pallas_kernels as pk
     rng = np.random.default_rng(1)
     B, T, H, Hkv, D = 2, 200, 6, 2, 16      # two tiles, the last ragged
-    monkeypatch.setattr(pk, "_mha_blocks", lambda b, tq, tk: (128, 128))
+    monkeypatch.setattr(pk, "_mha_window_tiles",
+                        lambda t, window: (128, 128, 64, 128))
     q, k, v = (jnp.asarray(rng.normal(size=(B * n, T, D)), jnp.float32)
                for n in (H, Hkv, Hkv))
     got = pk.flash_mha_window(q, k, v, 0, H, Hkv)
@@ -413,6 +414,8 @@ def small_tiles(monkeypatch):
     monkeypatch.setenv("MXNET_PALLAS", "1")
     from mxnet_tpu.ops import pallas_kernels as pk
     monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: 32)
+    monkeypatch.setattr(pk, "_mha_window_tiles",
+                        lambda t, window: (32, 32, 32, 32))
 
 
 @pytest.mark.parametrize("case", [0, 2], ids=["lower_half", "fills"])
@@ -448,10 +451,22 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
         len(lengths) * bucket - skipped, skipped)
     assert st["prefill_tiles_skipped_share"] == round(
         skipped / (len(lengths) * bucket), 4)
+    # an edge of its band crosses every tile a windowed layer walks
+    # here; the global layer masks its diagonal's tiles alone.  A tile
+    # of 32 is its own sub-block: every walked tile is computed whole
+    masked = {20: 4 * 1 + 1, 96: 4 * 5 + 3}
+    needed = {20: 5 * (20 * 21 // 2),
+              96: 4 * (32 * 33 // 2 + 64 * 32) + 96 * 97 // 2}
+    assert st["prefill_tiles_masked"] == sum(masked[n] for n in lengths)
+    assert st["prefill_scores_computed_over_needed"] == round(
+        st["prefill_tiles_walked"] * 32 * 32
+        / sum(needed[n] for n in lengths), 4)
     c = profiler.metrics_summary()
     assert c["counters"]["serving.prefill_tiles_walked"] >= 5
     assert c["gauges"]["serving.prefill_tiles_skipped_share"] == \
         st["prefill_tiles_skipped_share"]
+    assert c["gauges"]["serving.prefill_scores_computed_over_needed"] == \
+        st["prefill_scores_computed_over_needed"]
 
 
 def test_the_lax_bodies_count_no_prompt_tile():
@@ -461,6 +476,8 @@ def test_the_lax_bodies_count_no_prompt_tile():
         st = eng.stats()
     assert st["prefill_tiles_walked"] == st["prefill_tiles_skipped"] == 0
     assert st["prefill_tiles_skipped_share"] == 0.0
+    assert st["prefill_tiles_masked"] == 0
+    assert st["prefill_scores_computed_over_needed"] == 0.0
     assert ref.spec(CFG).prompt_attention() == (
         (W, False), (W, False), (0, False), (W, False), (W, False))
 

@@ -441,6 +441,12 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
         len(lengths) * bucket - skipped, skipped)
     assert st["prefill_tiles_skipped_share"] == round(
         skipped / (len(lengths) * bucket), 4)
+    # the latent kernel masks the diagonal's tile of each live query tile
+    assert st["prefill_tiles_masked"] == sum(
+        4 * -(-n // 32) for n in lengths)
+    assert st["prefill_scores_computed_over_needed"] == round(
+        st["prefill_tiles_walked"] * 32 * 32
+        / sum(4 * (n * (n + 1) // 2) for n in lengths), 4)
 
 
 def test_recompute_preemption_under_a_tight_pool_leaves_the_logits():

@@ -33,6 +33,8 @@ def interpreted(monkeypatch):
     """The kernels interpreted, in tiles of 128 rows."""
     monkeypatch.setenv("MXNET_PALLAS", "1")
     monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: BLOCK)
+    monkeypatch.setattr(pk, "_mha_window_tiles",
+                        lambda t, window: (BLOCK, BLOCK, BLOCK, BLOCK))
 
 
 def padded(x, lengths):
@@ -128,7 +130,8 @@ def computed_steps(monkeypatch, call):
     def when(cond):
         def bind(body):
             if body.__name__ in ("_init", "_dead", "_finalize",
-                                 "_finalize_last"):
+                                 "_finalize_last"):     # the others: a
+                # tile's unmasked body, an edge tile's walk, mla's tile
                 return real(cond)(body)
 
             def counted():
@@ -138,8 +141,10 @@ def computed_steps(monkeypatch, call):
         return bind
 
     monkeypatch.setattr(pk.pl, "when", when)
-    jax.block_until_ready(call())
+    pk._flash_mha_window.clear_cache()      # a trace of its own, with
+    jax.block_until_ready(call())           # this ``when``, dropped after
     jax.effects_barrier()
+    pk._flash_mha_window.clear_cache()
     return len(hits)
 
 
@@ -171,10 +176,10 @@ def test_prompt_tile_visits_are_the_latent_kernels_steps(
 
 
 def test_prompt_tile_visits_at_the_cells_shapes():
-    # longdoc's t32768 (tiles of 1,024): a global layer and a windowed
-    # one at a prompt a little over half the bucket; longctx's t8192
-    # (tiles of 512)
-    assert pk.prompt_tile_visits(17000, 32768) == (153, 375)
-    assert pk.prompt_tile_visits(17000, 32768, 4096) == (75, 75)
+    # longdoc's t32768 (query tiles of 1,024 rows over key tiles of
+    # 2,048): a global layer and a windowed one at a prompt a little
+    # over half the bucket; longctx's t8192 (tiles of 512)
+    assert pk.prompt_tile_visits(17000, 32768) == (81, 191)
+    assert pk.prompt_tile_visits(17000, 32768, 4096) == (45, 45)
     assert pk.prompt_tile_visits(5000, 8192, latent=True) == (55, 81)
-    assert pk.prompt_tile_visits(32768, 32768) == (528, 0)
+    assert pk.prompt_tile_visits(32768, 32768) == (272, 0)

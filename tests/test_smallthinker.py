@@ -218,6 +218,8 @@ def small_tiles(monkeypatch):
     monkeypatch.setenv("MXNET_PALLAS", "1")
     from mxnet_tpu.ops import pallas_kernels as pk
     monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: 32)
+    monkeypatch.setattr(pk, "_mha_window_tiles",
+                        lambda t, window: (32, 32, 32, 32))
 
 
 @pytest.mark.parametrize("n_prompt", [20, 96], ids=["lower_half", "fills"])
@@ -257,6 +259,11 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
         len(lengths) * bucket - skipped, skipped)
     assert st["prefill_tiles_skipped_share"] == round(
         skipped / (len(lengths) * bucket), 4)
+    # every tile a windowed layer walks here is crossed by an edge of
+    # its band, a global layer's diagonal tiles alone
+    assert st["prefill_tiles_masked"] == sum(
+        {20: 6 * 1 + 2 * 1, 96: 6 * 5 + 2 * 3}[n] for n in lengths)
+    assert st["prefill_scores_computed_over_needed"] > 1.0
 
 
 def watch_window_pages(eng):

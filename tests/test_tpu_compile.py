@@ -360,9 +360,12 @@ def test_paged_attention_at_the_mixed_cells_shapes(on_chip, one_chip,
         window) and ("paged_attention" in kernels[0]) != bool(window)
 
 
-@pytest.mark.parametrize("T", [8192, 1024])
+@pytest.mark.parametrize("T", [8192, 2048, 1024])
 def test_flash_mha_window_at_the_mixed_cells_shapes(on_chip, one_chip, T):
     _, _, Hq, Hkv, D, W = _MIXED
+    # query tiles of 1,024 rows over key tiles of 2,048 (one square tile
+    # at T = 1,024), an edge tile walked in sub-blocks of 256
+    assert pk._mha_window_tiles(T, W) == (1024, min(T, 2048), 256, 512)
     text = _compile(lambda q, k, v: pk.flash_mha_window(
         q, k, v, W, Hq, Hkv), one_chip, ((Hq, T, D), bf16),
         ((Hkv, T, D), bf16), ((Hkv, T, D), bf16)).as_text()

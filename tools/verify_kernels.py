@@ -27,6 +27,10 @@ after ANY kernel change:
     python tools/verify_kernels.py --tiles  # the packed kernels' tile
                                             # schedules at the cells'
                                             # shapes, ms a call each
+    python tools/verify_kernels.py --gqa-tiles # the grouped-query prompt
+                                            # kernel's tiles and sub-blocks
+                                            # at the longdoc and mixed
+                                            # cells' shapes, ms a call each
     python tools/verify_kernels.py --pages  # a prefill's K/V write: the
                                             # page kernel against the row
                                             # scatter at the cells' shapes
@@ -389,6 +393,84 @@ def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
     return ok
 
 
+def _gqa_schedule_note(n, T, window):
+    """The schedule's own counts for a prompt of n rows in a bucket of
+    T (host arithmetic, ``pk.prompt_tile_work``): the share of the
+    walked tiles that took a masked body, and the scores computed over
+    the pairs the band holds."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    walked, _ = pk.prompt_tile_visits(n, T, window)
+    masked, done, needed = pk.prompt_tile_work(n, T, window)
+    return (f"masked={masked}/{walked} tiles "
+            f"scores x{done / max(needed, 1):.3f}")
+
+
+# (query heads, KV heads, T): the longdoc cell's buckets, then mixed's
+GQA_SHAPES = ((48, 8, 32768), (48, 8, 8192), (48, 8, 4096),
+              (28, 4, 8192), (28, 4, 2048), (28, 4, 1024))
+
+
+def sweep_gqa_tiles(D=128, window=4096):
+    """The kernel-alone table of PERF.md (PR 43): ``flash_mha_window``
+    under every schedule (block_q, block_k, sub, inner) worth holding
+    against the one ``pk._mha_window_tiles`` picks, windowed and global,
+    at the longdoc and mixed cells' shapes — ms a call beside the least
+    time of the real pairs.  A schedule whose sub-block is the tile
+    masks its edge tiles whole; ``inner`` is the rows of an interior
+    tile updated at a time.  Every schedule's output is held against
+    the chosen one's."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    results = []
+    chooser = pk._mha_window_tiles
+    for Hq, Hkv, T in GQA_SHAPES:
+        rng = np.random.RandomState(T + Hq)
+        q, k, v = (jnp.asarray(rng.randn(n, T, D).astype(np.float32) * 0.5)
+                   .astype(jnp.bfloat16) for n in (Hq, Hkv, Hkv))
+        one = min(1024, T)
+        cands = [None, (one, one, one, one), (one, one, 256, one),
+                 (one, one, 128, one), (512, 512, 256, 512)]
+        if T >= 4096:
+            cands += [(1024, 2048, 1024, 512), (1024, 2048, 256, 1024),
+                      (1024, 2048, 128, 512), (2048, 2048, 256, 512),
+                      (512, 2048, 256, 512), (1024, 512, 256, 512)]
+        for w in (window, 0):
+            name = "flash_fwd_window" if w else "flash_fwd_mha"
+            want = None
+            for tiles in dict.fromkeys(cands):
+                if tiles is not None:
+                    pk._mha_window_tiles = lambda t, w_, tiles=tiles: tiles
+                try:
+                    fn = jax.jit(lambda q, k, v, w=w: pk.flash_mha_window(
+                        q, k, v, w, Hq, Hkv))
+                    got = np.asarray(fn(q, k, v).astype(jnp.float32))
+                    want = got if want is None else want
+                    err = float(np.abs(got - want).max()
+                                / max(np.abs(want).max(), 1e-9))
+                    ms = _named_ms(lambda x: fn(x, k, v), q, name)
+                    note = _gqa_schedule_note(T, T, w)
+                    bq, bk, sub, inner = tiles or chooser(T, w)
+                except Exception as e:  # noqa: BLE001 — the compiler's no
+                    print(f"FAIL gqa-tiles {name} H={Hq}/{Hkv} T={T} "
+                          f"tiles={tiles}: {type(e).__name__}: "
+                          f"{str(e)[:300]}", flush=True)
+                    results.append(False)
+                    continue
+                finally:
+                    pk._mha_window_tiles = chooser
+                least = 4.0 * Hq * D * _band_pairs(T, w) / 197e12 * 1e3
+                ok = err < TOL and bool(np.isfinite(got).all())
+                results.append(ok)
+                print(f"{'OK ' if ok else 'FAIL'} gqa-tiles {name} "
+                      f"H={Hq}/{Hkv} T={T} tiles={bq}x{bk}/{sub}/{inner}"
+                      f"{'' if tiles else ' (chosen)'}: {ms:.3f}ms "
+                      f"least={least:.3f}ms "
+                      f"roofline={100 * least / ms if ms else 0:.1f}% "
+                      f"{note} gap={err:.4f}", flush=True)
+    return results
+
+
 def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
     """The latent prefill kernel (one prompt, ``H`` heads of qk width
     n + r and v width dv, one rotary key for all) against the lax body
@@ -423,12 +505,13 @@ def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
     return ok
 
 
-def _fill_report(what, T, n, runs, need_flops):
+def _fill_report(what, T, n, runs, need_flops, notes=None):
     """One line a kernel of the ``--fill`` checks: ``runs`` = {name: (ms
     given the length, without it, of a bucket cut to the live tiles —
     what is left is the dead tiles' grid steps — and ok)};
     ``need_flops`` = {name: the FLOP of the prompt's REAL pairs}, the
-    least time from it at the v5e's peak."""
+    least time from it at the v5e's peak; ``notes`` = {name: what the
+    schedule says of itself}."""
     ok = True
     for name, (ms, ms_full, ms_live, good) in runs.items():
         ok = ok and good
@@ -437,7 +520,8 @@ def _fill_report(what, T, n, runs, need_flops):
               f"prompt={n} ({n / T:.3f}): lengths={ms:.3f}ms "
               f"lengths=None {ms_full:.3f}ms live_tiles_alone="
               f"{ms_live:.3f}ms least={least:.3f}ms "
-              f"roofline={100 * least / ms if ms else 0:.1f}%", flush=True)
+              f"roofline={100 * least / ms if ms else 0:.1f}%"
+              f"{' ' + notes[name] if notes else ''}", flush=True)
     return ok
 
 
@@ -446,13 +530,14 @@ def _named_ms(fn, x, name):
     return _kernel_ms(fn, x, n=3).get(name, 0.0)
 
 
-def _live_rows(n, T, latent=False):
+def _live_rows(n, T, latent=False, window=0):
     """The rows of a bucket of T that a prompt of n rows' live query
     tiles hold: a bucket cut there walks what the kernel given the
     length walks, WITHOUT the dead tiles' grid steps."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    blk = pk._mha_block(pk._MLA_BLOCK if latent else 0, T)
+    blk = pk._mha_block(pk._MLA_BLOCK, T) if latent \
+        else pk._mha_window_tiles(T, window)[0]
     return min(T, -(-n // blk) * blk)
 
 
@@ -474,7 +559,7 @@ def check_fill_window(T, window, fill, Hq=28, Hkv=4, D=128):
     q, k, v = (jnp.asarray(rng.randn(n_, T, D).astype(np.float32) * 0.5)
                .astype(jnp.bfloat16) for n_ in (Hq, Hkv, Hkv))
     lens = jnp.asarray([n], jnp.int32)
-    runs, need = {}, {}
+    runs, need, notes = {}, {}, {}
     for w in (window, 0):
         cut = jax.jit(lambda q, k, v, lens, w=w: pk.flash_mha_window(
             q, k, v, w, Hq, Hkv, lengths=lens))
@@ -485,7 +570,7 @@ def check_fill_window(T, window, fill, Hq=28, Hkv=4, D=128):
         good = bool((got[:, :n] == want[:, :n]).all()
                     and (got[:, n:] == 0).all())
         name = "flash_fwd_window" if w else "flash_fwd_mha"
-        live = _live_rows(n, T)
+        live = _live_rows(n, T, window=w)
         runs[name] = (
             _named_ms(lambda x: cut(x, k, v, lens), q, name),
             _named_ms(lambda x: whole(x, k, v), q, name),
@@ -493,8 +578,9 @@ def check_fill_window(T, window, fill, Hq=28, Hkv=4, D=128):
                       q[:, :live], name),
             good)
         need[name] = 4.0 * Hq * D * _band_pairs(n, w)
+        notes[name] = _gqa_schedule_note(n, T, w)
     return _fill_report(f"H={Hq}/{Hkv} D={D} window={window}", T, n, runs,
-                        need)
+                        need, notes)
 
 
 def check_fill_mla(T, fill, H=128, n=128, r=64, dv=128, scale=0.13523):
@@ -962,6 +1048,8 @@ def main():
         if "--fill" in sys.argv else []
     if "--tiles" in sys.argv:
         return _report(sweep_tiles())
+    if "--gqa-tiles" in sys.argv:
+        return _report(sweep_gqa_tiles())
     if "--pages" in sys.argv:
         # a prompt's K/V write at the serving cells' shapes: doc's one
         # bucket (a whole and a typical prompt), reason's two, mixed's
