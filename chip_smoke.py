@@ -27,7 +27,7 @@ One process, one TPU chip, the entry points a user calls:
    ``LOGPROB_TOL`` nats of its argmax, and at least ``MIN_EXACT`` of
    all tokens must be the argmax outright.
 3. ``resnet_train`` — ResNet-50, batch 128, bf16, three fused steps
-   through Module the way bench.py sets it up.
+   through Module (``Module.fit``'s fused step, synthetic batches).
 
 ``--chips 4`` runs ONLY the multi-chip paths, in one process that
 drives all four chips: the LM training step under a dp=2 x tp=2
@@ -313,7 +313,7 @@ def phase_lm_serve(mod, toks, cfg, serve, ctx, seed,
 
 
 # ---------------------------------------------------------------------------
-# phase 3: ResNet-50 (the path bench.py measures)
+# phase 3: ResNet-50 (Module's fused step on a convolutional net)
 # ---------------------------------------------------------------------------
 
 def phase_resnet_train(cfg, ctx, seed):

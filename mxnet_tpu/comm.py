@@ -145,7 +145,7 @@ def make_ps_launch(client, sync: bool = False):
     ONE D2H of the (optionally wire-compressed) packed bucket, then one
     multi-key frame per shard through the windowed connection pipeline;
     returns the collect-later finisher.  The ONE implementation shared
-    by DistKVStore, tools/bench_comm.py and the tests, so they all
+    by DistKVStore and the tests, so they all
     exercise the code path the kvstore actually runs."""
     def launch(bucket):
         flat = pack_bucket(bucket.arrays)
@@ -282,7 +282,7 @@ class CommScheduler:
         self._seq = 0
         self._stop = False
         self._failed: Optional[BaseException] = None
-        # telemetry the bench reads: comm-thread busy seconds vs main-
+        # telemetry: comm-thread busy seconds vs main-
         # thread blocked-waiting seconds → overlap ratio
         self.busy_s = 0.0
         self.blocked_s = 0.0
@@ -495,8 +495,8 @@ class CommScheduler:
             self._complete(bucket, exc=e)
             return
         # busy_s counts actual work (launch call + finisher call), NOT
-        # the time a finisher sat queued behind the window — the bench's
-        # overlap_ratio divides by it, and queue-idle time would
+        # the time a finisher sat queued behind the window — an
+        # overlap ratio divides by it, and queue-idle time would
         # over-report comm utilization.  The span below still covers
         # launch→completion: "bucket in flight" is what a trace shows.
         self.busy_s += time.perf_counter() - t0

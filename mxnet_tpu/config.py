@@ -70,8 +70,8 @@ register_env(
     "gradients reduce-scattered over 'dp', Adam/momentum slots stored "
     "and updated on the local 1/dp shard only, parameters all-gathered "
     "back in-program (Rajbhandari et al., 2020 stage 1).  Cuts "
-    "per-device optimizer-state bytes and update FLOPs ~dp×; see "
-    "tools/bench_zero.py.  0: replicate the optimizer state and the "
+    "per-device optimizer-state bytes and update FLOPs ~dp×; "
+    "tests/test_zero.py holds both.  0: replicate the optimizer state and the "
     "update on every device (the pre-ZeRO behavior).  Checkpointed "
     "optimizer states are layout-independent either way.")
 register_env(
@@ -123,7 +123,7 @@ register_env(
     "block parameters are stored STAGE-RESIDENT — per-slot (S, L/S, "
     "...) slabs sharded P('pp', ...) so each pipeline stage holds "
     "only its own layers' weights and optimizer state (~1/pp the "
-    "bytes; tools/bench_pp.py prints the number).  Stage-boundary "
+    "bytes; Module.param_bytes_per_device() is the number).  Stage-boundary "
     "data movement runs through explicit shard_map ppermute/psum "
     "helpers, NOT the SPMD partitioner's handling of a 'pp'-sharded "
     "scan carry — the documented MXNET_PP_CONSTRAIN miscompile on "

@@ -11,8 +11,8 @@ the end of backward.
 
 This module parses the scheduled HLO text (``is_scheduled=true``
 modules, the form ``jitted.lower(...).compile().as_text()`` returns)
-and answers both questions, so the bench tools, the dryrun and the
-tests can gate on structure rather than on wall-clock luck:
+and answers both questions, so the dryrun and the tests can gate on
+structure rather than on wall-clock luck:
 
 - :func:`collective_summary` — ordered per-op classification of the
   entry computation;

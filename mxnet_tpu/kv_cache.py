@@ -180,9 +180,9 @@ def pool_device_bytes(cache_blocks: int, kv_block: int,
     holds for a serving engine meshed ``tp x pp``: the stacked layer
     dim shards over 'pp' (stage-resident slabs) and the head dim over
     'tp', so per-device bytes fall as 1/(tp*pp).  ``tp=pp=1`` is the
-    single-device total — capacity planners (and bench_serving's
-    --tp sizing) compare the two to prove a model's pool doesn't fit
-    one chip.  ``latent_row`` = (kv_rank, rope_dim): the layers keep one
+    single-device total — capacity planners compare the two to
+    prove that a model's pool doesn't fit one chip before they
+    shard it.  ``latent_row`` = (kv_rank, rope_dim): the layers keep one
     latent row a token (:func:`latent_pool_shape`: ONE pool a layer,
     shared by all heads — nothing for ``tp`` to cut) in place of K and V
     rows of ``d_model``."""

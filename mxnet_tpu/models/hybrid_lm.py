@@ -66,21 +66,21 @@ MIXER and its own FFN:
 
 :class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
 takes: from the layer list it derives the feeds, the pools (pages for
-attention layers, slots for kda and mamba2 layers) and the prefill and
-decode symbols (a prefill's logits are those of each prompt's LAST row
-alone, (B, 1, vocab): the engine samples one token of it).  Four multipliers and the head's weights are data of
-the spec too: ``embed_scale`` (on the token rows), ``residual_scale``
-(on every block's output before it is added), ``logits_scale`` (on the
-last norm's output before the head) and ``tied_head`` (the head is the
-token table); so is ``post_norm`` (sandwich norms: a branch's OUTPUT,
-the mixer's and the FFN's alike, goes through an RMSNorm of its own —
-nodes ``layer{i}_post_norm1`` / ``_post_norm2`` — before
-``residual_scale`` and the add).  A key that is absent builds the
-symbol it built before the key existed; a key no kind knows is refused
-by name.  The equations are in ``benchmark/reference/
-solar_open2.py``, ``granitemoehybrid.py``, ``smallthinker.py``,
-``deepseek_v3.py`` and ``afmoe.py``, the plain references this family
-is held to.
+attention and mla layers, slots for kda and mamba2 layers) and the
+prefill and decode symbols (a prefill's logits are those of each
+prompt's LAST row alone, (B, 1, vocab): the engine samples one token).
+Data of the spec too: ``embed_scale`` (token rows), ``residual_scale``
+(a block's output before the add), ``logits_scale`` (the last norm's
+output), ``tied_head``, ``post_norm`` (a branch's OUTPUT through an
+RMSNorm of its own, ``layer{i}_post_norm1`` / ``_post_norm2``, before the
+scale and the add).  An absent key builds the symbol it built before the
+key existed; a key no kind knows is refused by name.  NODE NAMES (a traced
+run's ``scope_time`` line groups device time by them), after ``layer{i}_``:
+``norm1 norm2``; attention ``q k v q_norm k_norm attn gate o``; mla ``q_down
+q_norm q_up kv_down kv_norm``, ``kv_up`` (prefill) or ``absorb_k absorb_v``
+(decode), ``attn o``; kda ``qkv conv a_down a_up beta kda g_down g_up onorm
+o``; mamba2 ``in conv mamba2 onorm out``; FFNs ``ffn_* moe shared_*``; and
+``tok_embed final_norm last_row head``.  Equations: ``benchmark/reference/``.
 """
 
 from .. import symbol as sym

@@ -57,8 +57,8 @@ def _block(x, d_model, d_ff, name, attend, dropout=0.0, lora=(), layer=0):
     The fused QKV projection output feeds ``attend`` DIRECTLY — the
     packed-heads Pallas kernel slices heads by lane span, so no
     reshape/slice/transpose ops exist between the two matmuls (they
-    measured ~20 ms/step at GPT-2-small scale;
-    tools/profile_transformer.py, PERF.md).
+    measured ~20 ms/step at GPT-2-small scale in July; today
+    `benchmark/run.py --trace 1`'s `scope_time` line shows such ops).
 
     ``lora``: rank buckets (ints).  Each bucket adds a per-stream
     LoRA epilogue on the fused QKV projection — the adapter slabs

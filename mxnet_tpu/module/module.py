@@ -1202,9 +1202,9 @@ class Module(BaseModule):
     def param_bytes_per_device(self):
         """Bytes of LIVE parameter storage resident on ONE device —
         slabs count their per-device shard, per-name arrays count
-        theirs, freed (slab-covered) buffers count zero.  bench_pp's
-        ``weight_bytes_per_device`` reads this; stage residency drops
-        it ~1/pp for the stacked block weights."""
+        theirs, freed (slab-covered) buffers count zero.  Stage
+        residency drops it ~1/pp for the stacked block weights
+        (tests/test_pp.py holds the ratio)."""
         total = 0
 
         def add(d):
@@ -1960,10 +1960,10 @@ class Module(BaseModule):
         self._exec.outputs_cache = [NDArray(o, self._context[0]) for o in outs]
 
     def _account_step_flops(self, step_args):
-        """Promote the offline bench's FLOPs/MFU math into the live
-        fit path: XLA's own HLO cost analysis of the jitted fused
-        step's lowering yields the per-step FLOPs, divided across the
-        mesh so ``training.mfu`` is per-chip like the bench's number.
+        """The live fit path's FLOPs/MFU accounting: XLA's own HLO
+        cost analysis of the jitted fused step's lowering yields the
+        per-step FLOPs, divided across the mesh so ``training.mfu`` is
+        per-chip.
         The step is lowered HERE, from the very arrays the first call
         is about to pass: jax keeps that lowering, so the call traces
         and lowers nothing again and ``_fused_compiled`` hands out the
@@ -1980,7 +1980,7 @@ class Module(BaseModule):
         plan = self._mesh_plan
         if plan is not None and plan.pp > 1:
             # (pp-1)/(M+pp-1): the GPipe/1F1B fill-drain bubble of the
-            # static timetable (bench_pp measures the same quantity)
+            # static timetable
             tracker.set_pp_bubble(
                 (plan.pp - 1) / (plan.microbatches + plan.pp - 1))
         try:
@@ -2025,7 +2025,7 @@ class Module(BaseModule):
         """Compiled (scheduled, SPMD-partitioned) HLO text of the
         fused training step — the artifact the comm/compute-overlap
         inspection reads (``mxnet_tpu.hlo.overlap_report``;
-        tests/test_overlap.py, tools/bench_pp.py, PERF.md evidence).
+        tests/test_overlap.py).
 
         Reads the executable the step runs (no compile of its own);
         call after at least one fused step has run."""
@@ -2061,8 +2061,8 @@ class Module(BaseModule):
 
     def fused_memory_analysis(self):
         """Per-device compiled memory breakdown of the fused step
-        (argument/temp/output bytes) — bench_pp's
-        ``weight_bytes_per_device`` / stash-bytes evidence."""
+        (argument/temp/output bytes) of the executable the step
+        runs."""
         return self._fused_compiled().memory_analysis()
 
     def account_program_comm(self):

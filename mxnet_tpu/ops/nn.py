@@ -441,7 +441,7 @@ def _fused_mean_var(xf, in_dtype, axes, shift_slice, keepdims):
     8-bit mantissa cannot represent std below mean/256, so the f32
     accumulator keeps >=100x cancellation headroom, and the shift
     measured a 9 ms/step ResNet-50 regression by breaking XLA's fused
-    reduce pattern (tools/roofline_resnet.py, PERF.md).  Everything
+    reduce pattern (July's ResNet roofline run, git history).  Everything
     else (f32, and f16 whose 10-bit mantissa CAN express the hazard)
     subtracts a stop-gradient sampled shift s — always inside the
     data's range — so E[(x-s)^2] - E[x-s]^2 cannot catastrophically

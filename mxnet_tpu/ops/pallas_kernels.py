@@ -1381,7 +1381,7 @@ def _mha_bwd(q, k, v, o, lse, do, causal, block_size):
 #
 # The (BH, T, D) layouts above still require a T↔H relayout between the
 # model's (B, T, H·D) activations and the kernel — measured at ~20 ms
-# per transformer step (tools/profile_transformer.py), because narrow
+# per transformer step (the July per-op profile, git history), because narrow
 # d_head transposes run far below HBM speed.  This kernel removes the
 # relayout entirely: q, k, v are LANE-BLOCK VIEWS of the fused QKV
 # projection output (B, T, 3·H·D) — the same array is passed three

@@ -31,8 +31,8 @@ go, across every worker" question — Sigelman et al. 2010):
   Perfetto.  ``dump_rank_trace(dir)`` writes ``trace_rank<N>.json``.
 * metrics registry — always-on counters / gauges / histograms
   (``inc_counter`` / ``set_gauge`` / ``observe``); ``metrics_summary``
-  adds p50/p90/p99 and per-counter rate-since-reset so the serving
-  bench and the reporter share one schema.
+  adds p50/p90/p99 and per-counter rate-since-reset so the
+  benchmark's runners and the reporter share one schema.
 * exporters — ``prometheus_text()`` renders the registry in the
   Prometheus text exposition format (real ``histogram``
   ``_bucket``/``_sum``/``_count`` series since PR 12; the pre-PR-12
@@ -920,8 +920,8 @@ class MetricsRegistry:
         reset}, 'gauges': {...}, 'histograms': {name: {count, mean,
         min, max, p50, p90, p99}}, 'elapsed_s': ...} — JSON-ready.
 
-        The reporter's JSONL lines and ``serving.stats()``/
-        ``tools/bench_serving.py`` all consume this one schema."""
+        The reporter's JSONL lines, ``serving.stats()`` and the
+        benchmark's runners all consume this one schema."""
         import numpy as _np
 
         with self._lock:
@@ -1209,10 +1209,10 @@ def start_reporter(path, interval=10.0, registry=None) -> Reporter:
 
 # -- live goodput / MFU accounting ---------------------------------------
 # THE peak table: per-chip peak rates keyed by jax's ``device_kind``,
-# each row with its source.  bench.py, tools/bench_secondary.py and the
-# live MFU gauge all divide by these.  It holds the one chip this repo
-# runs on; a device that is not here is an error, not a default — add
-# its row, with the source, when it is first measured.
+# each row with its source.  The live MFU gauge divides by these (the
+# benchmark's own, benchmark/peaks.py, is held equal by a test).  It
+# holds the one chip this repo runs on; a device that is not here is
+# an error, not a default — add its row when it is first measured.
 PEAK_BY_DEVICE_KIND = {
     # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16,
     # 819 GB/s HBM.  jax reports the chip as "TPU v5 lite".
@@ -1311,7 +1311,7 @@ class GoodputTracker:
     def set_flops_per_step(self, flops):
         """Model FLOPs of ONE optimizer step (fwd+bwd+update) — from
         the fused program's XLA cost analysis (Module) or an analytic
-        formula (benches)."""
+        formula."""
         with self._lock:
             self._flops = float(flops) if flops else None
 

@@ -3,7 +3,6 @@ commit protocol, corruption fallback, auto-resume bit-exactness,
 fault injection (writer killed mid-shard), SIGTERM preemption, env-var
 validation, and the inspect/bench tools."""
 
-import json
 import os
 import signal
 import subprocess
@@ -369,19 +368,6 @@ def test_ckpt_inspect_tool(tmp_path):
         env=_subproc_env())
     assert r3.returncode == 1
     assert "CORRUPT" in r3.stdout
-
-
-def test_bench_ckpt_smoke():
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bench_ckpt.py"),
-         "--mb", "8", "--iters", "2"],
-        capture_output=True, text=True, timeout=300, cwd=REPO,
-        env=_subproc_env())
-    assert r.returncode == 0, r.stdout + r.stderr
-    out = json.loads(r.stdout.strip().splitlines()[-1])
-    assert out["sync_ms"] > 0 and out["async_blocking_ms"] > 0
-    # the whole point: async blocks (much) less than a synchronous save
-    assert out["blocking_ratio"] < 1.0
 
 
 def test_bucketing_module_optimizer_snapshot_roundtrip():

@@ -680,7 +680,7 @@ def test_ladder_coverage_validated_at_construction(lm):
 
 
 def test_reset_stats_isolates_measurement_points(lm):
-    """bench_serving sweeps one engine across load points; reset_stats
+    """A sweep drives one engine across load points; reset_stats
     must zero counters AND histogram reservoirs so a point's
     percentiles don't blend earlier points' samples."""
     params, _, _ = lm

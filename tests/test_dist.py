@@ -74,18 +74,6 @@ def test_launcher_propagates_failure():
     assert "failed" in r.stderr
 
 
-def test_bandwidth_tool_runs():
-    env = dict(os.environ, JAX_PLATFORMS="cpu",
-               XLA_FLAGS="--xla_force_host_platform_device_count=8",
-               PYTHONPATH=REPO)
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "tools", "bandwidth.py"),
-         "--sizes", "1", "--iters", "2"],
-        capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
-    assert r.returncode == 0, r.stdout + r.stderr
-    assert "psum GB/s" in r.stdout
-
-
 def test_launch_module_fit_dist_sync(tmp_path):
     """Module.fit across 2 real processes (kvstore='dist_sync',
     update_on_kvstore) must produce the same final weights as a

@@ -452,7 +452,7 @@ def test_trace_merge_clock_alignment(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# tools/parse_log.py + tools/xplane_parse.py
+# tools/parse_log.py
 # ---------------------------------------------------------------------------
 
 
@@ -478,76 +478,3 @@ def test_parse_log_plain_scientific_and_nan(tmp_path):
     assert data[1][0][0] == pytest.approx(0.15)
     assert data[1][1][1] == 1 and math.isnan(data[1][1][0])
     assert data[1][2][0] == pytest.approx(12.0)
-
-
-def _vint(v):
-    out = b""
-    while True:
-        b7 = v & 0x7F
-        v >>= 7
-        if v:
-            out += bytes([b7 | 0x80])
-        else:
-            return out + bytes([b7])
-
-
-def _pb(fn, payload):
-    """Length-delimited field."""
-    return _vint((fn << 3) | 2) + _vint(len(payload)) + payload
-
-
-def _pbv(fn, v):
-    """Varint field."""
-    return _vint(fn << 3) + _vint(v)
-
-
-def _synthetic_xspace():
-    """Hand-encode a tiny XSpace: one TPU device plane, an 'XLA
-    Modules' line with two executions of one module (3 ms + 1 ms)."""
-    ev1 = _pbv(1, 1) + _pbv(2, 0) + _pbv(3, 3_000_000_000)  # 3e9 ps = 3 ms
-    ev2 = _pbv(1, 1) + _pbv(2, 5_000_000_000) + _pbv(3, 1_000_000_000)
-    line = (_pb(2, b"XLA Modules") + _pbv(3, 1234)
-            + _pb(4, ev1) + _pb(4, ev2))
-    emeta = _pbv(1, 1) + _pb(2, b"jit_fused_step")  # XEventMetadata
-    entry = _pbv(1, 1) + _pb(2, emeta)              # map<id, metadata>
-    plane = _pb(2, b"/device:TPU:0") + _pb(3, line) + _pb(4, entry)
-    return _pb(1, plane)  # XSpace.planes
-
-
-def test_xplane_parse_synthetic(tmp_path):
-    import xplane_parse
-
-    pb = tmp_path / "host.xplane.pb"
-    pb.write_bytes(_synthetic_xspace())
-    planes = xplane_parse.load_xspace(str(pb))
-    assert len(planes) == 1
-    p = planes[0]
-    assert p.name == "/device:TPU:0"
-    assert p.event_names == {1: "jit_fused_step"}
-    assert len(p.lines) == 1
-    ln = p.lines[0]
-    assert ln.name == "XLA Modules" and ln.timestamp_ns == 1234
-    assert [e.duration_ps for e in ln.events] == [
-        3_000_000_000, 1_000_000_000]
-    # the shared helper: dominant module = 4 ms over 2 executions
-    ms, cnt = xplane_parse.dominant_module_ms(str(tmp_path))
-    assert cnt == 2
-    assert ms == pytest.approx(2.0)
-
-
-def test_xplane_parse_real_trace(tmp_path):
-    """End-to-end: parse the XSpace jax.profiler actually writes."""
-    logdir = str(tmp_path / "xla")
-    mx.profiler.start_xla_trace(logdir)
-    mx.nd.dot(mx.nd.ones((16, 16)), mx.nd.ones((16, 16))).asnumpy()
-    mx.profiler.stop_xla_trace()
-    import glob
-
-    import xplane_parse
-
-    paths = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
-                      recursive=True)
-    assert paths, "jax wrote no xplane.pb"
-    planes = xplane_parse.load_xspace(paths[0])
-    assert planes
-    assert any(p.lines for p in planes)

@@ -544,24 +544,3 @@ def test_pp_layers_must_divide_stages():
         b = next(iter(it))
         mod.forward_backward(b)
         mod.update()
-
-
-def test_bench_pp_tool_runs():
-    import json
-    import subprocess
-    import sys
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=repo, BENCH_PP_STEPS="2",
-               BENCH_PP_WARMUP="1", BENCH_PP_MICRO="1,4",
-               BENCH_PP_LAYERS="4", BENCH_PP_HIDDEN="32")
-    r = subprocess.run(
-        [sys.executable, os.path.join(repo, "tools", "bench_pp.py")],
-        capture_output=True, text=True, timeout=600, cwd=repo, env=env)
-    assert r.returncode == 0, r.stdout + r.stderr
-    rec = json.loads(r.stdout.strip().splitlines()[-1])
-    assert rec["metric"] == "pp_train_throughput"
-    assert rec["weights_match"] is True
-    by_m = {row["microbatches"]: row for row in rec["sweep"]}
-    assert by_m[4]["bubble_fraction"] == pytest.approx(
-        pp.bubble_fraction(4, rec["pp"]))

@@ -2,14 +2,9 @@
 the alert-before-conviction contract under injected latency
 (``MXNET_CHAOS_SLOW_RANK``), canary exclusion from the request
 counters, EXACT per-request cost-record conservation against the
-engine counters across a mixed prefix-hit/speculative/chunked run,
-and a perf_sentinel smoke (identical runs pass, a doctored 2x-worse
-run fails naming the metric).
+engine counters across a mixed prefix-hit/speculative/chunked run.
 """
 
-import importlib.util
-import json
-import os
 import time
 
 import numpy as np
@@ -20,8 +15,6 @@ from mxnet_tpu import chaos, models, profiler, slo
 from mxnet_tpu.elastic import dead_rank_timeout
 
 V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
-
-_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def _cfg(**kw):
@@ -371,83 +364,3 @@ def test_slow_rank_alert_fires_before_conviction(lm, monkeypatch,
     dumps = list(tmp_path.iterdir())
     assert dumps, "slo_alert flight-recorder dump missing"
     assert any("slo_alert" in d.name for d in dumps)
-
-
-# ---------------------------------------------------------------------------
-# perf_sentinel smoke (tier-1 safe: stdlib-only module, no jax)
-# ---------------------------------------------------------------------------
-
-
-@pytest.fixture(scope="module")
-def sentinel():
-    spec = importlib.util.spec_from_file_location(
-        "perf_sentinel", os.path.join(_REPO, "tools",
-                                      "perf_sentinel.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_file(path, tok_s, p99_ms):
-    path.write_text(
-        "[bench] log noise the parser must skip\n"
-        + json.dumps({"metric": "toy_throughput", "value": tok_s,
-                      "unit": "tokens/s/chip"}) + "\n"
-        + json.dumps({"metric": "toy_p99", "value": p99_ms,
-                      "unit": "ms"}) + "\n")
-    return str(path)
-
-
-def test_perf_sentinel_repeat_passes_regression_fails(
-        sentinel, tmp_path, capsys):
-    hist = str(tmp_path / "hist.jsonl")
-    good = _bench_file(tmp_path / "run_a.json", 100.0, 20.0)
-    assert sentinel.main(["--record", good, "--history", hist]) == 0
-    # an identical repeat run sits inside the noise band
-    assert sentinel.main(["--check", good, "--history", hist]) == 0
-    # a 2x-worse run fails with non-zero exit, NAMING the metrics —
-    # in both directions (throughput down, latency up)
-    bad = _bench_file(tmp_path / "run_bad.json", 50.0, 40.0)
-    capsys.readouterr()
-    assert sentinel.main(["--check", bad, "--history", hist]) == 1
-    out = capsys.readouterr()
-    assert "REGRESSED" in out.out
-    assert "toy_throughput" in out.err and "toy_p99" in out.err
-    # direction inference: ms is lower-better, /s is higher-better
-    assert sentinel.lower_is_better("ms")
-    assert not sentinel.lower_is_better("tokens/s/chip")
-    # unknown metrics pass by default, fail under --strict
-    new = _bench_file(tmp_path / "run_new.json", 1.0, 1.0)
-    hist2 = str(tmp_path / "empty.jsonl")
-    assert sentinel.main(["--check", new, "--history", hist2]) == 0
-    assert sentinel.main(["--check", new, "--history", hist2,
-                          "--strict"]) == 1
-
-
-def test_perf_sentinel_noise_band_uses_median_and_mad(
-        sentinel, tmp_path):
-    """5 recorded points around 100 (MAD 2): with sigma=5 the band is
-    max(5*1.4826*2, 10) ≈ 14.8, so 90 passes and 80 fails."""
-    hist = str(tmp_path / "h.jsonl")
-    for v in (97.0, 99.0, 100.0, 102.0, 104.0):
-        sentinel.main(["--record",
-                       _bench_file(tmp_path / "r.json", v, 20.0),
-                       "--history", hist])
-    b = sentinel.baseline(sentinel.load_history(hist),
-                          "toy_throughput")
-    assert b["median"] == 100.0 and b["mad"] == 2.0
-    ok = _bench_file(tmp_path / "ok.json", 90.0, 20.0)
-    assert sentinel.main(["--check", ok, "--history", hist]) == 0
-    sag = _bench_file(tmp_path / "sag.json", 80.0, 20.0)
-    assert sentinel.main(["--check", sag, "--history", hist]) == 1
-
-
-def test_perf_sentinel_committed_history_parses(sentinel):
-    """The committed BENCH_HISTORY.jsonl stays loadable and every
-    recorded metric yields a usable baseline."""
-    hist = sentinel.load_history(os.path.join(_REPO,
-                                              "BENCH_HISTORY.jsonl"))
-    assert hist, "committed BENCH_HISTORY.jsonl is empty"
-    for metric in {h["metric"] for h in hist}:
-        b = sentinel.baseline(hist, metric)
-        assert b["n"] >= 1 and b["median"] > 0

@@ -744,7 +744,7 @@ def test_mla_ops_update_the_latent_pool_in_place(on_chip, one_chip, phase):
 
 
 def test_lstm_scan_ptb(on_chip, one_chip):
-    # PTB LSTM (tools/bench_secondary.py): T=32, batch 32, hidden 200
+    # the PTB LSTM's shape: T=32, batch 32, hidden 200
     T, B, H = 32, 32, 200
     _compile(pk.lstm_scan, one_chip, ((T, B, 4 * H), f32), ((B, H), f32),
              ((B, H), f32), ((H, 4 * H), f32))

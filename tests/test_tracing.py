@@ -249,10 +249,10 @@ def test_peak_flops_env_override(monkeypatch):
 
 
 def test_one_peak_table_unknown_kind_is_an_error(monkeypatch):
-    """profiler.PEAK_BY_DEVICE_KIND is THE peak table (bench.py,
-    bench_secondary and the live gauge divide by it): it holds the one
-    chip there is, and a device_kind without a row raises instead of
-    answering None and letting MFU drop out in silence."""
+    """profiler.PEAK_BY_DEVICE_KIND is the package's peak table (the
+    live MFU gauge divides by it): it holds the one chip there is, and
+    a device_kind without a row raises instead of answering None and
+    letting MFU drop out in silence."""
     assert profiler.peak_flops("TPU v5 lite") == pytest.approx(197e12)
     with pytest.raises(mx.MXNetError, match="TPU v9000"):
         profiler.peak_flops("TPU v9000")

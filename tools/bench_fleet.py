@@ -2,7 +2,12 @@
 """Fleet benchmark + chaos drill: closed-loop client sweep over
 replica counts, and the kill-one-replica acceptance drill.
 
-Prints ONE JSON line per mode (the `bench.py` convention):
+Kept as the drill harness of the ``slow`` fleet tests and of
+``fleet.py``'s script builder.  Its req/s and latencies are the host's,
+over a toy MLP: a judgement of the drill, never a performance record
+(that is ``benchmark/run.py`` and ``PERF_LEDGER.jsonl``).
+
+Prints ONE JSON line per mode:
 
 Sweep (default):
   {"metric": "fleet_throughput", "value": N, "unit": "req/s",
@@ -23,8 +28,8 @@ local never-migrated reference engine (same params, same seeds):
    "migration_bytes": {"total": N, "frames": N, "avg_per_frame": N},
    ...}, "mixed": {...}, "ttft_isolation_vs_mixed": N}
 plus one companion {"metric": "fleet_disagg_<headline>", "value": N}
-line per headline (ttft_p99 / decode_p99_per_token / migration_p50)
-for perf_sentinel --record.
+line per headline (ttft_p99 / decode_p99_per_token / migration_p50),
+so a reader can take each headline on its own.
 
 Per-role kill drill (--disagg-drill prefill|decode): kill -9 the
 replica of that role mid-stream under disaggregated load; zero lost,
@@ -33,7 +38,7 @@ cross-process edge:
   {"metric": "fleet_disagg_drill_<role>", "lost": 0, "mismatched": 0,
    "re_prefills": N, "migration_edge_in_trace": true, ...}
 
-Methodology (PERF.md appendix "Multi-replica serving"):
+Methodology:
 - Replicas are REAL subprocesses, each wrapping a prewarmed
   InferenceEngine over a deterministic tiny MLP (seeded weights, so
   every replica — and the local reference — computes identical
@@ -332,8 +337,7 @@ def main_disagg(args):
         if d.get("engine_ttft_p99_ms") and m.get("engine_ttft_p99_ms")
         else None)
     print(json.dumps(out))
-    # companion one-metric lines so perf_sentinel --record can
-    # baseline each disagg headline independently
+    # companion one-metric lines: each disagg headline on its own
     for metric, value in (
             ("fleet_disagg_ttft_p99", d["ttft_p99_ms"]),
             ("fleet_disagg_decode_p99_per_token",
@@ -674,7 +678,7 @@ def main_drill(args):
         stats = router.stats()
         log(f"post-kill stats: {stats}")
 
-        # per-second p99 trace (the PERF.md kill-one-replica figure)
+        # per-second p99 trace (the kill-one-replica figure)
         t_start = trace[0][0] if trace else time.perf_counter()
         buckets = {}
         for t, ms in trace:
