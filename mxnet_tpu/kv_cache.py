@@ -151,10 +151,17 @@ def state_pool_shape(slots: int, head_state) -> tuple:
     the second kind of per-stream state, beside the K/V pages.  What a
     head's matrix is, is the mixer's to say (``models/hybrid_lm.py
     mixer_state``): a kda head's (d_v, d_k), held transposed; a mamba2
-    head's (head_dim, d_state), not square (``ops/pallas_hybrid.py``).
-    A stream holds ONE slot from admission to retirement, whatever its
-    length; slot 0 is scratch (padded batch rows land there), so
-    ``slots`` = live streams + 1."""
+    head's (head_dim, d_state), not square; a retention KV head's packed
+    symmetric square, ((head_dim / 2 + 1) * head_dim, head_dim): block
+    ``delta`` of head_dim rows holds the pairs of key lanes ``delta``
+    apart, transposed (value lane by key lane) — 8,320 rows at 128,
+    shared by the KV head's query heads (``ops/pallas_hybrid.py``).  The
+    same shape serves a mixer's AUXILIARY array where that is no
+    convolution's tail (:func:`conv_tail_shape`) but another stack of
+    matrices: the retention mixer's normaliser, (kv_heads, head_dim,
+    head_dim).  A stream holds ONE slot from admission to retirement,
+    whatever its length; slot 0 is scratch (padded batch rows land
+    there), so ``slots`` = live streams + 1."""
     heads, rows, lanes = (int(n) for n in head_state)
     return (int(slots), heads, rows, lanes)
 

@@ -47,6 +47,21 @@ MIXER and its own FFN:
   and C shared by all heads — ONE group; the gate BEFORE the output
   norm); its slot: the (heads, head_dim, d_state)
   float32 state and the convolution's last inputs;
+* mixer ``retention``: power retention — gated linear attention of
+  ``degree`` 2 (the one built) with grouped queries, ``heads`` query
+  heads over ``kv_heads`` KV heads of ``head_dim``: a query weighs a key
+  by ``(q.k / sqrt(head_dim))^2`` times the gates between them (one
+  log-sigmoid gate a token and KV head, from the block's normalised
+  input, with a float32 bias, parameter ``layer{i}_g_bias``) and
+  divides by the sum of its weights; ``qk_norm`` as an attention
+  mixer's, ``rope_theta`` required (it always rotates).  It keeps NO page: its slot is, a KV head, the
+  float32 symmetric square of the keys against the values — packed by
+  lane rolls and held transposed, ``(head_dim / 2 + 1) * head_dim`` rows
+  of ``head_dim`` lanes (``ops/hybrid.py retention_phi``: 8,320 rows at
+  128 where the Kronecker square would take 16,384), read by all of the
+  KV head's query heads — and, its auxiliary array, the normaliser
+  ``Z = sum decay k k^T`` (``head_dim`` x ``head_dim``): no convolution,
+  no tail;
 * ffn ``dense``: a gated (SiLU) feed-forward of ``width``;
 * ffn ``moe``: ``experts`` routed experts of ``width``, ``top_k`` a
   token (``score``: ``sigmoid`` — normalised sigmoid scores, the
@@ -66,7 +81,8 @@ MIXER and its own FFN:
 
 :class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
 takes: from the layer list it derives the feeds, the pools (pages for
-attention and mla layers, slots for kda and mamba2 layers) and the
+attention and mla layers, slots for kda, mamba2 and retention layers)
+and the
 prefill and decode symbols (a prefill's logits are those of each
 prompt's LAST row alone, (B, 1, vocab): the engine samples one token).
 Data of the spec too: ``embed_scale`` (token rows), ``residual_scale``
@@ -79,7 +95,8 @@ run's ``scope_time`` line groups device time by them), after ``layer{i}_``:
 ``norm1 norm2``; attention ``q k v q_norm k_norm attn gate o``; mla ``q_down
 q_norm q_up kv_down kv_norm``, ``kv_up`` (prefill) or ``absorb_k absorb_v``
 (decode), ``attn o``; kda ``qkv conv a_down a_up beta kda g_down g_up onorm
-o``; mamba2 ``in conv mamba2 onorm out``; FFNs ``ffn_* moe shared_*``; and
+o``; mamba2 ``in conv mamba2 onorm out``; retention ``q k v q_norm
+k_norm g retention o``; FFNs ``ffn_* moe shared_*``; and
 ``tok_embed final_norm last_row head``.  Equations: ``benchmark/reference/``.
 """
 
@@ -97,6 +114,8 @@ MIXERS = {
     "kda": ("kind", "heads", "head_dim", "conv", "neg_eigval"),
     "mamba2": ("kind", "heads", "head_dim", "d_state", "groups", "conv",
                "conv_bias"),
+    "retention": ("kind", "heads", "kv_heads", "head_dim", "degree",
+                  "rope_theta", "qk_norm"),
 }
 FFNS = {
     "dense": ("kind", "width"),
@@ -126,16 +145,36 @@ def _gated_ffn(h, width, d_model, name):
 
 
 def mixer_state(m):
-    """What a recurrent mixer keeps a stream: ((heads, rows, lanes) of
-    its float32 state, the channels its short convolution carries); None
-    for a mixer whose state is pages."""
+    """What a recurrent mixer keeps a stream, both float32: ((heads,
+    rows, lanes) of its state, the shape a slot of its AUXILIARY array —
+    its short convolution's tail, ``kv_cache.conv_tail_shape``, or, for
+    a retention mixer, which has no convolution, the normaliser's
+    (KV heads, head_dim, head_dim)); None for a mixer whose state is
+    pages."""
+    from ..kv_cache import conv_tail_shape
+
+    if m["kind"] == "retention":
+        from ..ops.hybrid import retention_rows
+
+        Hkv, D = int(m.get("kv_heads", m["heads"])), int(m["head_dim"])
+        return (Hkv, retention_rows(D), D), (Hkv, D, D)
     if m["kind"] == "kda":
         H, D = int(m["heads"]), int(m["head_dim"])
-        return (H, D, D), 3 * H * D
+        return (H, D, D), conv_tail_shape(1, int(m["conv"]), 3 * H * D)[1:]
     if m["kind"] == "mamba2":
         H, P, N = int(m["heads"]), int(m["head_dim"]), int(m["d_state"])
-        return (H, P, N), H * P + 2 * N
+        return (H, P, N), conv_tail_shape(1, int(m["conv"]),
+                                          _mamba2_channels(m))[1:]
     return None
+
+
+def _mamba2_channels(m):
+    """What a mamba2 mixer's convolution carries: x, B and C."""
+    return int(m["heads"]) * int(m["head_dim"]) + 2 * int(m["d_state"])
+
+
+# the auxiliary pool's name after ``layer{i}_``, by mixer kind
+_AUX_POOL = {"kda": "tail", "mamba2": "tail", "retention": "zsum"}
 
 
 class HybridSpec:
@@ -188,7 +227,18 @@ class HybridSpec:
                     f"layer {i}: moe act {f.get('act')!r} must be one of "
                     f"{tuple(EXPERT_ACTS)} and router_input "
                     f"{f.get('router_input')!r} one of {ROUTER_INPUTS}")
-            if m["kind"] == "attention" and \
+            if m["kind"] == "retention" and (
+                    int(m.get("degree", 2)) != 2
+                    or int(m["head_dim"]) % 2
+                    or not float(m.get("rope_theta") or 0) > 0):
+                raise MXNetError(
+                    f"layer {i}: power retention of degree "
+                    f"{m.get('degree')!r} over heads of {m['head_dim']} "
+                    f"lanes, rope_theta {m.get('rope_theta')!r}: degree "
+                    f"2 over an even head_dim is built (the state of "
+                    f"degree p grows as head_dim^p / p!), and it rotates: "
+                    f"rope_theta is required, positive")
+            if m["kind"] in ("attention", "retention") and \
                     int(m["heads"]) % int(m.get("kv_heads", m["heads"])):
                 raise MXNetError(
                     f"layer {i}: {m['kv_heads']} KV heads do not divide "
@@ -268,10 +318,16 @@ class HybridSpec:
 
     # -- what the engine asks (the protocol: DecodeEngine's docstring) ---
     phases = ("prefill", "decode")    # no suffix-prefill, no verify symbol
-    kv_dtypes = ("fp32", "bf16")      # no quantized pages
     positions = None                  # no learned positions: max_len given
     partition_rules = None            # no tp/pp placement
     lora_width = 0                    # no LoRA epilogue
+
+    @property
+    def kv_dtypes(self):
+        """No quantized pages; and a retention layer's degree-2 state
+        has no bfloat16 form (its running sums are float32 or wrong)."""
+        return ("fp32",) if "retention" in self.mixer_kinds() \
+            else ("fp32", "bf16")
 
     @property
     def num_layers(self):
@@ -312,11 +368,15 @@ class HybridSpec:
 
     def pool_kinds(self, kv_dtype="fp32"):
         """The kind of each of :meth:`pools`' rows: a recurrent layer's
-        state is what ``return_state`` reads, its convolution's tail
-        rides in the same slot."""
+        state is what ``return_state`` reads — a retention layer's
+        normaliser with it, which is the other half of its sums; a
+        convolution's tail rides in the same slot, read by the programs
+        alone."""
         out = []
         for k, ly in zip(self.cache_kinds(), self.layers):
-            out += ["slots", "slots_aux"] if k == "slots" else \
+            aux = "slots" if ly["mixer"]["kind"] == "retention" \
+                else "slots_aux"
+            out += ["slots", aux] if k == "slots" else \
                 [k] if ly["mixer"]["kind"] == "mla" else [k, k]
         return tuple(out) + (("counters",) if self.has_moe() else ())
 
@@ -328,8 +388,8 @@ class HybridSpec:
         them for this family); a windowed layer's pools hold
         ``window_blocks`` pages, a page-id space of their own; slot
         state is float32 whatever the model's."""
-        from ..kv_cache import (conv_tail_shape, latent_pool_shape,
-                                state_pool_shape, value_pool_shape)
+        from ..kv_cache import (latent_pool_shape, state_pool_shape,
+                                value_pool_shape)
 
         out = []
         for i, ly in enumerate(self.layers):
@@ -345,12 +405,11 @@ class HybridSpec:
                 out += [(f"layer{i}_kpool", shape, dtype, 0),
                         (f"layer{i}_vpool", shape, dtype, 0)]
             else:
-                head_state, channels = mixer_state(m)
+                head_state, aux = mixer_state(m)
                 out += [(f"layer{i}_state",
                          state_pool_shape(slots, head_state), "float32", 0),
-                        (f"layer{i}_tail",
-                         conv_tail_shape(slots, int(m["conv"]), channels),
-                         "float32", 0)]
+                        (f"layer{i}_{_AUX_POOL[m['kind']]}",
+                         (int(slots),) + aux, "float32", 0)]
         if self.has_moe():
             out.append((COUNTERS, (4,), "int32", 0))
         return out
@@ -375,10 +434,11 @@ class HybridSpec:
                    **{k: d[k] for k in cls._SCALARS if k in d})
 
 
-def _attention(spec, h, i, m, step, feeds):
+def _grouped_qkv(spec, h, name, m):
+    """(q, k, v, heads, KV heads) of a mixer with grouped queries: the
+    three projections and, under ``qk_norm``, the per-head norms."""
     H, Hkv, D = int(m["heads"]), int(m.get("kv_heads", m["heads"])), \
         int(m["head_dim"])
-    name = f"layer{i}"
     q = _fc(h, H * D, f"{name}_q")
     k = _fc(h, Hkv * D, f"{name}_k")
     v = _fc(h, Hkv * D, f"{name}_v")
@@ -387,6 +447,13 @@ def _attention(spec, h, i, m, step, feeds):
         # what it is given, so the norm comes first
         q = _norm(q, f"{name}_q_norm", spec.norm_eps, num_groups=H)
         k = _norm(k, f"{name}_k_norm", spec.norm_eps, num_groups=Hkv)
+    return q, k, v, H, Hkv
+
+
+def _attention(spec, h, i, m, step, feeds):
+    name = f"layer{i}"
+    q, k, v, H, Hkv = _grouped_qkv(spec, h, name, m)
+    D = int(m["head_dim"])
     op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
     attrs = {"scale": float(m["scale"])} if m.get("scale") else {}
     args = [q, k, v, sym.Variable(f"{name}_kpool"),
@@ -476,7 +543,7 @@ def _kda(spec, h, i, m, step, feeds):
 
 
 def _mamba2(spec, h, i, m, step, feeds):
-    (H, P, N), channels = mixer_state(m)
+    (H, P, N), channels = mixer_state(m)[0], _mamba2_channels(m)
     name = f"layer{i}"
     # one fused input map: gate | x, B, C | dt
     widths = (H * P, channels, H)
@@ -504,8 +571,22 @@ def _mamba2(spec, h, i, m, step, feeds):
     return _fc(out, spec.d_model, f"{name}_out"), [rec[1], conv[1]]
 
 
+def _retention(spec, h, i, m, step, feeds):
+    name = f"layer{i}"
+    q, k, v, H, Hkv = _grouped_qkv(spec, h, name, m)
+    # the log-gate's raw projection, one a token and KV head; its bias
+    # is added in the op, in float32
+    op = sym.RetentionStep if step else sym.RetentionChunk
+    rec = op(q, k, v, _fc(h, Hkv, f"{name}_g"),
+             sym.Variable(f"{name}_g_bias"), sym.Variable(f"{name}_state"),
+             sym.Variable(f"{name}_zsum"), feeds["slots"], feeds["lengths"],
+             feeds["positions"], num_heads=H, kv_heads=Hkv,
+             rope_theta=float(m["rope_theta"]), name=f"{name}_retention")
+    return _fc(rec[0], spec.d_model, f"{name}_o"), [rec[1], rec[2]]
+
+
 _MIXER_BUILDERS = {"attention": _attention, "mla": _mla, "kda": _kda,
-                   "mamba2": _mamba2}
+                   "mamba2": _mamba2, "retention": _retention}
 
 
 def _ffn(spec, h, x_in, i, f, step, feeds, counters):

@@ -926,6 +926,261 @@ def _mamba2_step(op_ctx, attrs, inputs, aux):
 
 
 # ---------------------------------------------------------------------------
+# Power retention: gated degree-2 linear attention over grouped KV heads
+# ---------------------------------------------------------------------------
+
+RETENTION_EPS = 1e-6    # added to a query's summed weights
+
+
+def retention_rows(D):
+    """Rows of a KV head's packed state: ``(D / 2 + 1) * D`` — 8,320 at
+    D = 128, where the symmetric square of D lanes needs D (D + 1) / 2 =
+    8,256 and the Kronecker form would take D^2 = 16,384."""
+    if D % 2:
+        raise MXNetError(f"power retention: head_dim {D} is packed in "
+                         f"pairs of lanes half a head apart; it is odd")
+    return (D // 2 + 1) * D
+
+
+def retention_weights(D):
+    """(D / 2 + 1,) float32: what multiplies block ``delta`` of
+    :func:`retention_phi` — 1 where the block holds every pair twice or
+    a lane with itself (``delta`` D / 2 and 0), sqrt 2 between."""
+    w = jnp.full((D // 2 + 1,), 2.0 ** 0.5, jnp.float32)
+    return w.at[0].set(1.0).at[D // 2].set(1.0)
+
+
+def retention_phi(x):
+    """The symmetric-power expansion of degree 2, PACKED BY LANE ROLLS:
+    x (..., D) -> (..., D / 2 + 1, D) float32 with ``phi[delta, a] =
+    w[delta] * x[a] * x[(a + delta) % D]``.  Every unordered pair of
+    lanes less than half a head apart stands once under sqrt 2, a lane
+    with itself once under 1 and the pairs exactly half a head apart
+    twice under 1, so ``sum(phi(x) * phi(y)) = (x . y)^2`` over all
+    (D / 2 + 1) * D entries, the D / 2 doubled ones included: the block
+    ``delta`` is one roll of the lanes and one product, which is what a
+    vector unit does well, for 64 entries more than the least."""
+    D = x.shape[-1]
+    xf = x.astype(jnp.float32)
+    rolled = jnp.stack([jnp.roll(xf, -d, axis=-1)
+                        for d in range(D // 2 + 1)], axis=-2)
+    return xf[..., None, :] * rolled * retention_weights(D)[:, None]
+
+
+def retention_chunked(q, k, v, la):
+    """Power retention over a prompt from the zero state, regrouped in
+    chunks of ``Q`` tokens.  With l the running sum of the log-gate
+    inside a chunk and c = D^-1/2: inside the chunk the attention form,
+    ``A = (c Q K^T)^2 . exp(l_i - l_j)[i >= j]``; from the chunks before
+    it the state, ``exp(l_i) phi(q_i) S_prev`` over ``exp(l_i) q_i^T
+    Z_prev q_i``; ``y = (A V + ...) / (rowsum A + ... + eps)``; a
+    chunk's own state ``sum_j exp(l_Q - l_j) phi(k_j) v_j^T`` and
+    normaliser ``sum_j exp(l_Q - l_j) c k_j k_j^T``, carried on with
+    ``exp(l_Q)``.  Every exponent is a sum of log-gates over a span of
+    tokens, <= 0.
+
+    q (B, T, Hkv, G, D), k, v (B, T, Hkv, D) in the model's type (the
+    products run in it, sums float32), dead positions' k and v zero; la
+    (B, T, Hkv) float32, 0 at dead positions -> (y (B, T, Hkv, G, D)
+    float32, the last state (B, Hkv, R, D) float32 — row delta * D + l,
+    lane a: the packed block delta TRANSPOSED, value lane l by key lane
+    a — and the last normaliser (B, Hkv, D, D) float32)."""
+    from .pallas_hybrid import RETENTION_CHUNK as Q
+
+    B, T, Hkv, G, D = q.shape
+    R = retention_rows(D)
+    c = float(D) ** -0.5
+    pad = -T % Q
+    if pad:        # gate 1, k = v = 0: the state stands still
+        q = jnp.pad(q, ((0, 0), (0, pad)) + ((0, 0),) * 3)
+        k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                for t in (k, v))
+        la = jnp.pad(la, ((0, 0), (0, pad), (0, 0)))
+    nc = (T + pad) // Q
+    f32, mm = jnp.float32, q.dtype
+    prec = HI if mm == jnp.float32 else None
+    qc = jnp.moveaxis(q.reshape(B, nc, Q, Hkv, G, D), 1, 0)
+    kc, vc = (jnp.moveaxis(t.reshape(B, nc, Q, Hkv, D), 1, 0)
+              for t in (k, v))
+    cum = jnp.moveaxis(jnp.cumsum(la.reshape(B, nc, Q, Hkv), axis=2), 1, 0)
+    causal = jnp.tril(jnp.ones((Q, Q), bool))
+
+    def chunk(carry, xs):
+        S, Z = carry                  # (B, Hkv, R, D), (B, Hkv, D, D)
+        qq, kq, vq, l = xs
+        lh = jnp.moveaxis(l, 2, 1)                        # (B, Hkv, Q)
+        decay = jnp.exp(jnp.where(
+            causal, lh[..., :, None] - lh[..., None, :], -jnp.inf))
+        s = c * jnp.einsum("bijgd,bsjd->bjgis", qq, kq, precision=prec,
+                           preferred_element_type=f32)
+        a = s * s * decay[:, :, None]
+        num = jnp.einsum("bjgis,bsjd->bijgd", a.astype(mm), vq,
+                         precision=prec, preferred_element_type=f32)
+        den = jnp.moveaxis(jnp.sum(a, axis=-1), 3, 1)     # (B, Q, Hkv, G)
+        # what the chunks before left: phi(q) S and q^T Z q, decayed
+        qf = qq.astype(f32)
+        pq = (c * retention_phi(qf)).astype(mm)
+        St = S.reshape(B, Hkv, R // D, D, D).astype(mm)   # [delta, l, a]
+        el = jnp.exp(l)[..., None]                        # (B, Q, Hkv, 1)
+        num = num + el[..., None] * jnp.einsum(
+            "bijgra,bjrla->bijgl", pq, St, precision=prec,
+            preferred_element_type=f32)
+        den = den + el * c * jnp.einsum(
+            "bijgd,bjde,bijge->bijg", qf, Z, qf, precision=HI)
+        tot = l[:, -1]                                    # (B, Hkv)
+        wk = jnp.exp(tot[:, None] - l)[..., None]         # (B, Q, Hkv, 1)
+        kf = kq.astype(f32)
+        pk_ = (c * retention_phi(kf)).astype(mm)          # (B,Q,Hkv,r,D)
+        vd = (wk * vq.astype(f32)).astype(mm)
+        e = jnp.exp(tot)[:, :, None, None]
+        S = e * S + jnp.einsum(
+            "bsjl,bsjra->bjrla", vd, pk_, precision=prec,
+            preferred_element_type=f32).reshape(B, Hkv, R, D)
+        Z = e * Z + c * jnp.einsum("bsjd,bsje->bjde", wk * kf, kf,
+                                   precision=HI)
+        return (S, Z), num / (den[..., None] + RETENTION_EPS)
+
+    (S, Z), y = lax.scan(
+        chunk, (jnp.zeros((B, Hkv, R, D), f32),
+                jnp.zeros((B, Hkv, D, D), f32)), (qc, kc, vc, cum))
+    y = jnp.moveaxis(y, 0, 1).reshape(B, T + pad, Hkv, G, D)
+    return y[:, :T], S, Z
+
+
+def retention_step(q, k, v, a, S, Z):
+    """One token of the recurrence, float32 throughout: q (B, Hkv, G,
+    D), k, v (B, Hkv, D), a (B, Hkv) the gate; S (B, Hkv, R, D) and Z
+    (B, Hkv, D, D) as :func:`retention_chunked` leaves them -> (y (B,
+    Hkv, G, D), S, Z).  ``S <- a S + v (x) phi(c^1/2 k)``, ``Z <- a Z +
+    c k k^T``, ``y = phi(c^1/2 q) S / (c q^T Z q + eps)``."""
+    B, Hkv, G, D = q.shape
+    c = float(D) ** -0.5
+    pk_ = c * retention_phi(k)                            # (B, Hkv, r, D)
+    St = S.reshape(B, Hkv, -1, D, D)                      # [delta, l, a]
+    St = a[:, :, None, None, None] * St \
+        + v[:, :, None, :, None] * pk_[:, :, :, None, :]
+    Z = a[:, :, None, None] * Z + c * k[..., :, None] * k[..., None, :]
+    num = jnp.einsum("bjgra,bjrla->bjgl", c * retention_phi(q), St,
+                     precision=HI)
+    den = c * jnp.einsum("bjgd,bjde,bjge->bjg", q, Z, q, precision=HI)
+    return num / (den[..., None] + RETENTION_EPS), St.reshape(S.shape), Z
+
+
+_RETENTION_ARGS = ("query", "key", "value", "gate", "gate_bias",
+                   "state_pool", "norm_pool", "slots", "lengths",
+                   "positions")
+
+
+def _retention_infer(attrs, in_shapes):
+    q, pool, norm = in_shapes[0], in_shapes[5], in_shapes[6]
+    if q is None or pool is None or norm is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(q), tuple(pool), tuple(norm)], []
+
+
+_RETENTION_OUTS = ("output", "new_state_pool", "new_norm_pool")
+_RETENTION_DOC = (
+    "query (B, S, H*D), key, value (B, S, Hkv*D): q and k already "
+    "normalised where the model normalises them, rotated in here by "
+    "positions (B, S) under rope_theta (rotate-half); gate (B, S, "
+    "Hkv): the raw gate projection, gamma = log sigmoid(gate + "
+    "gate_bias) in float32 (gate_bias (Hkv,) float32); state_pool "
+    "(slots, Hkv, (D/2 + 1) * D, D) float32, a KV "
+    "head's packed symmetric state, block delta transposed "
+    "(retention_phi, retention_chunked); norm_pool (slots, Hkv, D, D) "
+    "float32: Z = sum of the decayed c k k^T, whose q^T Z q is the sum "
+    "of a query's weights; slots (B,) int32 (0 = scratch) -> output "
+    "(B, S, H*D) + both pools.  Query head i reads KV head i // "
+    "(H / Hkv).  With c = D^-1/2: a_ts = (c q_t.k_s)^2 exp(Gamma_t - "
+    "Gamma_s), y_t = sum_s a_ts v_s / (sum_s a_ts + eps); as a "
+    "recurrence S_t = e^gamma_t S_(t-1) + phi(k_t) v_t^T, y_t = "
+    "phi(q_t)^T S_t / (q_t^T Z_t q_t + eps), eps = RETENTION_EPS.  The "
+    "state and every sum float32.  attrs: num_heads, kv_heads, "
+    "rope_theta")
+
+
+def _retention_inputs(attrs, inputs):
+    """(q (B, S, Hkv, G, D), k, v (B, S, Hkv, D) rotated, in the model's
+    type; gamma (B, S, Hkv) float32; both pools; slots; lengths)."""
+    q, k, v, g, bias, pool, norm, slots, lengths, positions = inputs
+    H, Hkv = _gqa_heads(attrs, q, k)
+    theta = attr_float(attrs["rope_theta"], 0.0)
+    q = rotate_half(q, positions, theta, H)
+    k = rotate_half(k, positions, theta, Hkv)
+    B, S, _ = q.shape
+    D = q.shape[-1] // H
+    if tuple(pool.shape[1:]) != (Hkv, retention_rows(D), D) \
+            or tuple(norm.shape[1:]) != (Hkv, D, D):
+        raise MXNetError(
+            f"power retention: a slot of the state pool "
+            f"{tuple(pool.shape)} is not ({Hkv}, {retention_rows(D)}, "
+            f"{D}) (KV heads, packed rows, head_dim), or one of the "
+            f"normaliser's {tuple(norm.shape)} not ({Hkv}, {D}, {D})")
+    gamma = jax.nn.log_sigmoid(g.astype(jnp.float32)
+                               + bias.astype(jnp.float32))
+    return (q.reshape(B, S, Hkv, H // Hkv, D), k.reshape(B, S, Hkv, D),
+            v.reshape(B, S, Hkv, D), gamma, pool, norm,
+            slots.astype(jnp.int32), lengths.astype(jnp.int32))
+
+
+@register("RetentionChunk", arg_names=_RETENTION_ARGS,
+          out_names=_RETENTION_OUTS, infer_shape=_retention_infer,
+          doc="Power retention over a (padded) prompt from the zero "
+              "state, in the chunk form (retention_chunked; on TPU the "
+              "kernel pallas_hybrid.retention_chunk, which stops at "
+              "lengths[b]); the state and the normaliser after position "
+              "lengths[b] - 1 are written to the slot.  "
+              + _RETENTION_DOC)
+def _retention_chunk(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+
+    q, k, v, la, pool, norm, slots, n = _retention_inputs(attrs, inputs)
+    B, T, Hkv, G, D = q.shape
+    # a padded position leaves the state as it is: gate 1, k = v = 0
+    live = (jnp.arange(T)[None, :] < n[:, None])[..., None]
+    la = jnp.where(live, la, 0.0)
+    k = jnp.where(live[..., None], k, jnp.zeros((), k.dtype))
+    v = jnp.where(live[..., None], v, jnp.zeros((), v.dtype))
+    if ph.retention_enabled(D):
+        y, last, z = ph.retention_chunk(
+            q.reshape(B, T, Hkv * G * D), k.reshape(B, T, Hkv * D),
+            v.reshape(B, T, Hkv * D), la, n)
+    else:
+        y, last, z = retention_chunked(q, k, v, la)
+    return [y.reshape(B, T, Hkv * G * D).astype(inputs[0].dtype),
+            pool.at[slots].set(last.astype(pool.dtype)),
+            norm.at[slots].set(z.astype(norm.dtype))]
+
+
+@register("RetentionStep", arg_names=_RETENTION_ARGS,
+          out_names=_RETENTION_OUTS, infer_shape=_retention_infer,
+          doc="Power retention for ONE token per stream against the "
+              "slot's state and normaliser, updated in place (S = 1; a "
+              "padded row sits on slot 0).  " + _RETENTION_DOC)
+def _retention_step(op_ctx, attrs, inputs, aux):
+    from . import pallas_hybrid as ph
+
+    q, k, v, la, pool, norm, slots, _ = _retention_inputs(attrs, inputs)
+    B, S, Hkv, G, D = q.shape
+    if S != 1:
+        raise MXNetError(f"RetentionStep feeds ONE position a step; got "
+                         f"query {tuple(inputs[0].shape)}")
+    f32 = jnp.float32
+    qf, kf, vf = (t[:, 0].astype(f32) for t in (q, k, v))
+    a = jnp.exp(la[:, 0])
+    if ph.retention_enabled(D):
+        y, pool, norm = ph.retention_step(
+            qf, kf, vf, a, pool.astype(f32), norm.astype(f32), slots)
+    else:
+        y, st, z = retention_step(qf, kf, vf, a, pool[slots].astype(f32),
+                                  norm[slots].astype(f32))
+        pool = pool.at[slots].set(st.astype(pool.dtype))
+        norm = norm.at[slots].set(z.astype(norm.dtype))
+    return [y.reshape(B, 1, Hkv * G * D).astype(inputs[0].dtype), pool,
+            norm]
+
+
+# ---------------------------------------------------------------------------
 # MoEFFN: routed experts, the share held here
 # ---------------------------------------------------------------------------
 

@@ -2,8 +2,9 @@
 (``models/hybrid_lm.py``): the KDA recurrence, one token against a
 stream's state (``kda_step``) and a whole prompt in the chunk (WY) form
 (``kda_chunk``); the Mamba-2 recurrence the same two ways
-(``mamba2_step``, ``mamba2_chunk``: their own section below); and the
-grouped matmuls of the routed experts (``moe_gmm_gate_up``,
+(``mamba2_step``, ``mamba2_chunk``: their own section below); power retention the same two
+ways (``retention_step``, ``retention_chunk``: their own section); and
+the grouped matmuls of the routed experts (``moe_gmm_gate_up``,
 ``moe_gmm_down``).  Helpers and conventions are
 ``pallas_kernels``'s: every ``pallas_call`` carries a ``name=``, which is
 what a device trace shows.
@@ -81,6 +82,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .hybrid import RETENTION_EPS
 from .pallas_kernels import (_VMEM_LIMIT, _compiler_params, _interpret,
                              _vmem_spec)
 
@@ -680,6 +682,321 @@ def mamba2_chunk(dx, xbc, la):
         name="mamba2_chunk_scan",
     )(dx, bm, cm, cum)
     return y[:, :live], last
+
+
+# ---------------------------------------------------------------------------
+# Power retention (``ops/hybrid.py`` RetentionChunk / RetentionStep): gated
+# degree-2 linear attention, ONE state a KV head read by its G query
+# heads.  The state is the symmetric square of the key, packed by lane
+# rolls (``ops/hybrid.py retention_phi``): block delta of D / 2 + 1 holds
+# ``w k[a] k[(a + delta) % D]`` on lane a, and is held TRANSPOSED —
+# ``St[delta]`` (D, D) with the VALUE's lane l on the rows and the key's
+# lane a on the lanes, a slot's (D / 2 + 1) * D rows of D lanes back to
+# back — so that phi is always a ROW: one roll of the lanes and one
+# product, broadcast down the rows, and a read of the state is a sum over
+# lanes.  The normaliser is ``Z = sum decay c k k^T`` (D, D): ``q^T Z q``
+# is the sum of a query's weights, 64 KB a KV head beside the state's
+# 4.26 MB.
+#
+# ``retention_step``: one token a stream on the VPU in float32, grid
+# (rows, KV heads): a head's whole state is one block in, one block out
+# (aliased, through the ``slots`` scalar operand), ``St <- a St + v_col
+# phi(k)_row`` and ``acc_i += St phi(q_i)_row`` a query head as it passes
+# — 3 + 2 G operations a register for 2 x 4.26 MB of traffic: the copies
+# bind.  The D / 2 + 1 rows of phi, a query head each, are made once a
+# head and broadcast down a register in VMEM; the walk is value rows
+# outside (G accumulators stay in registers), blocks inside.
+#
+# ``retention_chunk``: a prompt in chunks of ``RETENTION_CHUNK`` tokens
+# (``ops/hybrid.py retention_chunked``), grid (rows, KV heads, chunks),
+# the float32 state carried chunk to chunk in VMEM.  A chunk's own
+# tokens in the attention form, a query head at a time: ``(c Q K^T)^2 .
+# decay`` (the decay mask from the running log-gate, one a KV head), its
+# row sums, its product with V.  The chunks before it through the state,
+# the G query heads STACKED as rows: block by block ``phi_delta(Q)`` (one
+# roll, one product, in float32, rounded to the model's type) times
+# ``St[delta]^T``, and ``q^T Z q`` as one more product.  Then the state:
+# ``St[delta] <- e^(l_Q) St[delta] + (decayed V)^T phi_delta(K)``.  A
+# chunk that starts at or past ``lengths[b]`` does nothing (its rows
+# leave as 0): the first chunk of a prompt skips the state's part, which
+# is zero.  Products in the model's type with float32 sums, the state
+# float32; every exponent a sum of log-gates over a span, <= 0.
+# ---------------------------------------------------------------------------
+
+RETENTION_CHUNK = 256   # tokens of a chunk
+
+
+def retention_enabled(D):
+    """The kernels take the op?  On the chip a head of whole 128-lane
+    rows (another width gets the lax body); interpreted, any."""
+    from . import pallas_kernels as pk
+
+    return pk.enabled() and (_interpret() or D % 128 == 0)
+
+
+def _phi_scale(delta, D):
+    """``w[delta] * D^-1/2`` (``ops/hybrid.py retention_weights``)."""
+    one = (delta == 0) | (delta == D // 2)
+    return jnp.where(one, 1.0, 2.0 ** 0.5).astype(jnp.float32) \
+        * (float(D) ** -0.5)
+
+
+def _lane_roll(x, delta, D):
+    """``out[..., a] = x[..., (a + delta) % D]``."""
+    return pltpu.roll(x, (D - delta) % D, x.ndim - 1)
+
+
+def _retention_step_kernel(slots_ref, a_ref, x_ref, v_ref, s_ref, z_ref,
+                           y_ref, so_ref, zo_ref, phi_scr, vcol_scr, *,
+                           G, D):
+    del slots_ref  # used by the index maps
+    b, j = pl.program_id(0), pl.program_id(1)
+    f32 = jnp.float32
+    nd = D // 2 + 1
+    c = float(D) ** -0.5
+    a = a_ref[b, j]                    # the gate: a scalar, from SMEM
+    eye = _eye(D)
+    x = x_ref[0, 0]                    # rows: k, q_0 .. q_(G-1), zeros
+    k_row = x[0:1]
+    k_col = jnp.sum(eye * k_row, axis=1, keepdims=True)
+    v_col = jnp.sum(eye * v_ref[0, 0], axis=1, keepdims=True)
+    vcol_scr[...] = jnp.broadcast_to(v_col, (D, D))
+    # the normaliser, and each query head's sum of weights
+    z = a * z_ref[0, 0] + (c * k_col) * k_row
+    zo_ref[0, 0] = z
+    dens = []
+    for i in range(G):
+        q_row = x[1 + i:2 + i]
+        q_col = jnp.sum(eye * q_row, axis=1, keepdims=True)
+        dens.append(c * jnp.sum(jnp.sum(z * q_row * q_col, axis=1,
+                                        keepdims=True),
+                                axis=0, keepdims=True))      # (1, 1)
+
+    # phi, every block: one roll and one product for all the rows of x;
+    # each row broadcast down a register, to be read as it is
+    def make(delta, carry):
+        p = x * _lane_roll(x, delta, D) * _phi_scale(delta, D)
+        for n in range(G + 1):
+            phi_scr[delta, n] = jnp.broadcast_to(p[n:n + 1], (8, D))
+        return carry
+
+    jax.lax.fori_loop(0, nd, make, 0)
+    eye8 = _eye(8)
+
+    un = _divisor_at_most(nd, 5)       # blocks a turn of the loop
+    for L in range(D // 8):            # 8 value rows at a time
+        vc = vcol_scr[L * 8:(L + 1) * 8]
+
+        def body(turn, accs, L=L, vc=vc):
+            for u in range(un):
+                delta = turn * un + u
+                at = pl.multiple_of(delta * D + L * 8, 8)
+                st = a * s_ref[0, 0, pl.ds(at, 8), :] \
+                    + vc * phi_scr[delta, 0]
+                so_ref[0, 0, pl.ds(at, 8), :] = st
+                accs = tuple(acc + st * phi_scr[delta, 1 + i]
+                             for i, acc in enumerate(accs))
+            return accs
+
+        accs = jax.lax.fori_loop(
+            0, nd // un, body,
+            tuple(jnp.zeros((8, D), f32) for _ in range(G)))
+        for i, acc in enumerate(accs):
+            num = jnp.sum(acc, axis=1, keepdims=True)        # (8, 1)
+            y_col = num / (dens[i] + RETENTION_EPS)
+            # a column of 8 -> 8 lanes of the output's row
+            y_ref[0, 0, i:i + 1, L * 8:(L + 1) * 8] = jnp.sum(
+                eye8 * y_col, axis=0, keepdims=True)
+
+
+def retention_step(q, k, v, a, state, norm, slots):
+    """q (B, Hkv, G, D), k, v (B, Hkv, D), a (B, Hkv) the gate, all
+    float32 (q and k unscaled: the kernel applies D^-1/2 to phi); state
+    (S, Hkv, (D/2 + 1) * D, D) and norm (S, Hkv, D, D) float32; slots
+    (B,) int32 -> (y (B, Hkv, G, D) float32, both pools with the B
+    slots advanced).  The pools are aliased to their outputs: donated
+    under jit, the update is in place."""
+    B, Hkv, G, D = q.shape
+    R = state.shape[2]
+    rows = -(-(G + 1) // 8) * 8
+    x = jnp.concatenate([k[:, :, None], q], axis=2)
+    x = jnp.pad(x, ((0, 0), (0, 0), (0, rows - G - 1), (0, 0)))
+    at = lambda b, j, sl, a: (b, j, 0, 0)
+    slot = lambda b, j, sl, a: (sl[b], j, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(B, Hkv),
+        in_specs=[_vmem_spec((1, 1, rows, D), at),
+                  _vmem_spec((1, 1, 1, D), at),
+                  _vmem_spec((1, 1, R, D), slot),
+                  _vmem_spec((1, 1, D, D), slot)],
+        out_specs=[_vmem_spec((1, 1, G, D), at),
+                   _vmem_spec((1, 1, R, D), slot),
+                   _vmem_spec((1, 1, D, D), slot)],
+        scratch_shapes=[pltpu.VMEM((D // 2 + 1, G + 1, 8, D), jnp.float32),
+                        pltpu.VMEM((D, D), jnp.float32)])
+    y, state, norm = pl.pallas_call(
+        functools.partial(_retention_step_kernel, G=G, D=D),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
+                   jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct(norm.shape, norm.dtype)],
+        input_output_aliases={4: 1, 5: 2},
+        compiler_params=_compiler_params("arbitrary", "arbitrary",
+                                         vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="retention_step",
+    )(slots.astype(jnp.int32), a, x, v[:, :, None], state, norm)
+    return y, state, norm
+
+
+def _retention_chunk_kernel(n_ref, q_ref, k_ref, v_ref, l_ref, y_ref,
+                            s_ref, z_ref, s_scr, sm_scr, z_scr, q_scr,
+                            num_scr, *, G, D, nt):
+    b, h, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    f32 = jnp.float32
+    Q = k_ref.shape[1]
+    nd = D // 2 + 1
+    c = float(D) ** -0.5
+    mm = k_ref.dtype
+    hi = jax.lax.Precision.HIGHEST
+    prec = hi if mm == f32 else None
+
+    def dot(x, y, dims, precision=prec):
+        return jax.lax.dot_general(x, y, (dims, ((), ())),
+                                   precision=precision,
+                                   preferred_element_type=f32)
+
+    @pl.when(j == 0)
+    def _init():
+        s_scr[...] = jnp.zeros_like(s_scr)
+        z_scr[...] = jnp.zeros_like(z_scr)
+
+    live = j * Q < n_ref[b]
+
+    @pl.when(jnp.logical_not(live))
+    def _dead():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when(live)
+    def _chunk():
+        r = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
+        cc = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
+        l_row = l_ref[0, pl.ds(h, 1), :]                   # (1, Q): l_j
+        l_col = jnp.sum(jnp.where(r == cc, l_row, 0.0), axis=1,
+                        keepdims=True)                     # l_i
+        decay = jnp.where(r >= cc,
+                          jnp.exp(jnp.minimum(l_col - l_row, 0.0)), 0.0)
+        tot = jnp.sum(jnp.where(cc[:1] == Q - 1, l_row, 0.0), axis=1,
+                      keepdims=True)                       # (1, 1): l_Q
+        el = jnp.exp(l_col)                                # (Q, 1)
+        kq, vq = k_ref[0], v_ref[0]                        # (Q, D)
+        # the chunk's own tokens, a query head at a time
+        dens = []
+        for i in range(G):
+            qi = q_ref[0, :, i * D:(i + 1) * D]
+            s = c * dot(qi, kq, ((1,), (1,)))
+            w = s * s * decay
+            dens.append(jnp.sum(w, axis=1, keepdims=True))
+            num_scr[i * Q:(i + 1) * Q] = dot(w.astype(mm), vq,
+                                             ((1,), (0,)))
+            q_scr[i * Q:(i + 1) * Q] = qi.astype(f32)
+
+        # the chunks before it, through the state: G heads stacked
+        @pl.when(j > 0)
+        def _inter():
+            qs = q_scr[...]                                # (G Q, D)
+            els = jnp.concatenate([el] * G, axis=0)
+
+            def block(delta, carry):
+                p = (qs * _lane_roll(qs, delta, D)
+                     * _phi_scale(delta, D)).astype(mm)
+                num_scr[...] += els * dot(p, sm_scr[delta],
+                                          ((1,), (1,)))
+                return carry
+
+            jax.lax.fori_loop(0, nd, block, 0)
+
+        qz = dot(q_scr[...], z_scr[...], ((1,), (0,)), hi)
+        den_z = c * jnp.sum(qz * q_scr[...], axis=1, keepdims=True)
+        for i in range(G):
+            at = slice(i * Q, (i + 1) * Q)
+            den = dens[i] + el * den_z[at]
+            y_ref[0, :, i * D:(i + 1) * D] = (
+                num_scr[at] / (den + RETENTION_EPS)).astype(y_ref.dtype)
+
+        # the state the chunk leaves
+        kf = kq.astype(f32)
+        wk = jnp.exp(tot - l_col)                          # (Q, 1)
+        vd = (wk * vq.astype(f32)).astype(mm)
+        e = jnp.exp(tot)
+
+        def build(delta, carry):
+            p = (kf * _lane_roll(kf, delta, D)
+                 * _phi_scale(delta, D)).astype(mm)
+            at = pl.multiple_of(delta * D, D)
+            st = e * s_scr[pl.ds(at, D), :] + dot(vd, p, ((0,), (0,)))
+            s_scr[pl.ds(at, D), :] = st
+            sm_scr[delta] = st.astype(mm)
+            return carry
+
+        jax.lax.fori_loop(0, nd, build, 0)
+        z_scr[...] = e * z_scr[...] + c * dot(wk * kf, kf, ((0,), (0,)),
+                                              hi)
+
+    @pl.when(j == nt - 1)
+    def _last():
+        s_ref[0, 0] = s_scr[...]
+        z_ref[0, 0] = z_scr[...]
+
+
+def retention_chunk(q, k, v, la, lengths):
+    """A whole prompt from the zero state in the chunk form.
+
+    q (B, T, Hkv*G*D), k, v (B, T, Hkv*D) in the model's type, rotated,
+    KV head j's G query heads side by side; la (B, T, Hkv) float32: the
+    log-gate <= 0.  A padded position carries la 0 and k = v = 0, which
+    leaves the state as it is; T is padded in here with more such
+    positions up to whole chunks; lengths (B,) int32: a chunk at or past
+    it is not walked -> (y (B, T, Hkv*G*D) in q's type, the last state
+    (B, Hkv, (D/2 + 1) * D, D) and the last normaliser (B, Hkv, D, D),
+    float32)."""
+    B, live, Hkv = la.shape
+    D = k.shape[-1] // Hkv
+    G = q.shape[-1] // (Hkv * D)
+    Q = RETENTION_CHUNK
+    R = (D // 2 + 1) * D
+    pad = ((0, 0), (0, -live % Q), (0, 0))
+    q, k, v, la = (jnp.pad(t, pad) for t in (q, k, v, la))
+    T = la.shape[1]
+    nt = T // Q
+    # the running sum of the log-gate inside each chunk, a head's on a row
+    cum = jnp.cumsum(la.reshape(B, nt, Q, Hkv), axis=2)
+    cum = jnp.transpose(cum.reshape(B, T, Hkv), (0, 2, 1))
+    rows = lambda w: _vmem_spec((1, Q, w), lambda b, h, j, n: (b, j, h))
+    head = lambda r: _vmem_spec((1, 1, r, D), lambda b, h, j, n: (b, h, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(B, Hkv, nt),
+        in_specs=[rows(G * D), rows(D), rows(D),
+                  _vmem_spec((1, Hkv, Q), lambda b, h, j, n: (b, 0, j))],
+        out_specs=[rows(G * D), head(R), head(D)],
+        scratch_shapes=[pltpu.VMEM((R, D), jnp.float32),
+                        pltpu.VMEM((D // 2 + 1, D, D), k.dtype),
+                        pltpu.VMEM((D, D), jnp.float32),
+                        pltpu.VMEM((G * Q, D), jnp.float32),
+                        pltpu.VMEM((G * Q, D), jnp.float32)])
+    y, last, z = pl.pallas_call(
+        functools.partial(_retention_chunk_kernel, G=G, D=D, nt=nt),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((B, T, Hkv * G * D), q.dtype),
+                   jax.ShapeDtypeStruct((B, Hkv, R, D), jnp.float32),
+                   jax.ShapeDtypeStruct((B, Hkv, D, D), jnp.float32)],
+        compiler_params=_compiler_params("parallel", "parallel",
+                                         "arbitrary",
+                                         vmem_limit_bytes=_VMEM_LIMIT),
+        interpret=_interpret(),
+        name="retention_chunk",
+    )(lengths.astype(jnp.int32), q, k, v, cum)
+    return y[:, :live], last, z
 
 
 # ---------------------------------------------------------------------------

@@ -144,3 +144,48 @@ def test_peaks_unknown_kind_exits_by_name():
     with pytest.raises(SystemExit) as e:
         peaks.lookup("TPU v9000")
     assert "TPU v9000" in str(e.value) and "TPU v5 lite" in str(e.value)
+
+
+# what `reducers/family_kernel_roofline.py` asks of the newest family:
+# planted counters in, (operations, bytes) an execution out
+_BRUMBY = {"num_hidden_layers": 10, "num_attention_heads": 40,
+           "num_key_value_heads": 8, "head_dim": 128}
+
+
+@pytest.mark.parametrize("kernel, stats, per_layer", [
+    ("retention_step", {"steps": 50, "stream_steps": 600},
+     lambda f: f.retention_step(12.0, _BRUMBY)),
+    ("retention_step", {"steps": 50, "stream_steps": 425},
+     lambda f: f.retention_step(8.5, _BRUMBY)),
+    ("retention_chunk", {"prefills": 8, "prefill_tokens": 10240},
+     lambda f: f.retention_chunk(1280.0, 1, _BRUMBY, 2)),
+])
+def test_brumby_need_reads_the_planted_counters(kernel, stats, per_layer):
+    from benchmark.flops import brumby
+
+    ops, nbytes = brumby.need(kernel, stats, _BRUMBY, 2)
+    want_ops, want_bytes = per_layer(brumby)
+    assert (ops, nbytes) == (10 * want_ops, 10 * want_bytes)
+    assert ops > 0 and nbytes > 0
+
+
+@pytest.mark.parametrize("kernel, stats", [
+    ("retention_step", {}), ("retention_step", {"steps": 3}),
+    ("retention_chunk", {"prefills": 0, "prefill_tokens": 0})])
+def test_brumby_need_without_counters_reads_nothing(kernel, stats):
+    from benchmark.flops import brumby
+
+    assert brumby.need(kernel, stats, _BRUMBY, 2) is None
+
+
+def test_brumby_step_is_the_states_bytes_and_a_prompt_is_compute():
+    """The decode kernel's least time is its bytes (the state at the
+    NEEDED 8,256 rows, read and written), a prompt's its operations."""
+    from benchmark.flops import brumby
+
+    peak = peaks.lookup("TPU v5 lite")
+    ops, nbytes = brumby.retention_step(12, _BRUMBY)
+    assert nbytes / peak["hbm_bytes_per_s"] > ops / peak["bf16_flops"]
+    assert 12 * 2 * 8 * 8256 * 128 * 4 <= nbytes <= 12 * 2 * 8 * 8320 * 129 * 4
+    ops, nbytes = brumby.retention_chunk(1280, 1, _BRUMBY, 2)
+    assert ops / peak["bf16_flops"] > nbytes / peak["hbm_bytes_per_s"]

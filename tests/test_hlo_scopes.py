@@ -295,6 +295,8 @@ def test_scope_of_an_op_name(op_name, scope):
     ("optimizer_update/layer12_ff1_weight",
      "optimizer_update/layer*_ff1_weight"),
     ("layer3_mamba2/mamba2_chunk_scan", "layer*_mamba2/mamba2_chunk_scan"),
+    ("layer7_retention/retention_step", "layer*_retention/retention_step"),
+    ("layer7_g", "layer*_g"),
     ("head", "head"),
     ("", ""),
 ])
@@ -400,6 +402,49 @@ def test_closed_and_deleted_engine_is_still_nameable(lm_params):
     # its executables are let go once the tables stand
     assert profiler._retired["programs"] == {}
     assert profiler.program_scopes().keys() >= set(found)
+
+
+def test_retention_layers_programs_carry_the_mixers_node_names():
+    """A power-retention spec's two programs under the names
+    ``models/hybrid_lm.py`` lists for the mixer; the bare ``_g`` is a
+    node of its own, which ``scope_group_share``'s whole-name suffixes
+    book to no FFN's ``_gate`` (nor the FFN's to it)."""
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.reference import brumby as ref
+
+    cfg = {"hidden_size": 32, "num_hidden_layers": 2,
+           "num_attention_heads": 4, "num_key_value_heads": 2,
+           "head_dim": 8, "intermediate_size": 48, "vocab_size": 61,
+           "rms_norm_eps": 1e-6, "rope_theta": 1e4}
+    w = ref.draw(cfg, 3, embed_dtype="float32", dtype="float32")
+    eng = mx.DecodeEngine(ref.program_names(w), model=ref.spec(cfg),
+                          max_len=32, kv_block=4, max_streams=2,
+                          decode_buckets=[2], prefill_buckets=[16],
+                          temperature=0.0, ctx=mx.cpu(), dtype="float32")
+    try:
+        eng.submit(np.arange(1, 7, dtype=np.int32),
+                   max_new_tokens=3).result(timeout=300)
+        tables = eng.program_scopes()
+    finally:
+        eng.close()
+    want = {f"layer*_{n}" for n in ("q", "k", "v", "q_norm", "k_norm", "g",
+                                    "retention", "o")}
+    for prefix in ("jit_prefill_t", "jit_step_decode_b"):
+        table = next(t for n, t in tables.items() if n.startswith(prefix))
+        nodes = {r["group"].split("/", 1)[0] for r in table.values()}
+        nodes |= {s.split("/", 1)[0] for r in table.values()
+                  for s in map(hlo._group_of, r["scopes"])}
+        assert want <= nodes, sorted(want - nodes)
+        assert "layer*_ffn_gate" in nodes
+    suffixes = ("layer*_retention",)
+    assert not "layer*_ffn_gate".endswith(suffixes)
+    assert not "layer*_ffn_gate".endswith(("layer*_g",))
+    assert not "layer*_g".endswith(("layer*_gate", "_gate"))
 
 
 @pytest.mark.parametrize("n", [1, 3, 48])

@@ -244,7 +244,7 @@ def test_spec_round_trips_and_sizes_its_slots_by_the_mixer():
     assert spec.cache_kinds() == ("slots", "pages", "slots", "slots")
     assert (spec.embed_scale, spec.residual_scale, spec.logits_scale,
             spec.tied_head) == (12.0, 0.22, 1 / 16, True)
-    assert mixer_state(spec.layers[0]["mixer"]) == ((16, 8, 16), 160)
+    assert mixer_state(spec.layers[0]["mixer"]) == ((16, 8, 16), (8, 128))
     assert mixer_state(spec.layers[1]["mixer"]) is None
     pools = {n: s for n, s, _, _ in spec.pools(9, 4, 4, "float32")}
     assert pools["layer0_state"] == (4, 16, 8, 16)      # not square
@@ -254,7 +254,8 @@ def test_spec_round_trips_and_sizes_its_slots_by_the_mixer():
     assert "head_weight" not in args and "layer0_conv_bias" in args
     # a published config's own mixer sizes: (128, 64, 128) a slot
     full = dict(kind="mamba2", heads=128, head_dim=64, d_state=128, conv=4)
-    assert mixer_state(full) == ((128, 64, 128), 8448)
+    # (its convolution carries 8,448 channels: 3 rows of them in (8, 3200))
+    assert mixer_state(full) == ((128, 64, 128), (8, 3200))
     two = copy.deepcopy(spec.to_dict())
     two["layers"][0]["mixer"]["groups"] = 2
     with pytest.raises(MXNetError, match="one group"):

@@ -626,6 +626,39 @@ def test_mamba2_kernels_granite_cell(on_chip, one_chip, monkeypatch):
              ((1, T, H * P + 2 * N), bf16), ((1, T, H), f32))
 
 
+def test_retention_kernels_gen_cell(on_chip, one_chip, monkeypatch):
+    # brumby-14b-pp4: 40 query heads over 8 KV heads of 128, a packed
+    # state of 8,320 rows of 128 lanes a KV head; a 12-row decode step
+    # over 13 slots and a 2048-token prompt in chunks of 256
+    from mxnet_tpu.kv_cache import state_pool_shape
+    from mxnet_tpu.ops.hybrid import retention_rows
+
+    ph = _hybrid(monkeypatch)
+    H, J, D, B, T = 40, 8, 128, 12, 2048
+    R = retention_rows(D)
+    assert R == 8320
+    pool = state_pool_shape(B + 1, (J, R, D))
+    step = jax.jit(
+        lambda q, k, v, a, s, z, sl: ph.retention_step(q, k, v, a, s, z,
+                                                       sl),
+        donate_argnums=(4, 5)).lower(*[
+            jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in (
+                ((B, J, H // J, D), f32), ((B, J, D), f32), ((B, J, D), f32),
+                ((B, J), f32), (pool, f32),
+                (state_pool_shape(B + 1, (J, D, D)), f32), ((B,), i32))]
+    ).compile()
+    text = step.as_text()
+    assert _kernel_short_names(text) == ["retention_step"]
+    # both pools are updated in place: no copy of either beside them
+    dims = ",".join(str(n) for n in pool)
+    assert not re.findall(rf"= f32\[{dims}\]\S* copy\(.*", text)
+    compiled = _compile(
+        ph.retention_chunk,
+        one_chip, ((1, T, H * D), bf16), ((1, T, J * D), bf16),
+        ((1, T, J * D), bf16), ((1, T, J), f32), ((1,), i32))
+    assert _kernel_short_names(compiled.as_text()) == ["retention_chunk"]
+
+
 @pytest.mark.parametrize("cell, T, window", [
     (_LONGDOC, 32768, 0), (_LONGDOC, 32768, _LONGDOC[-1]),
     (_LONGDOC, 8192, 0), (_MIXED, 8192, 0), (_MIXED, 8192, _MIXED[-1])])

@@ -17,6 +17,9 @@ after ANY kernel change:
     python tools/verify_kernels.py --quick  # smoke subset
     python tools/verify_kernels.py --paged  # the paged kernel alone
     python tools/verify_kernels.py --mamba2 # the Mamba-2 kernels alone
+    python tools/verify_kernels.py --retention # the power-retention
+                                            # kernels at the gen cell's
+                                            # shapes against their lax bodies
     python tools/verify_kernels.py --window # the sliding-window kernels
                                             # (prefill band, decode walk)
     python tools/verify_kernels.py --longdoc # the four attention kernels
@@ -751,6 +754,71 @@ def check_mamba2(T, n, B=1, H=128, P=64, N=128):
     return ok
 
 
+def check_retention(T, n, B=1, H=40, J=8, D=128):
+    """``RetentionStep`` (T = 1: B rows against their slots) or
+    ``RetentionChunk`` (a prompt of n live tokens padded to T) through
+    the registered op, the Mosaic kernel against the op's lax body on
+    the same bfloat16 inputs: the outputs', the slots' and the
+    normalisers' largest gaps over the lax body's largest value, and both
+    bodies' time."""
+    from mxnet_tpu.ops import hybrid
+    from mxnet_tpu.ops.registry import OpContext, get_op
+
+    rng = np.random.RandomState(T + n)
+    f32 = lambda x: jnp.asarray(np.asarray(x, np.float32))
+    step = T == 1
+
+    def unit(*s):     # rows of norm sqrt(D): what the q/k norms leave
+        x = rng.randn(*s, D).astype(np.float32)
+        x *= np.sqrt(D) / np.linalg.norm(x, axis=-1, keepdims=True)
+        return jnp.asarray(x.reshape(s[0], s[1], -1)).astype(jnp.bfloat16)
+
+    q, k = unit(B, T, H), unit(B, T, J)
+    v = jnp.asarray(rng.randn(B, T, J * D).astype(np.float32)) \
+        .astype(jnp.bfloat16)
+    g = jnp.asarray(rng.randn(B, T, J).astype(np.float32)) \
+        .astype(jnp.bfloat16)
+    bias = f32(np.log(1 / np.exp(rng.uniform(
+        np.log(5e-4), np.log(2e-2), J)) - 1))
+    R = hybrid.retention_rows(D)
+    pool = f32(rng.randn(B + 1, J, R, D) * 0.1)
+    norm = f32(np.abs(rng.randn(B + 1, J, D, D)) * 0.1
+               + 10 * np.eye(D, dtype=np.float32))
+    slots = jnp.asarray(1 + rng.permutation(B).astype(np.int32))
+    lengths = jnp.full((B,), n, jnp.int32)
+    pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32)[None] + (
+        900 if step else 0), (B, T))
+    op = get_op("RetentionStep" if step else "RetentionChunk")
+    attrs = {"num_heads": str(H), "kv_heads": str(J),
+             "rope_theta": "1000000.0"}
+
+    def run(flag):
+        os.environ["MXNET_PALLAS"] = flag
+        fn = jax.jit(lambda *a: op.compute(
+            OpContext(is_train=False, rng=None), attrs, list(a), []))
+        args = (q, k, v, g, bias, pool, norm, slots, lengths, pos)
+        out = [np.asarray(x.astype(jnp.float32)) for x in fn(*args)]
+        return out, _time_ms(fn, *args)
+
+    try:
+        (y_k, s_k, z_k), ms_k = run("1")
+        (y_l, s_l, z_l), ms_l = run("0")
+    finally:
+        os.environ.pop("MXNET_PALLAS", None)
+    live = slice(0, n)
+    rows = np.asarray(slots)
+    gap = lambda a, b: float(np.abs(a - b).max() / max(np.abs(b).max(), 1e-9))
+    e_y = gap(y_k[:, live], y_l[:, live])
+    e_s, e_z = gap(s_k[rows], s_l[rows]), gap(z_k[rows], z_l[rows])
+    ok = max(e_y, e_s, e_z) < TOL and bool(np.isfinite(y_k).all()) \
+        and np.array_equal(s_k[0], s_l[0])
+    print(f"{'OK ' if ok else 'FAIL'} retention "
+          f"{'step' if step else 'chunk'} B={B} T={T} n={n} "
+          f"heads={H}/{J}x{D}: y={e_y:.4f} state={e_s:.4f} z={e_z:.4f} "
+          f"kernel={ms_k:.3f}ms lax={ms_l:.3f}ms", flush=True)
+    return ok
+
+
 def _grid_pages_write(k, v, k_pool, v_pool, pages):
     """The other form of the page write (ISSUE 37): a grid step a page
     through VMEM, the out BlockSpec's index map reading the page id, as
@@ -1076,6 +1144,14 @@ def main():
         results.append(check_mamba2(1, 1, B=64))
         for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1531)):
             results.append(check_mamba2(T, n))
+        return _report(results)
+    if "--retention" in sys.argv:
+        # the gen cell's own shapes: a 12-row decode step, prompts in
+        # the 1024 and 2048 buckets (whole, ending inside a chunk, and
+        # one that leaves three chunks unwalked)
+        results.append(check_retention(1, 1, B=12))
+        for T, n in ((1024, 1024), (1024, 700), (2048, 2048), (2048, 1290)):
+            results.append(check_retention(T, n))
         return _report(results)
     for fill in fills:
         if "--mla" in sys.argv:
