@@ -702,10 +702,27 @@ def mamba2_chunk(dx, xbc, la):
 # (rows, KV heads): a head's whole state is one block in, one block out
 # (aliased, through the ``slots`` scalar operand), ``St <- a St + v_col
 # phi(k)_row`` and ``acc_i += St phi(q_i)_row`` a query head as it passes
-# — 3 + 2 G operations a register for 2 x 4.26 MB of traffic: the copies
-# bind.  The D / 2 + 1 rows of phi, a query head each, are made once a
-# head and broadcast down a register in VMEM; the walk is value rows
-# outside (G accumulators stay in registers), blocks inside.
+# — 3 + 2 G operations a register for 2 x 4.26 MB of traffic.  THE
+# COPIES BIND, at what the chip gives a read and a write of one size:
+# alone (PERF.md §6, PR 46; us a grid step at the gen cell's shapes) a
+# head's block read and not written takes 6.15, written and not read
+# 7.07, both 13.4 through the BlockSpec pipeline and 13.0-13.4 by the
+# kernel's own copies however they are cut and ordered — 77-80% of 819
+# GB/s, not the pipeline's doing — so the pipeline stays.  The walk is
+# kept well under that: the D / 2 + 1 rows of phi, a query head each,
+# are made once a head (one roll, two products a block for all the rows
+# of x) and kept as ROWS in VMEM; the walk goes through the blocks once
+# for ``groups`` groups of 8 value rows at a time
+# (``_retention_step_blocks``), the groups' G accumulators each and
+# their value columns held in registers, so a row of phi is loaded once
+# (and broadcast down a register by its products) for ``groups``
+# registers of state: 1 + (G + 1) / groups loads a register where a group a pass had
+# 2 + G.  On a block that is not fetched again the whole body reads 4.4
+# us a grid step (the walk 1.1), where value rows outside and blocks
+# inside, phi broadcast into VMEM and the sums of weights on the VPU read
+# 15.4 — above the copies, which is what the kernel cost then.  ``q^T Z
+# q`` is one product of x's rows with Z for all the heads; ``y`` leaves
+# as whole rows, a division and a store a query head.
 #
 # ``retention_chunk``: a prompt in chunks of ``RETENTION_CHUNK`` tokens
 # (``ops/hybrid.py retention_chunked``), grid (rows, KV heads, chunks),
@@ -746,9 +763,29 @@ def _lane_roll(x, delta, D):
     return pltpu.roll(x, (D - delta) % D, x.ndim - 1)
 
 
+def _retention_step_blocks(G, D):
+    """(groups, blocks) of ``retention_step``'s walk over a head's state:
+    ``groups`` groups of 8 value rows are walked in one pass and
+    ``blocks`` of the D / 2 + 1 blocks in one turn of its loops.  The
+    shapes decide, nothing else; each choice is a row of the kernel-alone
+    table in PERF.md §6, PR 46.  A pass keeps in registers, for each of
+    its groups, G accumulators and the group's value column, beside the
+    G + 1 rows of phi of the block it is at: as many groups as leave a
+    quarter of the 64 registers to the products in flight (at G = 5 two
+    groups read 8.1 us a head, four 6.7, eight 6.3 with registers
+    spilt).  A turn holds at least 48 accumulations, in whole blocks: a
+    turn of 20 fills and drains its chains more than it works (3.4 us a
+    head's walk against 1.1 at 100)."""
+    nd = D // 2 + 1
+    groups = _divisor_at_most(D // 8, max(1, (48 - (G + 1)) // (G + 1)))
+    blocks = min(u for u in range(1, nd + 1)
+                 if nd % u == 0 and (groups * G * u >= 48 or u == nd))
+    return groups, blocks
+
+
 def _retention_step_kernel(slots_ref, a_ref, x_ref, v_ref, s_ref, z_ref,
-                           y_ref, so_ref, zo_ref, phi_scr, vcol_scr, *,
-                           G, D):
+                           y_ref, so_ref, zo_ref, phi_scr, vcol_scr,
+                           acc_scr, *, G, D, groups, blocks):
     del slots_ref  # used by the index maps
     b, j = pl.program_id(0), pl.program_id(1)
     f32 = jnp.float32
@@ -761,52 +798,64 @@ def _retention_step_kernel(slots_ref, a_ref, x_ref, v_ref, s_ref, z_ref,
     k_col = jnp.sum(eye * k_row, axis=1, keepdims=True)
     v_col = jnp.sum(eye * v_ref[0, 0], axis=1, keepdims=True)
     vcol_scr[...] = jnp.broadcast_to(v_col, (D, D))
-    # the normaliser, and each query head's sum of weights
+    # the normaliser, and every row's x^T Z x on its sublane: one
+    # product for all the query heads
     z = a * z_ref[0, 0] + (c * k_col) * k_row
     zo_ref[0, 0] = z
-    dens = []
-    for i in range(G):
-        q_row = x[1 + i:2 + i]
-        q_col = jnp.sum(eye * q_row, axis=1, keepdims=True)
-        dens.append(c * jnp.sum(jnp.sum(z * q_row * q_col, axis=1,
-                                        keepdims=True),
-                                axis=0, keepdims=True))      # (1, 1)
+    xz = jax.lax.dot_general(x, z, (((1,), (0,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
+                             preferred_element_type=f32)
+    den = c * jnp.sum(xz * x, axis=1, keepdims=True)         # (rows, 1)
 
-    # phi, every block: one roll and one product for all the rows of x;
-    # each row broadcast down a register, to be read as it is
-    def make(delta, carry):
-        p = x * _lane_roll(x, delta, D) * _phi_scale(delta, D)
-        for n in range(G + 1):
-            phi_scr[delta, n] = jnp.broadcast_to(p[n:n + 1], (8, D))
+    # phi, every block: one roll and one product for all the rows of x
+    # (``blocks`` a turn: a roll is long in coming, several are on
+    # their way at once)
+    def make(turn, carry):
+        for u in range(blocks):
+            delta = turn * blocks + u
+            phi_scr[delta] = x * _lane_roll(x, delta, D) \
+                * _phi_scale(delta, D)
         return carry
 
-    jax.lax.fori_loop(0, nd, make, 0)
-    eye8 = _eye(8)
+    jax.lax.fori_loop(0, nd // blocks, make, 0)
 
-    un = _divisor_at_most(nd, 5)       # blocks a turn of the loop
-    for L in range(D // 8):            # 8 value rows at a time
-        vc = vcol_scr[L * 8:(L + 1) * 8]
+    span = 8 * groups
 
-        def body(turn, accs, L=L, vc=vc):
-            for u in range(un):
-                delta = turn * un + u
-                at = pl.multiple_of(delta * D + L * 8, 8)
-                st = a * s_ref[0, 0, pl.ds(at, 8), :] \
-                    + vc * phi_scr[delta, 0]
-                so_ref[0, 0, pl.ds(at, 8), :] = st
-                accs = tuple(acc + st * phi_scr[delta, 1 + i]
-                             for i, acc in enumerate(accs))
-            return accs
+    def one_pass(p, carry):
+        base = pl.multiple_of(p * span, span)
+        vcs = [vcol_scr[pl.ds(base + g * 8, 8), :] for g in range(groups)]
+
+        def body(turn, accs):
+            accs = list(accs)
+            for u in range(blocks):
+                delta = turn * blocks + u
+                # a row of phi, read once for the pass's groups (the
+                # products broadcast it down a register)
+                pk_, *pqs = (phi_scr[delta, pl.ds(n, 1), :]
+                             for n in range(G + 1))
+                for g in range(groups):
+                    at = pl.multiple_of(delta * D + base + g * 8, 8)
+                    st = a * s_ref[0, 0, pl.ds(at, 8), :] + vcs[g] * pk_
+                    so_ref[0, 0, pl.ds(at, 8), :] = st
+                    for i, pq in enumerate(pqs):
+                        accs[g * G + i] = accs[g * G + i] + st * pq
+            return tuple(accs)
 
         accs = jax.lax.fori_loop(
-            0, nd // un, body,
-            tuple(jnp.zeros((8, D), f32) for _ in range(G)))
-        for i, acc in enumerate(accs):
-            num = jnp.sum(acc, axis=1, keepdims=True)        # (8, 1)
-            y_col = num / (dens[i] + RETENTION_EPS)
-            # a column of 8 -> 8 lanes of the output's row
-            y_ref[0, 0, i:i + 1, L * 8:(L + 1) * 8] = jnp.sum(
-                eye8 * y_col, axis=0, keepdims=True)
+            0, nd // blocks, body,
+            tuple(jnp.zeros((8, D), f32) for _ in range(groups * G)))
+        for g in range(groups):
+            for i in range(G):
+                acc_scr[i, pl.ds(base + g * 8, 8), :] = accs[g * G + i]
+        return carry
+
+    jax.lax.fori_loop(0, (D // 8) // groups, one_pass, 0)
+    # a query head's sums over the key's lanes, turned from a column to
+    # the output's row: one division and one store a head
+    for i in range(G):
+        num = jnp.sum(acc_scr[i], axis=1, keepdims=True)     # (D, 1)
+        row = jnp.sum(eye * num, axis=0, keepdims=True)      # (1, D)
+        y_ref[0, 0, i:i + 1, :] = row / (den[1 + i:2 + i] + RETENTION_EPS)
 
 
 def retention_step(q, k, v, a, state, norm, slots):
@@ -816,9 +865,16 @@ def retention_step(q, k, v, a, state, norm, slots):
     (B,) int32 -> (y (B, Hkv, G, D) float32, both pools with the B
     slots advanced).  The pools are aliased to their outputs: donated
     under jit, the update is in place."""
+    from .. import profiler
+
     B, Hkv, G, D = q.shape
     R = state.shape[2]
     rows = -(-(G + 1) // 8) * 8
+    groups, blocks = _retention_step_blocks(G, D)
+    # the schedule a build chose, on ``/metrics`` beside ``flash.*``
+    profiler.set_gauge("retention.step_row_groups", groups)
+    profiler.set_gauge("retention.step_loads_per_register",
+                       1 + (G + 1) / groups)
     x = jnp.concatenate([k[:, :, None], q], axis=2)
     x = jnp.pad(x, ((0, 0), (0, 0), (0, rows - G - 1), (0, 0)))
     at = lambda b, j, sl, a: (b, j, 0, 0)
@@ -832,10 +888,12 @@ def retention_step(q, k, v, a, state, norm, slots):
         out_specs=[_vmem_spec((1, 1, G, D), at),
                    _vmem_spec((1, 1, R, D), slot),
                    _vmem_spec((1, 1, D, D), slot)],
-        scratch_shapes=[pltpu.VMEM((D // 2 + 1, G + 1, 8, D), jnp.float32),
-                        pltpu.VMEM((D, D), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((D // 2 + 1, rows, D), jnp.float32),
+                        pltpu.VMEM((D, D), jnp.float32),
+                        pltpu.VMEM((G, D, D), jnp.float32)])
     y, state, norm = pl.pallas_call(
-        functools.partial(_retention_step_kernel, G=G, D=D),
+        functools.partial(_retention_step_kernel, G=G, D=D, groups=groups,
+                          blocks=blocks),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, Hkv, G, D), jnp.float32),
                    jax.ShapeDtypeStruct(state.shape, state.dtype),

@@ -165,8 +165,8 @@ def test_chunk_is_step_by_step_is_the_attention_form(kernels, T, n):
 
 def test_kernels_interpreted_are_their_lax_bodies(monkeypatch):
     """``retention_chunk`` (a prompt that ends inside its second chunk of
-    three: the third is not walked, its rows leave as 0) and
-    ``retention_step`` (two rows on two slots) against the lax bodies."""
+    three: the third is not walked, its rows leave as 0) against the lax
+    body; ``retention_step`` has the test below."""
     monkeypatch.setenv("MXNET_PALLAS", "1")
     monkeypatch.setattr(pallas_hybrid, "RETENTION_CHUNK", 16)
     rng = np.random.default_rng(5)
@@ -191,23 +191,61 @@ def test_kernels_interpreted_are_their_lax_bodies(monkeypatch):
     np.testing.assert_allclose(np.asarray(zk), np.asarray(zl), rtol=1e-4,
                                atol=1e-5)
 
+
+@pytest.mark.parametrize("rows", [1, 3])
+@pytest.mark.parametrize("g", [1, 4, 5, 8])
+def test_step_kernel_interpreted_is_its_lax_body(monkeypatch, g, rows):
+    """``retention_step`` at every group size its walk is scheduled for
+    (``_retention_step_blocks``), on permuted, non-adjacent slots: the
+    outputs, the advanced slots and their normalisers are the lax
+    body's, every other slot is left bit for bit."""
+    monkeypatch.setenv("MXNET_PALLAS", "1")
+    rng = np.random.default_rng(10 * g + rows)
     R = hybrid.retention_rows(D)
-    pool = jnp.asarray(f32(rng.standard_normal((4, J, R, D))))
-    norm = jnp.asarray(f32(np.abs(rng.standard_normal((4, J, D, D)))))
-    slots = jnp.asarray([3, 1], jnp.int32)
-    a = jnp.exp(jnp.asarray(la[:, 0]))
-    args = [jnp.asarray(t[:, 0]) for t in (q, k, v)] + [a]
-    y1, p1, z1 = pallas_hybrid.retention_step(*args, pool, norm, slots)
+    pool = jnp.asarray(f32(rng.standard_normal((7, J, R, D))))
+    norm = jnp.asarray(f32(np.abs(rng.standard_normal((7, J, D, D)))))
+    slots = np.array([5, 0, 3][:rows], np.int32)
+    q = f32(rng.standard_normal((rows, J, g, D)))
+    k, v = f32(rng.standard_normal((2, rows, J, D)))
+    a = f32(rng.uniform(0.6, 0.99, (rows, J)))
+    args = [jnp.asarray(t) for t in (q, k, v, a)]
+    y1, p1, z1 = pallas_hybrid.retention_step(*args, pool, norm,
+                                              jnp.asarray(slots))
     y2, s2, z2 = hybrid.retention_step(*args, pool[slots], norm[slots])
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-4,
                                atol=1e-5)
-    np.testing.assert_allclose(np.asarray(p1)[np.asarray(slots)],
-                               np.asarray(s2), rtol=1e-5, atol=1e-6)
-    np.testing.assert_allclose(np.asarray(z1)[np.asarray(slots)],
-                               np.asarray(z2), rtol=1e-5, atol=1e-6)
-    for untouched in (0, 2):
+    np.testing.assert_allclose(np.asarray(p1)[slots], np.asarray(s2),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(z1)[slots], np.asarray(z2),
+                               rtol=1e-5, atol=1e-6)
+    for untouched in sorted(set(range(7)) - set(slots.tolist())):
         assert np.array_equal(np.asarray(p1)[untouched],
                               np.asarray(pool)[untouched])
+        assert np.array_equal(np.asarray(z1)[untouched],
+                              np.asarray(norm)[untouched])
+    # the schedule the build chose is on ``/metrics``
+    groups, _ = pallas_hybrid._retention_step_blocks(g, D)
+    gauges = mx.profiler.metrics_summary()["gauges"]
+    assert gauges["retention.step_row_groups"] == groups
+    assert gauges["retention.step_loads_per_register"] == \
+        1 + (g + 1) / groups
+
+
+def test_step_schedule_fits_the_register_file():
+    """The walk's schedule for G = 1 .. 8 query heads a KV head at heads
+    of 128 and 256: a pass's accumulators, value columns and a block's
+    rows of phi fit the 64 registers with room for the products in
+    flight, its groups divide the head's groups of 8 value rows and its
+    turns the head's blocks."""
+    for d in (128, 256):
+        for g in range(1, 9):
+            groups, blocks = pallas_hybrid._retention_step_blocks(g, d)
+            held = groups * g + groups + (g + 1)
+            assert held <= 48, (g, d, groups, held)
+            assert (d // 8) % groups == 0 and (d // 2 + 1) % blocks == 0
+            assert groups * g * blocks >= 48
+    # the gen cell's heads: a phi row is loaded once for four registers
+    assert pallas_hybrid._retention_step_blocks(5, 128) == (4, 5)
 
 
 def test_five_query_heads_read_one_kv_heads_state():
