@@ -355,9 +355,11 @@ class HybridSpec:
     def prompt_attention(self):
         """``(window, latent)`` a layer whose prefill attends the whole
         prompt by a kernel that takes its length (``window`` 0: global;
-        ``latent``: an mla layer): what
-        ``pallas_kernels.prompt_tile_visits`` counts a prefill's tiles
-        by."""
+        ``latent``: an mla layer, whose ``mla_flash`` walks the same
+        band schedule without a window in tiles of its own choice,
+        ``pallas_kernels._mla_tiles``): what
+        ``pallas_kernels.prompt_tile_visits`` / ``prompt_tile_work``
+        count a prefill's tiles and scores by."""
         return tuple((int(ly["mixer"].get("window") or 0),
                       ly["mixer"]["kind"] == "mla")
                      for ly in self.layers

@@ -1042,50 +1042,65 @@ def _band_schedule(rows, block_q, block_k, sub, window):
 
 
 def _prompt_schedule(rows, window, latent):
-    """``_band_schedule`` of the kernel a layer's prefill runs over a
-    bucket of ``rows``, with its query tile: ``mla_flash`` (``latent``)
-    masks the diagonal's whole tile."""
+    """(query tile, padding block, schedule) of the kernel a layer's
+    prefill runs over a bucket of ``rows`` — ``flash_mha_window``, or
+    ``mla_flash`` where ``latent`` (no window; its walk follows from
+    the bucket alone, so no width is asked for): ``schedule(block)`` is
+    the ``_band_schedule`` of the bucket's rows in query blocks of
+    ``block`` rows over the kernel's key tiles and sub-blocks, and the
+    padding block the rows by which a kernel leaves its last live
+    tile's padding out (``flash_mha_window``: the tile, nothing left
+    out)."""
     if latent:
-        bq = bk = sub = _mha_block(_MLA_BLOCK, rows)
+        (bq, bk, sub, guard), window = _mla_tiles(rows, 0, 0, 0, 0)[:4], 0
     else:
         bq, bk, sub, _ = _mha_window_tiles(rows, window)
+        guard = bq
     padded = rows + (-rows) % max(bq, bk)
-    return bq, _band_schedule(padded, bq, bk, sub, 0 if latent else window)
+    return bq, guard, lambda block: _band_schedule(padded, block, bk, sub,
+                                                   window)
 
 
 def prompt_tile_visits(length, rows, window=0, latent=False):
     """(walked, skipped) key-tile visits of one head of a prompt kernel
     — ``flash_mha_window`` (``window`` 0: global), or ``mla_flash``
-    where ``latent`` — over a prompt of ``length`` rows in a bucket of
-    ``rows``: a live query tile walks its band's tiles up to the
+    where ``latent`` (the same walk without a window, over the tiles
+    ``_mla_tiles`` picks) — over a prompt of ``length`` rows in a bucket
+    of ``rows``: a live query tile walks its band's tiles up to the
     diagonal's, a dead one (only padding) walks none, and what the dead
     ones would have walked is ``skipped``.  Their sum is the bucket's
     tiles, what a caller without ``lengths`` walks.  Host arithmetic
     (the engine's ``prefill_tiles_*`` counters);
     tests/test_prompt_lengths.py holds it to the interpreted kernels'
     own steps."""
-    bq, plan = _prompt_schedule(rows, window, latent)
+    bq, _, schedule = _prompt_schedule(rows, window, latent)
+    plan = schedule(bq)
     walk = plan.last - plan.first + 1
     live = -(-min(max(int(length), 0), rows) // bq)
     return int(walk[:live].sum()), int(walk[live:].sum())
 
 
 def prompt_tile_work(length, rows, window=0, latent=False):
-    """(masked, computed, needed) of one head of a prompt kernel over a
+    """(masked, computed, needed) of one head of a prompt kernel
+    (``latent``: ``mla_flash``, whose only edge is the diagonal) over a
     prompt of ``length`` rows in a bucket of ``rows``: the walked tiles
     that took a MASKED body (an edge of the band crosses them; the
     others run without one), the score elements the schedule computes
     (a whole tile inside the band, only the sub-blocks a row can see of
-    an edge tile; the last live tile's padding rows count) and the
-    pairs the band holds, the least there is to compute.  Host
-    arithmetic beside :func:`prompt_tile_visits` (the engine's
-    ``prefill_tiles_masked`` / ``prefill_scores_computed_over_needed``),
-    held to the interpreted kernels' own blocks by the same test."""
-    bq, plan = _prompt_schedule(rows, window, latent)
+    an edge tile; the last live tile's padding rows count — in
+    ``mla_flash`` up to the end of the prompt's last block of ``inner``
+    rows, the blocks after it are left out) and the pairs the band
+    holds, the least there is to compute.  Host arithmetic beside
+    :func:`prompt_tile_visits` (the engine's ``prefill_tiles_masked`` /
+    ``prefill_scores_computed_over_needed``), held to the interpreted
+    kernels' own blocks by the same test."""
+    bq, guard, schedule = _prompt_schedule(rows, window, latent)
     n = min(max(int(length), 0), rows)
-    live = -(-n // bq)
     w = min(n, window) if window else n
-    return (int(plan.masked[:live].sum()), int(plan.scores[:live].sum()),
+    # a query tile's scores are its blocks' (a sub-block is computed
+    # where a row sees a column, whatever tile holds it)
+    return (int(schedule(bq).masked[:-(-n // bq)].sum()),
+            int(schedule(guard).scores[:-(-n // guard)].sum()),
             w * (w + 1) // 2 + (n - w) * w)
 
 
@@ -1096,6 +1111,42 @@ def _band_mask(sub, lo, hi):
     if lo is None:
         return d <= hi
     return d > lo if hi is None else (d > lo) & (d <= hi)
+
+
+def _band_update(scores, values, acc_ref, m_ref, l_ref, blk, pieces):
+    """One update of the rows ``blk``'s online-softmax state (``acc``,
+    ``m``, ``l``: a head's) over the column spans ``pieces`` =
+    [(columns, mask or None)], in the exp2 domain: ``scores(columns)``
+    the rows' float32 scores x scale x log2(e), ``values(columns)`` the
+    value rows their probabilities multiply."""
+    ss = []
+    for cols, mask in pieces:
+        s = scores(cols)
+        ss.append(s if mask is None else jnp.where(mask, s, -jnp.inf))
+    m_prev = m_ref[blk, :1]
+    m_new = m_prev
+    for s in ss:
+        m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
+    if any(mask is not None for _, mask in pieces):
+        # a row may have seen no key yet (under the window's edge)
+        m_use = jnp.where(m_new == -jnp.inf, 0.0, m_new)
+        alpha = jnp.where(m_prev == -jnp.inf, 0.0,
+                          jnp.exp2(m_prev - m_use))
+    else:       # every key is seen: the maximum is finite, and
+        m_use = m_new       # exp2(-inf - m) is 0 at the first tile
+        alpha = jnp.exp2(m_prev - m_new)
+    l_new = l_ref[blk, :1] * alpha
+    pv = None
+    for s, (cols, _) in zip(ss, pieces):
+        p = jnp.exp2(s - m_use)     # a masked score is -inf: 0
+        l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
+        v = values(cols)
+        d = _dot(p.astype(v.dtype), v, 1, 0)
+        pv = d if pv is None else pv + d
+    n = blk.stop - blk.start
+    l_ref[blk, :] = jnp.broadcast_to(l_new, (n, l_ref.shape[1]))
+    m_ref[blk, :] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
+    acc_ref[blk, :] = acc_ref[blk, :] * alpha + pv
 
 
 def _mha_window_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
@@ -1124,37 +1175,12 @@ def _mha_window_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
 
     def update(blk, pieces):
-        """One update of the rows ``blk``'s state over the column spans
-        ``pieces`` = [(columns, mask or None)], in the exp2 domain."""
         q = q_ref[0, blk, :]
-        ss = []
-        for cols, mask in pieces:
-            s = _dot(q, k_ref[0, cols, :], 1, 1) * (scale * _LOG2E)
-            ss.append(s if mask is None else jnp.where(mask, s, -jnp.inf))
-        m_prev = m_ref[blk, :1]
-        m_new = m_prev
-        for s in ss:
-            m_new = jnp.maximum(m_new, jnp.max(s, axis=1, keepdims=True))
-        if any(mask is not None for _, mask in pieces):
-            # a row may have seen no key yet (under the window's edge)
-            m_use = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-            alpha = jnp.where(m_prev == -jnp.inf, 0.0,
-                              jnp.exp2(m_prev - m_use))
-        else:       # every key is seen: the maximum is finite, and
-            m_use = m_new       # exp2(-inf - m) is 0 at the first tile
-            alpha = jnp.exp2(m_prev - m_new)
-        l_new = l_ref[blk, :1] * alpha
-        pv = None
-        for s, (cols, _) in zip(ss, pieces):
-            p = jnp.exp2(s - m_use)     # a masked score is -inf: 0
-            l_new = l_new + jnp.sum(p, axis=1, keepdims=True)
-            v = v_ref[0, cols, :]
-            d = _dot(p.astype(v.dtype), v, 1, 0)
-            pv = d if pv is None else pv + d
-        n = blk.stop - blk.start
-        l_ref[blk, :] = jnp.broadcast_to(l_new, (n, l_ref.shape[1]))
-        m_ref[blk, :] = jnp.broadcast_to(m_new, (n, m_ref.shape[1]))
-        acc_ref[blk, :] = acc_ref[blk, :] * alpha + pv
+        _band_update(
+            lambda cols: _dot(q, k_ref[0, cols, :], 1, 1)
+            * (scale * _LOG2E),
+            lambda cols: v_ref[0, cols, :], acc_ref, m_ref, l_ref, blk,
+            pieces)
 
     if interior:    # wholly inside the band: no mask, no guard
         inside = walked & (off <= -block_k)
@@ -2502,22 +2528,24 @@ def kv_pages_write(k, v, k_pool, v_pool, pages):
 # ---------------------------------------------------------------------------
 # Multi-head latent attention (ops/hybrid.py MLAPrefillAttention /
 # MLAPagedDecode): a prefill kernel with two head widths and one shared
-# positional key, a paged decode kernel over ONE pool of latent rows in
-# which the value is a lane span of the key, and the one-pool page write.
+# positional key that walks the grouped-query prompt kernel's band
+# schedule in tiles of its own (`_mla_tiles`), a paged decode kernel
+# over ONE pool of latent rows in which the value is a lane span of the
+# key, and the one-pool page write.
 #
 # The names hold none of ``paged_attention``, ``paged_window``,
 # ``flash_fwd_mha``, ``flash_fwd_window``, ``kv_pages_write``: the
 # benchmark's accepted readers book a kernel to a metric by SUBSTRING.
 # ---------------------------------------------------------------------------
 
-_MLA_BLOCK = 512
-
-
 def _mla_heads_per_step(heads, rope_dim):
     """Heads a grid step of the prefill kernel takes: their rotary query
     lanes (``hb·rope_dim``) must be whole lane tiles when compiled — 4 at
-    the published 64 — and the ONE rotary key tile is then fetched once
-    for the group, not once a head."""
+    the published 64 — with a head's rotary lanes a whole share of a
+    lane tile or whole tiles, and the ONE rotary key tile is then
+    fetched once for the group, not once a head."""
+    if rope_dim and 128 % rope_dim and rope_dim % 128:
+        return 0
     for hb in (4, 2, 8, 16):
         if heads % hb == 0 and (hb * rope_dim) % 128 == 0:
             return hb
@@ -2535,13 +2563,38 @@ def mla_flash_enabled(heads, nope_dim, rope_dim, v_dim) -> bool:
         and _mla_heads_per_step(heads, rope_dim) > 0)
 
 
+def _mla_tiles(t, heads, nope, rope, v):
+    """(block_q, block_k, sub, inner, heads_per_step) of ``mla_flash``
+    for a prompt bucket of ``t`` rows: the tile a grid step holds, the
+    sub-block the diagonal's tile is walked in, the rows of a tile that
+    are updated at a time (and left out where they hold only the last
+    live tile's padding), and the heads a grid step takes.  From the
+    shapes alone, each choice a row of the kernel-alone sweep in PERF.md
+    section 6, PR 47 (``tools/verify_kernels.py --mla-tiles`` prints it
+    again, patching this chooser).  The walk's four follow from the
+    bucket (``_prompt_schedule`` asks with no widths) and are
+    ``_mha_window_tiles``': the key tile's width is what counts (512 ->
+    1,024 -> 2,048 keys: -37%, -12% a call at 8,192 rows), a query tile
+    of 2,048 rows reads 1-9% faster still and compiles twice as long,
+    sub-blocks of 512 read the same as 256.  The heads from their count
+    and rotary width (``_mla_heads_per_step``: 2, 4 and 8 read within
+    1%; interpreted, any width: what divides)."""
+    return _mha_window_tiles(t, 0) + (
+        _mla_heads_per_step(heads, rope) or math.gcd(heads, 4),)
+
+
 def _mla_flash_kernel(len_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
-                      acc_ref, m_ref, l_ref, *, hb, n, r, dv, block, scale):
+                      acc_ref, m_ref, l_ref, *, hb, per, n, r, dv, block_q,
+                      block_k, sub, inner, scale, edges, interior):
     qi = pl.program_id(2)
-    kj = pl.program_id(3)
+    kj = pl.program_id(3)           # the walk starts at key tile 0
     length = len_ref[pl.program_id(0)]
-    live = qi * block < length      # its walk ends on the diagonal: key
-    # tile qi, never past the tile that holds row length - 1
+    live = qi * block_q < length
+    # a live tile's walk ends on the diagonal's tile, never past the
+    # tile that holds row length - 1
+    last = _diagonal_tile(qi, block_q, block_k)
+    walked = live & (kj <= last)
+    off = kj * block_k - qi * block_q   # first column less first row
 
     @pl.when(jnp.logical_not(live) & (kj == 0))
     def _dead():
@@ -2554,57 +2607,61 @@ def _mla_flash_kernel(len_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, -jnp.inf)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    def tile(masked):
-        # a tile wholly below the diagonal, without padded keys, needs
-        # no mask: every key is seen, every row's maximum is finite
-        if masked:
-            k_pos = kj * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 1)
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, block), 0)
-            valid = k_pos <= q_pos    # a key a prompt's row sees is below
-            # the length; the rows past it are zeroed at the end
-        kr = kr_ref[0]                          # (block, r): every head's
-        cross = (((1,), (1,)), ((), ()))
-        for i in range(hb):
-            s = jax.lax.dot_general(
-                qn_ref[0, :, i * n:(i + 1) * n],
-                kn_ref[0, :, i * n:(i + 1) * n], cross,
-                preferred_element_type=jnp.float32)
-            s = (s + jax.lax.dot_general(
-                qr_ref[0, :, i * r:(i + 1) * r], kr, cross,
-                preferred_element_type=jnp.float32)) * scale
-            m_prev = m_ref[i, :, :1]
-            if masked:
-                s = jnp.where(valid, s, -jnp.inf)
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=1, keepdims=True))
-                m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-                p = jnp.where(valid, jnp.exp(s - m_safe), 0.0)
-                alpha = jnp.where(m_prev == -jnp.inf, 0.0,
-                                  jnp.exp(m_prev - m_safe))
-            else:
-                m_new = jnp.maximum(m_prev,
-                                    jnp.max(s, axis=1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m_prev - m_new)     # exp(-inf) = 0 at first
-            l_ref[i] = jnp.broadcast_to(
-                l_ref[i, :, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
-                l_ref.shape[1:])
-            vv = v_ref[0, :, i * dv:(i + 1) * dv]
-            pv = jax.lax.dot_general(p.astype(vv.dtype), vv,
-                                     (((1,), (0,)), ((), ())),
-                                     preferred_element_type=jnp.float32)
-            acc_ref[i] = acc_ref[i] * alpha + pv
-            m_ref[i] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+    def heads(updates):
+        """``_band_update`` of each of the step's heads (a rolled loop:
+        the bodies are traced and compiled once, not once a head) for
+        ``updates`` = [(rows, pieces)] — but the blocks of ``inner`` rows
+        that hold only the last live tile's padding, which are left out:
+        their state stays empty and their rows come out 0."""
+        @functools.partial(jax.lax.fori_loop, 0, hb, init_val=None)
+        def _head(i, _):
+            def lanes(j, width):
+                return pl.ds(pl.multiple_of(j * width, width), width)
 
-    # wholly below the diagonal of a live tile is wholly below the
-    # length too: no mask
-    below = live & (kj < qi)
-    pl.when(below)(lambda: tile(False))
-    pl.when(live & (kj <= qi) & jnp.logical_not(below))(lambda: tile(True))
+            # the rotary lanes of `per` heads share a lane tile, which
+            # no dynamic slice may start inside: the tile is taken whole
+            # against the rotary key beside zeros (`_mla_flash`) — one
+            # product of n + per·r lanes gives q_n . k_n + q_r . k_r
+            tile = lanes(_tile_of(i, per), per * r)
+            spot = lanes(i & (per - 1), per * r)
+            for blk, pieces in updates:
+                def _rows(blk=blk, pieces=pieces):
+                    q = jnp.concatenate(
+                        [qn_ref[0, blk, lanes(i, n)], qr_ref[0, blk, tile]],
+                        axis=1)
+                    _band_update(
+                        lambda cols: _dot(q, jnp.concatenate(
+                            [kn_ref[0, cols, lanes(i, n)],
+                             kr_ref[0, cols, spot]], axis=1), 1, 1)
+                        * (scale * _LOG2E),
+                        lambda cols: v_ref[0, cols, lanes(i, dv)],
+                        acc_ref.at[i], m_ref.at[i], l_ref.at[i], blk, pieces)
 
-    whole = (qi + 1) * block <= length  # no row of the tile is padding
+                pl.when(qi * block_q + blk.start // inner * inner
+                        < length)(_rows)
+
+    if interior:    # wholly below the diagonal of a live tile, so below
+        # the length too: no mask, no guard, every key seen
+        @pl.when(walked & (off <= -block_k))
+        def _interior():
+            heads([(slice(r0, r0 + inner), [(slice(0, block_k), None)])
+                   for r0 in range(0, block_q, inner)])
+
+    # the diagonal crosses the tile: walked in sub-blocks, only what a
+    # row can see computed, only the blocks the diagonal cuts masked (a
+    # key a prompt's row sees is below the length; the rows past it are
+    # zeroed at the end).  One body an offset this grid can reach
+    for o in edges:
+        def _edge(o=o):
+            walk = _band_walk(o, block_q, block_k, sub)
+            masks = {cut: _band_mask(sub, *cut) for _, pieces in walk
+                     for _, cut in pieces if cut is not None}
+            heads([(blk, [(cols, cut and masks[cut]) for cols, cut in pieces])
+                   for blk, pieces in walk])
+
+        pl.when(walked & (off == o))(_edge)
+
+    whole = (qi + 1) * block_q <= length  # no row of the tile is padding
 
     def finalize(keep):
         for i in range(hb):
@@ -2613,15 +2670,15 @@ def _mla_flash_kernel(len_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
                 out if keep is None else jnp.where(keep, out, 0.0)
             ).astype(o_ref.dtype)
 
-    @pl.when(whole & (kj == qi))
+    @pl.when(whole & (kj == last))
     def _finalize():
         finalize(None)
 
-    @pl.when(live & jnp.logical_not(whole) & (kj == qi))
+    @pl.when(live & jnp.logical_not(whole) & (kj == last))
     def _finalize_last():
         # the prompt ends inside this tile: its padding rows as zeros
-        finalize(qi * block + jax.lax.broadcasted_iota(
-            jnp.int32, (block, dv), 0) < length)
+        finalize(qi * block_q + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, dv), 0) < length)
 
 
 def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale, lengths=None):
@@ -2634,65 +2691,111 @@ def mla_flash(q, q_r, kv, k_r, heads, nope_dim, v_dim, scale, lengths=None):
     repeated a head -> (B, T, H·dv).  Scores (q_n . k_n + q_r . k_r) x
     ``scale``.  Token-major rows as the projections leave them: a head
     is a lane span (k_n and v are two windows on ONE array, no slice is
-    copied out), a grid step takes ``_mla_heads_per_step`` heads over
-    one (query tile, key tile) and the key tiles above the diagonal are
-    neither fetched nor computed.  ``lengths`` (B,): each prompt's rows
-    in its bucket of T, as :func:`flash_mha_window` takes them — rows
-    at and past a length come out 0 and a query tile of them alone
-    walks nothing."""
+    copied out) and a grid step takes a group of heads over one (query
+    tile, key tile), the rotary key tile fetched once for the group.
+
+    The schedule is ``flash_mha_window``'s without a window, its tiles
+    this kernel's own choice from its shapes (``_mla_tiles``,
+    ``_band_schedule``): the grid holds no step above the diagonal; a
+    key tile wholly below it runs without a mask, ``inner`` rows at a
+    time over the whole tile; the diagonal's tile is walked in
+    sub-blocks (``_band_walk``), of which only those a row can see are
+    computed and only those the diagonal cuts are masked.  A head's
+    scores are ONE product: its rotary query lanes share a lane tile
+    with the step's other heads', so the tile is multiplied whole with
+    the rotary key beside zeros ([k_r | 0] or [0 | k_r], built here) —
+    the 128-deep pass the MXU pays for 64 lanes anyway.
+    :func:`prompt_tile_work` (``latent=True``) counts the walk; the
+    gauges ``mla_flash.tile_q`` / ``.tile_k`` / ``.subtile`` /
+    ``.heads_per_step`` say what was chosen.
+
+    ``lengths`` (B,): each prompt's rows in its bucket of T, as
+    :func:`flash_mha_window` takes them — rows at and past a length
+    come out 0, a query tile of them alone walks nothing and fetches
+    nothing, and of the last live tile the blocks of ``inner`` rows
+    past the prompt's are left out."""
+    from .. import profiler
+
     B, T, _ = q.shape
-    H, n, dv = int(heads), int(nope_dim), int(v_dim)
+    H = int(heads)
+    tiles = _mla_tiles(T, H, int(nope_dim), q_r.shape[2] // H, int(v_dim))
+    profiler.set_gauge("mla_flash.tile_q", tiles[0])
+    profiler.set_gauge("mla_flash.tile_k", tiles[1])
+    profiler.set_gauge("mla_flash.subtile", tiles[2])
+    profiler.set_gauge("mla_flash.heads_per_step", tiles[4])
+    return _mla_flash(q, q_r, kv, k_r, _prompt_lengths(lengths, B, T),
+                      heads=H, nope_dim=int(nope_dim), v_dim=int(v_dim),
+                      scale=float(scale), tiles=tiles)
+
+
+# jitted, as `_flash_mha_window` is: a program's layers share ONE trace
+# of the kernel and one lowered function
+@functools.partial(jax.jit, static_argnames=("heads", "nope_dim", "v_dim",
+                                             "scale", "tiles"))
+def _mla_flash(q, q_r, kv, k_r, lens, *, heads, nope_dim, v_dim, scale,
+               tiles):
+    B, T, _ = q.shape
+    H, n, dv = heads, nope_dim, v_dim
     r = q_r.shape[2] // H
-    hb = _mla_heads_per_step(H, r) or math.gcd(H, 4)
-    blk = _mha_block(_MLA_BLOCK, T)
+    bq, bk, sub, inner, hb = tiles
     v_in = kv
     if (H * n) % (hb * dv):       # v's lanes start inside a block of it
         v_in = kv[..., H * n:]
     v_first = 0 if v_in is not kv else H * n // (hb * dv)
-    qf, qr, kf, kr, vf = (_pad_to(x, 1, blk) for x in (q, q_r, kv, k_r,
-                                                       v_in))
-    nq = qf.shape[1] // blk
+    # `per` heads' rotary lanes a lane tile (a power of two, as `hb`
+    # is): the rotary key once a place in the tile, zeros beside it
+    per = math.gcd(hb, max(1, 128 // r))
+    k_w = (jnp.eye(per, dtype=k_r.dtype)[:, :, None]
+           * k_r[:, :, None, None, :]).reshape(B, T, per * per * r)
+    qf, qr, kf, kr, vf = (_pad_to(x, 1, max(bq, bk))
+                          for x in (q, q_r, kv, k_w, v_in))
+    rows = qf.shape[1]
+    plan = _band_schedule(rows, bq, bk, sub, 0)
+    steps = int((plan.last + 1).max())
+    last = functools.partial(_diagonal_tile, block_q=bq, block_k=bk)
 
     def q_map(b, g, qi, kj, len_ref):
-        return (b, jnp.minimum(qi, _last_live_tile(len_ref[b], blk)), g)
+        return (b, jnp.minimum(qi, _last_live_tile(len_ref[b], bq)), g)
 
     def seen(b, qi, kj, len_ref):
-        # above the diagonal, and through a dead tile, the index stands
-        # still: no tile is fetched
-        return _walked_key_tile(qi, kj, _last_live_tile(len_ref[b], blk),
-                                lambda qi: 0, nq)
+        # past the diagonal's tile, and through a dead query tile, the
+        # index stands still: no tile is fetched
+        return _walked_key_tile(qi, kj, _last_live_tile(len_ref[b], bq),
+                                lambda qi: 0, steps, last)
 
     kern = functools.partial(
-        _mla_flash_kernel, hb=hb, n=n, r=r, dv=dv, block=blk,
-        scale=float(scale))
+        _mla_flash_kernel, hb=hb, per=per, n=n, r=r, dv=dv, block_q=bq,
+        block_k=bk, sub=sub, inner=inner, scale=scale, edges=plan.edges,
+        interior=plan.interior)
     o = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(B, H // hb, nq, nq),
+            grid=(B, H // hb, rows // bq, steps),
             in_specs=[
-                _vmem_spec((1, blk, hb * n), q_map),
-                _vmem_spec((1, blk, hb * r), q_map),
-                _vmem_spec((1, blk, hb * n), lambda b, g, qi, kj, len_ref:
+                _vmem_spec((1, bq, hb * n), q_map),
+                _vmem_spec((1, bq, hb * r), q_map),
+                _vmem_spec((1, bk, hb * n), lambda b, g, qi, kj, len_ref:
                            (b, seen(b, qi, kj, len_ref), g)),
-                _vmem_spec((1, blk, r), lambda b, g, qi, kj, len_ref:
+                _vmem_spec((1, bk, per * per * r),
+                           lambda b, g, qi, kj, len_ref:
                            (b, seen(b, qi, kj, len_ref), 0)),
-                _vmem_spec((1, blk, hb * dv), lambda b, g, qi, kj, len_ref:
+                _vmem_spec((1, bk, hb * dv), lambda b, g, qi, kj, len_ref:
                            (b, seen(b, qi, kj, len_ref), v_first + g)),
             ],
             out_specs=_vmem_spec(
-                (1, blk, hb * dv),
+                (1, bq, hb * dv),
                 lambda b, g, qi, kj, len_ref: (b, qi, g)),
-            scratch_shapes=[pltpu.VMEM((hb, blk, dv), jnp.float32),
-                            pltpu.VMEM((hb, blk, 128), jnp.float32),
-                            pltpu.VMEM((hb, blk, 128), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((B, qf.shape[1], H * dv), kv.dtype),
+            scratch_shapes=[pltpu.VMEM((hb, bq, dv), jnp.float32),
+                            pltpu.VMEM((hb, bq, 128), jnp.float32),
+                            pltpu.VMEM((hb, bq, 128), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((B, rows, H * dv), kv.dtype),
         compiler_params=_compiler_params(
             "parallel", "parallel", "parallel", "arbitrary",
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=_interpret(),
         name="mla_flash_fwd",
-    )(_prompt_lengths(lengths, B, T), qf, qr, kf, kr, vf)
+    )(lens, qf, qr, kf, kr, vf)
     return o[:, :T]
 
 

@@ -237,12 +237,26 @@ def test_paged_kernel_walks_rows_of_unequal_length_and_an_empty_row(
         np.testing.assert_allclose(got[b], want, atol=1e-5)
 
 
-def test_prefill_kernel_pads_a_prompt_that_fills_no_tile(monkeypatch):
+# a prompt of two query tiles, the last ragged; B = 2 of unequal lengths,
+# one ending inside an edge sub-block, one a single row; a prompt that
+# fills its bucket
+@pytest.mark.parametrize("T, lengths", [
+    (200, None), (200, (150, 1)), (256, (256, 97))],
+    ids=["fills_no_tile", "unequal_lengths", "fills_the_bucket"])
+@pytest.mark.parametrize("tiles", [
+    (128, 128, 32, 64, 4), (64, 128, 32, 32, 2), None], ids=str)
+def test_prefill_kernel_under_its_walk_is_the_lax_body(monkeypatch, tiles,
+                                                       T, lengths):
+    # `mla_flash` under the band schedule — a bare body below the
+    # diagonal, the diagonal's tile in sub-blocks, key tiles wider than
+    # query tiles — at small tiles and at the ones `_mla_tiles` picks
+    # (None: one query tile of 256 rows here)
     monkeypatch.setenv("MXNET_PALLAS", "1")
     from mxnet_tpu.ops import pallas_kernels as pk
-    monkeypatch.setattr(pk, "_MLA_BLOCK", 128)
+    if tiles:
+        monkeypatch.setattr(pk, "_mla_tiles", lambda t, *widths: tiles)
     rng = np.random.default_rng(1)
-    B, T, H, n, r, dv = 2, 200, 4, 16, 8, 16   # two tiles, the last ragged
+    B, H, n, r, dv = 2, 4, 16, 8, 16
 
     def arr(*shape):
         return jnp.asarray(rng.normal(size=shape), jnp.float32)
@@ -250,10 +264,14 @@ def test_prefill_kernel_pads_a_prompt_that_fills_no_tile(monkeypatch):
     q_n, q_r, k_n, k_r, v = (arr(B, T, H * n), arr(B, T, H * r),
                              arr(B, T, H * n), arr(B, T, r),
                              arr(B, T, H * dv))
-    got = pk.mla_flash(jnp.concatenate([q_n, q_r], -1), q_r,
-                       jnp.concatenate([k_n, v], -1), k_r, H, n, dv, 0.2)
-    want = hy.mla_causal(q_n, q_r, k_n, k_r, v, H, 0.2)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
+    got = np.asarray(pk.mla_flash(
+        jnp.concatenate([q_n, q_r], -1), q_r, jnp.concatenate([k_n, v], -1),
+        k_r, H, n, dv, 0.2,
+        lengths=None if lengths is None else jnp.asarray(lengths)))
+    want = np.asarray(hy.mla_causal(q_n, q_r, k_n, k_r, v, H, 0.2))
+    for b, rows in enumerate(lengths or (T, T)):
+        np.testing.assert_allclose(got[b, :rows], want[b, :rows], atol=2e-5)
+        assert np.all(got[b, rows:] == 0)
 
 
 # -- the router -----------------------------------------------------------
@@ -403,7 +421,9 @@ def small_tiles(monkeypatch):
     of 32 rows: a bucket of 96 is three query tiles."""
     monkeypatch.setenv("MXNET_PALLAS", "1")
     from mxnet_tpu.ops import pallas_kernels as pk
-    monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: 32)
+    heads = pk._mla_tiles
+    monkeypatch.setattr(pk, "_mla_tiles", lambda t, *widths: (
+        32, 32, 32, 32, heads(t, *widths)[4]))
 
 
 @pytest.mark.parametrize("n_prompt", [20, 96], ids=["lower_half", "fills"])

@@ -32,9 +32,10 @@ LENGTHS = [(1, 127), (128, 129), (300, 512)]
 def interpreted(monkeypatch):
     """The kernels interpreted, in tiles of 128 rows."""
     monkeypatch.setenv("MXNET_PALLAS", "1")
-    monkeypatch.setattr(pk, "_mha_block", lambda block_size, t: BLOCK)
     monkeypatch.setattr(pk, "_mha_window_tiles",
                         lambda t, window: (BLOCK, BLOCK, BLOCK, BLOCK))
+    monkeypatch.setattr(pk, "_mla_tiles",
+                        lambda t, *widths: (BLOCK, BLOCK, BLOCK, BLOCK, 4))
 
 
 def padded(x, lengths):
@@ -130,8 +131,9 @@ def computed_steps(monkeypatch, call):
     def when(cond):
         def bind(body):
             if body.__name__ in ("_init", "_dead", "_finalize",
-                                 "_finalize_last"):     # the others: a
-                # tile's unmasked body, an edge tile's walk, mla's tile
+                                 "_finalize_last", "_rows"):    # the
+                # others: a tile's unmasked body, an edge tile's walk
+                # (`_rows`: a block of rows inside one of them)
                 return real(cond)(body)
 
             def counted():
@@ -141,10 +143,12 @@ def computed_steps(monkeypatch, call):
         return bind
 
     monkeypatch.setattr(pk.pl, "when", when)
-    pk._flash_mha_window.clear_cache()      # a trace of its own, with
+    for jitted in (pk._flash_mha_window, pk._mla_flash):
+        jitted.clear_cache()                # a trace of its own, with
     jax.block_until_ready(call())           # this ``when``, dropped after
     jax.effects_barrier()
-    pk._flash_mha_window.clear_cache()
+    for jitted in (pk._flash_mha_window, pk._mla_flash):
+        jitted.clear_cache()
     return len(hits)
 
 
@@ -178,8 +182,10 @@ def test_prompt_tile_visits_are_the_latent_kernels_steps(
 def test_prompt_tile_visits_at_the_cells_shapes():
     # longdoc's t32768 (query tiles of 1,024 rows over key tiles of
     # 2,048): a global layer and a windowed one at a prompt a little
-    # over half the bucket; longctx's t8192 (tiles of 512)
+    # over half the bucket; longctx's t8192 under the same tiles: five
+    # live query tiles of 1, 1, 2, 2, 3 key tiles, the three dead ones
+    # would have walked 3, 4, 4
     assert pk.prompt_tile_visits(17000, 32768) == (81, 191)
     assert pk.prompt_tile_visits(17000, 32768, 4096) == (45, 45)
-    assert pk.prompt_tile_visits(5000, 8192, latent=True) == (55, 81)
+    assert pk.prompt_tile_visits(5000, 8192, latent=True) == (9, 11)
     assert pk.prompt_tile_visits(32768, 32768) == (272, 0)

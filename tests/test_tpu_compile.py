@@ -709,16 +709,25 @@ def test_mla_flash_at_the_longctx_cells_shapes(on_chip, one_chip, T):
     assert "flash_fwd_mha" not in kernels[0]
 
 
-@pytest.mark.parametrize("T", [8192, 4096])
+# the cell's four prefill buckets under the tiles `_mla_tiles` picks at
+# the published widths: a step's blocks, double buffered, and its three
+# states must fit the VMEM the kernel asks for — here, not on the chip
+@pytest.mark.parametrize("T, tiles", [
+    (8192, (1024, 2048, 256, 512, 4)), (4096, (1024, 2048, 256, 512, 4)),
+    (2048, (1024, 2048, 256, 512, 4)), (1024, (1024, 1024, 256, 512, 4))])
 def test_mla_flash_takes_the_prompts_length_at_the_longctx_cells_shapes(
-        on_chip, one_chip, T):
+        on_chip, one_chip, T, tiles):
     _, _, H, n, r, dv, _ = _LONGCTX
+    assert pk._mla_tiles(T, H, n, r, dv) == tiles
     compiled = _compile(lambda q, qr, kv, kr, m: pk.mla_flash(
         q, qr, kv, kr, H, n, dv, 0.13523, lengths=m), one_chip,
         ((1, T, H * (n + r)), bf16), ((1, T, H * r), bf16),
         ((1, T, H * (n + dv)), bf16), ((1, T, r), bf16), ((1,), i32))
     assert _kernel_short_names(compiled.as_text()) == ["mla_flash_fwd"]
-    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+    # nothing is copied beside the operands but the rotary key spread
+    # to its two lane tiles: T rows of 256 bfloat16 lanes
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < (1 << 20) + T * 256 * 2
 
 
 def test_mla_paged_decode_at_the_longctx_cells_shapes(on_chip, one_chip):

@@ -1,14 +1,19 @@
-"""The tile schedule of the grouped-query prompt kernel
-(``flash_mha_window``: ``flash_fwd_window`` / ``flash_fwd_mha``): a tile
-wholly inside the band runs without a mask, a tile an edge of the band
-crosses is walked in sub-blocks of which only those a row can see are
-computed and only those an edge cuts are masked.  Interpreted on the
+"""The tile schedule of the prompt kernels — the grouped-query one
+(``flash_mha_window``: ``flash_fwd_window`` / ``flash_fwd_mha``) and,
+without a window, the latent one (``mla_flash``: ``mla_flash_fwd``): a
+tile wholly inside the band runs without a mask, a tile an edge of the
+band crosses is walked in sub-blocks of which only those a row can see
+are computed and only those an edge cuts are masked.  Interpreted on the
 CPU: the schedule against the lax body for windows that end inside a
 tile and inside a sub-block, square and non-square tiles, groups of 1, 6
-and 7 query heads; the prompt's length as tests/test_prompt_lengths.py
-holds it, at sub-block edges too; and the host's count of the masked
-tiles and the computed scores (``prompt_tile_work``) against the
-kernel's own blocks."""
+and 7 query heads (of 1, 2 and 4 latent heads a step); the prompt's
+length as tests/test_prompt_lengths.py holds it, at sub-block edges too;
+the host's count of the masked tiles and the computed scores
+(``prompt_tile_work``) against the kernels' own blocks; and the
+grouped-query kernels' program held to what it was before the latent
+kernel shared its walk."""
+
+import hashlib
 
 import os
 import sys
@@ -24,6 +29,7 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from mxnet_tpu.ops import attention as att  # noqa: E402
+from mxnet_tpu.ops import hybrid as hy  # noqa: E402
 from mxnet_tpu.ops import pallas_kernels as pk  # noqa: E402
 
 # (block_q, block_k, sub, inner): a square tile of 4 x 4 sub-blocks whose
@@ -147,11 +153,12 @@ def test_no_lengths_is_the_buckets_rows(interpreted, monkeypatch, tiles):
 
 # -- the host's counts against the kernel's own blocks ------------------------
 
-def kernel_counts(monkeypatch, call):
+def kernel_counts(monkeypatch, call, width=16):
     """(walked, masked, computed) of the interpreted kernel ``call``
     runs, counted as it runs: the grid steps that took a computing
     body, those of them whose body is the edge tiles', and the elements
-    of every q . k product."""
+    of every q . k product over ``width`` lanes (a head's; the latent
+    kernel's rotary product beside it is over other lanes)."""
     hits = {"walked": 0, "masked": 0, "computed": 0}
     real_when, real_dot = pk.pl.when, pk._dot
 
@@ -172,17 +179,19 @@ def kernel_counts(monkeypatch, call):
         return bind
 
     def dot(a, b, ca, cb):
-        if (ca, cb) == (1, 1):          # the scores: q . k
+        if (ca, cb) == (1, 1) and a.shape[1] == width:  # the scores
             n = a.shape[0] * b.shape[0]
             jax.debug.callback(lambda: bump("computed", n))
         return real_dot(a, b, ca, cb)
 
     monkeypatch.setattr(pk.pl, "when", when)
     monkeypatch.setattr(pk, "_dot", dot)
-    pk._flash_mha_window.clear_cache()      # a trace of its own, with
+    for jitted in (pk._flash_mha_window, pk._mla_flash):
+        jitted.clear_cache()                # a trace of its own, with
     jax.block_until_ready(call())           # these two, dropped after
     jax.effects_barrier()
-    pk._flash_mha_window.clear_cache()
+    for jitted in (pk._flash_mha_window, pk._mla_flash):
+        jitted.clear_cache()
     monkeypatch.setattr(pk.pl, "when", real_when)
     monkeypatch.setattr(pk, "_dot", real_dot)
     return hits["walked"], hits["masked"], hits["computed"]
@@ -205,11 +214,14 @@ def test_prompt_tile_work_is_the_kernels_own_blocks(
     assert masked <= walked
 
 
-def seen_blocks(length, rows, window, block_q, block_k, sub):
+def seen_blocks(length, rows, window, block_q, block_k, sub, guard=None):
     """(walked, masked, computed) from the pairs themselves: a (sub x
     sub) block of the score matrix is computed where a row of a live
-    query tile sees one of its columns, a tile is walked where it holds
-    such a block and masked where an edge cuts one of them."""
+    query tile sees one of its columns (``guard``: of a block of
+    ``guard`` rows that holds a row of the prompt — the latent kernel
+    leaves the others of its last live tile out), a tile is walked where
+    a live query tile's row sees a column of it and masked where an
+    edge cuts one of its blocks."""
     n = rows // sub
     i = np.arange(n)[:, None] * sub
     j = np.arange(n)[None, :] * sub
@@ -219,13 +231,16 @@ def seen_blocks(length, rows, window, block_q, block_k, sub):
         seen &= j + sub - 1 > i - window
         whole &= j > i + sub - 1 - window
     seen[-(-length // block_q) * block_q // sub:] = False
+    computed = seen.copy()
+    if guard:
+        computed[-(-length // guard) * guard // sub:] = False
 
     def tiles(blocks):
         return int(blocks.reshape(rows // block_q, block_q // sub,
                                   rows // block_k, block_k // sub)
                    .any(axis=(1, 3)).sum())
 
-    return tiles(seen), tiles(seen & ~whole), int(seen.sum()) * sub * sub
+    return tiles(seen), tiles(seen & ~whole), int(computed.sum()) * sub * sub
 
 
 @pytest.mark.parametrize("length, rows, window", [
@@ -269,9 +284,6 @@ def test_prompt_tile_work_at_the_cells_shapes():
     assert (m, round(c / n, 4)) == (4 + 2 * 28, 1.0625)
     m, c, n = pk.prompt_tile_work(32768, 32768)
     assert (m, round(c / n, 4)) == (32, 1.0078)
-    # mla_flash masks its diagonal's whole tile (tiles of 512)
-    assert pk.prompt_tile_work(5000, 8192, latent=True) == (
-        10, 55 * 512 * 512, 5000 * 5001 // 2)
 
 
 @pytest.mark.parametrize("block_q, block_k, sub", [
@@ -287,3 +299,225 @@ def test_band_walk_without_a_window_is_the_packed_familys_walk(
                        if p[0] is not None])
                 for blk, whole, diag in pk._walk(off, block_q, block_k, sub)]
         assert pk._band_walk(off, block_q, block_k, sub) == want
+
+
+# -- the latent prompt kernel under the same walk -----------------------------
+
+# (block_q, block_k, sub, inner, heads a step): TILES with the heads of a
+# grid step — all four, a pair twice, and one at a time
+MLA_TILES = [(128, 128, 32, 64, 4), (64, 128, 32, 32, 2),
+             (128, 64, 32, 128, 4), (128, 128, 128, 128, 1)]
+# heads, nope, v, scale (the rotary 8): a nope width with which v's
+# lanes start inside a block of four heads' (copied out) and on a block
+# of two's (a window on kv)
+MLA_DIMS = (4, 24, 16, 0.2)
+
+
+def use_mla_tiles(monkeypatch, tiles):
+    monkeypatch.setattr(pk, "_mla_tiles", lambda t, *widths: tiles)
+
+
+def mla_inputs(T, B=1, seed=0):
+    H, n, dv, _ = MLA_DIMS
+    rng = np.random.default_rng(seed)
+    return [rng.normal(size=(B, T, lanes)).astype(np.float32)
+            for lanes in (H * (n + 8), H * 8, H * (n + dv), 8)]
+
+
+def mla_lax_body(q, q_r, kv, k_r):
+    H, n, _, scale = MLA_DIMS
+    return np.asarray(hy.mla_causal(
+        *(jnp.asarray(x) for x in (q[..., :H * n], q_r, kv[..., :H * n],
+                                   k_r, kv[..., H * n:])), H, scale))
+
+
+def held_to_the_lax_body(xs, lengths):
+    """``mla_flash`` over ``xs``: rows below a length (None: every row)
+    the lax body's, rows at and past it zeros."""
+    got = np.asarray(pk.mla_flash(
+        *xs, *MLA_DIMS,
+        lengths=None if lengths is None else jnp.asarray(lengths)))
+    want = mla_lax_body(*xs)
+    for b, n in enumerate(lengths or [xs[0].shape[1]] * len(want)):
+        np.testing.assert_allclose(got[b, :n], want[b, :n], atol=2e-5)
+        assert np.all(got[b, n:] == 0)
+
+
+# every shape `_mla_tiles` can return: one query tile of the bucket's
+# rows in 128s (sub-blocks of 128 or 256, its rows whole or in 512s),
+# 1,024 rows over key tiles of 1,024, and of 2,048 where the rows
+# divide; prompts that end inside an edge sub-block, leave a block of
+# 512 rows out, fill no tile and fill the bucket
+@pytest.mark.parametrize("T, lengths", [
+    (200, None), (200, (77,)), (640, (300, 640)), (1024, (513, 1000)),
+    (1500, None), (2048, (1500,)), (2100, (1100,))])
+def test_the_latent_kernels_chosen_tiles_against_the_lax_body(
+        interpreted, T, lengths):
+    held_to_the_lax_body(mla_inputs(T, B=len(lengths or [0]), seed=T),
+                         lengths)
+
+
+def test_the_latent_kernels_tiles_come_from_its_shapes():
+    # the walk from the bucket alone (the host's counts ask with no
+    # width), the heads a step from their count and rotary width
+    for t, want in ((200, (256, 256, 256, 256)), (640, (640, 640, 128, 640)),
+                    (1024, (1024, 1024, 256, 512)),
+                    (2048, (1024, 2048, 256, 512)),
+                    (3000, (1024, 1024, 256, 512)),
+                    (8192, (1024, 2048, 256, 512))):
+        assert pk._mla_tiles(t, 128, 128, 64, 128) == want + (4,)
+        assert pk._mla_tiles(t, 0, 0, 0, 0)[:4] == want
+        assert pk._prompt_schedule(t, 0, True)[0] == want[0]
+    assert pk._mla_tiles(1024, 6, 128, 64, 128)[4] == 2
+    assert pk._mla_tiles(1024, 4, 16, 8, 16)[4] == 4     # interpreted
+    assert pk._mla_tiles(1024, 3, 16, 8, 16)[4] == 1
+
+
+@pytest.mark.parametrize("tiles", MLA_TILES, ids=str)
+@pytest.mark.parametrize("T, lengths", [
+    (512, None), (500, None), (512, (31, 300)), (300, (129, 33)),
+    (512, (128, 512))])
+def test_every_latent_tile_shape_against_the_lax_body(
+        interpreted, monkeypatch, tiles, T, lengths):
+    use_mla_tiles(monkeypatch, tiles)
+    held_to_the_lax_body(mla_inputs(T, B=len(lengths or [0]), seed=T),
+                         lengths)
+
+
+@pytest.mark.parametrize("tiles", MLA_TILES[:3], ids=str)
+@pytest.mark.parametrize("lengths", LENGTHS)
+def test_latent_rows_below_a_length_do_not_depend_on_it(
+        interpreted, monkeypatch, tiles, lengths):
+    use_mla_tiles(monkeypatch, tiles)
+    xs = mla_inputs(T, B=2, seed=2)
+    whole = np.asarray(pk.mla_flash(*xs, *MLA_DIMS))
+    got = np.asarray(pk.mla_flash(
+        *(padded(x, lengths, tiles[0]) for x in xs), *MLA_DIMS,
+        lengths=jnp.asarray(lengths, jnp.int32)))
+    for b, n in enumerate(lengths):
+        assert np.array_equal(got[b, :n], whole[b, :n])
+        assert np.all(got[b, n:] == 0)
+
+
+@pytest.mark.parametrize("tiles", MLA_TILES, ids=str)
+@pytest.mark.parametrize("length", [129, 300, 512])
+def test_latent_prompt_tile_work_is_the_kernels_own_blocks(
+        interpreted, monkeypatch, tiles, length):
+    use_mla_tiles(monkeypatch, tiles)
+    xs = mla_inputs(T)
+    walked, skipped = pk.prompt_tile_visits(length, T, latent=True)
+    masked, computed, needed = pk.prompt_tile_work(length, T, latent=True)
+    # a head's scores are ONE product over its nope lanes and the lane
+    # tile its rotary ones share with the step's other heads
+    width = 24 + 8 * tiles[4]
+    got = kernel_counts(monkeypatch, lambda: pk.mla_flash(
+        *xs, *MLA_DIMS, lengths=jnp.asarray([length])), width=width)
+    groups = MLA_DIMS[0] // tiles[4]        # a grid step a group of heads
+    assert got == (groups * walked, groups * masked,
+                   MLA_DIMS[0] * computed)
+    assert needed == length * (length + 1) // 2 <= computed
+    # without the length the whole bucket's tiles are walked
+    assert kernel_counts(monkeypatch, lambda: pk.mla_flash(
+        *xs, *MLA_DIMS), width=width)[0] == groups * (walked + skipped)
+
+
+@pytest.mark.parametrize("length, rows", [
+    (5000, 8192), (8192, 8192), (3072, 4096), (4096, 4096), (1500, 2048),
+    (1229, 2048), (700, 1024), (1024, 1024)])
+def test_latent_prompt_tile_work_counts_the_blocks_a_row_can_see(length,
+                                                                 rows):
+    bq, bk, sub, inner = pk._mla_tiles(rows, 128, 128, 64, 128)[:4]
+    walked, _ = pk.prompt_tile_visits(length, rows, latent=True)
+    masked, computed, _ = pk.prompt_tile_work(length, rows, latent=True)
+    assert (walked, masked, computed) == seen_blocks(
+        length, rows, 0, bq, bk, sub, guard=inner)
+
+
+def test_latent_prompt_tile_work_at_the_cells_shapes():
+    # longctx's t8192: the grouped-query kernel's tiles.  A prompt of
+    # 5,000 rows has five live query tiles of 1, 1, 2, 2, 3 key tiles
+    # (before PR 47, square tiles of 512 masked whole: 55 of 136 tiles
+    # walked, 10 of them masked, 55 x 512 x 512 scores)
+    edge, span = 10 * 256 * 256, 1024 * 1024
+    assert pk.prompt_tile_visits(5000, 8192, latent=True) == (9, 11)
+    assert pk.prompt_tile_work(5000, 8192, latent=True) == (
+        5, (0 + 1 + 2 + 3 + 4) * span + 5 * edge, 5000 * 5001 // 2)
+    # ISSUE 47's example, the last live tile's padding rows at 1,500 in
+    # 2,048: the tile's second block of 512 rows is left out (2.10
+    # computed over needed with it, 1.40 with query tiles of 512)
+    m, c, n = pk.prompt_tile_work(1500, 2048, latent=True)
+    assert (m, c, round(c / n, 2)) == (2, 1376256, 1.22)
+    m, c, n = pk.prompt_tile_work(2458, 4096, latent=True)
+    assert (m, c, round(c / n, 2)) == (3, 3604480, 1.19)
+    m, c, n = pk.prompt_tile_work(8192, 8192, latent=True)
+    assert (m, round(c / n, 4)) == (8, 1.0311)
+
+
+def test_the_kernel_says_what_it_chose(interpreted, monkeypatch):
+    from mxnet_tpu import profiler
+
+    use_mla_tiles(monkeypatch, MLA_TILES[1])
+    profiler.reset_metrics()
+    pk.mla_flash(*mla_inputs(256), *MLA_DIMS)
+    g = profiler.metrics_summary()["gauges"]
+    assert (g["mla_flash.tile_q"], g["mla_flash.tile_k"],
+            g["mla_flash.subtile"], g["mla_flash.heads_per_step"]) == (
+        64, 128, 32, 2)
+
+
+# -- the grouped-query kernels are left alone ---------------------------------
+
+def test_the_grouped_query_schedule_at_the_cells_shapes_is_pinned():
+    # longdoc's and mixed's buckets: what `_mha_window_tiles` and
+    # `_band_schedule` gave before the latent kernel shared them
+    for t, tiles in ((32768, (1024, 2048, 256, 512)),
+                     (8192, (1024, 2048, 256, 512)),
+                     (4096, (1024, 2048, 256, 512)),
+                     (2048, (1024, 2048, 256, 512)),
+                     (1024, (1024, 1024, 256, 512)),
+                     (3000, (1024, 1024, 256, 512))):
+        assert pk._mha_window_tiles(t, 4096) == tiles
+        assert pk._mha_window_tiles(t, 0) == tiles
+    plan = pk._band_schedule(32768, 1024, 2048, 256, 4096)
+    assert (plan.band, plan.edges, plan.interior) == (
+        4096, (-5120, -4096, -1024, 0), True)
+    assert plan.first.tolist() == [0] * 6 + [i // 2 for i in range(2, 28)]
+    assert plan.last.tolist() == [i // 2 for i in range(32)]
+    assert int(plan.masked.sum()) == 60
+    assert int(plan.scores.sum()) == 133693440
+    assert pk.prompt_tile_work(32768, 32768, 4096) == (
+        60, 133693440, 125831168)
+    plan = pk._band_schedule(8192, 1024, 2048, 256, 0)
+    assert (plan.band, plan.edges, plan.interior) == (0, (-1024, 0), True)
+    assert plan.first.tolist() == [0] * 8
+    assert plan.last.tolist() == [0, 0, 1, 1, 2, 2, 3, 3]
+    assert plan.masked.tolist() == [1] * 8
+    assert plan.scores.tolist() == [655360 + 1048576 * i for i in range(8)]
+    assert pk.prompt_tile_work(5000, 8192) == (5, 13762560, 12502500)
+    assert pk.prompt_tile_work(5000, 8192, 4096) == (6, 13369344, 12093440)
+
+
+@pytest.mark.parametrize("Hq, Hkv, t, window, text", [
+    (48, 8, 32768, 4096, ("d9c22be346b75a79792e6a37dafd218f"
+                          "18bf7f411f05e3dd45442307c7929ce1", 94256)),
+    (48, 8, 32768, 0, ("ac6296fba1cc7ccbe48a815a99bc6175"
+                       "d91e80ac39efb57b74111e6a8bb9c550", 54182)),
+    (28, 4, 8192, 4096, ("aabfaf10d3faeac23009eb28d1cf46bc"
+                         "9a1872310b424d2419515f97898fb596", 94245)),
+], ids=["longdoc_window", "longdoc_global", "mixed_window"])
+def test_the_grouped_query_kernels_program_is_the_parents(Hq, Hkv, t, window,
+                                                          text):
+    # `flash_mha_window` traced at a cell's shape (nothing runs): the
+    # program's text — the kernel's jaxpr, its grid and blocks —
+    # character for character what commit 3c67cef traced (its sha256 and
+    # length), from before `_band_update` was shared with `mla_flash`.
+    # A change MEANT for this kernel re-pins both; one meant for the
+    # latent kernel must not move them
+    def sds(heads):
+        return jax.ShapeDtypeStruct((heads, t, 128), jnp.bfloat16)
+
+    got = str(jax.make_jaxpr(
+        lambda q, k, v, n: pk.flash_mha_window(q, k, v, window, Hq, Hkv,
+                                               lengths=n))(
+        sds(Hq), sds(Hkv), sds(Hkv), jax.ShapeDtypeStruct((1,), jnp.int32)))
+    assert (hashlib.sha256(got.encode()).hexdigest(), len(got)) == text

@@ -44,7 +44,14 @@ after ANY kernel change:
     python tools/verify_kernels.py --mla    # the latent-attention kernels
                                             # (prefill, paged decode, page
                                             # write) at the longctx cell's
-                                            # widths, ms a call each
+                                            # widths, ms a call each; the
+                                            # prefill kernel's chosen tiles
+                                            # and share of its roofline
+    python tools/verify_kernels.py --mla-tiles --fill 1,0.6,0.75
+                                            # the latent prefill kernel's
+                                            # tiles, sub-blocks and heads a
+                                            # step at the longctx cell's
+                                            # four buckets, ms a call each
     python tools/verify_kernels.py --longdoc --window --mla --fill 1,0.6
                                             # the prompt kernels alone at
                                             # a prompt of F x T rows in
@@ -396,15 +403,15 @@ def check_window_flash(T, window, Hq=28, Hkv=4, D=128):
     return ok
 
 
-def _gqa_schedule_note(n, T, window):
+def _gqa_schedule_note(n, T, window, latent=False):
     """The schedule's own counts for a prompt of n rows in a bucket of
-    T (host arithmetic, ``pk.prompt_tile_work``): the share of the
-    walked tiles that took a masked body, and the scores computed over
-    the pairs the band holds."""
+    T (host arithmetic, ``pk.prompt_tile_work``; ``latent``: the latent
+    prompt kernel's): the share of the walked tiles that took a masked
+    body, and the scores computed over the pairs the band holds."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    walked, _ = pk.prompt_tile_visits(n, T, window)
-    masked, done, needed = pk.prompt_tile_work(n, T, window)
+    walked, _ = pk.prompt_tile_visits(n, T, window, latent)
+    masked, done, needed = pk.prompt_tile_work(n, T, window, latent)
     return (f"masked={masked}/{walked} tiles "
             f"scores x{done / max(needed, 1):.3f}")
 
@@ -481,14 +488,7 @@ def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
     from mxnet_tpu.ops import hybrid as hy
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    rng = np.random.RandomState(T)
-
-    def arr(lanes, s=0.5):
-        return jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
-                           * s).astype(jnp.bfloat16)
-
-    q, q_r, kv, k_r = arr(H * (n + r)), arr(H * r), arr(H * (n + dv)), \
-        arr(r, 1.5)
+    q, q_r, kv, k_r = _mla_inputs(T, H, n, r, dv)
     assert pk.mla_flash_enabled(H, n, r, dv)
     kern = jax.jit(lambda q, q_r, kv, k_r: pk.mla_flash(
         q, q_r, kv, k_r, H, n, dv, scale))
@@ -500,12 +500,112 @@ def check_mla_flash(T, H=128, n=128, r=64, dv=128, scale=0.13523):
     err = float(np.abs(got - want).max() / max(np.abs(want).max(), 1e-9))
     ok = err < TOL and bool(np.isfinite(got).all())
     ms = _kernel_ms(lambda x: kern(x, q_r, kv, k_r), q, n=3)
-    pairs = T * (T + 1) / 2
-    least = 2.0 * H * (n + r + dv) * pairs / 197e12 * 1e3
+    least = _mla_flops(T, H, n, r, dv) / 197e12 * 1e3
     print(f"{'OK ' if ok else 'FAIL'} mla_flash T={T} H={H} "
-          f"qk={n}+{r} v={dv}: fwd={err:.4f} least={least:.3f}ms"
-          + "".join(f" {k}={v:.3f}ms" for k, v in ms.items()), flush=True)
+          f"qk={n}+{r} v={dv} tiles={_mla_tiles_note(T, H, n, r, dv)}: "
+          f"fwd={err:.4f} least={least:.3f}ms"
+          + "".join(f" {k}={v:.3f}ms roofline={100 * least / v:.1f}%"
+                    for k, v in ms.items()), flush=True)
     return ok
+
+
+def _mla_flops(rows, H, n, r, dv):
+    """The FLOP of a prompt of ``rows`` rows' causal pairs in the latent
+    prefill kernel: what its roofline is counted from."""
+    return 2.0 * H * (n + r + dv) * rows * (rows + 1) / 2
+
+
+def _mla_tiles_note(T, H, n, r, dv):
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    bq, bk, sub, inner, hb = pk._mla_tiles(T, H, n, r, dv)
+    return f"{bq}x{bk}/{sub}/{inner}/h{hb}"
+
+
+def _mla_inputs(T, H, n, r, dv):
+    rng = np.random.RandomState(T)
+
+    def arr(lanes, s=0.5):
+        return jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
+                           * s).astype(jnp.bfloat16)
+
+    return arr(H * (n + r)), arr(H * r), arr(H * (n + dv)), arr(r, 1.5)
+
+
+# the longctx cell's four prefill buckets
+MLA_BUCKETS = (8192, 4096, 2048, 1024)
+# (block_q, block_k, sub, inner, heads a step) held against the chosen
+# one; the first is the parent's walk (square tiles of 512 masked whole)
+MLA_TILE_CANDIDATES = (
+    (512, 512, 512, 512, 4), (512, 512, 256, 512, 4),
+    (512, 1024, 256, 512, 4), (512, 2048, 256, 512, 4),
+    (512, 2048, 256, 256, 4), (1024, 512, 256, 512, 4),
+    (1024, 1024, 256, 512, 4), (1024, 1024, 512, 512, 4),
+    (1024, 1024, 256, 256, 4), (1024, 1024, 256, 1024, 4),
+    (1024, 1024, 256, 512, 2), (1024, 2048, 256, 512, 4),
+    (1024, 2048, 512, 512, 4), (1024, 2048, 256, 256, 4),
+    (1024, 2048, 256, 1024, 4), (1024, 2048, 256, 256, 2),
+    (1024, 2048, 256, 256, 8), (2048, 2048, 256, 256, 4),
+    (2048, 2048, 256, 512, 4), (1024, 4096, 256, 256, 4))
+
+
+def sweep_mla_tiles(fills, H=128, n=128, r=64, dv=128, scale=0.13523):
+    """The kernel-alone table of PERF.md (PR 47): ``mla_flash`` under
+    every schedule (block_q, block_k, sub, inner, heads a step) worth
+    holding against the one ``pk._mla_tiles`` picks, at the longctx
+    cell's four buckets and a prompt of F x T rows for each F of
+    ``--fill`` (1: the whole bucket) — ms a call beside the least time
+    of the prompt's real pairs.  A schedule whose sub-block is the tile
+    masks its diagonal tiles whole (the parent's walk at 512 x 512).
+    Every schedule's output is held against the chosen one's."""
+    from mxnet_tpu.ops import pallas_kernels as pk
+
+    results = []
+    chooser = pk._mla_tiles
+    name = "mla_flash_fwd"
+    for T in MLA_BUCKETS:
+        q, q_r, kv, k_r = _mla_inputs(T, H, n, r, dv)
+        cands = [None] + [
+            tiles for tiles in MLA_TILE_CANDIDATES
+            if max(tiles[:2]) <= T and tiles != chooser(T, H, n, r, dv)]
+        want = None
+        for tiles in cands:
+            if tiles is not None:
+                pk._mla_tiles = lambda *a, tiles=tiles: tiles
+            try:
+                fn = jax.jit(lambda q, q_r, kv, k_r, lens: pk.mla_flash(
+                    q, q_r, kv, k_r, H, n, dv, scale, lengths=lens))
+                cells = []
+                for fill in fills:
+                    rows = max(1, min(T, int(round(fill * T))))
+                    lens = jnp.asarray([rows], jnp.int32)
+                    got = np.asarray(fn(q, q_r, kv, k_r, lens)
+                                     .astype(jnp.float32))
+                    if fill == fills[0]:
+                        want = got if want is None else want
+                        err = float(np.abs(got - want).max()
+                                    / max(np.abs(want).max(), 1e-9))
+                        good = err < TOL and bool(np.isfinite(got).all())
+                    ms = _named_ms(lambda x: fn(x, q_r, kv, k_r, lens), q,
+                                   name)
+                    least = _mla_flops(rows, H, n, r, dv) / 197e12 * 1e3
+                    cells.append(
+                        f"fill={fill:g}: {ms:.3f}ms "
+                        f"{100 * least / ms if ms else 0:.1f}% "
+                        + _gqa_schedule_note(rows, T, 0, latent=True))
+                note = _mla_tiles_note(T, H, n, r, dv)
+            except Exception as e:  # noqa: BLE001 — the compiler's no
+                print(f"FAIL mla-tiles T={T} tiles={tiles}: "
+                      f"{type(e).__name__}: {str(e)[:300]}", flush=True)
+                results.append(False)
+                continue
+            finally:
+                pk._mla_tiles = chooser
+            results.append(good)
+            print(f"{'OK ' if good else 'FAIL'} mla-tiles T={T} "
+                  f"tiles={note}{'' if tiles else ' (chosen)'} "
+                  f"gap={err:.4f} | " + " | ".join(cells), flush=True)
+    return results
 
 
 def _fill_report(what, T, n, runs, need_flops, notes=None):
@@ -539,8 +639,7 @@ def _live_rows(n, T, latent=False, window=0):
     length walks, WITHOUT the dead tiles' grid steps."""
     from mxnet_tpu.ops import pallas_kernels as pk
 
-    blk = pk._mha_block(pk._MLA_BLOCK, T) if latent \
-        else pk._mha_window_tiles(T, window)[0]
+    blk = pk._prompt_schedule(T, window, latent)[0]
     return min(T, -(-n // blk) * blk)
 
 
@@ -591,14 +690,7 @@ def check_fill_mla(T, fill, H=128, n=128, r=64, dv=128, scale=0.13523):
     from mxnet_tpu.ops import pallas_kernels as pk
 
     rows = max(1, min(T, int(round(fill * T))))
-    rng = np.random.RandomState(T)
-
-    def arr(lanes, s=0.5):
-        return jnp.asarray(rng.randn(1, T, lanes).astype(np.float32)
-                           * s).astype(jnp.bfloat16)
-
-    q, q_r, kv, k_r = arr(H * (n + r)), arr(H * r), arr(H * (n + dv)), \
-        arr(r, 1.5)
+    q, q_r, kv, k_r = _mla_inputs(T, H, n, r, dv)
     lens = jnp.asarray([rows], jnp.int32)
     cut = jax.jit(lambda q, q_r, kv, k_r, lens: pk.mla_flash(
         q, q_r, kv, k_r, H, n, dv, scale, lengths=lens))
@@ -618,7 +710,9 @@ def check_fill_mla(T, fill, H=128, n=128, r=64, dv=128, scale=0.13523):
         good)}
     return _fill_report(
         f"H={H} qk={n}+{r} v={dv}", T, rows, runs,
-        {name: 2.0 * H * (n + r + dv) * rows * (rows + 1) / 2})
+        {name: _mla_flops(rows, H, n, r, dv)},
+        {name: f"tiles={_mla_tiles_note(T, H, n, r, dv)} "
+               + _gqa_schedule_note(rows, T, 0, latent=True)})
 
 
 def check_mla_paged(B, MB, lengths, H=128, R=512, r=64, KVB=16,
@@ -1149,6 +1243,8 @@ def main():
         return _report(sweep_tiles())
     if "--gqa-tiles" in sys.argv:
         return _report(sweep_gqa_tiles())
+    if "--mla-tiles" in sys.argv:
+        return _report(sweep_mla_tiles(fills or [1.0]))
     if "--pages" in sys.argv:
         # a prompt's K/V write at the serving cells' shapes: doc's one
         # bucket (a whole and a typical prompt), reason's two, mixed's
@@ -1186,7 +1282,7 @@ def main():
         return _report(results)
     for fill in fills:
         if "--mla" in sys.argv:
-            results += [check_fill_mla(T, fill) for T in (8192, 4096)]
+            results += [check_fill_mla(T, fill) for T in MLA_BUCKETS]
         if "--longdoc" in sys.argv:
             results += [check_fill_window(T, 4096, fill, Hq=48, Hkv=8)
                         for T in (32768, 16384, 8192)]
