@@ -62,10 +62,34 @@ MIXER and its own FFN:
   KV head's query heads — and, its auxiliary array, the normaliser
   ``Z = sum decay k k^T`` (``head_dim`` x ``head_dim``): no convolution,
   no tail;
+* mixer ``cca``: compressed convolutional attention — grouped-query
+  attention INSIDE a latent of ``heads`` query heads over ``kv_heads``
+  KV heads of ``head_dim`` (q, k and v projected straight from the
+  block's input at those widths; the output map leads back to the
+  model's), whose q | k rows are mixed along the sequence before the
+  scores: two causal convolutions of ``conv`` = [2, 2] taps (depthwise,
+  then grouped by head) plus the q-k mean of the rows before them, each
+  head then L2-normalised to length sqrt(head_dim), the keys times a
+  learned temperature a KV head (``layer{i}_qk_norm_temperature``,
+  float32); ``rope_theta`` required, ``rotary_dim``: the first that many
+  lanes of a head rotate (absent: the whole head); the second half of
+  the value lanes is projected from the PREVIOUS token.  Its per-stream
+  state is BOTH kinds: K/V pages (mixed, normalised, rotated keys) AND a
+  slot's auxiliary array, the tail — the previous token's rows before
+  and after the depthwise convolution and its shifted value half
+  (``ops/hybrid.py`` CCAMix);
 * ffn ``dense``: a gated (SiLU) feed-forward of ``width``;
 * ffn ``moe``: ``experts`` routed experts of ``width``, ``top_k`` a
   token (``score``: ``sigmoid`` — normalised sigmoid scores, the
-  default — or ``softmax_topk`` — a softmax over the chosen logits;
+  default — ``softmax_topk`` — a softmax over the chosen logits — or
+  ``softmax`` — a softmax over ALL experts, the chosen probabilities
+  un-normalised (a top-1 weight is not 1), ``select_bias`` moving the
+  choice alone; ``router``: absent, one matrix; ``{"kind": "mlp",
+  "hidden": n, "carry": bool}`` — a network of its own, all float32: a
+  map down to ``hidden``, under ``carry`` joined by the hidden row the
+  router of the layer BEFORE left (times a learned gain a channel; the
+  first such layer carries nothing), an RMSNorm, three maps with GELU
+  between to the experts' logits; ``top_k`` 1 is the one built;
   ``act``: the experts' gate, ``silu`` — the default — or ``relu``;
   ``router_input``: ``ffn`` — the router scores the rows the experts
   get, the default — or ``block`` — the block's un-normalised input,
@@ -81,7 +105,8 @@ MIXER and its own FFN:
 
 :class:`HybridSpec` is what ``mx.DecodeEngine(params, model=spec)``
 takes: from the layer list it derives the feeds, the pools (pages for
-attention and mla layers, slots for kda, mamba2 and retention layers)
+attention, mla and cca layers, slots for kda, mamba2 and retention
+layers, a slot's tail beside the pages for cca layers)
 and the
 prefill and decode symbols (a prefill's logits are those of each
 prompt's LAST row alone, (B, 1, vocab): the engine samples one token).
@@ -89,15 +114,19 @@ Data of the spec too: ``embed_scale`` (token rows), ``residual_scale``
 (a block's output before the add), ``logits_scale`` (the last norm's
 output), ``tied_head``, ``post_norm`` (a branch's OUTPUT through an
 RMSNorm of its own, ``layer{i}_post_norm1`` / ``_post_norm2``, before the
-scale and the add).  An absent key builds the symbol it built before the
+scale and the add), ``learned_residual`` (every add is ``(a_r x + b_r) +
+(a_o out + b_o)`` under four learned vectors, ``layer{i}_res1_scales`` /
+``_res2_scales`` (4, d_model)).  An absent key builds the symbol it built before the
 key existed; a key no kind knows is refused by name.  NODE NAMES (a traced
 run's ``scope_time`` line groups device time by them), after ``layer{i}_``:
 ``norm1 norm2``; attention ``q k v q_norm k_norm attn gate o``; mla ``q_down
 q_norm q_up kv_down kv_norm``, ``kv_up`` (prefill) or ``absorb_k absorb_v``
 (decode), ``attn o``; kda ``qkv conv a_down a_up beta kda g_down g_up onorm
 o``; mamba2 ``in conv mamba2 onorm out``; retention ``q k v q_norm
-k_norm g retention o``; FFNs ``ffn_* moe shared_*``; and
-``tok_embed final_norm last_row head``.  Equations: ``benchmark/reference/``.
+k_norm g retention o``; cca ``q k v1 v2 mix qk_norm v attn o``; FFNs
+``ffn_* moe shared_*``, an mlp router's ``router_down router_carry
+router_norm router_1 router_2 router_3``; ``res1 res2`` (learned residual
+scales); and ``tok_embed final_norm last_row head``.  Equations: ``benchmark/reference/``.
 """
 
 from .. import symbol as sym
@@ -116,15 +145,18 @@ MIXERS = {
                "conv_bias"),
     "retention": ("kind", "heads", "kv_heads", "head_dim", "degree",
                   "rope_theta", "qk_norm"),
+    "cca": ("kind", "heads", "kv_heads", "head_dim", "conv", "rope_theta",
+            "rotary_dim"),
 }
 FFNS = {
     "dense": ("kind", "width"),
     "moe": ("kind", "experts", "top_k", "width", "score", "shared",
             "shared_width", "experts_held", "first_expert", "act",
             "router_input", "groups", "top_groups", "routed_scale",
-            "select_bias"),
+            "select_bias", "router"),
 }
 ROUTER_INPUTS = ("ffn", "block")
+ROUTER_KEYS = ("kind", "hidden", "carry")     # of an ``mlp`` router
 COUNTERS = "moe_counters"
 
 
@@ -150,9 +182,13 @@ def mixer_state(m):
     its short convolution's tail, ``kv_cache.conv_tail_shape``, or, for
     a retention mixer, which has no convolution, the normaliser's
     (KV heads, head_dim, head_dim)); None for a mixer whose state is
-    pages."""
+    pages alone.  A cca mixer keeps pages AND a tail: (None, the shape
+    of a slot of it — the previous token's u | c | shifted value half,
+    ``_cca_widths``)."""
     from ..kv_cache import conv_tail_shape
 
+    if m["kind"] == "cca":
+        return None, _cca_tail(m)[1]
     if m["kind"] == "retention":
         from ..ops.hybrid import retention_rows
 
@@ -173,8 +209,26 @@ def _mamba2_channels(m):
     return int(m["heads"]) * int(m["head_dim"]) + 2 * int(m["d_state"])
 
 
+def _cca_widths(m):
+    """(channels of a cca mixer's latent q | k, lanes of the value half
+    it takes from the previous token)."""
+    H, Hkv, D = int(m["heads"]), int(m["kv_heads"]), int(m["head_dim"])
+    return (H + Hkv) * D, Hkv * D // 2
+
+
+def _cca_tail(m):
+    """(the float32 numbers a cca mixer carries a stream — the previous
+    token's u | c | shifted value half —, the shape of the slot that
+    holds them in whole tiles)."""
+    from ..kv_cache import conv_tail_shape
+
+    C, wv = _cca_widths(m)
+    return 2 * C + wv, conv_tail_shape(1, 2, 2 * C + wv)[1:]
+
+
 # the auxiliary pool's name after ``layer{i}_``, by mixer kind
-_AUX_POOL = {"kda": "tail", "mamba2": "tail", "retention": "zsum"}
+_AUX_POOL = {"kda": "tail", "mamba2": "tail", "retention": "zsum",
+             "cca": "tail"}
 
 
 class HybridSpec:
@@ -188,7 +242,7 @@ class HybridSpec:
 
     def __init__(self, vocab_size, d_model, layers, norm_eps=1e-5,
                  embed_scale=1.0, residual_scale=1.0, logits_scale=1.0,
-                 tied_head=False, post_norm=False):
+                 tied_head=False, post_norm=False, learned_residual=False):
         self.vocab_size = int(vocab_size)
         self.d_model = int(d_model)
         self.norm_eps = float(norm_eps)
@@ -197,6 +251,7 @@ class HybridSpec:
         self.logits_scale = float(logits_scale)
         self.tied_head = bool(tied_head)
         self.post_norm = bool(post_norm)
+        self.learned_residual = bool(learned_residual)
         self.layers = [dict(mixer=dict(ly["mixer"]), ffn=dict(ly["ffn"]))
                        for ly in layers]
         for i, ly in enumerate(self.layers):
@@ -238,7 +293,23 @@ class HybridSpec:
                     f"2 over an even head_dim is built (the state of "
                     f"degree p grows as head_dim^p / p!), and it rotates: "
                     f"rope_theta is required, positive")
-            if m["kind"] in ("attention", "retention") and \
+            if m["kind"] == "cca":
+                D = int(m["head_dim"])
+                span = int(m.get("rotary_dim", D))
+                if [int(t) for t in m.get("conv", ())] != [2, 2] \
+                        or not float(m.get("rope_theta") or 0) > 0 \
+                        or span % 2 or not 0 < span <= D \
+                        or int(m["kv_heads"]) * D % 2:
+                    raise MXNetError(
+                        f"layer {i}: a cca mixer of conv {m.get('conv')!r}, "
+                        f"rope_theta {m.get('rope_theta')!r}, rotary_dim "
+                        f"{m.get('rotary_dim')!r} over heads of {D}: two "
+                        f"convolutions of two taps each are built (conv "
+                        f"[2, 2]: the tail is ONE token), it rotates "
+                        f"(rope_theta required, positive) an even "
+                        f"rotary_dim of at most head_dim, and half of its "
+                        f"value lanes come from the previous token")
+            if m["kind"] in ("attention", "retention", "cca") and \
                     int(m["heads"]) % int(m.get("kv_heads", m["heads"])):
                 raise MXNetError(
                     f"layer {i}: {m['kv_heads']} KV heads do not divide "
@@ -265,6 +336,21 @@ class HybridSpec:
                     f"layer {i}: a mamba2 mixer of {m['groups']} groups "
                     f"of B and C; one group, shared by all heads, is "
                     f"built")
+            if f["kind"] == "moe" and f.get("router") is not None:
+                r = f["router"]
+                if not isinstance(r, dict) or r.get("kind") != "mlp" \
+                        or set(r) - set(ROUTER_KEYS) \
+                        or int(r.get("hidden", 0)) < 1 \
+                        or int(f["top_k"]) != 1 \
+                        or f.get("router_input", "ffn") != "ffn":
+                    raise MXNetError(
+                        f"layer {i}: moe router {r!r} with top_k "
+                        f"{f['top_k']}: a router is one matrix (no key) "
+                        f"or a dict of {ROUTER_KEYS} with kind 'mlp' and "
+                        f"a positive hidden width, reading the FFN's "
+                        f"rows; under it top_k 1 is built (what weighs "
+                        f"a second expert of an MLP router is not "
+                        f"published)")
             if f["kind"] == "moe":
                 held = int(f.get("experts_held", f["experts"]))
                 first = int(f.get("first_expert", 0))
@@ -274,7 +360,8 @@ class HybridSpec:
                         f"are not among the {f['experts']} routed")
         pages = {(int(ly["mixer"].get("kv_heads", ly["mixer"]["heads"])),
                   int(ly["mixer"]["head_dim"]))
-                 for ly in self.layers if ly["mixer"]["kind"] == "attention"}
+                 for ly in self.layers
+                 if ly["mixer"]["kind"] in ("attention", "cca")}
         if len(pages) > 1:
             raise MXNetError(
                 f"attention layers of different K/V widths {sorted(pages)} "
@@ -300,6 +387,14 @@ class HybridSpec:
             lanes = latent_pool_shape(1, 1, rank, rope)[2]
             self.latent_row = (rank + rope, lanes)
             self.kv_heads, self.head_dim = 1, lanes
+        # a spec of cca layers keeps a TAIL a stream beside its pages:
+        # (the float32 numbers it needs, the numbers its slots spend —
+        # whole tiles), summed over the layers
+        tails = [_cca_tail(ly["mixer"]) for ly in self.layers
+                 if ly["mixer"]["kind"] == "cca"]
+        self.tail_row = (sum(n for n, _ in tails),
+                         sum(r * w for _, (r, w) in tails)) if tails \
+            else None
         windows = {int(ly["mixer"]["window"]) for ly in self.layers
                    if ly["mixer"].get("window")}
         if len(windows) > 1:
@@ -345,9 +440,11 @@ class HybridSpec:
     def cache_kinds(self):
         """Per layer, the kind of its per-stream state: ``pages`` (K/V,
         through the block table), ``window_pages`` (K/V of a windowed
-        layer, through the window table) or ``slots`` (one row a
-        stream)."""
+        layer, through the window table), ``slots`` (one row a
+        stream) or ``pages+slots`` (a cca layer: K/V pages AND a slot's
+        tail)."""
         return tuple("pages" if ly["mixer"]["kind"] == "mla" else
+                     "pages+slots" if ly["mixer"]["kind"] == "cca" else
                      "slots" if ly["mixer"]["kind"] != "attention" else
                      "window_pages" if ly["mixer"].get("window") else "pages"
                      for ly in self.layers)
@@ -363,7 +460,7 @@ class HybridSpec:
         return tuple((int(ly["mixer"].get("window") or 0),
                       ly["mixer"]["kind"] == "mla")
                      for ly in self.layers
-                     if ly["mixer"]["kind"] in ("attention", "mla"))
+                     if ly["mixer"]["kind"] in ("attention", "mla", "cca"))
 
     def has_moe(self):
         return any(ly["ffn"]["kind"] == "moe" for ly in self.layers)
@@ -373,12 +470,13 @@ class HybridSpec:
         state is what ``return_state`` reads — a retention layer's
         normaliser with it, which is the other half of its sums; a
         convolution's tail rides in the same slot, read by the programs
-        alone."""
+        alone — a cca layer's too, beside its two page pools."""
         out = []
         for k, ly in zip(self.cache_kinds(), self.layers):
             aux = "slots" if ly["mixer"]["kind"] == "retention" \
                 else "slots_aux"
             out += ["slots", aux] if k == "slots" else \
+                ["pages", "pages", aux] if k == "pages+slots" else \
                 [k] if ly["mixer"]["kind"] == "mla" else [k, k]
         return tuple(out) + (("counters",) if self.has_moe() else ())
 
@@ -400,12 +498,15 @@ class HybridSpec:
                 out.append((f"layer{i}_latent_pool", latent_pool_shape(
                     cache_blocks, kv_block, m["kv_rank"], m["rope_dim"]),
                     dtype, 0))
-            elif m["kind"] == "attention":
+            elif m["kind"] in ("attention", "cca"):
                 shape = value_pool_shape(
                     window_blocks if m.get("window") else cache_blocks,
                     kv_block, self.kv_heads, self.head_dim)
                 out += [(f"layer{i}_kpool", shape, dtype, 0),
                         (f"layer{i}_vpool", shape, dtype, 0)]
+                if m["kind"] == "cca":
+                    out.append((f"layer{i}_tail", (int(slots),)
+                                + mixer_state(m)[1], "float32", 0))
             else:
                 head_state, aux = mixer_state(m)
                 out += [(f"layer{i}_state",
@@ -423,7 +524,8 @@ class HybridSpec:
         return _trunk(self, step=(which == "decode"))
 
     _SCALARS = ("norm_eps", "embed_scale", "residual_scale",
-                "logits_scale", "tied_head", "post_norm")
+                "logits_scale", "tied_head", "post_norm",
+                "learned_residual")
 
     def to_dict(self):
         return {"vocab_size": self.vocab_size, "d_model": self.d_model,
@@ -587,19 +689,75 @@ def _retention(spec, h, i, m, step, feeds):
     return _fc(rec[0], spec.d_model, f"{name}_o"), [rec[1], rec[2]]
 
 
+def _cca(spec, h, i, m, step, feeds):
+    H, Hkv, D = int(m["heads"]), int(m["kv_heads"]), int(m["head_dim"])
+    name = f"layer{i}"
+    half = _cca_widths(m)[1]
+    # the latent: q | k straight from the block's input, mixed along
+    # the sequence; the value's second half is the PREVIOUS token's
+    mix = sym.CCAMix(
+        _fc(h, H * D, f"{name}_q"), _fc(h, Hkv * D, f"{name}_k"),
+        _fc(h, half, f"{name}_v2"),
+        sym.Variable(f"{name}_mix_conv0_weight"),
+        sym.Variable(f"{name}_mix_conv1_weight"),
+        sym.Variable(f"{name}_tail"), feeds["slots"], feeds["lengths"],
+        num_heads=H, kv_heads=Hkv, step=step, name=f"{name}_mix")
+    qk = sym.QKL2Norm(mix[0], mix[1],
+                      sym.Variable(f"{name}_qk_norm_temperature"),
+                      num_heads=H, kv_heads=Hkv, name=f"{name}_qk_norm")
+    v = sym.Concat(_fc(h, Hkv * D - half, f"{name}_v1"), mix[2], dim=2,
+                   num_args=2, name=f"{name}_v")
+    op = sym.GQAPagedDecode if step else sym.GQAPrefillAttention
+    attrs = {"rotary_dim": int(m["rotary_dim"])} \
+        if int(m.get("rotary_dim", D)) != D else {}
+    att = op(qk[0], qk[1], v, sym.Variable(f"{name}_kpool"),
+             sym.Variable(f"{name}_vpool"), feeds["block_table"],
+             feeds["lengths"], feeds["positions"], num_heads=H,
+             kv_heads=Hkv, rope_theta=float(m["rope_theta"]),
+             name=f"{name}_attn", **attrs)
+    return _fc(att[0], spec.d_model, f"{name}_o"), [att[1], att[2], mix[3]]
+
+
 _MIXER_BUILDERS = {"attention": _attention, "mla": _mla, "kda": _kda,
-                   "mamba2": _mamba2, "retention": _retention}
+                   "mamba2": _mamba2, "retention": _retention, "cca": _cca}
 
 
-def _ffn(spec, h, x_in, i, f, step, feeds, counters):
+def _router_mlp(h, name, r, carried, eps):
+    """(the experts' logits of an ``mlp`` router, float32; its hidden
+    row, what the next layer's router is handed).  ``carried``: the row
+    the layer before left, or None (the first: nothing is carried).  The
+    maps' widths are their weights' (``hidden``, the experts)."""
+    def lin(x, node, **attrs):
+        return sym.RouterLinear(x, sym.Variable(f"{name}_{node}_weight"),
+                                name=f"{name}_{node}", **attrs)
+
+    row = lin(h, "router_down")
+    if r.get("carry") and carried is not None:
+        row = sym.RouterCarry(row, carried,
+                              sym.Variable(f"{name}_router_carry_gamma"),
+                              name=f"{name}_router_carry")
+    x = _norm(row, f"{name}_router_norm", eps)
+    x = lin(lin(x, "router_1", act="gelu"), "router_2", act="gelu")
+    return lin(x, "router_3"), (row if r.get("carry") else None)
+
+
+def _ffn(spec, h, x_in, i, f, step, feeds, counters, carried=None):
     """``h``: the rows the FFN takes; ``x_in``: the block's input, which
-    a router with ``router_input: block`` scores in their place."""
+    a router with ``router_input: block`` scores in their place;
+    ``carried``: the hidden row the last ``mlp`` router with ``carry``
+    left.  -> (output, counters, the row this layer's router leaves or
+    ``carried`` as it came)."""
     name = f"layer{i}"
     if f["kind"] == "dense":
         return _gated_ffn(h, int(f["width"]), spec.d_model,
-                          f"{name}_ffn"), counters
+                          f"{name}_ffn"), counters, carried
     attrs = {k: f[k] for k in ("score", "act") if f.get(k)}
-    args = [h, sym.Variable(f"{name}_router_weight"),
+    router = sym.Variable(f"{name}_router_weight")
+    if f.get("router"):
+        router, carried = _router_mlp(h, name, f["router"], carried,
+                                      spec.norm_eps)
+        attrs["router_logits"] = True
+    args = [h, router,
             sym.Variable(f"{name}_experts_gate_weight"),
             sym.Variable(f"{name}_experts_up_weight"),
             sym.Variable(f"{name}_experts_down_weight"), feeds["lengths"],
@@ -624,7 +782,7 @@ def _ffn(spec, h, x_in, i, f, step, feeds, counters):
                           int(f["width"]) * int(f["shared"])))
         out = out + _gated_ffn(h, width, spec.d_model, f"{name}_shared")
     # decode steps count; a prefill hands the counters on as they are
-    return out, (routed[1] if step else counters)
+    return out, (routed[1] if step else counters), carried
 
 
 def _trunk(spec, step):
@@ -643,23 +801,30 @@ def _trunk(spec, step):
             out = _norm(out, name, spec.norm_eps)
         return scaled(out, spec.residual_scale)
 
+    def add(x, out, name):   # the stream after a branch
+        if not spec.learned_residual:
+            return x + out
+        return sym.ScaledResidual(x, out, sym.Variable(f"{name}_scales"),
+                                  name=name)
+
     table = sym.Variable("tok_embed_weight")
     x = scaled(sym.Embedding(feeds["data"], input_dim=spec.vocab_size,
                              output_dim=spec.d_model, name="tok_embed",
                              weight=table), spec.embed_scale)
     counters = sym.Variable(COUNTERS) if spec.has_moe() else None
     state = []
+    carried = None           # an mlp router's hidden row, layer to layer
     for i, ly in enumerate(spec.layers):
         x_in = x
         h = _norm(x, f"layer{i}_norm1", spec.norm_eps)
         out, st = _MIXER_BUILDERS[ly["mixer"]["kind"]](
             spec, h, i, ly["mixer"], step, feeds)
         state += st
-        x = x + branch(out, f"layer{i}_post_norm1")
+        x = add(x, branch(out, f"layer{i}_post_norm1"), f"layer{i}_res1")
         h = _norm(x, f"layer{i}_norm2", spec.norm_eps)
-        out, counters = _ffn(spec, h, x_in, i, ly["ffn"], step, feeds,
-                             counters)
-        x = x + branch(out, f"layer{i}_post_norm2")
+        out, counters, carried = _ffn(spec, h, x_in, i, ly["ffn"], step,
+                                      feeds, counters, carried)
+        x = add(x, branch(out, f"layer{i}_post_norm2"), f"layer{i}_res2")
     if not step:
         x = sym.expand_dims(sym.SequenceLast(
             sym.SwapAxis(x, dim1=0, dim2=1), feeds["lengths"],

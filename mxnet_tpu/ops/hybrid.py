@@ -6,14 +6,18 @@ up-projection), the recurrent mixers over per-stream state SLOTS — the KDA
 linear-attention layer (short convolution with a carried tail, the gated
 delta rule with a per-channel decay) and the Mamba-2 state-space layer
 (the same convolution with a bias, a per-head scalar decay, B and C
-shared by a group's heads) — and the routed-expert feed-forward layer
-that is told which experts it holds.
+shared by a group's heads) — the sequence mixing and the head norm of
+compressed convolutional attention (whose attention proper is the
+grouped-query ops above, over K/V pages AND a tail in a slot), a router
+that is a small float32 network carried from layer to layer, the
+residual add under learned scales, and the routed-expert feed-forward
+layer that is told which experts it holds.
 
 Two kinds of per-stream state live side by side in a serving program:
 K/V PAGES (``kv_cache.value_pool_shape`` — or ``latent_pool_shape``,
 one pool a layer — addressed through a block table; attention layers) and SLOTS (``kv_cache.state_pool_shape`` /
-``conv_tail_shape``, one row per live stream, row 0 scratch; KDA and
-Mamba-2 layers).  Every op here that touches a pool takes it in and hands it
+``conv_tail_shape``, one row per live stream, row 0 scratch; KDA,
+Mamba-2 and retention layers — and a cca layer's tail, beside its pages).  Every op here that touches a pool takes it in and hands it
 back, so that a jitted step donates it and updates in place.
 
 Forward only: training this family fits no chip the benchmark has, so no
@@ -142,7 +146,9 @@ _GQA_ATTRS = (
     "attrs: num_heads, kv_heads, scale (the scores' multiplier; 0 = "
     "head_dim^-1/2), rope_theta (0 = no rotation; else an eighth input, "
     "positions (B, S) int32, and q and k are rotated by them — rotate-half "
-    "pairs (i, i + D/2) over the whole head, base rope_theta — before the "
+    "pairs (i, i + D/2) over the whole head, base rope_theta, or — "
+    "rotary_dim R > 0 — pairs (i, i + R/2) over a head's first R lanes "
+    "alone — before the "
     "scores and before k goes into the pages), window (0 = every key up "
     "to the query; else the query's own key and the window - 1 before it: "
     "block_table is then the table of a WINDOWED pool, whose entries "
@@ -155,22 +161,29 @@ def _gqa_args(attrs):
         else ())
 
 
-def rotate_half(x, positions, theta, heads, inv_freq=None):
+def rotate_half(x, positions, theta, heads, inv_freq=None, rotary_dim=0):
     """Rotary positions: x (B, S, heads·D) with each head's lanes in
     pairs (i, i + D/2), pair i turned by ``positions * theta^(-2i/D)``
     — or by ``positions * inv_freq[i]`` where the (D/2,) frequencies
     come as data (rescaled ones: :func:`yarn_inv_freq`); float32
-    inside, x's type out."""
+    inside, x's type out.  ``rotary_dim`` R (0: the whole head): only
+    the first R lanes of a head turn, in pairs (i, i + R/2) by
+    ``theta^(-2i/R)``; the other D - R carry no position."""
     B, S, HD = x.shape
     D = HD // heads
+    R = int(rotary_dim) or D
+    if R % 2 or R > D:
+        raise MXNetError(f"rotate_half: a span of {R} lanes of a head of "
+                         f"{D} is rotated in pairs inside the head")
     inv = jnp.asarray(theta, jnp.float32) ** (
-        -jnp.arange(0, D, 2, dtype=jnp.float32) / D) \
+        -jnp.arange(0, R, 2, dtype=jnp.float32) / R) \
         if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
-    ang = positions.astype(jnp.float32)[..., None] * inv        # (B, S, D/2)
+    ang = positions.astype(jnp.float32)[..., None] * inv        # (B, S, R/2)
     cos, sin = jnp.cos(ang)[:, :, None, :], jnp.sin(ang)[:, :, None, :]
-    x1, x2 = jnp.split(x.reshape(B, S, heads, D).astype(jnp.float32), 2,
-                       axis=-1)
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+    xf = x.reshape(B, S, heads, D).astype(jnp.float32)
+    x1, x2 = xf[..., :R // 2], xf[..., R // 2:R]
+    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin]
+                          + ([xf[..., R:]] if R < D else []), -1)
     return out.reshape(B, S, HD).astype(x.dtype)
 
 
@@ -181,8 +194,9 @@ def _gqa_rotated(attrs, inputs, H, Hkv):
     if not theta:
         return q, k
     positions = inputs[7]
-    return rotate_half(q, positions, theta, H), \
-        rotate_half(k, positions, theta, Hkv)
+    span = attr_int(attrs.get("rotary_dim", 0), 0)
+    return rotate_half(q, positions, theta, H, rotary_dim=span), \
+        rotate_half(k, positions, theta, Hkv, rotary_dim=span)
 
 
 @register("GQAPrefillAttention", arg_names=_gqa_args, out_names=_GQA_OUTS,
@@ -558,6 +572,23 @@ def short_conv(x, w, left, bias=None):
     return jax.nn.silu(y), xp
 
 
+def _write_tails(pool, tail, slots):
+    """pool (slots, 8, W) with row b of ``tail`` (B, run) — a stream's
+    carried numbers back to back, padded to the slot's whole tiles —
+    written to slot ``slots[b]``: one DMA a row where the kernels are on
+    (``pallas_hybrid.slot_rows_write``), a scatter elsewhere."""
+    from . import pallas_hybrid as ph
+    from . import pallas_kernels as pk
+
+    B, run = tail.shape
+    rows = jnp.pad(tail.astype(pool.dtype),
+                   ((0, 0), (0, pool.shape[1] * pool.shape[2] - run)))
+    rows = rows.reshape((B,) + pool.shape[1:])
+    if pk.enabled():
+        return ph.slot_rows_write(pool, rows, slots)
+    return pool.at[slots].set(rows)
+
+
 def _conv_infer(attrs, in_shapes):
     d, tail = in_shapes[0], in_shapes[2]
     if d is None or tail is None:
@@ -585,9 +616,6 @@ _CONV_ARGS = ("data", "weight", "tail_pool", "slots", "lengths")
               "is shifted by it.  bias=1: a sixth input, bias (C,), "
               "added before the SiLU.  -> output (B, S, C), the pool")
 def _short_conv(op_ctx, attrs, inputs, aux):
-    from . import pallas_hybrid as ph
-    from . import pallas_kernels as pk
-
     x, w, pool, slots, lengths = inputs[:5]
     bias = inputs[5] if len(inputs) > 5 else None
     step = attr_bool(attrs.get("step", False), False)
@@ -605,12 +633,8 @@ def _short_conv(op_ctx, attrs, inputs, aux):
         n = lengths.astype(jnp.int32)
         tail = jax.vmap(lambda row, at: lax.dynamic_slice_in_dim(
             row, at, K - 1, axis=0))(xp, n)
-    rows = jnp.pad(tail.reshape(B, run).astype(pool.dtype),
-                   ((0, 0), (0, pool.shape[1] * pool.shape[2] - run)))
-    rows = rows.reshape((B,) + pool.shape[1:])
-    if pk.enabled():
-        return [y.astype(x.dtype), ph.slot_rows_write(pool, rows, slots)]
-    return [y.astype(x.dtype), pool.at[slots].set(rows)]
+    return [y.astype(x.dtype),
+            _write_tails(pool, tail.reshape(B, run), slots)]
 
 
 # ---------------------------------------------------------------------------
@@ -1181,6 +1205,220 @@ def _retention_step(op_ctx, attrs, inputs, aux):
 
 
 # ---------------------------------------------------------------------------
+# Compressed convolutional attention: the latent's mixing, and its norm
+# ---------------------------------------------------------------------------
+#
+# The attention runs INSIDE a latent: ``heads`` query heads and
+# ``kv_heads`` KV heads of ``head_dim``, both projected straight from the
+# block's input, u = [q~ | k~] side by side as heads of D lanes.  Before
+# the scores the latent is mixed along the sequence by two causal
+# convolutions of two taps — depthwise ``c_t = a0 . u_t + a1 . u_(t-1)``,
+# then grouped by head ``e_t[j] = B0_j c_t[j] + B1_j c_(t-1)[j]`` — and
+# the q-k mean of the rows BEFORE the convolutions is added: ``q = e_q +
+# (q~ + k~ of its KV head) / 2``, ``k = e_k + (the mean of its query heads'
+# q~ + k~) / 2``.  Half of the value lanes come from the PREVIOUS token
+# (``value_prev``: the op hands the row back shifted by one).  What a
+# stream keeps beside its K/V pages to take one more token is its TAIL:
+# u_(t-1), c_(t-1) and value_prev_(t-1), float32, one slot a stream
+# (``kv_cache.conv_tail_shape(slots, 2, channels)``).
+
+def cca_mix(q, k, w0, w1, left_u, left_c, H, Hkv):
+    """The mixing of :class:`CCAMix` over S rows that follow ``left_u``,
+    ``left_c`` (B, C) float32 — the row before the first, before and
+    after the depthwise convolution (zeros where nothing came before).
+    q (B, S, H·D), k (B, S, Hkv·D); w0 (C, 2), w1 (H + Hkv, 2, D, D) =
+    [head, tap, out, in], tap 1 on the current token.  -> (q, k mixed,
+    in q's type; u and c (B, S + 1, C) float32 with the left rows
+    first).  The depthwise taps and the mean in float32; the grouped
+    matmul's operands in q's type, its sums float32."""
+    f32 = jnp.float32
+    B, S, HD = q.shape
+    D = HD // H
+    G = H // Hkv
+    mm = q.dtype
+    prec = HI if mm == f32 else None
+    u = jnp.concatenate([left_u.astype(f32)[:, None], jnp.concatenate(
+        [q, k], axis=-1).astype(f32)], axis=1)              # (B, S + 1, C)
+    w0f = w0.astype(f32)
+    c = u[:, 1:] * w0f[:, 1] + u[:, :-1] * w0f[:, 0]
+    c = jnp.concatenate([left_c.astype(f32)[:, None], c], axis=1)
+    ch = c.astype(mm).reshape(B, S + 1, H + Hkv, D)
+    e = sum(jnp.einsum("bsji,joi->bsjo", ch[:, tap:S + tap],
+                       w1[:, tap].astype(mm), precision=prec,
+                       preferred_element_type=f32) for tap in (0, 1))
+    q4 = u[:, 1:, :HD].reshape(B, S, Hkv, G, D)
+    k4 = u[:, 1:, HD:].reshape(B, S, Hkv, D)
+    mq = 0.5 * (q4 + k4[:, :, :, None])
+    mk = 0.5 * (jnp.mean(q4, axis=3) + k4)
+    qo = e[:, :, :H].reshape(B, S, HD) + mq.reshape(B, S, HD)
+    ko = e[:, :, H:].reshape(B, S, Hkv * D) + mk.reshape(B, S, Hkv * D)
+    return qo.astype(mm), ko.astype(mm), u, c
+
+
+def _cca_mix_infer(attrs, in_shapes):
+    q, k, v, pool = (in_shapes[i] for i in (0, 1, 2, 5))
+    if q is None or k is None or v is None or pool is None:
+        return in_shapes, None, None
+    return in_shapes, [tuple(q), tuple(k), tuple(v), tuple(pool)], []
+
+
+@register("CCAMix",
+          arg_names=("query", "key", "value_prev", "conv0_weight",
+                     "conv1_weight", "tail_pool", "slots", "lengths"),
+          out_names=("query_mixed", "key_mixed", "value_shifted",
+                     "new_tail_pool"),
+          infer_shape=_cca_mix_infer,
+          doc="The sequence mixing of compressed convolutional attention, "
+              "with the rows it needs of the previous token carried per "
+              "stream: query (B, S, H*D) and key (B, S, Hkv*D), the "
+              "latent u = [query | key] as H + Hkv heads of D; conv0_weight "
+              "(C, 2), depthwise, c_t = w[:, 1] u_t + w[:, 0] u_(t-1); "
+              "conv1_weight (H + Hkv, 2, D, D) = [head, tap, out, in], "
+              "e_t[j] = W[j, 1] c_t[j] + W[j, 0] c_(t-1)[j]; the q-k mean "
+              "of the rows before the convolutions added: query_mixed = "
+              "e_q + (query + its KV head's key) / 2, key_mixed = e_k + "
+              "(the mean of its query heads + key) / 2.  value_prev "
+              "(B, S, Wv): the value lanes taken from the previous token, "
+              "handed back shifted by one row.  tail_pool (slots, 8, W) "
+              "float32 (kv_cache.conv_tail_shape(slots, 2, 2 C + Wv): a "
+              "slot holds u_(t-1) | c_(t-1) | value_prev_(t-1)), slots "
+              "(B,) int32 (0 = scratch).  step=0 (prefill): the sequence "
+              "starts from nothing (zeros before its first row) and the "
+              "rows at position lengths[b] - 1 — the prompt's TRUE last, "
+              "not the bucket's — are written to the slot; step=1 "
+              "(decode, S = 1): the slot's rows precede the token and are "
+              "replaced by its own.  -> the three rows and the pool.  "
+              "attrs: num_heads, kv_heads, step")
+def _cca_mix(op_ctx, attrs, inputs, aux):
+    q, k, v2, w0, w1, pool, slots, lengths = inputs
+    H, Hkv = _gqa_heads(attrs, q, k)
+    step = attr_bool(attrs.get("step", False), False)
+    B, S, _ = q.shape
+    C, Wv = q.shape[-1] + k.shape[-1], v2.shape[-1]
+    run = 2 * C + Wv                # a slot's numbers, then padding
+    if tuple(w0.shape) != (C, 2) or tuple(w1.shape) != (
+            H + Hkv, 2, C // (H + Hkv), C // (H + Hkv)) \
+            or pool.shape[1] * pool.shape[2] < run:
+        raise MXNetError(
+            f"CCAMix: conv0 {tuple(w0.shape)} / conv1 {tuple(w1.shape)} "
+            f"are not two taps over {C} channels as {H + Hkv} heads, or a "
+            f"slot of the tail pool {tuple(pool.shape)} holds fewer than "
+            f"{run} numbers (u | c | value_prev)")
+    slots = slots.astype(jnp.int32)
+    f32 = jnp.float32
+    if step:
+        if S != 1:
+            raise MXNetError(f"CCAMix step=1 feeds ONE position a step; "
+                             f"got query {tuple(q.shape)}")
+        left = pool[slots].reshape(B, -1).astype(f32)
+        left_u, left_c, left_v = (left[:, :C], left[:, C:2 * C],
+                                  left[:, 2 * C:run])
+    else:
+        left_u = left_c = jnp.zeros((B, C), f32)
+        left_v = jnp.zeros((B, Wv), f32)
+    qo, ko, u, c = cca_mix(q, k, w0, w1, left_u, left_c, H, Hkv)
+    v = jnp.concatenate([left_v[:, None], v2.astype(f32)], axis=1)
+    if step:
+        last = jnp.ones((B,), jnp.int32)
+    else:       # rows 0 .. S of u, c, v: row n is position n - 1
+        last = jnp.clip(lengths.astype(jnp.int32), 0, S)
+    at = last[:, None, None]
+    tail = jnp.concatenate(
+        [jnp.take_along_axis(t, at, axis=1)[:, 0] for t in (u, c, v)],
+        axis=-1)
+    return [qo, ko, v[:, :S].astype(v2.dtype),
+            _write_tails(pool, tail, slots)]
+
+
+CCA_NORM_EPS = 1e-6     # under the root of a head's summed squares
+
+
+def qk_l2_norm(x, heads, temperature=None):
+    """Each head of x (..., heads·D) scaled to length sqrt(D): ``sqrt(D)
+    x / |x|_2`` — times ``temperature`` (heads,), one learned positive
+    number a head, where given.  Float32 inside, x's type out."""
+    D = x.shape[-1] // heads
+    xf = x.astype(jnp.float32).reshape(x.shape[:-1] + (heads, D))
+    y = xf * (lax.rsqrt(jnp.sum(xf * xf, axis=-1, keepdims=True)
+                        + CCA_NORM_EPS) * float(D) ** 0.5)
+    if temperature is not None:
+        y = y * temperature.astype(jnp.float32)[:, None]
+    return y.reshape(x.shape).astype(x.dtype)
+
+
+@register("QKL2Norm", arg_names=("query", "key", "temperature"),
+          out_names=("query_normed", "key_normed"),
+          infer_shape=lambda attrs, s: (
+              [s[0], s[1], (attr_int(attrs.get("kv_heads", 1), 1),)],
+              [s[0], s[1]], []),
+          doc="L2 normalisation of each head of query (B, S, H*D) and key "
+              "(B, S, Hkv*D) to length sqrt(D), the keys times a learned "
+              "temperature (Hkv,) float32, one a KV head: the scores' "
+              "head_dim^-1/2 then makes q.k the cosine times sqrt(D) "
+              "times the temperature.  Float32 inside.  attrs: num_heads, "
+              "kv_heads")
+def _qk_l2_norm(op_ctx, attrs, inputs, aux):
+    q, k, temp = inputs
+    H, Hkv = _gqa_heads(attrs, q, k)
+    return [qk_l2_norm(q, H), qk_l2_norm(k, Hkv, temp)]
+
+
+# ---------------------------------------------------------------------------
+# A router that is a small network, carried from layer to layer; the
+# residual stream under learned scales
+# ---------------------------------------------------------------------------
+
+ROUTER_ACTS = ("", "gelu")
+
+
+@register("RouterLinear", arg_names=("data", "weight"),
+          infer_shape=lambda attrs, s: (
+              s, [None if s[0] is None or s[1] is None
+                  else tuple(s[0][:-1]) + (s[1][0],)], []),
+          doc="One linear map of an expert router that is a small network: "
+              "data (B, S, K) in any type, weight (N, K) -> (B, S, N) "
+              "FLOAT32 at float32 precision (a router's choice must not "
+              "hang on the products' rounding), then act: '' (default) or "
+              "'gelu' (the exact one, by erf)")
+def _router_linear(op_ctx, attrs, inputs, aux):
+    x, w = inputs
+    act = str(attrs.get("act", ""))
+    if act not in ROUTER_ACTS:
+        raise MXNetError(f"RouterLinear: act {act!r} is none of "
+                         f"{ROUTER_ACTS}")
+    y = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32).T,
+                precision=HI)
+    return [jax.nn.gelu(y, approximate=False) if act else y]
+
+
+@register("RouterCarry", arg_names=("data", "carried", "gamma"),
+          infer_shape=lambda attrs, s: (
+              [s[0], s[0], None if s[0] is None else (s[0][-1],)],
+              [s[0]], []),
+          doc="A router's hidden row joined by the row the router of the "
+              "layer before left: data + gamma * carried, gamma one "
+              "learned number a channel; float32")
+def _router_carry(op_ctx, attrs, inputs, aux):
+    r, prev, gamma = (t.astype(jnp.float32) for t in inputs)
+    return [r + gamma * prev]
+
+
+@register("ScaledResidual", arg_names=("data", "branch", "scales"),
+          infer_shape=lambda attrs, s: (
+              [s[0], s[0], None if s[0] is None else (4, s[0][-1])],
+              [s[0]], []),
+          doc="The residual add under learned per-channel scales: scales "
+              "(4, d) = rows a_r, b_r, a_o, b_o -> (a_r * data + b_r) + "
+              "(a_o * branch + b_o); float32 inside, data's type out")
+def _scaled_residual(op_ctx, attrs, inputs, aux):
+    x, out, sc = inputs
+    a_r, b_r, a_o, b_o = sc.astype(jnp.float32)
+    y = (a_r * x.astype(jnp.float32) + b_r) \
+        + (a_o * out.astype(jnp.float32) + b_o)
+    return [y.astype(x.dtype)]
+
+
+# ---------------------------------------------------------------------------
 # MoEFFN: routed experts, the share held here
 # ---------------------------------------------------------------------------
 
@@ -1188,16 +1426,21 @@ MOE_COUNTERS = ("moe_pairs_here", "moe_pairs_elsewhere", "moe_experts_hit",
                 "moe_load_max")
 
 
-ROUTER_SCORES = ("sigmoid", "softmax_topk")
+ROUTER_SCORES = ("sigmoid", "softmax_topk", "softmax")
 
 
 def moe_route(x2, router_w, top_k, score="sigmoid", select_bias=None,
-              groups=0, top_groups=0, routed_scale=1.0):
+              groups=0, top_groups=0, routed_scale=1.0, logits=None):
     """The ``top_k`` experts of each token and their weights, float32,
     from the router's logits over ALL experts.  ``score``: ``sigmoid`` —
     sigmoid scores, the largest, weights ``s_e / sum_top s``;
     ``softmax_topk`` — the largest LOGITS, weights a softmax over those
-    ``top_k`` alone.  x2 (N, d); router_w (E, d).
+    ``top_k`` alone; ``softmax`` — a softmax over ALL experts, the
+    largest (of p + ``select_bias`` where given: the choice alone),
+    weights the chosen probabilities as they are, NOT normalised over
+    the chosen (a top-1 weight is p_e, not 1), times ``routed_scale``.
+    x2 (N, d); router_w (E, d) — or ``logits`` (N, E), the router's own
+    where it is not one matrix (x2 and router_w are then not read).
 
     Under ``sigmoid`` the CHOICE may be moved without moving the
     weights: ``select_bias`` (E,) is added to the scores it is made by;
@@ -1210,12 +1453,21 @@ def moe_route(x2, router_w, top_k, score="sigmoid", select_bias=None,
         raise MXNetError(f"router score {score!r} is none of "
                          f"{ROUTER_SCORES}")
     logits = jnp.dot(x2.astype(jnp.float32),
-                     router_w.astype(jnp.float32).T, precision=HI)
+                     router_w.astype(jnp.float32).T, precision=HI) \
+        if logits is None else logits.astype(jnp.float32)
     moved = select_bias is not None or groups or routed_scale != 1.0
-    if moved and score != "sigmoid":
+    if (moved and score == "softmax_topk") or (groups
+                                               and score != "sigmoid"):
         raise MXNetError(
-            f"router score {score!r} takes no selection bias, group limit "
-            f"or scale; 'sigmoid' does")
+            f"router score {score!r} takes no group limit"
+            + ("" if score == "softmax" else ", selection bias or scale")
+            + "; 'sigmoid' does")
+    if score == "softmax":
+        p = jax.nn.softmax(logits, axis=-1)
+        topi = lax.top_k(p if select_bias is None else
+                         p + select_bias.astype(jnp.float32), top_k)[1]
+        return topi, jnp.take_along_axis(p, topi, axis=-1) \
+            * jnp.float32(routed_scale)
     if score == "softmax_topk":
         topv, topi = lax.top_k(logits, top_k)
         return topi, jax.nn.softmax(topv, axis=-1)
@@ -1245,10 +1497,18 @@ def moe_route(x2, router_w, top_k, score="sigmoid", select_bias=None,
         * jnp.float32(routed_scale)
 
 
-def _tile_rows(n_pairs):
+def _tile_rows(n_pairs, held=0, step=True):
     """Rows of a tile of the grouped matmul: a decode batch's few rows
-    an expert want small tiles, a prompt's want the MXU's."""
-    return 128 if n_pairs >= 4096 else 16
+    an expert want small tiles, a prompt's want the MXU's.  A tile
+    streams its expert's three matrices whatever its rows, so a PROMPT
+    (``step`` false) whose ``held`` experts average two 16-row tiles or
+    more each — 32 pairs an expert: a prompt of 512 at one expert a
+    token over 16 held, far under the line of 4,096 pairs — takes the
+    MXU's tiles too: in 16-row tiles every expert's weights would
+    stream once a tile, 4-5 times over at 1,024 tokens."""
+    if n_pairs >= 4096 or (not step and held and n_pairs >= 32 * held):
+        return 128
+    return 16
 
 
 def _prompt_tiles(tm, d):
@@ -1390,7 +1650,11 @@ _MOE_ARGS = ("data", "router_weight", "gate_weight", "up_weight",
 
 
 def _moe_args(attrs):
-    return _MOE_ARGS + tuple(
+    # the second input is what the router gives: its matrix, or — a
+    # router that is a network of its own — the logits themselves
+    second = ("router_logits",) if attr_bool(
+        attrs.get("router_logits", False), False) else _MOE_ARGS[1:2]
+    return _MOE_ARGS[:1] + second + _MOE_ARGS[2:] + tuple(
         k for k in ("router_data", "select_bias")
         if attr_bool(attrs.get(k, False), False))
 
@@ -1404,7 +1668,11 @@ def _moe_args(attrs):
               "first_expert .. first_expert + held - 1.  score='sigmoid' "
               "(default): sigmoid scores, the top_k largest, weights s_e / "
               "sum_top s; score='softmax_topk': the top_k largest logits, "
-              "weights their softmax (float32 either way); "
+              "weights their softmax; score='softmax': a softmax over ALL "
+              "experts, the top_k largest, weights those probabilities "
+              "un-normalised (float32 all three); router_logits=1: the "
+              "second input is router_logits (B, S, experts) float32, a "
+              "router's own output, in place of router_weight; "
               "output = sum over a token's chosen experts that are held "
               "here of w_e E_e(x), E_e = W_down (act(W_gate x) * W_up "
               "x), act='silu' (default) or 'relu'.  router_data=1: an "
@@ -1425,7 +1693,8 @@ def _moe_args(attrs):
               "computed here, pairs left elsewhere, held experts hit, "
               "the largest expert's load — is added to where count=1.  "
               "attrs: top_k, first_expert, step, count, score, act, "
-              "router_data, select_bias, groups, top_groups, routed_scale")
+              "router_data, router_logits, select_bias, groups, "
+              "top_groups, routed_scale")
 def _moe_ffn(op_ctx, attrs, inputs, aux):
     from .. import profiler
 
@@ -1440,10 +1709,14 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
     count = attr_bool(attrs.get("count", False), False)
     B, S, d = x.shape
     held = w_gate.shape[0]
-    if first < 0 or first + held > router_w.shape[0]:
+    logits = None
+    if attr_bool(attrs.get("router_logits", False), False):
+        logits = router_w.reshape(B * S, -1)
+    experts = router_w.shape[0] if logits is None else logits.shape[1]
+    if first < 0 or first + held > experts:
         raise MXNetError(
             f"MoEFFN holds experts {first}..{first + held - 1} of the "
-            f"{router_w.shape[0]} the router scores")
+            f"{experts} the router scores")
     n = lengths.astype(jnp.int32)
     valid = (jnp.broadcast_to(n[:, None] > 0, (B, S)) if step
              else jnp.arange(S)[None, :] < n[:, None]).reshape(-1)
@@ -1456,11 +1729,12 @@ def _moe_ffn(op_ctx, attrs, inputs, aux):
         select_bias=extra.get("select_bias"),
         groups=attr_int(attrs.get("groups", 0), 0),
         top_groups=attr_int(attrs.get("top_groups", 0), 0),
-        routed_scale=attr_float(attrs.get("routed_scale", 1.0), 1.0))
-    tm = _tile_rows(B * S * min(top_k, held))
+        routed_scale=attr_float(attrs.get("routed_scale", 1.0), 1.0),
+        logits=logits)
+    tm = _tile_rows(B * S * min(top_k, held), held, step)
     here, pair_row, row_token, tile_expert, n_used, sizes = moe_dispatch(
         topi, valid, first, held, tm)
-    by_index = _by_index(tm, d, held, router_w.shape[0])
+    by_index = _by_index(tm, d, held, experts)
     profiler.inc_counter("moe.nodes_indexed" if by_index
                          else "moe.nodes_gathered")
     ys = moe_experts(x2, w_gate, w_up, w_down, row_token, tile_expert,

@@ -1163,6 +1163,11 @@ class DecodeEngine:
           ``positions``: the parameter whose rows are the learned
           positions and bound ``max_len``, or None (``max_len``
           required);
+        * optionally ``tail_row``: (float32 numbers needed, numbers
+          held) a stream, over the layers that keep a convolution's tail
+          in a ``slots_aux`` pool BESIDE their pages (a cca mixer): the
+          ``cca.cache_bytes_per_token`` (K and V of one layer) and
+          ``cca.tail_bytes_per_stream`` gauges report them;
         * optionally ``prompt_attention()``: ``(window, latent)`` a
           layer whose prefill kernel takes the prompt's length, for the
           ``prefill_tiles_*`` counters (absent: none counted).
@@ -1249,7 +1254,9 @@ class DecodeEngine:
                 f"kv_dtype {self._kv_dtype!r} must be one of {KV_DTYPES}")
         kv_store_dtype = kv_storage_dtype(self._kv_dtype)  # may raise
         self._pool_kinds = tuple(model.pool_kinds(self._kv_dtype))
-        slots = "slots" in self._pool_kinds
+        # a slot a stream: state ``return_state`` reads, or — a layer
+        # that holds pages AND a tail — rows the programs alone read
+        slots = any(k in ("slots", "slots_aux") for k in self._pool_kinds)
         windowed = "window_pages" in self._pool_kinds
         # a suffix prefill continues from pages alone; a verify step
         # rolls back pages alone: pages that are all still there
@@ -1670,6 +1677,14 @@ class DecodeEngine:
                 "cache_bytes_needed_per_token": need * item}
             for k, v in self._latent_bytes.items():
                 profiler.set_gauge(f"mla.{k}", v)
+        # a spec whose layers keep a tail beside their pages (cca): what
+        # a token leaves in a layer's pages, what a stream's slots hold
+        tail_row = getattr(model, "tail_row", None)
+        if tail_row:
+            profiler.set_gauge(
+                "cca.cache_bytes_per_token",
+                2 * self._H * self._D * np.dtype(self._np_dtype).itemsize)
+            profiler.set_gauge("cca.tail_bytes_per_stream", 4 * tail_row[1])
         # the layers whose prompt kernels stop at the prompt's last row,
         # how many of each (window, latent); none for a family without
         self._prompt_layers = collections.Counter(

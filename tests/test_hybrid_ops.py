@@ -287,18 +287,30 @@ def _nodes():
 
 
 # (the case, whether its node takes its rows BY INDEX where the kernels
-# are on): pairs at or past _tile_rows' line of 4,096 make a prompt's
-# tiles of 128 rows, and with at most half the experts held here the
-# kernels fetch those rows themselves; with every expert held the rows
-# are gathered and only the way out (slabs, the combine kernel) is the
-# prompt's; below the line a decode step's 16-row tiles, gathered
+# are on — as a prompt, as a step): pairs at or past _tile_rows' line of
+# 4,096 make tiles of 128 rows, and with at most half the experts held
+# here the kernels fetch those rows themselves; with every expert held
+# the rows are gathered and only the way out (slabs, the combine kernel)
+# is the prompt's; below the line a decode step's 16-row tiles, gathered.
+# Since PR 48 a PROMPT takes the 128-row tiles from 32 pairs a held
+# expert on (a tile streams its expert's matrices whatever its rows): the
+# two cases just below the line are a step's 16-row tiles and a prompt's
+# 128 (the half-held one then by index), the two small ones lie below
+# the prompt's line too, and the last case — one expert a token, every
+# expert held — between the two lines
 MOE_CASES = {
-    "all_held_k6": (_moe_case(704, 6, 8, 8), False),
-    "all_held_k6_below_the_line": (_moe_case(680, 6, 8, 8), False),
-    "thin_share_k8": (_moe_case(1024, 8, 32, 4, first=8), True),
-    "half_held_relu_k10": (_moe_case(416, 10, 32, 16, act="relu"), True),
+    "all_held_k6": (_moe_case(704, 6, 8, 8), False, False),
+    "all_held_k6_below_the_line": (_moe_case(680, 6, 8, 8), False, False),
+    "all_held_k6_below_both_lines": (_moe_case(40, 6, 8, 8), False, False),
+    "thin_share_k8": (_moe_case(1024, 8, 32, 4, first=8), True, True),
+    "half_held_relu_k10": (
+        _moe_case(416, 10, 32, 16, act="relu"), True, True),
     "half_held_relu_k10_below_the_line": (
-        _moe_case(400, 10, 32, 16, act="relu"), False),
+        _moe_case(400, 10, 32, 16, act="relu"), True, False),
+    "half_held_relu_k10_below_both_lines": (
+        _moe_case(48, 10, 32, 16, act="relu"), False, False),
+    "all_held_k1_a_prompts_own_line": (
+        _moe_case(160, 1, 4, 4), False, False),
 }
 
 
@@ -309,7 +321,8 @@ def test_moe_ffn_rows_by_index_and_gathered(kernels, case, step):
     every held share, k = 6, 8, 10 and both gates, with padding in both
     ``step`` forms: a prompt (1, T, d) whose last 9 positions lie past
     ``lengths``, and a step (T, 1, d) with every seventh row empty."""
-    (z, p, h, act), by_index = MOE_CASES[case]
+    (z, p, h, act), *by_index = MOE_CASES[case]
+    by_index = by_index[step]
     T, d = h.shape
     live = np.arange(T) % 7 != 3 if step else np.arange(T) < T - 9
     before = _nodes()

@@ -659,6 +659,93 @@ def test_retention_kernels_gen_cell(on_chip, one_chip, monkeypatch):
     assert _kernel_short_names(compiled.as_text()) == ["retention_chunk"]
 
 
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_cca_layer_rollout_cell(on_chip, one_chip, monkeypatch, phase):
+    """One whole layer of the rollout cell's programs at its widths (8
+    query heads over 2 KV heads of 128 inside the latent, 16 experts of
+    2,048 chosen top-1 by the MLP router; 128 rows over 96-page tables,
+    129 slots, a prompt of 1,024): the attention is the grouped kernels
+    the other cells run, the latent's mixing is XLA's, and the three
+    pools a layer — K and V pages of 256 lanes, the tail — are written
+    where they lie."""
+    from mxnet_tpu.executor import build_graph_fn
+    from mxnet_tpu.models.hybrid_lm import HybridSpec
+
+    _hybrid(monkeypatch)
+    B, MB, T, V, d = 128, 96, 1024, 1024, 2048
+    layer = {"mixer": {"kind": "cca", "heads": 8, "kv_heads": 2,
+                       "head_dim": 128, "conv": [2, 2], "rope_theta": 5e6,
+                       "rotary_dim": 64},
+             "ffn": {"kind": "moe", "experts": 16, "top_k": 1,
+                     "width": 2048, "score": "softmax", "select_bias": True,
+                     "router": {"kind": "mlp", "hidden": 256,
+                                "carry": True}}}
+    spec = HybridSpec(V, d, [layer] * 2, tied_head=True,
+                      learned_residual=True)
+    pools = spec.pools(1 + B * MB, _KVB, B + 1, bf16)
+    assert [(n, s) for n, s, _, _ in pools[:3]] == [
+        ("layer0_kpool", (1 + B * MB, 16, 256)),
+        ("layer0_vpool", (1 + B * MB, 16, 256)),
+        ("layer0_tail", (B + 1, 8, 384))]
+    fn = build_graph_fn(spec.symbol(phase))
+    rows, cols = (B, 1) if phase == "decode" else (1, T)
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    feeds = dict(data=sds((rows, cols), i32), positions=sds((rows, cols), i32),
+                 lengths=sds((rows,), i32), slots=sds((rows,), i32),
+                 block_table=sds((rows, MB if phase == "decode"
+                                  else T // _KVB), i32))
+    hd, kd, n = 1024, 256, 256
+    shapes = dict(
+        norm1_gamma=(d,), norm2_gamma=(d,), q_weight=(hd, d),
+        k_weight=(kd, d), v1_weight=(128, d), v2_weight=(128, d),
+        o_weight=(d, hd), mix_conv0_weight=(hd + kd, 2),
+        mix_conv1_weight=(10, 2, 128, 128), res1_scales=(4, d),
+        res2_scales=(4, d), experts_gate_weight=(16, d, 2048),
+        experts_up_weight=(16, d, 2048), experts_down_weight=(16, 2048, d))
+    router = dict(
+        qk_norm_temperature=(2,), router_down_weight=(n, d),
+        router_carry_gamma=(n,), router_norm_gamma=(n,),
+        router_1_weight=(n, n), router_2_weight=(n, n),
+        router_3_weight=(16, n), router_bias=(16,))
+    params = {"tok_embed_weight": sds((V, d), bf16),
+              "final_norm_gamma": sds((d,), bf16)}
+    for i in range(2):
+        params.update({f"layer{i}_{k}": sds(v, bf16)
+                       for k, v in shapes.items()})
+        params.update({f"layer{i}_{k}": sds(v, f32)
+                       for k, v in router.items()
+                       if i or k != "router_carry_gamma"})
+    names = [n for n, _, _, _ in pools]
+    key = jax.random.PRNGKey(0)
+
+    def run(args, state):
+        outs, _ = fn(dict(args, **dict(zip(names, state))), {}, key, False)
+        return outs
+
+    compiled = jax.jit(run, donate_argnums=(1,)).lower(
+        dict(params, **feeds),
+        tuple(sds(s, dt) for _, s, dt, _ in pools)).compile()
+    text = compiled.as_text()
+    want = {"decode": {"paged_attention", "moe_gmm_gate_up", "moe_gmm_down",
+                       "slot_rows_write"},
+            # a prompt of 1,024 at one expert a token: 64 rows an expert,
+            # the MXU's tiles and the slabs' combine (hybrid._tile_rows)
+            "prefill": {"flash_fwd_mha", "kv_pages_write", "moe_gmm_gate_up",
+                        "moe_gmm_down", "moe_gmm_combine",
+                        "slot_rows_write"}}[phase]
+    assert {re.sub(r"_(silu|relu)$", "", k)
+            for k in _kernel_short_names(text)} == want
+    for _, shape, dt, _ in pools[:3]:
+        dims = ",".join(str(x) for x in shape)
+        ty = "f32" if dt == "float32" else "bf16"
+        copies = re.findall(rf"= {ty}\[{dims}\]\S* copy\(.*", text)
+        assert not copies, copies[:2]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
 @pytest.mark.parametrize("cell, T, window", [
     (_LONGDOC, 32768, 0), (_LONGDOC, 32768, _LONGDOC[-1]),
     (_LONGDOC, 8192, 0), (_MIXED, 8192, 0), (_MIXED, 8192, _MIXED[-1])])
