@@ -15,6 +15,8 @@ chip is ``chip_smoke.py``; what compiles for it without one is
 import os
 import sys
 
+import pytest
+
 os.environ["JAX_PLATFORMS"] = "cpu"
 prev = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in prev:
@@ -35,3 +37,31 @@ def pytest_configure(config):
         "slow: multi-process / long-running tests excluded from the "
         "tier-1 `-m 'not slow'` gate (decode-pool fan-out, kill-and-"
         "resume subprocess drills)")
+
+
+@pytest.fixture(params=[False, True], ids=["lax", "pallas"])
+def kernels(request, monkeypatch):
+    """Both bodies of every op: the lax fallback and the Pallas kernels
+    (interpreted on the CPU)."""
+    monkeypatch.setenv("MXNET_PALLAS", "1" if request.param else "0")
+    return request.param
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """One engine a file for each distinct argument set (``_engines``)."""
+    import _engines
+
+    held = _engines.Engines()
+    yield held
+    held.close()
+
+
+@pytest.fixture(autouse=True)
+def _engines_a_test_built_are_closed():
+    yield
+    # (not imported here: most files build no engine, and the module
+    # imports the package)
+    mod = sys.modules.get("_engines")
+    while mod is not None and mod.UNCLOSED:
+        mod.UNCLOSED.pop().close()

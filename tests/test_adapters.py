@@ -32,17 +32,15 @@ import time
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
-from mxnet_tpu import models
 from mxnet_tpu.adapters import (AdapterPool, QuotaExceededError,
                                 TenantQuota, adapters_enabled,
                                 pool_from_env, quota_from_env)
 from mxnet_tpu.base import MXNetError
-from mxnet_tpu.executor import build_graph_fn
-from mxnet_tpu.models.transformer import transformer_lm_prefill
 from mxnet_tpu.speculative import DraftLMProposer, make_proposer
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import (KVB, DM, H, L, V,  # noqa: E402
+                      dense_engine as _engine, tiny_lm_params,
+                      tiny_lm_reference)
 
 
 # ---------------------------------------------------------------------------
@@ -208,24 +206,9 @@ def test_adapter_env_validation(monkeypatch):
 
 @pytest.fixture(scope="module")
 def lm():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
-
-
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    """The tiny LM's parameters.  Every engine below is its test's own:
+    each has its own pool of adapters, quota or proposer."""
+    return tiny_lm_params()
 
 
 def _adapters(rng, n=4):
@@ -256,37 +239,11 @@ def _merged(params, a, b, alpha):
 
 
 @pytest.fixture(scope="module")
-def naive(lm):
+def naive():
     """Greedy reference through the UNPAGED prefill symbol with
     arbitrary (possibly merged) params."""
-    import jax
-    import jax.numpy as jnp
-
-    ps = transformer_lm_prefill(V, num_layers=L, num_heads=H,
-                                d_model=DM, kv_block=KVB, paged=False)
-    gfn = build_graph_fn(ps)
-    names = [n for n in ps.list_arguments() if n in lm]
-    key = jax.random.PRNGKey(0)
-
-    def generate(params, prompt, n):
-        base = {m: jnp.asarray(params[m].asnumpy()
-                               if hasattr(params[m], "asnumpy")
-                               else params[m]) for m in names}
-        seq = list(np.asarray(prompt))
-        out = []
-        for _ in range(n):
-            t = len(seq)
-            a = dict(base)
-            a.update(data=jnp.asarray(np.asarray(seq, np.int32)[None]),
-                     positions=jnp.asarray(
-                         np.arange(t, dtype=np.int32)[None]),
-                     lengths=jnp.asarray(np.asarray([t], np.int32)))
-            outs, _ = gfn(a, {}, key, False)
-            out.append(int(np.argmax(np.asarray(outs[0][0, t - 1]))))
-            seq.append(out[-1])
-        return np.asarray(out, np.int32)
-
-    return generate
+    return lambda params, prompt, n: \
+        tiny_lm_reference(params)[1](prompt, n)
 
 
 def test_no_adapter_streams_bit_identical_to_pre_adapter_engine(lm):

@@ -27,13 +27,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import profiler  # noqa: E402
 from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.executor import build_graph_fn  # noqa: E402
 from mxnet_tpu.models.hybrid_lm import HybridSpec  # noqa: E402
 
 from benchmark.reference import afmoe as ref  # noqa: E402
+from _engines import WAIT, Family  # noqa: E402
 
 # the published shape at a size a test can hold: the dense layer 0
 # (sliding) and expert layers 6-9 (sliding, full, sliding, sliding) of a
@@ -88,6 +88,11 @@ class Programs:
         self.fn = {ph: jax.jit(g, static_argnums=(3,))
                    for ph, g in self.graph.items()}
         self.key = jax.random.PRNGKey(0)
+
+    def fresh(self):
+        """The same programs over pools nobody has written."""
+        self.pools = [jnp.zeros_like(p) for p in self.pools]
+        return self
 
     def tables(self, row, length):
         """(block table, window table) rows of stream ``row`` about to
@@ -168,16 +173,17 @@ CASES = [(20, 90, 96), (107, 150, 128), (96, 120, 96)]
 def served():
     """The program's logits (lax bodies) for each case."""
     drawn = draw()
+    progs = Programs(drawn)      # one build for the cases
     out = []
     for i, (n_prompt, total, bucket) in enumerate(CASES):
         seq = sequence(20 + i, total)
         out.append((seq, n_prompt,
-                    Programs(drawn).serve(seq, n_prompt, bucket)))
+                    progs.fresh().serve(seq, n_prompt, bucket)))
     return drawn, out
 
 
 def reference_rows(drawn, seq, n_prompt, precision="float32"):
-    return np.asarray(ref.forward(CFG, drawn, seq, precision))[n_prompt - 1:]
+    return FAMILY.logits(drawn, seq, precision)[n_prompt - 1:]
 
 
 @pytest.mark.parametrize("case", range(len(CASES)))
@@ -354,27 +360,20 @@ def test_served_gaps_over_cropped_rows_are_those_of_all_rows(monkeypatch):
 
 # -- the engine -------------------------------------------------------------
 
-def make_engine(drawn=None, **kw):
-    drawn = drawn or draw()
-    args = dict(model=ref.spec(CFG), max_len=352, kv_block=KVB,
+FAMILY = Family(ref, CFG, pad=352, max_len=352, kv_block=KVB,
                 max_streams=3, decode_buckets=(1, 2, 4),
-                cache_buckets=(8, 22), prefill_buckets=(32, 96, 128),
-                ctx=mx.cpu(), dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(drawn), **args), drawn
+                cache_buckets=(8, 22), prefill_buckets=(32, 96, 128))
+# the tests that name no argument share one engine (``engines``) and read
+# its counters from ``reset_stats()`` on
+make_engine, served_gap = FAMILY.engine, FAMILY.served_gap
 
 
-def served_gap(drawn, prompt, out):
-    """How far below the reference's best logit the served tokens lie,
-    teacher-forced through the reference's full forward."""
-    seq = np.concatenate([prompt, out])
-    z = np.asarray(ref.forward(CFG, drawn, seq))
-    rows = z[len(prompt) - 1:len(seq) - 1]
-    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
-def test_the_engine_serves_unequal_prompts_counts_its_buckets_and_gets_every_page_back():  # noqa: E501
-    eng, drawn = make_engine()
+def test_the_engine_serves_unequal_prompts_counts_its_buckets_and_gets_every_page_back(engines):  # noqa: E501
+    eng, drawn = engines(make_engine)
     rng = np.random.default_rng(3)
     # shorter than the window, several windows long, on a page's edge,
     # inside a page; the first decodes six windows: its windowed pages
@@ -382,10 +381,9 @@ def test_the_engine_serves_unequal_prompts_counts_its_buckets_and_gets_every_pag
     ps = [rng.integers(1, 96, n).astype(np.int32)
           for n in (12, 107, 96, 45)]
     new = (6 * W - 12, 40, 30, 50)
-    with eng:
-        outs = [f.result(timeout=600) for f in
-                [eng.submit(p, max_new_tokens=m) for p, m in zip(ps, new)]]
-        st = eng.stats()
+    outs = [f.result(timeout=WAIT) for f in
+            [eng.submit(p, max_new_tokens=m) for p, m in zip(ps, new)]]
+    st = eng.stats()
     for p, o, m in zip(ps, outs, new):
         assert len(o) == m and served_gap(drawn, p, o) < 1e-4
     # the rows of the programs that ran: 32 + 128 + 96 + 96
@@ -433,14 +431,15 @@ def test_prompt_kernels_given_the_length_leave_the_logits(served,
 @pytest.mark.parametrize("lengths", [(20,), (96,), (20, 96)],
                          ids=["lower_half", "fills", "both"])
 def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
-        small_tiles, lengths):
-    eng, drawn = make_engine(prefill_buckets=(96,))
+        engines, small_tiles, lengths):
+    # one engine for the three cases: its programs are traced under
+    # ``small_tiles``, which each of them sets
+    eng, drawn = engines(make_engine, prefill_buckets=(96,))
     rng = np.random.default_rng(6)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
-    with eng:
-        outs = [f.result(timeout=600) for f in
-                [eng.submit(p, max_new_tokens=6) for p in ps]]
-        st = eng.stats()
+    outs = [f.result(timeout=WAIT) for f in
+            [eng.submit(p, max_new_tokens=6) for p in ps]]
+    st = eng.stats()
     for p, o in zip(ps, outs):
         assert served_gap(drawn, p, o) < 1e-4
     # four windowed layers (a band of 32 keys: two tiles a query tile
@@ -469,11 +468,10 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
         st["prefill_scores_computed_over_needed"]
 
 
-def test_the_lax_bodies_count_no_prompt_tile():
-    eng, _ = make_engine()
-    with eng:
-        eng.submit(sequence(1, 20), max_new_tokens=2).result(timeout=600)
-        st = eng.stats()
+def test_the_lax_bodies_count_no_prompt_tile(engines):
+    eng, _ = engines(make_engine)
+    eng.submit(sequence(1, 20), max_new_tokens=2).result(timeout=WAIT)
+    st = eng.stats()
     assert st["prefill_tiles_walked"] == st["prefill_tiles_skipped"] == 0
     assert st["prefill_tiles_skipped_share"] == 0.0
     assert st["prefill_tiles_masked"] == 0
@@ -485,12 +483,13 @@ def test_the_lax_bodies_count_no_prompt_tile():
 def test_recompute_preemption_under_a_tight_pool_leaves_the_logits():
     # 13 ordinary pages for three streams that grow to 6 each: someone
     # is thrown out, gives back its pages of both pools, and comes back
+    # (an engine of its own: the pool is sized for it)
     eng, drawn = make_engine(cache_blocks=14, max_len=96,
                              cache_buckets=(6,), prefill_buckets=(32, 96))
     rng = np.random.default_rng(5)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in (30, 41, 36)]
     with eng:
-        outs = [f.result(timeout=600) for f in
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=50) for p in ps]]
         st = eng.stats()
     assert st["preempted"] >= 1
@@ -517,13 +516,12 @@ def test_features_over_windowed_pools_are_refused_by_name(kw, feature):
     assert feature in str(err.value) and "window" in str(err.value)
 
 
-def test_page_export_and_import_are_refused_by_name():
-    eng, _ = make_engine()
-    with eng:
-        with pytest.raises(MXNetError, match="page export.*windowed"):
-            eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
-        with pytest.raises(MXNetError, match="page import.*windowed"):
-            eng.import_stream({}, [])
+def test_page_export_and_import_are_refused_by_name(engines):
+    eng, _ = engines(make_engine)
+    with pytest.raises(MXNetError, match="page export.*windowed"):
+        eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
+    with pytest.raises(MXNetError, match="page import.*windowed"):
+        eng.import_stream({}, [])
 
 
 # -- the spec -------------------------------------------------------------

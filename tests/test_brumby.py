@@ -26,9 +26,9 @@ import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.models.hybrid_lm import HybridSpec, mixer_state  # noqa: E402
 from mxnet_tpu.ops import hybrid, pallas_hybrid  # noqa: E402
-from mxnet_tpu.ops.registry import OpContext, get_op  # noqa: E402
 
 from benchmark.reference import brumby as ref  # noqa: E402
+from _engines import WAIT, Family, run_op  # noqa: E402
 
 # the published shape at a size a test can hold: 3 layers, 4 query heads
 # over 2 KV heads of 16 (a state of 9 x 16 rows of 16 a KV head), a gate
@@ -45,20 +45,12 @@ H, J, D = 4, 2, 16
 G = H // J
 
 
-@pytest.fixture(params=[False, True], ids=["lax", "pallas"])
-def kernels(request, monkeypatch):
-    """Both bodies of every op: the lax fallback and the Pallas kernels
-    (interpreted on the CPU), the prompt in chunks of 16 so that a test's
+@pytest.fixture
+def kernels(kernels, monkeypatch):
+    """conftest's two bodies, the prompt in chunks of 16 so that a test's
     prompt is several."""
-    monkeypatch.setenv("MXNET_PALLAS", "1" if request.param else "0")
     monkeypatch.setattr(pallas_hybrid, "RETENTION_CHUNK", 16)
-    return request.param
-
-
-def run_op(name, inputs, **attrs):
-    attrs = {k: str(v) for k, v in attrs.items()}
-    return get_op(name).compute(OpContext(is_train=False, rng=None), attrs,
-                                [jnp.asarray(x) for x in inputs], [])
+    return kernels
 
 
 f32 = lambda v: np.asarray(v, np.float32)
@@ -314,24 +306,25 @@ def test_spec_refuses_by_name(change, word):
 
 # -- the engine -----------------------------------------------------------
 
-def _engine(w, **kw):
-    args = dict(model=ref.spec(CFG), max_len=64, kv_block=8, max_streams=2,
+FAMILY = Family(ref, CFG, pad=64, max_len=64, kv_block=8, max_streams=2,
                 decode_buckets=(2,), prefill_buckets=(16, 32),
-                temperature=0.0, ctx=mx.cpu(), dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(w), **args)
+                temperature=0.0)
+
+
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
 @pytest.fixture(scope="module")
 def weights():
-    return ref.draw(CFG, 7, embed_dtype="float32", dtype="float32")
+    return FAMILY.draw()
 
 
 def _gaps(w, prompt, served):
     """The served tokens' logit gaps below the reference's best, and the
     reference's states once all but the last token have been fed."""
     toks = np.concatenate([prompt, served])
-    lg = np.asarray(ref.forward(CFG, w, toks))[len(prompt) - 1:-1]
+    lg = FAMILY.logits(w, toks)[len(prompt) - 1:-1]
     gap = lg.max(-1) - np.take_along_axis(lg, served[:, None], -1)[:, 0]
     states = ref.final_states(CFG, w, jnp.asarray(np.pad(
         toks, (0, 64 - len(toks)))), len(toks) - 1)
@@ -362,14 +355,12 @@ def test_engine_prompt_then_decode_is_the_references_full_forward(
     every slot's last state against the reference."""
     rng = np.random.default_rng(0)
     prompts = [rng.integers(1, 97, n).astype(np.int32) for n in (11, 29, 7)]
-    eng = _engine(weights)
-    try:
+    # an engine a body (and the chunk of 16 is this test's alone)
+    with FAMILY.engine()[0] as eng:
         futs = [eng.submit(p, max_new_tokens=10, return_state=True)
                 for p in prompts]
-        outs = [f.result(timeout=600) for f in futs]
+        outs = [f.result(timeout=WAIT) for f in futs]
         st = eng.stats()
-    finally:
-        eng.close()
     assert st["state_slots"] == 2 and st["state_slots_live"] == 0
     for p, o in zip(prompts, outs):
         gap, states = _gaps(weights, p, np.asarray(o["tokens"]))
@@ -383,37 +374,27 @@ def test_engine_prompt_then_decode_is_the_references_full_forward(
 
 
 def test_interleaved_streams_do_not_share_and_a_slot_starts_from_zero(
-        weights):
+        engines):
     """Two streams decode side by side, then a third takes a freed slot:
-    each serves what it serves alone."""
+    each serves what it serves alone (one after another through an engine
+    of one stream: the one slot is dirty from the second on)."""
     rng = np.random.default_rng(3)
     prompts = [rng.integers(1, 97, n).astype(np.int32) for n in (9, 14, 12)]
-    alone = []
-    for p in prompts:
-        eng = _engine(weights, max_streams=1, decode_buckets=(1,))
-        try:
-            alone.append(np.asarray(eng.submit(p, max_new_tokens=8)
-                                    .result(timeout=600)))
-        finally:
-            eng.close()
-    eng = _engine(weights)
-    try:
-        futs = [eng.submit(p, max_new_tokens=8) for p in prompts]
-        together = [np.asarray(f.result(timeout=600)) for f in futs]
-    finally:
-        eng.close()
+    eng, _ = engines(FAMILY.engine, max_streams=1, decode_buckets=(1,))
+    alone = [np.asarray(eng.submit(p, max_new_tokens=8).result(timeout=WAIT))
+             for p in prompts]
+    eng, _ = engines(FAMILY.engine)
+    futs = [eng.submit(p, max_new_tokens=8) for p in prompts]
+    together = [np.asarray(f.result(timeout=WAIT)) for f in futs]
     for a, b in zip(alone, together):
         assert np.array_equal(a, b)
 
 
-def test_a_page_less_spec_builds_its_engine_and_carries_no_page(weights):
-    eng = _engine(weights)
-    try:
-        out = eng.submit(np.arange(1, 10, dtype=np.int32),
-                         max_new_tokens=4).result(timeout=600)
-        st = eng.stats()
-    finally:
-        eng.close()
+def test_a_page_less_spec_builds_its_engine_and_carries_no_page(engines):
+    eng, _ = engines(FAMILY.engine)
+    out = eng.submit(np.arange(1, 10, dtype=np.int32),
+                     max_new_tokens=4).result(timeout=WAIT)
+    st = eng.stats()
     assert len(out) == 4
     assert st["cache_util"] == 0.0 and st["context_tokens"] >= 0
     assert st["state_pool_bytes"] == 3 * 3 * J * (9 * D * D + D * D) * 4
@@ -431,7 +412,7 @@ def test_a_page_less_spec_builds_its_engine_and_carries_no_page(weights):
     ({"tp": 2}, "tp=2")])
 def test_engine_refuses_by_name(weights, kw, word):
     with pytest.raises(MXNetError, match=word) as e:
-        _engine(weights, **kw)
+        FAMILY.engine(weights, **kw)
     assert "slot" in str(e.value)
 
 

@@ -227,7 +227,7 @@ def test_kill_background_writer_mid_shard_then_resume(tmp_path):
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tests", "ckpt_crash_worker.py"),
          "--ckpt-dir", d, "--epochs", "2", "--every-n", "6"],
-        capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=120, cwd=REPO, env=env)
     assert r.returncode == 9, r.stdout + r.stderr  # the injected exit
     infos = C.list_checkpoints(d)
     committed = [i for i in infos if i.committed]

@@ -86,7 +86,7 @@ def test_script_fails_off_the_chip():
     r = subprocess.run([sys.executable,
                         os.path.join(REPO, "chip_smoke.py")],
                        env=env, capture_output=True, text=True,
-                       timeout=300)
+                       timeout=120)
     assert r.returncode != 0
     assert '"ok"' not in r.stdout
     assert "needs a TPU" in r.stderr
@@ -101,6 +101,6 @@ def test_script_fails_alone(tmp_path):
     env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
     r = subprocess.run([sys.executable, "chip_smoke.py"], cwd=tmp_path,
                        env=env, capture_output=True, text=True,
-                       timeout=300)
+                       timeout=120)
     assert r.returncode != 0
     assert '"ok"' not in r.stdout

@@ -14,70 +14,26 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import models, profiler
+from mxnet_tpu import profiler
 from mxnet_tpu.executor import build_graph_fn
 from mxnet_tpu.kv_cache import (BlockAllocator, blocks_for_tokens,
                                 bucket_ladder, value_pool_shape)
 from mxnet_tpu.models.transformer import (transformer_lm_decode,
                                           transformer_lm_prefill)
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import (KVB, MAXLEN, WAIT, DM, H, L, V,  # noqa: E402
+                      build, dense_engine as _engine, tiny_lm_params,
+                      tiny_lm_reference)
 
 
 @pytest.fixture(scope="module")
 def lm():
     """Tiny trained-shape transformer: params + a greedy full-forward
     reference that goes through the TRAINING symbol (SoftmaxOutput
-    head), so decode is checked against the genuine serving target."""
-    import jax
-    import jax.numpy as jnp
-
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    params = {**arg, **aux}
-
-    ps = transformer_lm_prefill(V, num_layers=L, num_heads=H,
-                                d_model=DM, kv_block=KVB, paged=False)
-    gfn = build_graph_fn(ps)
-    base = {n: jnp.asarray(params[n].asnumpy())
-            for n in ps.list_arguments() if n in params}
-    key = jax.random.PRNGKey(0)
-
-    def full_logits(seq):
-        """Full-sequence causal forward at the natural length."""
-        T = len(seq)
-        a = dict(base)
-        a.update(data=jnp.asarray(np.asarray(seq, np.int32)[None]),
-                 positions=jnp.asarray(
-                     np.arange(T, dtype=np.int32)[None]),
-                 lengths=jnp.asarray(np.asarray([T], np.int32)))
-        outs, _ = gfn(a, {}, key, False)
-        return np.asarray(outs[0][0])  # (T, V)
-
-    def naive_generate(prompt, n):
-        seq = list(np.asarray(prompt))
-        out = []
-        for _ in range(n):
-            out.append(int(np.argmax(full_logits(seq)[-1])))
-            seq.append(out[-1])
-        return np.asarray(out, np.int32)
-
-    return params, full_logits, naive_generate
-
-
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    head), so decode is checked against the genuine serving target.
+    The tests that name no argument share one engine (``engines``)."""
+    params = tiny_lm_params()
+    return (params,) + tiny_lm_reference(params)
 
 
 # ---------------------------------------------------------------------------
@@ -528,14 +484,24 @@ def test_blocks_for_tokens_and_ladder():
 # ---------------------------------------------------------------------------
 
 
-def test_engine_smoke_greedy_decode(lm):
+def test_the_padded_greedy_chain_is_the_natural_lengths(lm):
+    """``naive_generate`` runs one program at MAXLEN rows; its chain is
+    the chain of full forwards at each natural length."""
+    _, full_logits, naive_generate = lm
+    seq = [3, 17, 42, 5, 9]
+    for _ in range(3):
+        seq.append(int(np.argmax(full_logits(seq)[-1])))
+    np.testing.assert_array_equal(naive_generate(seq[:5], 3), seq[5:])
+
+
+def test_engine_smoke_greedy_decode(engines, lm):
     """4-token greedy decode on a tiny model equals the full-forward
     argmax chain — the tier-1-visible variant of the slow loops."""
     params, _, naive_generate = lm
     prompt = np.array([3, 17, 42, 5, 9], np.int32)
-    with _engine(params) as eng:
-        got = eng.generate(prompt, 4)
-        st = eng.stats()
+    eng = engines(_engine, params)
+    got = eng.generate(prompt, 4)
+    st = eng.stats()
     np.testing.assert_array_equal(got, naive_generate(prompt, 4))
     assert st["generations"] == 1 and st["tokens"] == 4
     assert st["prefill_tokens"] == 5
@@ -545,7 +511,7 @@ def test_engine_admission_and_cache_accounting(lm):
     params, _, _ = lm
     with _engine(params, cache_blocks=33) as eng:
         f = eng.submit(np.arange(1, 6, dtype=np.int32), 3)
-        f.result(timeout=120)
+        f.result(timeout=WAIT)
         st = eng.stats()
         # everything retired: all pages back in the pool
         assert st["cache_util"] == 0.0
@@ -566,15 +532,14 @@ def test_engine_submit_validation(lm):
         eng.submit(np.arange(3, dtype=np.int32), 2)
 
 
-def test_engine_eos_stops_early(lm):
+def test_engine_eos_stops_early(engines, lm):
     """Greedy chains revisit tokens; use the first generated token as
     eos so generation must stop right after producing it again."""
     params, _, naive_generate = lm
     prompt = np.array([3, 17, 42, 5, 9], np.int32)
     ref = naive_generate(prompt, 6)
     eos = int(ref[2])
-    with _engine(params) as eng:
-        got = eng.generate(prompt, 6, eos_id=eos)
+    got = engines(_engine, params).generate(prompt, 6, eos_id=eos)
     stop = int(np.argmax(ref == eos)) + 1
     np.testing.assert_array_equal(got, ref[:stop])
     assert got[-1] == eos
@@ -630,32 +595,32 @@ def test_env_validation_garbage_raises(monkeypatch, lm):
     params, _, _ = lm
     monkeypatch.setenv("MXNET_SERVING_KV_BLOCK", "banana")
     with pytest.raises(mx.MXNetError, match="MXNET_SERVING_KV_BLOCK"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     monkeypatch.setenv("MXNET_SERVING_KV_BLOCK", "-4")
     with pytest.raises(mx.MXNetError, match="MXNET_SERVING_KV_BLOCK"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     monkeypatch.delenv("MXNET_SERVING_KV_BLOCK")
     monkeypatch.setenv("MXNET_SERVING_MAX_STREAMS", "0")
     with pytest.raises(mx.MXNetError,
                        match="MXNET_SERVING_MAX_STREAMS"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     monkeypatch.delenv("MXNET_SERVING_MAX_STREAMS")
     monkeypatch.setenv("MXNET_SERVING_DECODE_BUCKETS", "4,2,1")
     with pytest.raises(mx.MXNetError, match="increasing"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     monkeypatch.setenv("MXNET_SERVING_DECODE_BUCKETS", "1,zebra")
     with pytest.raises(mx.MXNetError, match="comma-separated"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     monkeypatch.delenv("MXNET_SERVING_DECODE_BUCKETS")
     monkeypatch.setenv("MXNET_SERVING_PREFILL_BUCKETS", "3,7")
     with pytest.raises(mx.MXNetError, match="multiple of"):
-        mx.DecodeEngine(params, vocab_size=V, num_layers=L,
-                        num_heads=H, d_model=DM, max_len=MAXLEN)
+        build(params, vocab_size=V, num_layers=L, num_heads=H,
+                  d_model=DM, max_len=MAXLEN)
     # registered in the config catalog
     for name in ("MXNET_SERVING_KV_BLOCK", "MXNET_SERVING_MAX_STREAMS",
                  "MXNET_SERVING_DECODE_BUCKETS",
@@ -679,18 +644,18 @@ def test_ladder_coverage_validated_at_construction(lm):
         _engine(params, prefill_buckets=[16, 8])
 
 
-def test_reset_stats_isolates_measurement_points(lm):
+def test_reset_stats_isolates_measurement_points(engines, lm):
     """A sweep drives one engine across load points; reset_stats
     must zero counters AND histogram reservoirs so a point's
     percentiles don't blend earlier points' samples."""
     params, _, _ = lm
-    with _engine(params) as eng:
-        eng.generate(np.arange(1, 5, dtype=np.int32), 4)
-        st = eng.stats()
-        assert st["tokens"] >= 4 and st["p50_ms"] is not None
-        eng.reset_stats()
-        st = eng.stats()
-        assert st["tokens"] == 0 and st["p50_ms"] is None
+    eng = engines(_engine, params)
+    eng.generate(np.arange(1, 5, dtype=np.int32), 4)
+    st = eng.stats()
+    assert st["tokens"] >= 4 and st["p50_ms"] is not None
+    eng.reset_stats()
+    st = eng.stats()
+    assert st["tokens"] == 0 and st["p50_ms"] is None
 
 
 # ---------------------------------------------------------------------------
@@ -714,7 +679,7 @@ def test_multi_stream_join_retire_outputs_unchanged(lm):
             futs.append(eng.submit(p, n))
             if i == 2:
                 time.sleep(0.1)  # stagger: join mid-loop
-        outs = [f.result(timeout=300) for f in futs]
+        outs = [f.result(timeout=WAIT) for f in futs]
         st = eng.stats()
     for p, n, o in zip(prompts, lens, outs):
         np.testing.assert_array_equal(o, naive_generate(p, n))
@@ -734,7 +699,7 @@ def test_preemption_recompute_outputs_unchanged(lm):
                np.arange(13, 18, dtype=np.int32)]
     with _engine(params, max_streams=3, cache_blocks=10) as eng:
         futs = [eng.submit(p, 14) for p in prompts]
-        outs = [f.result(timeout=300) for f in futs]
+        outs = [f.result(timeout=WAIT) for f in futs]
         st = eng.stats()
     assert st["preempted"] > 0
     for p, o in zip(prompts, outs):
@@ -754,7 +719,7 @@ def test_temperature_sampling_reproducible_across_batching(lm):
         futs = [eng.submit(prompt, 8, temperature=0.8),
                 eng.submit(np.array([9, 9], np.int32), 8,
                            temperature=0.5)]
-        batched = futs[0].result(timeout=300)
+        batched = futs[0].result(timeout=WAIT)
     np.testing.assert_array_equal(alone, batched)
 
 
@@ -779,7 +744,7 @@ def test_capacity_edge_request_admits(lm):
     # capacity 4 pages = 16 tokens; 15-token prompt + 1 token fills it
     prompt = np.arange(1, 16, dtype=np.int32)
     with _engine(params, cache_blocks=5, max_streams=1) as eng:
-        out = eng.submit(prompt, 1).result(timeout=120)
+        out = eng.submit(prompt, 1).result(timeout=WAIT)
     np.testing.assert_array_equal(out, naive_generate(prompt, 1))
 
 
@@ -830,6 +795,8 @@ def test_multi_token_decode_qkv_rejected():
 def test_decode_telemetry_surfaces(lm):
     profiler.reset_metrics()
     params, _, _ = lm
+    # an engine of its own: the registry was reset, and an engine writes
+    # some of its gauges when it is built and when it is closed
     with _engine(params) as eng:
         eng.generate(np.arange(1, 5, dtype=np.int32), 4)
     summ = profiler.metrics_summary()
@@ -887,23 +854,20 @@ def test_decode_drain_resume_and_inflight(lm):
         eng.close(timeout=30)
 
 
-def test_submit_seed_override_reproduces_across_engines(lm):
+def test_submit_seed_override_reproduces_across_engines(engines, lm):
     """Fleet retry determinism: the same (prompt, seed) sampled at
     temperature > 0 yields identical tokens on a DIFFERENT engine with
     different stream-id history — the property that lets a survivor
     re-generate a dead replica's request bit-exactly."""
     params, _, _ = lm
     p = np.arange(1, 5, dtype=np.int32)
-    e1 = _engine(params)
+    a = engines(_engine, params).submit(
+        p, 6, temperature=0.7, seed=123).result(WAIT)
+    e2 = _engine(params)        # a second engine is the point
     try:
-        a = e1.submit(p, 6, temperature=0.7, seed=123).result(120)
-    finally:
-        e1.close(timeout=30)
-    e2 = _engine(params)
-    try:
-        e2.submit(p, 3).result(120)  # shift e2's stream-id history
-        b = e2.submit(p, 6, temperature=0.7, seed=123).result(120)
-        c = e2.submit(p, 6, temperature=0.7, seed=124).result(120)
+        e2.submit(p, 3).result(WAIT)  # shift e2's stream-id history
+        b = e2.submit(p, 6, temperature=0.7, seed=123).result(WAIT)
+        c = e2.submit(p, 6, temperature=0.7, seed=124).result(WAIT)
     finally:
         e2.close(timeout=30)
     assert np.array_equal(a, b)
@@ -918,14 +882,14 @@ def test_decode_swap_params_identity_and_validation(lm):
     eng = _engine(params)
     try:
         p = np.arange(1, 6, dtype=np.int32)
-        before = eng.submit(p, 5).result(120)
+        before = eng.submit(p, 5).result(WAIT)
         # warm the prefix-hit path too (a repeated prompt lazily
         # compiles the suffix-prefill bucket on its first hit — that
         # compile belongs to the hit, not to the swap under test)
-        assert np.array_equal(eng.submit(p, 5).result(120), before)
+        assert np.array_equal(eng.submit(p, 5).result(WAIT), before)
         eng.swap_params(params)  # same weights, full round-trip
         compiles_before = dict(eng.compiles)
-        after = eng.submit(p, 5).result(120)
+        after = eng.submit(p, 5).result(WAIT)
         assert np.array_equal(before, after)
         assert dict(eng.compiles) == compiles_before  # no recompile
         name = eng._param_names[0]
@@ -936,6 +900,6 @@ def test_decode_swap_params_identity_and_validation(lm):
         with pytest.raises(mx.MXNetError, match="shape"):
             eng.swap_params(bad)
         # the failed swaps never installed anything
-        assert np.array_equal(eng.submit(p, 5).result(120), before)
+        assert np.array_equal(eng.submit(p, 5).result(WAIT), before)
     finally:
         eng.close(timeout=30)

@@ -27,7 +27,6 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import kv_cache, profiler  # noqa: E402
 from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.executor import build_graph_fn  # noqa: E402
@@ -35,6 +34,7 @@ from mxnet_tpu.models.hybrid_lm import HybridSpec  # noqa: E402
 from mxnet_tpu.ops import hybrid as hy  # noqa: E402
 
 from benchmark.reference import deepseek_v3 as ref  # noqa: E402
+from _engines import WAIT, Family  # noqa: E402
 
 # the published shape at a size a test can hold: one dense layer and
 # three expert layers, 4 heads of (16 + 8 | 16), ranks 24 / 16, 16
@@ -86,6 +86,11 @@ class Programs:
         self.fn = {ph: jax.jit(g, static_argnums=(3,))
                    for ph, g in self.graph.items()}
         self.key = jax.random.PRNGKey(0)
+
+    def fresh(self):
+        """The same programs over pools nobody has written."""
+        self.pools = [jnp.zeros_like(p) for p in self.pools]
+        return self
 
     def args(self, tokens, positions, lengths):
         table = np.zeros((1, self.mb), np.int32)
@@ -144,16 +149,17 @@ CASES = [(20, 90), (32, 70)]
 def served():
     """The program's logits (lax bodies) for each case."""
     drawn = draw()
+    progs = Programs(drawn)      # one build for the cases
     out = []
     for i, (n_prompt, total) in enumerate(CASES):
         seq = sequence(20 + i, total)
         out.append((seq, n_prompt,
-                    Programs(drawn).serve(seq, n_prompt, bucket=96)))
+                    progs.fresh().serve(seq, n_prompt, bucket=96)))
     return drawn, out
 
 
 def reference_rows(drawn, seq, n_prompt, precision="float32"):
-    return np.asarray(ref.forward(CFG, drawn, seq, precision))[n_prompt - 1:]
+    return FAMILY.logits(drawn, seq, precision)[n_prompt - 1:]
 
 
 @pytest.mark.parametrize("case", range(len(CASES)))
@@ -370,35 +376,28 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
 
 # -- the engine: latent pages ---------------------------------------------
 
-def make_engine(drawn=None, **kw):
-    drawn = drawn or draw()
-    args = dict(model=ref.spec(CFG), max_len=128, kv_block=KVB,
+FAMILY = Family(ref, CFG, pad=128, max_len=128, kv_block=KVB,
                 max_streams=3, decode_buckets=(1, 2, 4),
-                cache_buckets=(4, 8), prefill_buckets=(32, 96),
-                ctx=mx.cpu(), dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(drawn), **args), drawn
+                cache_buckets=(4, 8), prefill_buckets=(32, 96))
+# the tests that name no argument share one engine (``engines``) and read
+# its counters from ``reset_stats()`` on
+make_engine, served_gap = FAMILY.engine, FAMILY.served_gap
 
 
-def served_gap(drawn, prompt, out):
-    """How far below the reference's best logit the served tokens lie,
-    teacher-forced through the reference's full forward."""
-    seq = np.concatenate([prompt, out])
-    z = np.asarray(ref.forward(CFG, drawn, seq))
-    rows = z[len(prompt) - 1:len(seq) - 1]
-    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
-def test_a_batch_of_unequal_lengths_is_served_and_every_page_comes_back():
-    eng, drawn = make_engine()
+def test_a_batch_of_unequal_lengths_is_served_and_every_page_comes_back(
+        engines):
+    eng, drawn = engines(make_engine)
     rng = np.random.default_rng(3)
     # one prompt ends on a page's edge, one inside a page, one is short
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in (32, 45, 7, 80)]
-    with eng:
-        outs = [f.result(timeout=600) for f in
-                [eng.submit(p, max_new_tokens=m)
-                 for p, m in zip(ps, (40, 30, 50, 20))]]
-        st = eng.stats()
+    outs = [f.result(timeout=WAIT) for f in
+            [eng.submit(p, max_new_tokens=m)
+             for p, m in zip(ps, (40, 30, 50, 20))]]
+    st = eng.stats()
     for p, o in zip(ps, outs):
         assert served_gap(drawn, p, o) < 1e-4
     assert eng._alloc.used_blocks == 0 and st["preempted"] == 0
@@ -441,15 +440,16 @@ def test_prompt_kernels_given_the_length_leave_the_logits(monkeypatch,
 @pytest.mark.parametrize("lengths", [(20,), (96,), (20, 96)],
                          ids=["lower_half", "fills", "both"])
 def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
-        monkeypatch, lengths):
+        engines, monkeypatch, lengths):
+    # one engine for the three cases: its programs are traced under
+    # ``small_tiles``, which each of them sets
     small_tiles(monkeypatch)
-    eng, drawn = make_engine(prefill_buckets=(96,))
+    eng, drawn = engines(make_engine, prefill_buckets=(96,))
     rng = np.random.default_rng(6)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
-    with eng:
-        outs = [f.result(timeout=600) for f in
-                [eng.submit(p, max_new_tokens=6) for p in ps]]
-        st = eng.stats()
+    outs = [f.result(timeout=WAIT) for f in
+            [eng.submit(p, max_new_tokens=6) for p in ps]]
+    st = eng.stats()
     for p, o in zip(ps, outs):
         assert served_gap(drawn, p, o) < 1e-4
     # four latent layers, every key up to the query (1 + 2 + 3), three
@@ -471,13 +471,14 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
 
 def test_recompute_preemption_under_a_tight_pool_leaves_the_logits():
     # 9 pages for three streams that grow to 5 each: someone is thrown
-    # out, gives its pages back, and is prefilled again
+    # out, gives its pages back, and is prefilled again (an engine of its
+    # own: the pool is sized for it)
     eng, drawn = make_engine(cache_blocks=10, max_len=80,
                              cache_buckets=(5,))
     rng = np.random.default_rng(5)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in (30, 41, 36)]
     with eng:
-        outs = [f.result(timeout=600) for f in
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=38) for p in ps]]
         st = eng.stats()
     assert st["preempted"] >= 1
@@ -501,13 +502,12 @@ def test_features_over_latent_pages_are_refused_by_name(kw, feature):
     assert feature in str(err.value) and "mla" in str(err.value)
 
 
-def test_page_export_and_import_are_refused_by_name():
-    eng, _ = make_engine()
-    with eng:
-        with pytest.raises(MXNetError, match="page export.*latent row"):
-            eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
-        with pytest.raises(MXNetError, match="page import.*latent row"):
-            eng.import_stream({}, [])
+def test_page_export_and_import_are_refused_by_name(engines):
+    eng, _ = engines(make_engine)
+    with pytest.raises(MXNetError, match="page export.*latent row"):
+        eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
+    with pytest.raises(MXNetError, match="page import.*latent row"):
+        eng.import_stream({}, [])
 
 
 # -- the spec -------------------------------------------------------------

@@ -31,7 +31,7 @@ def test_launch_two_process_dist_sync():
         [sys.executable, os.path.join(REPO, "tools", "launch.py"),
          "-n", "2", "--cpu",
          sys.executable, os.path.join(REPO, "tests", "dist_worker.py")],
-        capture_output=True, text=True, timeout=600,
+        capture_output=True, text=True, timeout=180,
         cwd=REPO, env=_worker_env())
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
@@ -87,7 +87,7 @@ def test_launch_module_fit_dist_sync(tmp_path):
          "-n", "2", "--cpu",
          sys.executable, os.path.join(REPO, "tests", "dist_module_worker.py"),
          out],
-        capture_output=True, text=True, timeout=900, cwd=REPO)
+        capture_output=True, text=True, timeout=180, cwd=REPO)
     o = r.stdout + r.stderr
     assert r.returncode == 0, o
     assert "worker 0/2: module fit dist_sync OK" in o
@@ -130,7 +130,7 @@ def test_launch_module_fit_tpu_mesh(tmp_path):
          "-n", "2", "--cpu",
          sys.executable, os.path.join(REPO, "tests", "dist_tpu_mesh_worker.py"),
          out],
-        capture_output=True, text=True, timeout=900, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=180, cwd=REPO, env=env)
     o = r.stdout + r.stderr
     assert r.returncode == 0, o
     assert "worker 0/2: module fit tpu mesh OK" in o
@@ -192,7 +192,7 @@ def test_launch_module_fit_dist_sync_on_server(tmp_path):
          "-n", "2", "--cpu",
          sys.executable,
          os.path.join(REPO, "tests", "dist_sync_server_worker.py"), out],
-        capture_output=True, text=True, timeout=900, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=180, cwd=REPO, env=env)
     o = r.stdout + r.stderr
     assert r.returncode == 0, o
     assert "worker 0/2: module fit dist_sync on-server OK" in o
@@ -230,7 +230,7 @@ def test_telemetry_traces_and_watchdog(tmp_path):
          "-n", "2", "--cpu",
          sys.executable,
          os.path.join(REPO, "tests", "dist_telemetry_worker.py"), trace_dir],
-        capture_output=True, text=True, timeout=600, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=180, cwd=REPO, env=env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
     assert "worker 0/2: telemetry OK" in out
@@ -285,7 +285,7 @@ def test_comm_overlap_trace(tmp_path):
          "-n", "2", "--cpu",
          sys.executable,
          os.path.join(REPO, "tests", "dist_overlap_worker.py"), trace_dir],
-        capture_output=True, text=True, timeout=600, cwd=REPO, env=env)
+        capture_output=True, text=True, timeout=180, cwd=REPO, env=env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
     digests = re.findall(r"comm overlap OK digest=([\d.]+)", out)
@@ -339,7 +339,7 @@ def test_launch_two_process_dist_async():
         [sys.executable, os.path.join(REPO, "tools", "launch.py"),
          "-n", "2", "--cpu",
          sys.executable, os.path.join(REPO, "tests", "dist_async_worker.py")],
-        capture_output=True, text=True, timeout=600, cwd=REPO,
+        capture_output=True, text=True, timeout=180, cwd=REPO,
         env=env)
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
@@ -358,7 +358,7 @@ def test_launch_module_fit_dist_async():
          "-n", "2", "--cpu",
          sys.executable,
          os.path.join(REPO, "tests", "dist_async_module_worker.py")],
-        capture_output=True, text=True, timeout=900, cwd=REPO,
+        capture_output=True, text=True, timeout=180, cwd=REPO,
         env=_worker_env())
     out = r.stdout + r.stderr
     assert r.returncode == 0, out
@@ -384,7 +384,7 @@ def test_elastic_chaos_drill_2_1_2(tmp_path):
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "chaos_drill.py"),
          "--out", out, "--kill-step", "10"],
-        capture_output=True, text=True, timeout=900, cwd=REPO)
+        capture_output=True, text=True, timeout=180, cwd=REPO)
     assert r.returncode == 0, r.stdout + r.stderr
     verdict = json.loads(r.stdout.strip().splitlines()[-1])
     assert verdict["converged"], verdict
@@ -416,7 +416,7 @@ def test_ckpt_kill_and_resume(tmp_path):
     # uninterrupted reference
     ckpt_a, out_a = str(tmp_path / "ckpt_a"), str(tmp_path / "a")
     r = subprocess.run(launch + [ckpt_a, out_a], capture_output=True,
-                      text=True, timeout=600, cwd=REPO, env=_worker_env())
+                      text=True, timeout=180, cwd=REPO, env=_worker_env())
     o = r.stdout + r.stderr
     assert r.returncode == 0, o
     assert "worker 0/2: ckpt dist fit OK" in o
@@ -427,7 +427,7 @@ def test_ckpt_kill_and_resume(tmp_path):
     env = _worker_env()
     env["MXNET_CKPT_CRASH"] = "before_commit:2"
     r = subprocess.run(launch + [ckpt_b, out_b], capture_output=True,
-                      text=True, timeout=600, cwd=REPO, env=env)
+                      text=True, timeout=180, cwd=REPO, env=env)
     assert r.returncode != 0, r.stdout + r.stderr
 
     from mxnet_tpu import checkpoint as C
@@ -446,7 +446,7 @@ def test_ckpt_kill_and_resume(tmp_path):
     # resume run: picks the last committed checkpoint (step 4),
     # replays, and lands on the uninterrupted run's exact weights
     r = subprocess.run(launch + [ckpt_b, out_b], capture_output=True,
-                      text=True, timeout=600, cwd=REPO, env=_worker_env())
+                      text=True, timeout=180, cwd=REPO, env=_worker_env())
     o = r.stdout + r.stderr
     assert r.returncode == 0, o
     assert "resuming from" in o and "step 4" in o

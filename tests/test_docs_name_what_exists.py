@@ -65,3 +65,27 @@ def test_the_check_sees_a_name_that_is_gone():
             f"{gone_record}, tools/launch.py, chip_smoke.py, "
             f"benchmark/run.py and the reference's src/tools/im2rec.py")
     assert missing(text) == {gone_tool, gone_bench, gone_record}
+
+
+def test_every_test_file_builds_its_engines_through_the_shared_helper():
+    """The rule that keeps tier-1 inside its clock (``tests/_engines.py``):
+    no ``tests/test_*.py`` constructs a ``DecodeEngine`` itself or
+    defines an engine helper of its own — it goes through ``build`` (or
+    ``dense_engine`` / ``Family.engine``, which do), so that conftest's
+    ``engines`` can share what is built and closes what is left open."""
+    built = re.compile(r"\bDecodeEngine\(")
+    own = re.compile(r"^def (make_engine|served_gap|watch_slots)\(", re.M)
+    at_fault = {}
+    for path in sorted(glob.glob(os.path.join(ROOT, "tests", "test_*.py"))):
+        if os.path.basename(path) == os.path.basename(__file__):
+            continue
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        found = built.findall(text) + own.findall(text)
+        if found:
+            at_fault[os.path.relpath(path, ROOT)] = found
+    assert not at_fault, (
+        f"engines built outside tests/_engines.py: {at_fault}")
+    with open(os.path.join(ROOT, "tests", "_engines.py"),
+              encoding="utf-8") as f:
+        assert len(built.findall(f.read())) == 1     # ``build`` alone

@@ -109,7 +109,7 @@ np.save(sys.argv[1], w)
                        MXNET_BACKWARD_DO_MIRROR=mirror)
             r = subprocess.run([sys.executable, "-c", script % REPO, out],
                                capture_output=True, text=True, env=env,
-                               timeout=300)
+                               timeout=120)
             assert r.returncode == 0, r.stderr
             outs.append(np.load(out))
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)

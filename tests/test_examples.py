@@ -11,7 +11,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 EX = os.path.join(REPO, "examples")
 
 
-def run_example(script, *args, mesh=False, timeout=600):
+def run_example(script, *args, mesh=False, timeout=180):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8" if mesh \

@@ -20,36 +20,18 @@ from concurrent.futures import Future
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
-from mxnet_tpu import models, wire
+from mxnet_tpu import wire
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.fleet import REPLICA_ROLES, Router, roles_env
 from mxnet_tpu.kv_cache import BlockAllocator
 from mxnet_tpu.serving import ReplicaHarness
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import KVB, DM, H, L, dense_engine as _engine, tiny_lm_params
 
 
 @pytest.fixture(scope="module")
 def lm_params():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
-
-
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    return tiny_lm_params()
 
 
 def _fp8_available():

@@ -10,8 +10,10 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import hlo, models, profiler
+from mxnet_tpu import hlo, profiler
 from mxnet_tpu.executor import build_graph_fn
+
+from _engines import WAIT, build, dense_engine as _engine, tiny_lm_params
 
 RECORD_KEYS = {"scope", "group", "opcodes", "scopes", "klass", "optimizer"}
 
@@ -307,28 +309,9 @@ def test_group_drops_the_layers_number_alone(scope, group):
 # ---------------------------------------------------------------------
 # (d) the engine's accessor: lazy, once, under the trace's names
 # ---------------------------------------------------------------------
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
-
-
 @pytest.fixture(scope="module")
 def lm_params():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
-
-
-def _engine(params):
-    return mx.DecodeEngine(
-        params, vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-        max_len=MAXLEN, kv_block=KVB, max_streams=4,
-        decode_buckets=[1, 2, 4], temperature=0.0)
+    return tiny_lm_params()
 
 
 def test_engine_that_nobody_asks_reads_no_text(lm_params, monkeypatch):
@@ -340,7 +323,7 @@ def test_engine_that_nobody_asks_reads_no_text(lm_params, monkeypatch):
     try:
         eng.warmup()
         out = eng.submit(np.arange(5, dtype=np.int32),
-                         max_new_tokens=4).result(timeout=120)
+                         max_new_tokens=4).result(timeout=WAIT)
         assert len(out) == 4
     finally:
         eng.close()
@@ -359,7 +342,7 @@ def test_engine_program_scopes_by_trace_names(lm_params, monkeypatch):
     monkeypatch.setattr(jax.stages.Compiled, "as_text", counted)
     try:
         eng.submit(np.arange(5, dtype=np.int32),
-                   max_new_tokens=3).result(timeout=120)
+                   max_new_tokens=3).result(timeout=WAIT)
         assert not calls
         tables = eng.program_scopes()
         # one table an executable, under the name jax gave the program
@@ -390,7 +373,7 @@ def test_closed_and_deleted_engine_is_still_nameable(lm_params):
 
     eng = _engine(lm_params)
     eng.submit(np.arange(6, dtype=np.int32),
-               max_new_tokens=2).result(timeout=120)
+               max_new_tokens=2).result(timeout=WAIT)
     names_held = len(eng._exe_cache)
     eng.close()
     del eng
@@ -422,13 +405,13 @@ def test_retention_layers_programs_carry_the_mixers_node_names():
            "head_dim": 8, "intermediate_size": 48, "vocab_size": 61,
            "rms_norm_eps": 1e-6, "rope_theta": 1e4}
     w = ref.draw(cfg, 3, embed_dtype="float32", dtype="float32")
-    eng = mx.DecodeEngine(ref.program_names(w), model=ref.spec(cfg),
-                          max_len=32, kv_block=4, max_streams=2,
-                          decode_buckets=[2], prefill_buckets=[16],
-                          temperature=0.0, ctx=mx.cpu(), dtype="float32")
+    eng = build(ref.program_names(w), model=ref.spec(cfg), max_len=32,
+                kv_block=4, max_streams=2, decode_buckets=[2],
+                prefill_buckets=[16], temperature=0.0, ctx=mx.cpu(),
+                dtype="float32")
     try:
         eng.submit(np.arange(1, 7, dtype=np.int32),
-                   max_new_tokens=3).result(timeout=300)
+                   max_new_tokens=3).result(timeout=WAIT)
         tables = eng.program_scopes()
     finally:
         eng.close()

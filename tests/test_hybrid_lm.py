@@ -23,6 +23,7 @@ from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.kv_cache import SlotAllocator  # noqa: E402
 
 from benchmark.reference import solar_open2 as ref  # noqa: E402
+from _engines import WAIT, Family, build, watch_slots  # noqa: E402
 
 # the published shape at a size a test can hold: one period (layer 0
 # gated GQA, layers 1-3 KDA), 16 query heads over 2 KV heads, 4 of 32
@@ -44,59 +45,25 @@ CFG = {
 
 # -- the engine: pages and slots against the reference's full forward ----
 
-def make_engine(seed=7, **kw):
-    drawn = ref.draw(CFG, seed, embed_dtype="float32", dtype="float32")
-    args = dict(model=ref.spec(CFG), max_len=96, kv_block=4, max_streams=3,
+FAMILY = Family(ref, CFG, pad=96, max_len=96, kv_block=4, max_streams=3,
                 decode_buckets=(1, 2, 4), cache_buckets=(8, 24),
-                prefill_buckets=(16, 32, 96), ctx=mx.cpu(),
-                dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(drawn), **args), drawn
+                prefill_buckets=(16, 32, 96))
+# the tests below that name no argument share one engine (``engines``)
+# and read its counters from ``reset_stats()`` on
+make_engine, served_gap, prompts = \
+    FAMILY.engine, FAMILY.served_gap, FAMILY.prompts
 
 
-def served_gap(drawn, prompt, out):
-    """How far below the reference's best logit the served tokens lie,
-    teacher-forced through the reference's full forward."""
-    seq = np.concatenate([prompt, out])
-    z = np.asarray(ref.forward(CFG, drawn, seq))
-    rows = z[len(prompt) - 1:len(seq) - 1]
-    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
-def prompts(rng, sizes):
-    return [rng.integers(1, CFG["vocab_size"], n).astype(np.int32)
-            for n in sizes]
-
-
-def watch_slots(eng):
-    """Record every slot's owners; fail the moment one is handed out
-    while held."""
-    alloc = eng._slot_alloc
-    held, history = {}, []
-    real_alloc, real_free = alloc.alloc, alloc.free
-
-    def a(owner=None):
-        slot = real_alloc(owner=owner)
-        assert slot not in held, f"slot {slot} given to two streams"
-        held[slot] = owner
-        history.append((slot, owner))
-        return slot
-
-    def f(slot):
-        del held[slot]
-        real_free(slot)
-
-    alloc.alloc, alloc.free = a, f
-    return held, history
-
-
-def test_engine_joins_and_retirements_match_the_reference():
-    eng, drawn = make_engine()
-    held, history = watch_slots(eng)
+def test_engine_joins_and_retirements_match_the_reference(engines):
+    eng, drawn = engines(make_engine)
     ps = prompts(np.random.default_rng(8), (9, 20, 13, 27, 6))
     news = (44, 41, 50, 43, 47)                      # >= 40 decode steps
-    with eng:
-        outs = [f.result(timeout=300) for f in
+    with watch_slots(eng) as (held, history):
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=m) for p, m in zip(ps, news)]]
         st = eng.stats()
     assert [len(o) for o in outs] == list(news)
@@ -115,12 +82,12 @@ def test_engine_joins_and_retirements_match_the_reference():
 
 def test_engine_preemption_recomputes_the_slot():
     # 17 pages for three streams that want ~13 each: someone is thrown
-    # out, its slot freed, and its re-prefill writes a slot anew
+    # out, its slot freed, and its re-prefill writes a slot anew (an
+    # engine of its own: the pool is sized for it)
     eng, drawn = make_engine(cache_blocks=34)
-    held, history = watch_slots(eng)
     ps = prompts(np.random.default_rng(9), (10, 12, 9))
-    with eng:
-        outs = [f.result(timeout=300) for f in
+    with eng, watch_slots(eng) as (held, history):
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=40) for p in ps]]
         st = eng.stats()
     assert st["preempted"] >= 1 and len(history) > 3
@@ -129,21 +96,24 @@ def test_engine_preemption_recomputes_the_slot():
     assert not held
 
 
-def test_returned_state_is_the_reference_scans_last_state():
+def test_returned_state_is_the_reference_scans_last_state(engines):
     # five streams through three slots, so slots are reused; two ask for
     # their state: what the slot holds at retirement is the scan's state
     # after prompt + every generated token but the last, never fed
-    eng, drawn = make_engine()
+    eng, drawn = engines(make_engine)
     ps = prompts(np.random.default_rng(11), (9, 21, 14, 30, 5))
-    with eng:
-        futs = [eng.submit(p, max_new_tokens=42, return_state=(i % 2 == 1))
-                for i, p in enumerate(ps)]
-        outs = [f.result(timeout=300) for f in futs]
+    futs = [eng.submit(p, max_new_tokens=42, return_state=(i % 2 == 1))
+            for i, p in enumerate(ps)]
+    outs = [f.result(timeout=WAIT) for f in futs]
     assert isinstance(outs[0], np.ndarray)
     for p, out in ((ps[1], outs[1]), (ps[3], outs[3])):
-        seq = np.concatenate([p, out["tokens"]])
+        # the reference takes a padded sequence and its length: one
+        # trace for both streams
+        n = len(p) + len(out["tokens"])
+        seq = np.zeros(96, np.int32)
+        seq[:n] = np.concatenate([p, out["tokens"]])
         assert served_gap(drawn, p, out["tokens"]) < 1e-4
-        want = ref.final_states(CFG, drawn, jnp.asarray(seq), len(seq) - 1)
+        want = ref.final_states(CFG, drawn, jnp.asarray(seq), n - 1)
         assert sorted(out["state"]) == sorted(want) == [
             "layer1_state", "layer2_state", "layer3_state"]
         for name, st in out["state"].items():
@@ -151,7 +121,7 @@ def test_returned_state_is_the_reference_scans_last_state():
                 st, np.asarray(want[name]).transpose(0, 2, 1), atol=2e-5)
             assert np.abs(st).max() > 1e-3
         # ... and not the state one token later
-        late = ref.final_states(CFG, drawn, jnp.asarray(seq), len(seq))
+        late = ref.final_states(CFG, drawn, jnp.asarray(seq), n)
         assert np.abs(np.asarray(late["layer1_state"]).transpose(0, 2, 1)
                       - out["state"]["layer1_state"]).max() > 1e-4
 
@@ -161,7 +131,7 @@ def test_return_state_needs_a_model_with_slots():
 
     cfg = {"n_layer": 1, "n_embd": 16, "n_head": 2, "vocab_size": 32,
            "n_positions": 16, "initializer_range": 0.02}
-    with mx.DecodeEngine(
+    with build(
             gpt2.program_names(gpt2.draw(cfg, 3, "float32", "float32")),
             vocab_size=32, num_layers=1, num_heads=2, d_model=16,
             max_len=16, ctx=mx.cpu(), dtype="float32") as eng:
@@ -170,14 +140,13 @@ def test_return_state_needs_a_model_with_slots():
                        return_state=True)
 
 
-def test_reset_stats_zeroes_the_routing_counters():
-    eng, _ = make_engine()
-    with eng:
-        eng.generate(prompts(np.random.default_rng(10), (8,))[0], 6)
-        assert eng.stats()["moe_pairs_here"] + \
-            eng.stats()["moe_pairs_elsewhere"] > 0
-        eng.reset_stats()
-        st = eng.stats()
+def test_reset_stats_zeroes_the_routing_counters(engines):
+    eng, _ = engines(make_engine)
+    eng.generate(prompts(np.random.default_rng(10), (8,))[0], 6)
+    assert eng.stats()["moe_pairs_here"] + \
+        eng.stats()["moe_pairs_elsewhere"] > 0
+    eng.reset_stats()
+    st = eng.stats()
     assert st["moe_pairs_here"] == st["moe_pairs_elsewhere"] == 0
     assert st["moe_experts_hit"] == st["moe_load_max"] == 0
 
@@ -198,23 +167,20 @@ def test_features_over_slots_are_refused_by_name(kw, feature):
     assert feature in str(err.value) and "kda" in str(err.value)
 
 
-def test_page_export_and_import_are_refused_by_name():
-    eng, _ = make_engine()
-    with eng:
-        with pytest.raises(MXNetError, match="page export.*kda"):
-            eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
-        with pytest.raises(MXNetError, match="page import.*kda"):
-            eng.import_stream({}, [])
+def test_page_export_and_import_are_refused_by_name(engines):
+    eng, _ = engines(make_engine)
+    with pytest.raises(MXNetError, match="page export.*kda"):
+        eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
+    with pytest.raises(MXNetError, match="page import.*kda"):
+        eng.import_stream({}, [])
 
 
 def test_spec_or_dense_keywords_not_both():
-    eng, drawn = make_engine()
-    eng.close()
     with pytest.raises(MXNetError, match="not both"):
-        mx.DecodeEngine(ref.program_names(drawn), model=ref.spec(CFG),
-                        num_heads=4, max_len=32, ctx=mx.cpu())
+        build(ref.program_names(FAMILY.draw()), model=ref.spec(CFG),
+              num_heads=4, max_len=32, ctx=mx.cpu())
     with pytest.raises(MXNetError, match="model=<spec> or all of"):
-        mx.DecodeEngine({}, vocab_size=10, ctx=mx.cpu())
+        build({}, vocab_size=10, ctx=mx.cpu())
 
 
 def test_spec_is_plain_data():
@@ -341,17 +307,17 @@ def test_a_feature_is_taken_or_refused_from_the_protocol_alone(family,
     asks, needs = FEATURES[feature]
     paged = "slots" not in spec.pool_kinds()
 
-    def build(**more):
-        return mx.DecodeEngine(params, model=spec, ctx=mx.cpu(),
-                               dtype="float32", **kw, **more)
+    def served(**more):
+        return build(params, model=spec, ctx=mx.cpu(), dtype="float32",
+                     **kw, **more)
 
     if not needs(spec, paged):
         with pytest.raises(MXNetError) as err:
-            build(**asks)
+            served(**asks)
         assert feature in str(err.value) and spec.name in str(err.value)
         return
     prompt = np.arange(1, 10, dtype=np.int32)
-    with build(**asks) as eng:
+    with served(**asks) as eng:
         out = eng.generate(prompt, 3)
         # the catalog's default (on) is taken only where it is carried
         if feature == "none":
@@ -360,8 +326,8 @@ def test_a_feature_is_taken_or_refused_from_the_protocol_alone(family,
     assert out.shape == (3,)
     if family == "stub" and feature == "none":
         dense, _, _ = seam_family("dense")
-        with mx.DecodeEngine(params, model=dense, ctx=mx.cpu(),
-                             dtype="float32", **kw) as eng:
+        with build(params, model=dense, ctx=mx.cpu(), dtype="float32",
+                   **kw) as eng:
             np.testing.assert_array_equal(out, eng.generate(prompt, 3))
 
 

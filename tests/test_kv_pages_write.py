@@ -24,6 +24,8 @@ import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu import profiler  # noqa: E402
 from mxnet_tpu.ops import attention as att, pallas_kernels as pk  # noqa: E402
 
+from _engines import WAIT, build  # noqa: E402
+
 KVB, P = 16, 24
 T = 4 * KVB
 
@@ -177,7 +179,7 @@ def _gpt2_engine():
     mx.random.seed(11)
     mod.init_params(mx.initializer.Xavier(factor_type="in", magnitude=2.0))
     arg, aux = mod.get_params()
-    return mx.DecodeEngine(
+    return build(
         {**arg, **aux}, vocab_size=V, num_layers=L, num_heads=H,
         d_model=DM, max_len=MAXLEN, kv_block=page, max_streams=2,
         decode_buckets=[1, 2], prefill_buckets=(32, 64), temperature=0.0,
@@ -201,7 +203,7 @@ def _hybrid_engine():
         "attention_initializer_range": 0.3,
     }
     drawn = ref.draw(cfg, 7, embed_dtype="float32", dtype="float32")
-    return mx.DecodeEngine(
+    return build(
         ref.program_names(drawn), model=ref.spec(cfg), max_len=96,
         kv_block=16, max_streams=2, decode_buckets=(1, 2),
         cache_buckets=(6,), prefill_buckets=(32, 64), ctx=mx.cpu(),
@@ -212,7 +214,7 @@ def _serve(make, prompts):
     eng = make()
     try:
         futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-        return [np.asarray(f.result(timeout=300)) for f in futs]
+        return [np.asarray(f.result(timeout=WAIT)) for f in futs]
     finally:
         eng.close()
 

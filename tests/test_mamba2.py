@@ -22,14 +22,13 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.kv_cache import state_pool_shape  # noqa: E402
 from mxnet_tpu.models.hybrid_lm import HybridSpec, mixer_state  # noqa: E402
 from mxnet_tpu.ops import hybrid  # noqa: E402
-from mxnet_tpu.ops.registry import OpContext, get_op  # noqa: E402
 
 from benchmark.reference import granitemoehybrid as ref  # noqa: E402
+from _engines import WAIT, Family, run_op  # noqa: E402
 
 # the published shape at a size a test can hold: the first five entries
 # of the layer pattern (attention second, so that mamba layers lie on
@@ -52,20 +51,6 @@ CFG = {
     "rms_norm_eps": 1e-5, "tie_word_embeddings": True,
     "initializer_range": 0.02,
 }
-
-
-@pytest.fixture(params=[False, True], ids=["lax", "pallas"])
-def kernels(request, monkeypatch):
-    """Both bodies of every op: the lax fallback and the Pallas kernels
-    (interpreted on the CPU)."""
-    monkeypatch.setenv("MXNET_PALLAS", "1" if request.param else "0")
-    return request.param
-
-
-def run_op(name, inputs, **attrs):
-    attrs = {k: str(v) for k, v in attrs.items()}
-    return get_op(name).compute(OpContext(is_train=False, rng=None), attrs,
-                                [jnp.asarray(x) for x in inputs], [])
 
 
 # -- Mamba2Chunk = Mamba2Step token by token = the recurrence as written --
@@ -284,13 +269,13 @@ def test_solar_symbols_are_what_they_were():
                    "decode": "e2ddb52657a71e28"}
 
 
-def make_engine(seed=7, **kw):
-    drawn = ref.draw(CFG, seed, embed_dtype="float32", dtype="float32")
-    args = dict(model=ref.spec(CFG), max_len=96, kv_block=4, max_streams=3,
+FAMILY = Family(ref, CFG, pad=96, max_len=96, kv_block=4, max_streams=3,
                 decode_buckets=(1, 2, 4), cache_buckets=(8, 24),
-                prefill_buckets=(16, 32, 96), ctx=mx.cpu(), dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(drawn), **args), drawn
+                prefill_buckets=(16, 32, 96))
+
+
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
 def test_engine_prompt_then_decode_is_the_references_full_forward(kernels):
@@ -298,29 +283,29 @@ def test_engine_prompt_then_decode_is_the_references_full_forward(kernels):
     state: served tokens are the reference's best at every position
     (logits, teacher-forced through its full forward), and a slot at
     retirement holds the reference scan's last state."""
-    eng, drawn = make_engine()
-    rng = np.random.default_rng(8)
-    ps = [rng.integers(1, CFG["vocab_size"], n).astype(np.int32)
-          for n in (9, 20, 13, 27, 6)]
+    # an engine a body: its programs are the body's
+    eng, drawn = FAMILY.engine()
+    ps = FAMILY.prompts(np.random.default_rng(8), (9, 20, 13, 27, 6))
     with eng:
         futs = [eng.submit(p, max_new_tokens=24, return_state=(i % 2 == 1))
                 for i, p in enumerate(ps)]
-        outs = [f.result(timeout=600) for f in futs]
+        outs = [f.result(timeout=WAIT) for f in futs]
         st = eng.stats()
     assert st["state_slots"] == 3 and st["state_slots_live"] == 0
     assert st["moe_pairs_here"] + st["moe_pairs_elsewhere"] == \
         st["stream_steps"] * CFG["num_experts_per_tok"] * 4
     for i, (p, out) in enumerate(zip(ps, outs)):
         tokens = out["tokens"] if i % 2 else out
-        seq = np.concatenate([p, tokens])
-        z = np.asarray(ref.forward(CFG, drawn, seq))
-        rows = z[len(p) - 1:len(seq) - 1]
+        n = len(p) + len(tokens)
+        seq = np.zeros(96, np.int32)    # padded: one trace for all five
+        seq[:n] = np.concatenate([p, tokens])
+        rows = FAMILY.logits(drawn, seq[:n])[len(p) - 1:-1]
         assert rows.max(-1).mean() - rows.mean() > 0.03   # logits spread
         gap = rows.max(-1) - rows[np.arange(len(tokens)), tokens]
         assert gap.max() < 1e-4
         if not i % 2:
             continue
-        want = ref.final_states(CFG, drawn, jnp.asarray(seq), len(seq) - 1)
+        want = ref.final_states(CFG, drawn, jnp.asarray(seq), n - 1)
         assert sorted(out["state"]) == sorted(want) == [
             "layer0_state", "layer2_state", "layer3_state"]
         for name, got in out["state"].items():

@@ -105,7 +105,7 @@ np.save(sys.argv[1], out.asnumpy())
                        PYTHONPATH=REPO)
             r = subprocess.run([sys.executable, "-c", script % REPO, path],
                                capture_output=True, text=True, env=env,
-                               timeout=300)
+                               timeout=120)
             assert r.returncode == 0, r.stderr
             outs.append(np.load(path))
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)
@@ -184,7 +184,7 @@ np.save(sys.argv[1], out.asnumpy())
                        PYTHONPATH=REPO)
             r = subprocess.run([sys.executable, "-c", script % REPO, path],
                                capture_output=True, text=True, env=env,
-                               timeout=300)
+                               timeout=120)
             assert r.returncode == 0, r.stderr
             outs.append(np.load(path))
         np.testing.assert_allclose(outs[0], outs[1], rtol=1e-5, atol=1e-6)

@@ -109,7 +109,7 @@ print('standalone artifact OK')
 """
     env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH="")
     r = subprocess.run([sys.executable, "-c", script], env=env,
-                       capture_output=True, text=True, timeout=300)
+                       capture_output=True, text=True, timeout=120)
     assert r.returncode == 0, r.stderr
     assert "standalone artifact OK" in r.stdout
 

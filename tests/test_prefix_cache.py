@@ -13,15 +13,14 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import models
-from mxnet_tpu.executor import build_graph_fn
 from mxnet_tpu.kv_cache import (BlockAllocator, blocks_for_tokens,
                                 bucket_ladder, kv_storage_dtype,
                                 value_pool_shape)
-from mxnet_tpu.models.transformer import transformer_lm_prefill
 from mxnet_tpu.prefix_cache import PrefixCache, PrefixIndex
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import (KVB, WAIT, V,  # noqa: E402
+                      dense_engine as _engine, tiny_lm_params,
+                      tiny_lm_reference)
 
 
 # ---------------------------------------------------------------------------
@@ -223,54 +222,11 @@ def test_needs_cow_semantics():
 
 @pytest.fixture(scope="module")
 def lm():
-    import jax
-    import jax.numpy as jnp
-
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    params = {**arg, **aux}
-
-    ps = transformer_lm_prefill(V, num_layers=L, num_heads=H,
-                                d_model=DM, kv_block=KVB, paged=False)
-    gfn = build_graph_fn(ps)
-    base = {n: jnp.asarray(params[n].asnumpy())
-            for n in ps.list_arguments() if n in params}
-    key = jax.random.PRNGKey(0)
-
-    def full_logits(seq):
-        T = len(seq)
-        a = dict(base)
-        a.update(data=jnp.asarray(np.asarray(seq, np.int32)[None]),
-                 positions=jnp.asarray(
-                     np.arange(T, dtype=np.int32)[None]),
-                 lengths=jnp.asarray(np.asarray([T], np.int32)))
-        outs, _ = gfn(a, {}, key, False)
-        return np.asarray(outs[0][0])
-
-    def naive_generate(prompt, n):
-        seq = list(np.asarray(prompt))
-        out = []
-        for _ in range(n):
-            out.append(int(np.argmax(full_logits(seq)[-1])))
-            seq.append(out[-1])
-        return np.asarray(out, np.int32)
-
-    return params, naive_generate
-
-
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    """-> (params, naive_generate).  Every engine below is its test's
+    own: what the prefix cache holds from one request to the next is the
+    state under test."""
+    params = tiny_lm_params()
+    return params, tiny_lm_reference(params)[1]
 
 
 def test_engine_smoke_hit_attach_diverge_evict(lm):
@@ -348,7 +304,7 @@ def test_engine_cow_isolation_diverging_streams(lm):
         eng.generate(shared, 2)  # seed the cache (greedy, retires)
         f1 = eng.submit(shared, 6, temperature=0.8, seed=7)
         f2 = eng.submit(shared, 6, temperature=0.8, seed=8)
-        g1, g2 = f1.result(120), f2.result(120)
+        g1, g2 = f1.result(WAIT), f2.result(WAIT)
         st = eng.stats()
     np.testing.assert_array_equal(g1, solo[7])
     np.testing.assert_array_equal(g2, solo[8])
@@ -369,7 +325,7 @@ def test_engine_preemption_frees_only_private_refs(lm):
     with _engine(params, cache_blocks=9, max_streams=2) as eng:
         f1 = eng.submit(pa, 10)
         f2 = eng.submit(pb, 10)
-        g1, g2 = f1.result(120), f2.result(120)
+        g1, g2 = f1.result(WAIT), f2.result(WAIT)
         st = eng.stats()
     np.testing.assert_array_equal(g1, naive(pa, 10))
     np.testing.assert_array_equal(g2, naive(pb, 10))
@@ -559,7 +515,7 @@ def test_engine_long_shared_prefix_sweep(lm):
                 1, V, size=rng.randint(6, 14)).astype(np.int32))
     with _engine(params, cache_blocks=25) as eng:
         futs = [(p, eng.submit(p, 5)) for p in reqs]
-        outs = [(p, f.result(240)) for p, f in futs]
+        outs = [(p, f.result(WAIT)) for p, f in futs]
         st = eng.stats()
     for p, got in outs:
         np.testing.assert_array_equal(got, naive(p, 5))

@@ -11,8 +11,9 @@ validation; the full (tp, pp) x feature matrix is ``slow``.
 import numpy as np
 import pytest
 
-import mxnet_tpu as mx
 from mxnet_tpu.base import MXNetError
+
+from _engines import WAIT, build
 
 V, KVB, L, H, DM, DFF, MAXLEN = 61, 4, 2, 2, 32, 128, 32
 
@@ -65,7 +66,7 @@ def _engine(params, **kw):
                 decode_buckets=[1, 2], temperature=0.8, seed=7,
                 prefix_cache=0, spec_tokens=0, prefill_chunk=0)
     args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    return build(params, **args)
 
 
 _PROMPTS = [np.array([3, 7, 1, 9, 2], np.int32),
@@ -74,7 +75,7 @@ _PROMPTS = [np.array([3, 7, 1, 9, 2], np.int32),
 
 def _generate_all(eng, prompts=_PROMPTS, n=5):
     futs = [eng.submit(p, n, seed=i) for i, p in enumerate(prompts)]
-    return [np.asarray(f.result(timeout=300)) for f in futs]
+    return [np.asarray(f.result(timeout=WAIT)) for f in futs]
 
 
 @pytest.fixture(scope="module")
@@ -233,11 +234,11 @@ def test_mesh_matrix_bit_identical(lm_params, tp, pp, feature):
     with _engine(lm_params, **kw) as ref:
         expect = _generate_all(ref)
         expect += [np.asarray(
-            ref.submit(_PROMPTS[0], 5, seed=0).result(timeout=300))]
+            ref.submit(_PROMPTS[0], 5, seed=0).result(timeout=WAIT))]
     with _engine(lm_params, tp=tp, pp=pp, **kw) as eng:
         got = _generate_all(eng)
         got += [np.asarray(
-            eng.submit(_PROMPTS[0], 5, seed=0).result(timeout=300))]
+            eng.submit(_PROMPTS[0], 5, seed=0).result(timeout=WAIT))]
     for a, b in zip(expect, got):
         np.testing.assert_array_equal(a, b)
 
@@ -255,10 +256,10 @@ def test_mesh_preemption_bit_identical(lm_params):
               temperature=0.0)
     with _engine(lm_params, **kw) as ref:
         futs = [ref.submit(p, 14) for p in prompts]
-        expect = [np.asarray(f.result(timeout=300)) for f in futs]
+        expect = [np.asarray(f.result(timeout=WAIT)) for f in futs]
     with _engine(lm_params, tp=2, **kw) as eng:
         futs = [eng.submit(p, 14) for p in prompts]
-        got = [np.asarray(f.result(timeout=300)) for f in futs]
+        got = [np.asarray(f.result(timeout=WAIT)) for f in futs]
         st = eng.stats()
     assert st["preempted"] > 0
     for a, b in zip(expect, got):

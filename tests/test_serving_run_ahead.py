@@ -6,8 +6,12 @@ served are the synchronous loop's, request for request.
 The synchronous loop is the same engine with ``_hold_back`` patched to
 hold every batch back: it drains before each program and fetches each
 program's tokens at once.  Tiny engines on the CPU: the dense block,
-the hybrid family with slots, the family's windowed spec.
+the hybrid family with slots, the family's windowed spec.  Tests that
+want the same arguments share one engine (``engines``, counters from
+``reset_stats()`` on); one that counts what is built, closes or breaks
+its engine, or sizes its pool builds its own and says so.
 """
+import functools
 import os
 import sys
 import time
@@ -21,42 +25,33 @@ for path in (ROOT, os.path.join(ROOT, "tests")):
     if path not in sys.path:
         sys.path.insert(0, path)
 
-import mxnet_tpu as mx  # noqa: E402
-from mxnet_tpu import models  # noqa: E402
 from mxnet_tpu.serving import EngineClosedError  # noqa: E402
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 64
+from _engines import WAIT, V, dense_engine, tiny_lm_params  # noqa: E402
+
+MAXLEN = 64
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @pytest.fixture(scope="module")
 def lm_params():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mx.random.seed(0)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
+    return tiny_lm_params(MAXLEN, seed=0)
 
 
-def dense(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0, seed=3,
-                prefix_cache=0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+dense = functools.partial(dense_engine, max_len=MAXLEN, seed=3,
+                          prefix_cache=0)
 
 
-def synchronous(eng):
-    """The same engine, never ahead of a token it has not read."""
-    eng._hold_back = lambda streams: "off"
-    return eng
+@pytest.fixture
+def synchronous(monkeypatch):
+    """-> the same engine, never ahead of a token it has not read (until
+    the test ends), its counters from here on."""
+    def hold(eng):
+        monkeypatch.setattr(eng, "_hold_back", lambda streams: "off")
+        eng.reset_stats()
+        return eng
+
+    return hold
 
 
 def prompts(seed, sizes, vocab=V):
@@ -74,24 +69,22 @@ def wait_for_steps(eng, n):
 
 def serve(eng, ps, news, **kw):
     """-> (the served result of each request, the engine's stats)."""
-    with eng:
-        futs = [eng.submit(p, max_new_tokens=m, seed=11 + i, **kw)
-                for i, (p, m) in enumerate(zip(ps, news))]
-        outs = [f.result(timeout=600) for f in futs]
-        st = eng.stats()
-    return outs, st
+    futs = [eng.submit(p, max_new_tokens=m, seed=11 + i, **kw)
+            for i, (p, m) in enumerate(zip(ps, news))]
+    outs = [f.result(timeout=WAIT) for f in futs]
+    return outs, eng.stats()
 
 
 def hybrid_engine(**kw):
     import test_hybrid_lm as th
 
-    return th.make_engine(**kw)[0]
+    return th.FAMILY.engine(**kw)[0]
 
 
 def windowed_engine(**kw):
     import test_smallthinker as ts
 
-    return ts.make_engine(**kw)[0]
+    return ts.FAMILY.engine(**kw)[0]
 
 
 SIZES, NEWS = (9, 20, 13, 27, 6, 11), (24, 21, 30, 23, 27, 5)
@@ -106,12 +99,14 @@ SIZES, NEWS = (9, 20, 13, 27, 6, 11), (24, 21, 30, 23, 27, 5)
     ("slots", {"temperature": 0.7}),
     ("windowed", {}),
 ])
-def test_served_tokens_equal_the_synchronous_loops(lm_params, family, kw):
-    make = {"dense": lambda: dense(lm_params),
-            "slots": hybrid_engine, "windowed": windowed_engine}[family]
+def test_served_tokens_equal_the_synchronous_loops(
+        engines, synchronous, lm_params, family, kw):
+    eng = {"dense": lambda: engines(dense, lm_params),
+           "slots": lambda: engines(hybrid_engine),
+           "windowed": lambda: engines(windowed_engine)}[family]()
     ps = prompts(8, SIZES, vocab=V if family == "dense" else 96)
-    ahead, st = serve(make(), ps, NEWS, **kw)
-    plain, st0 = serve(synchronous(make()), ps, NEWS, **kw)
+    ahead, st = serve(eng, ps, NEWS, **kw)
+    plain, st0 = serve(synchronous(eng), ps, NEWS, **kw)
     for a, b in zip(ahead, plain):
         np.testing.assert_array_equal(a, b)
     assert [len(a) for a in ahead] == list(NEWS)
@@ -127,8 +122,9 @@ def test_served_tokens_equal_the_synchronous_loops(lm_params, family, kw):
     assert st["overshoot_row_steps"] == 0
 
 
-def test_a_stream_ending_by_count_is_not_in_the_step_in_flight(lm_params):
-    eng = dense(lm_params)
+def test_a_stream_ending_by_count_is_not_in_the_step_in_flight(
+        engines, monkeypatch, lm_params):
+    eng = engines(dense, lm_params)
     booked = []
     real = eng._book_step
 
@@ -137,7 +133,7 @@ def test_a_stream_ending_by_count_is_not_in_the_step_in_flight(lm_params):
                       for s in rec.streams)
         return real(rec, toks, t_done)
 
-    eng._book_step = spy
+    monkeypatch.setattr(eng, "_book_step", spy)
     _, st = serve(eng, prompts(2, SIZES), NEWS)
     # no row was run for a stream that already had all its tokens: a
     # request of n tokens rides n - 1 steps (its prefill samples one)
@@ -157,49 +153,53 @@ def first_seen_at(tokens, lo=2):
     raise AssertionError(f"no fresh token in {tokens}")
 
 
-def test_an_eos_stream_overshoots_once_and_the_token_is_dropped(lm_params):
+def test_an_eos_stream_overshoots_once_and_the_token_is_dropped(
+        engines, synchronous, lm_params):
     ps, n = prompts(5, (7, 12, 9)), 20
     kw = dict(temperature=1.0)
-    free, _ = serve(dense(lm_params), ps, (n,) * 3, **kw)
+    eng = engines(dense, lm_params)
+    free, _ = serve(eng, ps, (n,) * 3, **kw)
     cut = [first_seen_at(list(o)) for o in free]
-    with dense(lm_params) as eng:
-        futs = [eng.submit(p, max_new_tokens=n, seed=11 + i,
-                           eos_id=int(o[k]), **kw)
-                for i, (p, o, k) in enumerate(zip(ps, free, cut))]
-        outs = [f.result(timeout=600) for f in futs]
-        time.sleep(0.05)
-        st = eng.stats()
-        # the overshoot's pages went back with the rest
-        assert eng._alloc.used_blocks == 0
+    eng.reset_stats()
+    futs = [eng.submit(p, max_new_tokens=n, seed=11 + i,
+                       eos_id=int(o[k]), **kw)
+            for i, (p, o, k) in enumerate(zip(ps, free, cut))]
+    outs = [f.result(timeout=WAIT) for f in futs]
+    time.sleep(0.05)
+    st = eng.stats()
+    # the overshoot's pages went back with the rest
+    assert eng._alloc.used_blocks == 0
     for o, ref, k in zip(outs, free, cut):
         np.testing.assert_array_equal(o, ref[:k + 1])  # eos included
     # each stream read its eos with ONE later row already dispatched
     assert st["overshoot_row_steps"] == len(ps)
     assert st["tokens"] == sum(k + 1 for k in cut)
     assert st["stream_steps"] == sum(cut) + len(ps)
-    same, st0 = serve(synchronous(dense(lm_params)), ps, (n,) * 3,
+    same, st0 = serve(synchronous(eng), ps, (n,) * 3,
                       eos_id=int(free[0][cut[0]]), **kw)
     np.testing.assert_array_equal(same[0], outs[0])
     assert st0["overshoot_row_steps"] == 0
 
 
-def test_return_state_with_eos_is_never_run_ahead_of_its_check():
+def test_return_state_with_eos_is_never_run_ahead_of_its_check(
+        engines, synchronous):
     ps = prompts(11, (9, 14), vocab=96)
-    free, _ = serve(hybrid_engine(), ps[:1], (18,), temperature=0.8)
+    eng = engines(hybrid_engine)
+    free, _ = serve(eng, ps[:1], (18,), temperature=0.8)
     k = first_seen_at(list(free[0]))
     kw = dict(temperature=0.8, eos_id=int(free[0][k]), return_state=True)
 
     def run(eng):
-        with eng:
-            other = eng.submit(ps[1], max_new_tokens=70, seed=5)
-            wait_for_steps(eng, 2)  # it is running ahead, alone
-            out = eng.submit(ps[0], max_new_tokens=18, seed=11,
-                             **kw).result(timeout=600)
-            other.result(timeout=600)
-            return out, eng.stats()
+        eng.reset_stats()
+        other = eng.submit(ps[1], max_new_tokens=70, seed=5)
+        wait_for_steps(eng, 2)  # it is running ahead, alone
+        out = eng.submit(ps[0], max_new_tokens=18, seed=11,
+                         **kw).result(timeout=WAIT)
+        other.result(timeout=WAIT)
+        return out, eng.stats()
 
-    out, st = run(hybrid_engine())
-    want, _ = run(synchronous(hybrid_engine()))
+    out, st = run(eng)
+    want, _ = run(synchronous(eng))
     np.testing.assert_array_equal(out["tokens"], free[0][:k + 1])
     np.testing.assert_array_equal(out["tokens"], want["tokens"])
     assert st["overshoot_row_steps"] == 0
@@ -213,51 +213,54 @@ def test_return_state_with_eos_is_never_run_ahead_of_its_check():
 
 # -- drains: what needs the delivery state whole, counted by reason ------
 
-def test_a_drain_comes_before_a_preemption(lm_params):
+def test_a_drain_comes_before_a_preemption(synchronous, lm_params):
     ps = [np.arange(1, 6, dtype=np.int32), np.arange(7, 12, dtype=np.int32),
           np.arange(13, 18, dtype=np.int32)]
-    short = dict(max_streams=3, cache_blocks=10)
-    outs, st = serve(dense(lm_params, **short), ps, (14,) * 3)
-    want, st0 = serve(synchronous(dense(lm_params, **short)), ps, (14,) * 3)
+    # an engine of its own: the pool is sized for the preemption
+    eng = dense(lm_params, max_streams=3, cache_blocks=10)
+    outs, st = serve(eng, ps, (14,) * 3)
+    want, st0 = serve(synchronous(eng), ps, (14,) * 3)
     assert st["preempted"] > 0 and st0["preempted"] > 0
     assert st["run_ahead_drain_reasons"]["preempt"] >= 1
     for a, b in zip(outs, want):
         np.testing.assert_array_equal(a, b)
 
 
-def test_a_drain_comes_before_a_verify_window(lm_params):
+def test_a_drain_comes_before_a_verify_window(engines, lm_params):
     rng = np.random.RandomState(0)
     motif = rng.randint(1, V, size=5).astype(np.int32)
     prompt = np.tile(motif, 4)[:18]
-    (want,), _ = serve(dense(lm_params), [prompt], (12,))
+    (want,), _ = serve(engines(dense, lm_params), [prompt], (12,))
+    # (the only engine with a verify program)
     (got,), st = serve(dense(lm_params, spec_tokens=3), [prompt], (12,))
     np.testing.assert_array_equal(got, want)
     assert st["spec_steps"] > 0
     assert st["run_ahead_drain_reasons"]["verify"] >= 1
 
 
-def test_a_drain_comes_before_a_chunk(lm_params):
+def test_a_drain_comes_before_a_chunk(engines, synchronous, lm_params):
     ps = prompts(4, (6, 30))
+    # (the only engine that chunks its prompts)
     with dense(lm_params, prefill_chunk=8) as eng:
         first = eng.submit(ps[0], max_new_tokens=50, seed=1)
         wait_for_steps(eng, 2)  # a step in flight when the long one comes
         second = eng.submit(ps[1], max_new_tokens=6, seed=2)
-        got = [first.result(timeout=600), second.result(timeout=600)]
+        got = [first.result(timeout=WAIT), second.result(timeout=WAIT)]
         st = eng.stats()
     assert st["prefill_chunks"] == 4
     assert st["run_ahead_drain_reasons"]["chunk"] >= 1
-    with synchronous(dense(lm_params)) as eng:
-        want = [eng.generate(ps[0], 50, seed=1),
-                eng.generate(ps[1], 6, seed=2)]
+    eng = synchronous(engines(dense, lm_params))
+    want = [eng.generate(ps[0], 50, seed=1),
+            eng.generate(ps[1], 6, seed=2)]
     for a, b in zip(got, want):
         np.testing.assert_array_equal(a, b)
 
 
-def test_a_drain_comes_before_swap_params_reset_stats_and_close(lm_params):
+def test_a_drain_comes_before_swap_params_reset_stats_and_close(
+        engines, lm_params):
     p = prompts(6, (5,))[0]
-    with dense(lm_params) as eng:
-        want = eng.generate(p, 50, seed=9)
-    eng = dense(lm_params)
+    want = engines(dense, lm_params).generate(p, 50, seed=9)
+    eng = dense(lm_params)      # its own: swapped into, and closed
     fut = eng.submit(p, max_new_tokens=50, seed=9)
     wait_for_steps(eng, 2)
     eng.swap_params(lm_params)  # the same weights: the tokens stay
@@ -267,7 +270,7 @@ def test_a_drain_comes_before_swap_params_reset_stats_and_close(lm_params):
     st = eng.stats()
     assert st["run_ahead_drains"] == 0 and st["steps_run_ahead"] == 0 \
         and st["run_ahead_drain_reasons"] == {}
-    np.testing.assert_array_equal(fut.result(timeout=600), want)
+    np.testing.assert_array_equal(fut.result(timeout=WAIT), want)
     # close: what is in flight is booked (and counted) before the rest
     # of the stream fails
     fut = eng.submit(p, max_new_tokens=50, seed=9)
@@ -279,7 +282,7 @@ def test_a_drain_comes_before_swap_params_reset_stats_and_close(lm_params):
 
 
 def test_a_device_error_at_the_fetch_fails_every_stream_in_flight(lm_params):
-    eng = dense(lm_params)
+    eng = dense(lm_params)      # its own: the error kills it
     real = eng._read
 
     def read(toks):
@@ -316,13 +319,13 @@ def test_warmup_builds_the_feed_program_and_nothing_is_built_after(
         if event == COMPILE_EVENT:
             built.append(event)
 
-    eng = dense(lm_params)
+    eng = dense(lm_params)      # its own: what it builds is counted
     eng.warmup()
     assert {("feed", bb) for bb in (1, 2, 4)} <= set(eng.compiles)
     # one turn of everything (pools written, tokens read), then listen
     serve_open = [eng.submit(p, max_new_tokens=6)
                   for p in prompts(1, (5, 9))]
-    [f.result(timeout=600) for f in serve_open]
+    [f.result(timeout=WAIT) for f in serve_open]
     before = dict(eng.compiles)
     jax.monitoring.register_event_duration_secs_listener(on_duration)
     try:
@@ -336,7 +339,7 @@ def test_warmup_builds_the_feed_program_and_nothing_is_built_after(
 
 def test_a_steady_batch_runs_ahead_nearly_always(lm_params):
     ps = prompts(9, (6, 9, 7, 8))
-    eng = dense(lm_params, decode_buckets=[4])
+    eng = dense(lm_params, decode_buckets=[4])      # the one bucket
     eng.warmup()
     outs, st = serve(eng, ps, (50,) * 4)
     assert st["run_ahead_share"] >= 0.9
@@ -347,16 +350,16 @@ def test_a_steady_batch_runs_ahead_nearly_always(lm_params):
     assert st["decode_buckets"] == [4] and all(len(o) == 50 for o in outs)
 
 
-def test_slots_held_by_streams_in_flight_hold_admission():
+def test_slots_held_by_streams_in_flight_hold_admission(engines):
     """Five streams through three slots, requests queued: a stream
     whose last token is in flight keeps its slot until it is fetched,
     and the next one waits for it (no slot is handed out twice)."""
     import test_hybrid_lm as th
 
-    eng, drawn = th.make_engine()
-    held, history = th.watch_slots(eng)
+    eng, drawn = engines(hybrid_engine), th.FAMILY.draw()
     ps = prompts(8, (9, 20, 13, 27, 6), vocab=96)
-    outs, st = serve(eng, ps, (12, 9, 14, 8, 11))
+    with th.watch_slots(eng) as (held, history):
+        outs, st = serve(eng, ps, (12, 9, 14, 8, 11))
     assert len(history) == 5 and not held
     assert st["state_slots_live"] == 0 and st["steps_run_ahead"] > 0
     for p, o in zip(ps, outs):

@@ -11,10 +11,10 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import chaos, models, profiler, slo
+from mxnet_tpu import chaos, profiler, slo
 from mxnet_tpu.elastic import dead_rank_timeout
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import dense_engine as _engine, tiny_lm_params
 
 
 def _cfg(**kw):
@@ -232,24 +232,7 @@ def test_canary_prober_rejects_zero_interval():
 
 @pytest.fixture(scope="module")
 def lm():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
-
-
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+    return tiny_lm_params()
 
 
 def test_cost_records_conserve_engine_counters(lm):

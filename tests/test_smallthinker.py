@@ -24,12 +24,12 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-import mxnet_tpu as mx  # noqa: E402
 from mxnet_tpu.base import MXNetError  # noqa: E402
 from mxnet_tpu.executor import build_graph_fn  # noqa: E402
 from mxnet_tpu.models.hybrid_lm import HybridSpec  # noqa: E402
 
 from benchmark.reference import smallthinker as ref  # noqa: E402
+from _engines import WAIT, Family  # noqa: E402
 
 # the published shape at a size a test can hold: two periods of the
 # [global, windowed x 3] pattern, 4 query heads over 2 KV heads, 8
@@ -74,6 +74,11 @@ class Programs:
                                static_argnums=(3,))
                    for ph in ("prefill", "decode")}
         self.key = jax.random.PRNGKey(0)
+
+    def fresh(self):
+        """The same programs over pools nobody has written."""
+        self.pools = [jnp.zeros_like(p) for p in self.pools]
+        return self
 
     def tables(self, length):
         """(block table, window table) for a stream about to be fed the
@@ -133,16 +138,17 @@ CASES = [(20, 90), (75, 110)]
 def served():
     """The program's logits and the reference's, for each case."""
     drawn = draw()
+    progs = Programs(drawn)      # one build for the cases
     out = []
     for i, (n_prompt, total) in enumerate(CASES):
         seq = sequence(20 + i, total)
-        got = Programs(drawn).serve(seq, n_prompt, bucket=96)
+        got = progs.fresh().serve(seq, n_prompt, bucket=96)
         out.append((seq, n_prompt, got))
     return drawn, out
 
 
 def reference_rows(drawn, seq, n_prompt, precision="float32"):
-    return np.asarray(ref.forward(CFG, drawn, seq, precision))[n_prompt - 1:]
+    return FAMILY.logits(drawn, seq, precision)[n_prompt - 1:]
 
 
 @pytest.mark.parametrize("case", range(len(CASES)))
@@ -193,23 +199,16 @@ def test_kernels_interpreted_match_the_lax_bodies(served, monkeypatch):
 
 # -- the engine: two allocators -----------------------------------------
 
-def make_engine(drawn=None, **kw):
-    drawn = drawn or draw()
-    args = dict(model=ref.spec(CFG), max_len=352, kv_block=KVB,
+FAMILY = Family(ref, CFG, pad=352, max_len=352, kv_block=KVB,
                 max_streams=3, decode_buckets=(1, 2, 4),
-                cache_buckets=(8, 22), prefill_buckets=(32, 96),
-                ctx=mx.cpu(), dtype="float32")
-    args.update(kw)
-    return mx.DecodeEngine(ref.program_names(drawn), **args), drawn
+                cache_buckets=(8, 22), prefill_buckets=(32, 96))
+# the tests that name no argument share one engine (``engines``) and read
+# its counters from ``reset_stats()`` on
+make_engine, served_gap = FAMILY.engine, FAMILY.served_gap
 
 
-def served_gap(drawn, prompt, out):
-    """How far below the reference's best logit the served tokens lie,
-    teacher-forced through the reference's full forward."""
-    seq = np.concatenate([prompt, out])
-    z = np.asarray(ref.forward(CFG, drawn, seq))
-    rows = z[len(prompt) - 1:len(seq) - 1]
-    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
 
 
 def small_tiles(monkeypatch):
@@ -238,12 +237,13 @@ def test_prompt_kernels_given_the_length_leave_the_logits(monkeypatch,
 @pytest.mark.parametrize("lengths", [(20, 96)], ids=["both"])
 def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
         monkeypatch, lengths):
+    # (an engine of its own: its programs are the interpreted kernels')
     small_tiles(monkeypatch)
     eng, drawn = make_engine(prefill_buckets=(96,))
     rng = np.random.default_rng(6)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in lengths]
     with eng:
-        outs = [f.result(timeout=600) for f in
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=6) for p in ps]]
         st = eng.stats()
     for p, o in zip(ps, outs):
@@ -266,7 +266,7 @@ def test_the_engine_counts_the_tiles_its_prompt_kernels_walk_and_skip(
     assert st["prefill_scores_computed_over_needed"] > 1.0
 
 
-def watch_window_pages(eng):
+def watch_window_pages(eng, monkeypatch):
     """Record the most windowed pages any one owner held, and fail the
     moment a page is handed out while held."""
     alloc = eng._walloc
@@ -287,24 +287,25 @@ def watch_window_pages(eng):
             del held[p]
         real_free(pages)
 
-    alloc.alloc, alloc.free = a, f
+    monkeypatch.setattr(alloc, "alloc", a)
+    monkeypatch.setattr(alloc, "free", f)
     return held, most
 
 
-def test_a_long_stream_holds_a_windows_pages_and_gives_the_rest_back():
-    eng, drawn = make_engine()
-    held, most = watch_window_pages(eng)
+def test_a_long_stream_holds_a_windows_pages_and_gives_the_rest_back(
+        engines, monkeypatch):
+    eng, drawn = engines(make_engine)
+    held, most = watch_window_pages(eng, monkeypatch)
     rng = np.random.default_rng(3)
     long_prompt = rng.integers(1, 96, 12).astype(np.int32)
-    with eng:
-        # 10 x W tokens: 20 pages of context through W / KVB + 2 = 4
-        first = eng.submit(long_prompt, max_new_tokens=10 * W - 12)
-        others = [eng.submit(rng.integers(1, 96, n).astype(np.int32),
-                             max_new_tokens=m)
-                  for n, m in ((40, 70), (9, 60), (80, 50), (25, 90))]
-        out = first.result(timeout=600)
-        outs = [f.result(timeout=600) for f in others]
-        st = eng.stats()
+    # 10 x W tokens: 20 pages of context through W / KVB + 2 = 4
+    first = eng.submit(long_prompt, max_new_tokens=10 * W - 12)
+    others = [eng.submit(rng.integers(1, 96, n).astype(np.int32),
+                         max_new_tokens=m)
+              for n, m in ((40, 70), (9, 60), (80, 50), (25, 90))]
+    out = first.result(timeout=WAIT)
+    outs = [f.result(timeout=WAIT) for f in others]
+    st = eng.stats()
     assert max(most.values()) <= W // KVB + 2
     assert st["window_pages"] == 3 * (W // KVB + 2)
     # pages given back were taken again: five streams, 12 pages
@@ -319,16 +320,17 @@ def test_a_long_stream_holds_a_windows_pages_and_gives_the_rest_back():
     assert len(out) == 10 * W - 12 and all(len(o) for o in outs)
 
 
-def test_preemption_and_retirement_leave_both_pools_empty():
+def test_preemption_and_retirement_leave_both_pools_empty(monkeypatch):
     # 13 ordinary pages for three streams that grow to 6 each: someone
     # is thrown out, gives back its pages of both pools, and comes back
+    # (an engine of its own: the pool is sized for it)
     eng, drawn = make_engine(cache_blocks=14, max_len=96,
                              cache_buckets=(6,))
-    held, _ = watch_window_pages(eng)
+    held, _ = watch_window_pages(eng, monkeypatch)
     rng = np.random.default_rng(5)
     ps = [rng.integers(1, 96, n).astype(np.int32) for n in (30, 41, 36)]
     with eng:
-        outs = [f.result(timeout=600) for f in
+        outs = [f.result(timeout=WAIT) for f in
                 [eng.submit(p, max_new_tokens=50) for p in ps]]
         st = eng.stats()
     assert st["preempted"] >= 1
@@ -339,19 +341,18 @@ def test_preemption_and_retirement_leave_both_pools_empty():
 
 
 @pytest.mark.parametrize("short", ["pages", "window_pages"])
-def test_admission_waits_when_either_pool_is_short(short):
-    eng, _ = make_engine()
+def test_admission_waits_when_either_pool_is_short(engines, short):
+    eng, _ = engines(make_engine)
     alloc = eng._alloc if short == "pages" else eng._walloc
-    with eng:
-        taken = alloc.alloc(alloc.free_blocks - 1, owner="test")
-        fut = eng.submit(np.arange(1, 41, dtype=np.int32), 4)
-        with pytest.raises(Exception):
-            fut.result(timeout=1.0)          # held in the queue
-        assert eng.stats()["pending"] == 1
-        alloc.free(taken)
-        with eng._cond:
-            eng._cond.notify_all()
-        assert len(fut.result(timeout=300)) == 4
+    taken = alloc.alloc(alloc.free_blocks - 1, owner="test")
+    fut = eng.submit(np.arange(1, 41, dtype=np.int32), 4)
+    with pytest.raises(Exception):
+        fut.result(timeout=1.0)          # held in the queue
+    assert eng.stats()["pending"] == 1
+    alloc.free(taken)
+    with eng._cond:
+        eng._cond.notify_all()
+    assert len(fut.result(timeout=WAIT)) == 4
 
 
 @pytest.mark.parametrize("kw, feature", [
@@ -367,13 +368,12 @@ def test_features_over_windowed_pools_are_refused_by_name(kw, feature):
     assert feature in str(err.value) and "window" in str(err.value)
 
 
-def test_page_export_and_import_are_refused_by_name():
-    eng, _ = make_engine()
-    with eng:
-        with pytest.raises(MXNetError, match="page export.*windowed"):
-            eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
-        with pytest.raises(MXNetError, match="page import.*windowed"):
-            eng.import_stream({}, [])
+def test_page_export_and_import_are_refused_by_name(engines):
+    eng, _ = engines(make_engine)
+    with pytest.raises(MXNetError, match="page export.*windowed"):
+        eng.submit(np.arange(1, 6, dtype=np.int32), prefill_only=True)
+    with pytest.raises(MXNetError, match="page import.*windowed"):
+        eng.import_stream({}, [])
 
 
 # -- the spec ------------------------------------------------------------

@@ -22,44 +22,31 @@ continue smoke); the wide multi-stream sweeps are marked ``slow``
 (the PR 7/13 pattern).
 """
 
+import functools
 import threading
 
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
-from mxnet_tpu import models
 from mxnet_tpu.kv_cache import trim_blocks, value_pool_shape
 from mxnet_tpu.speculative import NgramProposer, make_proposer
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 48
+from _engines import KVB, H, V, dense_engine, tiny_lm_params
+
+MAXLEN = 48
 
 
 @pytest.fixture(scope="module")
 def lm():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
     # seeded: the n-gram smoke below needs a model whose greedy output
     # repeats enough for SOME drafts to be accepted and some not — with
     # the global stream left wherever earlier tests put it, that was
     # luck of the file order
-    mx.random.seed(0)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
+    return tiny_lm_params(MAXLEN, seed=0)
 
 
-def _engine(params, **kw):
-    args = dict(vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-                max_len=MAXLEN, kv_block=KVB, max_streams=4,
-                decode_buckets=[1, 2, 4], temperature=0.0)
-    args.update(kw)
-    return mx.DecodeEngine(params, **args)
+_engine = functools.partial(dense_engine, max_len=MAXLEN)
 
 
 def _repetitive_prompt(rng, n=18, motif=5):

@@ -18,7 +18,8 @@ import pytest
 import mxnet_tpu as mx
 from mxnet_tpu import models, profiler
 
-V, KVB, L, H, DM, MAXLEN = 61, 4, 2, 2, 32, 32
+from _engines import DM, H, V, dense_engine, tiny_lm_params
+
 ENGINE_SPANS = ("serving.admit", "serving.prefill", "serving.step",
                 "serving.stage", "serving.decode_step",
                 "serving.d2h_sync", "serving.absorb", "serving.drain",
@@ -31,30 +32,16 @@ def family(name):
     return re.sub(r"\.(t|b)\d+(x\d+)?$", "", name)
 
 
-@pytest.fixture(scope="module")
-def lm_params():
-    sym = models.transformer_lm(V, MAXLEN, num_layers=L, num_heads=H,
-                                d_model=DM, block_size=KVB)
-    mod = mx.mod.Module(sym, context=mx.cpu())
-    mod.bind(data_shapes=[("data", (2, MAXLEN))],
-             label_shapes=[("softmax_label", (2, MAXLEN))],
-             for_training=False)
-    mod.init_params(mx.initializer.Xavier(factor_type="in",
-                                          magnitude=2.0))
-    arg, aux = mod.get_params()
-    return {**arg, **aux}
+def warmed():
+    eng = dense_engine(tiny_lm_params(), prefix_cache=0)
+    eng.warmup()
+    return eng
 
 
 @pytest.fixture
-def engine(lm_params):
-    eng = mx.DecodeEngine(
-        lm_params, vocab_size=V, num_layers=L, num_heads=H, d_model=DM,
-        max_len=MAXLEN, kv_block=KVB, max_streams=4,
-        decode_buckets=[1, 2, 4], temperature=0.0, prefix_cache=0)
-    eng.warmup()
-    eng.reset_stats()
-    yield eng
-    eng.close()
+def engine(engines):
+    """One warmed engine for the file's tests, its counters zeroed."""
+    return engines(warmed)
 
 
 # the scripted run: one caller, three requests one after another, so

@@ -34,6 +34,7 @@ from mxnet_tpu.models.hybrid_lm import HybridSpec, mixer_state  # noqa: E402
 from mxnet_tpu.ops import hybrid as hy  # noqa: E402
 
 from benchmark.reference import zaya as ref  # noqa: E402
+from _engines import WAIT, Family  # noqa: E402
 
 # the published shape at a size a test can hold: 4 query heads over 2 KV
 # heads of 16 (8 lanes rotated), 4 experts of 32 chosen top-1 by a router
@@ -193,25 +194,29 @@ def test_refusals_by_name(bad, match):
         HybridSpec(96, 64, [layer])
 
 
-def test_engine_refuses_what_a_slot_spec_cannot_carry():
-    spec, params = ref.spec(CFG), ref.program_names(draw())
-    kw = dict(model=spec, max_len=64, kv_block=KVB, max_streams=2,
-              ctx=mx.cpu(), dtype="float32")
+# the reference at one length, and the one engine the engine tests share
+FAMILY = Family(ref, CFG, pad=64, max_len=64, kv_block=KVB, max_streams=2,
+                decode_buckets=(2,), prefill_buckets=(16, 32),
+                temperature=0.0)
+
+
+def test_the_references_rows_do_not_see_the_padding_behind_them():
+    FAMILY.padding_is_not_seen()
+
+
+def test_engine_refuses_what_a_slot_spec_cannot_carry(engines):
     for extra, match in ((dict(prefix_cache=1), "prefix"),
                          (dict(prefill_chunk=16), "chunk"),
                          (dict(spec_tokens=2), "verify|spec"),
                          (dict(kv_dtype="int8"), "int8"),
                          (dict(tp=2), "tp|mesh|partition")):
         with pytest.raises(MXNetError, match=match):
-            mx.DecodeEngine(params, **kw, **extra)
-    eng = mx.DecodeEngine(params, **kw)
-    try:
-        with pytest.raises(MXNetError, match="prefill_only"):
-            eng.submit(sequence(5), max_new_tokens=2, prefill_only=True)
-        with pytest.raises(MXNetError, match="import"):
-            eng.import_stream({}, [])
-    finally:
-        eng.close()
+            FAMILY.engine(**extra)
+    eng, _ = engines(FAMILY.engine)
+    with pytest.raises(MXNetError, match="prefill_only"):
+        eng.submit(sequence(5), max_new_tokens=2, prefill_only=True)
+    with pytest.raises(MXNetError, match="import"):
+        eng.import_stream({}, [])
 
 
 # -- logits: prefill + decode through pages and tails --------------------
@@ -219,7 +224,7 @@ def test_engine_refuses_what_a_slot_spec_cannot_carry():
 def test_prefill_then_decode_equals_the_reference_forward():
     drawn = draw()
     seq = sequence(40)
-    want = np.asarray(ref.forward(CFG, drawn, seq))
+    want = FAMILY.logits(drawn, seq)
     # a prompt of 21 in a bucket of 32: the tail is read at row 20
     got = Programs(drawn).serve(seq, 21, 32)
     np.testing.assert_allclose(got, want[20:], atol=TOL, rtol=0)
@@ -232,8 +237,8 @@ def test_prefill_then_decode_equals_the_reference_forward():
 def test_each_mechanism_left_out_fails_the_comparison(wrong):
     drawn = draw()
     seq = sequence(40)
-    want = np.asarray(ref.forward(CFG, drawn, seq))
-    other = np.asarray(ref.forward(CFG, drawn, seq, precision=wrong))
+    want = FAMILY.logits(drawn, seq)
+    other = FAMILY.logits(drawn, seq, wrong)
     assert np.abs(other[20:] - want[20:]).max() > 50 * TOL, wrong
 
 
@@ -242,7 +247,7 @@ def test_a_tail_at_the_buckets_last_rows_fails():
     the bucket (padding) serves another next token."""
     drawn = draw()
     seq = sequence(12)
-    want = np.asarray(ref.forward(CFG, drawn, seq))
+    want = FAMILY.logits(drawn, seq)
     p = Programs(drawn)
     p.prefill(seq, 5, 8)
     sound = p.step([seq[5]], [6], (0,))[0]
@@ -266,8 +271,8 @@ def test_a_tail_at_the_buckets_last_rows_fails():
 def test_two_streams_share_no_tail_and_a_zeroed_tail_fails():
     drawn = draw()
     a, b = sequence(30, 1), sequence(30, 2)
-    want_a = np.asarray(ref.forward(CFG, drawn, a))
-    want_b = np.asarray(ref.forward(CFG, drawn, b))
+    want_a = FAMILY.logits(drawn, a)
+    want_b = FAMILY.logits(drawn, b)
     p = Programs(drawn, rows=2)
     p.prefill(a, 9, 16, row=0)
     p.prefill(b, 20, 32, row=1)
@@ -286,21 +291,14 @@ def test_two_streams_share_no_tail_and_a_zeroed_tail_fails():
     np.testing.assert_allclose(got[1], want_b[26], atol=TOL, rtol=0)
 
 
-def test_the_engine_serves_it_and_a_reused_slot_shows_no_last_owner():
-    drawn = draw()
-    params = ref.program_names(drawn)
-    eng = mx.DecodeEngine(params, model=ref.spec(CFG), max_len=64,
-                          kv_block=KVB, max_streams=2, decode_buckets=(2,),
-                          prefill_buckets=(16, 32), temperature=0.0,
-                          ctx=mx.cpu(), dtype="float32")
-    try:
-        prompts = [sequence(n, s) for n, s in ((5, 3), (17, 4), (9, 5),
-                                                (12, 6), (7, 7))]
-        futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
-        outs = [np.asarray(f.result(timeout=300)) for f in futs]
-        st = eng.stats()
-    finally:
-        eng.close()
+def test_the_engine_serves_it_and_a_reused_slot_shows_no_last_owner(
+        engines):
+    eng, drawn = engines(FAMILY.engine)
+    prompts = [sequence(n, s) for n, s in ((5, 3), (17, 4), (9, 5),
+                                            (12, 6), (7, 7))]
+    futs = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    outs = [np.asarray(f.result(timeout=WAIT)) for f in futs]
+    st = eng.stats()
     # five streams through two slots: every slot was reused
     assert st["state_slots"] == 2 and st["state_slots_live"] == 0
     assert st["state_pool_bytes"] == 3 * 3 * 8 * 128 * 4
@@ -315,7 +313,7 @@ def test_the_engine_serves_it_and_a_reused_slot_shows_no_last_owner():
         # greedy against the reference, teacher-forced: every served
         # token lies within TOL of the reference's best logit
         seq = np.concatenate([p, out])
-        z = np.asarray(ref.forward(CFG, drawn, seq))[len(p) - 1:-1]
+        z = FAMILY.logits(drawn, seq)[len(p) - 1:-1]
         gap = z.max(-1) - z[np.arange(len(out)), out]
         assert gap.max() < TOL, (len(p), gap)
 
